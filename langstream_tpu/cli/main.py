@@ -99,6 +99,11 @@ async def _request(method: str, url: str, **kwargs):
 @click.group()
 def cli() -> None:
     """langstream-tpu: TPU-native event-driven LLM application platform."""
+    # `run` compiles in this process and `mini up` in pod children that
+    # inherit the environment: place the compile cache before either
+    from langstream_tpu.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
 
 
 @cli.command()

@@ -114,10 +114,11 @@ async def _mini_up(
         + os.pathsep
         + os.environ.get("PYTHONPATH", ""),
     }
-    if not use_tpu:
-        pod_env["JAX_PLATFORMS"] = "cpu"
+    # a chip belongs to one process: the kubelet pins every pod to the CPU
+    # except the one whose agent requests google.com/tpu (`--tpu`: one chip)
     kubelet = ProcessKubelet(
-        HttpKubeApi(kube.url), root=data_dir / "kubelet", env_extra=pod_env
+        HttpKubeApi(kube.url), root=data_dir / "kubelet", env_extra=pod_env,
+        tpu_chips=1 if use_tpu else 0,
     ).start()
     click.echo(f"✔ operator + kubelet   pods under {data_dir / 'kubelet'}")
 
@@ -248,9 +249,11 @@ def mini() -> None:
 @click.option("--data-dir", default=None,
               help="cluster state root (default ~/.langstream-tpu/mini)")
 @click.option("--tpu", "use_tpu", is_flag=True, default=False,
-              help="let agent pods see the TPU (default: pods pin "
-                   "JAX_PLATFORMS=cpu so a laptop run never fights over "
-                   "one chip)")
+              help="offer the host's chip to the ONE agent pod that "
+                   "requests google.com/tpu (an agent with a device "
+                   "mesh); a second requester is refused — a chip belongs "
+                   "to one process. Default: every pod pins "
+                   "JAX_PLATFORMS=cpu")
 @click.option("--once", is_flag=True, default=False,
               help="smoke mode: drive one chat message through the "
                    "cluster, then tear down (CI-able)")

@@ -60,6 +60,7 @@ class _Pod:
     failed: bool = False
     reported: bool = False  # job completion already patched
     env: dict[str, str] = field(default_factory=dict)
+    tpu_chips: int = 0  # chips this pod's process holds while it runs
 
 
 def _hash_template(template: dict[str, Any]) -> str:
@@ -77,6 +78,7 @@ class ProcessKubelet:
         root: Path | str,
         env_extra: dict[str, str] | None = None,
         python: str | None = None,
+        tpu_chips: int = 0,
     ):
         self.api = api
         self.root = Path(root)
@@ -85,6 +87,13 @@ class ProcessKubelet:
         # mini API server), broker addresses, JAX platform pins, ...
         self.env_extra = dict(env_extra or {})
         self.python = python or sys.executable
+        # the node's chip inventory. 0 (a laptop): every container is
+        # pinned to the CPU. Otherwise chips go out the way the device
+        # plugin hands them out: only to a container that requests
+        # google.com/tpu, and to one pod at a time — a chip belongs to one
+        # process, and a second JAX process that reaches for it fails or
+        # hangs. Every other container is pinned to the CPU.
+        self.tpu_chips = tpu_chips
         self.pods: dict[tuple[str, str], _Pod] = {}  # (ns, pod name)
         self._stop = threading.Event()
         self._thread: threading.Thread | None = None
@@ -155,11 +164,31 @@ class ProcessKubelet:
             cmd[0] = self.python
         return cmd
 
+    def _chips_held(self, except_pod: _Pod) -> int:
+        return sum(
+            p.tpu_chips for p in self.pods.values()
+            if p is not except_pod and p.proc is not None
+            and p.proc.poll() is None
+        )
+
     def _container_env(
         self, pod: _Pod, container: dict[str, Any]
     ) -> dict[str, str]:
         env = dict(os.environ)
         env.update(self.env_extra)
+        limits = (container.get("resources") or {}).get("limits") or {}
+        wanted = int(limits.get("google.com/tpu", 0))
+        if wanted == 0 or self.tpu_chips == 0:
+            env["JAX_PLATFORMS"] = "cpu"
+        else:
+            free = self.tpu_chips - self._chips_held(pod)
+            if wanted > free:
+                raise RuntimeError(
+                    f"pod {pod.name} requests {wanted} google.com/tpu, "
+                    f"{free} of {self.tpu_chips} free on this node: a chip "
+                    f"belongs to one process at a time"
+                )
+            pod.tpu_chips = wanted
         for e in container.get("env", []):
             if "value" in e:
                 env[e["name"]] = str(e["value"])
@@ -208,7 +237,14 @@ class ProcessKubelet:
         containers = pod_spec.get("containers", [])
         main = containers[0]
         cmd = self._container_cmd(main, mounts)
-        pod.env = self._container_env(pod, main)
+        try:
+            pod.env = self._container_env(pod, main)
+        except RuntimeError as e:  # no chip free: unschedulable, retried
+            log.warning("%s", e)
+            log_f.write(f"{e}\n".encode())
+            log_f.close()
+            pod.failed = True
+            return
         pod.proc = subprocess.Popen(
             cmd, env=pod.env, stdout=log_f, stderr=subprocess.STDOUT,
             start_new_session=True,

@@ -48,10 +48,16 @@ def _flash_mode(seq_len: int) -> str | None:
 
     ``LS_TPU_FLASH``: ``auto`` (default — compiled kernel on TPU for
     long-enough sequences), ``1``/``0`` force on/off, ``interpret`` runs the
-    kernel in interpreter mode (CPU tests).
+    kernel in interpreter mode (CPU tests only: refused on a TPU backend).
     """
     env = os.environ.get("LS_TPU_FLASH", "auto").lower()
     if env == "interpret":
+        if jax.default_backend() == "tpu":
+            raise ValueError(
+                "LS_TPU_FLASH=interpret runs the flash kernel in the Pallas "
+                "interpreter, which exists for CPU tests; on a TPU use "
+                "auto, 1 or 0"
+            )
         return "interpret"
     if env in ("1", "true", "on"):
         return "compiled"

@@ -118,10 +118,14 @@ def llama_prefill_continue_paged(
     quant = isinstance(pool_k, dict)
     bs = (pool_k["q"] if quant else pool_k).shape[2]
     if quant and kernel != "xla":
-        # the multi-query history-read kernel has no int8 twin yet (the
-        # decode chunk's single-query kernel does); prefill continuations
-        # are a small share of traffic — degrade, don't crash
-        kernel = "xla"
+        # the multi-query history-read kernel has no int8 twin (the decode
+        # chunk's single-query kernel does); the caller selects "xla" for
+        # int8 pools (engine: continuation_read_kernel) — nothing is
+        # substituted here
+        raise ValueError(
+            f"kernel={kernel!r} cannot read an int8 pool in the "
+            f"continuation path; pass kernel='xla'"
+        )
     KhD = c.kv_heads * c.head_dim
     G = c.heads // c.kv_heads
     x = embedding_take(params["embed"], tokens)  # (B, P2, H)
@@ -680,10 +684,14 @@ def llama_decode_chunk_paged(
         and mesh is not None
         and len(mesh.devices.flatten()) > 1
     ):
-        # the shard_map Pallas wrapper doesn't carry the int8 scale specs
-        # yet; multi-device int8 pools stay on the (sharding-aware) XLA
-        # gather. Single device reads through the in-kernel dequant twin.
-        kernel = "xla"
+        # the shard_map Pallas wrapper carries no int8 scale specs;
+        # multi-device int8 pools read through the (sharding-aware) XLA
+        # gather, which the caller selects (the engine refuses this
+        # combination at construction) — nothing is substituted here
+        raise ValueError(
+            f"kernel={kernel!r} cannot read an int8 pool under a "
+            f"multi-device mesh; pass kernel='xla'"
+        )
     B = tokens0.shape[0]
     KhD = c.kv_heads * c.head_dim
     adv = active.astype(jnp.int32)

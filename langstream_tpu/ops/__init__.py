@@ -2,8 +2,15 @@
 
 - :mod:`langstream_tpu.ops.flash_attention` — blocked causal GQA attention
   (prefill/forward): O(S) memory instead of the O(S²) score matrix.
+- :mod:`langstream_tpu.ops.paged_attention` — paged decode reads (bf16 and
+  int8 pools) and the multi-query history read of continuation/verify.
+- :mod:`langstream_tpu.ops.selfcheck` — builds every kernel above at a
+  served model's shapes and compares it with the XLA read it replaces.
 
-Kernels run compiled on TPU and in interpret mode on CPU (tests).
+On a TPU the kernels run compiled (all four compile on the v5e at
+Llama-3-8B shapes — ``chip_smoke.py`` checks it on every run); a selected
+kernel that cannot be built raises, nothing falls back to XLA. Interpret
+mode is for the CPU tests only and is refused on a TPU backend.
 """
 
 from langstream_tpu.ops.flash_attention import flash_attention  # noqa: F401

@@ -28,8 +28,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from langstream_tpu.jax_compat import pallas_compiler_params as _compiler_params
-
 NEG_INF = float(jnp.finfo(jnp.float32).min)
 
 
@@ -166,7 +164,7 @@ def _flash_bhsd(
             pltpu.VMEM((block_q, 128), jnp.float32),
             pltpu.VMEM((block_q, D), jnp.float32),
         ],
-        compiler_params=_compiler_params()(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -206,8 +204,6 @@ def flash_attention(
 
         from jax.sharding import PartitionSpec as P
 
-        from langstream_tpu.jax_compat import shard_map
-
         axes = mesh.axis_names
         H_, Kh_, B_ = q.shape[2], k.shape[2], q.shape[0]
         tp = (
@@ -229,9 +225,9 @@ def flash_attention(
                 causal=causal, scale=scale, block_q=block_q, block_k=block_k,
                 interpret=interpret, mesh=None,
             )
-            return shard_map(
+            return jax.shard_map(
                 inner, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
-                check_rep=False,
+                check_vma=False,
             )(q, k, v)
         # no shardable axis (tiny batch on a dp-only mesh): the plain call
         # below is replicated per device by pjit — correct, just not sharded

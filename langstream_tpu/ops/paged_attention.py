@@ -36,8 +36,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from langstream_tpu.jax_compat import pallas_compiler_params as _compiler_params
-
 NEG_INF = float(jnp.finfo(jnp.float32).min)
 
 
@@ -301,7 +299,7 @@ def paged_attention_partial(
             jax.ShapeDtypeStruct((B, H, 128), jnp.float32),
             jax.ShapeDtypeStruct((B, H, 128), jnp.float32),
         ],
-        compiler_params=_compiler_params()(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -367,7 +365,7 @@ def _paged_attention_partial_q8(
             jax.ShapeDtypeStruct((B, H, 128), jnp.float32),
             jax.ShapeDtypeStruct((B, H, 128), jnp.float32),
         ],
-        compiler_params=_compiler_params()(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -550,7 +548,7 @@ def paged_attention_multiquery_partial(
             jax.ShapeDtypeStruct((B, nt * THb, 8), jnp.float32),
             jax.ShapeDtypeStruct((B, nt * THb, 8), jnp.float32),
         ],
-        compiler_params=_compiler_params()(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -587,8 +585,6 @@ def shard_mapped_paged_read(
 
     from jax.sharding import PartitionSpec as P
 
-    from langstream_tpu.jax_compat import shard_map
-
     axes = mesh.axis_names
     dp = (
         "dp"
@@ -608,7 +604,7 @@ def shard_mapped_paged_read(
         return {"dp": dp, "tp": tp}.get(entry, entry) if entry else None
 
     q_spec = P(dp, *(sub(e) for e in q_spec_tail))
-    return shard_map(
+    return jax.shard_map(
         _partial(fn, kv_heads=kv_heads // tp_size),
         mesh=mesh,
         in_specs=(

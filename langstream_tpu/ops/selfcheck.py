@@ -1,0 +1,220 @@
+"""Build every Pallas kernel the engine can select at a served model's
+shapes and compare each with the XLA read it replaces.
+
+The CPU tests only ever meet these kernels through the Pallas interpreter,
+which accepts layouts Mosaic refuses. This module is the compiled
+counterpart: ``chip_smoke.py`` runs it in the process that holds the chip,
+after the served requests, at the served model's head geometry and the
+window buckets the requests used. ``interpret=True`` exists for the CPU
+rehearsal only and is said so in every result row.
+
+Each row: ``{"kernel", "shape", "interpret", "ok", "max_abs_err", "tol"}``
+plus ``"error"`` (the compiler's or runtime's own words, trimmed) when the
+kernel did not build or run. Tolerance: inputs are bf16 (int8 pools carry
+f32 scales), outputs are O(1) softmax averages of unit-normal values, and
+the kernels accumulate in f32 over blocks where XLA reduces over the whole
+window — 3e-2 absolute covers the bf16 probability rounding on both sides
+(the interpreted CPU tests see ~3e-2 on O(1–4) outputs, tests/test_paged.py).
+"""
+
+from __future__ import annotations
+
+import math
+from typing import Any, Callable
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+TOLERANCE = 3e-2
+
+
+def _int8_pool(key, shape_rows: tuple, kv_heads: int, head_dim: int) -> dict:
+    kq, ks = jax.random.split(key)
+    return {
+        "q": jax.random.randint(
+            kq, (*shape_rows, kv_heads * head_dim), -127, 128, jnp.int8
+        ),
+        "s": jax.random.uniform(
+            ks, (*shape_rows, kv_heads), jnp.float32, 0.005, 0.02
+        ),
+    }
+
+
+def _tables_and_lengths(batch: int, read_blocks: int, block_size: int, nb: int):
+    """Distinct blocks per slot (block 0 is the scratch block, as in the
+    engine) and ragged lengths: a sub-block row, an exact block boundary,
+    the rest spread up to the full window."""
+    ids = 1 + np.arange(batch * read_blocks) % (nb - 1)
+    tables = ids.reshape(batch, read_blocks).astype(np.int32)
+    window = read_blocks * block_size
+    lengths = np.linspace(1, window, batch).astype(np.int32)
+    lengths[0] = max(1, block_size // 2 - 3)
+    if batch > 1:
+        lengths[1] = block_size
+    lengths[-1] = window
+    return jnp.asarray(tables), jnp.asarray(lengths)
+
+
+def _row(kernel: str, shape: dict, interpret: bool,
+         run: Callable[[], tuple[Any, Any]]) -> dict[str, Any]:
+    row: dict[str, Any] = {
+        "kernel": kernel, "shape": shape, "interpret": interpret,
+        "tol": TOLERANCE,
+    }
+    try:
+        got, ref = run()
+        got = np.asarray(jax.block_until_ready(got), dtype=np.float32)
+        ref = np.asarray(jax.block_until_ready(ref), dtype=np.float32)
+    except Exception as e:  # the row IS the boundary: the compiler's words
+        # are the finding, and the caller fails on ok=False
+        row.update(ok=False, error=f"{type(e).__name__}: {e}"[:2000])
+        return row
+    err = float(np.max(np.abs(got - ref)))
+    row.update(
+        ok=bool(np.isfinite(got).all() and err <= TOLERANCE),
+        max_abs_err=round(err, 5),
+    )
+    return row
+
+
+def check_kernels(
+    model_config,
+    *,
+    block_size: int,
+    read_blocks: tuple[int, ...],
+    batch: int,
+    flash_seq: int = 512,
+    interpret: bool = False,
+) -> list[dict[str, Any]]:
+    """One row per (kernel, window bucket). ``model_config`` supplies
+    heads / kv_heads / head_dim; pools are random, made from a fixed seed."""
+    from langstream_tpu.models.llama import _flash_mode
+    from langstream_tpu.models.llama_paged import (
+        _cache_partial_xla,
+        _gather_layer_window,
+    )
+    from langstream_tpu.ops.flash_attention import flash_attention
+    from langstream_tpu.ops.paged_attention import (
+        NEG_INF,
+        merge_partial_attention,
+        paged_attention_multiquery_partial,
+        paged_attention_partial,
+    )
+
+    c = model_config
+    H, Kh, D = c.heads, c.kv_heads, c.head_dim
+    nb = batch * max(read_blocks) + 1
+    keys = jax.random.split(jax.random.PRNGKey(21), 8)
+    q1 = jax.random.normal(keys[0], (batch, H, D), jnp.bfloat16)
+    pool_k = jax.random.normal(keys[1], (nb, block_size, Kh * D), jnp.bfloat16)
+    pool_v = jax.random.normal(keys[2], (nb, block_size, Kh * D), jnp.bfloat16)
+    pool_k8 = _int8_pool(keys[3], (nb, block_size), Kh, D)
+    pool_v8 = _int8_pool(keys[4], (nb, block_size), Kh, D)
+    t_block = 16
+    qT = jax.random.normal(keys[5], (batch, t_block, H, D), jnp.bfloat16)
+    rows: list[dict[str, Any]] = []
+
+    for nrb in read_blocks:
+        tables, lengths = _tables_and_lengths(batch, nrb, block_size, nb)
+        shape = {
+            "B": batch, "H": H, "Kh": Kh, "D": D, "block": block_size,
+            "read_blocks": nrb,
+        }
+
+        def single(pk, pv, nrb=nrb, tables=tables, lengths=lengths):
+            got = jax.jit(
+                lambda q, k, v, t, n: merge_partial_attention([
+                    paged_attention_partial(
+                        q, k, v, t, n, num_read_blocks=nrb, kv_heads=Kh,
+                        head_dim=D, interpret=interpret,
+                    )
+                ])
+            )(q1, pk, pv, tables, lengths)
+            ref = jax.jit(
+                lambda q, k, v, t, n: merge_partial_attention([
+                    _cache_partial_xla(c, q, k, v, t, n, nrb)
+                ])
+            )(q1, pk, pv, tables, lengths)
+            return got, ref
+
+        rows.append(_row(
+            "_paged_kernel", shape, interpret,
+            lambda: single(pool_k, pool_v),
+        ))
+        rows.append(_row(
+            "_paged_kernel_q8", shape, interpret,
+            lambda: single(pool_k8, pool_v8),
+        ))
+
+        def multi(nrb=nrb, tables=tables, lengths=lengths):
+            got = jax.jit(
+                lambda q, k, v, t, n: merge_partial_attention([
+                    paged_attention_multiquery_partial(
+                        q, k, v, t, n, num_read_blocks=nrb, kv_heads=Kh,
+                        head_dim=D, t_block=t_block, interpret=interpret,
+                    )
+                ])
+            )(qT, pool_k, pool_v, tables, lengths)
+
+            def xla_history(q, k, v, t, n):
+                # the read the kernel replaces: densify the window, every
+                # suffix query attends the history rows < start
+                kw = _gather_layer_window(c, k, t, nrb).astype(jnp.float32)
+                vw = _gather_layer_window(c, v, t, nrb).astype(jnp.float32)
+                W = kw.shape[1]
+                qg = q.astype(jnp.float32).reshape(
+                    batch, t_block, Kh, H // Kh, D
+                )
+                s = jnp.einsum("btkgd,bwkd->btkgw", qg, kw) / math.sqrt(D)
+                mask = (jnp.arange(W)[None, :] < n[:, None])[
+                    :, None, None, None, :
+                ]
+                p = jax.nn.softmax(jnp.where(mask, s, NEG_INF), axis=-1)
+                out = jnp.einsum("btkgw,bwkd->btkgd", p, vw)
+                return out.reshape(batch, t_block, H, D)
+
+            ref = jax.jit(xla_history)(qT, pool_k, pool_v, tables, lengths)
+            return got, ref
+
+        rows.append(_row(
+            "_paged_mq_kernel", {**shape, "T": t_block}, interpret, multi,
+        ))
+
+    def flash():
+        mode = "interpret" if interpret else _flash_mode(flash_seq)
+        if mode is None:
+            raise RuntimeError(
+                f"_flash_mode({flash_seq}) selected no kernel on backend "
+                f"{jax.default_backend()!r}"
+            )
+        kq, kk, kv = jax.random.split(keys[6], 3)
+        q = jax.random.normal(kq, (1, flash_seq, H, D), jnp.bfloat16)
+        k = jax.random.normal(kk, (1, flash_seq, Kh, D), jnp.bfloat16)
+        v = jax.random.normal(kv, (1, flash_seq, Kh, D), jnp.bfloat16)
+        got = jax.jit(
+            lambda q, k, v: flash_attention(
+                q, k, v, causal=True, interpret=(mode == "interpret")
+            )
+        )(q, k, v)
+
+        def xla_attention(q, k, v):
+            # the einsum branch of models/llama.py prefill_forward
+            qg = q.reshape(1, flash_seq, Kh, H // Kh, D)
+            s = jnp.einsum("bqkgd,bskd->bkgqs", qg, k).astype(jnp.float32)
+            s = s / math.sqrt(D)
+            causal = (
+                jnp.arange(flash_seq)[:, None] >= jnp.arange(flash_seq)[None, :]
+            )
+            s = jnp.where(causal[None, None, None], s, NEG_INF)
+            p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
+            out = jnp.einsum("bkgqs,bskd->bqkgd", p, v)
+            return out.reshape(1, flash_seq, H, D)
+
+        return got, jax.jit(xla_attention)(q, k, v)
+
+    rows.append(_row(
+        "_flash_kernel",
+        {"B": 1, "S": flash_seq, "H": H, "Kh": Kh, "D": D}, interpret, flash,
+    ))
+    return rows

@@ -26,8 +26,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from langstream_tpu.jax_compat import SHARD_MAP_PARTIAL_AUTO, shard_map
-
 from langstream_tpu.models.llama import (
     LlamaConfig,
     _rms_norm,
@@ -154,7 +152,7 @@ def llama_forward_pp(
         out, _ = jax.lax.scan(body, xm, local_layers)
         return out.astype(jnp.float32), jnp.float32(0.0)
 
-    run = shard_map(
+    run = jax.shard_map(
         lambda layers, xm: gpipe(partial(stage, layers), xm)[0],
         mesh=mesh,
         in_specs=(
@@ -192,10 +190,9 @@ def moe_forward_pp(
         raise ValueError(f"batch {B} not divisible by microbatches {M}")
     capacity = c.capacity((B // M) * S)
     axes = mesh.axis_names
-    # in-stage ep constraints need partial-manual shard_map (pp manual,
-    # ep/tp automatic); old jax runs the stage fully manual instead, where
-    # a mesh-axis constraint is illegal — experts are simply replicated
-    ep = "ep" if "ep" in axes and SHARD_MAP_PARTIAL_AUTO else None
+    # in-stage ep constraints ride the partial-manual shard_map below (pp
+    # manual via axis_names, ep/tp automatic)
+    ep = "ep" if "ep" in axes else None
     e_spec = NamedSharding(mesh, P(ep, None, None))
 
     x = jnp.take(params["embed"], tokens, axis=0)
@@ -232,7 +229,7 @@ def moe_forward_pp(
         )
         return out.astype(jnp.float32), aux_total
 
-    run = shard_map(
+    run = jax.shard_map(
         lambda layers, xm: gpipe(partial(stage_fn, layers), xm),
         mesh=mesh,
         in_specs=(

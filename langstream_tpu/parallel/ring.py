@@ -33,8 +33,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from langstream_tpu.jax_compat import shard_map
-
 
 def _axis_or_none(mesh: Mesh, name: str | None) -> str | None:
     if name is None or mesh is None:
@@ -153,7 +151,7 @@ def ring_attention(
     if sa is None:
         raise ValueError(f"mesh {mesh.axis_names} has no sequence axis {seq_axis!r}")
     spec = P(ba, sa, ha, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         partial(_ring_attention_local, axis_name=sa, causal=causal, scale=scale),
         mesh=mesh,
         in_specs=(spec, spec, spec),
@@ -244,7 +242,7 @@ def ulysses_attention(
     if sa is None:
         raise ValueError(f"mesh {mesh.axis_names} has no sequence axis {seq_axis!r}")
     spec = P(ba, sa, None, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         partial(_ulysses_local, axis_name=sa, causal=causal, scale=scale),
         mesh=mesh,
         in_specs=(spec, spec, spec),

@@ -763,13 +763,9 @@ def main(argv: list[str] | None = None) -> int:
         level=logging.INFO,
         format="%(asctime)s %(levelname)s %(name)s %(message)s",
     )
-    if os.environ.get("JAX_PLATFORMS"):
-        # some TPU plugins override JAX_PLATFORMS at interpreter start; the
-        # config knob re-asserts the pod spec's platform choice (the
-        # process-kubelet pins agent pods to CPU unless `mini up --tpu`)
-        import jax
+    from langstream_tpu.compile_cache import configure_compile_cache
 
-        jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
+    configure_compile_cache()
     argv = argv if argv is not None else sys.argv[1:]
     if not argv:
         print(

@@ -29,7 +29,7 @@ compiled programs, XLA scratch, allocator overhead — everything the
 engine cannot see from host). Prefix-cache blocks live *inside* the KV
 pool arrays, so they are reported as a sub-owner
 (``kv_pool_prefix_bytes``), never double-counted: the owner sum plus
-slack equals the detected (or table-fallback) capacity exactly.
+slack equals the allocator's ``bytes_limit`` exactly.
 
 Cost-model assumptions (documented limits, not hidden ones):
 
@@ -127,7 +127,9 @@ class ProgramCost:
     kv_write_bytes: int
     act_bytes: int
     flops: int
-    hbm_gbps: float
+    #: the device's published HBM bandwidth; None off-TPU, where there is
+    #: no roof and every expectation derived from it reads None
+    hbm_gbps: float | None
     #: tokens the dispatch advances when fully active (normalization)
     tokens: int
 
@@ -138,8 +140,10 @@ class ProgramCost:
             + self.act_bytes
         )
 
-    def expected_ms(self) -> float:
+    def expected_ms(self) -> float | None:
         """The HBM-bandwidth floor for one dispatch of this program."""
+        if self.hbm_gbps is None:
+            return None
         return self.total_bytes / (self.hbm_gbps * 1e9) * 1e3
 
     def to_dict(self) -> dict[str, Any]:
@@ -151,7 +155,11 @@ class ProgramCost:
             "total_bytes": self.total_bytes,
             "flops": self.flops,
             "tokens": self.tokens,
-            "expected_ms": round(self.expected_ms(), 4),
+            "expected_ms": (
+                round(expected, 4)
+                if (expected := self.expected_ms()) is not None
+                else None
+            ),
         }
 
 
@@ -161,7 +169,7 @@ def decode_cost(
     slots: int,
     window_rows: int,
     k_steps: int,
-    hbm_gbps: float,
+    hbm_gbps: float | None,
 ) -> ProgramCost:
     """One decode-chunk dispatch: ``k_steps`` fused steps over the full
     ``slots`` batch, each streaming every weight byte and sweeping a
@@ -196,7 +204,7 @@ def prefill_cost(
     rows: int,
     tokens_per_row: int,
     prefix_rows: int,
-    hbm_gbps: float,
+    hbm_gbps: float | None,
 ) -> ProgramCost:
     """One (possibly batched) prefill dispatch: ``rows`` padded batch
     rows of ``tokens_per_row`` new tokens each. ``prefix_rows`` > 0 is
@@ -237,7 +245,7 @@ def verify_cost(
     slots: int,
     window_rows: int,
     drafts: int,
-    hbm_gbps: float,
+    hbm_gbps: float | None,
 ) -> ProgramCost:
     """One speculative verify dispatch: every slot advances ``drafts+1``
     positions in one forward over the full KV window."""
@@ -352,7 +360,8 @@ class ProgramLedger:
                 # HBM bandwidth; low means THIS program owns gap
                 "achieved_vs_expected": (
                     round(expected / measured_p50, 6)
-                    if measured_p50 else None
+                    if measured_p50 and expected is not None
+                    else None
                 ),
             }
             out.append(entry)
@@ -375,7 +384,6 @@ def memory_ledger(
     sampler_bytes: int,
     tables_bytes: int,
     limit_bytes: int | None,
-    limit_source: str,
     in_transit_bytes: int = 0,
     kv_withheld_bytes: int = 0,
 ) -> dict[str, Any]:
@@ -385,8 +393,8 @@ def memory_ledger(
     compiled programs, XLA scratch, allocator overhead: resident bytes
     the host cannot attribute. By construction the owner sum (slack
     included) equals ``limit_bytes`` exactly when a limit is known; a
-    *negative* slack is reported honestly (the accounting or the
-    capacity table is wrong — either way the operator must see it).
+    *negative* slack is reported honestly (the accounting is wrong and
+    the operator must see it).
     Prefix-cache blocks live inside the KV pool arrays, so they are a
     sub-owner (``kv_pool_prefix_bytes``), never added to the sum — and
     so are budget blocks withheld by an adaptive pool-shrink
@@ -414,6 +422,5 @@ def memory_ledger(
         "kv_pool_prefix_bytes": prefix_blocks * bytes_per_block,
         "kv_pool_withheld_bytes": kv_withheld_bytes,
         "limit_bytes": limit_bytes,
-        "limit_source": limit_source,
         "slack_bytes": slack,
     }

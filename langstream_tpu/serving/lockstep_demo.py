@@ -28,7 +28,14 @@ from pathlib import Path
 
 
 def _force_cpu(devices_per_proc: int) -> None:
-    os.environ["JAX_PLATFORMS"] = "cpu"
+    """The demo is CPU-only by construction: a chip belongs to one process
+    and the group is two. ``python -m`` imports the package, and with it
+    jax, before this runs, so the platform goes through ``jax.config``
+    (the variable is only read at import); ``XLA_FLAGS`` is read at
+    backend init, so setting it here is still in time."""
+    import jax
+
+    jax.config.update("jax_platforms", "cpu")
     flags = os.environ.get("XLA_FLAGS", "")
     flag = f"--xla_force_host_platform_device_count={devices_per_proc}"
     if "xla_force_host_platform_device_count" not in flags:
@@ -62,7 +69,6 @@ def run_process(
     _force_cpu(devices_per_proc)
     import jax
 
-    jax.config.update("jax_platforms", "cpu")
     jax.distributed.initialize(
         coordinator_address=f"127.0.0.1:{coordinator_port}",
         num_processes=num_processes,
@@ -159,9 +165,6 @@ def main() -> None:
     if args.reference:
         total = args.num_processes * args.devices_per_proc
         _force_cpu(total)
-        import jax
-
-        jax.config.update("jax_platforms", "cpu")
         tokens = run_single_process_reference(total)
         if args.out:
             Path(args.out).write_text(json.dumps(tokens))

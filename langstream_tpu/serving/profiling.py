@@ -162,104 +162,73 @@ class DecodeRoofline:
     weight_bytes: int          # streamed once per step (all slots share it)
     cache_bytes_per_step: int  # KV window read across all slots
     total_bytes_per_step: int
-    hbm_gbps: float            # assumed device bandwidth
-    # detected device identity, recorded so a bench JSON says WHICH roof it
-    # was measured against instead of implying v5e everywhere
-    generation: str | None = None   # "v5e"/"v5p"/"v4"/"v6e"; None off-TPU
-    hbm_bytes: int | None = None    # allocator bytes_limit when exposed
+    # the device the roof belongs to, as JAX reports it; off-TPU there is
+    # no roof: hbm_gbps is None and the derived fields come out None too
+    device_kind: str
+    hbm_gbps: float | None
+    hbm_bytes: int | None = None    # allocator bytes_limit when reported
 
-    def min_step_ms(self) -> float:
+    def min_step_ms(self) -> float | None:
+        if self.hbm_gbps is None:
+            return None
         return self.total_bytes_per_step / (self.hbm_gbps * 1e9) * 1e3
 
-    def utilization(self, achieved_step_ms: float) -> float:
-        return self.min_step_ms() / max(achieved_step_ms, 1e-9)
+    def utilization(self, achieved_step_ms: float) -> float | None:
+        floor = self.min_step_ms()
+        if floor is None:
+            return None
+        return floor / max(achieved_step_ms, 1e-9)
 
 
-# published HBM bandwidth by TPU generation (GB/s); used for reporting only
-_HBM_GBPS = {"v5e": 819.0, "v5p": 2765.0, "v4": 1228.0, "v6e": 1640.0}
+class UnknownDeviceError(RuntimeError):
+    """A ``tpu`` device whose ``device_kind`` has no row in
+    :data:`DEVICE_PEAKS`: an error, never a default."""
 
-# published per-chip HBM capacity by generation — the fallback when the
-# platform's allocator hides memory stats (several TPU plugins return None
-# from memory_stats(), which is how BENCH_r05 recorded "hbm": null on a
-# real chip). Used for reporting and the attribution memory ledger.
-_HBM_CAPACITY_BYTES = {
-    "v5e": 16 * 2**30,
-    "v5p": 95 * 2**30,
-    "v4": 32 * 2**30,
-    "v6e": 32 * 2**30,
+
+# Published peaks, keyed by ``jax.devices()[0].device_kind`` exactly as the
+# runtime reports it. One row per device this repo has actually run on —
+# add a row (with its source) when it meets another; a guess for an unseen
+# device would put one chip's roof under another's numbers.
+DEVICE_PEAKS: dict[str, dict[str, Any]] = {
+    "TPU v5 lite": {
+        "hbm_gbps": 819.0,
+        "bf16_tflops": 197.0,
+        "int8_tops": 393.0,
+        "ici_gbit_s": 1600.0,
+        "source": 'Google Cloud documentation, "TPU v5e" system architecture',
+    },
 }
 
-# jax device_kind substrings → generation key (plugins spell these several
-# ways: "TPU v5 lite", "TPU v5e", "TPU v6 lite", ...). Checked in order so
-# the lite variants match before the bare version numbers.
-_DEVICE_KIND_GEN = (
-    ("v5 lite", "v5e"),
-    ("v5lite", "v5e"),
-    ("v5e", "v5e"),
-    ("v5p", "v5p"),
-    ("v6 lite", "v6e"),
-    ("v6lite", "v6e"),
-    ("v6e", "v6e"),
-    ("v4", "v4"),
-)
 
+def device_peaks() -> tuple[str, dict[str, Any] | None]:
+    """``(device_kind, peaks row)`` of the first local device. Off-TPU the
+    row is None (there is no roofline to report against a CPU); a ``tpu``
+    device that is not in the table raises :class:`UnknownDeviceError`."""
+    import jax
 
-def detect_generation() -> str | None:
-    """TPU generation key from ``TPU_ACCELERATOR_TYPE``, falling back to
-    the live backend's ``device_kind`` (the env var is unset under some
-    plugins — the reason ``device.hbm``/generation used to come out null).
-    None on CPU/GPU or when nothing matches."""
-    accel = os.environ.get("TPU_ACCELERATOR_TYPE", "")
-    for key in _HBM_GBPS:
-        if accel.startswith(key):
-            return key
-    try:
-        import jax
-
-        devices = jax.local_devices()
-        if not devices or devices[0].platform != "tpu":
-            return None
-        kind = getattr(devices[0], "device_kind", "").lower()
-        for pattern, key in _DEVICE_KIND_GEN:
-            if pattern in kind:
-                return key
-    except Exception:  # backend not initialized / no devices: just unknown
-        return None
-    return None
-
-
-def detect_hbm_capacity() -> tuple[int | None, str]:
-    """(per-chip HBM bytes, source) — allocator truth when the platform
-    exposes memory stats (``source: "memory_stats"``), else the published
-    per-generation capacity table (``source: "table:<gen>"`` — the fix
-    for BENCH_r05 recording ``"hbm": null`` on a real chip whose plugin
-    hides allocator stats), else ``(None, "unknown")`` (CPU/GPU)."""
-    try:
-        import jax
-
-        stats = jax.local_devices()[0].memory_stats()
-        if stats and stats.get("bytes_limit"):
-            return int(stats["bytes_limit"]), "memory_stats"
-    except Exception as e:
-        log.debug("memory_stats unavailable: %s", e)
-    generation = detect_generation()
-    if generation in _HBM_CAPACITY_BYTES:
-        return _HBM_CAPACITY_BYTES[generation], f"table:{generation}"
-    return None, "unknown"
+    device = jax.local_devices()[0]
+    if device.platform != "tpu":
+        return device.device_kind, None
+    peaks = DEVICE_PEAKS.get(device.device_kind)
+    if peaks is None:
+        raise UnknownDeviceError(
+            f"no published peaks for TPU device_kind {device.device_kind!r}; "
+            f"known: {sorted(DEVICE_PEAKS)}. Add a row with its source to "
+            f"langstream_tpu/serving/profiling.py DEVICE_PEAKS"
+        )
+    return device.device_kind, peaks
 
 
 def detect_hbm_bytes() -> int | None:
-    """Physical HBM per chip: the allocator's ``bytes_limit`` when
-    exposed, falling back to the per-generation capacity table (see
-    :func:`detect_hbm_capacity` for the source annotation)."""
-    return detect_hbm_capacity()[0]
+    """Device memory the allocator will hand out
+    (``memory_stats()["bytes_limit"]``), or None where the backend reports
+    none (CPU)."""
+    import jax
 
-
-def detect_hbm_gbps(default: float = 819.0) -> float:
-    """Bandwidth of the detected generation; ``default`` (v5e, the fleet
-    baseline) only when no generation can be detected at all."""
-    generation = detect_generation()
-    return _HBM_GBPS.get(generation, default)
+    stats = jax.local_devices()[0].memory_stats()
+    if stats and stats.get("bytes_limit"):
+        return int(stats["bytes_limit"])
+    return None
 
 
 def decode_step_bytes(
@@ -288,11 +257,12 @@ def decode_step_bytes(
     else:
         row_bytes = c.head_dim * kv_dtype_bytes
     cache = c.layers * slots * window * c.kv_heads * row_bytes * 2
+    kind, peaks = device_peaks()
     return DecodeRoofline(
         weight_bytes=wbytes,
         cache_bytes_per_step=cache,
         total_bytes_per_step=wbytes + cache,
-        hbm_gbps=detect_hbm_gbps(),
-        generation=detect_generation(),
+        device_kind=kind,
+        hbm_gbps=peaks["hbm_gbps"] if peaks else None,
         hbm_bytes=detect_hbm_bytes(),
     )

@@ -176,7 +176,6 @@ def test_memory_ledger_slack_identity_and_sub_owner():
         sampler_bytes=20,
         tables_bytes=30,
         limit_bytes=2000,
-        limit_source="table:v5e",
     )
     owners = out["hbm_bytes_by_owner"]
     assert out["accounted_bytes"] == 1550
@@ -189,7 +188,7 @@ def test_memory_ledger_slack_identity_and_sub_owner():
     unknown = memory_ledger(
         weights_bytes=1, kv_pool_bytes=1, prefix_blocks=0,
         bytes_per_block=0, sampler_bytes=0, tables_bytes=0,
-        limit_bytes=None, limit_source="unknown",
+        limit_bytes=None,
     )
     assert unknown["slack_bytes"] is None
     assert "slack" not in unknown["hbm_bytes_by_owner"]
@@ -203,17 +202,19 @@ def test_memory_ledger_slack_identity_and_sub_owner():
 def test_live_engine_attribution_and_memory_invariant(run_async, monkeypatch):
     """≥ 3 distinct registered programs, each with expected bytes, a
     measured p50, and an achieved-vs-expected ratio; the memory ledger's
-    owner sum equals the (table-fallback) capacity within the reported
-    slack; flight samples carry the program key."""
+    owner sum equals the capacity within the reported slack; flight
+    samples carry the program key."""
     import langstream_tpu.serving.engine as engine_mod
     from langstream_tpu.serving.engine import ServingConfig, TpuServingEngine
 
-    # a synthetic capacity table hit: CPU exposes no allocator limit,
-    # and the invariant needs a known denominator (the engine resolves
-    # capacity once at construction)
+    # a synthetic device: the CPU reports no allocator limit and has no
+    # published peaks, and the invariant and the ratios need known
+    # denominators (the engine resolves both once at construction)
     limit = 1 << 30
+    monkeypatch.setattr(engine_mod, "detect_hbm_bytes", lambda: limit)
     monkeypatch.setattr(
-        engine_mod, "detect_hbm_capacity", lambda: (limit, "table:test")
+        engine_mod, "device_peaks",
+        lambda: ("test-device", {"hbm_gbps": 819.0}),
     )
 
     async def main():
@@ -243,7 +244,7 @@ def test_live_engine_attribution_and_memory_invariant(run_async, monkeypatch):
             # memory invariant: owner sum + slack == capacity, exactly
             memory = section["memory"]
             owners = memory["hbm_bytes_by_owner"]
-            assert memory["limit_source"] == "table:test"
+            assert section["device_kind"] == "test-device"
             assert sum(owners.values()) == limit
             assert owners["slack"] == memory["slack_bytes"]
             assert memory["slack_bytes"] >= 0  # tiny model fits easily
@@ -556,7 +557,6 @@ def _attrib_entry(ratios: list[float]) -> dict:
             "accounted_bytes": 12 * 2**30 + 3072,
             "kv_pool_prefix_bytes": 2**20,
             "limit_bytes": 16 * 2**30,
-            "limit_source": "table:v5e",
             "slack_bytes": 4 * 2**30 - 3072,
         },
     }
@@ -569,7 +569,7 @@ def _load_engine_top():
 def test_engine_top_renders_attribution_payload():
     engine_top = _load_engine_top()
     frame = engine_top.render([_attrib_entry([0.3, 0.31, 0.29])])
-    assert "hbm" in frame and "table:v5e" in frame
+    assert "hbm" in frame and "limit 16.0GB" in frame
     assert "decode:w512:k32:greedy" in frame
     assert "weights" in frame and "slack" in frame
 

@@ -82,6 +82,37 @@ def test_kubelet_runs_job_to_completion_and_patches_status(kube, tmp_path):
     assert "job ran" in log
 
 
+def test_kubelet_hands_the_chip_to_one_requesting_pod(kube, tmp_path):
+    """One process per chip: only a container that requests google.com/tpu
+    sees the chip, one pod at a time; everything else is pinned to the CPU
+    (and on a chipless node, everything)."""
+    from langstream_tpu.k8s.kubelet import ProcessKubelet, _Pod
+
+    wants = {"resources": {"limits": {"google.com/tpu": "1"}}}
+    laptop = ProcessKubelet(kube, root=tmp_path / "laptop")
+    pod = _Pod(name="a-0", namespace="ns1", kind="StatefulSet", owner="a", template_hash="h")
+    assert laptop._container_env(pod, wants)["JAX_PLATFORMS"] == "cpu"
+
+    node = ProcessKubelet(
+        kube, root=tmp_path / "node", env_extra={"JAX_PLATFORMS": "tpu,cpu"},
+        tpu_chips=1,
+    )
+    assert node._container_env(pod, {})["JAX_PLATFORMS"] == "cpu"
+    assert node._container_env(pod, wants)["JAX_PLATFORMS"] == "tpu,cpu"
+    # the first requester runs and holds the chip; a second is refused
+    pod.proc = subprocess.Popen([sys.executable, "-c", "import time; time.sleep(60)"])
+    node.pods[("ns1", "a-0")] = pod
+    try:
+        other = _Pod(name="b-0", namespace="ns1", kind="StatefulSet", owner="b", template_hash="h")
+        with pytest.raises(RuntimeError, match="one process at a time"):
+            node._container_env(other, wants)
+    finally:
+        pod.proc.kill()
+        pod.proc.wait(timeout=10)
+    # the holder exited: the chip is free again
+    assert node._container_env(other, wants)["JAX_PLATFORMS"] == "tpu,cpu"
+
+
 def test_kubelet_statefulset_pods_env_volumes_and_scale(kube, tmp_path):
     """STS pods get the downward-API pod name, secret volumes as files with
     mountPaths rewritten, readyReplicas status; scale-down kills pods."""

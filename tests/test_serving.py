@@ -1053,8 +1053,15 @@ def test_decode_roofline_model():
     assert 0.8e9 < r8.weight_bytes < 1.1e9
     # cache window: L16 * 64 slots * 256 rows * 8 kvh * 128 d * 2B * 2(K,V)
     assert r8.cache_bytes_per_step == 16 * 64 * 256 * 8 * 128 * 2 * 2
-    assert r8.min_step_ms() > 0
-    assert 0 < r8.utilization(achieved_step_ms=10 * r8.min_step_ms()) <= 0.11
+    # on the CPU there is no roof: the fields that need a device's
+    # published bandwidth are null, never another chip's
+    assert r8.device_kind == "cpu" and r8.hbm_gbps is None
+    assert r8.min_step_ms() is None and r8.utilization(10.0) is None
+    import dataclasses
+
+    v5e = dataclasses.replace(r8, device_kind="TPU v5 lite", hbm_gbps=819.0)
+    assert v5e.min_step_ms() > 0
+    assert 0 < v5e.utilization(achieved_step_ms=10 * v5e.min_step_ms()) <= 0.11
 
 
 def test_mesh_engine_serves_with_kernels_on(run_async, monkeypatch):

@@ -827,16 +827,15 @@ def _render_scheduler(scheduler: dict | None, events: list[dict]) -> list[str]:
 
 
 def _render_memory(memory: dict | None) -> list[str]:
-    """HBM memory-ledger panel: one bar per owner against the detected
-    (or table-fallback) limit, plus the prefix-cache sub-owner and the
+    """HBM memory-ledger panel: one bar per owner against the allocator's
+    limit, plus the prefix-cache sub-owner and the
     slack line. Absent on pre-attribution payloads."""
     if not memory:
         return []
     owners = memory.get("hbm_bytes_by_owner") or {}
     limit = memory.get("limit_bytes")
     lines = [
-        f"hbm      limit {_fmt_bytes(limit)} "
-        f"({memory.get('limit_source', '?')})  accounted "
+        f"hbm      limit {_fmt_bytes(limit)}  accounted "
         f"{_fmt_bytes(memory.get('accounted_bytes'))}"
     ]
     for owner, owned in sorted(
