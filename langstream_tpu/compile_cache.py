@@ -10,7 +10,13 @@ suite) calls :func:`configure_compile_cache` first. The rule:
   Fixed because the path is part of what makes a cache findable again — a
   directory named after a pid, a temporary name or the time never hits.
 
-The variable is exported, so child processes share the parent's cache.
+An entry's key covers the program's metadata too (``jax.named_scope`` names,
+source locations), which JAX leaves out by default: the scope names the
+serving programs carry (docs/OBSERVABILITY.md, "Device profiling") are
+metadata only, so without this a cache written before a scope was added or
+renamed would keep serving executables whose profile shows the old names.
+
+The variables are exported, so child processes share the parent's cache.
 Imports nothing heavy: callers run it before their first ``import jax``.
 When JAX is already imported (a test process, an embedding program) the
 same directory is handed to ``jax.config`` instead — the cache is only
@@ -23,6 +29,7 @@ import os
 import sys
 
 ENV_VAR = "JAX_COMPILATION_CACHE_DIR"
+KEY_ENV_VAR = "JAX_COMPILATION_CACHE_INCLUDE_METADATA_IN_KEY"
 DEFAULT_DIR = os.path.join(
     os.path.dirname(os.path.dirname(os.path.abspath(__file__))), ".jax_cache"
 )
@@ -30,6 +37,11 @@ DEFAULT_DIR = os.path.join(
 
 def configure_compile_cache() -> str:
     """Resolve the cache directory (see module docstring); returns it."""
+    os.environ[KEY_ENV_VAR] = "1"
+    if "jax" in sys.modules:
+        sys.modules["jax"].config.update(
+            "jax_compilation_cache_include_metadata_in_key", True
+        )
     placed = os.environ.get(ENV_VAR)
     if placed:
         return placed

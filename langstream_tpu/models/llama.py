@@ -313,9 +313,11 @@ def prefill_forward(
     if ffn is None:
         ffn = _default_ffn
     B, Pn = tokens.shape
-    x = embedding_take(params["embed"], tokens)  # (B, P, H)
-    positions = jnp.arange(Pn)[None, :].repeat(B, axis=0)
-    cos, sin = _rope(positions, c.head_dim, c.rope_theta)
+    with jax.named_scope("embed"):
+        x = embedding_take(params["embed"], tokens)  # (B, P, H)
+    with jax.named_scope("attn_qkv"):
+        positions = jnp.arange(Pn)[None, :].repeat(B, axis=0)
+        cos, sin = _rope(positions, c.head_dim, c.rope_theta)
     # causal + padding mask: (B, 1, P, P)
     q_idx = jnp.arange(Pn)[:, None]
     k_idx = jnp.arange(Pn)[None, :]
@@ -368,59 +370,65 @@ def prefill_forward(
             lp = layer_in
         else:
             lp, al = layer_in
-        h = _rms_norm(x, lp["attn_norm"], c.norm_eps)
-        q = jnp.einsum("bph,hd->bpd", h, _w(lp["wq"]))
-        k = jnp.einsum("bph,hd->bpd", h, _w(lp["wk"]))
-        v = jnp.einsum("bph,hd->bpd", h, _w(lp["wv"]))
-        if adapters is not None:
-            ids = adapters["ids"]
-            q = q + lora_delta(h, ids, al["wq_a"], al["wq_b"])
-            k = k + lora_delta(h, ids, al["wk_a"], al["wk_b"])
-            v = v + lora_delta(h, ids, al["wv_a"], al["wv_b"])
-        q = q.reshape(B, Pn, c.heads, c.head_dim)
-        k = k.reshape(B, Pn, c.kv_heads, c.head_dim)
-        v = v.reshape(B, Pn, c.kv_heads, c.head_dim)
-        q = _apply_rope(q, cos, sin)
-        k = _apply_rope(k, cos, sin)
+        with jax.named_scope("attn_qkv"):
+            h = _rms_norm(x, lp["attn_norm"], c.norm_eps)
+            q = jnp.einsum("bph,hd->bpd", h, _w(lp["wq"]))
+            k = jnp.einsum("bph,hd->bpd", h, _w(lp["wk"]))
+            v = jnp.einsum("bph,hd->bpd", h, _w(lp["wv"]))
+            if adapters is not None:
+                ids = adapters["ids"]
+                q = q + lora_delta(h, ids, al["wq_a"], al["wq_b"])
+                k = k + lora_delta(h, ids, al["wk_a"], al["wk_b"])
+                v = v + lora_delta(h, ids, al["wv_a"], al["wv_b"])
+            q = q.reshape(B, Pn, c.heads, c.head_dim)
+            k = k.reshape(B, Pn, c.kv_heads, c.head_dim)
+            v = v.reshape(B, Pn, c.kv_heads, c.head_dim)
+            q = _apply_rope(q, cos, sin)
+            k = _apply_rope(k, cos, sin)
         if sp_ring:
-            # causality alone hides right-padded keys from every real query
-            # row (padded rows sit after all real rows); their outputs are
-            # garbage the caller discards, their cache rows are overwritten
-            # before ever being attended to (same argument as flash below)
-            from langstream_tpu.parallel.ring import ring_attention
+            with jax.named_scope("kv_read"):
+                # causality alone hides right-padded keys from every real query
+                # row (padded rows sit after all real rows); their outputs are
+                # garbage the caller discards, their cache rows are overwritten
+                # before ever being attended to (same argument as flash below)
+                from langstream_tpu.parallel.ring import ring_attention
 
-            out = ring_attention(
-                q, k, v, mesh, causal=True,
-                batch_axis=sp_dp, head_axis=sp_tp,
-            )
-            out = out.reshape(B, Pn, c.heads * c.head_dim)
+                out = ring_attention(
+                    q, k, v, mesh, causal=True,
+                    batch_axis=sp_dp, head_axis=sp_tp,
+                )
+                out = out.reshape(B, Pn, c.heads * c.head_dim)
         elif flash is not None:
-            # Pallas blocked attention: no (B,H,P,P) score matrix in HBM.
-            # Causality alone hides right-padded keys from every real query
-            # row; padded rows' outputs are garbage the caller discards.
-            from langstream_tpu.ops.flash_attention import flash_attention
+            with jax.named_scope("flash"):
+                # Pallas blocked attention: no (B,H,P,P) score matrix in HBM.
+                # Causality alone hides right-padded keys from every real query
+                # row; padded rows' outputs are garbage the caller discards.
+                from langstream_tpu.ops.flash_attention import flash_attention
 
-            out = flash_attention(
-                q, k, v, causal=True, interpret=(flash == "interpret"),
-                mesh=mesh,
-            )
-            out = out.reshape(B, Pn, c.heads * c.head_dim)
+                out = flash_attention(
+                    q, k, v, causal=True, interpret=(flash == "interpret"),
+                    mesh=mesh,
+                )
+                out = out.reshape(B, Pn, c.heads * c.head_dim)
         else:
-            # grouped-query attention: heads = kv_heads * group
-            G = c.heads // c.kv_heads
-            qg = q.reshape(B, Pn, c.kv_heads, G, c.head_dim)
-            scores = jnp.einsum("bqkgd,bskd->bkgqs", qg, k).astype(jnp.float32)
-            scores = scores / math.sqrt(c.head_dim)
-            scores = jnp.where(mask[:, None, None, :, :], scores, neg)
-            probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
-            out = jnp.einsum("bkgqs,bskd->bqkgd", probs, v)
-            out = out.reshape(B, Pn, c.heads * c.head_dim)
-        attn = jnp.einsum("bpd,dh->bph", out, _w(lp["wo"]))
-        if adapters is not None:
-            attn = attn + lora_delta(out, adapters["ids"], al["wo_a"], al["wo_b"])
-        x = x + attn
-        h2 = _rms_norm(x, lp["mlp_norm"], c.norm_eps)
-        x = x + ffn(h2, lp, pos_valid)
+            with jax.named_scope("kv_read"):
+                # grouped-query attention: heads = kv_heads * group
+                G = c.heads // c.kv_heads
+                qg = q.reshape(B, Pn, c.kv_heads, G, c.head_dim)
+                scores = jnp.einsum("bqkgd,bskd->bkgqs", qg, k).astype(jnp.float32)
+                scores = scores / math.sqrt(c.head_dim)
+                scores = jnp.where(mask[:, None, None, :, :], scores, neg)
+                probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
+                out = jnp.einsum("bkgqs,bskd->bqkgd", probs, v)
+                out = out.reshape(B, Pn, c.heads * c.head_dim)
+        with jax.named_scope("attn_out"):
+            attn = jnp.einsum("bpd,dh->bph", out, _w(lp["wo"]))
+            if adapters is not None:
+                attn = attn + lora_delta(out, adapters["ids"], al["wo_a"], al["wo_b"])
+            x = x + attn
+        with jax.named_scope("ffn"):
+            h2 = _rms_norm(x, lp["mlp_norm"], c.norm_eps)
+            x = x + ffn(h2, lp, pos_valid)
         if sp_ring:
             x = jax.lax.with_sharding_constraint(x, x_spec)
         return x, (k, v)
@@ -431,12 +439,13 @@ def prefill_forward(
         else (params["layers"], adapters["layers"])
     )
     x, (ks, vs) = jax.lax.scan(layer, x, layer_xs)
-    x = _rms_norm(x, params["final_norm"], c.norm_eps)
-    # logits for the last real token of each prompt
-    last = jnp.take_along_axis(
-        x, (lengths - 1)[:, None, None].clip(0), axis=1
-    ).squeeze(1)
-    logits = jnp.einsum("bh,hv->bv", last, _w(params["lm_head"])).astype(jnp.float32)
+    with jax.named_scope("lm_head"):
+        x = _rms_norm(x, params["final_norm"], c.norm_eps)
+        # logits for the last real token of each prompt
+        last = jnp.take_along_axis(
+            x, (lengths - 1)[:, None, None].clip(0), axis=1
+        ).squeeze(1)
+        logits = jnp.einsum("bh,hv->bv", last, _w(params["lm_head"])).astype(jnp.float32)
     return logits, ks, vs
 
 
@@ -499,8 +508,10 @@ def llama_decode_step(
         active = jnp.ones(tokens.shape[0], dtype=bool)
     B = tokens.shape[0]
     S = cache_seq_len(cache_k)
-    x = embedding_take(params["embed"], tokens)  # (B, H)
-    cos, sin = _rope(lengths, c.head_dim, c.rope_theta)  # (B, half)
+    with jax.named_scope("embed"):
+        x = embedding_take(params["embed"], tokens)  # (B, H)
+    with jax.named_scope("attn_qkv"):
+        cos, sin = _rope(lengths, c.head_dim, c.rope_theta)  # (B, half)
     k_idx = jnp.arange(S)[None, :]
     key_mask = k_idx <= lengths[:, None]  # (B, S)
     neg = jnp.finfo(jnp.float32).min
@@ -510,30 +521,35 @@ def llama_decode_step(
     def layer(carry, layer_in):
         x = carry
         lp, ck_l, cv_l = layer_in
-        h = _rms_norm(x, lp["attn_norm"], c.norm_eps)
-        q = (h @ _w(lp["wq"])).reshape(B, c.heads, c.head_dim)
-        k = (h @ _w(lp["wk"])).reshape(B, c.kv_heads, c.head_dim)
-        v = (h @ _w(lp["wv"])).reshape(B, c.kv_heads, c.head_dim)
-        q = _apply_rope(q, cos, sin)
-        k = _apply_rope(k, cos, sin)
-        ck_l = cache_write_rows(ck_l, k, (batch_idx, lengths))
-        cv_l = cache_write_rows(cv_l, v, (batch_idx, lengths))
-        qg = q.reshape(B, c.kv_heads, G, c.head_dim)
-        scores = cache_scores(qg, ck_l) / math.sqrt(c.head_dim)
-        scores = jnp.where(key_mask[:, None, None, :], scores, neg)
-        probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
-        out = cache_values(probs, cv_l)
-        out = out.reshape(B, c.heads * c.head_dim)
-        x = x + out @ _w(lp["wo"])
-        h2 = _rms_norm(x, lp["mlp_norm"], c.norm_eps)
-        x = x + ffn(h2, lp, active)
+        with jax.named_scope("attn_qkv"):
+            h = _rms_norm(x, lp["attn_norm"], c.norm_eps)
+            q = (h @ _w(lp["wq"])).reshape(B, c.heads, c.head_dim)
+            k = (h @ _w(lp["wk"])).reshape(B, c.kv_heads, c.head_dim)
+            v = (h @ _w(lp["wv"])).reshape(B, c.kv_heads, c.head_dim)
+            q = _apply_rope(q, cos, sin)
+            k = _apply_rope(k, cos, sin)
+            ck_l = cache_write_rows(ck_l, k, (batch_idx, lengths))
+            cv_l = cache_write_rows(cv_l, v, (batch_idx, lengths))
+        with jax.named_scope("kv_read"):
+            qg = q.reshape(B, c.kv_heads, G, c.head_dim)
+            scores = cache_scores(qg, ck_l) / math.sqrt(c.head_dim)
+            scores = jnp.where(key_mask[:, None, None, :], scores, neg)
+            probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
+            out = cache_values(probs, cv_l)
+            out = out.reshape(B, c.heads * c.head_dim)
+        with jax.named_scope("attn_out"):
+            x = x + out @ _w(lp["wo"])
+        with jax.named_scope("ffn"):
+            h2 = _rms_norm(x, lp["mlp_norm"], c.norm_eps)
+            x = x + ffn(h2, lp, active)
         return x, (ck_l, cv_l)
 
     x, (new_k, new_v) = jax.lax.scan(
         layer, x, (params["layers"], cache_k, cache_v)
     )
-    x = _rms_norm(x, params["final_norm"], c.norm_eps)
-    logits = (x @ _w(params["lm_head"])).astype(jnp.float32)
+    with jax.named_scope("lm_head"):
+        x = _rms_norm(x, params["final_norm"], c.norm_eps)
+        logits = (x @ _w(params["lm_head"])).astype(jnp.float32)
     return logits, new_k, new_v
 
 
@@ -599,56 +615,65 @@ def llama_decode_chunk(
         else:
             tokens, kbuf, vbuf, key = carry
             counts = None
-        key, sub = jax.random.split(key)
-        x = embedding_take(params["embed"], tokens)  # (B, H)
-        positions = base_lengths + step_idx * adv
-        cos, sin = _rope(positions, c.head_dim, c.rope_theta)
-        buf_mask = (jnp.arange(num_steps)[None, :] <= step_idx)  # (1, K)
+        with jax.named_scope("sample"):
+            key, sub = jax.random.split(key)
+        with jax.named_scope("embed"):
+            x = embedding_take(params["embed"], tokens)  # (B, H)
+        with jax.named_scope("attn_qkv"):
+            positions = base_lengths + step_idx * adv
+            cos, sin = _rope(positions, c.head_dim, c.rope_theta)
+            buf_mask = (jnp.arange(num_steps)[None, :] <= step_idx)  # (1, K)
 
         def layer(x, layer_in):
             lp, ck_l, cv_l, kbuf_l, vbuf_l = layer_in
-            h = _rms_norm(x, lp["attn_norm"], c.norm_eps)
-            q = (h @ _w(lp["wq"])).reshape(B, c.heads, c.head_dim)
-            k = (h @ _w(lp["wk"])).reshape(B, c.kv_heads, c.head_dim)
-            v = (h @ _w(lp["wv"])).reshape(B, c.kv_heads, c.head_dim)
-            q = _apply_rope(q, cos, sin)
-            k = _apply_rope(k, cos, sin)
-            kbuf_l = jax.lax.dynamic_update_slice_in_dim(
-                kbuf_l, k[:, None], step_idx, axis=1
-            )
-            vbuf_l = jax.lax.dynamic_update_slice_in_dim(
-                vbuf_l, v[:, None], step_idx, axis=1
-            )
-            qg = q.reshape(B, c.kv_heads, G, c.head_dim)
-            s_cache = cache_scores(qg, ck_l)
-            s_buf = jnp.einsum("bkgd,btkd->bkgt", qg, kbuf_l).astype(jnp.float32)
-            scale = 1.0 / math.sqrt(c.head_dim)
-            s_cache = jnp.where(
-                cache_mask[:, None, None, :], s_cache * scale, neg
-            )
-            s_buf = jnp.where(buf_mask[:, None, None, :], s_buf * scale, neg)
-            s_all = jnp.concatenate([s_cache, s_buf], axis=-1)
-            probs = jax.nn.softmax(s_all, axis=-1).astype(x.dtype)
-            p_cache, p_buf = probs[..., :S], probs[..., S:]
-            out = cache_values(p_cache, cv_l) + jnp.einsum(
-                "bkgt,btkd->bkgd", p_buf, vbuf_l
-            )
-            out = out.reshape(B, c.heads * c.head_dim)
-            x = x + out @ _w(lp["wo"])
-            h2 = _rms_norm(x, lp["mlp_norm"], c.norm_eps)
-            x = x + ffn(h2, lp, active)
+            with jax.named_scope("attn_qkv"):
+                h = _rms_norm(x, lp["attn_norm"], c.norm_eps)
+                q = (h @ _w(lp["wq"])).reshape(B, c.heads, c.head_dim)
+                k = (h @ _w(lp["wk"])).reshape(B, c.kv_heads, c.head_dim)
+                v = (h @ _w(lp["wv"])).reshape(B, c.kv_heads, c.head_dim)
+                q = _apply_rope(q, cos, sin)
+                k = _apply_rope(k, cos, sin)
+                kbuf_l = jax.lax.dynamic_update_slice_in_dim(
+                    kbuf_l, k[:, None], step_idx, axis=1
+                )
+                vbuf_l = jax.lax.dynamic_update_slice_in_dim(
+                    vbuf_l, v[:, None], step_idx, axis=1
+                )
+            with jax.named_scope("kv_read"):
+                qg = q.reshape(B, c.kv_heads, G, c.head_dim)
+                s_cache = cache_scores(qg, ck_l)
+                s_buf = jnp.einsum("bkgd,btkd->bkgt", qg, kbuf_l).astype(jnp.float32)
+                scale = 1.0 / math.sqrt(c.head_dim)
+                s_cache = jnp.where(
+                    cache_mask[:, None, None, :], s_cache * scale, neg
+                )
+                s_buf = jnp.where(buf_mask[:, None, None, :], s_buf * scale, neg)
+                s_all = jnp.concatenate([s_cache, s_buf], axis=-1)
+                probs = jax.nn.softmax(s_all, axis=-1).astype(x.dtype)
+                p_cache, p_buf = probs[..., :S], probs[..., S:]
+                out = cache_values(p_cache, cv_l) + jnp.einsum(
+                    "bkgt,btkd->bkgd", p_buf, vbuf_l
+                )
+                out = out.reshape(B, c.heads * c.head_dim)
+            with jax.named_scope("attn_out"):
+                x = x + out @ _w(lp["wo"])
+            with jax.named_scope("ffn"):
+                h2 = _rms_norm(x, lp["mlp_norm"], c.norm_eps)
+                x = x + ffn(h2, lp, active)
             return x, (kbuf_l, vbuf_l)
 
         x, (kbuf, vbuf) = jax.lax.scan(
             layer, x, (params["layers"], cache_k, cache_v, kbuf, vbuf)
         )
-        x = _rms_norm(x, params["final_norm"], c.norm_eps)
-        logits = (x @ _w(params["lm_head"])).astype(jnp.float32)
-        if pen:
-            nxt, lp = sample_fn(logits, sub, counts)
-        else:
-            nxt, lp = sample_fn(logits, sub)
-        nxt = jnp.where(active, nxt, tokens)
+        with jax.named_scope("lm_head"):
+            x = _rms_norm(x, params["final_norm"], c.norm_eps)
+            logits = (x @ _w(params["lm_head"])).astype(jnp.float32)
+        with jax.named_scope("sample"):
+            if pen:
+                nxt, lp = sample_fn(logits, sub, counts)
+            else:
+                nxt, lp = sample_fn(logits, sub)
+            nxt = jnp.where(active, nxt, tokens)
         if pen:
             counts = counts.at[jnp.arange(B), nxt].add(adv)
             return (nxt, kbuf, vbuf, key, counts), (nxt, lp)

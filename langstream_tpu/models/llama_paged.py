@@ -128,9 +128,11 @@ def llama_prefill_continue_paged(
         )
     KhD = c.kv_heads * c.head_dim
     G = c.heads // c.kv_heads
-    x = embedding_take(params["embed"], tokens)  # (B, P2, H)
-    positions = start_lengths[:, None] + jnp.arange(P2)[None, :]
-    cos, sin = _rope(positions, c.head_dim, c.rope_theta)
+    with jax.named_scope("embed"):
+        x = embedding_take(params["embed"], tokens)  # (B, P2, H)
+    with jax.named_scope("attn_qkv"):
+        positions = start_lengths[:, None] + jnp.arange(P2)[None, :]
+        cos, sin = _rope(positions, c.head_dim, c.rope_theta)
     pos_valid = jnp.arange(P2)[None, :] < suffix_lengths[:, None]  # (B, P2)
     scale = 1.0 / math.sqrt(c.head_dim)
     # suffix key-block size: online-softmax over key blocks bounds score
@@ -148,182 +150,186 @@ def llama_prefill_continue_paged(
             lp, ck_l, cv_l = layer_in
         else:
             lp, al, ck_l, cv_l = layer_in
-        h = _rms_norm(x, lp["attn_norm"], c.norm_eps)
-        q = jnp.einsum("bph,hd->bpd", h, _w(lp["wq"]))
-        k = jnp.einsum("bph,hd->bpd", h, _w(lp["wk"]))
-        v = jnp.einsum("bph,hd->bpd", h, _w(lp["wv"]))
-        if adapters is not None:
-            ids = adapters["ids"]
-            q = q + lora_delta(h, ids, al["wq_a"], al["wq_b"])
-            k = k + lora_delta(h, ids, al["wk_a"], al["wk_b"])
-            v = v + lora_delta(h, ids, al["wv_a"], al["wv_b"])
-        q = q.reshape(B, P2, c.heads, c.head_dim)
-        k = k.reshape(B, P2, c.kv_heads, c.head_dim)
-        v = v.reshape(B, P2, c.kv_heads, c.head_dim)
-        q = _apply_rope(q, cos, sin)
-        k = _apply_rope(k, cos, sin)
-        qg = q.reshape(B, P2, c.kv_heads, G, c.head_dim)
+        with jax.named_scope("attn_qkv"):
+            h = _rms_norm(x, lp["attn_norm"], c.norm_eps)
+            q = jnp.einsum("bph,hd->bpd", h, _w(lp["wq"]))
+            k = jnp.einsum("bph,hd->bpd", h, _w(lp["wk"]))
+            v = jnp.einsum("bph,hd->bpd", h, _w(lp["wv"]))
+            if adapters is not None:
+                ids = adapters["ids"]
+                q = q + lora_delta(h, ids, al["wq_a"], al["wq_b"])
+                k = k + lora_delta(h, ids, al["wk_a"], al["wk_b"])
+                v = v + lora_delta(h, ids, al["wv_a"], al["wv_b"])
+            q = q.reshape(B, P2, c.heads, c.head_dim)
+            k = k.reshape(B, P2, c.kv_heads, c.head_dim)
+            v = v.reshape(B, P2, c.kv_heads, c.head_dim)
+            q = _apply_rope(q, cos, sin)
+            k = _apply_rope(k, cos, sin)
+        with jax.named_scope("kv_read"):
+            qg = q.reshape(B, P2, c.kv_heads, G, c.head_dim)
 
-        m0 = jnp.full((B, c.kv_heads, G, P2), NEG_INF, jnp.float32)
-        l0 = jnp.zeros((B, c.kv_heads, G, P2), jnp.float32)
-        o0 = jnp.zeros((B, c.kv_heads, G, P2, c.head_dim), jnp.float32)
+            m0 = jnp.full((B, c.kv_heads, G, P2), NEG_INF, jnp.float32)
+            l0 = jnp.zeros((B, c.kv_heads, G, P2), jnp.float32)
+            o0 = jnp.zeros((B, c.kv_heads, G, P2, c.head_dim), jnp.float32)
 
-        # the kvquant helpers work on (B, Kh, G', T/D) — fold the query
-        # axis into G (one source of truth for the int8 scale-folding
-        # identities; the reshapes touch only score-sized tensors)
-        qg_flat = qg.transpose(0, 2, 3, 1, 4).reshape(
-            B, c.kv_heads, G * P2, c.head_dim
-        )
-
-        def online_update(carry, k_blk, v_blk, mask_blk):
-            # one flash-attention style block update: k/v (B, T, Kh, D) —
-            # bf16 arrays, or int8 {"q","s"} pairs read through the fused
-            # kvquant helpers — mask (B, 1, 1, P2?, T) broadcastable over
-            # (B,Kh,G,P2,T)
-            from langstream_tpu.models.kvquant import cache_scores, cache_values
-
-            o, l, m = carry
-            T = (k_blk["s"] if isinstance(k_blk, dict) else k_blk).shape[1]
-            s = cache_scores(qg_flat, k_blk).reshape(
-                B, c.kv_heads, G, P2, T
-            ) * scale
-            s = jnp.where(mask_blk, s, NEG_INF)
-            m_new = jnp.maximum(m, s.max(axis=-1))
-            shift = jnp.where(m_new <= NEG_INF, 0.0, m_new)
-            p = jnp.where(mask_blk, jnp.exp(s - shift[..., None]), 0.0)
-            alpha = jnp.exp(jnp.where(m <= NEG_INF, NEG_INF, m - shift))
-            l = l * alpha + p.sum(axis=-1)
-            update = cache_values(
-                p.astype(qg.dtype).reshape(B, c.kv_heads, G * P2, T), v_blk
-            ).reshape(B, c.kv_heads, G, P2, c.head_dim)
-            o = o * alpha[..., None] + update.astype(jnp.float32)
-            return o, l, m_new
-
-        if kernel != "xla":
-            # multi-query scalar-prefetch kernel: no densified gather, the
-            # block table drives the DMA (ops/paged_attention.py)
-            from langstream_tpu.ops.paged_attention import (
-                paged_attention_multiquery_partial,
+            # the kvquant helpers work on (B, Kh, G', T/D) — fold the query
+            # axis into G (one source of truth for the int8 scale-folding
+            # identities; the reshapes touch only score-sized tensors)
+            qg_flat = qg.transpose(0, 2, 3, 1, 4).reshape(
+                B, c.kv_heads, G * P2, c.head_dim
             )
 
-            # keep (t_block·G)-row MXU tiles even for narrow suffixes
-            # (speculative verify runs D1 = 1+drafts wide): history
-            # attention is mask-uniform across queries, so padded rows
-            # compute harmless extra attention that is sliced away
-            tb = min(16, -(-P2 // 8) * 8)
-            P2p = -(-P2 // tb) * tb
-            qk = (
-                jnp.pad(q, ((0, 0), (0, P2p - P2), (0, 0), (0, 0)))
-                if P2p != P2
-                else q
-            )
+            def online_update(carry, k_blk, v_blk, mask_blk):
+                # one flash-attention style block update: k/v (B, T, Kh, D) —
+                # bf16 arrays, or int8 {"q","s"} pairs read through the fused
+                # kvquant helpers — mask (B, 1, 1, P2?, T) broadcastable over
+                # (B,Kh,G,P2,T)
+                from langstream_tpu.models.kvquant import cache_scores, cache_values
 
-            def mq_partial(q_, ck_, cv_, tables_, starts_, kv_heads):
-                return paged_attention_multiquery_partial(
-                    q_, ck_, cv_, tables_, starts_,
-                    num_read_blocks=num_read_blocks,
-                    kv_heads=kv_heads, head_dim=c.head_dim, t_block=tb,
-                    scale=scale, interpret=(kernel == "pallas-interpret"),
-                )
+                o, l, m = carry
+                T = (k_blk["s"] if isinstance(k_blk, dict) else k_blk).shape[1]
+                s = cache_scores(qg_flat, k_blk).reshape(
+                    B, c.kv_heads, G, P2, T
+                ) * scale
+                s = jnp.where(mask_blk, s, NEG_INF)
+                m_new = jnp.maximum(m, s.max(axis=-1))
+                shift = jnp.where(m_new <= NEG_INF, 0.0, m_new)
+                p = jnp.where(mask_blk, jnp.exp(s - shift[..., None]), 0.0)
+                alpha = jnp.exp(jnp.where(m <= NEG_INF, NEG_INF, m - shift))
+                l = l * alpha + p.sum(axis=-1)
+                update = cache_values(
+                    p.astype(qg.dtype).reshape(B, c.kv_heads, G * P2, T), v_blk
+                ).reshape(B, c.kv_heads, G, P2, c.head_dim)
+                o = o * alpha[..., None] + update.astype(jnp.float32)
+                return o, l, m_new
 
-            if mesh is not None and len(mesh.devices.flatten()) > 1:
-                # pallas_call has no SPMD rule: shared mesh wrapper — slots
-                # on dp, heads on tp, per-axis degradation
+            if kernel != "xla":
+                # multi-query scalar-prefetch kernel: no densified gather, the
+                # block table drives the DMA (ops/paged_attention.py)
                 from langstream_tpu.ops.paged_attention import (
-                    shard_mapped_paged_read,
+                    paged_attention_multiquery_partial,
                 )
 
-                acc_h, m_h, l_h = shard_mapped_paged_read(
-                    mq_partial, mesh,
-                    kv_heads=c.kv_heads, batch=B,
-                    q_spec_tail=(None, "tp", None),       # (B, P2p, H, D)
-                    out_spec_tails=(
-                        (None, "tp", None),               # acc (B,T,H,D)
-                        (None, "tp"),                     # m (B,T,H)
-                        (None, "tp"),                     # l (B,T,H)
-                    ),
-                )(qk, ck_l, cv_l, block_tables, start_lengths)
-            else:
-                acc_h, m_h, l_h = mq_partial(
-                    qk, ck_l, cv_l, block_tables, start_lengths,
-                    kv_heads=c.kv_heads,
+                # keep (t_block·G)-row MXU tiles even for narrow suffixes
+                # (speculative verify runs D1 = 1+drafts wide): history
+                # attention is mask-uniform across queries, so padded rows
+                # compute harmless extra attention that is sliced away
+                tb = min(16, -(-P2 // 8) * 8)
+                P2p = -(-P2 // tb) * tb
+                qk = (
+                    jnp.pad(q, ((0, 0), (0, P2p - P2), (0, 0), (0, 0)))
+                    if P2p != P2
+                    else q
                 )
-            acc_h = acc_h[:, :P2]
-            m_h, l_h = m_h[:, :P2], l_h[:, :P2]
-            # (B, P2, H[, D]) → the (B, Kh, G, P2[, D]) carry layout
-            carry = (
-                acc_h.reshape(B, P2, c.kv_heads, G, c.head_dim).transpose(
-                    0, 2, 3, 1, 4
-                ),
-                l_h.reshape(B, P2, c.kv_heads, G).transpose(0, 2, 3, 1),
-                m_h.reshape(B, P2, c.kv_heads, G).transpose(0, 2, 3, 1),
-            )
-        else:
-            # segment 1: pool history, ~128 rows of table columns per step
-            # (one tiny per-pool-block step would serialize the sweep
-            # ~128/bs-fold deeper for the same score memory)
-            cps = max(1, 128 // bs)                         # columns/step
-            n_hist_steps = -(-num_read_blocks // cps)
 
-            def hist_step(carry, t):
-                col_idx = t * cps + jnp.arange(cps)         # (cps,)
-                safe = jnp.minimum(col_idx, num_read_blocks - 1)
-                cols = jnp.take(block_tables, safe, axis=1)  # (B, cps)
-
-                def take_blk(pool_l):
-                    if isinstance(pool_l, dict):
-                        return {
-                            "q": jnp.take(pool_l["q"], cols, axis=0).reshape(
-                                B, cps * bs, c.kv_heads, c.head_dim
-                            ),
-                            "s": jnp.take(pool_l["s"], cols, axis=0).reshape(
-                                B, cps * bs, c.kv_heads
-                            ),
-                        }
-                    return jnp.take(pool_l, cols, axis=0).reshape(
-                        B, cps * bs, c.kv_heads, c.head_dim
+                def mq_partial(q_, ck_, cv_, tables_, starts_, kv_heads):
+                    return paged_attention_multiquery_partial(
+                        q_, ck_, cv_, tables_, starts_,
+                        num_read_blocks=num_read_blocks,
+                        kv_heads=kv_heads, head_dim=c.head_dim, t_block=tb,
+                        scale=scale, interpret=(kernel == "pallas-interpret"),
                     )
 
-                k_blk = take_blk(ck_l)
-                v_blk = take_blk(cv_l)
-                # positions from the UNclamped indices: a clamped
-                # (duplicate) tail column computes positions ≥
-                # num_read_blocks·bs, which the < start mask never admits
-                w_pos = (
-                    col_idx[:, None] * bs + jnp.arange(bs)[None, :]
-                ).reshape(-1)
-                mask = (w_pos[None, :] < start_lengths[:, None])[
-                    :, None, None, None, :
-                ]
+                if mesh is not None and len(mesh.devices.flatten()) > 1:
+                    # pallas_call has no SPMD rule: shared mesh wrapper — slots
+                    # on dp, heads on tp, per-axis degradation
+                    from langstream_tpu.ops.paged_attention import (
+                        shard_mapped_paged_read,
+                    )
+
+                    acc_h, m_h, l_h = shard_mapped_paged_read(
+                        mq_partial, mesh,
+                        kv_heads=c.kv_heads, batch=B,
+                        q_spec_tail=(None, "tp", None),       # (B, P2p, H, D)
+                        out_spec_tails=(
+                            (None, "tp", None),               # acc (B,T,H,D)
+                            (None, "tp"),                     # m (B,T,H)
+                            (None, "tp"),                     # l (B,T,H)
+                        ),
+                    )(qk, ck_l, cv_l, block_tables, start_lengths)
+                else:
+                    acc_h, m_h, l_h = mq_partial(
+                        qk, ck_l, cv_l, block_tables, start_lengths,
+                        kv_heads=c.kv_heads,
+                    )
+                acc_h = acc_h[:, :P2]
+                m_h, l_h = m_h[:, :P2], l_h[:, :P2]
+                # (B, P2, H[, D]) → the (B, Kh, G, P2[, D]) carry layout
+                carry = (
+                    acc_h.reshape(B, P2, c.kv_heads, G, c.head_dim).transpose(
+                        0, 2, 3, 1, 4
+                    ),
+                    l_h.reshape(B, P2, c.kv_heads, G).transpose(0, 2, 3, 1),
+                    m_h.reshape(B, P2, c.kv_heads, G).transpose(0, 2, 3, 1),
+                )
+            else:
+                # segment 1: pool history, ~128 rows of table columns per step
+                # (one tiny per-pool-block step would serialize the sweep
+                # ~128/bs-fold deeper for the same score memory)
+                cps = max(1, 128 // bs)                         # columns/step
+                n_hist_steps = -(-num_read_blocks // cps)
+
+                def hist_step(carry, t):
+                    col_idx = t * cps + jnp.arange(cps)         # (cps,)
+                    safe = jnp.minimum(col_idx, num_read_blocks - 1)
+                    cols = jnp.take(block_tables, safe, axis=1)  # (B, cps)
+
+                    def take_blk(pool_l):
+                        if isinstance(pool_l, dict):
+                            return {
+                                "q": jnp.take(pool_l["q"], cols, axis=0).reshape(
+                                    B, cps * bs, c.kv_heads, c.head_dim
+                                ),
+                                "s": jnp.take(pool_l["s"], cols, axis=0).reshape(
+                                    B, cps * bs, c.kv_heads
+                                ),
+                            }
+                        return jnp.take(pool_l, cols, axis=0).reshape(
+                            B, cps * bs, c.kv_heads, c.head_dim
+                        )
+
+                    k_blk = take_blk(ck_l)
+                    v_blk = take_blk(cv_l)
+                    # positions from the UNclamped indices: a clamped
+                    # (duplicate) tail column computes positions ≥
+                    # num_read_blocks·bs, which the < start mask never admits
+                    w_pos = (
+                        col_idx[:, None] * bs + jnp.arange(bs)[None, :]
+                    ).reshape(-1)
+                    mask = (w_pos[None, :] < start_lengths[:, None])[
+                        :, None, None, None, :
+                    ]
+                    return online_update(carry, k_blk, v_blk, mask), None
+
+                carry, _ = jax.lax.scan(
+                    hist_step, (o0, l0, m0), jnp.arange(n_hist_steps)
+                )
+
+            # segment 2: causal self-attention among the suffix, key-blocked
+            def suf_step(carry, t):
+                k_blk = jax.lax.dynamic_slice_in_dim(k, t * sbs, sbs, axis=1)
+                v_blk = jax.lax.dynamic_slice_in_dim(v, t * sbs, sbs, axis=1)
+                k_pos = t * sbs + jnp.arange(sbs)
+                mask = (
+                    (jnp.arange(P2)[:, None] >= k_pos[None, :])[None]
+                    & (k_pos[None, None, :] < suffix_lengths[:, None, None])
+                )[:, None, None, :, :]
                 return online_update(carry, k_blk, v_blk, mask), None
 
-            carry, _ = jax.lax.scan(
-                hist_step, (o0, l0, m0), jnp.arange(n_hist_steps)
+            (o, l, m), _ = jax.lax.scan(
+                suf_step, carry, jnp.arange(n_suffix_blocks)
             )
-
-        # segment 2: causal self-attention among the suffix, key-blocked
-        def suf_step(carry, t):
-            k_blk = jax.lax.dynamic_slice_in_dim(k, t * sbs, sbs, axis=1)
-            v_blk = jax.lax.dynamic_slice_in_dim(v, t * sbs, sbs, axis=1)
-            k_pos = t * sbs + jnp.arange(sbs)
-            mask = (
-                (jnp.arange(P2)[:, None] >= k_pos[None, :])[None]
-                & (k_pos[None, None, :] < suffix_lengths[:, None, None])
-            )[:, None, None, :, :]
-            return online_update(carry, k_blk, v_blk, mask), None
-
-        (o, l, m), _ = jax.lax.scan(
-            suf_step, carry, jnp.arange(n_suffix_blocks)
-        )
-        inv = jnp.where(l > 0.0, 1.0 / jnp.maximum(l, 1e-30), 0.0)
-        out = (o * inv[..., None]).astype(x.dtype)  # (B, Kh, G, P2, D)
-        out = out.transpose(0, 3, 1, 2, 4).reshape(B, P2, c.heads * c.head_dim)
-        attn = jnp.einsum("bpd,dh->bph", out, _w(lp["wo"]))
-        if adapters is not None:
-            attn = attn + lora_delta(out, adapters["ids"], al["wo_a"], al["wo_b"])
-        x = x + attn
-        h2 = _rms_norm(x, lp["mlp_norm"], c.norm_eps)
-        x = x + ffn(h2, lp, pos_valid)
+            inv = jnp.where(l > 0.0, 1.0 / jnp.maximum(l, 1e-30), 0.0)
+            out = (o * inv[..., None]).astype(x.dtype)  # (B, Kh, G, P2, D)
+            out = out.transpose(0, 3, 1, 2, 4).reshape(B, P2, c.heads * c.head_dim)
+        with jax.named_scope("attn_out"):
+            attn = jnp.einsum("bpd,dh->bph", out, _w(lp["wo"]))
+            if adapters is not None:
+                attn = attn + lora_delta(out, adapters["ids"], al["wo_a"], al["wo_b"])
+            x = x + attn
+        with jax.named_scope("ffn"):
+            h2 = _rms_norm(x, lp["mlp_norm"], c.norm_eps)
+            x = x + ffn(h2, lp, pos_valid)
         return x, (k, v)
 
     layer_xs = (
@@ -332,18 +338,19 @@ def llama_prefill_continue_paged(
         else (params["layers"], adapters["layers"], pool_k, pool_v)
     )
     x, (ks, vs) = jax.lax.scan(layer, x, layer_xs)
-    x = _rms_norm(x, params["final_norm"], c.norm_eps)
-    if return_all_logits:
-        logits = jnp.einsum("bph,hv->bpv", x, _w(params["lm_head"])).astype(
-            jnp.float32
-        )
-    else:
-        last = jnp.take_along_axis(
-            x, (suffix_lengths - 1)[:, None, None].clip(0), axis=1
-        ).squeeze(1)
-        logits = jnp.einsum("bh,hv->bv", last, _w(params["lm_head"])).astype(
-            jnp.float32
-        )
+    with jax.named_scope("lm_head"):
+        x = _rms_norm(x, params["final_norm"], c.norm_eps)
+        if return_all_logits:
+            logits = jnp.einsum("bph,hv->bpv", x, _w(params["lm_head"])).astype(
+                jnp.float32
+            )
+        else:
+            last = jnp.take_along_axis(
+                x, (suffix_lengths - 1)[:, None, None].clip(0), axis=1
+            ).squeeze(1)
+            logits = jnp.einsum("bh,hv->bv", last, _w(params["lm_head"])).astype(
+                jnp.float32
+            )
     L = c.layers
     pool_k = write_rows(
         pool_k, ks.reshape(L, B, P2, KhD), block_tables, start_lengths, pos_valid
@@ -552,41 +559,42 @@ def llama_verify_chunk_paged(
         num_read_blocks, ffn=ffn, return_all_logits=True, kernel=kernel,
         mesh=mesh, adapters=adapters,
     )  # logits (B, D1, V)
-    drafts = tokens[:, 1:]                                   # (B, D1-1)
-    logits_f32 = logits.astype(jnp.float32)
-    if sampler_mode is None or sampler_mode[2]:  # all-greedy
-        model_next = jnp.argmax(logits, axis=-1).astype(jnp.int32)  # (B, D1)
-        # draft j (= input position j+1) is accepted iff every earlier
-        # draft matched and the model's token at position j equals it
-        match = model_next[:, :-1] == drafts                 # (B, D1-1)
-        accepted = jnp.cumprod(match.astype(jnp.int32), axis=1).sum(axis=1)
-        emitted = model_next
-    else:
-        from langstream_tpu.serving.sampler import speculative_accept
+    with jax.named_scope("sample"):
+        drafts = tokens[:, 1:]                                   # (B, D1-1)
+        logits_f32 = logits.astype(jnp.float32)
+        if sampler_mode is None or sampler_mode[2]:  # all-greedy
+            model_next = jnp.argmax(logits, axis=-1).astype(jnp.int32)  # (B, D1)
+            # draft j (= input position j+1) is accepted iff every earlier
+            # draft matched and the model's token at position j equals it
+            match = model_next[:, :-1] == drafts                 # (B, D1-1)
+            accepted = jnp.cumprod(match.astype(jnp.int32), axis=1).sum(axis=1)
+            emitted = model_next
+        else:
+            from langstream_tpu.serving.sampler import speculative_accept
 
-        use_top_p, use_top_k, _ = sampler_mode
-        accepted, fallback = speculative_accept(
-            logits_f32, drafts, key, temps, topks, topps,
-            use_top_p=use_top_p, use_top_k=use_top_k,
+            use_top_p, use_top_k, _ = sampler_mode
+            accepted, fallback = speculative_accept(
+                logits_f32, drafts, key, temps, topks, topps,
+                use_top_p=use_top_p, use_top_k=use_top_k,
+            )
+            # emit accepted drafts verbatim, then the residual/bonus sample at
+            # the stop position (the only fallback column the engine reads)
+            pos = jnp.arange(D1)[None, :]
+            drafts_pad = jnp.pad(drafts, ((0, 0), (0, 1)))
+            emitted = jnp.where(pos < accepted[:, None], drafts_pad, fallback)
+            emitted = emitted.astype(jnp.int32)
+        logprobs = jnp.take_along_axis(
+            jax.nn.log_softmax(logits_f32, axis=-1), emitted[..., None], axis=-1
+        ).squeeze(-1)
+        adv = jnp.where(active, accepted + 1, 0)                 # tokens emitted
+        new_lengths = base_lengths + adv
+        next_tokens = jnp.where(
+            active,
+            jnp.take_along_axis(
+                emitted, jnp.maximum(adv - 1, 0)[:, None], axis=1
+            ).squeeze(1),
+            tokens[:, 0],
         )
-        # emit accepted drafts verbatim, then the residual/bonus sample at
-        # the stop position (the only fallback column the engine reads)
-        pos = jnp.arange(D1)[None, :]
-        drafts_pad = jnp.pad(drafts, ((0, 0), (0, 1)))
-        emitted = jnp.where(pos < accepted[:, None], drafts_pad, fallback)
-        emitted = emitted.astype(jnp.int32)
-    logprobs = jnp.take_along_axis(
-        jax.nn.log_softmax(logits_f32, axis=-1), emitted[..., None], axis=-1
-    ).squeeze(-1)
-    adv = jnp.where(active, accepted + 1, 0)                 # tokens emitted
-    new_lengths = base_lengths + adv
-    next_tokens = jnp.where(
-        active,
-        jnp.take_along_axis(
-            emitted, jnp.maximum(adv - 1, 0)[:, None], axis=1
-        ).squeeze(1),
-        tokens[:, 0],
-    )
     return emitted, adv, next_tokens, new_lengths, pool_k, pool_v, logprobs
 
 
@@ -737,11 +745,14 @@ def llama_decode_chunk_paged(
         else:
             tokens, kbuf, vbuf, key = carry
             counts = None
-        key, sub = jax.random.split(key)
-        x = embedding_take(params["embed"], tokens)
-        positions = base_lengths + step_idx * adv
-        cos, sin = _rope(positions, c.head_dim, c.rope_theta)
-        buf_mask = jnp.arange(num_steps)[None, :] <= step_idx  # (1, K)
+        with jax.named_scope("sample"):
+            key, sub = jax.random.split(key)
+        with jax.named_scope("embed"):
+            x = embedding_take(params["embed"], tokens)
+        with jax.named_scope("attn_qkv"):
+            positions = base_lengths + step_idx * adv
+            cos, sin = _rope(positions, c.head_dim, c.rope_theta)
+            buf_mask = jnp.arange(num_steps)[None, :] <= step_idx  # (1, K)
         G = c.heads // c.kv_heads
 
         def layer(x, layer_in):
@@ -749,58 +760,62 @@ def llama_decode_chunk_paged(
                 lp, ck_l, cv_l, kbuf_l, vbuf_l = layer_in
             else:
                 lp, al, ck_l, cv_l, kbuf_l, vbuf_l = layer_in
-            h = _rms_norm(x, lp["attn_norm"], c.norm_eps)
-            q = h @ _w(lp["wq"])
-            k = h @ _w(lp["wk"])
-            v = h @ _w(lp["wv"])
-            if adapters is not None:
-                ids = adapters["ids"]
-                q = q + lora_delta(h, ids, al["wq_a"], al["wq_b"])
-                k = k + lora_delta(h, ids, al["wk_a"], al["wk_b"])
-                v = v + lora_delta(h, ids, al["wv_a"], al["wv_b"])
-            q = q.reshape(B, c.heads, c.head_dim)
-            k = k.reshape(B, c.kv_heads, c.head_dim)
-            v = v.reshape(B, c.kv_heads, c.head_dim)
-            q = _apply_rope(q, cos, sin)
-            k = _apply_rope(k, cos, sin)
-            kbuf_l = jax.lax.dynamic_update_slice_in_dim(
-                kbuf_l, k[:, None], step_idx, axis=1
-            )
-            vbuf_l = jax.lax.dynamic_update_slice_in_dim(
-                vbuf_l, v[:, None], step_idx, axis=1
-            )
-            # segment 1: paged pool (partial stats)
-            acc_c, m_c, l_c = cache_partial(q, ck_l, cv_l)
-            # segment 2: in-chunk buffer (partial stats, tiny)
-            qg = q.reshape(B, c.kv_heads, G, c.head_dim)
-            s_buf = jnp.einsum("bkgd,btkd->bkgt", qg, kbuf_l).astype(jnp.float32)
-            s_buf = s_buf / math.sqrt(c.head_dim)
-            s_buf = jnp.where(buf_mask[:, None, None, :], s_buf, NEG_INF)
-            m_b = jnp.max(s_buf, axis=-1)
-            shift = jnp.where(m_b <= NEG_INF, 0.0, m_b)
-            p_b = jnp.exp(s_buf - shift[..., None])
-            p_b = jnp.where(buf_mask[:, None, None, :], p_b, 0.0)
-            l_b = jnp.sum(p_b, axis=-1)
-            acc_b = jnp.einsum(
-                "bkgt,btkd->bkgd", p_b.astype(vbuf_l.dtype), vbuf_l
-            ).astype(jnp.float32)
-            out = merge_partial_attention([
-                (acc_c, m_c, l_c),
-                (
-                    acc_b.reshape(B, c.heads, c.head_dim),
-                    m_b.reshape(B, c.heads),
-                    l_b.reshape(B, c.heads),
-                ),
-            ]).astype(x.dtype)
-            out = out.reshape(B, c.heads * c.head_dim)
-            attn = out @ _w(lp["wo"])
-            if adapters is not None:
-                attn = attn + lora_delta(
-                    out, adapters["ids"], al["wo_a"], al["wo_b"]
+            with jax.named_scope("attn_qkv"):
+                h = _rms_norm(x, lp["attn_norm"], c.norm_eps)
+                q = h @ _w(lp["wq"])
+                k = h @ _w(lp["wk"])
+                v = h @ _w(lp["wv"])
+                if adapters is not None:
+                    ids = adapters["ids"]
+                    q = q + lora_delta(h, ids, al["wq_a"], al["wq_b"])
+                    k = k + lora_delta(h, ids, al["wk_a"], al["wk_b"])
+                    v = v + lora_delta(h, ids, al["wv_a"], al["wv_b"])
+                q = q.reshape(B, c.heads, c.head_dim)
+                k = k.reshape(B, c.kv_heads, c.head_dim)
+                v = v.reshape(B, c.kv_heads, c.head_dim)
+                q = _apply_rope(q, cos, sin)
+                k = _apply_rope(k, cos, sin)
+                kbuf_l = jax.lax.dynamic_update_slice_in_dim(
+                    kbuf_l, k[:, None], step_idx, axis=1
                 )
-            x = x + attn
-            h2 = _rms_norm(x, lp["mlp_norm"], c.norm_eps)
-            x = x + ffn(h2, lp, active)
+                vbuf_l = jax.lax.dynamic_update_slice_in_dim(
+                    vbuf_l, v[:, None], step_idx, axis=1
+                )
+            with jax.named_scope("kv_read"):
+                # segment 1: paged pool (partial stats)
+                acc_c, m_c, l_c = cache_partial(q, ck_l, cv_l)
+                # segment 2: in-chunk buffer (partial stats, tiny)
+                qg = q.reshape(B, c.kv_heads, G, c.head_dim)
+                s_buf = jnp.einsum("bkgd,btkd->bkgt", qg, kbuf_l).astype(jnp.float32)
+                s_buf = s_buf / math.sqrt(c.head_dim)
+                s_buf = jnp.where(buf_mask[:, None, None, :], s_buf, NEG_INF)
+                m_b = jnp.max(s_buf, axis=-1)
+                shift = jnp.where(m_b <= NEG_INF, 0.0, m_b)
+                p_b = jnp.exp(s_buf - shift[..., None])
+                p_b = jnp.where(buf_mask[:, None, None, :], p_b, 0.0)
+                l_b = jnp.sum(p_b, axis=-1)
+                acc_b = jnp.einsum(
+                    "bkgt,btkd->bkgd", p_b.astype(vbuf_l.dtype), vbuf_l
+                ).astype(jnp.float32)
+                out = merge_partial_attention([
+                    (acc_c, m_c, l_c),
+                    (
+                        acc_b.reshape(B, c.heads, c.head_dim),
+                        m_b.reshape(B, c.heads),
+                        l_b.reshape(B, c.heads),
+                    ),
+                ]).astype(x.dtype)
+                out = out.reshape(B, c.heads * c.head_dim)
+            with jax.named_scope("attn_out"):
+                attn = out @ _w(lp["wo"])
+                if adapters is not None:
+                    attn = attn + lora_delta(
+                        out, adapters["ids"], al["wo_a"], al["wo_b"]
+                    )
+                x = x + attn
+            with jax.named_scope("ffn"):
+                h2 = _rms_norm(x, lp["mlp_norm"], c.norm_eps)
+                x = x + ffn(h2, lp, active)
             return x, (kbuf_l, vbuf_l)
 
         layer_xs = (
@@ -810,13 +825,15 @@ def llama_decode_chunk_paged(
                   kbuf, vbuf)
         )
         x, (kbuf, vbuf) = jax.lax.scan(layer, x, layer_xs)
-        x = _rms_norm(x, params["final_norm"], c.norm_eps)
-        logits = (x @ _w(params["lm_head"])).astype(jnp.float32)
-        if pen:
-            nxt, lp_ = sample_fn(logits, sub, counts)
-        else:
-            nxt, lp_ = sample_fn(logits, sub)
-        nxt = jnp.where(active, nxt, tokens)
+        with jax.named_scope("lm_head"):
+            x = _rms_norm(x, params["final_norm"], c.norm_eps)
+            logits = (x @ _w(params["lm_head"])).astype(jnp.float32)
+        with jax.named_scope("sample"):
+            if pen:
+                nxt, lp_ = sample_fn(logits, sub, counts)
+            else:
+                nxt, lp_ = sample_fn(logits, sub)
+            nxt = jnp.where(active, nxt, tokens)
         if pen:
             counts = counts.at[jnp.arange(B), nxt].add(adv)
             return (nxt, kbuf, vbuf, key, counts), (nxt, lp_)

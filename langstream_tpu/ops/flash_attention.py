@@ -168,6 +168,7 @@ def _flash_bhsd(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="flash_prefill",
     )(q, k, v)
 
 

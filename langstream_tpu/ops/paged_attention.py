@@ -303,6 +303,7 @@ def paged_attention_partial(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="paged_read",
     )(block_tables, lengths, q, k_pool, v_pool)
     return acc, m[:, :, 0], l[:, :, 0]
 
@@ -369,6 +370,7 @@ def _paged_attention_partial_q8(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="paged_read_q8",
     )(block_tables, lengths, q, k_pool["q"], k_pool["s"],
       v_pool["q"], v_pool["s"])
     return acc, m[:, :, 0], l[:, :, 0]
@@ -552,6 +554,7 @@ def paged_attention_multiquery_partial(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="paged_read_mq",
     )(block_tables, starts, q, k_pool, v_pool)
     # kernel rows are (Kh, t, G)-major per t-block → back to (B, T, H)
     G = H // kv_heads

@@ -48,7 +48,7 @@ Cost-model assumptions (documented limits, not hidden ones):
   reported for context; the expected time is the HBM-bytes floor
   (decode is bandwidth-bound; a program whose achieved-vs-expected
   ratio is low while FLOP-heavy is compute-bound instead, and
-  ``tools/trace_attrib.py`` is the post-mortem for that disagreement).
+  ``bench/lib/hosttrace.py`` is the post-mortem for that disagreement).
 - MoE engines approximate: every expert's weights count as streamed
   (routed-expert reads are data-dependent; the host cannot know which
   experts fired). Ratios there are a *floor* on efficiency.

@@ -723,6 +723,11 @@ def _normalize_stop(value) -> list[str]:
     return [s if isinstance(s, str) else str(s) for s in value if s]
 
 
+def _span_meta(ticket: dict) -> dict:
+    """A dispatch's ticket as the meta of its ``*.dispatch`` span."""
+    return {"seq": ticket["dispatch"], "program": ticket["program"]}
+
+
 def _pow2(n: int) -> int:
     """Smallest power of two >= n (batch-row padding: compiling one jit
     variant per exact row count is a compile per new size)."""
@@ -1298,8 +1303,12 @@ class TpuServingEngine:
         )
         # a dispatched-but-unprocessed decode chunk carried across the
         # burst boundary so admission prefills dispatch under its device
-        # shadow: (out, active slot ids, request identities at capture, K)
+        # shadow: (out, active slot ids, request identities at capture, K,
+        # the dispatch's ticket)
         self._pending_chunk: tuple | None = None
+        # ordinal of the last dispatch made (prefill or decode): the `seq`
+        # of its host spans and the `dispatch` of its flight sample
+        self._dispatch_seq = 0
         # inside a pipelined burst, finished slots' block releases are
         # DEFERRED to burst exit: an in-flight chunk still commits via the
         # tables captured at its dispatch, and a mid-burst re-allocation
@@ -2122,13 +2131,14 @@ class TpuServingEngine:
                         tables, use_flash=prefill_flash, mesh=mesh_static,
                         ffn=ffn_static, adapters=adapters,
                     )
-                    next_tokens, logprobs = _fetchable(
-                        *sample_tokens(
-                            logits, key, temps, topks,
-                            use_top_p=use_top_p, top_ps=topps,
-                            use_top_k=use_top_k, all_greedy=all_greedy,
+                    with jax.named_scope("sample"):
+                        next_tokens, logprobs = _fetchable(
+                            *sample_tokens(
+                                logits, key, temps, topks,
+                                use_top_p=use_top_p, top_ps=topps,
+                                use_top_k=use_top_k, all_greedy=all_greedy,
+                            )
                         )
-                    )
                     return next_tokens, logprobs, ck, cv
 
                 return _prefill
@@ -2140,13 +2150,14 @@ class TpuServingEngine:
                     mc_static, params, tokens, lengths, cache_k, cache_v, slot_ids,
                     use_flash=prefill_flash, mesh=mesh_static, ffn=ffn_static,
                 )
-                next_tokens, logprobs = _fetchable(
-                    *sample_tokens(
-                        logits, key, temps, topks,
-                        use_top_p=use_top_p, top_ps=topps,
-                        use_top_k=use_top_k, all_greedy=all_greedy,
+                with jax.named_scope("sample"):
+                    next_tokens, logprobs = _fetchable(
+                        *sample_tokens(
+                            logits, key, temps, topks,
+                            use_top_p=use_top_p, top_ps=topps,
+                            use_top_k=use_top_k, all_greedy=all_greedy,
+                        )
                     )
-                )
                 return next_tokens, logprobs, ck, cv
 
             return _prefill
@@ -2177,13 +2188,14 @@ class TpuServingEngine:
                     ffn=ffn_static, kernel=self.continuation_read_kernel,
                     mesh=mesh_static, adapters=adapters,
                 )
-                next_tokens, logprobs = _fetchable(
-                    *sample_tokens(
-                        logits, key, temps, topks,
-                        use_top_p=use_top_p, top_ps=topps,
-                        use_top_k=use_top_k, all_greedy=all_greedy,
+                with jax.named_scope("sample"):
+                    next_tokens, logprobs = _fetchable(
+                        *sample_tokens(
+                            logits, key, temps, topks,
+                            use_top_p=use_top_p, top_ps=topps,
+                            use_top_k=use_top_k, all_greedy=all_greedy,
+                        )
                     )
-                )
                 return next_tokens, logprobs, ck, cv
 
             return _prefill_cont
@@ -2475,9 +2487,14 @@ class TpuServingEngine:
         spec_accepted: int = 0,
         spec_rejected: int = 0,
         program: str | None = None,
+        dispatch: int | None = None,
+        steps: int = 0,
+        active_at_dispatch: int | None = None,
     ) -> None:
         """One flight sample per dispatched burst, plus its Prometheus
-        mirrors. ``overlapped_s`` is host work the pipelined loop ran
+        mirrors. ``program``, ``dispatch``, ``steps`` and
+        ``active_at_dispatch`` are the dispatch's :meth:`_ticket`, taken
+        when it was made. ``overlapped_s`` is host work the pipelined loop ran
         under an in-flight dispatch's device shadow (see flight.py).
         ``program`` keys the sample by the compiled variant that ran and
         feeds the attribution ledger's measured side (achieved-vs-
@@ -2509,6 +2526,9 @@ class TpuServingEngine:
             spec_rejected=spec_rejected,
             queue_by_class=depths,
             program=program,
+            dispatch=dispatch,
+            steps=steps,
+            active_at_dispatch=active_at_dispatch,
         )
         # watchdog heartbeat: a recorded dispatch IS step progress
         self.watchdog.beat(sample["queue_depth"])
@@ -2544,6 +2564,18 @@ class TpuServingEngine:
             self._m_kv_used(kv_used)
         if stall is not None:
             self._m_stall[stall](sample["wall_ms"] / 1000.0)
+
+    def _ticket(self, program: str, steps: int, active: int) -> dict:
+        """What a dispatch knows when it is made and its flight sample,
+        recorded when the result is processed, no longer does: the program
+        variant, the dispatch's ordinal (the ``seq`` of its host spans),
+        the decode steps it fuses (0 for a prefill) and the slots running.
+        Loop thread only; rides to :meth:`_flight_record` as keywords."""
+        self._dispatch_seq += 1
+        return {
+            "program": program, "dispatch": self._dispatch_seq,
+            "steps": steps, "active_at_dispatch": active,
+        }
 
     def _flight_stall(self, reason: str) -> None:
         """Record an idle/blocked engine-loop gap as stall time."""
@@ -4431,12 +4463,13 @@ class TpuServingEngine:
                             or self._prefix_demote_pending()
                             else 1.0
                         )
-                        try:
-                            await asyncio.wait_for(
-                                self._wake.wait(), timeout=idle_s
-                            )
-                        except asyncio.TimeoutError:
-                            pass
+                        with self.flight.span("ls.idle"):
+                            try:
+                                await asyncio.wait_for(
+                                    self._wake.wait(), timeout=idle_s
+                                )
+                            except asyncio.TimeoutError:
+                                pass
                         # the whole gap was engine idle time: record it so
                         # the flight timeline stays contiguous and the
                         # rollup's stall component is exact
@@ -5691,7 +5724,9 @@ class TpuServingEngine:
         as the sequential decode loop)."""
         K = 1
         fn = self._decode_fn(sampler_mode, nrb, K, False)
-        program = self._program_decode(nrb, K, sampler_mode, False)
+        ticket = self._ticket(
+            self._program_decode(nrb, K, sampler_mode, False), K, len(live)
+        )
         amask, temps, topks, topps = self._sampler_device(active_mask)
         lengths_np = self._lengths.copy()
         current_np = self._current.copy()
@@ -5748,7 +5783,7 @@ class TpuServingEngine:
         )
         self._flight_record(
             "decode", device_s=fetch_s,
-            tokens=self.total_generated - gen_before, program=program,
+            tokens=self.total_generated - gen_before, **ticket,
         )
         await self._flush_emits(live)
         return finished
@@ -6029,6 +6064,16 @@ class TpuServingEngine:
             fetch_s,
         )
 
+    async def _await_chunk(self, loop, packed, k_steps: int, ticket: dict):
+        """The loop thread's wait for a dispatched chunk's tokens, from
+        handing :meth:`_fetch_chunk` to the dispatch thread until this
+        coroutine runs again: the ``ls.decode.fetch`` span, the one span
+        held across an ``await``."""
+        with self.flight.span("ls.decode.fetch", seq=ticket["dispatch"]):
+            return await loop.run_in_executor(
+                self._executor, partial(self._fetch_chunk, packed, k_steps)
+            )
+
     @staticmethod
     def _chunk_ready(packed) -> bool:
         """Non-blocking completion probe for an in-flight packed chunk
@@ -6106,51 +6151,59 @@ class TpuServingEngine:
         ``pipeline=False`` / ``LS_TPU_PIPELINE=0`` escape hatch — it is
         the reference the pipelined loop's greedy byte-identity is tested
         against."""
-        key1 = self._split_key()
-        active_mask = np.zeros(self.config.slots, dtype=bool)
-        active_mask[active] = True
-        amask, temps, topks, topps = self._sampler_device(active_mask)
-        sampler_mode = self._sampler_mode(
-            self._temps[active_mask], self._topks[active_mask],
-            self._topps[active_mask],
-        )
-        light = len(active) <= self._light_threshold()
-        K = (
-            self.config.decode_chunk_light if light
-            else self.config.decode_chunk
-        )
-        # never fuse far past the longest remaining budget: a 96-step chunk
-        # serving 48-token answers burns half its steps on finished slots
-        # (and doubles head-of-line latency for queued arrivals). Halving
-        # buckets keep the compile-variant count logarithmic.
-        max_remaining = 1
-        for slot_id in active:
-            request = self.slots[slot_id].request
-            if request is not None:
-                max_remaining = max(
-                    max_remaining,
-                    request.max_tokens - len(request.generated),
-                )
-        while K >= 2 * max(max_remaining, self.config.decode_chunk_light, 1):
-            K //= 2
-        # presence/frequency penalties: the in-chunk token counts evolve in
-        # the scan carry but are NOT returned (the host rebuilds them from
-        # request.generated before each dispatch) — so penalty bursts run
-        # the SEQUENTIAL path: a pipelined speculative chunk would need the
-        # previous chunk's final counts before the host has its tokens
-        pen = bool(
-            (self._pres[active_mask] != 0).any()
-            or (self._freq[active_mask] != 0).any()
-        )
-        # penalty state snapshotted on the LOOP thread: _admit/_advance_
-        # prefills rewrite these arrays between bursts, and the dispatch
-        # thread must never re-read engine fields mid-flight (RACE801)
-        pres_np = self._pres.copy() if pen else None
-        freq_np = self._freq.copy() if pen else None
-        # host-tracked longest active sequence: each dispatched chunk grows
-        # it by K; the attention window bucket follows
-        base_max = int(self._lengths[active].max())
-        paged = self.block_mgr is not None
+        # host spans (flight.SPANS): the loop-thread work before each
+        # dispatch is ``ls.decode.prepare``
+        with self.flight.span("ls.decode.prepare", active=len(active)):
+            key1 = self._split_key()
+            active_mask = np.zeros(self.config.slots, dtype=bool)
+            active_mask[active] = True
+            amask, temps, topks, topps = self._sampler_device(active_mask)
+            sampler_mode = self._sampler_mode(
+                self._temps[active_mask], self._topks[active_mask],
+                self._topps[active_mask],
+            )
+            light = len(active) <= self._light_threshold()
+            K = (
+                self.config.decode_chunk_light if light
+                else self.config.decode_chunk
+            )
+            # never fuse far past the longest remaining budget: a 96-step
+            # chunk serving 48-token answers burns half its steps on
+            # finished slots (and doubles head-of-line latency for queued
+            # arrivals). Halving buckets keep the compile-variant count
+            # logarithmic.
+            max_remaining = 1
+            for slot_id in active:
+                request = self.slots[slot_id].request
+                if request is not None:
+                    max_remaining = max(
+                        max_remaining,
+                        request.max_tokens - len(request.generated),
+                    )
+            while K >= 2 * max(
+                max_remaining, self.config.decode_chunk_light, 1
+            ):
+                K //= 2
+            # presence/frequency penalties: the in-chunk token counts evolve
+            # in the scan carry but are NOT returned (the host rebuilds them
+            # from request.generated before each dispatch) — so penalty
+            # bursts run the SEQUENTIAL path: a pipelined speculative chunk
+            # would need the previous chunk's final counts before the host
+            # has its tokens
+            pen = bool(
+                (self._pres[active_mask] != 0).any()
+                or (self._freq[active_mask] != 0).any()
+            )
+            # penalty state snapshotted on the LOOP thread: _admit/
+            # _advance_prefills rewrite these arrays between bursts, and the
+            # dispatch thread must never re-read engine fields mid-flight
+            # (RACE801)
+            pres_np = self._pres.copy() if pen else None
+            freq_np = self._freq.copy() if pen else None
+            # host-tracked longest active sequence: each dispatched chunk
+            # grows it by K; the attention window bucket follows
+            base_max = int(self._lengths[active].max())
+            paged = self.block_mgr is not None
 
         def _build_counts() -> np.ndarray:
             counts = np.zeros(
@@ -6206,7 +6259,7 @@ class TpuServingEngine:
             return self.block_mgr.tables.copy()
 
         def _dispatch(tokens, lengths, key, window, tables, decode_fn,
-                      counts_np=None, first=False, ad_np=None):
+                      ticket, counts_np=None, first=False, ad_np=None):
             # async JAX dispatch: returns device arrays without blocking.
             # Everything the closure needs (the resolved jit variant, the
             # penalty snapshot, the block tables) was prepared on the loop
@@ -6249,41 +6302,45 @@ class TpuServingEngine:
                     )
                 self._lockstep.broadcast(desc)
             self.profiler.on_decode_chunk()
-            tables_dev = self._tables_device(tables)
-            args = (
-                (self.params, self.cache_k, self.cache_v,
-                 tokens, lengths, amask, tables_dev, key, temps, topks, topps)
-                if paged
-                else (self.params, self.cache_k, self.cache_v,
-                      tokens, lengths, amask, key, temps, topks, topps)
-            )
-            if pen:
-                args = args + (
-                    jnp.asarray(pres_np), jnp.asarray(freq_np),
-                    jnp.asarray(counts_np),
+            # the uploads and the call into the jitted chunk
+            with self.flight.span(
+                "ls.decode.dispatch", **_span_meta(ticket), steps=K
+            ):
+                tables_dev = self._tables_device(tables)
+                args = (
+                    (self.params, self.cache_k, self.cache_v,
+                     tokens, lengths, amask, tables_dev, key, temps, topks, topps)
+                    if paged
+                    else (self.params, self.cache_k, self.cache_v,
+                          tokens, lengths, amask, key, temps, topks, topps)
                 )
-            # adapter rows ride as kwargs only when the store is enabled:
-            # the default engine's trace (and its jaxpr) stays the seed's.
-            # _ad_layers is touched only on this (dispatch) thread, so the
-            # snapshot here serializes after any in-flight row load.
-            ad_kw = (
-                {}
-                if ad_np is None
-                else {"ad_layers": self._ad_layers,
-                      "ad_ids": jnp.asarray(ad_np)}
-            )
-            self.profiler.dump_hlo(
-                f"decode_chunk_w{window}_s{sampler_mode}", decode_fn, *args
-            )
-            packed, t, l, ck, cv = decode_fn(*args, **ad_kw)
-            self.cache_k, self.cache_v = ck, cv
-            # tokens+logprobs were packed INSIDE the decode program
-            # (sample-in-program): start their D2H copy now, so by the
-            # time the deferred _fetch_chunk wait runs, the transfer has
-            # been riding under this dispatch's own device shadow
-            self._decode_dispatches += 1
-            self._start_fetch(packed)
-            return packed, t, l
+                if pen:
+                    args = args + (
+                        jnp.asarray(pres_np), jnp.asarray(freq_np),
+                        jnp.asarray(counts_np),
+                    )
+                # adapter rows ride as kwargs only when the store is enabled:
+                # the default engine's trace (and its jaxpr) stays the seed's.
+                # _ad_layers is touched only on this (dispatch) thread, so the
+                # snapshot here serializes after any in-flight row load.
+                ad_kw = (
+                    {}
+                    if ad_np is None
+                    else {"ad_layers": self._ad_layers,
+                          "ad_ids": jnp.asarray(ad_np)}
+                )
+                self.profiler.dump_hlo(
+                    f"decode_chunk_w{window}_s{sampler_mode}", decode_fn, *args
+                )
+                packed, t, l, ck, cv = decode_fn(*args, **ad_kw)
+                self.cache_k, self.cache_v = ck, cv
+                # tokens+logprobs were packed INSIDE the decode program
+                # (sample-in-program): start their D2H copy now, so by the
+                # time the deferred _fetch_chunk wait runs, the transfer has
+                # been riding under this dispatch's own device shadow
+                self._decode_dispatches += 1
+                self._start_fetch(packed)
+                return packed, t, l
 
         def _bucket_for(max_len: int):
             return (
@@ -6291,10 +6348,11 @@ class TpuServingEngine:
                 else self._window_for(max_len)
             )
 
-        # program ids of dispatched-but-unrecorded chunks, FIFO (≤ 2 in
-        # flight under the depth-2 pipeline): each flight record pops the
-        # oldest so measured device time lands on the variant that ran it
-        prog_q: list[str] = []
+        # tickets (program id, dispatch ordinal, steps, slots running) of
+        # dispatched-but-unrecorded chunks, FIFO (≤ 2 in flight under the
+        # depth-2 pipeline): each fetch pops the oldest so measured device
+        # time lands on the variant that ran it
+        prog_q: list[dict] = []
 
         def _submit(tokens, lengths, key, window, tables, first=False):
             """Loop-thread half of a chunk dispatch: resolve the jit
@@ -6305,7 +6363,11 @@ class TpuServingEngine:
             Returns the executor future — awaited immediately by the
             sequential path, left in flight by the pipelined one."""
             decode_fn = self._decode_fn(sampler_mode, window, K, pen)
-            prog_q.append(self._program_decode(window, K, sampler_mode, pen))
+            ticket = self._ticket(
+                self._program_decode(window, K, sampler_mode, pen),
+                K, len(active),
+            )
+            prog_q.append(ticket)
             counts_np = _build_counts() if pen else None
             # slot→adapter-row mirror snapshotted on the LOOP thread
             # (RACE801): admission rewrites _ad_rows between bursts
@@ -6317,13 +6379,18 @@ class TpuServingEngine:
             return loop.run_in_executor(
                 self._executor,
                 partial(_dispatch, tokens, lengths, key, window, tables,
-                        decode_fn, counts_np, first=first, ad_np=ad_np),
+                        decode_fn, ticket, counts_np, first=first,
+                        ad_np=ad_np),
             )
 
-        out = await _submit(
-            jnp.asarray(self._current), jnp.asarray(self._lengths),
-            key1, _bucket_for(base_max), _grow_blocks(0), first=True,
-        )
+        with self.flight.span(
+            "ls.decode.prepare", active=len(active), steps=K
+        ):
+            first_out = _submit(
+                jnp.asarray(self._current), jnp.asarray(self._lengths),
+                key1, _bucket_for(base_max), _grow_blocks(0), first=True,
+            )
+        out = await first_out
         chunk_index = 0
         if light or pen or not self._pipeline_on:
             # the SEQUENTIAL reference loop (also the light-load / penalty
@@ -6331,16 +6398,20 @@ class TpuServingEngine:
             # any finish — byte-identical greedy output is defined here,
             # and the pipelined loop below is equivalence-tested against it
             while True:
-                chunk_t, chunk_lp, fetch_s = await loop.run_in_executor(
-                    self._executor, partial(self._fetch_chunk, out[0], K)
+                ticket = prog_q.pop(0)
+                chunk_t, chunk_lp, fetch_s = await self._await_chunk(
+                    loop, out[0], K, ticket
                 )
                 gen_before = self.total_generated
-                finished = self._process_chunk(chunk_t, chunk_lp, active)
-                self._flight_record(
-                    "decode", device_s=fetch_s,
-                    tokens=self.total_generated - gen_before,
-                    program=prog_q.pop(0) if prog_q else None,
-                )
+                with self.flight.span(
+                    "ls.decode.process", seq=ticket["dispatch"],
+                    tokens=chunk_t.size,
+                ):
+                    finished = self._process_chunk(chunk_t, chunk_lp, active)
+                    self._flight_record(
+                        "decode", device_s=fetch_s,
+                        tokens=self.total_generated - gen_before, **ticket,
+                    )
                 await self._flush_emits(active)
                 if self._burst_should_yield(finished):
                     return
@@ -6348,25 +6419,35 @@ class TpuServingEngine:
                 chunk_index += 1
                 # sequential: the chunk just processed is in _lengths, so
                 # blocks grow with a fixed one-chunk lookahead
-                out = await _submit(
-                    out[1], out[2], self._split_key(),
-                    _bucket_for(base_max), _grow_blocks(0),
-                )
+                with self.flight.span(
+                    "ls.decode.prepare", active=len(active), steps=K
+                ):
+                    next_out = _submit(
+                        out[1], out[2], self._split_key(),
+                        _bucket_for(base_max), _grow_blocks(0),
+                    )
+                out = await next_out
 
         async def _drain(out, expected, overlapped_s: float = 0.0) -> None:
             """Fetch + apply one dispatched chunk (the burst's tail or an
             all-finished over-run): identity-filtered so tokens never land
             on a request the slot no longer runs."""
-            chunk_t, chunk_lp, fetch_s = await loop.run_in_executor(
-                self._executor, partial(self._fetch_chunk, out[0], K)
+            ticket = prog_q.pop(0)
+            chunk_t, chunk_lp, fetch_s = await self._await_chunk(
+                loop, out[0], K, ticket
             )
             gen_before = self.total_generated
-            self._process_chunk(chunk_t, chunk_lp, active, expected=expected)
-            self._flight_record(
-                "decode", device_s=fetch_s, overlapped_s=overlapped_s,
-                tokens=self.total_generated - gen_before,
-                program=prog_q.pop(0) if prog_q else None,
-            )
+            with self.flight.span(
+                "ls.decode.process", seq=ticket["dispatch"],
+                tokens=chunk_t.size,
+            ):
+                self._process_chunk(
+                    chunk_t, chunk_lp, active, expected=expected
+                )
+                self._flight_record(
+                    "decode", device_s=fetch_s, overlapped_s=overlapped_s,
+                    tokens=self.total_generated - gen_before, **ticket,
+                )
             await self._flush_emits(active)
 
         # the PIPELINED depth-2 loop: chunk N+1 executes on device while
@@ -6401,15 +6482,19 @@ class TpuServingEngine:
                 # speculate the next chunk from device state
                 base_max += K
                 chunk_index += 1
-                key_next = self._split_key()
-                # pipelined: exactly one dispatched chunk is still
-                # unprocessed when the speculative chunk is dispatched
-                next_out_task = _submit(
-                    out[1], out[2], key_next,
-                    _bucket_for(base_max), _grow_blocks(1),
-                )
-                chunk_t, chunk_lp, fetch_s = await loop.run_in_executor(
-                    self._executor, partial(self._fetch_chunk, out[0], K)
+                ticket = prog_q.pop(0)  # of the chunk about to be fetched
+                with self.flight.span(
+                    "ls.decode.prepare", active=len(active), steps=K
+                ):
+                    key_next = self._split_key()
+                    # pipelined: exactly one dispatched chunk is still
+                    # unprocessed when the speculative chunk is dispatched
+                    next_out_task = _submit(
+                        out[1], out[2], key_next,
+                        _bucket_for(base_max), _grow_blocks(1),
+                    )
+                chunk_t, chunk_lp, fetch_s = await self._await_chunk(
+                    loop, out[0], K, ticket
                 )
                 # the dispatch ran before the fetch on the single executor
                 # thread, so this await resolves instantly — we just need
@@ -6423,7 +6508,11 @@ class TpuServingEngine:
                 # the device share and overlap_ratio could never collapse
                 t_overlap = time.monotonic()
                 in_flight = not self._chunk_ready(out[0])
-                finished = self._process_chunk(chunk_t, chunk_lp, active)
+                with self.flight.span(
+                    "ls.decode.process", seq=ticket["dispatch"],
+                    tokens=chunk_t.size,
+                ):
+                    finished = self._process_chunk(chunk_t, chunk_lp, active)
                 await self._flush_emits(active)
                 elapsed = time.monotonic() - t_overlap
                 if not in_flight:
@@ -6435,8 +6524,7 @@ class TpuServingEngine:
                 self._flight_record(
                     "decode", device_s=fetch_s,
                     overlapped_s=overlapped_s,
-                    tokens=self.total_generated - gen_before,
-                    program=prog_q.pop(0) if prog_q else None,
+                    tokens=self.total_generated - gen_before, **ticket,
                 )
                 if self._burst_should_yield(finished, pipelined=True):
                     if not self._stop:
@@ -6448,7 +6536,7 @@ class TpuServingEngine:
                         self._pending_chunk = (
                             out, list(active),
                             [self.slots[i].request for i in active], K,
-                            prog_q.pop(0) if prog_q else None,
+                            prog_q.pop(0),
                         )
                         return
                     # stopping: nothing will drain a pending chunk — do it
@@ -6475,17 +6563,19 @@ class TpuServingEngine:
         if pending is None:
             return
         self._pending_chunk = None
-        out, active, expected, k_steps, program = pending
-        chunk_t, chunk_lp, fetch_s = await loop.run_in_executor(
-            self._executor, partial(self._fetch_chunk, out[0], k_steps)
+        out, active, expected, k_steps, ticket = pending
+        chunk_t, chunk_lp, fetch_s = await self._await_chunk(
+            loop, out[0], k_steps, ticket
         )
         gen_before = self.total_generated
-        self._process_chunk(chunk_t, chunk_lp, active, expected=expected)
-        self._flight_record(
-            "decode", device_s=fetch_s,
-            tokens=self.total_generated - gen_before,
-            program=program,
-        )
+        with self.flight.span(
+            "ls.decode.process", seq=ticket["dispatch"], tokens=chunk_t.size
+        ):
+            self._process_chunk(chunk_t, chunk_lp, active, expected=expected)
+            self._flight_record(
+                "decode", device_s=fetch_s,
+                tokens=self.total_generated - gen_before, **ticket,
+            )
         await self._flush_emits(active)
 
     def _release_blocks(self, slot_id: int) -> None:
@@ -6528,37 +6618,45 @@ class TpuServingEngine:
         pre = [i for i, s in enumerate(self.slots) if s.prefilling]
         if not pre:
             return
-        C = self.config.prefill_chunk
-        Bp = _pow2(len(pre))
-        tokens = np.zeros((Bp, C), dtype=np.int32)
-        starts = np.zeros(Bp, dtype=np.int32)
-        suffix_lens = np.zeros(Bp, dtype=np.int32)
-        slot_ids = np.zeros(Bp, dtype=np.int32)
-        temps = np.zeros(Bp, dtype=np.float32)
-        topks = np.zeros(Bp, dtype=np.int32)
-        topps = np.ones(Bp, dtype=np.float32)
-        for i in range(Bp):
-            slot_id = pre[min(i, len(pre) - 1)]
-            slot = self.slots[slot_id]
-            request = slot.request
-            chunk = request.context_tokens[
-                slot.prefill_done : slot.prefill_done + C
-            ]
-            tokens[i, : len(chunk)] = chunk
-            starts[i] = slot.prefill_done
-            suffix_lens[i] = len(chunk)
-            slot_ids[i] = slot_id
-            temps[i] = request.temperature
-            topks[i] = request.top_k
-            topps[i] = request.top_p
+        with self.flight.span(
+            "ls.prefill.pack", rows=len(pre),
+            bucket=self.config.prefill_chunk,
+        ):
+            C = self.config.prefill_chunk
+            Bp = _pow2(len(pre))
+            tokens = np.zeros((Bp, C), dtype=np.int32)
+            starts = np.zeros(Bp, dtype=np.int32)
+            suffix_lens = np.zeros(Bp, dtype=np.int32)
+            slot_ids = np.zeros(Bp, dtype=np.int32)
+            temps = np.zeros(Bp, dtype=np.float32)
+            topks = np.zeros(Bp, dtype=np.int32)
+            topps = np.ones(Bp, dtype=np.float32)
+            for i in range(Bp):
+                slot_id = pre[min(i, len(pre) - 1)]
+                slot = self.slots[slot_id]
+                request = slot.request
+                chunk = request.context_tokens[
+                    slot.prefill_done : slot.prefill_done + C
+                ]
+                tokens[i, : len(chunk)] = chunk
+                starts[i] = slot.prefill_done
+                suffix_lens[i] = len(chunk)
+                slot_ids[i] = slot_id
+                temps[i] = request.temperature
+                topks[i] = request.top_k
+                topps[i] = request.top_p
         mode = self._sampler_mode(temps, topks, topps)
         nrb = self._read_blocks_for(max(int(starts.max()), 1))
         fn = self._prefill_continue_fn(mode, nrb)
         # the continuation variant re-traces per (rows, chunk, window) shape
         self._note_compile("prefill-continue", (mode, nrb, Bp, C))
-        program = self._program_prefill_continue(nrb, Bp, C, mode)
+        ticket = self._ticket(
+            self._program_prefill_continue(nrb, Bp, C, mode), 0,
+            sum(1 for s in self.slots if not s.free and not s.prefilling),
+        )
         sel_np = self.block_mgr.tables[slot_ids]
-        key = self._split_key()
+        with self.flight.span("ls.prefill.dispatch", **_span_meta(ticket)):
+            key = self._split_key()
         # adapter rows for the CHUNK batch rows (loop-thread snapshot,
         # RACE801); None when the store is disabled keeps the seed trace
         ad_np = (
@@ -6584,19 +6682,22 @@ class TpuServingEngine:
                         "topps": topps,
                     }
                 )
-            ad_kw = (
-                {}
-                if ad_np is None
-                else {"ad_layers": self._ad_layers,
-                      "ad_ids": jnp.asarray(ad_np)}
-            )
-            out = fn(
-                self.params, self.cache_k, self.cache_v,
-                jnp.asarray(tokens), jnp.asarray(starts),
-                jnp.asarray(suffix_lens), jnp.asarray(sel_np), key,
-                jnp.asarray(temps), jnp.asarray(topks), jnp.asarray(topps),
-                **ad_kw,
-            )
+            with self.flight.span(
+                "ls.prefill.dispatch", **_span_meta(ticket)
+            ):
+                ad_kw = (
+                    {}
+                    if ad_np is None
+                    else {"ad_layers": self._ad_layers,
+                          "ad_ids": jnp.asarray(ad_np)}
+                )
+                out = fn(
+                    self.params, self.cache_k, self.cache_v,
+                    jnp.asarray(tokens), jnp.asarray(starts),
+                    jnp.asarray(suffix_lens), jnp.asarray(sel_np), key,
+                    jnp.asarray(temps), jnp.asarray(topks),
+                    jnp.asarray(topps), **ad_kw,
+                )
             # the donated caches are re-bound HERE, on the dispatch thread
             # — the same side that reads them in every dispatch closure, so
             # cache_k/cache_v stay single-thread-role (RACE801)
@@ -6610,55 +6711,57 @@ class TpuServingEngine:
             device_s = time.monotonic() - t_dev
             return np.asarray(out[0]), np.asarray(out[1]), device_s
 
-        next_np, logprob_np, device_s = await loop.run_in_executor(
-            self._executor, _run
-        )
-        now = time.monotonic()
-        done_slots = []
-        for i, slot_id in enumerate(pre):
-            slot = self.slots[slot_id]
-            request = slot.request
-            slot.prefill_done += int(suffix_lens[i])
-            if slot.prefill_done >= len(request.context_tokens):
-                self._lengths[slot_id] = len(request.context_tokens)
-                self._current[slot_id] = int(next_np[i])
-                self._temps[slot_id] = request.temperature
-                self._topks[slot_id] = request.top_k
-                self._topps[slot_id] = request.top_p
-                self._pres[slot_id] = request.presence_penalty
-                self._freq[slot_id] = request.frequency_penalty
-                if request.first_token_time is None:
-                    # a resumed request keeps its ORIGINAL first-token
-                    # time: TTFT measures the client-visible first token
-                    request.first_token_time = now
-                    self._journey(request, "first-token")
-                slot.prefilling = False
-                # register BEFORE emitting: a max-tokens=1 / instant-EOS
-                # request is released inside _emit_token, and registering
-                # against a released slot's empty table publishes nothing.
-                # Resumed contexts stay out of the prefix cache — their
-                # block chains mix generated content into what looks like
-                # a prompt prefix. Adapter contexts stay out too: their
-                # KV is adapter-colored (docs/ADAPTERS.md).
-                if (
-                    self.config.prefix_cache
-                    and not request.preemptions
-                    and not request.adapter
-                ):
-                    self.block_mgr.register_prefix(
-                        slot_id, request.prompt_tokens
+        with self.flight.span("ls.prefill.fetch", seq=ticket["dispatch"]):
+            next_np, logprob_np, device_s = await loop.run_in_executor(
+                self._executor, _run
+            )
+        with self.flight.span("ls.prefill.emit", rows=len(pre)):
+            now = time.monotonic()
+            done_slots = []
+            for i, slot_id in enumerate(pre):
+                slot = self.slots[slot_id]
+                request = slot.request
+                slot.prefill_done += int(suffix_lens[i])
+                if slot.prefill_done >= len(request.context_tokens):
+                    self._lengths[slot_id] = len(request.context_tokens)
+                    self._current[slot_id] = int(next_np[i])
+                    self._temps[slot_id] = request.temperature
+                    self._topks[slot_id] = request.top_k
+                    self._topps[slot_id] = request.top_p
+                    self._pres[slot_id] = request.presence_penalty
+                    self._freq[slot_id] = request.frequency_penalty
+                    if request.first_token_time is None:
+                        # a resumed request keeps its ORIGINAL first-token
+                        # time: TTFT measures the client-visible first token
+                        request.first_token_time = now
+                        self._journey(request, "first-token")
+                    slot.prefilling = False
+                    # register BEFORE emitting: a max-tokens=1 / instant-EOS
+                    # request is released inside _emit_token, and registering
+                    # against a released slot's empty table publishes nothing.
+                    # Resumed contexts stay out of the prefix cache — their
+                    # block chains mix generated content into what looks like
+                    # a prompt prefix. Adapter contexts stay out too: their
+                    # KV is adapter-colored (docs/ADAPTERS.md).
+                    if (
+                        self.config.prefix_cache
+                        and not request.preemptions
+                        and not request.adapter
+                    ):
+                        self.block_mgr.register_prefix(
+                            slot_id, request.prompt_tokens
+                        )
+                    self._emit_token(
+                        slot_id, int(next_np[i]), float(logprob_np[i])
                     )
-                self._emit_token(
-                    slot_id, int(next_np[i]), float(logprob_np[i])
-                )
-                done_slots.append(slot_id)
-                self._m_tokens(1)
-        self._flight_record(
-            "prefill", device_s=device_s, tokens=len(done_slots),
-            program=program,
-        )
+                    done_slots.append(slot_id)
+                    self._m_tokens(1)
+            self._flight_record(
+                "prefill", device_s=device_s, tokens=len(done_slots),
+                **ticket,
+            )
         if done_slots:
-            await self._flush_emits(done_slots)
+            await self._flush_emits(done_slots, "ls.prefill.emit")
 
     async def _admit(self, loop) -> None:
         """Admit queued requests in batched prefill calls (grouped by
@@ -6682,33 +6785,37 @@ class TpuServingEngine:
                 not self.scheduler.empty()
                 and len(batch) < min(len(free), self.config.prefill_batch)
             ):
-                # the scheduler names the next admission candidate (FIFO
-                # head by default; the WDRR-selected class head under QoS)
-                request = self.scheduler.peek()
-                if request is None:
-                    break
-                if request.future.cancelled():
-                    self.scheduler.pop()  # caller gave up while queued
-                    # the caller walked away — answered by cancellation,
-                    # so a restart must not replay it
-                    self._journal_retire(request)
-                    continue
-                if request.deadline is not None:
-                    # deadline gate (docs/RESILIENCE.md): shed BEFORE
-                    # any device work when the remaining budget cannot
-                    # cover the admission estimate — an explicit
-                    # 504-shaped refusal beats a silent late completion
-                    left = remaining_s(request.deadline)
-                    estimate = self._admit_estimate_s()
-                    if left <= estimate:
-                        self.scheduler.pop()
-                        err = self._note_deadline_shed(
-                            request, "admission", left, estimate
-                        )
+                # ``ls.admit`` spans the synchronous stretches of this
+                # pass; its two awaits (adapter resolve, prefix promotion)
+                # run outside any span
+                with self.flight.span("ls.admit"):
+                    # the scheduler names the next admission candidate (FIFO
+                    # head by default; the WDRR-selected class head under QoS)
+                    request = self.scheduler.peek()
+                    if request is None:
+                        break
+                    if request.future.cancelled():
+                        self.scheduler.pop()  # caller gave up while queued
+                        # the caller walked away — answered by cancellation,
+                        # so a restart must not replay it
                         self._journal_retire(request)
-                        if not request.future.done():
-                            request.future.set_exception(err)
                         continue
+                    if request.deadline is not None:
+                        # deadline gate (docs/RESILIENCE.md): shed BEFORE
+                        # any device work when the remaining budget cannot
+                        # cover the admission estimate — an explicit
+                        # 504-shaped refusal beats a silent late completion
+                        left = remaining_s(request.deadline)
+                        estimate = self._admit_estimate_s()
+                        if left <= estimate:
+                            self.scheduler.pop()
+                            err = self._note_deadline_shed(
+                                request, "admission", left, estimate
+                            )
+                            self._journal_retire(request)
+                            if not request.future.done():
+                                request.future.set_exception(err)
+                            continue
                 if self.adapter_store is not None and request.adapter:
                     # multi-LoRA resolve (docs/ADAPTERS.md): the request
                     # admits only once its adapter holds a device row.
@@ -6722,60 +6829,61 @@ class TpuServingEngine:
                         break
                     if verdict != "ready":
                         continue
-                # one chain-digest walk per admission attempt, shared by
-                # the hydration check, the promotion, and match_prefix
-                # below — the admission path hashes the prompt ONCE
-                chain = (
-                    self.block_mgr.chain_digests(request.context_tokens)
-                    if self.prefix_store is not None
-                    and use_prefix
-                    and not request.preemptions
-                    and not request.adapter
-                    else None
-                )
-                if (
-                    chain is not None
-                    and not request.hydrate_attempted
-                    and not self._draining
-                ):
-                    # tiered prefix store: when the prompt's chain
-                    # extends into T2 (object storage), stash the
-                    # request OFF the queue while the background
-                    # hydrator pulls the blobs into T1 — it requeues at
-                    # class front the moment they land (or the timeout
-                    # falls it back to cold compute). Never head-blocks:
-                    # the loop moves on to the next admission candidate.
-                    request.hydrate_attempted = True
-                    missing = self._chain_t2_candidates(chain)
-                    if missing and self.prefix_store.request_hydration(
-                        missing
+                with self.flight.span("ls.admit"):
+                    # one chain-digest walk per admission attempt, shared by
+                    # the hydration check, the promotion, and match_prefix
+                    # below — the admission path hashes the prompt ONCE
+                    chain = (
+                        self.block_mgr.chain_digests(request.context_tokens)
+                        if self.prefix_store is not None
+                        and use_prefix
+                        and not request.preemptions
+                        and not request.adapter
+                        else None
+                    )
+                    if (
+                        chain is not None
+                        and not request.hydrate_attempted
+                        and not self._draining
                     ):
-                        self.scheduler.pop()
-                        deadline = (
-                            time.monotonic()
-                            + self.prefix_store.spec.hydrate_timeout_s
-                        )
-                        self._prefix_hydrating.append(
-                            (request, deadline, missing)
-                        )
-                        self.flight.event(
-                            "prefix-hydrate", stage="begin",
-                            blocks=len(missing),
-                        )
-                        self._journey(
-                            request, "hydrate-begin", blocks=len(missing)
-                        )
-                        continue
-                if self.block_mgr is not None and not self.block_mgr.can_admit(
-                    len(request.prompt_tokens) + request.max_tokens + 1
-                ):
-                    # paged backpressure: the worst case doesn't fit the
-                    # pool right now; finished slots will free reservations.
-                    # (Requests that could NEVER fit are rejected up front in
-                    # generate(), so this always unblocks eventually. The
-                    # QoS loop may also preempt a lower-class victim to
-                    # unblock this head — see _maybe_preempt.)
-                    break
+                        # tiered prefix store: when the prompt's chain
+                        # extends into T2 (object storage), stash the
+                        # request OFF the queue while the background
+                        # hydrator pulls the blobs into T1 — it requeues at
+                        # class front the moment they land (or the timeout
+                        # falls it back to cold compute). Never head-blocks:
+                        # the loop moves on to the next admission candidate.
+                        request.hydrate_attempted = True
+                        missing = self._chain_t2_candidates(chain)
+                        if missing and self.prefix_store.request_hydration(
+                            missing
+                        ):
+                            self.scheduler.pop()
+                            deadline = (
+                                time.monotonic()
+                                + self.prefix_store.spec.hydrate_timeout_s
+                            )
+                            self._prefix_hydrating.append(
+                                (request, deadline, missing)
+                            )
+                            self.flight.event(
+                                "prefix-hydrate", stage="begin",
+                                blocks=len(missing),
+                            )
+                            self._journey(
+                                request, "hydrate-begin", blocks=len(missing)
+                            )
+                            continue
+                    if self.block_mgr is not None and not self.block_mgr.can_admit(
+                        len(request.prompt_tokens) + request.max_tokens + 1
+                    ):
+                        # paged backpressure: the worst case doesn't fit the
+                        # pool right now; finished slots will free reservations.
+                        # (Requests that could NEVER fit are rejected up front in
+                        # generate(), so this always unblocks eventually. The
+                        # QoS loop may also preempt a lower-class victim to
+                        # unblock this head — see _maybe_preempt.)
+                        break
                 # a resumed request's prefill content is its full context
                 # (prompt + generated so far), rebuilding the KV state the
                 # preemption dropped; untouched requests see ctx == prompt
@@ -6786,177 +6894,196 @@ class TpuServingEngine:
                 # would splice foreign KV under this request — and
                 # registering theirs would poison adapter-less traffic
                 # (docs/ADAPTERS.md)
-                if use_prefix and not request.preemptions \
-                        and not request.adapter:
-                    if chain is not None:
-                        # promote the T1 run extending this prompt's T0
-                        # chain back into pool blocks, so the match
-                        # below sees the longer chain (docs/PREFIX.md)
-                        await self._promote_prefix(loop, request, chain)
-                    blocks, reuse = self.block_mgr.match_prefix(
-                        ctx, digests=chain
-                    )
-                    if (
-                        reuse
-                        and len(ctx) - reuse
-                        > self.config.prefix_cache_max_suffix
-                    ):
-                        # long suffix, small saving: the flash/ring full
-                        # prefill beats the XLA continuation path
+                shared = (
+                    use_prefix and not request.preemptions
+                    and not request.adapter
+                )
+                if shared and chain is not None:
+                    # promote the T1 run extending this prompt's T0
+                    # chain back into pool blocks, so the match
+                    # below sees the longer chain (docs/PREFIX.md)
+                    await self._promote_prefix(loop, request, chain)
+                with self.flight.span("ls.admit"):
+                    if shared:
+                        blocks, reuse = self.block_mgr.match_prefix(
+                            ctx, digests=chain
+                        )
+                        if (
+                            reuse
+                            and len(ctx) - reuse
+                            > self.config.prefix_cache_max_suffix
+                        ):
+                            # long suffix, small saving: the flash/ring full
+                            # prefill beats the XLA continuation path
+                            blocks, reuse = [], 0
+                    else:
                         blocks, reuse = [], 0
-                else:
-                    blocks, reuse = [], 0
-                to_prefill = len(ctx) - reuse
-                if (
-                    self.block_mgr is not None
-                    and self.config.prefill_chunk > 0
-                    and to_prefill > self.config.prefill_chunk
-                ):
-                    # chunked prefill: claim the slot + reservation now, but
-                    # feed the prompt through _advance_prefills one bounded
-                    # chunk per loop pass instead of one monolithic prefill
-                    slot_id = free.pop(len(batch))
-                    self.scheduler.pop()
-                    self.block_mgr.admit(
-                        slot_id,
-                        len(request.prompt_tokens) + request.max_tokens + 1,
-                    )
-                    if blocks:
-                        self.block_mgr.adopt_prefix(slot_id, blocks)
-                    slot = self.slots[slot_id]
-                    # slot claimed BEFORE the physical grow: an allocator
-                    # failure below is then recoverable (a popped request
-                    # in no slot would be invisible to every failure
-                    # path). The chunked claim must undo ITSELF on a
-                    # grow failure: a prefilling slot whose table never
-                    # grew would scatter its chunks into the scratch
-                    # block (silent corruption), and the shrink sweep
-                    # deliberately leaves prefilling slots alone —
-                    # requeue (or shed past the retry cap) HERE, then
-                    # re-raise so the loop's shrink pass still adapts.
-                    slot.request = request
-                    slot.prefilling = True
-                    slot.prefill_done = reuse
-                    if self._ad_rows is not None:
-                        self._ad_rows[slot_id] = request.adapter_row
-                    try:
-                        self._fault("pool-grow")
-                        self.block_mgr.ensure_capacity(slot_id, len(ctx))
-                    except Exception as e:
-                        # monolithic members selected earlier this pass
-                        # are popped + reserved but NOT yet slotted —
-                        # invisible to every failure path (the shrink
-                        # sweep and _fail_inflight both walk slots):
-                        # undo them first, reservations released and
-                        # requeued front in order
-                        for sid, req, _r in reversed(batch):
-                            self.block_mgr.release(sid)
-                            self.scheduler.requeue_front(req)
-                        batch.clear()
-                        if not self._resource_exhausted(e):
+                    to_prefill = len(ctx) - reuse
+                    if (
+                        self.block_mgr is not None
+                        and self.config.prefill_chunk > 0
+                        and to_prefill > self.config.prefill_chunk
+                    ):
+                        # chunked prefill: claim the slot + reservation now, but
+                        # feed the prompt through _advance_prefills one bounded
+                        # chunk per loop pass instead of one monolithic prefill
+                        slot_id = free.pop(len(batch))
+                        self.scheduler.pop()
+                        self.block_mgr.admit(
+                            slot_id,
+                            len(request.prompt_tokens) + request.max_tokens + 1,
+                        )
+                        if blocks:
+                            self.block_mgr.adopt_prefix(slot_id, blocks)
+                        slot = self.slots[slot_id]
+                        # slot claimed BEFORE the physical grow: an allocator
+                        # failure below is then recoverable (a popped request
+                        # in no slot would be invisible to every failure
+                        # path). The chunked claim must undo ITSELF on a
+                        # grow failure: a prefilling slot whose table never
+                        # grew would scatter its chunks into the scratch
+                        # block (silent corruption), and the shrink sweep
+                        # deliberately leaves prefilling slots alone —
+                        # requeue (or shed past the retry cap) HERE, then
+                        # re-raise so the loop's shrink pass still adapts.
+                        slot.request = request
+                        slot.prefilling = True
+                        slot.prefill_done = reuse
+                        if self._ad_rows is not None:
+                            self._ad_rows[slot_id] = request.adapter_row
+                        try:
+                            self._fault("pool-grow")
+                            self.block_mgr.ensure_capacity(slot_id, len(ctx))
+                        except Exception as e:
+                            # monolithic members selected earlier this pass
+                            # are popped + reserved but NOT yet slotted —
+                            # invisible to every failure path (the shrink
+                            # sweep and _fail_inflight both walk slots):
+                            # undo them first, reservations released and
+                            # requeued front in order
+                            for sid, req, _r in reversed(batch):
+                                self.block_mgr.release(sid)
+                                self.scheduler.requeue_front(req)
+                            batch.clear()
+                            if not self._resource_exhausted(e):
+                                raise
+                            if request.preemptions >= _SHRINK_RETRY_CAP:
+                                self._shed_stranded(slot_id, e)
+                                self._shrink_inline_shed += 1
+                            else:
+                                self._preempt_slot(
+                                    slot_id, reason="pool-shrink"
+                                )
+                                self._shrink_inline_preempted += 1
                             raise
-                        if request.preemptions >= _SHRINK_RETRY_CAP:
-                            self._shed_stranded(slot_id, e)
-                            self._shrink_inline_shed += 1
-                        else:
-                            self._preempt_slot(
-                                slot_id, reason="pool-shrink"
-                            )
-                            self._shrink_inline_preempted += 1
-                        raise
-                    request.admit_time = time.monotonic()
-                    self._note_resume(request)
-                    self._journey(request, "admit", chunked=True)
-                    if reuse:
-                        self.prefix_hits += 1
-                        self.prefix_tokens += reuse
-                        self._m_prefix_hits(1)
-                        self._m_prefix_tokens(reuse)
-                    continue
-                b = _bucket(to_prefill, hi=self.model_config.max_seq_len)
-                if bucket is None:
-                    bucket = b
-                elif b != bucket:
-                    break
-                slot_id = free[len(batch)]
-                self.scheduler.pop()
-                if self.block_mgr is not None:
-                    # reserve at pop time so the NEXT peek's can_admit sees
-                    # this batch member's reservation
-                    self.block_mgr.admit(
-                        slot_id, len(request.prompt_tokens) + request.max_tokens + 1
-                    )
-                    if blocks:
-                        self.block_mgr.adopt_prefix(slot_id, blocks)
-                batch.append((slot_id, request, reuse))
+                        request.admit_time = time.monotonic()
+                        self._note_resume(request)
+                        self._journey(request, "admit", chunked=True)
+                        if reuse:
+                            self.prefix_hits += 1
+                            self.prefix_tokens += reuse
+                            self._m_prefix_hits(1)
+                            self._m_prefix_tokens(reuse)
+                        continue
+                    b = _bucket(to_prefill, hi=self.model_config.max_seq_len)
+                    if bucket is None:
+                        bucket = b
+                    elif b != bucket:
+                        break
+                    slot_id = free[len(batch)]
+                    self.scheduler.pop()
+                    if self.block_mgr is not None:
+                        # reserve at pop time so the NEXT peek's can_admit sees
+                        # this batch member's reservation
+                        self.block_mgr.admit(
+                            slot_id, len(request.prompt_tokens) + request.max_tokens + 1
+                        )
+                        if blocks:
+                            self.block_mgr.adopt_prefix(slot_id, blocks)
+                    batch.append((slot_id, request, reuse))
             if not batch:
                 return
-            admit_now = time.monotonic()
-            for slot_id, request, _reuse in batch:
-                self.slots[slot_id].request = request
-                if self._ad_rows is not None:
-                    self._ad_rows[slot_id] = request.adapter_row
-                request.admit_time = admit_now
-                self._note_resume(request)
-                self._journey(request, "admit")
-            # physical grows AFTER every batch member owns its slot: an
-            # allocator failure here is then recoverable by the shrink
-            # pass's preempt-and-requeue sweep (a popped request in no
-            # slot would be invisible to every failure path)
-            if self.block_mgr is not None:
-                self._fault("pool-grow")
+            with self.flight.span(
+                "ls.admit", queued=self.scheduler.qsize(),
+                admitted=len(batch),
+            ):
+                admit_now = time.monotonic()
                 for slot_id, request, _reuse in batch:
-                    self.block_mgr.ensure_capacity(
-                        slot_id, len(request.context_tokens)
-                    )
-            Bp = _pow2(len(batch))
-            use_continue = any(r > 0 for _, _, r in batch)
-            padded = np.zeros((Bp, bucket), dtype=np.int32)
-            lengths = np.zeros(Bp, dtype=np.int32)
-            starts = np.zeros(Bp, dtype=np.int32)
-            slot_ids = np.zeros(Bp, dtype=np.int32)
-            temps = np.zeros(Bp, dtype=np.float32)
-            topks = np.zeros(Bp, dtype=np.int32)
-            topps = np.ones(Bp, dtype=np.float32)
-            for i in range(Bp):
-                slot_id, request, reuse = batch[min(i, len(batch) - 1)]
-                suffix = request.context_tokens[reuse:]
-                padded[i, : len(suffix)] = suffix
-                lengths[i] = len(suffix)
-                starts[i] = reuse
-                slot_ids[i] = slot_id
-                temps[i] = request.temperature
-                topks[i] = request.top_k
-                topps[i] = request.top_p
-            key = self._split_key()
-            prefill_mode = self._sampler_mode(temps, topks, topps)
-            # per-batch-row adapter rows (loop-thread snapshot, RACE801)
-            ad_np = (
-                self._ad_rows[slot_ids].copy()
-                if self._ad_rows is not None else None
-            )
+                    self.slots[slot_id].request = request
+                    if self._ad_rows is not None:
+                        self._ad_rows[slot_id] = request.adapter_row
+                    request.admit_time = admit_now
+                    self._note_resume(request)
+                    self._journey(request, "admit")
+                # physical grows AFTER every batch member owns its slot: an
+                # allocator failure here is then recoverable by the shrink
+                # pass's preempt-and-requeue sweep (a popped request in no
+                # slot would be invisible to every failure path)
+                if self.block_mgr is not None:
+                    self._fault("pool-grow")
+                    for slot_id, request, _reuse in batch:
+                        self.block_mgr.ensure_capacity(
+                            slot_id, len(request.context_tokens)
+                        )
+            with self.flight.span(
+                "ls.prefill.pack", rows=len(batch), bucket=bucket
+            ):
+                Bp = _pow2(len(batch))
+                use_continue = any(r > 0 for _, _, r in batch)
+                padded = np.zeros((Bp, bucket), dtype=np.int32)
+                lengths = np.zeros(Bp, dtype=np.int32)
+                starts = np.zeros(Bp, dtype=np.int32)
+                slot_ids = np.zeros(Bp, dtype=np.int32)
+                temps = np.zeros(Bp, dtype=np.float32)
+                topks = np.zeros(Bp, dtype=np.int32)
+                topps = np.ones(Bp, dtype=np.float32)
+                for i in range(Bp):
+                    slot_id, request, reuse = batch[min(i, len(batch) - 1)]
+                    suffix = request.context_tokens[reuse:]
+                    padded[i, : len(suffix)] = suffix
+                    lengths[i] = len(suffix)
+                    starts[i] = reuse
+                    slot_ids[i] = slot_id
+                    temps[i] = request.temperature
+                    topks[i] = request.top_k
+                    topps[i] = request.top_p
+                prefill_mode = self._sampler_mode(temps, topks, topps)
+                # per-batch-row adapter rows (loop-thread snapshot, RACE801)
+                ad_np = (
+                    self._ad_rows[slot_ids].copy()
+                    if self._ad_rows is not None else None
+                )
 
-            if self.block_mgr is not None:
-                # per-batch-row block tables (duplicate padded rows write
-                # identical values to identical blocks — harmless)
-                sel_np = self.block_mgr.tables[slot_ids]
-            else:
-                sel_np = slot_ids
-            sel = jnp.asarray(sel_np)
-            if use_continue:
-                nrb = self._read_blocks_for(int(starts.max()))
-                prefill_fn = self._prefill_continue_fn(prefill_mode, nrb)
-                self._note_compile(
-                    "prefill-continue", (prefill_mode, nrb, Bp, bucket)
-                )
-                program = self._program_prefill_continue(
-                    nrb, Bp, bucket, prefill_mode
-                )
-            else:
-                prefill_fn = self._prefill_fn(prefill_mode)
-                # same Python variant, fresh XLA program per (bucket, rows)
-                self._note_compile("prefill", (prefill_mode, bucket, Bp))
-                program = self._program_prefill(bucket, Bp, prefill_mode)
+                if self.block_mgr is not None:
+                    # per-batch-row block tables (duplicate padded rows write
+                    # identical values to identical blocks — harmless)
+                    sel_np = self.block_mgr.tables[slot_ids]
+                else:
+                    sel_np = slot_ids
+                sel = jnp.asarray(sel_np)
+                if use_continue:
+                    nrb = self._read_blocks_for(int(starts.max()))
+                    prefill_fn = self._prefill_continue_fn(prefill_mode, nrb)
+                    self._note_compile(
+                        "prefill-continue", (prefill_mode, nrb, Bp, bucket)
+                    )
+                    program = self._program_prefill_continue(
+                        nrb, Bp, bucket, prefill_mode
+                    )
+                else:
+                    prefill_fn = self._prefill_fn(prefill_mode)
+                    # same Python variant, fresh XLA program per (bucket, rows)
+                    self._note_compile("prefill", (prefill_mode, bucket, Bp))
+                    program = self._program_prefill(bucket, Bp, prefill_mode)
+            ticket = self._ticket(
+                program, 0,
+                sum(1 for s in self.slots if not s.free and not s.prefilling)
+                - len(batch),
+            )
+            with self.flight.span(
+                "ls.prefill.dispatch", **_span_meta(ticket)
+            ):
+                key = self._split_key()
 
             def _run():
                 self._fault("prefill")
@@ -6979,33 +7106,36 @@ class TpuServingEngine:
                     else:
                         desc["op"] = "prefill"
                     self._lockstep.broadcast(desc)
-                if use_continue:
-                    args = (
-                        self.params, self.cache_k, self.cache_v,
-                        jnp.asarray(padded), jnp.asarray(starts),
-                        jnp.asarray(lengths), sel, key,
-                        jnp.asarray(temps), jnp.asarray(topks),
-                        jnp.asarray(topps),
+                with self.flight.span(
+                    "ls.prefill.dispatch", **_span_meta(ticket)
+                ):
+                    if use_continue:
+                        args = (
+                            self.params, self.cache_k, self.cache_v,
+                            jnp.asarray(padded), jnp.asarray(starts),
+                            jnp.asarray(lengths), sel, key,
+                            jnp.asarray(temps), jnp.asarray(topks),
+                            jnp.asarray(topps),
+                        )
+                    else:
+                        args = (
+                            self.params, self.cache_k, self.cache_v,
+                            jnp.asarray(padded), jnp.asarray(lengths),
+                            sel, key,
+                            jnp.asarray(temps), jnp.asarray(topks),
+                            jnp.asarray(topps),
+                        )
+                    ad_kw = (
+                        {}
+                        if ad_np is None
+                        else {"ad_layers": self._ad_layers,
+                              "ad_ids": jnp.asarray(ad_np)}
                     )
-                else:
-                    args = (
-                        self.params, self.cache_k, self.cache_v,
-                        jnp.asarray(padded), jnp.asarray(lengths),
-                        sel, key,
-                        jnp.asarray(temps), jnp.asarray(topks),
-                        jnp.asarray(topps),
+                    variant = f"_cont_nrb{nrb}" if use_continue else ""
+                    self.profiler.dump_hlo(
+                        f"prefill_p{bucket}_b{Bp}{variant}", prefill_fn, *args
                     )
-                ad_kw = (
-                    {}
-                    if ad_np is None
-                    else {"ad_layers": self._ad_layers,
-                          "ad_ids": jnp.asarray(ad_np)}
-                )
-                variant = f"_cont_nrb{nrb}" if use_continue else ""
-                self.profiler.dump_hlo(
-                    f"prefill_p{bucket}_b{Bp}{variant}", prefill_fn, *args
-                )
-                out = prefill_fn(*args, **ad_kw)
+                    out = prefill_fn(*args, **ad_kw)
                 # donated caches re-bound on the dispatch thread — see
                 # _advance_prefills._run (RACE801: single thread role)
                 self.cache_k, self.cache_v = out[2], out[3]
@@ -7018,46 +7148,50 @@ class TpuServingEngine:
                 device_s = time.monotonic() - t_dev
                 return np.asarray(out[0]), np.asarray(out[1]), device_s
 
-            next_np, logprob_np, device_s = await loop.run_in_executor(
-                self._executor, _run
-            )
-            if use_prefix:
-                for slot_id, request, reuse in batch:
-                    if request.preemptions or request.adapter:
-                        # resumed contexts stay out of the prefix cache
-                        # (generated content is not a shareable prompt);
-                        # adapter contexts too — their KV is colored by
-                        # the adapter's projections (docs/ADAPTERS.md)
-                        continue
-                    self.block_mgr.register_prefix(
-                        slot_id, request.prompt_tokens
-                    )
-                    if reuse:
-                        self.prefix_hits += 1
-                        self.prefix_tokens += reuse
-                        self._m_prefix_hits(1)
-                        self._m_prefix_tokens(reuse)
-            now = time.monotonic()
-            admitted_slots = []
-            for i, (slot_id, request, _reuse) in enumerate(batch):
-                self._lengths[slot_id] = len(request.context_tokens)
-                self._current[slot_id] = int(next_np[i])
-                self._temps[slot_id] = request.temperature
-                self._topks[slot_id] = request.top_k
-                self._topps[slot_id] = request.top_p
-                self._pres[slot_id] = request.presence_penalty
-                self._freq[slot_id] = request.frequency_penalty
-                if request.first_token_time is None:
-                    request.first_token_time = now
-                    self._journey(request, "first-token")
-                self._emit_token(slot_id, int(next_np[i]), float(logprob_np[i]))
-                admitted_slots.append(slot_id)
-            self._m_tokens(len(batch))
-            self._flight_record(
-                "prefill", device_s=device_s, tokens=len(batch),
-                program=program,
-            )
-            await self._flush_emits(admitted_slots)
+            with self.flight.span(
+                "ls.prefill.fetch", seq=ticket["dispatch"]
+            ):
+                next_np, logprob_np, device_s = await loop.run_in_executor(
+                    self._executor, _run
+                )
+            with self.flight.span("ls.prefill.emit", rows=len(batch)):
+                if use_prefix:
+                    for slot_id, request, reuse in batch:
+                        if request.preemptions or request.adapter:
+                            # resumed contexts stay out of the prefix cache
+                            # (generated content is not a shareable prompt);
+                            # adapter contexts too — their KV is colored by
+                            # the adapter's projections (docs/ADAPTERS.md)
+                            continue
+                        self.block_mgr.register_prefix(
+                            slot_id, request.prompt_tokens
+                        )
+                        if reuse:
+                            self.prefix_hits += 1
+                            self.prefix_tokens += reuse
+                            self._m_prefix_hits(1)
+                            self._m_prefix_tokens(reuse)
+                now = time.monotonic()
+                admitted_slots = []
+                for i, (slot_id, request, _reuse) in enumerate(batch):
+                    self._lengths[slot_id] = len(request.context_tokens)
+                    self._current[slot_id] = int(next_np[i])
+                    self._temps[slot_id] = request.temperature
+                    self._topks[slot_id] = request.top_k
+                    self._topps[slot_id] = request.top_p
+                    self._pres[slot_id] = request.presence_penalty
+                    self._freq[slot_id] = request.frequency_penalty
+                    if request.first_token_time is None:
+                        request.first_token_time = now
+                        self._journey(request, "first-token")
+                    self._emit_token(slot_id, int(next_np[i]), float(logprob_np[i]))
+                    admitted_slots.append(slot_id)
+                self._m_tokens(len(batch))
+                self._flight_record(
+                    "prefill", device_s=device_s, tokens=len(batch),
+                    **ticket,
+                )
+            await self._flush_emits(admitted_slots, "ls.prefill.emit")
 
     def _process_chunk(
         self,
@@ -7285,87 +7419,96 @@ class TpuServingEngine:
         return self.config.stream_stall_s
 
     async def _deliver_chunk(
-        self, request: _Request, is_final: bool, now: float
+        self, request: _Request, is_final: bool, now: float,
+        span: str = "ls.decode.emit",
     ) -> None:
         """Deliver one committed decode chunk to the request's on_chunk
         consumer and record its telemetry. Runs at the burst-flush safe
         point between device dispatches — wait-free apart from awaiting
         the consumer itself (graftcheck STRM1501 polices this body the
         way OBS503 polices the emit hot loop)."""
-        if request.stream_closed:
-            return
-        if request.future.cancelled():
-            # the client is gone — deliver nothing; the finished drain
-            # records the stream-cancel evidence below
-            request.stream_closed = True
-            return
-        safe = self._stream_text(request, is_final)
-        delta = safe[request.stream_sent_chars:]
-        new_ids = request.generated[request.stream_sent_tokens:]
-        if not delta and not new_ids and not is_final:
-            return  # the holdback ate the whole chunk; nothing surfaced
-        request.stream_sent_chars = max(
-            request.stream_sent_chars, len(safe)
-        )
-        request.stream_sent_tokens = len(request.generated)
-        if request.stream_tbt is not None:
-            if request.stream_first_emit is None:
-                request.stream_first_emit = now
-                self._journey(request, "first-emit")
-            else:
-                interval = now - (request.stream_last_emit or now)
-                request.stream_tbt.add(interval)
-                digest = self._stream_tbt_by_class.get(request.priority)
-                if digest is None:
-                    digest = TbtDigest()
-                    self._stream_tbt_by_class[request.priority] = digest
-                digest.add(interval)
-                self._stream_tbt_hist(request.priority)(
-                    interval,
-                    request.journey_id
-                    if request.trace is not None
-                    else None,
-                )
-                threshold = self._stream_stall_threshold(request.priority)
-                if interval > threshold:
-                    request.stream_stalls += 1
-                    self.stream_stalls_total += 1
-                    self.flight.event(
-                        "stream-stall",
-                        request=request.journey_id,
-                        interval_s=round(interval, 6),
-                        threshold_s=threshold,
-                        priority=request.priority,
-                        tokens=len(request.generated),
-                    )
-            request.stream_last_emit = now
-            request.stream_emits += 1
-            self.stream_emits_total += 1
-        if is_final:
-            request.stream_closed = True
+        # everything but the consumer's own coroutine
+        with self.flight.span(span):
+            if request.stream_closed:
+                return
+            if request.future.cancelled():
+                # the client is gone — deliver nothing; the finished drain
+                # records the stream-cancel evidence below
+                request.stream_closed = True
+                return
+            safe = self._stream_text(request, is_final)
+            delta = safe[request.stream_sent_chars:]
+            new_ids = request.generated[request.stream_sent_tokens:]
+            if not delta and not new_ids and not is_final:
+                return  # the holdback ate the whole chunk; nothing surfaced
+            request.stream_sent_chars = max(
+                request.stream_sent_chars, len(safe)
+            )
+            request.stream_sent_tokens = len(request.generated)
             if request.stream_tbt is not None:
-                # ONE summarized event per stream, never one per chunk
-                # (a 4k-token stream would otherwise flood the ring)
-                summary = request.stream_tbt.summary()
-                self.flight.event(
-                    "stream-emit",
-                    request=request.journey_id,
-                    emits=request.stream_emits,
-                    tokens=len(request.generated),
-                    tbt_p50_s=summary["p50"],
-                    tbt_p99_s=summary["p99"],
-                    tbt_max_s=summary["max"],
-                    stalls=request.stream_stalls,
-                    priority=request.priority,
-                )
-                self._journey(
-                    request, "last-emit", emits=request.stream_emits
-                )
-        result = request.on_chunk(new_ids, delta, is_final)
+                if request.stream_first_emit is None:
+                    request.stream_first_emit = now
+                    self._journey(request, "first-emit")
+                else:
+                    interval = now - (request.stream_last_emit or now)
+                    request.stream_tbt.add(interval)
+                    digest = self._stream_tbt_by_class.get(request.priority)
+                    if digest is None:
+                        digest = TbtDigest()
+                        self._stream_tbt_by_class[request.priority] = digest
+                    digest.add(interval)
+                    self._stream_tbt_hist(request.priority)(
+                        interval,
+                        request.journey_id
+                        if request.trace is not None
+                        else None,
+                    )
+                    threshold = self._stream_stall_threshold(request.priority)
+                    if interval > threshold:
+                        request.stream_stalls += 1
+                        self.stream_stalls_total += 1
+                        self.flight.event(
+                            "stream-stall",
+                            request=request.journey_id,
+                            interval_s=round(interval, 6),
+                            threshold_s=threshold,
+                            priority=request.priority,
+                            tokens=len(request.generated),
+                        )
+                request.stream_last_emit = now
+                request.stream_emits += 1
+                self.stream_emits_total += 1
+            if is_final:
+                request.stream_closed = True
+                if request.stream_tbt is not None:
+                    # ONE summarized event per stream, never one per chunk
+                    # (a 4k-token stream would otherwise flood the ring)
+                    summary = request.stream_tbt.summary()
+                    self.flight.event(
+                        "stream-emit",
+                        request=request.journey_id,
+                        emits=request.stream_emits,
+                        tokens=len(request.generated),
+                        tbt_p50_s=summary["p50"],
+                        tbt_p99_s=summary["p99"],
+                        tbt_max_s=summary["max"],
+                        stalls=request.stream_stalls,
+                        priority=request.priority,
+                    )
+                    self._journey(
+                        request, "last-emit", emits=request.stream_emits
+                    )
+            result = request.on_chunk(new_ids, delta, is_final)
         if asyncio.iscoroutine(result):
             await result
 
-    async def _flush_emits(self, active: list[int]) -> None:
+    async def _flush_emits(
+        self, active: list[int], span: str = "ls.decode.emit"
+    ) -> None:
+        """Deliver what the burst committed and settle finished requests.
+        ``span`` names the host spans around the synchronous parts (a
+        prefill's first tokens pass ``ls.prefill.emit``); the consumers'
+        own coroutines run outside any span."""
         emits, self._pending_emits = self._pending_emits, []
         # per-request chunk grouping, first-appearance order: on_token
         # subscribers keep exact per-token delivery; on_chunk subscribers
@@ -7388,240 +7531,241 @@ class TpuServingEngine:
             # client observes, so inter-EMIT gaps are what TBT digests
             now = time.monotonic()
             for request, done in chunks.values():
-                await self._deliver_chunk(request, done, now)
-        # decode-pool first-step edge: the first NEW token after a KV
-        # import closes the decode-admission segment (the emits list
-        # above only carries on_token subscribers; imported handoffs
-        # stream nothing, so the finished/slot scan below is the spot
-        # that sees every request). One attribute check per emit batch.
-        for slot in self.slots:
-            request = slot.request
-            if (
-                request is not None
-                and request.imported
-                and not request.first_step_noted
-                and len(request.generated) > request.import_base_tokens
-            ):
-                request.first_step_noted = True
-                self._journey(request, "first-step")
-        finished, self._finished_requests = self._finished_requests, []
-        for request, is_eos in finished:
-            # tenant tokens/s accounting (QoS post-debit): cancelled
-            # requests debit too — their tokens burned engine capacity
-            self.scheduler.on_finished(request)
-            # crash-requeue journal: the request is ANSWERED (result,
-            # cancellation — either way nothing is left to replay)
-            self._journal_retire(request)
-            if request.imported and not request.first_step_noted:
-                # finished inside its first emit batch: the slot is
-                # already released, so the scan above never saw it
-                request.first_step_noted = True
-                self._journey(request, "first-step")
-            if request.future.cancelled():
-                # aborted by the caller: not a served request — keep it out
-                # of the request-rate/TTFT metrics (a disconnect storm must
-                # not read as healthy throughput) and skip the decode
-                if request.on_chunk is not None and self.config.streaming:
-                    # disconnect-as-cancellation evidence: the slot was
-                    # freed in _emit_token's done branch, i.e. within one
-                    # chunk boundary of the cancel landing. tokens_wasted
-                    # is the decode work nobody consumed (generated but
-                    # never delivered — the engine-visible waste).
-                    self.stream_cancels_total += 1
-                    self.stream_reclaims_total += 1
-                    self.flight.event(
-                        "stream-cancel",
-                        request=request.journey_id,
-                        tokens_generated=len(request.generated),
-                        tokens_delivered=request.stream_sent_tokens,
-                        tokens_wasted=(
-                            len(request.generated)
-                            - request.stream_sent_tokens
-                        ),
-                        emits=request.stream_emits,
-                        priority=request.priority,
-                        tenant=request.tenant,
-                        slot_reclaimed=True,
-                    )
-                self._journey(request, "cancelled")
-                continue
-            self.completed_requests += 1
-            self._m_requests()
-            if request.first_token_time is not None:
-                self._m_ttft(request.first_token_time - request.enqueue_time)
-            # OpenAI semantics: the stop match itself is excluded. The
-            # token list keeps every generated token (they are in the
-            # KV cache and were streamed); only the text truncates. The
-            # find runs on the FINAL decode (the detection window can
-            # render boundary chars differently) — shared with the
-            # streaming final chunk so deltas concatenate to this exact
-            # string.
-            text = self._final_text(request)
-            done_t = time.monotonic()
-            first = request.first_token_time or done_t
-            admit = request.admit_time or first
-            if request.deadline is not None:
-                # the deadline acceptance's second half: a request that
-                # completes PAST its budget still answers (the work is
-                # done; discarding it helps nobody) but the overrun is
-                # recorded — never a silent late completion
-                overrun = time.time() - request.deadline  # graftcheck: disable=OBS501 deadline overrun compares epoch stamps, not a latency
-                if overrun > 0:
-                    self.deadline_overruns += 1
-                    self.flight.event(
-                        "deadline-overrun",
-                        overrun_s=round(overrun, 6),
-                        tokens=len(request.generated),
-                        tenant=request.tenant,
-                    )
-                    self._journey(
-                        request, "deadline-overrun",
-                        overrun_s=round(overrun, 6),
-                    )
-            timing = {
-                "queue_wait": admit - request.enqueue_time,
-                "prefill": first - admit,
-                "ttft": first - request.enqueue_time,
-                # decode phase + its step count: the bench derives achieved
-                # step time from these (EOS can end a request well before
-                # max_tokens, so the client can't know the step count)
-                "decode": done_t - first,
-                "tokens": float(len(request.generated)),
-            }
-            if request.imported:
-                # KV-import admission skipped prefill entirely: the
-                # marker the disagg acceptance asserts on (queue_wait/
-                # prefill here are decode-pod-local and ~0 by design —
-                # the prefill pool's share rode the handoff header)
-                timing["imported"] = 1.0
-            if request.stream_tbt is not None and request.stream_tbt.count:
-                # bounded TBT record (p50/p99/max + count, NEVER the raw
-                # interval list): what the gateway bench and perf_diff
-                # read off request_timings
-                summary = request.stream_tbt.summary()
-                timing["tbt_p50"] = summary["p50"]
-                timing["tbt_p99"] = summary["p99"]
-                timing["tbt_max"] = summary["max"]
-                timing["tbt_count"] = float(summary["count"])
-            if not request.warmup:
-                # warmup probes never enter the latency record: their TTFT
-                # is XLA compile time, which would poison both the
-                # cumulative histograms and the bench's request_timings
-                # decomposition (a warmup_on_start engine created lazily
-                # inside the measured window)
-                self.request_timings.append(timing)
-                # exemplar: a traced request's journey id rides the TTFT
-                # bucket it lands in (None for untraced traffic — the
-                # default scrape stays byte-identical)
-                self._m_ttft_hist(
-                    timing["ttft"],
-                    request.journey_id
-                    if request.trace is not None
-                    else None,
-                )
-                self._m_queue_wait_hist(timing["queue_wait"])
-                # SLO evidence (no-ops without a declared objective): a
-                # served request is availability-good, and the tracker
-                # judges the measured latencies against the declared
-                # thresholds
-                self._slo_record("availability", True)
-                self._slo_record_latency("ttft", timing["ttft"])
-                self._slo_record_latency("queue-wait", timing["queue_wait"])
+                await self._deliver_chunk(request, done, now, span)
+        with self.flight.span(span, frames=len(chunks)):
+            # decode-pool first-step edge: the first NEW token after a KV
+            # import closes the decode-admission segment (the emits list
+            # above only carries on_token subscribers; imported handoffs
+            # stream nothing, so the finished/slot scan below is the spot
+            # that sees every request). One attribute check per emit batch.
+            for slot in self.slots:
+                request = slot.request
                 if (
-                    request.stream_tbt is not None
-                    and request.stream_tbt.count
+                    request is not None
+                    and request.imported
+                    and not request.first_step_noted
+                    and len(request.generated) > request.import_base_tokens
                 ):
-                    # one tbt event per finished stream: the request's
-                    # own p99 inter-emit interval, judged against (a)
-                    # the engine-wide slo.tbt objective when declared
-                    # and (b) the class's tbt-p99-s burn tracker — the
-                    # health() tbt_burn predicate reads the latter
-                    p99 = request.stream_tbt.quantile(0.99)
-                    self._slo_record_latency("tbt", p99)
-                    tracker = self._stream_slo.get(request.priority)
-                    if tracker is not None:
-                        verdict = tracker.record_latency(
-                            "tbt", p99 * 1000.0
+                    request.first_step_noted = True
+                    self._journey(request, "first-step")
+            finished, self._finished_requests = self._finished_requests, []
+            for request, is_eos in finished:
+                # tenant tokens/s accounting (QoS post-debit): cancelled
+                # requests debit too — their tokens burned engine capacity
+                self.scheduler.on_finished(request)
+                # crash-requeue journal: the request is ANSWERED (result,
+                # cancellation — either way nothing is left to replay)
+                self._journal_retire(request)
+                if request.imported and not request.first_step_noted:
+                    # finished inside its first emit batch: the slot is
+                    # already released, so the scan above never saw it
+                    request.first_step_noted = True
+                    self._journey(request, "first-step")
+                if request.future.cancelled():
+                    # aborted by the caller: not a served request — keep it out
+                    # of the request-rate/TTFT metrics (a disconnect storm must
+                    # not read as healthy throughput) and skip the decode
+                    if request.on_chunk is not None and self.config.streaming:
+                        # disconnect-as-cancellation evidence: the slot was
+                        # freed in _emit_token's done branch, i.e. within one
+                        # chunk boundary of the cancel landing. tokens_wasted
+                        # is the decode work nobody consumed (generated but
+                        # never delivered — the engine-visible waste).
+                        self.stream_cancels_total += 1
+                        self.stream_reclaims_total += 1
+                        self.flight.event(
+                            "stream-cancel",
+                            request=request.journey_id,
+                            tokens_generated=len(request.generated),
+                            tokens_delivered=request.stream_sent_tokens,
+                            tokens_wasted=(
+                                len(request.generated)
+                                - request.stream_sent_tokens
+                            ),
+                            emits=request.stream_emits,
+                            priority=request.priority,
+                            tenant=request.tenant,
+                            slot_reclaimed=True,
                         )
-                        if verdict is not None and verdict["transition"]:
-                            self.flight.event(
-                                "alert",
-                                objective=f"tbt:{request.priority}",
-                                state=(
-                                    "firing"
-                                    if verdict["alerting"]
-                                    else "resolved"
-                                ),
-                                burn_rate_fast=verdict["burn_rate_fast"],
-                                burn_rate_slow=verdict["burn_rate_slow"],
-                                budget_remaining=verdict[
-                                    "budget_remaining"
-                                ],
-                                target=verdict["target"],
+                    self._journey(request, "cancelled")
+                    continue
+                self.completed_requests += 1
+                self._m_requests()
+                if request.first_token_time is not None:
+                    self._m_ttft(request.first_token_time - request.enqueue_time)
+                # OpenAI semantics: the stop match itself is excluded. The
+                # token list keeps every generated token (they are in the
+                # KV cache and were streamed); only the text truncates. The
+                # find runs on the FINAL decode (the detection window can
+                # render boundary chars differently) — shared with the
+                # streaming final chunk so deltas concatenate to this exact
+                # string.
+                text = self._final_text(request)
+                done_t = time.monotonic()
+                first = request.first_token_time or done_t
+                admit = request.admit_time or first
+                if request.deadline is not None:
+                    # the deadline acceptance's second half: a request that
+                    # completes PAST its budget still answers (the work is
+                    # done; discarding it helps nobody) but the overrun is
+                    # recorded — never a silent late completion
+                    overrun = time.time() - request.deadline  # graftcheck: disable=OBS501 deadline overrun compares epoch stamps, not a latency
+                    if overrun > 0:
+                        self.deadline_overruns += 1
+                        self.flight.event(
+                            "deadline-overrun",
+                            overrun_s=round(overrun, 6),
+                            tokens=len(request.generated),
+                            tenant=request.tenant,
+                        )
+                        self._journey(
+                            request, "deadline-overrun",
+                            overrun_s=round(overrun, 6),
+                        )
+                timing = {
+                    "queue_wait": admit - request.enqueue_time,
+                    "prefill": first - admit,
+                    "ttft": first - request.enqueue_time,
+                    # decode phase + its step count: the bench derives achieved
+                    # step time from these (EOS can end a request well before
+                    # max_tokens, so the client can't know the step count)
+                    "decode": done_t - first,
+                    "tokens": float(len(request.generated)),
+                }
+                if request.imported:
+                    # KV-import admission skipped prefill entirely: the
+                    # marker the disagg acceptance asserts on (queue_wait/
+                    # prefill here are decode-pod-local and ~0 by design —
+                    # the prefill pool's share rode the handoff header)
+                    timing["imported"] = 1.0
+                if request.stream_tbt is not None and request.stream_tbt.count:
+                    # bounded TBT record (p50/p99/max + count, NEVER the raw
+                    # interval list): what the gateway bench and perf_diff
+                    # read off request_timings
+                    summary = request.stream_tbt.summary()
+                    timing["tbt_p50"] = summary["p50"]
+                    timing["tbt_p99"] = summary["p99"]
+                    timing["tbt_max"] = summary["max"]
+                    timing["tbt_count"] = float(summary["count"])
+                if not request.warmup:
+                    # warmup probes never enter the latency record: their TTFT
+                    # is XLA compile time, which would poison both the
+                    # cumulative histograms and the bench's request_timings
+                    # decomposition (a warmup_on_start engine created lazily
+                    # inside the measured window)
+                    self.request_timings.append(timing)
+                    # exemplar: a traced request's journey id rides the TTFT
+                    # bucket it lands in (None for untraced traffic — the
+                    # default scrape stays byte-identical)
+                    self._m_ttft_hist(
+                        timing["ttft"],
+                        request.journey_id
+                        if request.trace is not None
+                        else None,
+                    )
+                    self._m_queue_wait_hist(timing["queue_wait"])
+                    # SLO evidence (no-ops without a declared objective): a
+                    # served request is availability-good, and the tracker
+                    # judges the measured latencies against the declared
+                    # thresholds
+                    self._slo_record("availability", True)
+                    self._slo_record_latency("ttft", timing["ttft"])
+                    self._slo_record_latency("queue-wait", timing["queue_wait"])
+                    if (
+                        request.stream_tbt is not None
+                        and request.stream_tbt.count
+                    ):
+                        # one tbt event per finished stream: the request's
+                        # own p99 inter-emit interval, judged against (a)
+                        # the engine-wide slo.tbt objective when declared
+                        # and (b) the class's tbt-p99-s burn tracker — the
+                        # health() tbt_burn predicate reads the latter
+                        p99 = request.stream_tbt.quantile(0.99)
+                        self._slo_record_latency("tbt", p99)
+                        tracker = self._stream_slo.get(request.priority)
+                        if tracker is not None:
+                            verdict = tracker.record_latency(
+                                "tbt", p99 * 1000.0
                             )
-                            if verdict["alerting"]:
-                                # the streaming SLO paged: capture at the
-                                # breach, keyed per class so one flapping
-                                # class can't spam (cooldown + dedup in
-                                # the recorder; no-op without
-                                # incident-dir)
-                                self._incident_capture(
-                                    "tbt-burn",
-                                    {
-                                        "source": "stream-slo",
-                                        "objective": (
-                                            f"tbt:{request.priority}"
-                                        ),
-                                        "tbt_p99_s": p99,
-                                        "burn_rate_fast": verdict[
-                                            "burn_rate_fast"
-                                        ],
-                                        "budget_remaining": verdict[
-                                            "budget_remaining"
-                                        ],
-                                        "target": verdict["target"],
-                                    },
-                                    dedup_key=request.priority,
+                            if verdict is not None and verdict["transition"]:
+                                self.flight.event(
+                                    "alert",
+                                    objective=f"tbt:{request.priority}",
+                                    state=(
+                                        "firing"
+                                        if verdict["alerting"]
+                                        else "resolved"
+                                    ),
+                                    burn_rate_fast=verdict["burn_rate_fast"],
+                                    burn_rate_slow=verdict["burn_rate_slow"],
+                                    budget_remaining=verdict[
+                                        "budget_remaining"
+                                    ],
+                                    target=verdict["target"],
                                 )
-            self._journey(
-                request, "finish",
-                reason=(
-                    "stop" if is_eos or request.stop_matched else "length"
-                ),
-                tokens=len(request.generated),
-                model=self.config.model,
-            )
-            if request.trace is not None:
-                # materialize the request's phases as child spans from the
-                # timestamps above — no extra clocks in the decode loop,
-                # and record_span never raises into the serving path
-                svc = f"engine:{self.config.model}"
-                record_span("engine.queue", svc, request.trace,
-                            request.enqueue_time, admit)
-                record_span("engine.prefill", svc, request.trace, admit, first,
-                            attributes={
-                                "prompt-tokens": len(request.prompt_tokens)
-                            })
-                record_span("engine.decode", svc, request.trace, first, done_t,
-                            attributes={"tokens": len(request.generated)})
-            if not request.future.done():
-                request.future.set_result(
-                    {
-                        "tokens": request.generated,
-                        "text": text,
-                        "logprobs": request.logprobs,
-                        "num_prompt_tokens": len(request.prompt_tokens),
-                        "num_completion_tokens": len(request.generated),
-                        "ttft": timing["ttft"],
-                        "queue_wait": timing["queue_wait"],
-                        "prefill": timing["prefill"],
-                        "finish_reason": (
-                            "stop"
-                            if is_eos or request.stop_matched
-                            else "length"
-                        ),
-                    }
+                                if verdict["alerting"]:
+                                    # the streaming SLO paged: capture at the
+                                    # breach, keyed per class so one flapping
+                                    # class can't spam (cooldown + dedup in
+                                    # the recorder; no-op without
+                                    # incident-dir)
+                                    self._incident_capture(
+                                        "tbt-burn",
+                                        {
+                                            "source": "stream-slo",
+                                            "objective": (
+                                                f"tbt:{request.priority}"
+                                            ),
+                                            "tbt_p99_s": p99,
+                                            "burn_rate_fast": verdict[
+                                                "burn_rate_fast"
+                                            ],
+                                            "budget_remaining": verdict[
+                                                "budget_remaining"
+                                            ],
+                                            "target": verdict["target"],
+                                        },
+                                        dedup_key=request.priority,
+                                    )
+                self._journey(
+                    request, "finish",
+                    reason=(
+                        "stop" if is_eos or request.stop_matched else "length"
+                    ),
+                    tokens=len(request.generated),
+                    model=self.config.model,
                 )
+                if request.trace is not None:
+                    # materialize the request's phases as child spans from the
+                    # timestamps above — no extra clocks in the decode loop,
+                    # and record_span never raises into the serving path
+                    svc = f"engine:{self.config.model}"
+                    record_span("engine.queue", svc, request.trace,
+                                request.enqueue_time, admit)
+                    record_span("engine.prefill", svc, request.trace, admit, first,
+                                attributes={
+                                    "prompt-tokens": len(request.prompt_tokens)
+                                })
+                    record_span("engine.decode", svc, request.trace, first, done_t,
+                                attributes={"tokens": len(request.generated)})
+                if not request.future.done():
+                    request.future.set_result(
+                        {
+                            "tokens": request.generated,
+                            "text": text,
+                            "logprobs": request.logprobs,
+                            "num_prompt_tokens": len(request.prompt_tokens),
+                            "num_completion_tokens": len(request.generated),
+                            "ttft": timing["ttft"],
+                            "queue_wait": timing["queue_wait"],
+                            "prefill": timing["prefill"],
+                            "finish_reason": (
+                                "stop"
+                                if is_eos or request.stop_matched
+                                else "length"
+                            ),
+                        }
+                    )
 
 
 def flight_report(

@@ -71,6 +71,16 @@ Hot-path discipline (graftcheck rule OBS503 gates this): the record path
 is append-only on GIL-atomic deques — **no locks, no I/O, nothing that can
 block the engine loop**. Rollups snapshot with ``list(deque)``.
 
+Host **spans** (:meth:`FlightRecorder.span`) mark the same dispatch
+boundaries on the profiler's clock: a ``jax.profiler.TraceAnnotation`` and
+nothing else. With no profiler session a span records nothing and leaves the
+recorder untouched; under one (``/profile/start``, the benchmark's
+``--trace 1``) it lands in the ``/host:CPU`` plane of the same
+``.xplane.pb`` as the device operations, where ``bench/lib/hosttrace.py``
+lays it against the device's idle gaps. The vocabulary is :data:`SPANS`
+(docs/OBSERVABILITY.md, "Device profiling"); a dispatch's ``seq`` is the
+``dispatch`` field of its flight sample.
+
 Sizing: ``LS_TPU_FLIGHT_BUFFER`` samples (default 4096, min 64). Cumulative
 totals (wall/device/host/stall, per-phase step counts, stall seconds by
 reason, token counts) are plain counters maintained alongside the ring, so
@@ -101,6 +111,22 @@ STALL_REASONS = (
 
 #: dispatch phases (a "stall" sample is the fifth, non-dispatch kind)
 PHASES = ("prefill", "decode", "verify")
+
+#: host spans the engine loop opens (the whole vocabulary; every name a
+#: reader of the profile may meet)
+SPANS = (
+    "ls.admit",
+    "ls.prefill.pack",
+    "ls.prefill.dispatch",
+    "ls.prefill.fetch",
+    "ls.prefill.emit",
+    "ls.decode.prepare",
+    "ls.decode.dispatch",
+    "ls.decode.fetch",
+    "ls.decode.process",
+    "ls.decode.emit",
+    "ls.idle",
+)
 
 
 def _buffer_size() -> int:
@@ -161,6 +187,17 @@ class FlightRecorder:
         after a long construction gap, so the gap isn't billed as host)."""
         self._last_mark = time.monotonic()
 
+    @staticmethod
+    def span(name: str, **meta: Any):
+        """A host span on the profiler's clock: a context manager around
+        synchronous code (or the one ``await`` a ``*.fetch`` span names).
+        ``name`` is one of :data:`SPANS`; ``meta`` become the event's
+        stats. Outside a profiler session this checks a flag and records
+        nothing."""
+        from jax.profiler import TraceAnnotation
+
+        return TraceAnnotation(name, **meta)
+
     def sample(
         self,
         phase: str,
@@ -177,6 +214,9 @@ class FlightRecorder:
         spec_rejected: int = 0,
         queue_by_class: dict[str, int] | None = None,
         program: str | None = None,
+        dispatch: int | None = None,
+        steps: int = 0,
+        active_at_dispatch: int | None = None,
     ) -> dict[str, Any]:
         """Record one dispatched burst. ``wall`` is the time since the
         previous boundary. ``overlapped_s`` is host work the pipelined
@@ -189,7 +229,12 @@ class FlightRecorder:
         omitted when None. ``program`` keys the sample by the compiled
         program variant that ran (the attribution ledger's id,
         serving/attribution.py) — omitted when unknown so pre-attribution
-        consumers see an unchanged schema."""
+        consumers see an unchanged schema. ``dispatch`` (the ordinal the
+        engine gave the dispatch, the ``seq`` of its host spans), ``steps``
+        (decode steps it fused; 0 for a prefill) and ``active_at_dispatch``
+        (slots running when it was dispatched) were taken at dispatch, not
+        at this later boundary where ``occupancy`` is read; omitted
+        together when the caller has no dispatch to name."""
         now = time.monotonic()
         wall_ms = (now - self._last_mark) * 1000.0
         self._last_mark = now
@@ -224,6 +269,10 @@ class FlightRecorder:
             entry["queue_by_class"] = dict(queue_by_class)
         if program is not None:
             entry["program"] = program
+        if dispatch is not None:
+            entry["dispatch"] = dispatch
+            entry["steps"] = steps
+            entry["active_at_dispatch"] = active_at_dispatch
         self._samples.append(entry)
         self.recorded += 1
         self.wall_ms += wall_ms

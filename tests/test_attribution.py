@@ -6,8 +6,7 @@ sums-to-detected-limit invariant (unit and on a live CPU engine), the
 ``/attribution``/``/memory`` pod endpoints and their acceptance shape
 (≥ 3 registered programs with expected bytes, measured p50, and
 achieved-vs-expected), the control-plane scoping, the
-``tools/trace_attrib.py`` golden fixture, the ``tools/perf_diff.py``
-regression sentry (an injected 30% step-time regression flags exactly
+``tools/perf_diff.py`` regression sentry (an injected 30% step-time regression flags exactly
 that metric; identical rollups stay quiet), and the ``engine_top``
 attribution panels + degraded-program flag."""
 
@@ -340,48 +339,6 @@ def test_dev_attribution_scoped_to_declared_models(monkeypatch):
     assert [e["model"] for e in compute.attribution("t", "app")] == ["tiny"]
     assert compute.attribution("t", "plain") == []
     assert compute.attribution("t", "ghost") == []
-
-
-# --------------------------------------------------------------------------
-# tools/trace_attrib.py: golden fixture
-# --------------------------------------------------------------------------
-
-_FIXTURE = (
-    Path(__file__).resolve().parent / "fixtures"
-    / "mini_trace.trace.json.gz"
-)
-
-
-def test_trace_attrib_golden_fixture():
-    trace_attrib = _load_tool("trace_attrib")
-    agg = trace_attrib.bucket_events(
-        trace_attrib._load_trace(str(_FIXTURE))
-    )
-    rep = trace_attrib.report(agg)
-    buckets = rep["buckets"]
-    # hand-pinned against the checked-in fixture's event durations (µs)
-    assert rep["total_device_ms"] == pytest.approx(8.6)
-    assert buckets["attention"]["device_ms"] == pytest.approx(3.0)
-    assert buckets["mlp"]["device_ms"] == pytest.approx(4.0)
-    assert buckets["collectives"]["device_ms"] == pytest.approx(0.5)
-    assert buckets["sampling"]["device_ms"] == pytest.approx(0.75)
-    assert buckets["copies"]["device_ms"] == pytest.approx(0.25)
-    assert buckets["other"]["device_ms"] == pytest.approx(0.1)
-    # the host lane (pid 2, a 100s python_sleep) is excluded by the
-    # device-pid filter — its inclusion would swamp every bucket
-    assert buckets["attention"]["events"] == 2
-    top = buckets["mlp"]["top_ops"]
-    assert top[0]["name"] == "dot_general.7"
-    # text renderer smoke
-    assert "attention" in trace_attrib.render(rep)
-
-
-def test_trace_attrib_cli_on_fixture(capsys):
-    trace_attrib = _load_tool("trace_attrib")
-    assert trace_attrib.main([str(_FIXTURE), "--json"]) == 0
-    out = json.loads(capsys.readouterr().out)
-    assert out["total_device_ms"] == pytest.approx(8.6)
-    assert trace_attrib.main(["/nonexistent/dir"]) == 2
 
 
 # --------------------------------------------------------------------------

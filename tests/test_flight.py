@@ -132,6 +132,42 @@ def test_events_ring_and_counters():
     assert kinds == ["recompile", "pool-grow", "warmup"]
 
 
+def test_a_span_without_a_profiler_session_leaves_the_recorder_untouched():
+    """The span helper is a profiler annotation and nothing else: with no
+    session it records nothing, and it never touches the recorder's rings,
+    counters or timeline boundary."""
+    from langstream_tpu.serving.flight import SPANS
+
+    recorder = FlightRecorder(slots=4)
+    recorder.sample("decode", device_s=0.001, tokens=4)
+    before = {k: (list(v) if hasattr(v, "append") else
+                  dict(v) if isinstance(v, dict) else v)
+              for k, v in vars(recorder).items()}
+    for name in SPANS:
+        with recorder.span(name, seq=7, program="decode:w128:k4:greedy"):
+            pass
+    after = {k: (list(v) if hasattr(v, "append") else
+                 dict(v) if isinstance(v, dict) else v)
+             for k, v in vars(recorder).items()}
+    assert after == before
+    assert recorder.recorded == 1 and len(recorder.recent(0)) == 1
+
+
+@pytest.mark.parametrize("fields, expected", [
+    (dict(dispatch=9, steps=32, active_at_dispatch=61),
+     {"dispatch": 9, "steps": 32, "active_at_dispatch": 61}),
+    (dict(dispatch=10, active_at_dispatch=3),           # a prefill: no steps
+     {"dispatch": 10, "steps": 0, "active_at_dispatch": 3}),
+    (dict(), {}),                       # no dispatch named: schema unchanged
+])
+def test_sample_carries_what_the_dispatch_knew(fields, expected):
+    recorder = FlightRecorder(slots=64)
+    entry = recorder.sample("decode", device_s=0.001, occupancy=58, **fields)
+    assert {k: entry[k] for k in ("dispatch", "steps", "active_at_dispatch")
+            if k in entry} == expected
+    assert entry["occupancy"] == 58     # stays what it was for its readers
+
+
 def test_bench_rollup_carries_the_record_keys():
     recorder = FlightRecorder(slots=2, maxlen=32)
     recorder.sample("decode", device_s=0.001, tokens=8, stall="no-kv-blocks")
@@ -216,6 +252,46 @@ def test_paged_engine_under_load_decomposes_wall_time(run_async):
             await engine.close()
 
     run_async(main())
+
+
+def test_engine_samples_name_their_dispatch(run_async):
+    """Decode and prefill samples carry the dispatch's ordinal, the steps
+    it fused and the slots running when it was made: taken at dispatch and
+    carried to the sample, which is recorded when the result is processed
+    (after finished slots were freed)."""
+    from langstream_tpu.serving.engine import ServingConfig, TpuServingEngine
+
+    async def main():
+        engine = TpuServingEngine(
+            ServingConfig(
+                model="tiny", slots=4, max_seq_len=128, decode_chunk=4,
+                kv_layout="paged", prefix_cache=False,
+            )
+        )
+        try:
+            await asyncio.gather(*(
+                engine.generate(f"dispatch fields {i}", {"max-tokens": 9})
+                for i in range(4)
+            ))
+        finally:
+            await engine.close()
+        return engine.flight.recent(0)
+
+    samples = [s for s in run_async(main()) if s["phase"] != "stall"]
+    assert samples and all("dispatch" in s for s in samples)
+    ordinals = [s["dispatch"] for s in samples]
+    assert len(set(ordinals)) == len(ordinals)        # one sample a dispatch
+    decode = [s for s in samples if s["phase"] == "decode"]
+    prefill = [s for s in samples if s["phase"] == "prefill"]
+    assert decode and prefill
+    assert all(s["steps"] == 0 for s in prefill)
+    for s in decode:
+        # the steps are the program's own chunk size
+        assert f":k{s['steps']}:" in s["program"] and s["steps"] > 0
+        assert 1 <= s["active_at_dispatch"] <= 4
+    # a chunk in which requests finish is recorded after their slots were
+    # freed: what was running at dispatch is the larger number
+    assert any(s["active_at_dispatch"] > s["occupancy"] for s in decode)
 
 
 def test_timeline_mark_recompile_events_and_idle_stall(run_async):

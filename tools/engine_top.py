@@ -904,7 +904,7 @@ def _degraded_programs(programs: list, min_dispatches: int = 8) -> list[str]:
                 f"across the dump ({program.get('dispatches')} dispatches, "
                 f"measured p50 {program.get('measured_ms_p50')}ms) — this "
                 f"program owns a disproportionate share of the device gap; "
-                f"profile it (tools/trace_attrib.py over a /profile "
+                f"profile it (bench/lib/hosttrace.py over a /profile "
                 f"capture) before blaming the blended roofline"
             )
     return flags
