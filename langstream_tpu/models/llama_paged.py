@@ -708,20 +708,23 @@ def llama_decode_chunk_paged(
     pen = sample_extras is not None
     counts0 = sample_extras[2] if pen else None
 
-    def _kernel_partial(q, ck_l, cv_l, tables, lengths, kv_heads):
+    def _kernel_partial(q, pk, pv, layer_idx, tables, lengths, kv_heads):
         return paged_attention_partial(
-            q, ck_l, cv_l, tables, lengths,
+            q, pk, pv, layer_idx, tables, lengths,
             num_read_blocks=num_read_blocks,
             kv_heads=kv_heads, head_dim=c.head_dim,
             scale=1.0 / math.sqrt(c.head_dim),
             interpret=(kernel == "pallas-interpret"),
         )
 
-    def cache_partial(q, ck_l, cv_l):
+    def cache_partial(q, kv_l):
         if kernel == "xla":
+            ck_l, cv_l = kv_l
             return _cache_partial_xla(
                 c, q, ck_l, cv_l, block_tables, base_lengths, num_read_blocks
             )
+        # the Pallas read takes the stacked pool where it lies (read-only
+        # for the whole chunk) and the layer's index: no slice of it exists
         if mesh is not None and len(mesh.devices.flatten()) > 1:
             # pallas_call has no SPMD rule: shared mesh wrapper — slots on
             # dp, heads on tp, per-axis degradation
@@ -734,9 +737,10 @@ def llama_decode_chunk_paged(
                 kv_heads=c.kv_heads, batch=B,
                 q_spec_tail=("tp", None),                  # (B, H, D)
                 out_spec_tails=(("tp", None), ("tp",), ("tp",)),
-            )(q, ck_l, cv_l, block_tables, base_lengths)
+                stacked_pool=True,
+            )(q, pool_k, pool_v, kv_l, block_tables, base_lengths)
         return _kernel_partial(
-            q, ck_l, cv_l, block_tables, base_lengths, c.kv_heads
+            q, pool_k, pool_v, kv_l, block_tables, base_lengths, c.kv_heads
         )
 
     def step(carry, step_idx):
@@ -756,10 +760,7 @@ def llama_decode_chunk_paged(
         G = c.heads // c.kv_heads
 
         def layer(x, layer_in):
-            if adapters is None:
-                lp, ck_l, cv_l, kbuf_l, vbuf_l = layer_in
-            else:
-                lp, al, ck_l, cv_l, kbuf_l, vbuf_l = layer_in
+            lp, al, kv_l, kbuf_l, vbuf_l = layer_in
             with jax.named_scope("attn_qkv"):
                 h = _rms_norm(x, lp["attn_norm"], c.norm_eps)
                 q = h @ _w(lp["wq"])
@@ -783,7 +784,7 @@ def llama_decode_chunk_paged(
                 )
             with jax.named_scope("kv_read"):
                 # segment 1: paged pool (partial stats)
-                acc_c, m_c, l_c = cache_partial(q, ck_l, cv_l)
+                acc_c, m_c, l_c = cache_partial(q, kv_l)
                 # segment 2: in-chunk buffer (partial stats, tiny)
                 qg = q.reshape(B, c.kv_heads, G, c.head_dim)
                 s_buf = jnp.einsum("bkgd,btkd->bkgt", qg, kbuf_l).astype(jnp.float32)
@@ -819,10 +820,12 @@ def llama_decode_chunk_paged(
             return x, (kbuf_l, vbuf_l)
 
         layer_xs = (
-            (params["layers"], pool_k, pool_v, kbuf, vbuf)
-            if adapters is None
-            else (params["layers"], adapters["layers"], pool_k, pool_v,
-                  kbuf, vbuf)
+            params["layers"],
+            None if adapters is None else adapters["layers"],
+            # the XLA read has the scan slice the layer's pool; the Pallas
+            # read is handed the layer's index and closes over the pool
+            (pool_k, pool_v) if kernel == "xla" else jnp.arange(c.layers),
+            kbuf, vbuf,
         )
         x, (kbuf, vbuf) = jax.lax.scan(layer, x, layer_xs)
         with jax.named_scope("lm_head"):

@@ -1,10 +1,12 @@
-"""Paged-attention decode kernel (Pallas TPU).
+"""Paged-attention decode kernels (Pallas TPU).
 
 One decode step reads each slot's KV *blocks* straight out of the shared
-pool — the block table rides in as a scalar-prefetch argument, so each grid
-step's ``index_map`` picks the right pool block to DMA into VMEM. No
+pool. The single-query read (:func:`paged_attention_partial`) takes the
+layer-stacked pool as it lies in HBM and fetches, with its own DMAs, exactly
+the blocks a slot's length makes live: no per-layer slice of the pool, no
 densified gather copy (the XLA reference path :func:`gather_kv` pays one),
-no ``slots × max_seq`` layout anywhere.
+no ``slots × max_seq`` layout anywhere, and no visit to a table column past
+a slot's length.
 
 Online softmax over the block sweep, same discipline as
 ``flash_attention.py``. The kernel returns *partial* results
@@ -13,17 +15,28 @@ because decode attends over two segments: the paged cache (here) and the
 in-chunk KV buffer (tiny, handled in XLA). The caller merges the two with
 the standard online-softmax combine (``merge_partial_attention``).
 
-Shapes (one layer; the layer loop lives in the model's ``lax.scan``):
+Shapes (the layer loop lives in the model's ``lax.scan``, which hands the
+kernel the layer's index and closes over the pool):
   q             (B, H, D)
-  k_pool/v_pool (nb, bs, Kh*D)
-  block_tables  (B, max_blocks) int32   [scalar prefetch]
-  lengths       (B,) int32              [scalar prefetch]
+  k_pool/v_pool (L, nb, bs, Kh*D)         [stay in HBM]
+  layer         () int32                  [scalar prefetch]
+  block_tables  (B, max_blocks) int32     [scalar prefetch]
+  lengths       (B,) int32                [scalar prefetch]
   → acc (B, H, D) f32, m (B, H, 128) f32, l (B, H, 128) f32
     (m/l broadcast along a 128-lane axis: TPU-friendly layout)
 
-Grid ``(B, num_read_blocks)``, block sweep innermost; fully-masked blocks
-(``start >= length``) are skipped with ``pl.when`` — their DMA still
-happens (block 0, the scratch block), which is the price of a static grid.
+Grid ``(B,)``, one step a slot, in order. Inside a step a loop runs over the
+slot's ``cdiv(length, bs)`` live blocks, a *tile* of several blocks at a
+time: each live block of the tile is one ``make_async_copy`` from
+``pool[layer, table[b, j]]`` into one of two VMEM tiles, and the next tile's
+copies (the next live slot's first tile, at a slot's end) are in flight
+while this one is computed. A slot of length 0 runs zero iterations and
+starts no copy. What a call costs follows the live blocks, not
+``num_read_blocks``, which only caps the rows a slot may attend.
+
+The multi-query twin (:func:`paged_attention_multiquery_partial`,
+continuation prefill and speculative verify) still sweeps a static
+``(B, T-blocks, num_read_blocks)`` grid over one layer's pool slice.
 """
 
 from __future__ import annotations
@@ -38,96 +51,168 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = float(jnp.finfo(jnp.float32).min)
 
+# VMEM the single-query read's tiles may take, both buffers of K and of V:
+# a tile is the largest whole number of blocks that fits (8 blocks =
+# 512 rows at bs 64, Kh*D 1024, bf16). A constant of the kernel, sized
+# against the 16 MiB of scoped VMEM a v5e kernel gets by default.
+TILE_VMEM_BYTES = 4 * 1024 * 1024
 
-def _paged_kernel(
-    # NOTE: _paged_mq_kernel below is this kernel's multi-query twin
-    # (this one is its t_block=1 special case). They are kept separate ON
-    # PURPOSE for now: this kernel is the recorded decode benchmark's hot
-    # path, validated on real hardware, and consolidating the two must be
-    # done with the device microbenchmark in hand (round-4 item) — not
-    # blind. Any fix to the online-softmax discipline here must be
-    # mirrored there until they merge.
+
+def _paged_read_kernel(
+    layer_ref,    # SMEM (1,) int32
     tables_ref,   # SMEM (B, max_blocks) int32
     lengths_ref,  # SMEM (B,) int32
     q_ref,        # (1, H, D)
-    k_ref,        # (1, bs, KhD)
-    v_ref,        # (1, bs, KhD)
+    k_hbm,        # (L, nb, bs, KhD), in HBM
+    v_hbm,
     acc_out,      # (1, H, D) f32
     m_out,        # (1, H, 128) f32
     l_out,        # (1, H, 128) f32
-    m_ref,        # VMEM (H, 128) f32
-    l_ref,        # VMEM (H, 128) f32
-    acc_ref,      # VMEM (H, D) f32
+    k_tile,       # VMEM (2, T*bs, KhD): two buffers of one tile
+    v_tile,
+    sems,         # DMA (2, 2): [k|v, buffer]
+    buf_ref,      # SMEM (1,) int32: the buffer the next tile to compute is in
     *,
     scale: float,
     block_size: int,
+    tile_blocks: int,
+    num_read_blocks: int,
     kv_heads: int,
     head_dim: int,
 ):
     b = pl.program_id(0)
-    ji = pl.program_id(1)
-    num_j = pl.num_programs(1)
-    length = lengths_ref[b]
-    start = ji * block_size
+    B = pl.num_programs(0)
+    _, H, D = q_ref.shape
+    G = H // kv_heads
+    bs, T = block_size, tile_blocks
+    rows_t = T * bs
+    layer = layer_ref[0]
 
-    @pl.when(ji == 0)
-    def _init():
-        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
+    def rows_of(slot):
+        return jnp.minimum(lengths_ref[slot], num_read_blocks * bs)
 
-    @pl.when(start < length)
-    def _accumulate():
-        H, D = acc_ref.shape
-        G = H // kv_heads
-        q = q_ref[0]                                   # (H, D)
-        k = k_ref[0].reshape(block_size, kv_heads, head_dim)
-        v = v_ref[0].reshape(block_size, kv_heads, head_dim)
-        # scores per kv-head group: q rows [kh*G:(kh+1)*G] attend k[:, kh]
-        qg = q.reshape(kv_heads, G, D)
-        s = jax.lax.dot_general(
-            qg, k, (((2,), (2,)), ((0,), (1,))),
-            preferred_element_type=jnp.float32,
-        ) * scale                                      # (Kh, G, bs)
-        s = s.reshape(H, block_size)
-        cols = start + jax.lax.broadcasted_iota(
-            jnp.int32, (H, block_size), 1
+    def for_live_blocks(slot, t, buf, act):
+        """``act`` on the K and the V copy of each live block of tile ``t``
+        of ``slot``; none for the table columns past the slot's length."""
+        n = pl.cdiv(rows_of(slot), bs)
+        for j in range(T):
+            @pl.when(t * T + j < n)
+            def _():
+                blk = tables_ref[slot, t * T + j]
+                for i, (pool, tile) in enumerate(
+                    ((k_hbm, k_tile), (v_hbm, v_tile))
+                ):
+                    act(pltpu.make_async_copy(
+                        pool.at[layer, blk],
+                        tile.at[buf, pl.ds(j * bs, bs)],
+                        sems.at[i, buf],
+                    ))
+
+    def next_live(after):
+        return jax.lax.while_loop(
+            lambda i: jnp.logical_and(
+                i < B, lengths_ref[jnp.minimum(i, B - 1)] <= 0
+            ),
+            lambda i: i + 1,
+            after + 1,
         )
-        mask = cols < length
-        s = jnp.where(mask, s, NEG_INF)
-        m_prev = m_ref[:, 0]                           # (H,)
-        l_prev = l_ref[:, 0]
-        m_cur = jnp.max(s, axis=1)
-        m_new = jnp.maximum(m_prev, m_cur)
+
+    @pl.when(b == 0)
+    def _first_fetch():
+        buf_ref[0] = 0
+        first = next_live(-1)
+
+        @pl.when(first < B)
+        def _():
+            for_live_blocks(first, 0, 0, lambda c: c.start())
+
+    length = rows_of(b)
+    num_tiles = pl.cdiv(pl.cdiv(length, bs), T)
+    after = next_live(b)
+    q = q_ref[0]                                       # (H, D)
+
+    def head(tile, buf, kh):
+        # rows × one kv head: a lane-aligned slice (D = 128), so the dots
+        # are plain 2-D matmuls on the stored layout. No (rows, Kh, D)
+        # reshape and no batch dimension in the dot: that relayout is what
+        # r5's chip attribution pinned the q8 lane's 62-vs-42 ms/step on.
+        return tile[buf, :, kh * head_dim:(kh + 1) * head_dim]
+
+    def tile_step(t, carry):
+        m_prev, l_prev, acc = carry                    # (H,1) (H,1) (H,D)
+        buf = buf_ref[0]
+        # the next tile's copies fly while this one is computed: the slot's
+        # own next tile, or at its end the next live slot's first
+        last = t + 1 >= num_tiles
+        ahead_slot = jnp.where(last, after, b)
+        ahead_tile = jnp.where(last, 0, t + 1)
+
+        @pl.when(ahead_slot < B)
+        def _():
+            for_live_blocks(
+                ahead_slot, ahead_tile, 1 - buf, lambda c: c.start()
+            )
+
+        for_live_blocks(b, t, buf, lambda c: c.wait())
+        start = t * rows_t
+        live = start + jax.lax.broadcasted_iota(
+            jnp.int32, (1, rows_t), 1
+        ) < length
+        live_rows = start + jax.lax.broadcasted_iota(
+            jnp.int32, (rows_t, 1), 0
+        ) < length
+        s = jnp.concatenate([
+            jax.lax.dot_general(
+                q[kh * G:(kh + 1) * G], head(k_tile, buf, kh),
+                (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+            )                                          # (G, rows)
+            for kh in range(kv_heads)
+        ], axis=0) * scale
+        s = jnp.where(live, s, NEG_INF)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         shift = jnp.where(m_new <= NEG_INF, 0.0, m_new)
-        p = jnp.exp(s - shift[:, None])
-        p = jnp.where(mask, p, 0.0)
+        p = jnp.where(live, jnp.exp(s - shift), 0.0)
         alpha = jnp.exp(jnp.where(m_prev <= NEG_INF, NEG_INF, m_prev - shift))
-        l_ref[:] = jnp.broadcast_to(
-            (l_prev * alpha + jnp.sum(p, axis=1))[:, None], l_ref.shape
-        )
-        pg = p.reshape(kv_heads, G, block_size)
-        pv = jax.lax.dot_general(
-            pg.astype(v.dtype), v, (((2,), (0,)), ((0,), (1,))),
-            preferred_element_type=jnp.float32,
-        )                                              # (Kh, G, D)
-        acc_ref[:] = acc_ref[:] * alpha[:, None] + pv.reshape(H, D)
-        m_ref[:] = jnp.broadcast_to(m_new[:, None], m_ref.shape)
+        l_new = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
+        p = p.astype(v_tile.dtype)
+        pv = []
+        for kh in range(kv_heads):
+            # rows that are not live (the last block's tail, what an
+            # earlier tile left in the buffer) are zeroed, not only given
+            # probability 0: 0 × NaN is NaN
+            v_h = head(v_tile, buf, kh)
+            v_h = jnp.where(live_rows, v_h, jnp.zeros_like(v_h))
+            pv.append(jax.lax.dot_general(
+                p[kh * G:(kh + 1) * G], v_h,
+                (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
+            ))                                         # (G, D)
+        acc = acc * alpha + jnp.concatenate(pv, axis=0)
+        buf_ref[0] = 1 - buf
+        return m_new, l_new, acc
 
-    @pl.when(ji == num_j - 1)
-    def _finalize():
-        acc_out[0] = acc_ref[:]
-        m_out[0] = m_ref[:]
-        l_out[0] = l_ref[:]
+    m, l, acc = jax.lax.fori_loop(
+        0, num_tiles, tile_step,
+        (
+            jnp.full((H, 1), NEG_INF, jnp.float32),
+            jnp.zeros((H, 1), jnp.float32),
+            jnp.zeros((H, D), jnp.float32),
+        ),
+    )
+    acc_out[0] = acc
+    m_out[0] = jnp.broadcast_to(m, m_out.shape[1:])
+    l_out[0] = jnp.broadcast_to(l, l_out.shape[1:])
 
 
 def _paged_kernel_q8(
-    # int8 twin of _paged_kernel: k/v arrive as int8 blocks with per-(row,
-    # kv-head) f32 scales. The k scale multiplies the SCORE (constant along
-    # D, factored out of the dot); the v scale folds into the probabilities
-    # before the value dot — exactly the fused-dequant discipline of the
-    # XLA path (models/kvquant.py cache_scores/cache_values), so the two
-    # lanes are numerically interchangeable.
+    # int8 twin of _paged_read_kernel, still on the static grid
+    # (B, num_read_blocks) over one layer's pool slice (fully-masked blocks
+    # are skipped with pl.when, their DMA of block 0 still happens): k/v
+    # arrive as int8 blocks with per-(row, kv-head) f32 scales. The k scale
+    # multiplies the SCORE (constant along D, factored out of the dot); the
+    # v scale folds into the probabilities before the value dot — exactly
+    # the fused-dequant discipline of the XLA path (models/kvquant.py
+    # cache_scores/cache_values), so the two lanes are numerically
+    # interchangeable.
     tables_ref,   # SMEM (B, max_blocks) int32
     lengths_ref,  # SMEM (B,) int32
     q_ref,        # (1, H, D)
@@ -226,12 +311,13 @@ def _paged_kernel_q8(
 
 def paged_attention_partial(
     q: jax.Array,             # (B, H, D)
-    k_pool,                   # (nb, bs, Kh*D) bf16, or int8 {"q","s"} pool
+    k_pool,                   # (L, nb, bs, Kh*D) bf16, or int8 {"q","s"} pool
     v_pool,
+    layer,                    # () int32 — which layer of the stacked pool
     block_tables: jax.Array,  # (B, max_blocks) int32
     lengths: jax.Array,       # (B,) int32 — cache rows to attend per slot
     *,
-    num_read_blocks: int,     # static table columns to sweep (window bucket)
+    num_read_blocks: int,     # static cap on the table columns a slot reads
     kv_heads: int,
     head_dim: int,
     scale: float | None = None,
@@ -242,53 +328,56 @@ def paged_attention_partial(
     Returns ``(acc (B,H,D) f32, m (B,H) f32, l (B,H) f32)`` for the caller
     to merge with other segments via :func:`merge_partial_attention`.
 
-    int8 pools (``{"q": int8, "s": f32}`` dicts) read through the in-kernel
-    fused-dequant twin — no densified bf16 window copy, which on the XLA
-    gather path costs more HBM traffic than the weights themselves at
-    serving batch sizes (r5 chip attribution).
+    The pool is the layer-stacked one, read in place: the kernel fetches
+    ``pool[layer, table[b, j]]`` for the live ``j`` only.
+
+    int8 pools (``{"q": int8, "s": f32}`` dicts) still read through the
+    static-grid twin on a slice of the layer: their ``(bs, Kh)`` scale rows
+    are 8 lanes wide, and Mosaic refuses a hand-made copy of them ("slice
+    shape must be aligned to tiling (128)"); see ROADMAP S3.
     """
     if isinstance(k_pool, dict):
+        at_layer = lambda a: jax.lax.dynamic_index_in_dim(  # noqa: E731
+            a, layer, keepdims=False
+        )
         return _paged_attention_partial_q8(
-            q, k_pool, v_pool, block_tables, lengths,
+            q, jax.tree.map(at_layer, k_pool), jax.tree.map(at_layer, v_pool),
+            block_tables, lengths,
             num_read_blocks=num_read_blocks, kv_heads=kv_heads,
             head_dim=head_dim, scale=scale, interpret=interpret,
         )
     B, H, D = q.shape
-    nb, bs, KhD = k_pool.shape
+    _, _, bs, KhD = k_pool.shape
     if scale is None:
         scale = 1.0 / math.sqrt(D)
+    tile_blocks = max(1, min(
+        TILE_VMEM_BYTES // (4 * bs * KhD * k_pool.dtype.itemsize),
+        num_read_blocks,
+    ))
     kernel = functools.partial(
-        _paged_kernel,
-        scale=scale,
-        block_size=bs,
-        kv_heads=kv_heads,
-        head_dim=head_dim,
+        _paged_read_kernel,
+        scale=scale, block_size=bs, tile_blocks=tile_blocks,
+        num_read_blocks=num_read_blocks, kv_heads=kv_heads, head_dim=head_dim,
     )
+    per_slot = lambda shape: pl.BlockSpec(  # noqa: E731
+        shape, lambda b, layer, tables, lengths: (b, 0, 0)
+    )
+    tile = pltpu.VMEM((2, tile_blocks * bs, KhD), k_pool.dtype)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, num_read_blocks),
+        num_scalar_prefetch=3,
+        grid=(B,),
         in_specs=[
-            pl.BlockSpec(
-                (1, H, D), lambda b, j, tables, lengths: (b, 0, 0)
-            ),
-            pl.BlockSpec(
-                (1, bs, KhD),
-                lambda b, j, tables, lengths: (tables[b, j], 0, 0),
-            ),
-            pl.BlockSpec(
-                (1, bs, KhD),
-                lambda b, j, tables, lengths: (tables[b, j], 0, 0),
-            ),
+            per_slot((1, H, D)),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
         out_specs=[
-            pl.BlockSpec((1, H, D), lambda b, j, tables, lengths: (b, 0, 0)),
-            pl.BlockSpec((1, H, 128), lambda b, j, tables, lengths: (b, 0, 0)),
-            pl.BlockSpec((1, H, 128), lambda b, j, tables, lengths: (b, 0, 0)),
+            per_slot((1, H, D)), per_slot((1, H, 128)), per_slot((1, H, 128)),
         ],
         scratch_shapes=[
-            pltpu.VMEM((H, 128), jnp.float32),
-            pltpu.VMEM((H, 128), jnp.float32),
-            pltpu.VMEM((H, D), jnp.float32),
+            tile, tile,
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.SMEM((1,), jnp.int32),
         ],
     )
     acc, m, l = pl.pallas_call(
@@ -300,11 +389,15 @@ def paged_attention_partial(
             jax.ShapeDtypeStruct((B, H, 128), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
+            # in order: a slot's step starts the next live slot's first tile
+            dimension_semantics=("arbitrary",),
         ),
         interpret=interpret,
         name="paged_read",
-    )(block_tables, lengths, q, k_pool, v_pool)
+    )(
+        jnp.asarray(layer, jnp.int32).reshape(1), block_tables, lengths,
+        q, k_pool, v_pool,
+    )
     return acc, m[:, :, 0], l[:, :, 0]
 
 
@@ -481,9 +574,10 @@ def paged_attention_multiquery_partial(
     queries per slot attend the slot's paged HISTORY (rows ``< starts``) —
     the continuation-prefill / speculative-verify hot read. History is
     mask-uniform across the T axis (causality among the suffix itself is
-    the caller's separate XLA segment), so the kernel is the single-query
-    sweep with a query-block grid axis and (T·G)-row MXU tiles instead of
-    G-row ones.
+    the caller's separate XLA segment), so one online-softmax sweep serves
+    a block of queries with (T·G)-row MXU tiles instead of G-row ones. It
+    takes one layer's pool slice and sweeps a static grid of
+    ``num_read_blocks`` table columns, dead ones skipped by ``pl.when``.
 
     Returns ``(acc (B,T,H,D) f32, m (B,T,H) f32, l (B,T,H) f32)``.
     ``T`` must be a multiple of ``t_block``.
@@ -578,6 +672,7 @@ def shard_mapped_paged_read(
     batch: int,
     q_spec_tail: tuple,       # q PartitionSpec entries AFTER the batch axis
     out_spec_tails: tuple,    # per-output spec entries after the batch axis
+    stacked_pool: bool = False,  # pools (L, nb, bs, Kh·D) + a layer index
 ):
     """Shared mesh wrapper for the paged read kernels (decode single-query
     and continuation multi-query): slots on ``dp``, heads on ``tp`` (the
@@ -607,13 +702,19 @@ def shard_mapped_paged_read(
         return {"dp": dp, "tp": tp}.get(entry, entry) if entry else None
 
     q_spec = P(dp, *(sub(e) for e in q_spec_tail))
+    # the single-query read takes the layer-stacked pool and the layer's
+    # index (replicated); the multi-query read one layer's slice
+    pool_specs = (
+        (P(None, None, None, tp), P(None, None, None, tp), P())
+        if stacked_pool
+        else (P(None, None, tp), P(None, None, tp))  # (nb, bs, Kh·D)
+    )
     return jax.shard_map(
         _partial(fn, kv_heads=kv_heads // tp_size),
         mesh=mesh,
         in_specs=(
             q_spec,
-            P(None, None, tp),  # k pool (nb, bs, Kh·D)
-            P(None, None, tp),  # v pool
+            *pool_specs,
             P(dp, None),        # block tables (B, max_blocks)
             P(dp),              # lengths/starts (B,)
         ),

@@ -123,14 +123,18 @@ def check_kernels(
         }
 
         def single(pk, pv, nrb=nrb, tables=tables, lengths=lengths):
+            # the kernel reads the layer-stacked pool in place: layer 1 of
+            # two holds the pool, layer 0 zeros
+            stack = lambda a: jnp.stack([jnp.zeros_like(a), a])  # noqa: E731
             got = jax.jit(
                 lambda q, k, v, t, n: merge_partial_attention([
                     paged_attention_partial(
-                        q, k, v, t, n, num_read_blocks=nrb, kv_heads=Kh,
+                        q, k, v, 1, t, n, num_read_blocks=nrb, kv_heads=Kh,
                         head_dim=D, interpret=interpret,
                     )
                 ])
-            )(q1, pk, pv, tables, lengths)
+            )(q1, jax.tree.map(stack, pk), jax.tree.map(stack, pv),
+              tables, lengths)
             ref = jax.jit(
                 lambda q, k, v, t, n: merge_partial_attention([
                     _cache_partial_xla(c, q, k, v, t, n, nrb)
@@ -139,7 +143,7 @@ def check_kernels(
             return got, ref
 
         rows.append(_row(
-            "_paged_kernel", shape, interpret,
+            "_paged_read_kernel", shape, interpret,
             lambda: single(pool_k, pool_v),
         ))
         rows.append(_row(

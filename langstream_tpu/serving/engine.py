@@ -1775,11 +1775,14 @@ class TpuServingEngine:
             if kernel == "auto":
                 # bf16 pools read through the Pallas kernel on TPU (under
                 # a mesh per-shard via shard_map: slots on dp, heads on
-                # tp). int8 pools read through the fused XLA gather: the
-                # transpose-free _paged_kernel_q8 compiles on the v5e and
-                # matches the gather at 8B shapes (chip_smoke.py), but has
-                # no timing yet — which one is faster is ROADMAP S3's
-                # question; paged_kernel=pallas selects it meanwhile.
+                # tp): it fetches only the live blocks, from the stacked
+                # pool in place. int8 pools read through the fused XLA
+                # gather: their Pallas twin _paged_kernel_q8 is still on
+                # the static (slots, table columns) grid over a slice of
+                # the layer's pool, compiles on the v5e and matches the
+                # gather at 8B shapes (chip_smoke.py), and has no timing;
+                # moving it onto the bf16 kernel's driver is ROADMAP S3's
+                # next step; paged_kernel=pallas selects it meanwhile.
                 kernel = (
                     "pallas"
                     if jax.default_backend() == "tpu" and not quant_pool
@@ -2490,11 +2493,14 @@ class TpuServingEngine:
         dispatch: int | None = None,
         steps: int = 0,
         active_at_dispatch: int | None = None,
+        live_blocks: int | None = None,
+        table_blocks: int | None = None,
     ) -> None:
         """One flight sample per dispatched burst, plus its Prometheus
-        mirrors. ``program``, ``dispatch``, ``steps`` and
-        ``active_at_dispatch`` are the dispatch's :meth:`_ticket`, taken
-        when it was made. ``overlapped_s`` is host work the pipelined loop ran
+        mirrors. ``program``, ``dispatch``, ``steps``,
+        ``active_at_dispatch``, ``live_blocks`` and ``table_blocks`` are
+        the dispatch's :meth:`_ticket`, taken when it was made.
+        ``overlapped_s`` is host work the pipelined loop ran
         under an in-flight dispatch's device shadow (see flight.py).
         ``program`` keys the sample by the compiled variant that ran and
         feeds the attribution ledger's measured side (achieved-vs-
@@ -2529,6 +2535,8 @@ class TpuServingEngine:
             dispatch=dispatch,
             steps=steps,
             active_at_dispatch=active_at_dispatch,
+            live_blocks=live_blocks,
+            table_blocks=table_blocks,
         )
         # watchdog heartbeat: a recorded dispatch IS step progress
         self.watchdog.beat(sample["queue_depth"])
@@ -2565,17 +2573,36 @@ class TpuServingEngine:
         if stall is not None:
             self._m_stall[stall](sample["wall_ms"] / 1000.0)
 
-    def _ticket(self, program: str, steps: int, active: int) -> dict:
+    def _ticket(
+        self, program: str, steps: int, active: int,
+        live_blocks: int | None = None, table_blocks: int | None = None,
+    ) -> dict:
         """What a dispatch knows when it is made and its flight sample,
         recorded when the result is processed, no longer does: the program
         variant, the dispatch's ordinal (the ``seq`` of its host spans),
-        the decode steps it fuses (0 for a prefill) and the slots running.
+        the decode steps it fuses (0 for a prefill), the slots running and,
+        for a paged decode chunk, the pool blocks its read has to fetch
+        against the table columns of its window (:meth:`_read_blocks`).
         Loop thread only; rides to :meth:`_flight_record` as keywords."""
         self._dispatch_seq += 1
         return {
             "program": program, "dispatch": self._dispatch_seq,
             "steps": steps, "active_at_dispatch": active,
+            "live_blocks": live_blocks, "table_blocks": table_blocks,
         }
+
+    def _read_blocks(self, active: list[int], ahead: int, window: int):
+        """``(live_blocks, table_blocks)`` of a decode chunk's paged read:
+        the blocks that hold rows, summed over every slot of the batch from
+        the host's lengths (``ahead`` rows further for the running slots,
+        whose in-flight chunk the host has not processed), and the table
+        columns a sweep of the whole window would visit. Their ratio is
+        the share of such a sweep that is live."""
+        bs = self.paged_layout.block_size
+        rows = self._lengths.astype(np.int64)
+        rows[active] += ahead
+        rows = np.minimum(rows, window * bs)
+        return int((-(-rows // bs)).sum()), self.config.slots * window
 
     def _flight_stall(self, reason: str) -> None:
         """Record an idle/blocked engine-loop gap as stall time."""
@@ -6354,7 +6381,7 @@ class TpuServingEngine:
         # time lands on the variant that ran it
         prog_q: list[dict] = []
 
-        def _submit(tokens, lengths, key, window, tables, first=False):
+        def _submit(tokens, lengths, key, window, pending, first=False):
             """Loop-thread half of a chunk dispatch: resolve the jit
             variant (so the ``_decode_chunk_fns``/``_compiled_shapes``
             bookkeeping never runs on the dispatch thread), rebuild the
@@ -6362,10 +6389,13 @@ class TpuServingEngine:
             then hand the fully-prepared closure to the dispatch thread.
             Returns the executor future — awaited immediately by the
             sequential path, left in flight by the pipelined one."""
+            tables = _grow_blocks(pending)
             decode_fn = self._decode_fn(sampler_mode, window, K, pen)
             ticket = self._ticket(
                 self._program_decode(window, K, sampler_mode, pen),
                 K, len(active),
+                *(self._read_blocks(active, pending * K, window)
+                  if paged else ()),
             )
             prog_q.append(ticket)
             counts_np = _build_counts() if pen else None
@@ -6388,7 +6418,7 @@ class TpuServingEngine:
         ):
             first_out = _submit(
                 jnp.asarray(self._current), jnp.asarray(self._lengths),
-                key1, _bucket_for(base_max), _grow_blocks(0), first=True,
+                key1, _bucket_for(base_max), 0, first=True,
             )
         out = await first_out
         chunk_index = 0
@@ -6424,7 +6454,7 @@ class TpuServingEngine:
                 ):
                     next_out = _submit(
                         out[1], out[2], self._split_key(),
-                        _bucket_for(base_max), _grow_blocks(0),
+                        _bucket_for(base_max), 0,
                     )
                 out = await next_out
 
@@ -6491,7 +6521,7 @@ class TpuServingEngine:
                     # unprocessed when the speculative chunk is dispatched
                     next_out_task = _submit(
                         out[1], out[2], key_next,
-                        _bucket_for(base_max), _grow_blocks(1),
+                        _bucket_for(base_max), 1,
                     )
                 chunk_t, chunk_lp, fetch_s = await self._await_chunk(
                     loop, out[0], K, ticket

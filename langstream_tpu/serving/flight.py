@@ -217,6 +217,8 @@ class FlightRecorder:
         dispatch: int | None = None,
         steps: int = 0,
         active_at_dispatch: int | None = None,
+        live_blocks: int | None = None,
+        table_blocks: int | None = None,
     ) -> dict[str, Any]:
         """Record one dispatched burst. ``wall`` is the time since the
         previous boundary. ``overlapped_s`` is host work the pipelined
@@ -234,7 +236,9 @@ class FlightRecorder:
         (decode steps it fused; 0 for a prefill) and ``active_at_dispatch``
         (slots running when it was dispatched) were taken at dispatch, not
         at this later boundary where ``occupancy`` is read; omitted
-        together when the caller has no dispatch to name."""
+        together when the caller has no dispatch to name. ``live_blocks``
+        and ``table_blocks`` (a paged decode chunk only) are the pool
+        blocks its read had to fetch and the table columns of its window."""
         now = time.monotonic()
         wall_ms = (now - self._last_mark) * 1000.0
         self._last_mark = now
@@ -273,6 +277,9 @@ class FlightRecorder:
             entry["dispatch"] = dispatch
             entry["steps"] = steps
             entry["active_at_dispatch"] = active_at_dispatch
+        if live_blocks is not None:
+            entry["live_blocks"] = live_blocks
+            entry["table_blocks"] = table_blocks
         self._samples.append(entry)
         self.recorded += 1
         self.wall_ms += wall_ms

@@ -158,13 +158,18 @@ def test_a_span_without_a_profiler_session_leaves_the_recorder_untouched():
      {"dispatch": 9, "steps": 32, "active_at_dispatch": 61}),
     (dict(dispatch=10, active_at_dispatch=3),           # a prefill: no steps
      {"dispatch": 10, "steps": 0, "active_at_dispatch": 3}),
+    (dict(dispatch=11, steps=32, active_at_dispatch=97,  # a paged decode
+          live_blocks=730, table_blocks=4096),
+     {"dispatch": 11, "steps": 32, "active_at_dispatch": 97,
+      "live_blocks": 730, "table_blocks": 4096}),
     (dict(), {}),                       # no dispatch named: schema unchanged
 ])
 def test_sample_carries_what_the_dispatch_knew(fields, expected):
     recorder = FlightRecorder(slots=64)
     entry = recorder.sample("decode", device_s=0.001, occupancy=58, **fields)
-    assert {k: entry[k] for k in ("dispatch", "steps", "active_at_dispatch")
-            if k in entry} == expected
+    carried = ("dispatch", "steps", "active_at_dispatch", "live_blocks",
+               "table_blocks")
+    assert {k: entry[k] for k in carried if k in entry} == expected
     assert entry["occupancy"] == 58     # stays what it was for its readers
 
 
@@ -289,6 +294,13 @@ def test_engine_samples_name_their_dispatch(run_async):
         # the steps are the program's own chunk size
         assert f":k{s['steps']}:" in s["program"] and s["steps"] > 0
         assert 1 <= s["active_at_dispatch"] <= 4
+        # the paged read's work: blocks that hold rows (a prompt of ~20
+        # bytes and up to 9 answers: one 64-row block a slot, two at most)
+        # against the table columns of the whole batch's window
+        assert s["active_at_dispatch"] <= s["live_blocks"] <= 8
+        assert s["table_blocks"] >= 4 and s["table_blocks"] % 4 == 0
+        assert s["live_blocks"] <= s["table_blocks"]
+    assert all("live_blocks" not in s for s in prefill)
     # a chunk in which requests finish is recorded after their slots were
     # freed: what was running at dispatch is the larger number
     assert any(s["active_at_dispatch"] > s["occupancy"] for s in decode)

@@ -257,7 +257,7 @@ def test_paged_kernel_partial_matches_xla_reference():
 
     ref = _cache_partial_xla(c, q, pool_k, pool_v, tables, lengths, nrb)
     got = paged_attention_partial(
-        q, pool_k, pool_v, tables, lengths,
+        q, pool_k[None], pool_v[None], 0, tables, lengths,
         num_read_blocks=nrb, kv_heads=Kh, head_dim=D, interpret=True,
     )
     # compare the *normalised* outputs (partials differ by shift convention)
@@ -294,8 +294,10 @@ def test_paged_kernel_partial_q8_matches_xla_reference():
     lengths = jnp.array([20, 9, 24], jnp.int32)
 
     ref = _cache_partial_xla(c, q, pool_k, pool_v, tables, lengths, nrb)
+    add_l = lambda a: a[None]  # noqa: E731 — the kernel takes stacked pools
     got = paged_attention_partial(
-        q, pool_k, pool_v, tables, lengths,
+        q, jax.tree.map(add_l, pool_k), jax.tree.map(add_l, pool_v), 0,
+        tables, lengths,
         num_read_blocks=nrb, kv_heads=Kh, head_dim=D, interpret=True,
     )
     out_ref = merge_partial_attention([ref])
@@ -342,8 +344,10 @@ def test_paged_kernel_q8_batch_leading_layout_pin():
     lengths = jnp.array([3, 8, 11, 16, 5, 13], jnp.int32)
 
     ref = _cache_partial_xla(c, q, pool_k, pool_v, tables, lengths, nrb)
+    add_l = lambda a: a[None]  # noqa: E731 — the kernel takes stacked pools
     got = paged_attention_partial(
-        q, pool_k, pool_v, tables, lengths,
+        q, jax.tree.map(add_l, pool_k), jax.tree.map(add_l, pool_v), 0,
+        tables, lengths,
         num_read_blocks=nrb, kv_heads=Kh, head_dim=D, interpret=True,
     )
     out_ref = merge_partial_attention([ref])
@@ -353,6 +357,240 @@ def test_paged_kernel_q8_batch_leading_layout_pin():
         np.asarray(out_got, dtype=np.float32),
         rtol=5e-2, atol=5e-2,
     )
+
+
+# the single-query read against the XLA gather, case by case: a stacked pool
+# whose layers differ, read at a layer other than 0 through shuffled,
+# non-contiguous tables (bs 8, a window of 5 blocks = 40 rows)
+_READ_BS, _READ_NRB, _READ_NB, _READ_LAYERS = 8, 5, 64, 3
+_READ_WINDOW = _READ_BS * _READ_NRB
+
+
+def _read_case(G, lengths, *, seed=0, dtype=jnp.float32):
+    import dataclasses
+
+    from langstream_tpu.models.llama import LlamaConfig
+
+    c = dataclasses.replace(LlamaConfig.tiny(), heads=2 * G, kv_heads=2)
+    B, KhD = len(lengths), c.kv_heads * c.head_dim
+    rng = np.random.default_rng(seed)
+    tables = rng.permutation(np.arange(1, _READ_NB))[: B * _READ_NRB]
+    tables = tables.reshape(B, _READ_NRB).astype(np.int32)
+    kq, kk, kv = jax.random.split(jax.random.PRNGKey(seed), 3)
+    shape = (_READ_LAYERS, _READ_NB, _READ_BS, KhD)
+    return (
+        c,
+        jax.random.normal(kq, (B, c.heads, c.head_dim), dtype),
+        np.array(jax.random.normal(kk, shape, dtype)),
+        np.array(jax.random.normal(kv, shape, dtype)),
+        tables,
+        np.asarray(lengths, np.int32),
+    )
+
+
+def _read_both(c, q, pool_k, pool_v, tables, lengths, layer):
+    """Normalised outputs of the kernel (interpreter) and of the XLA read."""
+    from langstream_tpu.models.llama_paged import _cache_partial_xla
+    from langstream_tpu.ops.paged_attention import (
+        merge_partial_attention, paged_attention_partial,
+    )
+
+    tables, lengths = jnp.asarray(tables), jnp.asarray(lengths)
+    got = paged_attention_partial(
+        q, jnp.asarray(pool_k), jnp.asarray(pool_v), layer, tables, lengths,
+        num_read_blocks=_READ_NRB, kv_heads=c.kv_heads, head_dim=c.head_dim,
+        interpret=True,
+    )
+    ref = _cache_partial_xla(
+        c, q, jnp.asarray(pool_k[layer]), jnp.asarray(pool_v[layer]),
+        tables, lengths, _READ_NRB,
+    )
+    # a slot that attends nothing has no rows to sum: l is 0 on both sides
+    np.testing.assert_array_equal(
+        np.asarray(got[2]) > 0,
+        np.broadcast_to(np.asarray(lengths)[:, None] > 0, got[2].shape),
+    )
+    return (
+        np.asarray(merge_partial_attention([got])),
+        np.asarray(merge_partial_attention([ref])),
+    )
+
+
+def _tile_blocks(monkeypatch, blocks, *, kv_heads=2, head_dim=16, itemsize=4):
+    """Make the kernel's tile hold ``blocks`` blocks at the cases' shapes
+    (its VMEM budget is a constant of the module, read at call time)."""
+    from langstream_tpu.ops import paged_attention
+
+    monkeypatch.setattr(
+        paged_attention, "TILE_VMEM_BYTES",
+        blocks * 4 * _READ_BS * kv_heads * head_dim * itemsize,
+    )
+
+
+@pytest.mark.parametrize("G", [1, 2, 4])
+@pytest.mark.parametrize("lengths", [
+    pytest.param((0, 17, 0), id="empty"),
+    pytest.param((1, 17, 1), id="one-row"),
+    pytest.param((_READ_BS - 1, 17, _READ_BS - 1), id="block-less-one"),
+    pytest.param((_READ_BS, 17, _READ_BS), id="one-block"),
+    pytest.param((_READ_BS + 1, 17, _READ_BS + 1), id="block-and-one"),
+    pytest.param((_READ_WINDOW - 1, 17, _READ_WINDOW - 1), id="window-less-one"),
+    pytest.param((_READ_WINDOW, 17, _READ_WINDOW), id="whole-window"),
+    pytest.param((20, 0, 9, _READ_WINDOW, 1, 33, 0, 16), id="ragged"),
+])
+def test_paged_read_matches_xla_over_lengths(G, lengths, monkeypatch):
+    """Lengths at every edge of a block and of the window, alone and mixed
+    in one batch, for each query-group width; tiles of two blocks, so that
+    the window's five end on a half-filled tile."""
+    _tile_blocks(monkeypatch, 2)
+    case = _read_case(G, lengths, seed=G)
+    got, ref = _read_both(*case, layer=2)
+    np.testing.assert_allclose(got, ref, rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("layer", [0, 1])
+@pytest.mark.parametrize("blocks", [1, 2, 3, 5, 64])
+def test_paged_read_matches_xla_over_tile_sizes(blocks, layer, monkeypatch):
+    """Tile boundaries inside a slot's blocks, at their end, and beyond
+    them (one tile holds any slot; 64 is capped at the window): slots of
+    1-5 blocks, some free, each layer of the stack giving its own answer."""
+    _tile_blocks(monkeypatch, blocks)
+    lengths = (40, 3, 0, 24, 16, 17, 0, 33, 32, 8)
+    case = _read_case(2, lengths, seed=7)
+    got, ref = _read_both(*case, layer=layer)
+    np.testing.assert_allclose(got, ref, rtol=1e-5, atol=1e-5)
+    other, _ = _read_both(*case, layer=2)
+    assert np.abs(other - got).max() > 1e-2
+
+
+@pytest.mark.parametrize("blocks", [1, 2, 5])
+def test_paged_read_never_touches_a_dead_block_or_row(blocks, monkeypatch):
+    """NaN in every block no live table entry names (block 0, where dead
+    columns point, among them) and in the rows of each last block past the
+    slot's length: the result is finite and is the clean pool's. Tiles of
+    one, two and five blocks leave different rows of an earlier slot in
+    the buffer."""
+    from langstream_tpu.ops.paged_attention import (
+        merge_partial_attention, paged_attention_partial,
+    )
+
+    _tile_blocks(monkeypatch, blocks)
+    lengths = np.array((33, 0, 5, 40, 16, 9, 0, 25), np.int32)
+    c, q, pool_k, pool_v, tables, _ = _read_case(2, lengths, seed=3)
+    live_cols = -(-lengths // _READ_BS)
+    tables = np.where(
+        np.arange(_READ_NRB)[None, :] < live_cols[:, None], tables, 0
+    )
+    clean, ref = _read_both(c, q, pool_k, pool_v, tables, lengths, layer=1)
+    np.testing.assert_allclose(clean, ref, rtol=1e-5, atol=1e-5)
+
+    dead = np.ones(_READ_NB, bool)
+    poisoned_k, poisoned_v = pool_k.copy(), pool_v.copy()
+    for b, n in enumerate(lengths):
+        for j in range(live_cols[b]):
+            dead[tables[b, j]] = False
+        if n % _READ_BS:
+            last = tables[b, live_cols[b] - 1]
+            poisoned_k[:, last, n % _READ_BS:] = np.nan
+            poisoned_v[:, last, n % _READ_BS:] = np.nan
+    assert dead[0] and dead.sum() > 10
+    poisoned_k[:, dead] = np.nan
+    poisoned_v[:, dead] = np.nan
+    acc, m, l = paged_attention_partial(
+        q, jnp.asarray(poisoned_k), jnp.asarray(poisoned_v), 1,
+        jnp.asarray(tables), jnp.asarray(lengths),
+        num_read_blocks=_READ_NRB, kv_heads=c.kv_heads, head_dim=c.head_dim,
+        interpret=True,
+    )
+    assert all(np.isfinite(np.asarray(x)).all() for x in (acc, m, l))
+    np.testing.assert_array_equal(
+        np.asarray(merge_partial_attention([(acc, m, l)])), clean
+    )
+
+
+def _tiny_chunk_layer_body(kernel):
+    """The jaxpr of the layer scan's body in the tiny decode chunk (no
+    lowering: ``kernel="pallas"`` traces on any backend), and the scan's
+    equation in the step body."""
+    from langstream_tpu.models.llama import LlamaConfig, init_llama_params
+    from langstream_tpu.models.llama_paged import llama_decode_chunk_paged
+
+    c = LlamaConfig.tiny()
+    params = init_llama_params(c, jax.random.PRNGKey(0))
+    B = 2
+    pool = jnp.zeros((c.layers, 9, 8, c.kv_heads * c.head_dim), c.dtype)
+
+    def chunk(params, tokens, lengths, active, pool_k, pool_v, tables, key):
+        return llama_decode_chunk_paged(
+            c, params, tokens, lengths, active, pool_k, pool_v, tables,
+            greedy_sample, key, 2, num_read_blocks=3, kernel=kernel,
+        )
+
+    jaxpr = jax.make_jaxpr(chunk)(
+        params, jnp.zeros((B,), jnp.int32), jnp.ones((B,), jnp.int32),
+        jnp.ones((B,), bool), pool, pool, jnp.zeros((B, 4), jnp.int32),
+        jax.random.PRNGKey(0),
+    ).jaxpr
+    scan = lambda j: next(  # noqa: E731
+        e for e in j.eqns if e.primitive.name == "scan"
+    )
+    layers = scan(scan(jaxpr).params["jaxpr"].jaxpr)
+    return c, layers.params["jaxpr"].jaxpr
+
+
+def test_pallas_read_takes_the_stacked_pool_in_place():
+    """The layer body hands the kernel the pool as it lies: both pool
+    operands have rank 4 and the layer count in front, and they are the
+    body's own inputs (constants of the layer scan), not the result of a
+    ``dynamic_slice``, ``squeeze``, ``gather`` or any other equation."""
+    c, body = _tiny_chunk_layer_body("pallas")
+    calls = [e for e in body.eqns if e.primitive.name == "pallas_call"]
+    assert len(calls) == 1                       # one read a layer a step
+    assert "kv_read" in str(calls[0].source_info.name_stack)
+    pools = [v for v in calls[0].invars if len(v.aval.shape) == 4]
+    assert [v.aval.shape for v in pools] == [
+        (c.layers, 9, 8, c.kv_heads * c.head_dim)
+    ] * 2
+    made_here = {id(v) for e in body.eqns for v in e.outvars}
+    assert all(id(v) not in made_here for v in pools)
+    assert all(any(v is i for i in body.invars) for v in pools)
+    # and nothing else in the body holds a pool-sized value
+    assert not [
+        e.primitive.name for e in body.eqns for v in e.outvars
+        if getattr(v.aval, "shape", ())[-3:] == (9, 8, c.kv_heads * c.head_dim)
+    ]
+
+
+# the XLA read's part of the layer body, primitive by primitive, as the
+# parent commit (82ff52d) traced it: the guard on the CPU for "the program
+# of an engine on paged_kernel=xla did not change" (Mistral's cell)
+_XLA_KV_READ = (
+    "broadcast_in_dim slice jit reshape slice squeeze reshape "
+    "broadcast_in_dim slice jit reshape slice squeeze reshape reshape "
+    "dot_general convert_element_type div iota broadcast_in_dim "
+    "broadcast_in_dim lt broadcast_in_dim jit reduce_max le jit "
+    "broadcast_in_dim sub exp jit reduce_sum convert_element_type "
+    "dot_general convert_element_type reshape reshape reshape reshape "
+    "dot_general convert_element_type div broadcast_in_dim jit reduce_max "
+    "le jit broadcast_in_dim sub exp broadcast_in_dim jit reduce_sum "
+    "convert_element_type dot_general convert_element_type reshape reshape "
+    "reshape max le jit le sub jit exp le sub jit exp broadcast_in_dim mul "
+    "broadcast_in_dim mul add mul mul add gt max div jit broadcast_in_dim "
+    "mul convert_element_type reshape"
+).split()
+
+
+def test_xla_read_traces_to_the_program_it_traced_to():
+    c, body = _tiny_chunk_layer_body("xla")
+    kv_read = [
+        e.primitive.name for e in body.eqns
+        if "kv_read" in str(e.source_info.name_stack)
+    ]
+    assert kv_read == _XLA_KV_READ
+    # the scan still slices the layer's pool for it: the body's inputs
+    # hold one layer's (nb, bs, Kh·D), not the stack
+    pool_l = (9, 8, c.kv_heads * c.head_dim)
+    assert [v.aval.shape for v in body.invars].count(pool_l) == 2
 
 
 # ---------------------------------------------------------------------------
