@@ -1,0 +1,771 @@
+"""Hybrid decoder: Mamba-2 mixers, grouped-query attention and routed
+experts in one stack (the ``nemotron_h`` family), over the paged pool.
+
+Every layer is ONE mixer with its own pre-norm and residual,
+``x <- x + Mixer_kind(RMSNorm(x))``, its kind from the published pattern
+(``M`` Mamba-2, ``*`` attention, ``E`` mixture of experts). The pattern is a
+run of blocks ``M [*] E`` (23 of them at the published depth, 6 with an
+attention layer), and that block is what the programs scan: the Mamba-2 and
+expert weights are stacked by block, the attention weights by attention
+layer and reached through the block's index when it has one.
+
+Two kinds of per-request state live side by side:
+
+- the six attention layers' keys and values, in the paged pool
+  (:mod:`langstream_tpu.models.paged`), read through the kernel the engine
+  selected, exactly as the dense family's;
+- a fixed-size recurrent state per slot for the 23 Mamba-2 layers: the
+  float32 state ``(heads, head_dim, state)`` and the last ``kernel - 1``
+  inputs of the convolution. It is not paged: prefill writes a slot's rows
+  whole, a decode step advances them in place.
+
+The expert layer serves one chip's share of an expert-parallel deployment
+(``experts_held`` of ``experts`` from ``expert_first``): the router keeps
+its published width and top-k, this chip computes the chosen pairs whose
+expert it holds and the shared expert (models/moe.py, dropless), and the
+partial result goes on to the next layer.
+
+Attention applies no rotary embedding (the family's attention applies
+none; ``rope_theta`` is carried because the published config has it).
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import math
+import re
+from typing import Any, Callable
+
+import jax
+import jax.numpy as jnp
+
+from langstream_tpu.models.llama import _flash_mode, _rms_norm
+from langstream_tpu.models.llama_paged import (
+    _cache_partial_xla,
+    pack_tokens_logprobs,
+)
+from langstream_tpu.models.moe import relu2_experts, sigmoid_topk_routing
+from langstream_tpu.ops.paged_attention import (
+    NEG_INF,
+    merge_partial_attention,
+    paged_attention_partial,
+)
+
+NEMOTRON3_NANO_PATTERN = "MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*EMEMEMEME"
+
+
+@dataclasses.dataclass(frozen=True)
+class HybridConfig:
+    # the fields the dense family's config has, under the same names
+    vocab_size: int = 16384
+    hidden: int = 2688
+    layers: int = 52
+    heads: int = 32
+    kv_heads: int = 2
+    head_dim: int = 128
+    intermediate: int = 1856          # one routed expert's width
+    rope_theta: float = 10000.0       # published; unused (no rotary)
+    norm_eps: float = 1e-5
+    max_seq_len: int = 2048
+    dtype: Any = jnp.bfloat16
+    pattern: str = NEMOTRON3_NANO_PATTERN
+    # Mamba-2
+    ssm_heads: int = 64
+    ssm_head_dim: int = 64
+    ssm_groups: int = 8
+    ssm_state: int = 128
+    conv_kernel: int = 4
+    chunk_size: int = 128
+    state_dtype: Any = jnp.float32
+    # experts
+    experts: int = 128
+    experts_per_token: int = 6
+    shared_intermediate: int = 3712
+    routed_scale: float = 2.5
+    router_dtype: Any = jnp.float32   # published; lower only as a control
+    # this chip's share of the expert-parallel deployment
+    experts_held: int = 16
+    expert_first: int = 0
+
+    def __post_init__(self):
+        if not re.fullmatch(r"(M\*?E)+", self.pattern):
+            raise ValueError(
+                f"pattern {self.pattern!r} is not a run of blocks M[*]E"
+            )
+        if len(self.pattern) != self.layers:
+            raise ValueError(
+                f"layers={self.layers} but the pattern has "
+                f"{len(self.pattern)}"
+            )
+        if not 0 <= self.expert_first <= self.experts - self.experts_held:
+            raise ValueError("the held experts lie outside the router's")
+
+    @classmethod
+    def nemotron3_nano_ep8(cls, max_seq_len: int = 2048) -> "HybridConfig":
+        """NVIDIA-Nemotron-3-Nano-30B-A3B as one chip of eight that share
+        each layer: 16 of 128 experts and 16,384 of 131,072 vocabulary rows
+        held here, mixers and the shared expert whole, all 52 layers."""
+        return cls(max_seq_len=max_seq_len)
+
+    @classmethod
+    def tiny(cls, max_seq_len: int = 128, expert_first: int = 0) -> "HybridConfig":
+        """Test size: the same family, at least two of each kind."""
+        return cls(
+            vocab_size=384, hidden=64, layers=8, heads=4, kv_heads=2,
+            head_dim=16, intermediate=32, pattern="MEM*EM*E",
+            ssm_heads=8, ssm_head_dim=8, ssm_groups=2, ssm_state=16,
+            conv_kernel=4, chunk_size=16, experts=8, experts_per_token=3,
+            shared_intermediate=48, experts_held=2,
+            expert_first=expert_first, max_seq_len=max_seq_len,
+        )
+
+    @property
+    def blocks(self) -> tuple[bool, ...]:
+        """One entry a block ``M [*] E``: whether it has the attention."""
+        return tuple("*" in b for b in re.findall(r"M\*?E", self.pattern))
+
+    @property
+    def attn_layers(self) -> int:
+        return self.pattern.count("*")
+
+    @property
+    def d_inner(self) -> int:
+        return self.ssm_heads * self.ssm_head_dim
+
+    @property
+    def conv_dim(self) -> int:
+        return self.d_inner + 2 * self.ssm_groups * self.ssm_state
+
+    @property
+    def state_bytes_per_slot(self) -> int:
+        """Recurrent state and convolution tail of one slot, all layers."""
+        n = len(self.blocks)
+        ssm = (self.ssm_heads * self.ssm_head_dim * self.ssm_state
+               * jnp.dtype(self.state_dtype).itemsize)
+        conv = ((self.conv_kernel - 1) * self.conv_dim
+                * jnp.dtype(self.dtype).itemsize)
+        return n * (ssm + conv)
+
+
+# ---------------------------------------------------------------------------
+# parameters and state
+# ---------------------------------------------------------------------------
+
+
+def init_hybrid_params(config: HybridConfig, key: jax.Array | None = None) -> dict:
+    """Random parameters from a key, one jitted draw a leaf so that the
+    float32 draw of a big leaf never sits beside the whole tree. An
+    expert's weights depend on its GLOBAL id, so the shares of one
+    deployment are slices of the same 128 experts. ``A_log``, ``dt_bias``,
+    ``D`` and the router's correction bias are drawn well away from
+    trivial values: a term left out changes the logits."""
+    c = config
+    key = key if key is not None else jax.random.PRNGKey(0)
+    n = len(c.blocks)
+    nA = c.attn_layers
+    H, I, Is = c.hidden, c.intermediate, c.shared_intermediate
+    names = iter(range(10 ** 6))
+
+    def normal(shape, fan_in, dtype=None):
+        k = jax.random.fold_in(key, next(names))
+        scale = 1.0 / math.sqrt(fan_in)
+        return jax.jit(
+            lambda k: (jax.random.normal(k, shape, jnp.float32) * scale
+                       ).astype(dtype or c.dtype)
+        )(k)
+
+    def uniform(shape, lo, hi):
+        k = jax.random.fold_in(key, next(names))
+        return jax.random.uniform(k, shape, jnp.float32, lo, hi)
+
+    def experts(shape, fan_in):
+        """(blocks, held) + shape, expert e of block i from (i, global e)."""
+        k = jax.random.fold_in(key, next(names))
+        scale = 1.0 / math.sqrt(fan_in)
+
+        def one(i, e):
+            ke = jax.random.fold_in(jax.random.fold_in(k, i), e)
+            return (jax.random.normal(ke, shape, jnp.float32) * scale
+                    ).astype(c.dtype)
+
+        held = c.expert_first + jnp.arange(c.experts_held)
+        return jax.jit(jax.vmap(
+            lambda i: jax.vmap(lambda e: one(i, e))(held)
+        ))(jnp.arange(n))
+
+    dt = jnp.exp(uniform((n, c.ssm_heads), math.log(0.001), math.log(0.1)))
+    return {
+        "embed": normal((c.vocab_size, H), 1.0),
+        "final_norm": jnp.ones((H,), c.dtype),
+        "lm_head": normal((H, c.vocab_size), H),
+        "mamba": {
+            "norm": jnp.ones((n, H), c.dtype),
+            # [z | xBC | dt] = W_in u, the published fused projection as
+            # its three column blocks: the fused width (10304 at the
+            # published sizes) is no multiple of the 128-lane tile, and
+            # the runtime then keeps the array transposed and the program
+            # copies all of it back before every chunk
+            "w_z": normal((n, H, c.d_inner), H),
+            "w_xbc": normal((n, H, c.conv_dim), H),
+            "w_dt": normal((n, H, c.ssm_heads), H),
+            "conv_w": normal((n, c.conv_dim, c.conv_kernel), c.conv_kernel),
+            "conv_b": normal((n, c.conv_dim), 25.0),
+            # softplus(dt_bias) is log-uniform in [0.001, 0.1]; A in [1, 16]
+            "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+            "A_log": jnp.log(uniform((n, c.ssm_heads), 1.0, 16.0)),
+            "D": uniform((n, c.ssm_heads), 0.5, 1.5),
+            "gate_norm": jnp.ones((n, c.d_inner), c.dtype),
+            "w_out": normal((n, c.d_inner, H), c.d_inner),
+        },
+        "attn": {
+            "norm": jnp.ones((nA, H), c.dtype),
+            "wq": normal((nA, H, c.heads * c.head_dim), H),
+            "wk": normal((nA, H, c.kv_heads * c.head_dim), H),
+            "wv": normal((nA, H, c.kv_heads * c.head_dim), H),
+            "wo": normal((nA, c.heads * c.head_dim, H), c.heads * c.head_dim),
+        },
+        "moe": {
+            "norm": jnp.ones((n, H), c.dtype),
+            "router": normal((n, H, c.experts), H, jnp.float32),
+            # small beside the spread of the scores, as a trained bias is:
+            # the scores decide the winners and the bias the near-ties
+            "bias": uniform((n, c.experts), -0.02, 0.02),
+            # both (held, I, H): the expert width (1856) is no multiple of
+            # the lane tile either, so the up-projection is kept output-
+            # major and contracts its last axis
+            "w_up": experts((I, H), H),
+            "w_down": experts((I, H), I),
+            "ws_up": normal((n, H, Is), H),
+            "ws_down": normal((n, Is, H), Is),
+        },
+    }
+
+
+def init_hybrid_pool(config: HybridConfig, layout) -> tuple[jax.Array, jax.Array]:
+    """The paged pool of the attention layers alone: ``(attention layers,
+    num_blocks, block_size, Kh*D)`` for K and for V."""
+    c = config
+    shape = (c.attn_layers, layout.num_blocks, layout.block_size,
+             c.kv_heads * c.head_dim)
+    return jnp.zeros(shape, c.dtype), jnp.zeros(shape, c.dtype)
+
+
+def init_hybrid_state(config: HybridConfig, slots: int) -> dict:
+    """``{"ssm": (blocks, slots, heads, head_dim, state), "conv": (blocks,
+    slots, kernel - 1, conv_dim)}``, zeros."""
+    c = config
+    n = len(c.blocks)
+    return {
+        "ssm": jnp.zeros(
+            (n, slots, c.ssm_heads, c.ssm_head_dim, c.ssm_state), c.state_dtype
+        ),
+        "conv": jnp.zeros((n, slots, c.conv_kernel - 1, c.conv_dim), c.dtype),
+    }
+
+
+# ---------------------------------------------------------------------------
+# Mamba-2
+# ---------------------------------------------------------------------------
+
+
+def _project_in(lp: dict, u: jax.Array):
+    """``[z | xBC | dt] = W_in u`` by its three column blocks."""
+    return u @ lp["w_z"], u @ lp["w_xbc"], u @ lp["w_dt"]
+
+
+def _split_xbc(c: HybridConfig, xbc: jax.Array):
+    lead = xbc.shape[:-1]
+    gn = c.ssm_groups * c.ssm_state
+    x = xbc[..., : c.d_inner].reshape(lead + (c.ssm_heads, c.ssm_head_dim))
+    b = xbc[..., c.d_inner : c.d_inner + gn].reshape(
+        lead + (c.ssm_groups, c.ssm_state))
+    cm = xbc[..., c.d_inner + gn :].reshape(lead + (c.ssm_groups, c.ssm_state))
+    return x, b, cm
+
+
+def _gated_out(c: HybridConfig, lp: dict, y: jax.Array, z: jax.Array):
+    """``W_out (RMSNorm_groups(y * silu(z)) * w)``; y float32 (..., d_inner)."""
+    g = y * jax.nn.silu(z.astype(jnp.float32))
+    lead = g.shape[:-1]
+    g = g.reshape(lead + (c.ssm_groups, c.d_inner // c.ssm_groups))
+    g = g * jax.lax.rsqrt(jnp.mean(g * g, axis=-1, keepdims=True) + c.norm_eps)
+    g = g.reshape(lead + (c.d_inner,)).astype(c.dtype) * lp["gate_norm"]
+    return g @ lp["w_out"]
+
+
+def ssd_chunked(
+    x: jax.Array,     # (B, P, heads, head_dim)
+    dt: jax.Array,    # (B, P, heads) float32, 0 where a row is padding
+    A: jax.Array,     # (heads,) float32, negative
+    Bm: jax.Array,    # (B, P, groups, state)
+    Cm: jax.Array,    # (B, P, groups, state)
+    chunk: int,
+) -> tuple[jax.Array, jax.Array]:
+    """The Mamba-2 recurrence ``h_t = exp(dt_t A) h_{t-1} + dt_t x_t B_t^T``,
+    ``y_t = h_t C_t`` over a whole prompt in chunks: inside a chunk as one
+    masked matmul, between chunks as a scan over their states. Returns
+    ``(y (B, P, heads, head_dim) float32, h_P (B, heads, head_dim, state)
+    float32)``. A position with ``dt = 0`` neither decays nor feeds the
+    state, so a right-padded row ends with the state after its last token."""
+    Bsz, Pn, h, p = x.shape
+    g, n = Bm.shape[2:]
+    j = h // g
+    L = min(chunk, Pn)
+    nc = Pn // L
+    f32 = jnp.float32
+    a = (dt * A).reshape(Bsz, nc, L, g, j)
+    dtc = dt.reshape(Bsz, nc, L, g, j)
+    xc = x.reshape(Bsz, nc, L, g, j, p)
+    Bc = Bm.reshape(Bsz, nc, L, g, n)
+    Cc = Cm.reshape(Bsz, nc, L, g, n)
+    a_cs = jnp.cumsum(a, axis=2)                          # (B,nc,L,g,j)
+    # inside the chunk: (C B^T) x decay(l <- s) x dt_s, applied to x
+    # float32 operands: a TPU's default precision multiplies them in one
+    # bfloat16 pass and accumulates, and returns, float32
+    xc, Bc, Cc = xc.astype(f32), Bc.astype(f32), Cc.astype(f32)
+    cb = jnp.einsum("bclgn,bcsgn->bclsg", Cc, Bc)
+    seg = a_cs[:, :, :, None] - a_cs[:, :, None, :]       # (B,nc,L,L,g,j)
+    causal = (jnp.arange(L)[:, None] >= jnp.arange(L)[None, :])[
+        None, None, :, :, None, None]
+    w = jnp.where(causal, jnp.exp(jnp.where(causal, seg, 0.0)), 0.0)
+    w = w * cb[..., None] * dtc[:, :, None]
+    y = jnp.einsum("bclsgj,bcsgjp->bclgjp", w, xc)
+    # each chunk's own contribution to the state at its end
+    to_end = jnp.exp(a_cs[:, :, -1:] - a_cs) * dtc        # (B,nc,L,g,j)
+    own = jnp.einsum("bclgjp,bclgn->bcgjpn", xc * to_end[..., None], Bc)
+    chunk_decay = jnp.exp(a_cs[:, :, -1])                 # (B,nc,g,j)
+
+    def carry_state(hc, inp):
+        own_c, decay_c = inp
+        return hc * decay_c[..., None, None] + own_c, hc
+
+    h_last, h_in = jax.lax.scan(
+        carry_state, jnp.zeros((Bsz, g, j, p, n), f32),
+        (own.swapaxes(0, 1), chunk_decay.swapaxes(0, 1)),
+    )
+    h_in = h_in.swapaxes(0, 1)                            # (B,nc,g,j,p,n)
+    y = y + jnp.einsum(
+        "bclgn,bcgjpn->bclgjp", Cc, h_in,
+    ) * jnp.exp(a_cs)[..., None]
+    return y.reshape(Bsz, Pn, h, p), h_last.reshape(Bsz, h, p, n)
+
+
+def mamba_prefill(c: HybridConfig, lp: dict, u: jax.Array, lengths: jax.Array):
+    """The Mamba-2 mixer over right-padded prompts ``u (B, P, H)`` (already
+    normed). Returns ``(out (B, P, H), state (B, heads, head_dim, state),
+    conv tail (B, kernel - 1, conv_dim))``, state and tail as they stand
+    after each row's last real token."""
+    B, Pn, _ = u.shape
+    k = c.conv_kernel
+    with jax.named_scope("ssm_in"):
+        z, xbc, dt = _project_in(lp, u)
+    with jax.named_scope("ssm_conv"):
+        padded = jnp.pad(xbc, ((0, 0), (k - 1, 0), (0, 0)))
+        conv = sum(
+            padded[:, i : i + Pn].astype(jnp.float32)
+            * lp["conv_w"][:, i].astype(jnp.float32)
+            for i in range(k)
+        ) + lp["conv_b"].astype(jnp.float32)
+        x, Bm, Cm = _split_xbc(c, jax.nn.silu(conv).astype(c.dtype))
+        # the last k-1 inputs of each row: padded[n .. n+k-2] are the
+        # original positions n-k+1 .. n-1 (zeros before the prompt)
+        tail = jnp.take_along_axis(
+            padded, (lengths[:, None] + jnp.arange(k - 1)[None, :])[..., None],
+            axis=1,
+        )
+    with jax.named_scope("ssm_scan"):
+        real = jnp.arange(Pn)[None, :] < lengths[:, None]
+        dt = jax.nn.softplus(dt.astype(jnp.float32) + lp["dt_bias"])
+        dt = jnp.where(real[..., None], dt, 0.0)
+        y, state = ssd_chunked(x, dt, -jnp.exp(lp["A_log"]), Bm, Cm,
+                               c.chunk_size)
+        y = y + lp["D"][:, None] * x.astype(jnp.float32)
+    with jax.named_scope("ssm_out"):
+        out = _gated_out(c, lp, y.reshape(B, Pn, c.d_inner), z)
+    return out, state.astype(c.state_dtype), tail
+
+
+def mamba_step(c: HybridConfig, lp: dict, u: jax.Array, ssm: jax.Array,
+               conv: jax.Array, i: jax.Array, active: jax.Array):
+    """One token a slot through Mamba-2 layer ``i``: ``u (B, H)`` normed,
+    ``ssm (layers, B, heads, head_dim, state)`` and ``conv (layers, B,
+    kernel - 1, conv_dim)`` the stacked state of every layer, of which this
+    one's rows are read and replaced in place (under the scopes, so that a
+    trace charges the state's traffic to ``ssm_scan`` and the tail's to
+    ``ssm_conv``). A slot that is not active keeps its rows."""
+    B = u.shape[0]
+    j = c.ssm_heads // c.ssm_groups
+    with jax.named_scope("ssm_in"):
+        z, xbc, dt = _project_in(lp, u)
+    with jax.named_scope("ssm_conv"):
+        tail = jax.lax.dynamic_index_in_dim(conv, i, keepdims=False)
+        window = jnp.concatenate([tail, xbc[:, None]], axis=1)   # (B, k, C)
+        out = jnp.einsum(
+            "bkc,ck->bc", window.astype(jnp.float32),
+            lp["conv_w"].astype(jnp.float32),
+        ) + lp["conv_b"].astype(jnp.float32)
+        x, Bm, Cm = _split_xbc(c, jax.nn.silu(out).astype(c.dtype))
+        conv = jax.lax.dynamic_update_index_in_dim(
+            conv, jnp.where(active[:, None, None], window[:, 1:], tail), i, 0)
+    with jax.named_scope("ssm_scan"):
+        f32 = jnp.float32
+        state = jax.lax.dynamic_index_in_dim(ssm, i, keepdims=False)
+        dt = jax.nn.softplus(dt.astype(f32) + lp["dt_bias"])     # (B, heads)
+        decay = jnp.exp(dt * -jnp.exp(lp["A_log"]))
+        xf = x.astype(f32)
+        Bh = jnp.repeat(Bm.astype(f32), j, axis=1)               # (B, heads, n)
+        Ch = jnp.repeat(Cm.astype(f32), j, axis=1)
+        new = (state.astype(f32) * decay[..., None, None]
+               + (dt[..., None] * xf)[..., None] * Bh[:, :, None, :])
+        y = jnp.einsum("bhpn,bhn->bhp", new, Ch) + lp["D"][:, None] * xf
+        ssm = jax.lax.dynamic_update_index_in_dim(
+            ssm,
+            jnp.where(active[:, None, None, None], new.astype(ssm.dtype), state),
+            i, 0)
+    with jax.named_scope("ssm_out"):
+        out = _gated_out(c, lp, y.reshape(B, c.d_inner), z)
+    return out, ssm, conv
+
+
+# ---------------------------------------------------------------------------
+# experts
+# ---------------------------------------------------------------------------
+
+
+def moe_mixer(c: HybridConfig, lp: dict, h: jax.Array, valid: jax.Array,
+              layer: jax.Array | None = None):
+    """Routed experts held here plus the shared expert over rows ``h (T,
+    H)``; ``lp`` is one expert layer's weights, or with ``layer`` its
+    ``w_up`` and ``w_down`` are the stacks of every layer's
+    (models/moe.py ``relu2_experts_grouped``). Returns ``(out (T, H), load (experts_held,) int32, chosen experts
+    (T, k))``; ``valid`` rows are the ones that count (padding and idle
+    slots route nowhere)."""
+    with jax.named_scope("moe_router"):
+        experts, weights = sigmoid_topk_routing(
+            h, lp["router"], lp["bias"], c.experts_per_token, c.routed_scale,
+            c.router_dtype,
+        )
+    routed, load = relu2_experts(
+        h, experts, weights, lp["w_up"], lp["w_down"], c.expert_first, valid,
+        layer=layer,
+    )
+    with jax.named_scope("moe_shared"):
+        shared = jnp.square(jax.nn.relu(h @ lp["ws_up"])) @ lp["ws_down"]
+    with jax.named_scope("moe_combine"):
+        return (routed + shared.astype(jnp.float32)).astype(h.dtype), load, experts
+
+
+# ---------------------------------------------------------------------------
+# the pool of the attention layers
+# ---------------------------------------------------------------------------
+
+
+def write_rows(pool: jax.Array, rows: jax.Array, block_tables: jax.Array,
+               starts: jax.Array, valid: jax.Array) -> jax.Array:
+    """``rows (layers, B, T, Kh*D)`` into ``pool (layers, nb, bs, Kh*D)`` at
+    each slot's block-mapped positions from ``starts``; rows that are not
+    ``valid`` land in block 0, the scratch block (models/paged.py). One
+    scatter of rows into the pool seen as ``(layers * nb * bs, Kh*D)``: with
+    the layer folded into the row index there is no layer axis for the
+    compiler to move inward, which is what makes it copy the whole pool
+    around the dense family's commit."""
+    L, nb, bs, KhD = pool.shape
+    B, T = rows.shape[1:3]
+    pos = starts[:, None] + jnp.arange(T)[None, :]
+    block = jnp.take_along_axis(
+        block_tables, jnp.clip(pos // bs, 0, block_tables.shape[1] - 1), axis=1)
+    flat = jnp.where(valid, block * bs + pos % bs, 0).reshape(-1)     # (B*T,)
+    index = (jnp.arange(L)[:, None] * (nb * bs) + flat[None, :]).reshape(-1)
+    return pool.reshape(L * nb * bs, KhD).at[index].set(
+        rows.reshape(L * B * T, KhD)).reshape(pool.shape)
+
+
+# ---------------------------------------------------------------------------
+# prefill
+# ---------------------------------------------------------------------------
+
+
+def _block_xs(c: HybridConfig, params: dict, experts_in_xs: bool = True):
+    """What the scan over blocks slices a block at a time. A prefill keeps
+    the routed experts' stacks out (``experts_in_xs=False``) and reads them
+    by the block's index (:func:`moe_mixer` ``layer``)."""
+    has = jnp.asarray(c.blocks)
+    # a block without attention points at the spare row past the last layer
+    idx = jnp.where(has, jnp.cumsum(has) - 1, c.attn_layers).astype(jnp.int32)
+    moe = params["moe"] if experts_in_xs else {
+        k: v for k, v in params["moe"].items() if k not in ("w_up", "w_down")}
+    return (params["mamba"], moe, has, idx,
+            jnp.arange(len(c.blocks), dtype=jnp.int32))
+
+
+def _attn_weights(params: dict, a: jax.Array) -> dict:
+    return jax.tree.map(
+        lambda t: jax.lax.dynamic_index_in_dim(t, a, keepdims=False),
+        params["attn"],
+    )
+
+
+def hybrid_prefill_paged(
+    config: HybridConfig,
+    params: dict,
+    tokens: jax.Array,        # (B, P) int32, right-padded
+    lengths: jax.Array,       # (B,) true lengths
+    pool_k: jax.Array,        # (attention layers, nb, bs, Kh*D)
+    pool_v: jax.Array,
+    state: dict,              # init_hybrid_state: all slots
+    block_tables: jax.Array,  # (B, max_blocks): rows of THIS batch
+    slot_ids: jax.Array,      # (B,) the slots whose state rows are written
+    use_flash: bool | None = None,
+):
+    """Prompt forward: the attention layers' K/V rows land in the pool, and
+    each row's recurrent state and convolution tail, as they stand after its
+    last real token, overwrite its slot's rows of ``state``. Returns
+    ``(last-token logits (B, V), pool_k, pool_v, state, routed)``; ``routed
+    (blocks, B, P, k)`` are the experts the router chose, for the reference
+    check (a caller that drops it pays nothing for it)."""
+    c = config
+    B, Pn = tokens.shape
+    nA = c.attn_layers
+    KhD = c.kv_heads * c.head_dim
+    G = c.heads // c.kv_heads
+    real = jnp.arange(Pn)[None, :] < lengths[:, None]             # (B, P)
+    flash = (_flash_mode(Pn) if use_flash is None
+             else ("compiled" if use_flash else None))
+    with jax.named_scope("embed"):
+        x = params["embed"][tokens]
+
+    def attention(x, a):
+        ap = _attn_weights(params, a)
+        with jax.named_scope("attn_qkv"):
+            h = _rms_norm(x, ap["norm"], c.norm_eps)
+            q = jnp.einsum("bph,hd->bpd", h, ap["wq"]).reshape(
+                B, Pn, c.heads, c.head_dim)
+            k = jnp.einsum("bph,hd->bpd", h, ap["wk"]).reshape(
+                B, Pn, c.kv_heads, c.head_dim)
+            v = jnp.einsum("bph,hd->bpd", h, ap["wv"]).reshape(
+                B, Pn, c.kv_heads, c.head_dim)
+        if flash is not None:
+            with jax.named_scope("flash"):
+                # causality alone hides the right-padding from real rows
+                from langstream_tpu.ops.flash_attention import flash_attention
+
+                out = flash_attention(
+                    q, k, v, causal=True, interpret=(flash == "interpret"))
+        else:
+            with jax.named_scope("kv_read"):
+                qg = q.reshape(B, Pn, c.kv_heads, G, c.head_dim)
+                s = jnp.einsum("bqkgd,bskd->bkgqs", qg, k).astype(jnp.float32)
+                s = s / math.sqrt(c.head_dim)
+                mask = (jnp.arange(Pn)[:, None] >= jnp.arange(Pn)[None, :])[
+                    None] & real[:, None, :]
+                s = jnp.where(mask[:, None, None], s, NEG_INF)
+                out = jnp.einsum(
+                    "bkgqs,bskd->bqkgd", jax.nn.softmax(s, -1).astype(x.dtype), v)
+        with jax.named_scope("attn_out"):
+            out = out.reshape(B, Pn, c.heads * c.head_dim)
+            x = x + jnp.einsum("bpd,dh->bph", out, ap["wo"])
+        return x, k.reshape(B, Pn, KhD), v.reshape(B, Pn, KhD)
+
+    def no_attention(x, a):
+        zero = jnp.zeros((B, Pn, KhD), x.dtype)
+        return x, zero, zero
+
+    def block(carry, xs):
+        x, ks, vs, ssm_all, conv_all = carry
+        mp, ep, has, a, i = xs
+        out, ssm, tail = mamba_prefill(
+            c, mp, _rms_norm(x, mp["norm"], c.norm_eps), lengths)
+        with jax.named_scope("ssm_state_write"):
+            # this layer's rows of the batch's slots, in the carry: the
+            # whole state is never stacked beside itself
+            ssm_all = ssm_all.at[i, slot_ids].set(ssm)
+            conv_all = conv_all.at[i, slot_ids].set(tail)
+        x = x + out
+        x, k, v = jax.lax.cond(has, attention, no_attention, x, a)
+        ks = jax.lax.dynamic_update_index_in_dim(ks, k, a, 0)
+        vs = jax.lax.dynamic_update_index_in_dim(vs, v, a, 0)
+        h = _rms_norm(x, ep["norm"], c.norm_eps).reshape(B * Pn, c.hidden)
+        ep = dict(ep, w_up=params["moe"]["w_up"], w_down=params["moe"]["w_down"])
+        out, _, chosen = moe_mixer(c, ep, h, real.reshape(-1), layer=i)
+        return (x + out.reshape(B, Pn, c.hidden), ks, vs, ssm_all, conv_all), \
+            chosen.reshape(B, Pn, -1)
+
+    spare = jnp.zeros((nA + 1, B, Pn, KhD), c.dtype)
+    (x, ks, vs, ssm_all, conv_all), routed = jax.lax.scan(
+        block, (x, spare, spare, state["ssm"], state["conv"]),
+        _block_xs(c, params, experts_in_xs=False))
+    with jax.named_scope("lm_head"):
+        x = _rms_norm(x, params["final_norm"], c.norm_eps)
+        last = jnp.take_along_axis(
+            x, (lengths - 1)[:, None, None].clip(0), axis=1).squeeze(1)
+        logits = (last @ params["lm_head"]).astype(jnp.float32)
+    starts = jnp.zeros((B,), jnp.int32)
+    with jax.named_scope("kv_write"):
+        pool_k = write_rows(pool_k, ks[:nA], block_tables, starts, real)
+        pool_v = write_rows(pool_v, vs[:nA], block_tables, starts, real)
+    return logits, pool_k, pool_v, {"ssm": ssm_all, "conv": conv_all}, routed
+
+
+# ---------------------------------------------------------------------------
+# decode
+# ---------------------------------------------------------------------------
+
+
+def hybrid_decode_chunk_paged(
+    config: HybridConfig,
+    params: dict,
+    tokens0: jax.Array,       # (B,)
+    base_lengths: jax.Array,  # (B,)
+    active: jax.Array,        # (B,) bool
+    pool_k: jax.Array,        # read-only during the chunk
+    pool_v: jax.Array,
+    state: dict,              # advanced in the scan's carry
+    block_tables: jax.Array,  # (B, max_blocks)
+    sample_fn: Callable,
+    key: jax.Array,
+    num_steps: int,
+    num_read_blocks: int,
+    kernel: str = "xla",
+    sample_extras=None,       # (presences, frequencies, counts0)
+    return_packed: bool = False,
+):
+    """K fused decode steps. The pool is read-only and the new K/V rows of
+    the attention layers gather in a chunk buffer (one scatter at the end),
+    as in the dense family's chunk; the recurrent state rides the scan's
+    carry and each Mamba-2 layer replaces its own rows of it in place.
+
+    Returns ``(chunk_tokens, chunk_logprobs, final_tokens, final_lengths,
+    pool_k, pool_v, state, load, routed)`` where ``load (blocks,
+    experts_held)`` counts the chosen pairs each held expert got over the
+    chunk's active rows and ``routed (steps, blocks, B, k)`` are the experts
+    the router chose (for the reference check); ``return_packed=True`` folds
+    tokens, logprobs and ``load`` into one int32 array in their place and
+    leaves ``routed`` out."""
+    c = config
+    B = tokens0.shape[0]
+    nA, nB = c.attn_layers, len(c.blocks)
+    KhD = c.kv_heads * c.head_dim
+    G = c.heads // c.kv_heads
+    scale = 1.0 / math.sqrt(c.head_dim)
+    adv = active.astype(jnp.int32)
+    pen = sample_extras is not None
+    counts0 = sample_extras[2] if pen else None
+    block_xs = _block_xs(c, params)
+
+    def cache_partial(q, a):
+        if kernel == "xla":
+            at = lambda t: jax.lax.dynamic_index_in_dim(t, a, keepdims=False)  # noqa: E731
+            return _cache_partial_xla(
+                c, q, at(pool_k), at(pool_v), block_tables, base_lengths,
+                num_read_blocks,
+            )
+        return paged_attention_partial(
+            q, pool_k, pool_v, a, block_tables, base_lengths,
+            num_read_blocks=num_read_blocks, kv_heads=c.kv_heads,
+            head_dim=c.head_dim, scale=scale,
+            interpret=(kernel == "pallas-interpret"),
+        )
+
+    def step(carry, step_idx):
+        tokens, kbuf, vbuf, key, ssm, conv, load = carry[:7]
+        counts = carry[7] if pen else None
+        with jax.named_scope("sample"):
+            key, sub = jax.random.split(key)
+        with jax.named_scope("embed"):
+            x = params["embed"][tokens]
+        buf_mask = jnp.arange(num_steps)[None, :] <= step_idx      # (1, K)
+
+        def attention(x, a, kbuf, vbuf):
+            ap = _attn_weights(params, a)
+            with jax.named_scope("attn_qkv"):
+                h = _rms_norm(x, ap["norm"], c.norm_eps)
+                q = (h @ ap["wq"]).reshape(B, c.heads, c.head_dim)
+                k = (h @ ap["wk"]).reshape(B, c.kv_heads, c.head_dim)
+                v = (h @ ap["wv"]).reshape(B, c.kv_heads, c.head_dim)
+                # this layer's rows of the chunk so far, with this step's
+                kb = jax.lax.dynamic_update_slice_in_dim(
+                    jax.lax.dynamic_index_in_dim(kbuf, a, keepdims=False),
+                    k[:, None], step_idx, axis=1)                  # (B,K,Kh,D)
+                vb = jax.lax.dynamic_update_slice_in_dim(
+                    jax.lax.dynamic_index_in_dim(vbuf, a, keepdims=False),
+                    v[:, None], step_idx, axis=1)
+            with jax.named_scope("kv_read"):
+                acc_c, m_c, l_c = cache_partial(q, a)
+                qg = q.reshape(B, c.kv_heads, G, c.head_dim)
+                s = jnp.einsum("bkgd,btkd->bkgt", qg, kb).astype(jnp.float32)
+                s = jnp.where(buf_mask[:, None, None, :], s * scale, NEG_INF)
+                m_b = jnp.max(s, axis=-1)
+                p_b = jnp.where(
+                    buf_mask[:, None, None, :], jnp.exp(s - m_b[..., None]), 0.0)
+                acc_b = jnp.einsum(
+                    "bkgt,btkd->bkgd", p_b.astype(vb.dtype), vb
+                ).astype(jnp.float32)
+                out = merge_partial_attention([
+                    (acc_c, m_c, l_c),
+                    (acc_b.reshape(B, c.heads, c.head_dim),
+                     m_b.reshape(B, c.heads),
+                     jnp.sum(p_b, axis=-1).reshape(B, c.heads)),
+                ]).astype(x.dtype).reshape(B, c.heads * c.head_dim)
+            with jax.named_scope("attn_out"):
+                return x + out @ ap["wo"], k, v
+
+        def no_attention(x, a, kbuf, vbuf):
+            zero = jnp.zeros((B, c.kv_heads, c.head_dim), x.dtype)
+            return x, zero, zero
+
+        def block(carry, xs):
+            x, kbuf, vbuf, ssm, conv = carry
+            mp, ep, has, a, i = xs
+            out, ssm, conv = mamba_step(
+                c, mp, _rms_norm(x, mp["norm"], c.norm_eps), ssm, conv, i,
+                active)
+            x = x + out
+            x, k, v = jax.lax.cond(
+                has, attention, no_attention, x, a, kbuf, vbuf)
+            with jax.named_scope("attn_qkv"):
+                kbuf = jax.lax.dynamic_update_slice(
+                    kbuf, k[None, :, None], (a, 0, step_idx, 0, 0))
+                vbuf = jax.lax.dynamic_update_slice(
+                    vbuf, v[None, :, None], (a, 0, step_idx, 0, 0))
+            out, load_i, chosen = moe_mixer(
+                c, ep, _rms_norm(x, ep["norm"], c.norm_eps), active)
+            return (x + out, kbuf, vbuf, ssm, conv), (load_i, chosen)
+
+        (x, kbuf, vbuf, ssm, conv), (load_step, chosen) = jax.lax.scan(
+            block, (x, kbuf, vbuf, ssm, conv), block_xs)
+        with jax.named_scope("lm_head"):
+            x = _rms_norm(x, params["final_norm"], c.norm_eps)
+            logits = (x @ params["lm_head"]).astype(jnp.float32)
+        with jax.named_scope("sample"):
+            nxt, lp_ = (sample_fn(logits, sub, counts) if pen
+                        else sample_fn(logits, sub))
+            nxt = jnp.where(active, nxt, tokens)
+        out_carry = (nxt, kbuf, vbuf, key, ssm, conv, load + load_step)
+        if pen:
+            out_carry += (counts.at[jnp.arange(B), nxt].add(adv),)
+        return out_carry, (nxt, lp_, chosen)
+
+    kbuf0 = jnp.zeros((nA + 1, B, num_steps, c.kv_heads, c.head_dim), c.dtype)
+    carry0 = (tokens0, kbuf0, kbuf0, key, state["ssm"], state["conv"],
+              jnp.zeros((nB, c.experts_held), jnp.int32))
+    if pen:
+        carry0 += (counts0,)
+    out_carry, (chunk_tokens, chunk_lps, routed) = jax.lax.scan(
+        step, carry0, jnp.arange(num_steps))
+    final_tokens, kbuf, vbuf, _, ssm, conv, load = out_carry[:7]
+    valid = jnp.broadcast_to(active[:, None], (B, num_steps))
+    with jax.named_scope("kv_write"):
+        pool_k = write_rows(
+            pool_k, kbuf[:nA].reshape(nA, B, num_steps, KhD), block_tables,
+            base_lengths, valid)
+        pool_v = write_rows(
+            pool_v, vbuf[:nA].reshape(nA, B, num_steps, KhD), block_tables,
+            base_lengths, valid)
+    final_lengths = base_lengths + num_steps * adv
+    state = {"ssm": ssm, "conv": conv}
+    if return_packed:
+        packed = jnp.concatenate(
+            [pack_tokens_logprobs(chunk_tokens, chunk_lps), load.reshape(-1)])
+        return packed, final_tokens, final_lengths, pool_k, pool_v, state
+    return (chunk_tokens, chunk_lps, final_tokens, final_lengths, pool_k,
+            pool_v, state, load, routed)

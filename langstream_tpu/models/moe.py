@@ -362,3 +362,160 @@ def moe_param_count(config: MoEConfig) -> int:
     experts = c.experts * 3 * c.hidden * c.moe_intermediate
     per_layer = attn + experts + c.hidden * c.experts + 2 * c.hidden
     return c.layers * per_layer + 2 * c.vocab_size * c.hidden + c.hidden
+
+
+# ---------------------------------------------------------------------------
+# dropless top-k routing over the experts this chip holds
+# ---------------------------------------------------------------------------
+#
+# The capacity path above drops what overflows an expert, so a token's
+# output depends on its batch neighbours. The functions below drop nothing:
+# a router over ALL experts chooses k of them per token, and this chip
+# computes the chosen pairs whose expert it holds (``[first, first+held)``).
+# What the other chips' experts would add is left out here, as it would be
+# before the exchange in an expert-parallel deployment.
+
+#: rows up to which the dense pass over the held experts is taken: every
+#: held expert's weights are streamed anyway at a decode batch, and the
+#: pass has no sort, gather or scatter, while the grouped pass costs at
+#: least one 256-row block a held expert that any row chose. Measured on
+#: the v5e at the published share (16 experts of 2 x 2688 x 1856, top 6 of
+#: 128; tools/hybrid_probe.py --crossover, ms a layer, dense / grouped):
+#: 256 rows 0.56 / 1.44, 512 rows 1.09 / 1.24, 768 rows 1.56 / 1.29,
+#: 2,048 rows 4.36 / 1.47
+DENSE_ROWS_MAX = 512
+#: rows of one step of the grouped pass (one expert's weights a step)
+GROUP_BLOCK_ROWS = 256
+
+
+def sigmoid_topk_routing(
+    h: jax.Array,          # (T, H)
+    router: jax.Array,     # (H, E) float32
+    bias: jax.Array,       # (E,) float32: e_score_correction_bias
+    k: int,
+    scale: float,
+    dtype=jnp.float32,
+) -> tuple[jax.Array, jax.Array]:
+    """``(experts (T, k) int32, weights (T, k) float32)``: sigmoid scores
+    over all experts in float32, the top ``k`` of score + bias chosen, the
+    chosen scores (without the bias) normalised to sum 1 and scaled.
+    ``dtype`` below float32 (a control of the reference check, never
+    served) rounds the operands, the logits and the scores to it."""
+    f32 = jnp.float32
+    if jnp.dtype(dtype) == f32:
+        rounded = lambda x: x                                 # noqa: E731
+    else:
+        # not a pair of converts: XLA keeps the excess precision of those
+        info = jnp.finfo(dtype)
+        rounded = lambda x: jax.lax.reduce_precision(         # noqa: E731
+            x, info.nexp, info.nmant)
+    scores = rounded(jax.nn.sigmoid(rounded(jnp.dot(
+        rounded(h.astype(f32)), rounded(router.astype(f32)),
+        precision=jax.lax.Precision.HIGHEST,
+    ))))
+    _, experts = jax.lax.top_k(scores + bias.astype(jnp.float32), k)
+    chosen = jnp.take_along_axis(scores, experts, axis=-1)
+    weights = chosen / (chosen.sum(axis=-1, keepdims=True) + 1e-20) * scale
+    return experts.astype(jnp.int32), weights
+
+
+def relu2_experts_dense(
+    x: jax.Array,          # (T, H)
+    experts: jax.Array,    # (T, k) global expert ids
+    weights: jax.Array,    # (T, k) float32
+    w_up: jax.Array,       # (held, I, H): output-major, contracts H
+    w_down: jax.Array,     # (held, I, H)
+    first: int,
+    valid: jax.Array | None = None,   # (T,) rows that count
+) -> tuple[jax.Array, jax.Array]:
+    """Every held expert over every row, combined with the routing weight
+    (zero for a pair that was not chosen): exact, and free of any
+    dependence on the other rows. Returns ``(out (T, H) float32,
+    load (held,) int32)``: the chosen pairs each held expert got."""
+    held = w_up.shape[0]
+    with jax.named_scope("moe_dispatch"):
+        hit = (experts - first)[..., None] == jnp.arange(held)   # (T, k, held)
+        if valid is not None:
+            hit = hit & valid[:, None, None]
+        combine = jnp.sum(jnp.where(hit, weights[..., None], 0.0), axis=1)
+        load = hit.sum(axis=(0, 1)).astype(jnp.int32)
+    with jax.named_scope("moe_experts"):
+        up = jnp.einsum("th,eih->eti", x, w_up)
+        act = jnp.square(jax.nn.relu(up))
+        down = jnp.einsum("eti,eih->eth", act, w_down)
+    with jax.named_scope("moe_combine"):
+        out = jnp.einsum("eth,te->th", down.astype(jnp.float32), combine)
+    return out, load
+
+
+def relu2_experts_grouped(
+    x: jax.Array, experts: jax.Array, weights: jax.Array,
+    w_up: jax.Array, w_down: jax.Array, first: int,
+    valid: jax.Array | None = None, block_rows: int = GROUP_BLOCK_ROWS,
+    layer: jax.Array | None = None,
+) -> tuple[jax.Array, jax.Array]:
+    """The chosen pairs sorted by held expert, each expert's run padded to
+    whole blocks of ``block_rows``, and one block a step: gather its rows,
+    the expert's two matmuls, scale by the routing weight, scatter-add. The
+    loop runs as many steps as there are blocks, so the work follows the
+    pairs routed here and not the worst case. Same results as
+    :func:`relu2_experts_dense`.
+
+    With ``layer``, ``w_up`` and ``w_down`` are the stacks ``(layers, held,
+    I, H)`` of a model that scans its layers, and a step reads
+    ``w_up[layer, e]`` from them: the scan's own slice of the layer would be
+    a copy of all its held experts a layer (the loop inside cannot read
+    through it), whoever is chosen."""
+    T, k = experts.shape
+    held = w_up.shape[-3]
+    at = (lambda w, e: w[e]) if layer is None else (lambda w, e: w[layer, e])
+    R = block_rows
+    with jax.named_scope("moe_dispatch"):
+        local = experts - first
+        here = (local >= 0) & (local < held)
+        if valid is not None:
+            here = here & valid[:, None]
+        group = jnp.where(here, local, held).reshape(-1)          # (T*k,)
+        order = jnp.argsort(group)          # stable: pairs by held expert
+        counts = jnp.bincount(group, length=held + 1)[:held].astype(jnp.int32)
+        sorted_start = jnp.cumsum(counts) - counts
+        blocks_of = -(-counts // R)
+        block_end = jnp.cumsum(blocks_of)
+        num_blocks = block_end[-1]
+        pair_weight = weights.reshape(-1)
+
+    def step(b, out):
+        with jax.named_scope("moe_dispatch"):
+            e = jnp.searchsorted(block_end, b, side="right").astype(jnp.int32)
+            within = (b - (block_end[e] - blocks_of[e])) * R + jnp.arange(R)
+            live = within < counts[e]
+            pair = order[jnp.clip(sorted_start[e] + within, 0, T * k - 1)]
+            row = pair // k
+            xb = x[row]
+        with jax.named_scope("moe_experts"):
+            up = jnp.einsum("th,ih->ti", xb, at(w_up, e))
+            down = jnp.dot(jnp.square(jax.nn.relu(up)), at(w_down, e))
+        with jax.named_scope("moe_combine"):
+            scale = jnp.where(live, pair_weight[pair], 0.0)
+            return out.at[jnp.where(live, row, T)].add(
+                down.astype(jnp.float32) * scale[:, None], mode="drop"
+            )
+
+    out = jax.lax.fori_loop(
+        0, num_blocks, step, jnp.zeros((T, x.shape[1]), jnp.float32)
+    )
+    return out, counts
+
+
+def relu2_experts(x, experts, weights, w_up, w_down, first, valid=None,
+                  layer=None):
+    """Dropless routed experts (non-gated relu-squared): the dense pass for
+    a decode batch, the grouped pass for a prefill's rows. ``w_up`` and
+    ``w_down`` are one layer's ``(held, I, H)``, or with ``layer`` the
+    stacks ``(layers, held, I, H)``."""
+    if x.shape[0] > DENSE_ROWS_MAX:
+        return relu2_experts_grouped(
+            x, experts, weights, w_up, w_down, first, valid, layer=layer)
+    if layer is not None:
+        w_up, w_down = w_up[layer], w_down[layer]
+    return relu2_experts_dense(x, experts, weights, w_up, w_down, first, valid)

@@ -212,8 +212,15 @@ class BlockManager:
     list runs dry, so caching never reduces admissible capacity.
     """
 
-    def __init__(self, layout: PagedLayout, slots: int):
+    def __init__(self, layout: PagedLayout, slots: int,
+                 state_bytes_per_slot: int = 0):
         self.layout = layout
+        # a hybrid model keeps a fixed-size recurrent state per slot beside
+        # its pool rows (models/hybrid.py): allocated once for every slot,
+        # never paged; a slot's rows are live from its admission to its
+        # release (a preemption drops them: the request prefills again)
+        self.state_bytes_per_slot = state_bytes_per_slot
+        self._state_live = [False] * slots
         self._free = list(range(layout.num_blocks - 1, 0, -1))  # block 0 reserved
         self._reserved = 0
         # adaptive pool-shrink (docs/RESILIENCE.md): blocks withheld from
@@ -559,6 +566,7 @@ class BlockManager:
             raise RuntimeError("paged KV pool exhausted (admission bug)")
         self._slot_reservation[slot] = need
         self._reserved += need
+        self._state_live[slot] = True
 
     # -- growth --------------------------------------------------------
 
@@ -593,6 +601,7 @@ class BlockManager:
         self._slot_shared[slot] = []
         self._slot_blocks[slot] = []
         self.tables[slot, :] = 0
+        self._state_live[slot] = False
 
     # -- stats ---------------------------------------------------------
 
@@ -634,4 +643,8 @@ class BlockManager:
                 }
             ),
             "cached_prefix_blocks": len(self._prefix),
+            # recurrent state beside the pool (0 for a model that has none)
+            "state_bytes": self.state_bytes_per_slot * len(self._state_live),
+            "state_live_bytes": self.state_bytes_per_slot
+            * sum(self._state_live),
         }

@@ -386,6 +386,7 @@ def memory_ledger(
     limit_bytes: int | None,
     in_transit_bytes: int = 0,
     kv_withheld_bytes: int = 0,
+    recurrent_state_bytes: int = 0,
 ) -> dict[str, Any]:
     """Assemble the ``hbm_bytes_by_owner`` breakdown.
 
@@ -411,6 +412,10 @@ def memory_ledger(
         # the same ledger so a stalled handoff pipeline names its bytes
         "in-transit": in_transit_bytes,
     }
+    if recurrent_state_bytes:
+        # a hybrid model's per-slot recurrent state beside its pool
+        # (models/hybrid.py): allocated once for every slot, never paged
+        owners["recurrent-state"] = recurrent_state_bytes
     accounted = sum(owners.values())
     slack = None
     if limit_bytes is not None:

@@ -145,6 +145,14 @@ _MODEL_CONFIGS = {
 # (models/moe.py `moe_serving_ffn`). Lazy: moe.py imports only when used.
 _MOE_MODELS = ("moe-tiny", "moe-8x7b", "mixtral-8x7b")
 
+# Hybrid (Mamba-2 + attention + routed experts, models/hybrid.py): a family
+# of its own programs, with a per-slot recurrent state beside the paged pool
+# (name -> the HybridConfig classmethod that builds it)
+_HYBRID_MODELS = {
+    "hybrid-tiny": "tiny",
+    "nemotron-3-nano-30b-a3b-ep8": "nemotron3_nano_ep8",
+}
+
 #: adaptive pool-shrink (docs/RESILIENCE.md): preempt-and-retry rounds a
 #: stranded (never-prefilled) request gets before its failure stops
 #: being treated as transient pressure and it is shed loudly — the
@@ -165,6 +173,12 @@ _RESOURCE_EXHAUSTED_RE = re.compile(
 
 
 def _resolve_model_config(name: str, max_seq_len: int):
+    if name in _HYBRID_MODELS:
+        from langstream_tpu.models.hybrid import HybridConfig
+
+        return getattr(HybridConfig, _HYBRID_MODELS[name])(
+            max_seq_len=max_seq_len
+        )
     if name in _MOE_MODELS:
         from langstream_tpu.models.moe import MoEConfig
 
@@ -177,7 +191,7 @@ def _resolve_model_config(name: str, max_seq_len: int):
     if name not in _MODEL_CONFIGS:
         raise ValueError(
             f"unknown model {name!r}; known: "
-            f"{sorted(_MODEL_CONFIGS) + sorted(_MOE_MODELS)}"
+            f"{sorted(_MODEL_CONFIGS) + sorted(_MOE_MODELS) + sorted(_HYBRID_MODELS)}"
         )
     return _MODEL_CONFIGS[name](max_seq_len=max_seq_len)
 
@@ -868,6 +882,7 @@ class TpuServingEngine:
                 self.model_config, dtype=dtypes[config.model_dtype]
             )
         self.is_moe = config.model in _MOE_MODELS
+        self.is_hybrid = config.model in _HYBRID_MODELS
         self.tokenizer: Tokenizer = load_tokenizer(config.tokenizer)
         if self.tokenizer.vocab_size > self.model_config.vocab_size:
             raise ValueError(
@@ -1337,8 +1352,9 @@ class TpuServingEngine:
             if self.block_mgr is not None
             else 0
         )
+        self._state_bytes = tree_device_bytes(self.state)
         act_bytes = np.dtype(mc.dtype).itemsize
-        if self.is_moe:
+        if self.is_moe or self.is_hybrid:
             # routed experts: the host can't know which experts fire, so
             # the FLOPs term estimates params from the measured bytes —
             # divided by the ACTUAL weight width (int8 → 1, else the
@@ -1602,7 +1618,16 @@ class TpuServingEngine:
         # full-precision tree PLUS the int8 copy (>= 24 GB at the 8B shape
         # — certain OOM on a 16 GB chip, round-4 bench root cause)
         quantized_at_init = False
-        if self.is_moe:
+        if self.is_hybrid:
+            self._refuse_what_assumes_history_is_kv()
+            from langstream_tpu.models.hybrid import init_hybrid_params
+
+            log.warning(
+                "model %r: using random-init weights (offline/dev mode)",
+                self.config.model,
+            )
+            self.params = init_hybrid_params(mc)
+        elif self.is_moe:
             from langstream_tpu.models.moe import init_moe_params, moe_serving_ffn
 
             ep_constrain = None
@@ -1740,6 +1765,7 @@ class TpuServingEngine:
                 "boundary quantization differs under the verify path)"
             )
         self.block_mgr = None
+        init_state = lambda: None  # noqa: E731  (the hybrid family has one)
         if self.config.kv_layout == "paged":
             from langstream_tpu.models.paged import (
                 BlockManager,
@@ -1754,8 +1780,21 @@ class TpuServingEngine:
                 hbm_fraction_of_dense=self.config.kv_pool_fraction,
                 num_blocks=self.config.kv_pool_blocks,
             )
-            self.block_mgr = BlockManager(self.paged_layout, self.config.slots)
-            if self.config.kv_quantize == "int8":
+            self.block_mgr = BlockManager(
+                self.paged_layout, self.config.slots,
+                state_bytes_per_slot=(
+                    mc.state_bytes_per_slot if self.is_hybrid else 0
+                ),
+            )
+            if self.is_hybrid:
+                from langstream_tpu.models.hybrid import (
+                    init_hybrid_pool,
+                    init_hybrid_state,
+                )
+
+                init_cache = partial(init_hybrid_pool, mc, self.paged_layout)
+                init_state = partial(init_hybrid_state, mc, self.config.slots)
+            elif self.config.kv_quantize == "int8":
                 from langstream_tpu.models.paged import init_paged_kv_cache_int8
 
                 init_cache = partial(
@@ -1850,8 +1889,13 @@ class TpuServingEngine:
                     )
             self.dense_read_kernel = kernel
 
-        self._refuse_cache_that_cannot_fit(init_cache)
+        self._refuse_cache_that_cannot_fit(
+            lambda: (init_cache(), init_state())
+        )
         cache_k, cache_v = init_cache()
+        # recurrent state beside the pool: None for every family but the
+        # hybrid one; donated and re-bound with the caches
+        self.state = init_state()
 
         if self.mesh is not None:
             from jax.sharding import NamedSharding, PartitionSpec as P
@@ -2030,6 +2074,27 @@ class TpuServingEngine:
             def _extras(pres, freq, counts):
                 return (pres, freq, counts) if use_pen else None
 
+            if self.is_hybrid:
+                @partial(jax.jit, donate_argnums=(1, 2, 3))
+                def _decode_chunk(params, cache_k, cache_v, state, tokens,
+                                  lengths, active, tables, key, temps, topks,
+                                  topps, pres=None, freq=None, counts=None):
+                    from langstream_tpu.models.hybrid import (
+                        hybrid_decode_chunk_paged,
+                    )
+
+                    return hybrid_decode_chunk_paged(
+                        mc_static, params, tokens, lengths, active,
+                        cache_k, cache_v, state, tables,
+                        _sample_fn_for(temps, topks, topps, pres, freq),
+                        key, K, num_read_blocks=window,
+                        kernel=self.paged_read_kernel,
+                        sample_extras=_extras(pres, freq, counts),
+                        return_packed=True,
+                    )
+
+                return _decode_chunk
+
             if paged:
                 @partial(jax.jit, donate_argnums=(1, 2))
                 def _decode_chunk(params, cache_k, cache_v, tokens, lengths,
@@ -2116,6 +2181,29 @@ class TpuServingEngine:
 
         def _make_prefill(sampler_mode: tuple):
             use_top_p, use_top_k, all_greedy = sampler_mode
+            if self.is_hybrid:
+                @partial(jax.jit, donate_argnums=(1, 2, 3))
+                def _prefill(params, cache_k, cache_v, state, tokens, lengths,
+                             sel, key, temps, topks, topps):
+                    from langstream_tpu.models.hybrid import (
+                        hybrid_prefill_paged,
+                    )
+
+                    tables, slot_ids = sel
+                    logits, ck, cv, st, _routed = hybrid_prefill_paged(
+                        mc_static, params, tokens, lengths, cache_k, cache_v,
+                        state, tables, slot_ids, use_flash=prefill_flash,
+                    )
+                    with jax.named_scope("sample"):
+                        next_tokens, logprobs = sample_tokens(
+                            logits, key, temps, topks,
+                            use_top_p=use_top_p, top_ps=topps,
+                            use_top_k=use_top_k, all_greedy=all_greedy,
+                        )
+                    return next_tokens, logprobs, ck, cv, st
+
+                return _prefill
+
             if paged:
                 @partial(jax.jit, donate_argnums=(1, 2))
                 def _prefill(params, cache_k, cache_v, tokens, lengths, tables,
@@ -2249,6 +2337,64 @@ class TpuServingEngine:
         self._prefill_fns: dict[tuple, Any] = {}
         self._prefill_continue_fns: dict[tuple[tuple, int], Any] = {}
         self._spec_step_fns: dict[tuple[int, tuple], Any] = {}
+
+    def _refuse_what_assumes_history_is_kv(self) -> None:
+        """A hybrid model's history is K/V blocks AND a recurrent state
+        that has no snapshot: every feature that adopts, rolls back, moves
+        or replays a request's history by its blocks alone would serve
+        wrong tokens. Each is refused here by the name of its option;
+        nothing falls back."""
+        cfg = self.config
+        refused = {  # option: (set, why it cannot be served)
+            "prefix-cache": (
+                cfg.prefix_cache,
+                "adopted blocks carry no recurrent state; set "
+                "prefix-cache: false"),
+            "prefix-store": (
+                cfg.prefix_store is not None and cfg.prefix_store.enabled,
+                "its tiers hold K/V blocks only"),
+            "prefill-chunk": (
+                cfg.prefill_chunk > 0,
+                "continuation prefill resumes from K/V alone; set "
+                "prefill-chunk: 0"),
+            "speculative-drafts": (
+                cfg.speculative_drafts > 0,
+                "a rejected draft cannot be rolled out of the recurrent "
+                "state; set speculative-drafts: 0"),
+            "pool-role": (
+                cfg.pool_role != "combined",
+                "the K/V handoff carries no recurrent state; use "
+                "pool-role: combined"),
+            "adapter-store": (
+                cfg.adapter_store is not None and cfg.adapter_store.enabled,
+                "the hybrid programs apply no adapters"),
+            "quantize": (
+                cfg.quantize not in (None, "none"),
+                "the hybrid programs read bf16 weights only"),
+            "kv-quantize": (
+                cfg.kv_quantize not in (None, "none"),
+                "the hybrid programs read a bf16 pool only"),
+            "kv-layout": (
+                cfg.kv_layout != "paged",
+                "the attention layers read the paged pool; set "
+                "kv-layout: paged"),
+            "mesh": (
+                bool(cfg.mesh),
+                "this family serves one chip's share of its deployment; "
+                "no mesh"),
+            "journal-dir": (
+                bool(cfg.journal_dir),
+                "journal replay re-admits by K/V-era rules untested beside "
+                "recurrent state"),
+            "checkpoint": (
+                bool(cfg.checkpoint), "no checkpoint loader for this family"),
+        }
+        for option, (on, why) in refused.items():
+            if on:
+                raise ValueError(
+                    f"model {cfg.model!r} keeps a recurrent state beside "
+                    f"its K/V blocks and cannot serve with {option}: {why}"
+                )
 
     def _refuse_cache_that_cannot_fit(self, init_cache) -> None:
         """Refuse, in words and before the allocator has to, a KV cache
@@ -2495,11 +2641,17 @@ class TpuServingEngine:
         active_at_dispatch: int | None = None,
         live_blocks: int | None = None,
         table_blocks: int | None = None,
+        routed_pairs: int | None = None,
+        expert_load_max: int | None = None,
+        state_bytes: int | None = None,
     ) -> None:
         """One flight sample per dispatched burst, plus its Prometheus
         mirrors. ``program``, ``dispatch``, ``steps``,
         ``active_at_dispatch``, ``live_blocks`` and ``table_blocks`` are
-        the dispatch's :meth:`_ticket`, taken when it was made.
+        the dispatch's :meth:`_ticket`, taken when it was made;
+        ``routed_pairs``, ``expert_load_max`` and ``state_bytes`` (a hybrid
+        model's decode chunk) joined it when the chunk's packed fetch
+        landed (:meth:`_await_chunk`).
         ``overlapped_s`` is host work the pipelined loop ran
         under an in-flight dispatch's device shadow (see flight.py).
         ``program`` keys the sample by the compiled variant that ran and
@@ -2537,6 +2689,9 @@ class TpuServingEngine:
             active_at_dispatch=active_at_dispatch,
             live_blocks=live_blocks,
             table_blocks=table_blocks,
+            routed_pairs=routed_pairs,
+            expert_load_max=expert_load_max,
+            state_bytes=state_bytes,
         )
         # watchdog heartbeat: a recorded dispatch IS step progress
         self.watchdog.beat(sample["queue_depth"])
@@ -3005,6 +3160,7 @@ class TpuServingEngine:
                 if self.block_mgr is not None
                 else 0
             ),
+            recurrent_state_bytes=self._state_bytes,
         )
 
     @staticmethod
@@ -3410,6 +3566,8 @@ class TpuServingEngine:
         self.params = None
         # graftcheck: disable=RACE801 loop task awaited + executor joined (wait=True): no dispatch closure can still run
         self.cache_k = self.cache_v = None
+        # graftcheck: disable=RACE801 loop task awaited + executor joined (wait=True): no dispatch closure can still run
+        self.state = None
         # graftcheck: disable=RACE801 loop task awaited + executor joined (wait=True): no dispatch closure can still run
         self._ad_layers = None
         self._decode_chunk_fns.clear()
@@ -5797,7 +5955,7 @@ class TpuServingEngine:
             self.cache_k, self.cache_v = ck, cv
             self._decode_dispatches += 1
             self._start_fetch(packed)
-            return self._fetch_chunk(packed, K)
+            return self._fetch_chunk(packed, K)[:3]
 
         t_wall = time.monotonic()
         chunk_t, chunk_lp, fetch_s = await loop.run_in_executor(
@@ -6070,14 +6228,16 @@ class TpuServingEngine:
 
     def _fetch_chunk(
         self, packed, k_steps: int
-    ) -> tuple[np.ndarray, np.ndarray, float]:
+    ) -> tuple[np.ndarray, np.ndarray, float, np.ndarray]:
         """The designated fetch stage (graftcheck PERF701 polices syncs
         anywhere else on the dispatch path): ONE device→host transfer per
         chunk — tokens and bitcast logprobs ride the same packed array,
         whose D2H copy the dispatch already started asynchronously. The
         third element is the seconds this call spent blocked on the
         device — the chunk's un-overlapped device wait, which the flight
-        recorder subtracts from wall time to expose the host share."""
+        recorder subtracts from wall time to expose the host share. The
+        fourth is whatever counters the program packed behind them (a
+        hybrid model's expert loads; empty otherwise)."""
         B = self.config.slots
         n = k_steps * B
         self._fault("fetch")
@@ -6087,8 +6247,9 @@ class TpuServingEngine:
         self._decode_fetches += 1
         return (
             flat[:n].reshape(k_steps, B),
-            flat[n:].view(np.float32).reshape(k_steps, B),
+            flat[n:2 * n].view(np.float32).reshape(k_steps, B),
             fetch_s,
+            flat[2 * n:],
         )
 
     async def _await_chunk(self, loop, packed, k_steps: int, ticket: dict):
@@ -6097,9 +6258,18 @@ class TpuServingEngine:
         coroutine runs again: the ``ls.decode.fetch`` span, the one span
         held across an ``await``."""
         with self.flight.span("ls.decode.fetch", seq=ticket["dispatch"]):
-            return await loop.run_in_executor(
+            chunk_t, chunk_lp, fetch_s, loads = await loop.run_in_executor(
                 self._executor, partial(self._fetch_chunk, packed, k_steps)
             )
+        if loads.size:
+            # a hybrid chunk's expert loads, by (layer, held expert)
+            ticket.update(
+                routed_pairs=int(loads.sum()),
+                expert_load_max=int(loads.max()),
+                state_bytes=ticket["active_at_dispatch"]
+                * self.model_config.state_bytes_per_slot,
+            )
+        return chunk_t, chunk_lp, fetch_s
 
     @staticmethod
     def _chunk_ready(packed) -> bool:
@@ -6334,12 +6504,13 @@ class TpuServingEngine:
                 "ls.decode.dispatch", **_span_meta(ticket), steps=K
             ):
                 tables_dev = self._tables_device(tables)
-                args = (
-                    (self.params, self.cache_k, self.cache_v,
-                     tokens, lengths, amask, tables_dev, key, temps, topks, topps)
+                caches = (self.params, self.cache_k, self.cache_v) + (
+                    () if self.state is None else (self.state,)
+                )
+                args = caches + (
+                    (tokens, lengths, amask, tables_dev, key, temps, topks, topps)
                     if paged
-                    else (self.params, self.cache_k, self.cache_v,
-                          tokens, lengths, amask, key, temps, topks, topps)
+                    else (tokens, lengths, amask, key, temps, topks, topps)
                 )
                 if pen:
                     args = args + (
@@ -6359,8 +6530,10 @@ class TpuServingEngine:
                 self.profiler.dump_hlo(
                     f"decode_chunk_w{window}_s{sampler_mode}", decode_fn, *args
                 )
-                packed, t, l, ck, cv = decode_fn(*args, **ad_kw)
+                packed, t, l, ck, cv, *st = decode_fn(*args, **ad_kw)
                 self.cache_k, self.cache_v = ck, cv
+                if st:
+                    self.state = st[0]
                 # tokens+logprobs were packed INSIDE the decode program
                 # (sample-in-program): start their D2H copy now, so by the
                 # time the deferred _fetch_chunk wait runs, the transfer has
@@ -7091,6 +7264,9 @@ class TpuServingEngine:
                 else:
                     sel_np = slot_ids
                 sel = jnp.asarray(sel_np)
+                if self.is_hybrid:
+                    # the recurrent state's rows are the slots' own
+                    sel = (sel, jnp.asarray(slot_ids))
                 if use_continue:
                     nrb = self._read_blocks_for(int(starts.max()))
                     prefill_fn = self._prefill_continue_fn(prefill_mode, nrb)
@@ -7148,8 +7324,9 @@ class TpuServingEngine:
                             jnp.asarray(topps),
                         )
                     else:
-                        args = (
-                            self.params, self.cache_k, self.cache_v,
+                        args = (self.params, self.cache_k, self.cache_v) + (
+                            () if self.state is None else (self.state,)
+                        ) + (
                             jnp.asarray(padded), jnp.asarray(lengths),
                             sel, key,
                             jnp.asarray(temps), jnp.asarray(topks),
@@ -7169,6 +7346,8 @@ class TpuServingEngine:
                 # donated caches re-bound on the dispatch thread — see
                 # _advance_prefills._run (RACE801: single thread role)
                 self.cache_k, self.cache_v = out[2], out[3]
+                if self.state is not None:
+                    self.state = out[4]
                 t_dev = time.monotonic()
                 # same single sync the loop-thread np.asarray used to pay,
                 # moved onto the dispatch thread so it can be timed; the

@@ -219,6 +219,9 @@ class FlightRecorder:
         active_at_dispatch: int | None = None,
         live_blocks: int | None = None,
         table_blocks: int | None = None,
+        routed_pairs: int | None = None,
+        expert_load_max: int | None = None,
+        state_bytes: int | None = None,
     ) -> dict[str, Any]:
         """Record one dispatched burst. ``wall`` is the time since the
         previous boundary. ``overlapped_s`` is host work the pipelined
@@ -238,7 +241,13 @@ class FlightRecorder:
         at this later boundary where ``occupancy`` is read; omitted
         together when the caller has no dispatch to name. ``live_blocks``
         and ``table_blocks`` (a paged decode chunk only) are the pool
-        blocks its read had to fetch and the table columns of its window."""
+        blocks its read had to fetch and the table columns of its window.
+        ``routed_pairs``, ``expert_load_max`` and ``state_bytes`` (a hybrid
+        model's decode chunk only, models/hybrid.py) are the (token, expert)
+        pairs the chunk's active rows sent to the experts held here, the most
+        any one expert of any one layer got of them, and the bytes of
+        recurrent state of the slots it advanced (read and written once a
+        step)."""
         now = time.monotonic()
         wall_ms = (now - self._last_mark) * 1000.0
         self._last_mark = now
@@ -280,6 +289,10 @@ class FlightRecorder:
         if live_blocks is not None:
             entry["live_blocks"] = live_blocks
             entry["table_blocks"] = table_blocks
+        if routed_pairs is not None:
+            entry["routed_pairs"] = routed_pairs
+            entry["expert_load_max"] = expert_load_max
+            entry["state_bytes"] = state_bytes
         self._samples.append(entry)
         self.recorded += 1
         self.wall_ms += wall_ms

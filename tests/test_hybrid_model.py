@@ -1,0 +1,257 @@
+"""The hybrid family's mixers (models/hybrid.py, the dropless routing of
+models/moe.py) against plain spellings of the same equations, in float32 on
+the CPU: the chunked prefill scan, the sequential recurrence and the
+step-by-step decode are one function; padding and batch neighbours change
+nothing; routing drops nothing and depends on no other row; the shares of
+one deployment add up to the uncut layer."""
+
+import dataclasses
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from langstream_tpu.models import moe
+from langstream_tpu.models.hybrid import (
+    HybridConfig,
+    hybrid_prefill_paged,
+    init_hybrid_params,
+    init_hybrid_pool,
+    init_hybrid_state,
+    mamba_prefill,
+    mamba_step,
+    moe_mixer,
+    ssd_chunked,
+)
+from langstream_tpu.models.paged import PagedLayout
+
+BENCH = os.path.join(os.path.dirname(__file__), "..", "bench")
+if BENCH not in sys.path:
+    sys.path.insert(0, BENCH)
+
+
+@pytest.fixture(scope="module")
+def c():
+    return dataclasses.replace(HybridConfig.tiny(max_seq_len=256),
+                               dtype=jnp.float32)
+
+
+@pytest.fixture(scope="module")
+def params(c):
+    return init_hybrid_params(c)
+
+
+def layer(tree, i):
+    return jax.tree.map(lambda a: a[i], tree)
+
+
+def sequential(x, dt, A, Bm, Cm):
+    """h_t = exp(dt_t A) h_{t-1} + dt_t x_t B_t^T; y_t = h_t C_t, one
+    position after another, in numpy float64."""
+    Bsz, T, h, p = x.shape
+    g, n = Bm.shape[2:]
+    group = np.arange(h) // (h // g)
+    state = np.zeros((Bsz, h, p, n))
+    ys = np.zeros((Bsz, T, h, p))
+    for t in range(T):
+        decay = np.exp(dt[:, t] * A)[:, :, None, None]
+        state = decay * state + (dt[:, t, :, None] * x[:, t])[..., None] \
+            * Bm[:, t, group][:, :, None, :]
+        ys[:, t] = np.einsum("bhpn,bhn->bhp", state, Cm[:, t, group])
+    return ys, state
+
+
+@pytest.mark.parametrize("tokens, chunk", [(48, 16), (64, 16), (16, 16), (8, 16)])
+def test_the_chunked_scan_is_the_sequential_recurrence(tokens, chunk):
+    rng = np.random.default_rng(tokens)
+    h, p, g, n = 8, 8, 2, 16
+    x = rng.normal(size=(2, tokens, h, p)).astype(np.float32)
+    dt = rng.uniform(0.001, 0.3, size=(2, tokens, h)).astype(np.float32)
+    A = -rng.uniform(1, 16, size=h).astype(np.float32)
+    Bm = rng.normal(size=(2, tokens, g, n)).astype(np.float32)
+    Cm = rng.normal(size=(2, tokens, g, n)).astype(np.float32)
+    y, state = ssd_chunked(*map(jnp.asarray, (x, dt, A, Bm, Cm)), chunk)
+    want_y, want_state = sequential(*(a.astype(np.float64)
+                                      for a in (x, dt, A, Bm, Cm)))
+    np.testing.assert_allclose(y, want_y, rtol=2e-4, atol=2e-5)
+    np.testing.assert_allclose(state, want_state, rtol=2e-4, atol=2e-5)
+
+
+def test_prefill_then_steps_is_one_long_prefill(c, params):
+    """State and convolution tail after a prefill of n tokens, advanced one
+    token at a time, are those of a prefill of the longer sequence; and the
+    outputs along the way are the same."""
+    lp = layer(params["mamba"], 1)
+    rng = np.random.default_rng(5)
+    u = jnp.asarray(rng.normal(size=(2, 64, c.hidden)), jnp.float32)
+    whole, state_w, tail_w = mamba_prefill(c, lp, u, jnp.asarray([64, 64]))
+    out, state, tail = mamba_prefill(c, lp, u[:, :32], jnp.asarray([27, 32]))
+    state, tail = state[None], tail[None]      # a stack of this one layer
+    # row 0 stops at 27 inside the bucket of 32: advance it from there
+    for t in range(27, 64):
+        active = jnp.asarray([True, t >= 32])
+        col = jnp.stack([u[0, t], u[1, max(t, 32)]])
+        step_out, state, tail = mamba_step(c, lp, col, state, tail, 0, active)
+        np.testing.assert_allclose(step_out[0], whole[0, t], rtol=2e-4, atol=2e-5)
+        if t >= 32:
+            np.testing.assert_allclose(step_out[1], whole[1, t], rtol=2e-4,
+                                       atol=2e-5)
+    np.testing.assert_allclose(out[0, :27], whole[0, :27], rtol=2e-4, atol=2e-5)
+    np.testing.assert_allclose(state[0], state_w, rtol=2e-4, atol=2e-5)
+    np.testing.assert_allclose(tail[0], tail_w, rtol=1e-5, atol=1e-6)
+
+
+def test_an_idle_slot_keeps_its_state(c, params):
+    lp = layer(params["mamba"], 0)
+    rng = np.random.default_rng(6)
+    state = jnp.asarray(rng.normal(size=(3, 2, 8, 8, 16)), jnp.float32)
+    tail = jnp.asarray(rng.normal(size=(3, 2, 3, c.conv_dim)), jnp.float32)
+    u = jnp.asarray(rng.normal(size=(2, c.hidden)), jnp.float32)
+    _, new_state, new_tail = mamba_step(
+        c, lp, u, state, tail, 1, jnp.asarray([True, False]))
+    assert not np.allclose(new_state[1, 0], state[1, 0])
+    np.testing.assert_array_equal(new_state[1, 1], state[1, 1])
+    np.testing.assert_array_equal(new_tail[1, 1], tail[1, 1])
+    for other in (0, 2):                      # the other layers' rows stay
+        np.testing.assert_array_equal(new_state[other], state[other])
+        np.testing.assert_array_equal(new_tail[other], tail[other])
+
+
+def prefill(c, params, rows, bucket, slots=4):
+    layout = PagedLayout(block_size=16, num_blocks=1 + len(rows) * 16,
+                         max_blocks_per_slot=16)
+    pool_k, pool_v = init_hybrid_pool(c, layout)
+    tokens = np.zeros((len(rows), bucket), np.int32)
+    for i, row in enumerate(rows):
+        tokens[i, : len(row)] = row
+    tables = 1 + np.arange(len(rows) * 16, dtype=np.int32).reshape(len(rows), 16)
+    return jax.jit(lambda *a: hybrid_prefill_paged(c, *a))(
+        params, jnp.asarray(tokens),
+        jnp.asarray([len(r) for r in rows], jnp.int32), pool_k, pool_v,
+        init_hybrid_state(c, slots), jnp.asarray(tables),
+        jnp.arange(len(rows), dtype=jnp.int32))
+
+
+def test_a_padded_bucket_and_batch_neighbours_change_nothing(c, params):
+    rng = np.random.default_rng(7)
+    mine = rng.integers(0, c.vocab_size, size=37)
+    others = [rng.integers(0, c.vocab_size, size=n) for n in (128, 90, 3, 77)]
+    alone = prefill(c, params, [mine], 64)             # 64 rows: the dense pass
+    # 5 x 128 = 640 rows: the grouped pass, reading the experts' stacks by layer
+    crowd = prefill(c, params, [others[0], mine, *others[1:]], 128, slots=6)
+    np.testing.assert_allclose(alone[0][0], crowd[0][1], rtol=2e-4, atol=2e-5)
+    for key in ("ssm", "conv"):
+        np.testing.assert_allclose(alone[3][key][:, 0], crowd[3][key][:, 1],
+                                   rtol=2e-4, atol=2e-5)
+    np.testing.assert_array_equal(alone[4][:, 0, :37], crowd[4][:, 1, :37])
+    # slot 5 was no row of either batch: its state stays zero
+    assert not np.asarray(crowd[3]["ssm"][:, 5]).any()
+
+
+# -- dropless routing ------------------------------------------------------
+
+
+def loop_over_experts(x, experts, weights, w_up, w_down, first):
+    out = np.zeros(x.shape, np.float64)
+    for t in range(x.shape[0]):
+        for e, w in zip(experts[t], weights[t]):
+            if first <= e < first + w_up.shape[0]:
+                up = np.maximum(w_up[e - first] @ x[t], 0.0) ** 2
+                out[t] += w * (up @ w_down[e - first])
+    return out
+
+
+@pytest.fixture(scope="module")
+def routed():
+    rng = np.random.default_rng(11)
+    T, H, I, E, held, k = 600, 32, 24, 16, 4, 3
+    x = rng.normal(size=(T, H)).astype(np.float32)
+    router = rng.normal(size=(H, E)).astype(np.float32) / np.sqrt(H)
+    bias = rng.uniform(-0.2, 0.2, size=E).astype(np.float32)
+    w_up = rng.normal(size=(held, I, H)).astype(np.float32) / np.sqrt(H)
+    w_down = rng.normal(size=(held, I, H)).astype(np.float32) / np.sqrt(I)
+    experts, weights = moe.sigmoid_topk_routing(
+        jnp.asarray(x), jnp.asarray(router), jnp.asarray(bias), k, 2.5)
+    return x, np.asarray(experts), np.asarray(weights), w_up, w_down
+
+
+def test_routing_follows_the_published_rule(routed):
+    x, experts, weights, _, _ = routed
+    assert experts.shape == (600, 3) and weights.shape == (600, 3)
+    assert all(len(set(row)) == 3 for row in experts)     # distinct winners
+    np.testing.assert_allclose(weights.sum(-1), 2.5, rtol=1e-5)
+
+
+@pytest.mark.parametrize("first", [0, 4, 12])
+@pytest.mark.parametrize("path", ["dense", "grouped", "grouped-stacked"])
+def test_both_passes_equal_a_loop_over_the_chosen_held_experts(routed, path, first):
+    x, experts, weights, w_up, w_down = routed
+    stack = lambda w: jnp.stack([jnp.zeros_like(w), jnp.asarray(w)])  # noqa: E731
+    fn = {
+        "dense": moe.relu2_experts_dense,
+        "grouped": lambda *a: moe.relu2_experts_grouped(*a, block_rows=64),
+        # the experts' weights as layer 1 of a stack of two
+        "grouped-stacked": lambda x, e, w, up, down, f: moe.relu2_experts_grouped(
+            x, e, w, stack(up), stack(down), f, block_rows=64, layer=1),
+    }[path]
+    out, load = jax.jit(fn, static_argnums=5)(
+        *map(jnp.asarray, (x, experts, weights, w_up, w_down)), first)
+    want = loop_over_experts(x.astype(np.float64), experts, weights,
+                             w_up.astype(np.float64), w_down.astype(np.float64),
+                             first)
+    np.testing.assert_allclose(out, want, rtol=2e-4, atol=2e-5)
+    np.testing.assert_array_equal(
+        load, [(experts == first + e).sum() for e in range(4)])
+
+
+def test_a_row_does_not_depend_on_its_batch_neighbours(routed):
+    x, experts, weights, w_up, w_down = routed
+    args = lambda rows: map(jnp.asarray, (  # noqa: E731
+        x[rows], experts[rows], weights[rows], w_up, w_down))
+    full, _ = moe.relu2_experts_grouped(*args(slice(None)), 4, block_rows=64)
+    few, _ = moe.relu2_experts_grouped(*args(slice(100, 420)), 4, block_rows=64)
+    one, _ = moe.relu2_experts_dense(*args(slice(123, 124)), 4)
+    np.testing.assert_allclose(few, full[100:420], rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(one[0], full[123], rtol=1e-5, atol=1e-6)
+
+
+def test_rows_that_do_not_count_route_nowhere(routed):
+    x, experts, weights, w_up, w_down = routed
+    valid = np.arange(600) % 3 != 0
+    for fn in (moe.relu2_experts_dense, moe.relu2_experts_grouped):
+        out, load = fn(*map(jnp.asarray, (x, experts, weights, w_up, w_down)),
+                       0, jnp.asarray(valid))
+        assert not np.asarray(out)[~valid].any()
+        assert int(load.sum()) == int((experts[valid] < 4).sum())
+
+
+def test_the_shares_add_up_to_the_uncut_reference_layer(c):
+    """Four chips of two experts each: what every share's routed experts
+    give, plus the shared expert counted once, is the reference's layer
+    over all eight experts."""
+    from reference import hybrid_ssm_moe as reference
+
+    shares = [dataclasses.replace(c, expert_first=first)
+              for first in range(0, c.experts, c.experts_held)]
+    assert len(shares) == 4
+    trees = [init_hybrid_params(s)["moe"] for s in shares]
+    whole = {k: trees[0][k][0] for k in trees[0]}
+    for k in ("w_up", "w_down"):        # the same eight experts, by global id
+        whole[k] = jnp.concatenate([t[k][0] for t in trees])
+        assert whole[k].shape[0] == c.experts
+    rng = np.random.default_rng(13)
+    h = jnp.asarray(rng.normal(size=(40, c.hidden)), jnp.float32)
+    with jax.default_matmul_precision("highest"):
+        want, _ = reference.experts(h, whole, c, first=0, held=c.experts)
+        shared_only, _ = reference.experts(h, whole, c, first=0, held=0)
+    total = np.zeros_like(np.asarray(want))
+    for share, tree in zip(shares, trees):
+        out, load, _ = moe_mixer(share, layer(tree, 0), h,
+                                 jnp.ones((40,), bool))
+        total += np.asarray(out) - np.asarray(shared_only)
+    assert np.abs(np.asarray(want) - np.asarray(shared_only)).max() > 0.1
+    np.testing.assert_allclose(total + np.asarray(shared_only), want,
+                               rtol=2e-4, atol=2e-5)
