@@ -45,6 +45,7 @@ from langstream_tpu.models.llama_paged import (
     pack_tokens_logprobs,
 )
 from langstream_tpu.models.moe import relu2_experts, sigmoid_topk_routing
+from langstream_tpu.models.paged import write_rows
 from langstream_tpu.ops.paged_attention import (
     NEG_INF,
     merge_partial_attention,
@@ -456,31 +457,6 @@ def moe_mixer(c: HybridConfig, lp: dict, h: jax.Array, valid: jax.Array,
 
 
 # ---------------------------------------------------------------------------
-# the pool of the attention layers
-# ---------------------------------------------------------------------------
-
-
-def write_rows(pool: jax.Array, rows: jax.Array, block_tables: jax.Array,
-               starts: jax.Array, valid: jax.Array) -> jax.Array:
-    """``rows (layers, B, T, Kh*D)`` into ``pool (layers, nb, bs, Kh*D)`` at
-    each slot's block-mapped positions from ``starts``; rows that are not
-    ``valid`` land in block 0, the scratch block (models/paged.py). One
-    scatter of rows into the pool seen as ``(layers * nb * bs, Kh*D)``: with
-    the layer folded into the row index there is no layer axis for the
-    compiler to move inward, which is what makes it copy the whole pool
-    around the dense family's commit."""
-    L, nb, bs, KhD = pool.shape
-    B, T = rows.shape[1:3]
-    pos = starts[:, None] + jnp.arange(T)[None, :]
-    block = jnp.take_along_axis(
-        block_tables, jnp.clip(pos // bs, 0, block_tables.shape[1] - 1), axis=1)
-    flat = jnp.where(valid, block * bs + pos % bs, 0).reshape(-1)     # (B*T,)
-    index = (jnp.arange(L)[:, None] * (nb * bs) + flat[None, :]).reshape(-1)
-    return pool.reshape(L * nb * bs, KhD).at[index].set(
-        rows.reshape(L * B * T, KhD)).reshape(pool.shape)
-
-
-# ---------------------------------------------------------------------------
 # prefill
 # ---------------------------------------------------------------------------
 
@@ -522,7 +498,11 @@ def hybrid_prefill_paged(
     last real token, overwrite its slot's rows of ``state``. Returns
     ``(last-token logits (B, V), pool_k, pool_v, state, routed)``; ``routed
     (blocks, B, P, k)`` are the experts the router chose, for the reference
-    check (a caller that drops it pays nothing for it)."""
+    check (a caller that drops it pays nothing for it).
+
+    The commit is :func:`langstream_tpu.models.paged.write_rows`, the one
+    every family uses: the attention layer is folded into the row index, so
+    the pool is scattered where it lies and never copied."""
     c = config
     B, Pn = tokens.shape
     nA = c.attn_layers
@@ -630,9 +610,11 @@ def hybrid_decode_chunk_paged(
     return_packed: bool = False,
 ):
     """K fused decode steps. The pool is read-only and the new K/V rows of
-    the attention layers gather in a chunk buffer (one scatter at the end),
-    as in the dense family's chunk; the recurrent state rides the scan's
-    carry and each Mamba-2 layer replaces its own rows of it in place.
+    the attention layers gather in a chunk buffer (one scatter at the end:
+    :func:`langstream_tpu.models.paged.write_rows`, the layer in the row
+    index, no copy of the pool), as in the dense family's chunk; the
+    recurrent state rides the scan's carry and each Mamba-2 layer replaces
+    its own rows of it in place.
 
     Returns ``(chunk_tokens, chunk_logprobs, final_tokens, final_lengths,
     pool_k, pool_v, state, load, routed)`` where ``load (blocks,

@@ -118,51 +118,53 @@ def write_rows(
 ):
     """Scatter ``rows`` into the pool at each slot's block-mapped positions.
 
-    Invalid rows are redirected to a scratch row (block 0 never backs live
-    data; see BlockManager) so the scatter stays shape-static. An int8 pool
-    quantises the rows here — write sites stay layout-agnostic. Rows that
-    are ALREADY quantized (an int8 ``{"q","s"}`` pair, e.g. a KV handoff
-    payload from another replica's identical pool) scatter verbatim, so a
-    transfer never pays a dequant/requant round trip.
+    The one commit of every program family (dense prefill, continuation,
+    decode chunk and verify, the K/V handoff's import, the hybrid's
+    attention layers). The pool is scattered as ``(L * nb * bs, tail)`` with
+    the layer folded into the row index, ``l * nb * bs + row``: one scatter
+    along the leading axis, in place on a donated pool. Scattered along its
+    second axis (``.at[:, row]``) the compiler moves the layer axis inward
+    and back out, a copy of the whole pool each way for K and for V; the
+    reshapes here move nothing (``bs`` is a multiple of every row tile).
+
+    Invalid rows are redirected to their layer's scratch row (block 0 never
+    backs live data; see BlockManager) so the scatter stays shape-static. An
+    int8 pool quantises the rows here — write sites stay layout-agnostic.
+    Rows that are ALREADY quantized (an int8 ``{"q","s"}`` pair, e.g. a KV
+    handoff payload from another replica's identical pool) scatter verbatim,
+    so a transfer never pays a dequant/requant round trip.
     """
     quant = isinstance(cache, dict)
-    nb, bs, KhD = (cache["q"] if quant else cache).shape[1:]
-    rows_data = rows["q"] if isinstance(rows, dict) else rows
-    B, T = rows_data.shape[1], rows_data.shape[2]
+    L, nb, bs, KhD = (cache["q"] if quant else cache).shape
+    B, T = (rows["q"] if isinstance(rows, dict) else rows).shape[1:3]
     pos = starts[:, None] + jnp.arange(T)[None, :]          # (B, T)
     # clamp: invalid rows may compute positions past the table; they're
     # redirected to scratch below, the clamp just keeps indexing in-bounds
-    block_idx = jnp.clip(pos // bs, 0, block_tables.shape[1] - 1)
-    offset = pos % bs
-    blocks = jnp.take_along_axis(block_tables, block_idx, axis=1)  # (B, T)
-    flat = blocks * bs + offset                              # row in (nb*bs)
+    block = jnp.take_along_axis(
+        block_tables, jnp.clip(pos // bs, 0, block_tables.shape[1] - 1), axis=1
+    )
     # invalid rows land in block 0 (reserved scratch, never allocated), so
     # the scatter stays shape-static and garbage never touches live data
-    flat = jnp.where(valid, flat, 0).reshape(-1)             # (B*T,)
+    flat = jnp.where(valid, block * bs + pos % bs, 0).reshape(-1)  # (B*T,)
+    index = (jnp.arange(L)[:, None] * (nb * bs) + flat[None, :]).reshape(-1)
 
-    def scatter(pool, new_rows):  # trailing dims: KhD / Kh
-        L = new_rows.shape[0]
-        tail = pool.shape[3:]
-        flat_cache = pool.reshape((L, nb * bs) + tail)
-        flat_rows = new_rows.reshape((L, B * T) + tail)
-        return flat_cache.at[:, flat].set(flat_rows).reshape(pool.shape)
+    def scatter(pool, new_rows):  # trailing dim: KhD / Kh
+        tail = pool.shape[3]
+        return pool.reshape(L * nb * bs, tail).at[index].set(
+            new_rows.reshape(L * B * T, tail)
+        ).reshape(pool.shape)
 
     if not quant:
         return scatter(cache, rows)
-    if isinstance(rows, dict):
-        # pre-quantized rows (KV handoff): bit-exact pass-through
-        return {
-            "q": scatter(cache["q"], rows["q"]),
-            "s": scatter(cache["s"], rows["s"]),
-        }
-    from langstream_tpu.models.kvquant import quantize_rows
+    if not isinstance(rows, dict):
+        from langstream_tpu.models.kvquant import quantize_rows
 
-    L = rows.shape[0]
-    Kh = cache["s"].shape[3]
-    q = quantize_rows(rows.reshape(L, B, T, Kh, KhD // Kh))
+        Kh = cache["s"].shape[3]
+        rows = quantize_rows(rows.reshape(L, B, T, Kh, KhD // Kh))
+    # pre-quantized rows (KV handoff) pass through bit-exact
     return {
-        "q": scatter(cache["q"], q["q"].reshape(L, B, T, KhD)),
-        "s": scatter(cache["s"], q["s"]),
+        "q": scatter(cache["q"], rows["q"]),
+        "s": scatter(cache["s"], rows["s"]),
     }
 
 

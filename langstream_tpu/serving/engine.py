@@ -6335,7 +6335,9 @@ class TpuServingEngine:
         never billed. The burst ends when admission work appears, leaving
         its in-flight chunk pending so the admission prefill dispatches
         under that chunk's shadow (drained identity-filtered afterwards —
-        see :meth:`_drain_pending`).
+        see :meth:`_drain_pending`). It also ends, with nothing pending,
+        when every running request's budget ends inside the chunk in
+        flight: the next chunk could only compute over-run tokens.
 
         Light-load regime (active slots <= ``_light_threshold``): the burst
         fuses only ``decode_chunk_light`` steps per dispatch and runs them
@@ -6682,6 +6684,18 @@ class TpuServingEngine:
                         amask, temps, topks, topps = self._sampler_device(
                             active_mask
                         )
+                running = [self.slots[i].request for i in active]
+                if all(
+                    r is None or r.max_tokens - len(r.generated) <= K
+                    for r in running
+                ):
+                    # every running request ends inside the chunk in flight
+                    # (its budget is at most K tokens away): a speculative
+                    # chunk behind it would run K steps for tokens that are
+                    # all discarded, ahead of whatever prefill is waiting.
+                    # Fetch the one in flight and give the loop back.
+                    await _drain(out, running)
+                    return
                 # speculate the next chunk from device state
                 base_max += K
                 chunk_index += 1

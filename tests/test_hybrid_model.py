@@ -255,3 +255,76 @@ def test_the_shares_add_up_to_the_uncut_reference_layer(c):
     assert np.abs(np.asarray(want) - np.asarray(shared_only)).max() > 0.1
     np.testing.assert_allclose(total + np.asarray(shared_only), want,
                                rtol=2e-4, atol=2e-5)
+
+
+# -- the commit is the one every family uses -------------------------------
+
+
+def parents_hybrid_write_rows(pool, rows, block_tables, starts, valid):
+    """The hybrid's own commit as the parent (8e35ac5) had it in
+    ``models/hybrid.py``: what ``models/paged.py`` ``write_rows`` has to
+    trace to for a plain-array pool."""
+    L, nb, bs, KhD = pool.shape
+    B, T = rows.shape[1:3]
+    pos = starts[:, None] + jnp.arange(T)[None, :]
+    block = jnp.take_along_axis(
+        block_tables, jnp.clip(pos // bs, 0, block_tables.shape[1] - 1), axis=1)
+    flat = jnp.where(valid, block * bs + pos % bs, 0).reshape(-1)
+    index = (jnp.arange(L)[:, None] * (nb * bs) + flat[None, :]).reshape(-1)
+    return pool.reshape(L * nb * bs, KhD).at[index].set(
+        rows.reshape(L * B * T, KhD)).reshape(pool.shape)
+
+
+def _hybrid_programs(c, params):
+    """Jaxpr and optimised HLO (without the metadata that names source
+    lines) of the tiny prefill and decode chunk, pools and state donated."""
+    from test_profile_scopes import instructions
+
+    from langstream_tpu.models.hybrid import hybrid_decode_chunk_paged
+
+    layout = PagedLayout(block_size=16, num_blocks=9, max_blocks_per_slot=4)
+    pool_k, pool_v = init_hybrid_pool(c, layout)
+    state = init_hybrid_state(c, 2)
+    tables = jnp.asarray([[1, 2, 3, 4], [5, 6, 7, 8]], jnp.int32)
+
+    def prefill(params, pool_k, pool_v, state):
+        return hybrid_prefill_paged(
+            c, params, jnp.zeros((2, 32), jnp.int32),
+            jnp.asarray([20, 32], jnp.int32), pool_k, pool_v, state, tables,
+            jnp.arange(2, dtype=jnp.int32))
+
+    def chunk(params, pool_k, pool_v, state):
+        return hybrid_decode_chunk_paged(
+            c, params, jnp.zeros((2,), jnp.int32),
+            jnp.asarray([20, 33], jnp.int32), jnp.asarray([True, True]),
+            pool_k, pool_v, state, tables,
+            lambda logits, key: (jnp.argmax(logits, -1).astype(jnp.int32),
+                                 jnp.zeros(logits.shape[:1], jnp.float32)),
+            jax.random.PRNGKey(0), 4, num_read_blocks=3, return_packed=True)
+
+    out = {}
+    for fn in (prefill, chunk):
+        args = (params, pool_k, pool_v, state)
+        text = jax.jit(fn, donate_argnums=(1, 2, 3)).lower(
+            *args).compile().as_text()
+        out[fn.__name__] = (str(jax.make_jaxpr(fn)(*args)), instructions(text))
+    return out
+
+
+def test_the_shared_commit_leaves_the_hybrid_programs_as_they_were(
+        c, params, monkeypatch):
+    """``models/hybrid.py`` no longer has a ``write_rows`` of its own; with
+    the one of ``models/paged.py`` its prefill and decode chunk are, jaxpr
+    and optimised HLO instruction for instruction, what they were with the
+    parent's own."""
+    from langstream_tpu.models import hybrid, paged
+
+    assert hybrid.write_rows is paged.write_rows
+    assert "def write_rows" not in open(hybrid.__file__).read()
+    shared = _hybrid_programs(c, params)
+    monkeypatch.setattr(hybrid, "write_rows", parents_hybrid_write_rows)
+    own = _hybrid_programs(c, params)
+    for name in ("prefill", "chunk"):
+        assert "scatter" in shared[name][0]
+        assert shared[name][0] == own[name][0], name
+        assert shared[name][1] == own[name][1], name

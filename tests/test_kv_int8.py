@@ -237,6 +237,57 @@ def test_paged_write_gather_roundtrip_int8():
     assert np.all(diff <= step[:, :, :T] * 0.51)
 
 
+@pytest.mark.parametrize("program", ["prefill", "continue", "chunk"])
+def test_the_int8_pool_after_a_program_is_the_parents_bit_for_bit(
+        program, monkeypatch):
+    """Each dense program that commits, run over an int8 pool that already
+    holds rows: data and scales come out as they did with the parent's
+    commit (``tests/test_paged.py`` keeps it as the plain reference), and
+    so do the tokens and logits beside them."""
+    from test_paged import greedy_sample, reference_write_rows
+
+    from langstream_tpu.models import llama_paged
+    from langstream_tpu.models.paged import PagedLayout, init_paged_kv_cache_int8
+
+    mc = LlamaConfig.tiny(max_seq_len=64)
+    params = init_llama_params(mc, jax.random.PRNGKey(2))
+    layout = PagedLayout(block_size=8, num_blocks=9, max_blocks_per_slot=4)
+    rng = np.random.default_rng(17)
+    pools = [
+        {"q": jnp.asarray(rng.integers(-127, 128, leaf["q"].shape), jnp.int8),
+         "s": jnp.asarray(rng.uniform(0.01, 0.1, leaf["s"].shape), jnp.float32)}
+        for leaf in init_paged_kv_cache_int8(mc, layout)
+    ]
+    tables = jnp.asarray([[3, 1, 6, 2], [8, 4, 7, 5]], jnp.int32)
+    tokens = jnp.asarray(rng.integers(0, mc.vocab_size, (2, 16)), jnp.int32)
+
+    def run():
+        if program == "prefill":
+            return llama_paged.llama_prefill_paged(
+                mc, params, tokens, jnp.asarray([11, 16], jnp.int32), *pools,
+                tables)
+        if program == "continue":
+            return llama_paged.llama_prefill_continue_paged(
+                mc, params, tokens[:, :8], jnp.asarray([8, 13], jnp.int32),
+                jnp.asarray([8, 5], jnp.int32), *pools, tables,
+                num_read_blocks=2)
+        return llama_paged.llama_decode_chunk_paged(
+            mc, params, tokens[:, 0], jnp.asarray([6, 19], jnp.int32),
+            jnp.asarray([True, False]), *pools, tables, greedy_sample,
+            jax.random.PRNGKey(0), 4, num_read_blocks=3)
+
+    got = run()
+    monkeypatch.setattr(llama_paged, "write_rows", reference_write_rows)
+    want = run()
+    assert jax.tree.structure(got) == jax.tree.structure(want)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+    # the program did commit: both pools differ from what went in
+    changed = [not np.array_equal(np.asarray(a), np.asarray(b))
+               for a, b in zip(jax.tree.leaves(got[-2:]), jax.tree.leaves(pools))]
+    assert all(changed)
+
+
 def test_engine_serves_paged_int8_with_schedulers(run_async):
     """The full paged posture on the int8 pool: prefix cache + speculative
     decoding + chunked prefill all read/write through the quantised pool,

@@ -112,6 +112,233 @@ def test_write_rows_and_gather_roundtrip():
 
 
 # ---------------------------------------------------------------------------
+# the commit: one scatter with the layer in the row index, held bit for bit
+# to the form it replaced
+# ---------------------------------------------------------------------------
+
+
+def reference_write_rows(cache, rows, block_tables, starts, valid):
+    """The commit as the parent (8e35ac5) wrote it, the plain reference:
+    the pool seen as ``(L, nb*bs, tail)`` and scattered along its SECOND
+    axis. Same rows to the same places; on the chip the compiler moved the
+    layer axis inward and back out around it, a copy of the whole pool each
+    way."""
+    from langstream_tpu.models.kvquant import quantize_rows
+
+    quant = isinstance(cache, dict)
+    nb, bs, KhD = (cache["q"] if quant else cache).shape[1:]
+    rows_data = rows["q"] if isinstance(rows, dict) else rows
+    B, T = rows_data.shape[1], rows_data.shape[2]
+    pos = starts[:, None] + jnp.arange(T)[None, :]
+    block_idx = jnp.clip(pos // bs, 0, block_tables.shape[1] - 1)
+    blocks = jnp.take_along_axis(block_tables, block_idx, axis=1)
+    flat = jnp.where(valid, blocks * bs + pos % bs, 0).reshape(-1)
+
+    def scatter(pool, new_rows):
+        L = new_rows.shape[0]
+        tail = pool.shape[3:]
+        flat_cache = pool.reshape((L, nb * bs) + tail)
+        flat_rows = new_rows.reshape((L, B * T) + tail)
+        return flat_cache.at[:, flat].set(flat_rows).reshape(pool.shape)
+
+    if not quant:
+        return scatter(cache, rows)
+    if isinstance(rows, dict):
+        return {"q": scatter(cache["q"], rows["q"]),
+                "s": scatter(cache["s"], rows["s"])}
+    L = rows.shape[0]
+    Kh = cache["s"].shape[3]
+    q = quantize_rows(rows.reshape(L, B, T, Kh, KhD // Kh))
+    return {"q": scatter(cache["q"], q["q"].reshape(L, B, T, KhD)),
+            "s": scatter(cache["s"], q["s"])}
+
+
+_COMMIT_BS, _COMMIT_NB, _COMMIT_COLS, _COMMIT_KH, _COMMIT_D = 8, 9, 3, 2, 16
+
+# starts (B,), T, valid (B, T): two slots of three table columns of 8 rows
+_COMMITS = {
+    # a decode chunk's commit: each slot appends from inside a block
+    "mid-block": ([3, 13], 4, np.ones((2, 4), bool)),
+    # rows on both sides of a block edge, one slot across two of them
+    "block-edge": ([6, 7], 11, np.ones((2, 11), bool)),
+    # a padded prefill bucket and an idle slot: nine rows go to the scratch
+    # row at once, and none of them may reach a live row
+    "invalid-rows": ([0, 9], 6, np.array([[True] * 3 + [False] * 3,
+                                          [False] * 6])),
+    # positions past the table's last column (24 rows): invalid, and the
+    # clamp keeps the lookup of their block inside the table
+    "past-the-table": ([20, 23], 9, np.array([[True] * 4 + [False] * 5,
+                                              [True] + [False] * 8])),
+}
+
+
+def _commit_case(kind, L, seed):
+    """A pool with something in every row, rows to commit and the tables."""
+    rng = np.random.default_rng(seed)
+    KhD = _COMMIT_KH * _COMMIT_D
+    shape = (L, _COMMIT_NB, _COMMIT_BS)
+    if kind == "bf16":
+        pool = jnp.asarray(rng.normal(size=shape + (KhD,)), jnp.bfloat16)
+    else:
+        pool = {
+            "q": jnp.asarray(rng.integers(-127, 128, shape + (KhD,)), jnp.int8),
+            "s": jnp.asarray(rng.uniform(0.01, 1.0, shape + (_COMMIT_KH,)),
+                             jnp.float32),
+        }
+    tables = jnp.asarray([[4, 1, 7], [2, 8, 5]], jnp.int32)
+
+    def rows(T):
+        if kind == "int8-prequantised":
+            return {
+                "q": jnp.asarray(rng.integers(-127, 128, (L, 2, T, KhD)),
+                                 jnp.int8),
+                "s": jnp.asarray(rng.uniform(0.01, 1.0, (L, 2, T, _COMMIT_KH)),
+                                 jnp.float32),
+            }
+        return jnp.asarray(rng.normal(size=(L, 2, T, KhD)), jnp.bfloat16)
+
+    return pool, rows, tables
+
+
+@pytest.mark.parametrize("L", [1, 3])
+@pytest.mark.parametrize("commit", sorted(_COMMITS))
+@pytest.mark.parametrize("kind", ["bf16", "int8", "int8-prequantised"])
+def test_write_rows_writes_the_pool_the_parents_form_wrote(kind, commit, L):
+    """Every bit of the pool (data and scales, the scratch block too) after
+    the folded scatter is what the parent's scatter along the second axis
+    left: bf16 pool, int8 pool quantising bf16 rows, int8 pool taking
+    quantised rows verbatim."""
+    from langstream_tpu.models.paged import write_rows
+
+    starts, T, valid = _COMMITS[commit]
+    pool, rows, tables = _commit_case(kind, L, seed=len(commit) + L)
+    args = (pool, rows(T), tables, jnp.asarray(starts, jnp.int32),
+            jnp.asarray(valid))
+    got = jax.jit(write_rows)(*args)
+    want = jax.jit(reference_write_rows)(*args)
+    assert jax.tree.structure(got) == jax.tree.structure(want)
+    for g, w, before in zip(*map(jax.tree.leaves, (got, want, pool))):
+        assert g.dtype == w.dtype and g.shape == w.shape
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+        # and it did write (every commit here has a valid row)
+        assert not np.array_equal(np.asarray(g), np.asarray(before))
+    if kind == "int8-prequantised" and valid[0, 0]:
+        # verbatim: slot 0's first row is the payload's, bit for bit
+        block, row = int(tables[0, starts[0] // 8]), starts[0] % 8
+        for leaf in ("q", "s"):
+            np.testing.assert_array_equal(
+                np.asarray(got[leaf][:, block, row]),
+                np.asarray(args[1][leaf][:, 0, 0]))
+
+
+def _all_eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs inside it (jit, scan,
+    cond, custom calls), in order."""
+    for e in jaxpr.eqns:
+        yield e
+        for sub in jax.core.jaxprs_in_params(e.params):
+            yield from _all_eqns(sub)
+
+
+def _tiny_commit_programs():
+    """(name, function, arguments) of the tiny prefill, continuation and
+    decode chunk over a pool of 9 blocks x 8 rows a layer, in float32 (the
+    CPU's scatter widens bfloat16: converts that say nothing of the chip)."""
+    import dataclasses
+
+    from langstream_tpu.models.llama import LlamaConfig, init_llama_params
+    from langstream_tpu.models.llama_paged import (
+        llama_decode_chunk_paged,
+        llama_prefill_continue_paged,
+        llama_prefill_paged,
+    )
+
+    c = dataclasses.replace(LlamaConfig.tiny(), dtype=jnp.float32)
+    params = init_llama_params(c, jax.random.PRNGKey(0))
+    B = 2
+    pool = jnp.zeros((c.layers, 9, 8, c.kv_heads * c.head_dim), c.dtype)
+    tables = jnp.asarray([[1, 2, 3, 4], [5, 6, 7, 8]], jnp.int32)
+    key = jax.random.PRNGKey(0)
+
+    def prefill(params, pool_k, pool_v):
+        return llama_prefill_paged(
+            c, params, jnp.zeros((B, 16), jnp.int32),
+            jnp.asarray([9, 16], jnp.int32), pool_k, pool_v, tables)
+
+    def cont(params, pool_k, pool_v):
+        return llama_prefill_continue_paged(
+            c, params, jnp.zeros((B, 8), jnp.int32),
+            jnp.asarray([8, 11], jnp.int32), jnp.asarray([8, 3], jnp.int32),
+            pool_k, pool_v, tables, num_read_blocks=2)
+
+    def chunk(params, pool_k, pool_v):
+        return llama_decode_chunk_paged(
+            c, params, jnp.zeros((B,), jnp.int32),
+            jnp.asarray([5, 17], jnp.int32), jnp.ones((B,), bool), pool_k,
+            pool_v, tables, greedy_sample, key, 2, num_read_blocks=3,
+            kernel="xla")
+
+    return c, [(f.__name__, f, (params, pool, pool))
+               for f in (prefill, cont, chunk)]
+
+
+def test_the_commit_is_one_scatter_of_rows_into_the_pool_where_it_lies():
+    """In the tiny prefill, continuation and decode chunk the only scatters
+    into something of the pool's size take a rank-2 operand of ``L*nb*bs``
+    rows (one for K, one for V), and no ``transpose`` or ``copy`` anywhere
+    in the program touches a value of the pool's size."""
+    c, programs = _tiny_commit_programs()
+    KhD = c.kv_heads * c.head_dim
+    pool_elems = c.layers * 9 * 8 * KhD
+    for name, fn, args in programs:
+        eqns = list(_all_eqns(jax.make_jaxpr(fn)(*args).jaxpr))
+        size = lambda v: int(np.prod(getattr(v.aval, "shape", ())))  # noqa: E731
+        scatters = [e for e in eqns if e.primitive.name.startswith("scatter")
+                    and size(e.invars[0]) >= pool_elems // c.layers]
+        assert [e.invars[0].aval.shape for e in scatters] == \
+            [(c.layers * 9 * 8, KhD)] * 2, name
+        moved = [e.primitive.name for e in eqns
+                 if e.primitive.name in ("transpose", "copy", "copy_p")
+                 and any(size(v) >= pool_elems // c.layers
+                         for v in (*e.invars, *e.outvars))]
+        assert not moved, (name, moved)
+
+
+def test_no_compiled_program_holds_a_second_pool(monkeypatch):
+    """What the builder reads on the chip, at tiny shapes on the CPU
+    (``tools/ops_of_shape.py``): with the pools donated, the optimised
+    program has no instruction that computes or moves a value of the
+    stacked pool's shape other than the scatter; with the parent's
+    expression in its place the same reader finds the transposes."""
+    from test_ops_of_shape import load_tool
+
+    from langstream_tpu.models import llama_paged
+
+    tool = load_tool()
+
+    def pool_ops():
+        c, programs = _tiny_commit_programs()
+        KhD = c.kv_heads * c.head_dim
+        shapes = [f"f32[{c.layers},9,8,{KhD}]", f"f32[{c.layers * 72},{KhD}]",
+                  f"f32[{c.layers},72,{KhD}]", f"f32[72,{c.layers},{KhD}]"]
+        found = {}
+        for name, fn, args in programs:
+            text = jax.jit(fn, donate_argnums=(1, 2)).lower(
+                *args).compile().as_text()
+            found[name] = tool.moved(tool.hlo_ops_of_shape(text, shapes))
+        return found
+
+    for name, ops in pool_ops().items():
+        opcodes = [op for _, op, _ in ops]
+        assert opcodes.count("scatter") == 2, (name, ops)
+        # a fusion is the scatter's own wrapper; nothing else is there
+        assert set(opcodes) <= {"scatter", "fusion"}, (name, ops)
+    monkeypatch.setattr(llama_paged, "write_rows", reference_write_rows)
+    for name, ops in pool_ops().items():
+        assert {"transpose", "copy"} & {op for _, op, _ in ops}, (name, ops)
+
+
+# ---------------------------------------------------------------------------
 # model equivalence: paged vs dense
 # ---------------------------------------------------------------------------
 

@@ -635,3 +635,37 @@ def test_engine_top_analyze_flags_overlap_collapse():
     assert any(
         "overlap collapse" in f for f in engine_top._anomalies(rollup_entry)
     )
+
+
+@pytest.mark.parametrize("budget, chunks", [(9, 1), (17, 2), (12, 2)])
+def test_no_speculative_chunk_behind_the_last_one(run_async, budget, chunks):
+    """When every running request ends inside the chunk in flight, a
+    speculative chunk behind it could only compute discarded tokens (and
+    would run ahead of whatever prefill is waiting): the burst fetches the
+    one in flight and returns. A convoy of equal budgets, one token from
+    the prefill and the rest in chunks of 8, costs exactly
+    ceil((budget - 1) / 8) decode dispatches, and the tokens are the
+    sequential loop's."""
+    from langstream_tpu.serving.engine import TpuServingEngine
+
+    prompts = ["the quick brown fox", "pack my box", "judge my vow"]
+
+    async def run_one(pipeline: bool):
+        engine = TpuServingEngine(_config(pipeline=pipeline))
+        try:
+            results = await asyncio.gather(*(
+                engine.generate(p, {"max-tokens": budget, "temperature": 0})
+                for p in prompts
+            ))
+            return [r["tokens"] for r in results], engine._decode_dispatches
+        finally:
+            await engine.close()
+
+    async def main():
+        seq_tokens, _ = await run_one(pipeline=False)
+        pipe_tokens, dispatches = await run_one(pipeline=True)
+        assert pipe_tokens == seq_tokens
+        assert all(len(t) == budget for t in pipe_tokens)
+        assert dispatches == chunks
+
+    run_async(main())
