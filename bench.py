@@ -43,14 +43,12 @@ Phases (BASELINE.md targets: >= 2000 tok/s/chip, p50 gateway TTFT < 200ms):
 2. **Gateway TTFT**: websocket chat gateway → topic → engine → streamed
    chunks, Poisson arrivals at a sub-saturation rate, measured at the
    client socket (tools/gateway_bench.py).
-3. **Paged-KV / int8-KV decode** (1b proxy path only — the 8B headline
-   already runs paged+int8): the same workload on the block-pool cache and
-   on the int8 KV cache, so both layouts have driver-recorded numbers.
-   The paged phase additionally runs the **pipeline ablation**: the same
-   workload through the sequential reference loop (``pipeline=False``,
-   the ``LS_TPU_PIPELINE=0`` escape hatch), recording both legs'
-   ``overlap_ratio``/``host_exposed_ms_p50`` flight rollups and the
-   step-time speedup the depth-2 pipelined dispatch buys.
+3. **int8-KV decode** (1b proxy path only — the 8B headline already
+   runs int8): the same workload on the int8 pool. The **pipeline
+   ablation** (``run_paged_pipeline_phase``: the same workload through
+   the sequential reference loop, ``pipeline=False``) is no phase of a
+   run since PR 29, when the dense headline it ran beside went;
+   ``tests/test_pipeline.py`` calls it.
 4. **Speculative decode** on a context-copying workload: uplift vs off.
 5. **Prefix-cache TTFT**: cold vs warm TTFT for requests sharing a long
    preamble (paged layout; warm requests adopt cached prefix blocks).
@@ -62,8 +60,8 @@ Phases (BASELINE.md targets: >= 2000 tok/s/chip, p50 gateway TTFT < 200ms):
    actually bounds interactive latency under contention.
 
 Env knobs: BENCH_MODEL (tiny|llama-1b|llama3-8b|...), BENCH_SLOTS,
-BENCH_DECODE_CHUNK, BENCH_QUANTIZE (int8|none), BENCH_KV (dense|paged),
-BENCH_KV_QUANT (int8|none), BENCH_GATEWAY=0 / BENCH_PAGED=0 /
+BENCH_DECODE_CHUNK, BENCH_QUANTIZE (int8|none),
+BENCH_KV_QUANT (int8|none), BENCH_GATEWAY=0 /
 BENCH_PREFIX=0 / BENCH_KV_INT8=0 / BENCH_SPEC=0 / BENCH_QOS=0 /
 BENCH_OOM=0 / BENCH_PARTITION=0 / BENCH_STREAM=0 / BENCH_LORA=0 to
 skip phases.
@@ -110,15 +108,13 @@ BASELINE_TOK_S = 2000.0
 # BENCH_QUANTIZE=none reverts to bf16
 _quant_env = os.environ.get("BENCH_QUANTIZE", "int8").strip().lower()
 QUANTIZE = None if _quant_env in ("", "none", "bf16") else _quant_env
-KV_LAYOUT = os.environ.get("BENCH_KV", "").strip().lower()
+KV_LAYOUT = "paged"  # the engine serves the paged pool and nothing else
 _kvq_env = os.environ.get("BENCH_KV_QUANT", "").strip().lower()
 KV_QUANT = None if _kvq_env in ("", "none", "bf16") else _kvq_env
-# explicit env pins win over model-based defaults (an explicit "none" is a
-# pin too — it must not be re-defaulted to int8 for the 8B posture)
-KV_LAYOUT_PINNED = bool(KV_LAYOUT)
+# an explicit env pin wins over the model-based default (an explicit "none"
+# is a pin too — it must not be re-defaulted to int8 for the 8B posture)
 KV_QUANT_PINNED = "BENCH_KV_QUANT" in os.environ
 RUN_GATEWAY = os.environ.get("BENCH_GATEWAY", "1") != "0"
-RUN_PAGED = os.environ.get("BENCH_PAGED", "1") != "0"
 RUN_PREFIX = os.environ.get("BENCH_PREFIX", "1") != "0"
 RUN_PREFIX_WARM = os.environ.get("BENCH_PREFIX_WARM", "1") != "0"
 RUN_KV_INT8 = os.environ.get("BENCH_KV_INT8", "1") != "0"
@@ -258,9 +254,9 @@ def _finalize_model_choice() -> None:
     Live TPU → the real Llama-3-8B shape in the full serving posture
     (int8 weights + paged int8 KV: ~8GB + ~4.3GB in 16GB HBM). Off-TPU
     nothing is picked: the caller names the model (the tool's own CPU
-    self-test) or the run fails. Explicit BENCH_MODEL / BENCH_KV /
+    self-test) or the run fails. Explicit BENCH_MODEL /
     BENCH_KV_QUANT win."""
-    global MODEL, KV_LAYOUT, KV_QUANT
+    global MODEL, KV_QUANT
     if not MODEL:
         if _PROBE_INFO.get("backend") != "tpu":
             raise RuntimeError(
@@ -269,8 +265,6 @@ def _finalize_model_choice() -> None:
                 f"nothing is benchmarked in place of the chip"
             )
         MODEL = "llama3-8b"
-    if not KV_LAYOUT_PINNED:
-        KV_LAYOUT = "paged" if MODEL in ("llama3-8b", "llama-3-8b") else "dense"
     if not KV_QUANT_PINNED and MODEL in ("llama3-8b", "llama-3-8b"):
         KV_QUANT = "int8"
 
@@ -279,14 +273,13 @@ def _posture_env() -> dict:
     """Env pins handing the parent's finalized model/posture to a child."""
     return {
         "BENCH_MODEL": MODEL,
-        "BENCH_KV": KV_LAYOUT or "dense",
         "BENCH_KV_QUANT": KV_QUANT or "none",
     }
 
 
 def _record(headline: dict, detail: dict) -> dict:
     wdtype = "int8-weights" if QUANTIZE == "int8" else "bf16"
-    kv_desc = f"{KV_LAYOUT or 'dense'}{' int8' if KV_QUANT == 'int8' else ''} KV"
+    kv_desc = f"{KV_LAYOUT}{' int8' if KV_QUANT == 'int8' else ''} KV"
     # the device as the probe child's JAX reported it — never assumed
     kind = _PROBE_INFO.get("device_kind") or "no device"
     if MODEL in ("llama3-8b", "llama-3-8b"):
@@ -393,7 +386,7 @@ def run_bench() -> dict:
     headline = _run_child("decode", budget, _posture_env())
     if "error" in headline:
         headline["tok_s"] = 0.0
-    detail[KV_LAYOUT or "dense"] = headline
+    detail[KV_LAYOUT] = headline
     _emit(_record(headline, detail))  # headline locked in — flush it
 
     # ---- optional phases, each its own child --------------------------
@@ -411,7 +404,6 @@ def run_bench() -> dict:
         _emit(_record(headline, detail))
 
     optional("gateway", RUN_GATEWAY)
-    optional("paged", RUN_PAGED and KV_LAYOUT != "paged")
     # same saturated workload on the int8 KV cache: halved cache-read bytes
     # halve the roofline floor — this records what that buys
     optional("kv_int8", RUN_KV_INT8 and KV_QUANT != "int8")
@@ -628,7 +620,9 @@ async def run_decode_bench(
 
     prompt_tokens = results[0]["num_prompt_tokens"]
     mean_len = prompt_tokens + MAX_TOKENS / 2
-    window = engine._window_for(int(mean_len)) or MAX_SEQ
+    window = (
+        engine._read_blocks_for(int(mean_len)) * engine.paged_layout.block_size
+    )
     roof = decode_step_bytes(
         engine.model_config, slots=SLOTS, window=window, quantize=QUANTIZE,
         kv_quantize=kv_quantize,
@@ -831,7 +825,7 @@ async def run_qos_mix_phase() -> dict:
             },
         }
     )
-    cfg = _dc.replace(_serving_config(KV_LAYOUT or "dense", KV_QUANT), qos=qos)
+    cfg = _dc.replace(_serving_config(KV_LAYOUT, KV_QUANT), qos=qos)
     engine = TpuServingEngine.get_or_create(cfg)
     await asyncio.gather(
         *(
@@ -1038,14 +1032,12 @@ async def _child_phase(phase: str) -> dict:
     if phase == "decode":
         return await _phase(
             run_decode_bench(
-                KV_LAYOUT or "dense", BENCH_REQUESTS, kv_quantize=KV_QUANT
+                KV_LAYOUT, BENCH_REQUESTS, kv_quantize=KV_QUANT
             )
         )
-    if phase == "paged":
-        return await _phase(run_paged_pipeline_phase())
     if phase == "kv_int8":
         return await _phase(
-            run_decode_bench("dense", BENCH_REQUESTS // 2, kv_quantize="int8")
+            run_decode_bench(KV_LAYOUT, BENCH_REQUESTS // 2, kv_quantize="int8")
         )
     if phase == "gateway":
         return await _phase(run_gateway_phase())

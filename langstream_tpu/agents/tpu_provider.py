@@ -24,9 +24,8 @@ Resource shape (``configuration.yaml``):
           quantize: "int8"             # weight-only int8 (or null = bf16)
           kv-quantize: null            # "int8": per-row int8 KV cache halves
                                        # decode's cache-read HBM traffic
-                                       # (dense + paged layouts)
-          kv-layout: "paged"           # or "dense"; paged enables the three
-                                       # serving schedulers below
+          kv-block-size: 64            # rows per block of the paged KV pool
+          kv-pool-fraction: 0.5        # pool size, of slots x max-seq-len rows
           prefix-cache: true           # shared prompt prefixes skip prefill
           prefill-chunk: 0             # >0: long prompts interleave with decode
           speculative-drafts: 0        # >0: prompt-lookup speculation (greedy)

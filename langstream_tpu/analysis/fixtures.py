@@ -115,7 +115,7 @@ class Engine:
     fix=(
         "Pass the request-derived value through a sanctioned bucketing "
         "function (SANCTIONED_BUCKETING in analysis/rules_flow.py: "
-        "_pow2 / _bucket / _window_for / _read_blocks_for / "
+        "_pow2 / _bucket / _read_blocks_for / "
         "_sampler_mode, or any `*bucket*` helper) before it reaches a "
         "shape, a specialization-getter argument, or a `self._*_fns[...]` "
         "key. To sanction a new helper, add it to the registry AND a TN "

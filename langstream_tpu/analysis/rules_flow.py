@@ -85,8 +85,6 @@ from langstream_tpu.analysis.project import (
 SANCTIONED_BUCKETING = {
     "_pow2",
     "_bucket",
-    "_bucket_for",
-    "_window_for",
     "_read_blocks_for",
     "_sampler_mode",
 }

@@ -1,4 +1,11 @@
-"""int8 KV cache for the dense layout — the decode-bandwidth lever.
+"""int8 KV rows — the decode-bandwidth lever.
+
+The row arithmetic (:func:`quantize_rows`, :func:`cache_scores`,
+:func:`cache_values`) is shared by the paged int8 pool the engine serves
+(:mod:`langstream_tpu.models.paged`, ``ops/paged_attention.py``). The
+dense-cache helpers (:func:`init_kv_cache_int8`, :func:`cache_write_rows`,
+:func:`cache_slice_window`, ...) serve the dense reference in
+:mod:`langstream_tpu.models.llama`, which only tests call since PR 29.
 
 Decode throughput is bounded by HBM reads of weights + the KV window
 (serving/profiling.py roofline); at serving shapes the KV window is the
@@ -17,8 +24,7 @@ TPU-first read path — the dequantisation never materialises a bf16 cache:
 
 Cache representation: ``{"q": int8 (L, B, S, K, D), "s": f32 (L, B, S, K)}``
 — a pytree that flows through jit/scan/donation/sharding like the plain
-bf16 array it replaces (engine shards "q" and "s" with the same dp/tp
-axes). Write sites (prefill row fill, decode-chunk commit, single-step
+bf16 array it replaces. Write sites (prefill row fill, decode-chunk commit, single-step
 write) quantise; prefill's own attention runs on the fresh bf16 K/V it
 just computed, so quantisation error only enters through cross-step
 cache reads.

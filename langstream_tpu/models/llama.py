@@ -14,7 +14,15 @@ Design choices (vs. a torch port):
   down row-sharded; XLA inserts the psums on ICI. KV cache shards on the KV
   head axis; batch (slots) shards on ``dp``.
 
-Capability parity: this is the engine behind ``ai-chat-completions`` /
+The dense-cache functions here (:func:`init_kv_cache`, :func:`kv_cache_spec`,
+:func:`llama_prefill`, :func:`llama_decode_step`, :func:`llama_decode_chunk`)
+have no production caller since PR 29: the serving engine runs the paged
+programs of :mod:`langstream_tpu.models.llama_paged` only. They stay as the
+plain reference the paged programs are tested against (``tests/test_paged.py``,
+``test_kv_int8.py``, ``test_golden*.py``, ``test_moe_serving.py``) and that
+``__graft_entry__.py`` compiles.
+
+Capability parity: this is the model behind ``ai-chat-completions`` /
 ``ai-text-completions`` (reference: ``ChatCompletionsStep.java`` calling
 OpenAI etc. — here the model is local).
 """

@@ -867,55 +867,3 @@ def llama_decode_chunk_paged(
         packed = pack_tokens_logprobs(chunk_tokens, chunk_lps)
         return packed, final_tokens, final_lengths, pool_k, pool_v
     return chunk_tokens, chunk_lps, final_tokens, final_lengths, pool_k, pool_v
-
-
-def llama_decode_chunk_dense_pallas(
-    config: LlamaConfig,
-    params: dict,
-    tokens0: jax.Array,
-    base_lengths: jax.Array,
-    active: jax.Array,
-    cache_k: jax.Array,       # (L, B, S, Kh, D) — the DENSE layout
-    cache_v: jax.Array,
-    sample_fn: Callable,
-    key: jax.Array,
-    num_steps: int,
-    window: int | None,
-    kernel: str = "pallas",
-    block_size: int = 128,
-    ffn=None,                 # pluggable FFN sub-block (MoE family hook)
-    sample_extras=None,       # (presences, frequencies, counts0)
-) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array, jax.Array, jax.Array]:
-    """Dense-cache decode through the PAGED Pallas read kernel.
-
-    A dense cache is a degenerate block pool: slot ``b``'s rows are the
-    contiguous blocks ``[b*S/bs, (b+1)*S/bs)``, so reshaping the cache to
-    ``(L, B·S/bs, bs, Kh·D)`` and handing the kernel identity block tables
-    reuses the tested scalar-prefetch kernel verbatim — no densified gather,
-    no second kernel to maintain. The XLA einsum path stays the reference
-    (and the mesh path); this is the single-chip TPU fast path where the
-    GQA einsum's 2-row MXU tiles leave throughput on the table.
-    """
-    c = config
-    L, B, S, Kh, D = cache_k.shape
-    if S % block_size:
-        raise ValueError(f"max_seq_len {S} not divisible by {block_size}")
-    nb = S // block_size
-    pool_k = cache_k.reshape(L, B * nb, block_size, Kh * D)
-    pool_v = cache_v.reshape(L, B * nb, block_size, Kh * D)
-    tables = (
-        jnp.arange(B, dtype=jnp.int32)[:, None] * nb
-        + jnp.arange(nb, dtype=jnp.int32)[None, :]
-    )
-    rows = window if window is not None else S
-    num_read_blocks = max(1, min(-(-rows // block_size), nb))
-    out = llama_decode_chunk_paged(
-        c, params, tokens0, base_lengths, active, pool_k, pool_v, tables,
-        sample_fn, key, num_steps, num_read_blocks=num_read_blocks,
-        kernel=kernel, ffn=ffn, sample_extras=sample_extras,
-    )
-    chunk_t, chunk_lp, final_t, final_l, pk, pv = out
-    return (
-        chunk_t, chunk_lp, final_t, final_l,
-        pk.reshape(L, B, S, Kh, D), pv.reshape(L, B, S, Kh, D),
-    )

@@ -3,7 +3,8 @@
 Execution model:
 
 - A fixed pool of ``slots`` (the decode batch dimension). Each active slot
-  owns a row of the KV cache ``(L, slots, S, K, D)``.
+  owns blocks of the paged KV pool ``(L, blocks, block_size, K*D)`` through
+  its row of the block table (models/paged.py).
 - **Admission**: a queued request prefilles into a free slot (prompt padded
   to a power-of-two bucket → few compiled shapes) and immediately joins the
   decode batch. No stop-the-world: decode keeps a fixed batch shape, so a
@@ -12,11 +13,12 @@ Execution model:
   sampling happens in-jit (see sampler.py), only (B,) token ids come back.
 - **At-least-once friendly**: generation is driven by the agent layer's
   record loop; the engine itself is agnostic to commits.
-- **Sharding**: with a mesh, params are TP-sharded (Megatron), cache shards
-  KV heads on ``tp`` and slots on ``dp``; XLA places the collectives on ICI.
+- **Sharding**: with a mesh, params are TP-sharded (Megatron), the pool
+  shards KV heads on ``tp`` and is replicated over ``dp`` (any slot may use
+  any block); XLA places the collectives on ICI.
   An ``sp`` axis makes long prefills sequence-parallel (ring attention);
   ``ep`` shards MoE experts.
-- **Paged serving schedulers** (``kv-layout: paged``): automatic prefix
+- **Serving schedulers over the pool**: automatic prefix
   caching (shared prompt prefixes adopt content-addressed blocks; suffix-
   only prefill), chunked prefill (long prompts interleave with decode
   bursts), and prompt-lookup speculative decoding (greedy bursts verify
@@ -58,12 +60,8 @@ from langstream_tpu.core.tracing import (
 )
 from langstream_tpu.models.llama import (
     LlamaConfig,
-    init_kv_cache,
     init_llama_params,
-    llama_decode_step,
     llama_param_specs,
-    llama_prefill,
-    kv_cache_spec,
 )
 from langstream_tpu.models.encoder import (
     EncoderConfig,
@@ -246,16 +244,18 @@ class ServingConfig:
     # weight-only quantization: None (bf16) or "int8" (scales TP-shard
     # with their weights, so the mesh posture keeps the int8 default)
     quantize: str | None = None
-    # KV-cache quantization (dense AND paged layouts): None (bf16) or
-    # "int8" — per-(position, head)-row absmax int8 halves the cache-read
-    # HBM traffic that dominates the decode roofline; the scale folds into
-    # scores/probs so no bf16 cache is ever materialised (models/kvquant.py).
-    # Which kernel reads an int8 cache: see paged_kernel / dense_kernel
+    # KV-pool quantization: None (bf16) or "int8" — per-(position,
+    # head)-row absmax int8 halves the cache-read HBM traffic that
+    # dominates the decode roofline; the scale folds into scores/probs so
+    # no bf16 cache is ever materialised (models/kvquant.py). Which kernel
+    # reads an int8 pool: see paged_kernel
     kv_quantize: str | None = None
-    # KV cache layout: "dense" reserves slots × max_seq_len rows up front;
-    # "paged" shares a block pool sized kv_pool_fraction of that, with
-    # worst-case admission reservations (models/paged.py)
-    kv_layout: str = "dense"
+    # A constant, not an option: the engine serves the paged block pool
+    # (models/paged.py: kv_pool_fraction of slots x max_seq_len rows, with
+    # worst-case admission reservations) and nothing else. The keyword
+    # stays because bench/configs/*.json and tests/bench pass it; any
+    # other value is refused in __post_init__
+    kv_layout: str = "paged"
     kv_block_size: int = 64
     kv_pool_fraction: float = 0.5
     kv_pool_blocks: int | None = None  # explicit pool size override
@@ -270,17 +270,12 @@ class ServingConfig:
     # "pallas-interpret" runs the kernel in the Pallas interpreter: CPU
     # tests only, refused on a TPU backend.
     paged_kernel: str = "auto"
-    # dense decode read path. "auto": the Pallas paged-read kernel over the
-    # dense cache viewed as identity-mapped blocks on single-chip TPU with
-    # a bf16 cache; the XLA einsum elsewhere, under meshes and for int8
-    # caches. "xla" | "pallas" | "pallas-interpret" as above.
-    dense_kernel: str = "auto"
-    # automatic prefix caching (paged layout only): full prompt blocks are
+    # automatic prefix caching: full prompt blocks are
     # content-addressed; requests sharing a prefix (system preambles, RAG
     # templates, chat history) adopt the cached blocks read-only and
     # prefill just the suffix — the TTFT lever for shared-prefix traffic
     prefix_cache: bool = True
-    # prompt-lookup speculative decoding (paged layout, greedy bursts):
+    # prompt-lookup speculative decoding (greedy bursts):
     # each step drafts N continuation tokens by matching the
     # context's last bigram earlier in the context (strong on RAG /
     # summarization / code where output copies input) and verifies them in
@@ -288,7 +283,7 @@ class ServingConfig:
     # have produced anyway, so streams are bit-identical to plain decode —
     # accepted drafts just arrive ~k tokens per step. 0 disables.
     speculative_drafts: int = 0
-    # chunked prefill (paged layout only): prompts whose to-prefill length
+    # chunked prefill: prompts whose to-prefill length
     # exceeds this are admitted immediately but prefilled prefill_chunk
     # tokens at a time through the continuation path, INTERLEAVED with
     # decode bursts — a long prompt no longer stalls every active stream
@@ -347,8 +342,7 @@ class ServingConfig:
     # prefix-cache-aware) then EXPORTS the request's KV blocks over the
     # handoff plane (serving/kvtransfer.py) instead of decoding;
     # "decode" additionally accepts imports that join the decode batch
-    # directly, skipping prefill. Both split roles require kv-layout=
-    # paged (the handoff serializes paged blocks). Deployed pods get the
+    # directly, skipping prefill. Deployed pods get the
     # role from the StatefulSet split's LS_POOL_ROLE env (from_dict
     # fallback) so both pools share one agent config secret.
     pool_role: str = "combined"
@@ -358,8 +352,7 @@ class ServingConfig:
     # (object storage via the kvtransfer wire format) under the T0
     # cache: eviction demotes T0→T1→T2, admission promotes/hydrates on
     # hit, and cross-replica cold starts of shared system prompts
-    # hydrate instead of recomputing. Requires kv-layout=paged with
-    # prefix-cache on.
+    # hydrate instead of recomputing. Requires prefix-cache on.
     prefix_store: "PrefixStoreSpec | None" = None
     # multi-LoRA adapter store (serving/adapters.py, docs/ADAPTERS.md):
     # None keeps the single-model engine, bit for bit — no stacked
@@ -368,9 +361,9 @@ class ServingConfig:
     # t0-entries device-resident adapter rows (row 0 = zeros for
     # adapter-less slots), a T1 host-RAM spill, and a T2 object-storage
     # origin; requests name adapters via the langstream-adapter header
-    # and admission blocks on hydration like the prefix stash. Requires
-    # kv-layout=paged; incompatible with multi-host lockstep (followers
-    # replay positional descriptors that carry no adapter rows).
+    # and admission blocks on hydration like the prefix stash.
+    # Incompatible with multi-host lockstep (followers replay positional
+    # descriptors that carry no adapter rows).
     adapter_store: "AdapterStoreSpec | None" = None
     # device-survival plane (docs/RESILIENCE.md): a device allocator
     # failure (RESOURCE_EXHAUSTED and its jaxlib spellings) at a
@@ -410,6 +403,17 @@ class ServingConfig:
     # beats skipping it on the slower one
     prefix_cache_max_suffix: int = 4096
 
+    def __post_init__(self) -> None:
+        if self.kv_layout != "paged":
+            what = (
+                "was removed at PR 29" if self.kv_layout == "dense"
+                else "is unknown"
+            )
+            raise ValueError(
+                f"kv-layout {self.kv_layout!r} {what}: the engine serves "
+                f"the paged pool; drop the key"
+            )
+
     def to_dict(self) -> dict[str, Any]:
         """Kebab-case dict that :meth:`from_dict` round-trips — the lockstep
         handshake ships this so followers build the identical engine."""
@@ -434,7 +438,6 @@ class ServingConfig:
             "kv-pool-fraction": self.kv_pool_fraction,
             "kv-pool-blocks": self.kv_pool_blocks,
             "paged-kernel": self.paged_kernel,
-            "dense-kernel": self.dense_kernel,
             "prefix-cache": self.prefix_cache,
             "prefix-cache-max-suffix": self.prefix_cache_max_suffix,
             "prefix-store": (
@@ -493,7 +496,7 @@ class ServingConfig:
                 d.get("warmup-on-start", d.get("warmup_on_start", False))
             ),
             prefill_batch=int(d.get("prefill-batch", 8)),
-            kv_layout=d.get("kv-layout", d.get("kv_layout", "dense")),
+            kv_layout=d.get("kv-layout", d.get("kv_layout", "paged")),
             kv_block_size=int(d.get("kv-block-size", d.get("kv_block_size", 64))),
             kv_pool_fraction=float(
                 d.get("kv-pool-fraction", d.get("kv_pool_fraction", 0.5))
@@ -504,7 +507,6 @@ class ServingConfig:
                 else None
             ),
             paged_kernel=d.get("paged-kernel", d.get("paged_kernel", "auto")),
-            dense_kernel=d.get("dense-kernel", d.get("dense_kernel", "auto")),
             prefix_cache=_parse_bool(
                 d.get("prefix-cache", d.get("prefix_cache", True))
             ),
@@ -1349,8 +1351,6 @@ class TpuServingEngine:
         ) + tree_device_bytes(self.cache_v)
         self._kv_block_bytes = (
             self._kv_cache_bytes // self.paged_layout.num_blocks
-            if self.block_mgr is not None
-            else 0
         )
         self._state_bytes = tree_device_bytes(self.state)
         act_bytes = np.dtype(mc.dtype).itemsize
@@ -1409,8 +1409,8 @@ class TpuServingEngine:
         }
         # tiered prefix store (serving/prefixstore.py, docs/PREFIX.md):
         # T1 host-RAM spill + T2 object storage under the T0 prefix
-        # cache. Constructed only for a paged engine with the cache on
-        # (validated above); requests stalled on a T2 hydration are
+        # cache. Constructed only with the cache on (validated above);
+        # requests stalled on a T2 hydration are
         # stashed OFF the scheduler so they never head-block admission.
         self.prefix_store: PrefixStore | None = None
         self._prefix_hydrating: list = []  # (request, deadline_m, digests)
@@ -1419,7 +1419,6 @@ class TpuServingEngine:
         if (
             config.prefix_store is not None
             and config.prefix_store.enabled
-            and self.block_mgr is not None
             and config.prefix_cache
         ):
             self.prefix_store = PrefixStore(
@@ -1548,27 +1547,23 @@ class TpuServingEngine:
         # through to failing every in-flight request
         self._shrink_inline_preempted = 0
         self._shrink_inline_shed = 0
-        self._m_shrinks = None
-        self._m_restores = None
-        self._m_budget = None
-        if self.block_mgr is not None:
-            self._m_shrinks = reporter.counter(
-                "pool_shrinks_total",
-                "adaptive KV-budget shrinks after a device allocator "
-                "failure (degrade-don't-die: evidence rides the "
-                "pool-shrink flight events)",
-            )
-            self._m_restores = reporter.counter(
-                "pool_restores_total",
-                "shrink quanta restored by the recovery probe after a "
-                "quiet window",
-            )
-            self._m_budget = reporter.gauge(
-                "kv_budget_blocks",
-                "live paged-KV admission budget in blocks (configured "
-                "pool minus blocks withheld by adaptive shrink)",
-            )
-            self._m_budget(self.block_mgr.usable_blocks)
+        self._m_shrinks = reporter.counter(
+            "pool_shrinks_total",
+            "adaptive KV-budget shrinks after a device allocator "
+            "failure (degrade-don't-die: evidence rides the "
+            "pool-shrink flight events)",
+        )
+        self._m_restores = reporter.counter(
+            "pool_restores_total",
+            "shrink quanta restored by the recovery probe after a "
+            "quiet window",
+        )
+        self._m_budget = reporter.gauge(
+            "kv_budget_blocks",
+            "live paged-KV admission budget in blocks (configured "
+            "pool minus blocks withheld by adaptive shrink)",
+        )
+        self._m_budget(self.block_mgr.usable_blocks)
         self.journal: RequestJournal | None = None
         self._m_journal_depth = None
         if config.journal_dir:
@@ -1699,55 +1694,24 @@ class TpuServingEngine:
                 f"combined, prefill, decode"
             )
         if (
-            self.config.pool_role != "combined"
-            and self.config.kv_layout != "paged"
+            self.config.prefix_store is not None
+            and self.config.prefix_store.enabled
+            and not self.config.prefix_cache
         ):
             raise ValueError(
-                "pool-role prefill/decode requires kv-layout=paged (the "
-                "KV handoff plane serializes paged blocks; a dense cache "
-                "has no block tables to hand off)"
+                "prefix-store requires prefix-cache=true (T0 IS the "
+                "automatic prefix cache; without it there is nothing "
+                "to demote or promote)"
             )
-        if self.config.prefix_store is not None and self.config.prefix_store.enabled:
-            if self.config.kv_layout != "paged":
-                raise ValueError(
-                    "prefix-store requires kv-layout=paged (the tiers "
-                    "demote/promote content-addressed pool blocks; a dense "
-                    "cache has none)"
-                )
-            if not self.config.prefix_cache:
-                raise ValueError(
-                    "prefix-store requires prefix-cache=true (T0 IS the "
-                    "automatic prefix cache; without it there is nothing "
-                    "to demote or promote)"
-                )
         if (
             self.config.adapter_store is not None
             and self.config.adapter_store.enabled
-        ):
-            if self.config.kv_layout != "paged":
-                raise ValueError(
-                    "adapter-store requires kv-layout=paged (batched "
-                    "ragged adapter application rides the paged "
-                    "decode/prefill programs)"
-                )
-            if jax.process_count() > 1:
-                raise ValueError(
-                    "adapter-store is incompatible with multi-host "
-                    "lockstep (followers replay positional dispatch "
-                    "descriptors that carry no adapter rows)"
-                )
-        if self.config.prefill_chunk > 0 and self.config.kv_layout != "paged":
-            raise ValueError(
-                "prefill-chunk requires kv-layout=paged (chunked prefill "
-                "commits through the paged continuation path)"
-            )
-        if (
-            self.config.speculative_drafts > 0
-            and self.config.kv_layout != "paged"
+            and jax.process_count() > 1
         ):
             raise ValueError(
-                "speculative-drafts requires kv-layout=paged (the verify "
-                "step commits through the paged continuation path)"
+                "adapter-store is incompatible with multi-host "
+                "lockstep (followers replay positional dispatch "
+                "descriptors that carry no adapter rows)"
             )
         if (
             self.config.speculative_drafts > 0
@@ -1764,130 +1728,84 @@ class TpuServingEngine:
                 "may diverge from non-speculative runs (int8 KV commit-"
                 "boundary quantization differs under the verify path)"
             )
-        self.block_mgr = None
         init_state = lambda: None  # noqa: E731  (the hybrid family has one)
-        if self.config.kv_layout == "paged":
-            from langstream_tpu.models.paged import (
-                BlockManager,
-                PagedLayout,
-                init_paged_kv_cache,
+        from langstream_tpu.models.paged import (
+            BlockManager,
+            PagedLayout,
+            init_paged_kv_cache,
+        )
+
+        self.paged_layout = PagedLayout.for_model(
+            mc.max_seq_len,
+            self.config.slots,
+            block_size=self.config.kv_block_size,
+            hbm_fraction_of_dense=self.config.kv_pool_fraction,
+            num_blocks=self.config.kv_pool_blocks,
+        )
+        self.block_mgr = BlockManager(
+            self.paged_layout, self.config.slots,
+            state_bytes_per_slot=(
+                mc.state_bytes_per_slot if self.is_hybrid else 0
+            ),
+        )
+        if self.is_hybrid:
+            from langstream_tpu.models.hybrid import (
+                init_hybrid_pool,
+                init_hybrid_state,
             )
 
-            self.paged_layout = PagedLayout.for_model(
-                mc.max_seq_len,
-                self.config.slots,
-                block_size=self.config.kv_block_size,
-                hbm_fraction_of_dense=self.config.kv_pool_fraction,
-                num_blocks=self.config.kv_pool_blocks,
-            )
-            self.block_mgr = BlockManager(
-                self.paged_layout, self.config.slots,
-                state_bytes_per_slot=(
-                    mc.state_bytes_per_slot if self.is_hybrid else 0
-                ),
-            )
-            if self.is_hybrid:
-                from langstream_tpu.models.hybrid import (
-                    init_hybrid_pool,
-                    init_hybrid_state,
-                )
+            init_cache = partial(init_hybrid_pool, mc, self.paged_layout)
+            init_state = partial(init_hybrid_state, mc, self.config.slots)
+        elif self.config.kv_quantize == "int8":
+            from langstream_tpu.models.paged import init_paged_kv_cache_int8
 
-                init_cache = partial(init_hybrid_pool, mc, self.paged_layout)
-                init_state = partial(init_hybrid_state, mc, self.config.slots)
-            elif self.config.kv_quantize == "int8":
-                from langstream_tpu.models.paged import init_paged_kv_cache_int8
-
-                init_cache = partial(
-                    init_paged_kv_cache_int8, mc, self.paged_layout
-                )
-            else:
-                init_cache = partial(init_paged_kv_cache, mc, self.paged_layout)
-            # The read kernels are resolved HERE, once, from what the
-            # engine can observe (backend, pool dtype, mesh) — the model
-            # functions run exactly the kernel they are handed and raise
-            # on one they cannot, so no path gives way silently.
-            kernel = self.config.paged_kernel
-            quant_pool = self.config.kv_quantize == "int8"
-            if kernel not in ("auto", "xla", "pallas", "pallas-interpret"):
-                raise ValueError(f"unknown paged_kernel {kernel!r}")
-            self._refuse_interpreter_on_tpu("paged_kernel", kernel)
-            if kernel == "auto":
-                # bf16 pools read through the Pallas kernel on TPU (under
-                # a mesh per-shard via shard_map: slots on dp, heads on
-                # tp): it fetches only the live blocks, from the stacked
-                # pool in place. int8 pools read through the fused XLA
-                # gather: their Pallas twin _paged_kernel_q8 is still on
-                # the static (slots, table columns) grid over a slice of
-                # the layer's pool, compiles on the v5e and matches the
-                # gather at 8B shapes (chip_smoke.py), and has no timing;
-                # moving it onto the bf16 kernel's driver is ROADMAP S3's
-                # next step; paged_kernel=pallas selects it meanwhile.
-                kernel = (
-                    "pallas"
-                    if jax.default_backend() == "tpu" and not quant_pool
-                    else "xla"
-                )
-            elif (
-                kernel != "xla"
-                and quant_pool
-                and self.mesh is not None
-                and self.mesh.size > 1
-            ):
-                raise ValueError(
-                    f"paged_kernel={kernel!r} with kv-quantize=int8 under "
-                    f"a mesh: the shard_map wrapper of the paged read "
-                    f"carries no specs for the int8 scales; use "
-                    f"paged_kernel=xla (or auto) for sharded int8 pools"
-                )
-            self.paged_read_kernel = kernel
-            # continuation prefill / speculative verify read history
-            # through the multi-query kernel, which has no int8 twin:
-            # int8 pools take the XLA history sweep there, by selection
-            self.continuation_read_kernel = "xla" if quant_pool else kernel
-        elif self.config.kv_layout != "dense":
-            raise ValueError(f"unknown kv_layout {self.config.kv_layout!r}")
+            init_cache = partial(
+                init_paged_kv_cache_int8, mc, self.paged_layout
+            )
         else:
-            if self.config.kv_quantize == "int8":
-                from langstream_tpu.models.kvquant import init_kv_cache_int8
-
-                init_cache = partial(init_kv_cache_int8, mc, self.config.slots)
-            else:
-                init_cache = partial(init_kv_cache, mc, self.config.slots)
-            kernel = self.config.dense_kernel
-            self._refuse_interpreter_on_tpu("dense_kernel", kernel)
-            if kernel == "auto":
-                # the paged Pallas read kernel doubles as the dense fast
-                # path (identity block tables); meshes keep the XLA einsum,
-                # and so does the int8 cache (the scale-folded einsum read
-                # IS the fused fast path — the Pallas kernel is bf16-only)
-                kernel = (
-                    "pallas"
-                    if self.mesh is None
-                    and jax.default_backend() == "tpu"
-                    and mc.max_seq_len % 128 == 0
-                    and self.config.kv_quantize != "int8"
-                    else "xla"
-                )
-            elif kernel != "xla":
-                # forced kernels fail fast at construction, not inside a
-                # jitted trace at first decode
-                if self.config.kv_quantize == "int8":
-                    raise ValueError(
-                        "dense_kernel=pallas reads a bf16 cache; with "
-                        "kv-quantize=int8 keep dense_kernel=xla"
-                    )
-                if self.mesh is not None:
-                    raise ValueError(
-                        "dense_kernel=pallas runs per-device; under a mesh "
-                        "keep dense_kernel=xla (the paged layout has the "
-                        "shard_map'd kernel)"
-                    )
-                if mc.max_seq_len % 128 != 0:
-                    raise ValueError(
-                        f"dense_kernel=pallas needs max_seq_len divisible by "
-                        f"128, got {mc.max_seq_len}"
-                    )
-            self.dense_read_kernel = kernel
+            init_cache = partial(init_paged_kv_cache, mc, self.paged_layout)
+        # The read kernels are resolved HERE, once, from what the
+        # engine can observe (backend, pool dtype, mesh) — the model
+        # functions run exactly the kernel they are handed and raise
+        # on one they cannot, so no path gives way silently.
+        kernel = self.config.paged_kernel
+        quant_pool = self.config.kv_quantize == "int8"
+        if kernel not in ("auto", "xla", "pallas", "pallas-interpret"):
+            raise ValueError(f"unknown paged_kernel {kernel!r}")
+        self._refuse_interpreter_on_tpu("paged_kernel", kernel)
+        if kernel == "auto":
+            # bf16 pools read through the Pallas kernel on TPU (under
+            # a mesh per-shard via shard_map: slots on dp, heads on
+            # tp): it fetches only the live blocks, from the stacked
+            # pool in place. int8 pools read through the fused XLA
+            # gather: their Pallas twin _paged_kernel_q8 is still on
+            # the static (slots, table columns) grid over a slice of
+            # the layer's pool, compiles on the v5e and matches the
+            # gather at 8B shapes (chip_smoke.py), and has no timing;
+            # moving it onto the bf16 kernel's driver is ROADMAP S3's
+            # next step; paged_kernel=pallas selects it meanwhile.
+            kernel = (
+                "pallas"
+                if jax.default_backend() == "tpu" and not quant_pool
+                else "xla"
+            )
+        elif (
+            kernel != "xla"
+            and quant_pool
+            and self.mesh is not None
+            and self.mesh.size > 1
+        ):
+            raise ValueError(
+                f"paged_kernel={kernel!r} with kv-quantize=int8 under "
+                f"a mesh: the shard_map wrapper of the paged read "
+                f"carries no specs for the int8 scales; use "
+                f"paged_kernel=xla (or auto) for sharded int8 pools"
+            )
+        self.paged_read_kernel = kernel
+        # continuation prefill / speculative verify read history
+        # through the multi-query kernel, which has no int8 twin:
+        # int8 pools take the XLA history sweep there, by selection
+        self.continuation_read_kernel = "xla" if quant_pool else kernel
 
         self._refuse_cache_that_cannot_fit(
             lambda: (init_cache(), init_state())
@@ -1949,37 +1867,21 @@ class TpuServingEngine:
                 specs,
                 is_leaf=lambda x: isinstance(x, P),
             )
-            if self.block_mgr is not None:
-                from langstream_tpu.models.paged import paged_cache_spec
+            from langstream_tpu.models.paged import paged_cache_spec
 
-                cspec = NamedSharding(
-                    self.mesh, paged_cache_spec(self.mesh.axis_names)
+            cspec = NamedSharding(
+                self.mesh, paged_cache_spec(self.mesh.axis_names)
+            )
+            if isinstance(cache_k, dict):
+                # the same (..., tp) spec fits both leaves: data ends in
+                # the fused Kh*D axis, scales in Kh — both shard on tp
+                place = lambda cache: jax.tree.map(
+                    lambda a: put_global(a, cspec), cache
                 )
-                if isinstance(cache_k, dict):
-                    # the same (..., tp) spec fits both leaves: data ends in
-                    # the fused Kh*D axis, scales in Kh — both shard on tp
-                    place = lambda cache: jax.tree.map(
-                        lambda a: put_global(a, cspec), cache
-                    )
-                    cache_k, cache_v = place(cache_k), place(cache_v)
-                else:
-                    cache_k = put_global(cache_k, cspec)
-                    cache_v = put_global(cache_v, cspec)
+                cache_k, cache_v = place(cache_k), place(cache_v)
             else:
-                spec = kv_cache_spec(self.mesh.axis_names)
-                if isinstance(cache_k, dict):
-                    # int8 cache pytree: data (L,B,S,K,D) takes the full
-                    # spec, scales (L,B,S,K) the same minus the head_dim axis
-                    sharding = {
-                        "q": NamedSharding(self.mesh, spec),
-                        "s": NamedSharding(self.mesh, P(*spec[:4])),
-                    }
-                    cache_k = jax.tree.map(put_global, cache_k, sharding)
-                    cache_v = jax.tree.map(put_global, cache_v, sharding)
-                else:
-                    cspec = NamedSharding(self.mesh, spec)
-                    cache_k = put_global(cache_k, cspec)
-                    cache_v = put_global(cache_v, cspec)
+                cache_k = put_global(cache_k, cspec)
+                cache_v = put_global(cache_v, cspec)
         self.cache_k, self.cache_v = cache_k, cache_v
 
         # stacked device LoRA buffers (docs/ADAPTERS.md): row 0 is the
@@ -2032,16 +1934,14 @@ class TpuServingEngine:
             def _fetchable(*arrays):
                 return arrays
 
-        paged = self.block_mgr is not None
         # None = auto (LS_TPU_FLASH env); under a mesh the kernel runs
         # per-shard through shard_map (heads on tp), so TP serving keeps it
         prefill_flash = None
         mesh_static = self.mesh
 
-        def _make_decode(sampler_mode: tuple, window: int | None,
+        def _make_decode(sampler_mode: tuple, window: int,
                          k_steps: int = 0, use_pen: bool = False):
-            """``window``: dense → cache-row bucket (None = full cache);
-            paged → number of block-table columns to sweep. ``k_steps``:
+            """``window``: number of block-table columns to sweep. ``k_steps``:
             fused steps per dispatch (0 → config.decode_chunk); light-load
             bursts compile a short variant. ``use_pen``: the variant takes
             (presences, frequencies, counts) after topps and samples with
@@ -2050,8 +1950,8 @@ class TpuServingEngine:
             K = k_steps or self.config.decode_chunk
 
             def _sample_fn_for(temps, topks, topps, pres=None, freq=None):
-                # ONE definition for all three decode variants (paged,
-                # dense-pallas, dense-xla) — they must sample identically
+                # ONE definition for both families' decode programs — they
+                # must sample identically
                 if use_pen:
                     def sample_fn(logits, sub, counts):
                         return sample_tokens(
@@ -2095,85 +1995,38 @@ class TpuServingEngine:
 
                 return _decode_chunk
 
-            if paged:
-                @partial(jax.jit, donate_argnums=(1, 2))
-                def _decode_chunk(params, cache_k, cache_v, tokens, lengths,
-                                  active, tables, key, temps, topks, topps,
-                                  pres=None, freq=None, counts=None,
-                                  ad_layers=None, ad_ids=None):
-                    from langstream_tpu.models.llama_paged import (
-                        llama_decode_chunk_paged,
-                    )
-
-                    # kwargs default to None so the adapter-less engine traces
-                    # the exact seed jaxpr — adapters ride in only when the
-                    # store is enabled and the dispatch passes them explicitly
-                    adapters = (
-                        None if ad_ids is None
-                        else {"ids": ad_ids, "layers": ad_layers}
-                    )
-                    sample_fn = _sample_fn_for(temps, topks, topps, pres, freq)
-                    # return_packed folds the tokens+bitcast-logprobs pack
-                    # into the decode program itself: the chunk's whole
-                    # host traffic is out[0]'s D2H copy, with no post-hoc
-                    # pack dispatch (pre-fusion _pack_chunk) behind it
-                    out = llama_decode_chunk_paged(
-                        mc_static, params, tokens, lengths, active,
-                        cache_k, cache_v, tables, sample_fn, key, K,
-                        num_read_blocks=window,
-                        kernel=self.paged_read_kernel,
-                        mesh=mesh_static, ffn=ffn_static,
-                        sample_extras=_extras(pres, freq, counts),
-                        adapters=adapters,
-                        return_packed=True,
-                    )
-                    return _fetchable(out[0]) + out[1:]
-
-                return _decode_chunk
-
             @partial(jax.jit, donate_argnums=(1, 2))
-            def _decode_chunk(params, cache_k, cache_v, tokens, lengths, active,
-                              key, temps, topks, topps,
-                              pres=None, freq=None, counts=None):
-                """K fused decode steps; one host round-trip per chunk. The
-                big cache is read-only inside the chunk (llama_decode_chunk)
-                — per-step HBM traffic is params+cache *read* only, and the
-                static ``window`` caps the cache read to the smallest bucket
-                covering the longest active sequence."""
-                from langstream_tpu.models.llama import llama_decode_chunk
+            def _decode_chunk(params, cache_k, cache_v, tokens, lengths,
+                              active, tables, key, temps, topks, topps,
+                              pres=None, freq=None, counts=None,
+                              ad_layers=None, ad_ids=None):
                 from langstream_tpu.models.llama_paged import (
-                    pack_tokens_logprobs,
+                    llama_decode_chunk_paged,
                 )
 
+                # kwargs default to None so the adapter-less engine traces
+                # the exact seed jaxpr — adapters ride in only when the
+                # store is enabled and the dispatch passes them explicitly
+                adapters = (
+                    None if ad_ids is None
+                    else {"ids": ad_ids, "layers": ad_layers}
+                )
                 sample_fn = _sample_fn_for(temps, topks, topps, pres, freq)
-                if self.dense_read_kernel != "xla":
-                    from langstream_tpu.models.llama_paged import (
-                        llama_decode_chunk_dense_pallas,
-                    )
-
-                    out = llama_decode_chunk_dense_pallas(
-                        mc_static, params, tokens, lengths, active,
-                        cache_k, cache_v, sample_fn,
-                        key, K,
-                        window=window, kernel=self.dense_read_kernel,
-                        ffn=ffn_static,
-                        sample_extras=_extras(pres, freq, counts),
-                    )
-                    # dense twins pack inside THIS jit: same one-fetch
-                    # tail, same single compiled program per chunk
-                    return _fetchable(
-                        pack_tokens_logprobs(out[0], out[1])
-                    ) + out[2:]
-
-                out = llama_decode_chunk(
+                # return_packed folds the tokens+bitcast-logprobs pack
+                # into the decode program itself: the chunk's whole
+                # host traffic is out[0]'s D2H copy, with no post-hoc
+                # pack dispatch (pre-fusion _pack_chunk) behind it
+                out = llama_decode_chunk_paged(
                     mc_static, params, tokens, lengths, active,
-                    cache_k, cache_v, sample_fn,
-                    key, K, window=window, ffn=ffn_static,
+                    cache_k, cache_v, tables, sample_fn, key, K,
+                    num_read_blocks=window,
+                    kernel=self.paged_read_kernel,
+                    mesh=mesh_static, ffn=ffn_static,
                     sample_extras=_extras(pres, freq, counts),
+                    adapters=adapters,
+                    return_packed=True,
                 )
-                return _fetchable(
-                    pack_tokens_logprobs(out[0], out[1])
-                ) + out[2:]
+                return _fetchable(out[0]) + out[1:]
 
             return _decode_chunk
 
@@ -2204,42 +2057,22 @@ class TpuServingEngine:
 
                 return _prefill
 
-            if paged:
-                @partial(jax.jit, donate_argnums=(1, 2))
-                def _prefill(params, cache_k, cache_v, tokens, lengths, tables,
-                             key, temps, topks, topps,
-                             ad_layers=None, ad_ids=None):
-                    from langstream_tpu.models.llama_paged import (
-                        llama_prefill_paged,
-                    )
-
-                    adapters = (
-                        None if ad_ids is None
-                        else {"ids": ad_ids, "layers": ad_layers}
-                    )
-                    logits, ck, cv = llama_prefill_paged(
-                        mc_static, params, tokens, lengths, cache_k, cache_v,
-                        tables, use_flash=prefill_flash, mesh=mesh_static,
-                        ffn=ffn_static, adapters=adapters,
-                    )
-                    with jax.named_scope("sample"):
-                        next_tokens, logprobs = _fetchable(
-                            *sample_tokens(
-                                logits, key, temps, topks,
-                                use_top_p=use_top_p, top_ps=topps,
-                                use_top_k=use_top_k, all_greedy=all_greedy,
-                            )
-                        )
-                    return next_tokens, logprobs, ck, cv
-
-                return _prefill
-
             @partial(jax.jit, donate_argnums=(1, 2))
-            def _prefill(params, cache_k, cache_v, tokens, lengths, slot_ids,
-                         key, temps, topks, topps):
-                logits, ck, cv = llama_prefill(
-                    mc_static, params, tokens, lengths, cache_k, cache_v, slot_ids,
-                    use_flash=prefill_flash, mesh=mesh_static, ffn=ffn_static,
+            def _prefill(params, cache_k, cache_v, tokens, lengths, tables,
+                         key, temps, topks, topps,
+                         ad_layers=None, ad_ids=None):
+                from langstream_tpu.models.llama_paged import (
+                    llama_prefill_paged,
+                )
+
+                adapters = (
+                    None if ad_ids is None
+                    else {"ids": ad_ids, "layers": ad_layers}
+                )
+                logits, ck, cv = llama_prefill_paged(
+                    mc_static, params, tokens, lengths, cache_k, cache_v,
+                    tables, use_flash=prefill_flash, mesh=mesh_static,
+                    ffn=ffn_static, adapters=adapters,
                 )
                 with jax.named_scope("sample"):
                     next_tokens, logprobs = _fetchable(
@@ -2256,7 +2089,7 @@ class TpuServingEngine:
         self._make_prefill = _make_prefill
 
         def _make_prefill_continue(sampler_mode: tuple, nrb: int):
-            """Suffix prefill against cached prefix blocks (paged only):
+            """Suffix prefill against cached prefix blocks:
             the automatic-prefix-caching fast path. ``nrb`` is the static
             block-window bucket covering the longest reused prefix."""
             use_top_p, use_top_k, all_greedy = sampler_mode
@@ -2333,7 +2166,7 @@ class TpuServingEngine:
         # in only when an active request needs them; decode additionally
         # specialises per attention window bucket. All variants compile
         # lazily on first use.
-        self._decode_chunk_fns: dict[tuple[tuple, int | None, int], Any] = {}
+        self._decode_chunk_fns: dict[tuple[tuple, int, int], Any] = {}
         self._prefill_fns: dict[tuple, Any] = {}
         self._prefill_continue_fns: dict[tuple[tuple, int], Any] = {}
         self._spec_step_fns: dict[tuple[int, tuple], Any] = {}
@@ -2374,10 +2207,6 @@ class TpuServingEngine:
             "kv-quantize": (
                 cfg.kv_quantize not in (None, "none"),
                 "the hybrid programs read a bf16 pool only"),
-            "kv-layout": (
-                cfg.kv_layout != "paged",
-                "the attention layers read the paged pool; set "
-                "kv-layout: paged"),
             "mesh": (
                 bool(cfg.mesh),
                 "this family serves one chip's share of its deployment; "
@@ -2419,12 +2248,11 @@ class TpuServingEngine:
         raise ValueError(
             f"model {cfg.model!r} does not fit the device: weights "
             f"{weight_bytes / 1e9:.2f} GB + KV cache {cache_bytes / 1e9:.2f} "
-            f"GB ({cfg.kv_layout}, {cfg.kv_quantize or 'bf16'}, "
+            f"GB ({cfg.kv_quantize or 'bf16'} pool, "
             f"{cfg.slots} slots x {cfg.max_seq_len} rows) over {devices} "
             f"device(s) exceed the {limit / 1e9:.2f} GB the allocator "
             f"reports (bytes_limit). Lower slots or max-seq-len, or use "
-            f"kv-layout: paged with kv-quantize: int8 and a smaller "
-            f"kv-pool-fraction"
+            f"kv-quantize: int8 and a smaller kv-pool-fraction"
         )
 
     @staticmethod
@@ -2436,7 +2264,7 @@ class TpuServingEngine:
                 f"pallas, xla or auto"
             )
 
-    def _decode_fn(self, sampler_mode: tuple, window: int | None,
+    def _decode_fn(self, sampler_mode: tuple, window: int,
                    k_steps: int = 0, use_pen: bool = False):
         k_steps = k_steps or self.config.decode_chunk
         key = (sampler_mode, window, k_steps, use_pen)
@@ -2511,22 +2339,15 @@ class TpuServingEngine:
             tag += "-tp"
         return tag
 
-    def _window_rows(self, window: int | None) -> int:
-        """Cache rows a decode/verify variant actually sweeps per slot:
-        paged variants specialize on block-table columns, dense on row
-        windows (None = the full cache)."""
-        if self.block_mgr is not None:
-            blocks = window or self.paged_layout.max_blocks_per_slot
-            return blocks * self.paged_layout.block_size
-        return window or self.model_config.max_seq_len
-
     def _program_decode(
-        self, window: int | None, k_steps: int, sampler_mode: tuple,
+        self, window: int, k_steps: int, sampler_mode: tuple,
         pen: bool,
     ) -> str:
         """Program id for a decode-chunk variant; registers its cost
         model on first sight (arithmetic only — loop-thread safe)."""
-        rows = self._window_rows(window)
+        # variants specialize on block-table columns: the rows a slot's
+        # read sweeps
+        rows = window * self.paged_layout.block_size
         program = (
             f"decode:w{rows}:k{k_steps}:{self._sampler_code(sampler_mode)}"
             + (":pen" if pen else "")
@@ -2615,14 +2436,13 @@ class TpuServingEngine:
             return None
         if not any(s.free for s in self.slots):
             return "no-free-slot"
-        if self.block_mgr is not None:
-            head = self.scheduler.peek()  # engine-loop only
-            if head is None:
-                return None
-            if not self.block_mgr.can_admit(
-                len(head.prompt_tokens) + head.max_tokens + 1
-            ):
-                return "no-kv-blocks"
+        head = self.scheduler.peek()  # engine-loop only
+        if head is None:
+            return None
+        if not self.block_mgr.can_admit(
+            len(head.prompt_tokens) + head.max_tokens + 1
+        ):
+            return "no-kv-blocks"
         if self._has_prefilling():
             return "prefill-in-flight"
         return None
@@ -2666,9 +2486,7 @@ class TpuServingEngine:
         if program is not None:
             self.attribution.observe(program, device_s + overlapped_s)
         stall = self._admission_stall()
-        kv_used = (
-            self.block_mgr.used_ratio() if self.block_mgr is not None else None
-        )
+        kv_used = self.block_mgr.used_ratio()
         depths = self.scheduler.depths()
         sample = self.flight.sample(
             phase,
@@ -2723,8 +2541,7 @@ class TpuServingEngine:
         if hist is not None:
             hist(sample["wall_ms"] / 1000.0)
         self._m_host_overhead(sample["host_ms"] / 1000.0)
-        if kv_used is not None:
-            self._m_kv_used(kv_used)
+        self._m_kv_used(kv_used)
         if stall is not None:
             self._m_stall[stall](sample["wall_ms"] / 1000.0)
 
@@ -2736,7 +2553,7 @@ class TpuServingEngine:
         recorded when the result is processed, no longer does: the program
         variant, the dispatch's ordinal (the ``seq`` of its host spans),
         the decode steps it fuses (0 for a prefill), the slots running and,
-        for a paged decode chunk, the pool blocks its read has to fetch
+        for a decode chunk, the pool blocks its read has to fetch
         against the table columns of its window (:meth:`_read_blocks`).
         Loop thread only; rides to :meth:`_flight_record` as keywords."""
         self._dispatch_seq += 1
@@ -2761,14 +2578,11 @@ class TpuServingEngine:
 
     def _flight_stall(self, reason: str) -> None:
         """Record an idle/blocked engine-loop gap as stall time."""
-        kv_used = (
-            self.block_mgr.used_ratio() if self.block_mgr is not None else None
-        )
         sample = self.flight.stall(
             reason,
             occupancy=sum(1 for s in self.slots if not s.free),
             queue_depth=self.scheduler.qsize(),
-            kv_used=kv_used,
+            kv_used=self.block_mgr.used_ratio(),
             queue_by_class=self.scheduler.depths(),
         )
         # heartbeat on idle gaps too: an idle engine beats ~once a second,
@@ -2955,11 +2769,7 @@ class TpuServingEngine:
             # currently withheld from the KV admission budget — the pod
             # probes surface it so an operator reading /healthz sees a
             # degraded-capacity replica without another round trip
-            "budget_withheld": (
-                self.block_mgr.budget_reduction
-                if self.block_mgr is not None
-                else 0
-            ),
+            "budget_withheld": self.block_mgr.budget_reduction,
         }
         if self.config.streaming:
             # which classes are currently fast-burning their tbt-p99-s
@@ -3135,15 +2945,10 @@ class TpuServingEngine:
         init (the shapes are fixed; the live handles are donated and
         rebound on the dispatch thread, so readers never touch them);
         the LRU and prefix-cache terms are snapshot reads."""
-        prefix_blocks = (
-            self.block_mgr.prefix_block_count()
-            if self.block_mgr is not None
-            else 0
-        )
         return memory_ledger(
             weights_bytes=self._weights_bytes,
             kv_pool_bytes=self._kv_cache_bytes,
-            prefix_blocks=prefix_blocks,
+            prefix_blocks=self.block_mgr.prefix_block_count(),
             bytes_per_block=self._kv_block_bytes,
             sampler_bytes=self._sampler_dev_cache.device_bytes(),
             tables_bytes=self._tables_dev_cache.device_bytes(),
@@ -3157,8 +2962,6 @@ class TpuServingEngine:
             # bytes, so the owner sum is identical across shrink/restore
             kv_withheld_bytes=(
                 self.block_mgr.budget_reduction * self._kv_block_bytes
-                if self.block_mgr is not None
-                else 0
             ),
             recurrent_state_bytes=self._state_bytes,
         )
@@ -3172,9 +2975,10 @@ class TpuServingEngine:
         all_greedy = bool((temps <= 0).all()) and not use_top_p and not use_top_k
         return (use_top_p, use_top_k, all_greedy)
 
-    def _window_for(self, max_len: int) -> int | None:
-        """Smallest 128-multiple cache window covering ``max_len`` rows (the
-        chunk's new tokens live in the chunk buffer, not the window).
+    def _read_blocks_for(self, max_len: int) -> int:
+        """Block-table columns a decode or verify variant sweeps: the
+        smallest bucketed window of rows covering ``max_len`` (the chunk's
+        new tokens live in the chunk buffer, not the window), in blocks.
 
         Decode is cache-read bound, so window granularity is read traffic:
         power-of-two buckets read up to 2× the needed rows near bucket
@@ -3182,20 +2986,14 @@ class TpuServingEngine:
         1024 rows (excess <128 rows/slot where most serving lengths live),
         powers of two beyond (a long-context engine would otherwise compile
         a fresh ~30s decode variant every 128 generated tokens)."""
-        S = self.model_config.max_seq_len
         if max_len <= 1024:
-            w = max(128, -(-max_len // 128) * 128)
+            window = max(128, -(-max_len // 128) * 128)
         else:
-            w = 2048
-            while w < max_len:
-                w *= 2
-        return None if w >= S else w
-
-    def _read_blocks_for(self, max_len: int) -> int:
-        """Paged analogue of :meth:`_window_for`: block-table columns to
-        sweep, bucketed so few decode variants compile."""
+            window = 2048
+            while window < max_len:
+                window *= 2
+        window = min(window, self.model_config.max_seq_len)
         bs = self.paged_layout.block_size
-        window = self._window_for(max_len) or self.model_config.max_seq_len
         return max(1, min(-(-window // bs), self.paged_layout.max_blocks_per_slot))
 
     # ------------------------------------------------------------------
@@ -3256,9 +3054,7 @@ class TpuServingEngine:
             int(options.get("max-tokens", self.config.default_max_tokens)),
             self.model_config.max_seq_len - len(tokens) - 1,
         )
-        if self.block_mgr is not None and not self.block_mgr.fits_ever(
-            len(tokens) + max_tokens + 1
-        ):
+        if not self.block_mgr.fits_ever(len(tokens) + max_tokens + 1):
             raise ValueError(
                 f"request needs {len(tokens) + max_tokens + 1} tokens of KV, "
                 f"more than the paged pool can ever hold "
@@ -3516,8 +3312,7 @@ class TpuServingEngine:
             # hit/load/eviction counters, resident rows, exact byte
             # ledger (docs/ADAPTERS.md)
             out["adapters"] = self.adapter_store_section()
-        if self.block_mgr is not None:
-            out["kv"] = {"layout": "paged", **self.block_mgr.stats()}
+        out["kv"] = {"layout": "paged", **self.block_mgr.stats()}
         if self.config.speculative_drafts > 0:
             out["speculative"] = self.speculative_section()
         if self.incidents is not None:
@@ -3922,8 +3717,7 @@ class TpuServingEngine:
                 self._adapter_release(request)
                 if self._ad_rows is not None:
                     self._ad_rows[slot_id] = 0
-                if self.block_mgr is not None:
-                    self.block_mgr.release(slot_id)
+                self.block_mgr.release(slot_id)
                 self.scheduler.on_finished(request)
                 self._journey(request, "cancelled")
                 continue
@@ -4162,10 +3956,6 @@ class TpuServingEngine:
             # is byte-identical to the disaggregated path
             raise kvtransfer.LayoutMismatch(
                 "prefill-role engine does not accept KV imports"
-            )
-        if self.block_mgr is None:
-            raise kvtransfer.LayoutMismatch(
-                "kv-layout=dense engine cannot accept a paged KV handoff"
             )
         header, arrays = kvtransfer.deserialize_handoff(payload, header)
         kvtransfer.check_fingerprint(
@@ -4662,7 +4452,6 @@ class TpuServingEngine:
                     continue
                 if (
                     self.config.speculative_drafts > 0
-                    and self.block_mgr is not None
                     # measured-uplift auto-disable parks the engine on the
                     # plain pipelined loop until the retry window elapses
                     and not self._spec_auto_disabled
@@ -4752,8 +4541,7 @@ class TpuServingEngine:
             slot.request = None
             slot.prefilling = False
             slot.prefill_done = 0
-            if self.block_mgr is not None:
-                self.block_mgr.release(slot_id)
+            self.block_mgr.release(slot_id)
         self._lengths[:] = 0
         if self._ad_rows is not None:
             self._ad_rows[:] = 0
@@ -4807,7 +4595,7 @@ class TpuServingEngine:
         it so the waiter's blocks free immediately. Returns True when a
         slot was preempted (the caller re-runs admission). Runs at the
         loop's safe point — no dispatch is in flight."""
-        if not self._qos_enabled or self.block_mgr is None:
+        if not self._qos_enabled:
             return False
         if self._admission_stall() != "no-kv-blocks":
             return False
@@ -4848,8 +4636,7 @@ class TpuServingEngine:
         request.adapter_hydrate_attempted = False
         if self._ad_rows is not None:
             self._ad_rows[slot_id] = 0
-        if self.block_mgr is not None:
-            self.block_mgr.release(slot_id)
+        self.block_mgr.release(slot_id)
         request.preemptions += 1
         request.preempt_time = now
         self.scheduler.note_preempted(request)
@@ -4925,8 +4712,7 @@ class TpuServingEngine:
         self._adapter_release(request)
         if self._ad_rows is not None:
             self._ad_rows[slot_id] = 0
-        if self.block_mgr is not None:
-            self.block_mgr.release(slot_id)
+        self.block_mgr.release(slot_id)
         self.flight.event(
             "shed", reason="device-oom", tenant=request.tenant,
             priority=request.priority, retry_after_s=2.0,
@@ -5036,10 +4822,8 @@ class TpuServingEngine:
         self.pool_shrinks += 1
         self.shrink_preempted += preempted
         self._shrink_recover_at = now + self.config.shrink_recovery_s
-        if self._m_shrinks is not None:
-            self._m_shrinks(1)
-        if self._m_budget is not None:
-            self._m_budget(bm.usable_blocks)
+        self._m_shrinks(1)
+        self._m_budget(bm.usable_blocks)
         # the evidence event PRECEDES any admission against the reduced
         # budget (same loop pass): site + error text, what was withheld,
         # what preemption freed, and the budget admissions now face
@@ -5086,10 +4870,8 @@ class TpuServingEngine:
         restored = bm.restore_budget(quantum)
         if restored:
             self.pool_restores += 1
-            if self._m_restores is not None:
-                self._m_restores(1)
-            if self._m_budget is not None:
-                self._m_budget(bm.usable_blocks)
+            self._m_restores(1)
+            self._m_budget(bm.usable_blocks)
             self.flight.event(
                 "pool-restore",
                 restored_blocks=restored,
@@ -5132,11 +4914,8 @@ class TpuServingEngine:
                     int(entry["max-tokens"]),
                     self.model_config.max_seq_len - len(tokens) - 1,
                 )
-                if max_tokens < 1 or (
-                    self.block_mgr is not None
-                    and not self.block_mgr.fits_ever(
-                        len(tokens) + max_tokens + 1
-                    )
+                if max_tokens < 1 or not self.block_mgr.fits_ever(
+                    len(tokens) + max_tokens + 1
                 ):
                     # generate() refuses never-fitting requests up front
                     # and admission relies on that invariant — a replayed
@@ -5531,11 +5310,7 @@ class TpuServingEngine:
         the tier gauges refresh here so any reader keeps the scrape
         surface current."""
         store = self.prefix_store
-        t0_blocks = (
-            self.block_mgr.prefix_block_count()
-            if self.block_mgr is not None
-            else 0
-        )
+        t0_blocks = self.block_mgr.prefix_block_count()
         t0_bytes = t0_blocks * self._kv_block_bytes
         section = {
             "t0": {
@@ -6293,12 +6068,10 @@ class TpuServingEngine:
         except AttributeError:  # backends without async D2H: fetch blocks
             pass
 
-    def _tables_device(self, tables: np.ndarray | None):
+    def _tables_device(self, tables: np.ndarray):
         """Device copy of the block tables, re-uploaded only on a content
         miss (most chunks allocate no new blocks). LRU-bounded: see
         :class:`_DeviceLru`."""
-        if tables is None:
-            return None
         return self._tables_dev_cache.get_or_put(
             tables.tobytes(), lambda: jnp.asarray(tables)
         )
@@ -6402,7 +6175,6 @@ class TpuServingEngine:
             # host-tracked longest active sequence: each dispatched chunk
             # grows it by K; the attention window bucket follows
             base_max = int(self._lengths[active].max())
-            paged = self.block_mgr is not None
 
         def _build_counts() -> np.ndarray:
             counts = np.zeros(
@@ -6416,8 +6188,8 @@ class TpuServingEngine:
                         counts[slot_id, t] += 1
             return counts
 
-        def _grow_blocks(pending_chunks: int) -> np.ndarray | None:
-            """Paged: allocate blocks covering this dispatch's chunk plus
+        def _grow_blocks(pending_chunks: int) -> np.ndarray:
+            """Allocate blocks covering this dispatch's chunk plus
             the ``pending_chunks`` dispatched-but-unprocessed chunks whose
             tokens host ``_lengths`` doesn't reflect yet (0 in the
             sequential path — lengths are current at each re-dispatch; 1
@@ -6428,8 +6200,6 @@ class TpuServingEngine:
             dispatch converts it device-side — keeping it numpy here lets
             the lockstep broadcast ship it without a device→host
             round-trip)."""
-            if not paged:
-                return None
             self._fault("pool-grow")
             S = self.model_config.max_seq_len
             grown_blocks = grown_slots = 0
@@ -6478,9 +6248,8 @@ class TpuServingEngine:
                     "k": K,
                     "key": np.asarray(key),
                     "active": active_mask,
+                    "tables": tables,  # host snapshot from _grow_blocks
                 }
-                if tables is not None:
-                    desc["tables"] = tables  # host snapshot from _grow_blocks
                 if pen:
                     # penalty bursts are sequential, so every chunk ships
                     # fresh host state (counts are (slots, vocab) — heavy,
@@ -6510,9 +6279,7 @@ class TpuServingEngine:
                     () if self.state is None else (self.state,)
                 )
                 args = caches + (
-                    (tokens, lengths, amask, tables_dev, key, temps, topks, topps)
-                    if paged
-                    else (tokens, lengths, amask, key, temps, topks, topps)
+                    tokens, lengths, amask, tables_dev, key, temps, topks, topps,
                 )
                 if pen:
                     args = args + (
@@ -6544,12 +6311,6 @@ class TpuServingEngine:
                 self._start_fetch(packed)
                 return packed, t, l
 
-        def _bucket_for(max_len: int):
-            return (
-                self._read_blocks_for(max_len) if paged
-                else self._window_for(max_len)
-            )
-
         # tickets (program id, dispatch ordinal, steps, slots running) of
         # dispatched-but-unrecorded chunks, FIFO (≤ 2 in flight under the
         # depth-2 pipeline): each fetch pops the oldest so measured device
@@ -6569,8 +6330,7 @@ class TpuServingEngine:
             ticket = self._ticket(
                 self._program_decode(window, K, sampler_mode, pen),
                 K, len(active),
-                *(self._read_blocks(active, pending * K, window)
-                  if paged else ()),
+                *self._read_blocks(active, pending * K, window),
             )
             prog_q.append(ticket)
             counts_np = _build_counts() if pen else None
@@ -6593,7 +6353,7 @@ class TpuServingEngine:
         ):
             first_out = _submit(
                 jnp.asarray(self._current), jnp.asarray(self._lengths),
-                key1, _bucket_for(base_max), 0, first=True,
+                key1, self._read_blocks_for(base_max), 0, first=True,
             )
         out = await first_out
         chunk_index = 0
@@ -6629,7 +6389,7 @@ class TpuServingEngine:
                 ):
                     next_out = _submit(
                         out[1], out[2], self._split_key(),
-                        _bucket_for(base_max), 0,
+                        self._read_blocks_for(base_max), 0,
                     )
                 out = await next_out
 
@@ -6660,7 +6420,7 @@ class TpuServingEngine:
         # slots' block releases are deferred to burst exit — an in-flight
         # chunk commits via the tables captured at its dispatch, and no
         # mid-burst allocation may reuse those blocks under it.
-        self._defer_release = self.block_mgr is not None
+        self._defer_release = True
         finished = False
         try:
             while True:
@@ -6708,7 +6468,7 @@ class TpuServingEngine:
                     # unprocessed when the speculative chunk is dispatched
                     next_out_task = _submit(
                         out[1], out[2], key_next,
-                        _bucket_for(base_max), 1,
+                        self._read_blocks_for(base_max), 1,
                     )
                 chunk_t, chunk_lp, fetch_s = await self._await_chunk(
                     loop, out[0], K, ticket
@@ -6802,8 +6562,6 @@ class TpuServingEngine:
         reusing its blocks mid-burst would land stale K/V on a live
         slot — between bursts the adopting prefill's overwrite makes the
         immediate release safe)."""
-        if self.block_mgr is None:
-            return
         # the slot's device-resident context row is dead with the request:
         # the next occupant re-syncs from host truth
         self._ctx_synced[slot_id] = 0
@@ -6819,7 +6577,7 @@ class TpuServingEngine:
         the request's first generated token — the slot then joins decode."""
         # a cancelled caller's prefill stops here: release the slot AND its
         # worst-case block reservation instead of burning the remaining
-        # chunks for a dead request (under paged backpressure that
+        # chunks for a dead request (under pool backpressure that
         # reservation is exactly what blocks live admissions)
         for i, s in enumerate(self.slots):
             if s.prefilling and s.request.future.cancelled():
@@ -6830,8 +6588,7 @@ class TpuServingEngine:
                 s.prefill_done = 0
                 if self._ad_rows is not None:
                     self._ad_rows[i] = 0
-                if self.block_mgr is not None:
-                    self.block_mgr.release(i)
+                self.block_mgr.release(i)
         pre = [i for i, s in enumerate(self.slots) if s.prefilling]
         if not pre:
             return
@@ -6985,13 +6742,11 @@ class TpuServingEngine:
         prompt-length bucket, count padded to a power of two by repeating
         the last row — a duplicate write of identical K/V is a no-op).
 
-        With the paged prefix cache on, each request first matches its
+        With the prefix cache on, each request first matches its
         prompt against cached block chains; matched requests adopt the
         shared blocks and prefill only the SUFFIX (grouped by suffix-length
         bucket, dispatched through the continuation path)."""
-        use_prefix = (
-            self.block_mgr is not None and self.config.prefix_cache
-        )
+        use_prefix = self.config.prefix_cache
         while not self.scheduler.empty():
             free = [i for i, s in enumerate(self.slots) if s.free]
             if not free:
@@ -7091,10 +6846,10 @@ class TpuServingEngine:
                                 request, "hydrate-begin", blocks=len(missing)
                             )
                             continue
-                    if self.block_mgr is not None and not self.block_mgr.can_admit(
+                    if not self.block_mgr.can_admit(
                         len(request.prompt_tokens) + request.max_tokens + 1
                     ):
-                        # paged backpressure: the worst case doesn't fit the
+                        # pool backpressure: the worst case doesn't fit the
                         # pool right now; finished slots will free reservations.
                         # (Requests that could NEVER fit are rejected up front in
                         # generate(), so this always unblocks eventually. The
@@ -7137,8 +6892,7 @@ class TpuServingEngine:
                         blocks, reuse = [], 0
                     to_prefill = len(ctx) - reuse
                     if (
-                        self.block_mgr is not None
-                        and self.config.prefill_chunk > 0
+                        self.config.prefill_chunk > 0
                         and to_prefill > self.config.prefill_chunk
                     ):
                         # chunked prefill: claim the slot + reservation now, but
@@ -7209,14 +6963,13 @@ class TpuServingEngine:
                         break
                     slot_id = free[len(batch)]
                     self.scheduler.pop()
-                    if self.block_mgr is not None:
-                        # reserve at pop time so the NEXT peek's can_admit sees
-                        # this batch member's reservation
-                        self.block_mgr.admit(
-                            slot_id, len(request.prompt_tokens) + request.max_tokens + 1
-                        )
-                        if blocks:
-                            self.block_mgr.adopt_prefix(slot_id, blocks)
+                    # reserve at pop time so the NEXT peek's can_admit sees
+                    # this batch member's reservation
+                    self.block_mgr.admit(
+                        slot_id, len(request.prompt_tokens) + request.max_tokens + 1
+                    )
+                    if blocks:
+                        self.block_mgr.adopt_prefix(slot_id, blocks)
                     batch.append((slot_id, request, reuse))
             if not batch:
                 return
@@ -7236,12 +6989,11 @@ class TpuServingEngine:
                 # allocator failure here is then recoverable by the shrink
                 # pass's preempt-and-requeue sweep (a popped request in no
                 # slot would be invisible to every failure path)
-                if self.block_mgr is not None:
-                    self._fault("pool-grow")
-                    for slot_id, request, _reuse in batch:
-                        self.block_mgr.ensure_capacity(
-                            slot_id, len(request.context_tokens)
-                        )
+                self._fault("pool-grow")
+                for slot_id, request, _reuse in batch:
+                    self.block_mgr.ensure_capacity(
+                        slot_id, len(request.context_tokens)
+                    )
             with self.flight.span(
                 "ls.prefill.pack", rows=len(batch), bucket=bucket
             ):
@@ -7271,12 +7023,9 @@ class TpuServingEngine:
                     if self._ad_rows is not None else None
                 )
 
-                if self.block_mgr is not None:
-                    # per-batch-row block tables (duplicate padded rows write
-                    # identical values to identical blocks — harmless)
-                    sel_np = self.block_mgr.tables[slot_ids]
-                else:
-                    sel_np = slot_ids
+                # per-batch-row block tables (duplicate padded rows write
+                # identical values to identical blocks — harmless)
+                sel_np = self.block_mgr.tables[slot_ids]
                 sel = jnp.asarray(sel_np)
                 if self.is_hybrid:
                     # the recurrent state's rows are the slots' own
@@ -7360,8 +7109,8 @@ class TpuServingEngine:
                 # donated caches re-bound on the dispatch thread — see
                 # _advance_prefills._run (RACE801: single thread role)
                 self.cache_k, self.cache_v = out[2], out[3]
-                if self.state is not None:
-                    self.state = out[4]
+                # the hybrid family's recurrent state is donated with them
+                self.state = out[4] if len(out) > 4 else None
                 t_dev = time.monotonic()
                 # same single sync the loop-thread np.asarray used to pay,
                 # moved onto the dispatch thread so it can be timed; the
@@ -8183,7 +7932,7 @@ async def import_kv_handoff(
 ) -> dict[str, Any]:
     """Route one KV handoff payload to this pod's matching engine (the
     ``POST /kv/import`` handler): the header's fingerprint model picks
-    the engine, decode-role engines first (a combined paged engine also
+    the engine, decode-role engines first (a combined engine also
     accepts — the dev/test posture). ``trace_header`` is the pod HTTP
     request's ``langstream-trace`` value — the fallback trace parent
     when the payload header carries none. The result echoes the
@@ -8199,12 +7948,11 @@ async def import_kv_handoff(
         engine
         for engine in list(TpuServingEngine._instances.values())
         if engine.config.model == model
-        and engine.block_mgr is not None
         and engine.config.pool_role != "prefill"
     ]
     if not candidates:
         raise LayoutMismatch(
-            f"no decode-capable paged engine for model {model!r} in this pod"
+            f"no decode-capable engine for model {model!r} in this pod"
         )
     candidates.sort(
         key=lambda e: 0 if e.config.pool_role == "decode" else 1

@@ -398,10 +398,7 @@ class LockstepFollower:
                 args = [
                     engine.params, engine.cache_k, engine.cache_v,
                     tokens, lengths, burst["active"],
-                ]
-                if engine.block_mgr is not None:
-                    args.append(jnp.asarray(desc["tables"]))
-                args += [
+                    jnp.asarray(desc["tables"]),
                     jnp.asarray(desc["key"]), burst["temps"],
                     burst["topks"], burst["topps"],
                 ]

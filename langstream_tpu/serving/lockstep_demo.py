@@ -121,11 +121,10 @@ def run_process(
 def demo_config(total_devices: int):
     from langstream_tpu.serving.engine import ServingConfig
 
-    # LS_DEMO_KV=paged exercises the block-pool cache across the process
-    # boundary (block tables ride the lockstep descriptors);
-    # LS_DEMO_SPEC=N additionally runs greedy bursts speculatively (the
-    # "verify" descriptor replays host drafts on the followers)
-    kv_layout = os.environ.get("LS_DEMO_KV", "dense")
+    # the block-pool cache crosses the process boundary (block tables
+    # ride the lockstep descriptors); LS_DEMO_SPEC=N additionally runs
+    # greedy bursts speculatively (the "verify" descriptor replays host
+    # drafts on the followers)
     spec = int(os.environ.get("LS_DEMO_SPEC", "0"))
     return ServingConfig(
         model="tiny",
@@ -134,7 +133,6 @@ def demo_config(total_devices: int):
         decode_chunk=4,
         prefill_batch=2,
         seed=0,
-        kv_layout=kv_layout,
         kv_block_size=16,
         speculative_drafts=spec,
         # tiny model: 2 kv heads caps tp at 2; the rest of the devices go dp

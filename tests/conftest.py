@@ -58,3 +58,44 @@ def run_async():
         return asyncio.run(coro)
 
     return _run
+
+
+@pytest.fixture
+def dense_reference_greedy():
+    """Greedy tokens of the plain reference: a loop over ``llama_prefill`` and
+    ``llama_decode_step`` on the dense cache of ``models/llama.py``, with the
+    engine's own weights and FFN hook. The served (paged) programs are held
+    to it; build the engine with ``model_dtype="float32"`` so that argmax
+    does not depend on a program's shape. The prompt is right-padded to the
+    engine's prefill bucket: a routed FFN's expert capacity follows the
+    number of rows it is given, padding included."""
+
+    def _run(engine, prompt_tokens: list[int], n: int) -> list[int]:
+        import jax.numpy as jnp
+
+        from langstream_tpu.models.llama import (
+            init_kv_cache,
+            llama_decode_step,
+            llama_prefill,
+        )
+        from langstream_tpu.serving.engine import _bucket
+
+        c, params, ffn = engine.model_config, engine.params, engine._ffn
+        cache_k, cache_v = init_kv_cache(c, 1)
+        lengths = jnp.asarray([len(prompt_tokens)], jnp.int32)
+        pad = _bucket(len(prompt_tokens), hi=c.max_seq_len) - len(prompt_tokens)
+        logits, cache_k, cache_v = llama_prefill(
+            c, params, jnp.asarray([prompt_tokens + [0] * pad], jnp.int32),
+            lengths, cache_k, cache_v, jnp.asarray([0]), ffn=ffn,
+        )
+        out = []
+        for _ in range(n):
+            out.append(int(jnp.argmax(logits[0])))
+            logits, cache_k, cache_v = llama_decode_step(
+                c, params, jnp.asarray([out[-1]], jnp.int32), lengths,
+                cache_k, cache_v, ffn=ffn,
+            )
+            lengths = lengths + 1
+        return out
+
+    return _run

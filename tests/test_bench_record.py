@@ -27,11 +27,10 @@ def _bench_env(tmp_path, **overrides) -> dict:
         BENCH_DECODE_CHUNK="4",
         BENCH_WARMUP_REQUESTS="2",
         BENCH_REQUESTS="8",
-        # decode phase only: the gateway/paged/prefix phases have their own
+        # decode phase only: the gateway/prefix phases have their own
         # coverage (tools/gateway_bench.py main, tests/test_paged.py) and
         # would triple this test's runtime
         BENCH_GATEWAY="0",
-        BENCH_PAGED="0",
         BENCH_PREFIX="0",
         BENCH_KV_INT8="0",
         BENCH_SPEC="0",
@@ -78,10 +77,10 @@ def test_bench_record_last_line_parses(tmp_path):
         record["value"] / 2000.0, abs=5e-4
     )
     detail = record["detail"]
-    assert detail["dense"]["tok_s"] == record["value"]
-    assert "roofline" in detail["dense"]
+    assert detail["paged"]["tok_s"] == record["value"]
+    assert "roofline" in detail["paged"]
     # CPU run: the device probe must not have failed the record
-    assert detail["dense"].get("error") is None
+    assert detail["paged"].get("error") is None
     assert "device_probe" not in detail
 
 

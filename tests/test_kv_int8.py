@@ -180,13 +180,6 @@ def test_engine_rejects_unsupported_kv_quantize_combos():
 
     with pytest.raises(ValueError, match="kv_quantize"):
         TpuServingEngine(ServingConfig(model="tiny", kv_quantize="fp8"))
-    with pytest.raises(ValueError, match="dense_kernel=xla"):
-        TpuServingEngine(
-            ServingConfig(
-                model="tiny", max_seq_len=128, kv_quantize="int8",
-                dense_kernel="pallas-interpret",
-            )
-        )
     # kv-quantize=int8 + a forced Pallas paged kernel is a SUPPORTED combo
     # since the in-kernel dequant twin (ops/paged_attention.
     # _paged_kernel_q8) landed: construction honours the forced kernel

@@ -210,16 +210,9 @@ def test_pool_role_config_roundtrip_and_validation(monkeypatch):
     monkeypatch.setenv("LS_POOL_ROLE", "decode")
     assert ServingConfig.from_dict({"model": "tiny"}).pool_role == "decode"
     monkeypatch.delenv("LS_POOL_ROLE")
-    # unknown role / dense layout fail at construction, loudly
+    # an unknown role fails at construction, loudly
     with pytest.raises(ValueError, match="pool_role"):
         TpuServingEngine(_disagg_config(pool_role="both"))
-    with pytest.raises(ValueError, match="paged"):
-        TpuServingEngine(
-            ServingConfig(
-                model="tiny", slots=2, max_seq_len=64,
-                kv_layout="dense", pool_role="prefill",
-            )
-        )
 
 
 # --------------------------------------------------------------------------

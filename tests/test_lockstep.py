@@ -38,18 +38,12 @@ def _sub_env() -> dict[str, str]:
 
 
 @pytest.mark.slow
-@pytest.mark.parametrize(
-    "kv_layout,spec",
-    [("dense", 0), ("paged", 0), ("paged", 4)],
-)
-def test_two_process_lockstep_decode_matches_single_process(
-    tmp_path, kv_layout, spec
-):
+@pytest.mark.parametrize("spec", [0, 4])
+def test_two_process_lockstep_decode_matches_single_process(tmp_path, spec):
     coordinator_port = _free_port()
     lockstep_port = _free_port()
     out = tmp_path / "leader_tokens.json"
     env = _sub_env()
-    env["LS_DEMO_KV"] = kv_layout
     env["LS_DEMO_SPEC"] = str(spec)
 
     follower = subprocess.Popen(
@@ -85,12 +79,10 @@ def test_two_process_lockstep_decode_matches_single_process(
         run_single_process_reference,
     )
 
-    os.environ["LS_DEMO_KV"] = kv_layout
     os.environ["LS_DEMO_SPEC"] = str(spec)
     try:
         reference_tokens = run_single_process_reference(8)
     finally:
-        os.environ.pop("LS_DEMO_KV", None)
         os.environ.pop("LS_DEMO_SPEC", None)
     assert lockstep_tokens == reference_tokens
     assert len(lockstep_tokens) == 3
@@ -217,7 +209,6 @@ def test_follower_death_mid_burst_leader_fails_loud(tmp_path):
     coordinator_port = _free_port()
     lockstep_port = _free_port()
     env = _sub_env()
-    env["LS_DEMO_KV"] = "dense"
     env["LS_DEMO_MAX_TOKENS"] = "40"  # many bursts: death lands mid-stream
     fenv = dict(env)
     fenv["LS_DEMO_FOLLOWER_DIE_AFTER"] = "4"
@@ -259,7 +250,6 @@ def test_leader_death_follower_exits_promptly(tmp_path):
     coordinator_port = _free_port()
     lockstep_port = _free_port()
     env = _sub_env()
-    env["LS_DEMO_KV"] = "dense"
     env["LS_DEMO_LEADER_ABRUPT_EXIT"] = "1"
 
     follower = subprocess.Popen(

@@ -1,8 +1,8 @@
 """MoE (Mixtral-family) models on the serving engine.
 
 The MoE family plugs its routed-expert FFN into the shared llama layer math
-(``moe_serving_ffn``), so every serving mode — dense KV, paged KV, int8,
-ep/tp meshes — must hold for MoE exactly as the dense suites pin them for
+(``moe_serving_ffn``), so every serving mode — the dense reference, paged
+KV, int8, ep/tp meshes — must hold for MoE exactly as the dense suites pin them for
 Llama. Capability anchor: the reference reaches MoE models only through
 SaaS providers (``HuggingFaceProvider.java:47``); here they are in-tree.
 """
@@ -27,13 +27,21 @@ def _fresh_engines():
     EmbeddingEngine.reset_instances()
 
 
-def _generate(cfg_kwargs, prompt="the quick brown fox", max_tokens=16):
+def _generate(cfg_kwargs, prompt="the quick brown fox", max_tokens=16,
+              reference=None):
+    """One greedy generation; with ``reference`` (the ``dense_reference_greedy``
+    fixture) also the dense reference's tokens for the same prompt, from the
+    same engine's weights and FFN hook."""
     from langstream_tpu.serving.engine import ServingConfig, TpuServingEngine
 
     async def run():
         eng = TpuServingEngine(ServingConfig(**cfg_kwargs))
         try:
-            return await eng.generate(prompt, {"max-tokens": max_tokens})
+            tokens = eng.tokenizer.encode(prompt)
+            out = await eng.generate(tokens, {"max-tokens": max_tokens})
+            if reference is not None:
+                out["reference"] = reference(eng, tokens, max_tokens)
+            return out
         finally:
             await eng.close()
 
@@ -163,15 +171,20 @@ def test_quantized_moe_params_shapes():
 # ---------------------------------------------------------------------------
 
 
-def test_moe_engine_generates_dense():
-    out = _generate(BASE)
-    assert len(out["tokens"]) == 16
-    assert out["text"]
+def test_moe_engine_generates_dense(dense_reference_greedy):
+    """The dense reference loop generates with the routed FFN hook, and in
+    float32 the engine's tokens are its tokens over the whole answer."""
+    out = _generate(
+        {**BASE, "model_dtype": "float32"}, reference=dense_reference_greedy
+    )
+    assert len(out["reference"]) == 16
+    assert out["tokens"] == out["reference"][: len(out["tokens"])]
 
 
 def test_moe_engine_generates_paged():
-    out = _generate({**BASE, "kv_layout": "paged"})
+    out = _generate(BASE)
     assert len(out["tokens"]) == 16
+    assert out["text"]
 
 
 def test_moe_engine_int8_generates():
@@ -197,10 +210,11 @@ def test_moe_engine_mesh_matches_single_device():
     assert r0["tokens"][:_HORIZON] == r1["tokens"][:_HORIZON]
 
 
-def test_moe_engine_paged_matches_dense():
-    r0 = _generate(BASE)
-    r1 = _generate({**BASE, "kv_layout": "paged"})
-    assert r0["tokens"][:_HORIZON] == r1["tokens"][:_HORIZON]
+def test_moe_engine_paged_matches_dense(dense_reference_greedy):
+    """bf16, as served: the paged engine against the dense reference loop
+    over the comparison horizon."""
+    out = _generate(BASE, reference=dense_reference_greedy)
+    assert out["tokens"][:_HORIZON] == out["reference"][:_HORIZON]
 
 
 def test_moe_checkpoint_roundtrip(tmp_path):

@@ -834,32 +834,36 @@ def _fresh_engines():
     TpuServingEngine.reset_instances()
 
 
-def test_paged_engine_matches_dense_engine(run_async):
-    """Greedy generations from the paged engine must equal the dense
-    engine's token-for-token (same model, same seed)."""
+def test_paged_engine_matches_dense_engine(run_async, dense_reference_greedy):
+    """Greedy generations from the engine (four slots at once, paged pool)
+    must equal the dense reference's token-for-token: ``llama_prefill`` +
+    ``llama_decode_step`` on the dense cache, same weights, float32."""
+    import asyncio
+
     from langstream_tpu.serving.engine import ServingConfig, TpuServingEngine
 
     prompts = ["paged cache equivalence", "second prompt!", "a", "and a longer fourth prompt here"]
 
-    async def run(layout):
+    async def run():
         engine = TpuServingEngine.get_or_create(
             ServingConfig(
-                model="tiny", slots=4, max_seq_len=128, decode_chunk=4,
-                default_max_tokens=12, kv_layout=layout, kv_block_size=16,
+                model="tiny", model_dtype="float32", slots=4, max_seq_len=128,
+                decode_chunk=4, default_max_tokens=12, kv_block_size=16,
                 kv_pool_fraction=0.75, paged_kernel="xla",
             )
         )
+        tokens = [engine.tokenizer.encode(p) for p in prompts]
         results = await asyncio.gather(
-            *(engine.generate(p, {"max-tokens": 12}) for p in prompts)
+            *(engine.generate(t, {"max-tokens": 12}) for t in tokens)
         )
+        reference = [dense_reference_greedy(engine, t, 12) for t in tokens]
         await engine.close()
-        return [r["tokens"] for r in results]
+        return [r["tokens"] for r in results], reference
 
-    import asyncio
-
-    dense = run_async(run("dense"))
-    paged = run_async(run("paged"))
-    assert dense == paged
+    paged, reference = run_async(run())
+    for got, ref in zip(paged, reference):
+        assert got and got == ref[: len(got)]
+    assert any(len(got) == 12 for got in paged)
 
 
 def test_paged_engine_backpressure_completes_all(run_async):
@@ -948,74 +952,6 @@ def test_paged_kernel_sharded_matches_xla():
         kernel="pallas-interpret", mesh=mesh,
     )
     np.testing.assert_array_equal(np.asarray(ref[0]), np.asarray(got[0]))
-
-
-def test_dense_pallas_adapter_matches_dense_xla():
-    """Dense decode through the paged Pallas kernel (identity block tables,
-    interpret mode) ≡ the dense XLA einsum chunk — token-exact in fp32."""
-    import dataclasses
-
-    import jax.random as jrandom
-
-    from langstream_tpu.models.llama import (
-        LlamaConfig, init_kv_cache, init_llama_params, llama_decode_chunk,
-    )
-    from langstream_tpu.models.llama_paged import (
-        llama_decode_chunk_dense_pallas,
-    )
-
-    c = dataclasses.replace(LlamaConfig.tiny(max_seq_len=256), dtype=jnp.float32)
-    params = init_llama_params(c)
-    B, K = 3, 4
-    cache_k, cache_v = init_kv_cache(c, B)
-    # seed the caches with "prefilled" content
-    k1, k2 = jrandom.split(jrandom.PRNGKey(5))
-    cache_k = cache_k.at[:, :, :40].set(
-        jrandom.normal(k1, (c.layers, B, 40, c.kv_heads, c.head_dim), jnp.float32)
-    )
-    cache_v = cache_v.at[:, :, :40].set(
-        jrandom.normal(k2, (c.layers, B, 40, c.kv_heads, c.head_dim), jnp.float32)
-    )
-    lengths = jnp.asarray([40, 17, 3], jnp.int32)
-    tokens0 = jnp.asarray([7, 8, 9], jnp.int32)
-    active = jnp.ones((B,), bool)
-
-    def greedy(logits, key):
-        t = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        return t, jnp.zeros_like(t, jnp.float32)
-
-    ref = llama_decode_chunk(
-        c, params, tokens0, lengths, active, cache_k, cache_v,
-        greedy, jrandom.PRNGKey(0), K, window=128,
-    )
-    got = llama_decode_chunk_dense_pallas(
-        c, params, tokens0, lengths, active, cache_k, cache_v,
-        greedy, jrandom.PRNGKey(0), K, window=128,
-        kernel="pallas-interpret",
-    )
-    np.testing.assert_array_equal(np.asarray(ref[0]), np.asarray(got[0]))
-    np.testing.assert_array_equal(np.asarray(ref[3]), np.asarray(got[3]))
-    # caches agree where data lives (committed chunk rows + prefill rows)
-    np.testing.assert_allclose(
-        np.asarray(ref[4][:, :, :44]), np.asarray(got[4][:, :, :44]),
-        rtol=1e-5, atol=1e-5,
-    )
-
-
-def test_engine_dense_pallas_kernel_serves(run_async):
-    from langstream_tpu.serving.engine import ServingConfig, TpuServingEngine
-
-    async def main():
-        config = ServingConfig(
-            model="tiny", slots=2, max_seq_len=128, decode_chunk=4,
-            default_max_tokens=6, dense_kernel="pallas-interpret",
-        )
-        engine = TpuServingEngine.get_or_create(config)
-        r = await engine.generate("dense kernel", {"max-tokens": 6})
-        await engine.close()
-        assert 0 < len(r["tokens"]) <= 6
-
-    run_async(main())
 
 
 # ---------------------------------------------------------------------------

@@ -139,20 +139,13 @@ def test_validate_application_prefix_store():
         )
 
 
-def test_engine_config_requires_paged_prefix_cache():
+def test_engine_config_requires_prefix_cache():
     from langstream_tpu.serving.engine import ServingConfig, TpuServingEngine
 
-    with pytest.raises(ValueError, match="kv-layout=paged"):
-        TpuServingEngine(
-            ServingConfig(
-                model="tiny", slots=1, max_seq_len=64,
-                prefix_store=_spec(t2=None),
-            )
-        )
     with pytest.raises(ValueError, match="prefix-cache"):
         TpuServingEngine(
             ServingConfig(
-                model="tiny", slots=1, max_seq_len=64, kv_layout="paged",
+                model="tiny", slots=1, max_seq_len=64,
                 kv_block_size=16, prefix_cache=False,
                 prefix_store=_spec(t2=None),
             )

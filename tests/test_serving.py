@@ -689,7 +689,8 @@ def test_stop_sequences_truncate_and_free_slot(run_async):
 
 def test_long_context_pow2_window_lane(run_async):
     """Long-context serving: beyond 1024 rows the attention window buckets
-    switch from 128-multiples to powers of two (engine._window_for) — a
+    switch from 128-multiples to powers of two (engine._read_blocks_for,
+    which counts them in block-table columns of 64 rows) — a
     long prompt must prefill, decode through the pow2 lane, and produce
     the same stream as a fresh engine (determinism across bucket growth)."""
     from langstream_tpu.serving.engine import ServingConfig, TpuServingEngine
@@ -700,9 +701,10 @@ def test_long_context_pow2_window_lane(run_async):
         )
         engine = TpuServingEngine(cfg)
         # window bucketing: 128-multiples below 1024, pow2 above
-        assert engine._window_for(900) == 1024
-        assert engine._window_for(1100) == 2048
-        assert engine._window_for(3000) is None  # full length
+        assert engine._read_blocks_for(130) == 4    # 256 rows
+        assert engine._read_blocks_for(900) == 16   # 1024 rows
+        assert engine._read_blocks_for(1100) == 32  # 2048 rows
+        assert engine._read_blocks_for(3000) == 64  # the whole table
         # prompt lands just under the 1024 boundary; 48 decoded tokens
         # carry the sequence across it, so decode re-dispatches under the
         # grown 2048 pow2 bucket MID-GENERATION — the transition the pow2
@@ -713,7 +715,7 @@ def test_long_context_pow2_window_lane(run_async):
         assert r["num_prompt_tokens"] + len(r["tokens"]) > 1024
         assert len(r["tokens"]) == 48
         windows = {key[1] for key in engine._decode_chunk_fns}
-        assert {1024, 2048} <= windows, sorted(windows)
+        assert {16, 32} <= windows, sorted(windows)
         await engine.close()
 
         engine2 = TpuServingEngine(cfg)
@@ -795,8 +797,63 @@ def test_presence_frequency_penalties():
     assert int(tokens[0]) == 5
 
 
-@pytest.mark.parametrize("kv_layout", ["dense", "paged"])
-def test_engine_frequency_penalty_prevents_repeats(run_async, kv_layout):
+FEATURES_OF_THE_POOL = {
+    "pool-role": {"pool-role": "prefill"},
+    "prefix-store": {"prefix-store": {"t1-bytes": 1 << 20}},
+    "adapter-store": {"adapter-store": {"rank": 2, "t0-entries": 2}},
+    "prefill-chunk": {"prefill-chunk": 16},
+    "speculative-drafts": {"speculative-drafts": 4},
+}
+
+
+@pytest.mark.parametrize("feature", sorted(FEATURES_OF_THE_POOL))
+def test_a_feature_of_the_pool_needs_no_layout_named(run_async, feature):
+    """Each of these was refused until a configuration also said
+    ``kv-layout: paged``; the engine has one layout, so the feature's own
+    key is enough to construct and to serve a request."""
+    from langstream_tpu.serving.engine import ServingConfig, TpuServingEngine
+
+    async def main():
+        engine = TpuServingEngine(
+            ServingConfig.from_dict(
+                {"model": "tiny", "slots": 2, "max-seq-len": 128,
+                 "kv-block-size": 16, **FEATURES_OF_THE_POOL[feature]}
+            )
+        )
+        try:
+            r = await engine.generate(
+                "one request through the pool, forty characters long",
+                {"max-tokens": 6, "temperature": 0},
+            )
+        finally:
+            await engine.close()
+        assert r["tokens"]
+        # a prefill-role engine answers with the first token and a ticket
+        assert (r["finish_reason"] == "handoff") == (feature == "pool-role")
+
+    run_async(main())
+
+
+@pytest.mark.parametrize(
+    "value,says", [("dense", "removed at PR 29"), ("ragged", "unknown")]
+)
+@pytest.mark.parametrize("through", ["yaml", "constructor"])
+def test_a_layout_other_than_the_pool_is_refused_by_name(value, says, through):
+    from langstream_tpu.serving.engine import ServingConfig
+
+    with pytest.raises(ValueError, match="kv-layout") as e:
+        if through == "yaml":
+            ServingConfig.from_dict({"model": "tiny", "kv-layout": value})
+        else:
+            ServingConfig(model="tiny", kv_layout=value)
+    assert repr(value) in str(e.value) and says in str(e.value)
+    # the constant itself is accepted and read back, as the cells write it
+    assert ServingConfig.from_dict({"kv-layout": "paged"}).to_dict()[
+        "kv-layout"
+    ] == "paged"
+
+
+def test_engine_frequency_penalty_prevents_repeats(run_async):
     """A strong frequency penalty makes every generated token distinct —
     each emission forbids that token for the rest of the stream (counts
     ride the decode-chunk carry; penalty bursts run sequentially)."""
@@ -807,8 +864,7 @@ def test_engine_frequency_penalty_prevents_repeats(run_async, kv_layout):
         engine = TpuServingEngine.get_or_create(
             ServingConfig(
                 model="tiny", slots=4, max_seq_len=64, decode_chunk=4,
-                kv_layout=kv_layout,
-                kv_block_size=16 if kv_layout == "paged" else 64,
+                kv_block_size=16,
             )
         )
         r = await engine.generate(

@@ -300,18 +300,6 @@ def test_speculative_concurrent_requests_complete():
     asyncio.run(main())
 
 
-def test_speculative_requires_paged():
-    from langstream_tpu.serving.engine import ServingConfig, TpuServingEngine
-
-    with pytest.raises(ValueError, match="speculative"):
-        TpuServingEngine(
-            ServingConfig(
-                model="tiny", slots=2, max_seq_len=64,
-                kv_layout="dense", speculative_drafts=4,
-            )
-        )
-
-
 def test_speculative_with_chunked_prefill_and_prefix_cache():
     """All three schedulers at once: a long prompt chunk-prefills while
     another slot decodes speculatively; the verify step's commits must not

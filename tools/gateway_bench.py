@@ -300,9 +300,7 @@ async def run_gateway_bench(
             engine = engines[0]
             cfg = engine.config
             try:
-                window = (
-                    engine._window_for(cfg.max_seq_len) or cfg.max_seq_len
-                )
+                window = cfg.max_seq_len
                 roofline = decode_step_bytes(
                     engine.model_config,
                     slots=cfg.slots,
