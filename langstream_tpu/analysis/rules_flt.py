@@ -47,6 +47,10 @@ _DISPATCH_FUNCS = {
     "_speculative_burst",
     "_advance_prefills",
     "_admit",
+    "_admit_dispatch",
+    "_admit_complete",
+    "_dispatch_prefill",
+    "_fetch_prefill",
     "_apply_imports",
     "_export_ready_slots",
     "_export_slot",

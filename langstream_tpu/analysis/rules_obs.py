@@ -127,6 +127,10 @@ _HOT_LOOP_FUNCS = {
     "_speculative_burst",
     "_advance_prefills",
     "_admit",
+    "_admit_dispatch",
+    "_admit_complete",
+    "_dispatch_prefill",
+    "_fetch_prefill",
     "_process_chunk",
     "_emit_token",
     "_flush_emits",

@@ -2464,6 +2464,7 @@ class TpuServingEngine:
         routed_pairs: int | None = None,
         expert_load_max: int | None = None,
         state_bytes: int | None = None,
+        ahead: int | None = None,
     ) -> None:
         """One flight sample per dispatched burst, plus its Prometheus
         mirrors. ``program``, ``dispatch``, ``steps``,
@@ -2471,7 +2472,8 @@ class TpuServingEngine:
         the dispatch's :meth:`_ticket`, taken when it was made;
         ``routed_pairs``, ``expert_load_max`` and ``state_bytes`` (a hybrid
         model's decode chunk) joined it when the chunk's packed fetch
-        landed (:meth:`_await_chunk`).
+        landed (:meth:`_await_chunk`). ``ahead`` (a prefill batch) is 1 when
+        it was dispatched with its predecessor unfetched (:meth:`_admit`).
         ``overlapped_s`` is host work the pipelined loop ran
         under an in-flight dispatch's device shadow (see flight.py).
         ``program`` keys the sample by the compiled variant that ran and
@@ -2510,6 +2512,7 @@ class TpuServingEngine:
             routed_pairs=routed_pairs,
             expert_load_max=expert_load_max,
             state_bytes=state_bytes,
+            ahead=ahead,
         )
         # watchdog heartbeat: a recorded dispatch IS step progress
         self.watchdog.beat(sample["queue_depth"])
@@ -3278,6 +3281,8 @@ class TpuServingEngine:
             # running engine decompose where its dispatches go without a
             # bench run
             "steps": dict(self.flight.steps_by_phase),
+            # the share of prefill batches dispatched one ahead (_admit)
+            "prefill_ahead_share": self.flight.prefill_ahead_share,
             # watchdog verdict + warmup/readiness posture (serving/health.py)
             "health": self.health(),
             # drain-before-terminate posture + last drain's counts
@@ -6570,6 +6575,102 @@ class TpuServingEngine:
         else:
             self.block_mgr.release(slot_id)
 
+    async def _dispatch_prefill(
+        self, loop, ticket: dict, mode, tokens, lengths, sel_np, sel,
+        temps, topks, topps, ad_np, starts=None, nrb=None,
+    ):
+        """The first half of a prefill batch: resolve its program (the
+        continuation variant when ``starts`` is given) and split its key
+        here, then on the dispatch thread tell the followers, upload, call
+        the program and re-bind what it donated. Returns the first tokens
+        and their logprobs still on the device: nothing here waits for the
+        program, so :meth:`_admit` packs and dispatches the next batch
+        before :meth:`_fetch_prefill` asks for this one."""
+        with self.flight.span("ls.prefill.dispatch", **_span_meta(ticket)):
+            fn = (
+                self._prefill_fn(mode) if starts is None
+                else self._prefill_continue_fn(mode, nrb)
+            )
+            key = self._split_key()
+
+        def _run():
+            self._fault("prefill")
+            if self._lockstep is not None:
+                desc = {
+                    "op": "prefill",
+                    "sampler_mode": list(mode),
+                    "tokens": tokens,
+                    "lengths": lengths,
+                    "sel": np.asarray(sel_np),
+                    "key": np.asarray(key),
+                    "temps": temps,
+                    "topks": topks,
+                    "topps": topps,
+                }
+                if starts is not None:
+                    desc.update(op="prefill_continue", starts=starts, nrb=nrb)
+                self._lockstep.broadcast(desc)
+            with self.flight.span(
+                "ls.prefill.dispatch", **_span_meta(ticket)
+            ):
+                # the hybrid family's state rides behind the caches, a
+                # continuation's starts behind the tokens (never both)
+                args = (self.params, self.cache_k, self.cache_v) + (
+                    () if self.state is None else (self.state,)
+                ) + (jnp.asarray(tokens),) + (
+                    () if starts is None else (jnp.asarray(starts),)
+                ) + (
+                    jnp.asarray(lengths), sel, key, jnp.asarray(temps),
+                    jnp.asarray(topks), jnp.asarray(topps),
+                )
+                # adapter rows of the batch rows; None when the store is
+                # disabled keeps the seed trace
+                ad_kw = (
+                    {}
+                    if ad_np is None
+                    else {"ad_layers": self._ad_layers,
+                          "ad_ids": jnp.asarray(ad_np)}
+                )
+                variant = f"_cont_nrb{nrb}" if starts is not None else ""
+                self.profiler.dump_hlo(
+                    f"prefill_p{tokens.shape[1]}_b{tokens.shape[0]}{variant}",
+                    fn, *args,
+                )
+                out = fn(*args, **ad_kw)
+            # the donated caches are re-bound HERE, on the dispatch thread
+            # — the same side that reads them in every dispatch closure, so
+            # cache_k/cache_v stay single-thread-role (RACE801)
+            self.cache_k, self.cache_v = out[2], out[3]
+            # the hybrid family's recurrent state is donated with them
+            self.state = out[4] if len(out) > 4 else None
+            return out[0], out[1]
+
+        # held across the await, as the ``*.fetch`` spans are: until this
+        # coroutine runs again, a device with nothing queued waits for it
+        with self.flight.span("ls.prefill.dispatch", **_span_meta(ticket)):
+            return await loop.run_in_executor(self._executor, _run)
+
+    async def _fetch_prefill(self, loop, ticket: dict, out):
+        """The second half: wait on the dispatch thread for a dispatched
+        batch's first tokens and logprobs (the caches it returned may
+        already be donated to the batch dispatched after it, so only these
+        two are waited on). ``device_s`` is this wait: the program's run
+        time for a lone batch, what was left of it for a batch that had a
+        successor packed and dispatched meanwhile."""
+
+        def _run():
+            t_dev = time.monotonic()
+            # the ONE per-dispatch sync, on the dispatch thread and timed
+            # (the sample's device_ms); the token/logprob fetch rides the
+            # same stop so the loop thread never blocks on the device
+            # graftcheck: disable=JAX104 the one per-dispatch sync, moved off-loop and timed
+            jax.block_until_ready(out)
+            device_s = time.monotonic() - t_dev
+            return np.asarray(out[0]), np.asarray(out[1]), device_s
+
+        with self.flight.span("ls.prefill.fetch", seq=ticket["dispatch"]):
+            return await loop.run_in_executor(self._executor, _run)
+
     async def _advance_prefills(self, loop) -> None:
         """One bounded chunk of progress for every mid-prefill slot, batched
         through the continuation path. Intermediate chunks commit K/V only;
@@ -6621,7 +6722,6 @@ class TpuServingEngine:
                 topps[i] = request.top_p
         mode = self._sampler_mode(temps, topks, topps)
         nrb = self._read_blocks_for(max(int(starts.max()), 1))
-        fn = self._prefill_continue_fn(mode, nrb)
         # the continuation variant re-traces per (rows, chunk, window) shape
         self._note_compile("prefill-continue", (mode, nrb, Bp, C))
         ticket = self._ticket(
@@ -6629,66 +6729,18 @@ class TpuServingEngine:
             sum(1 for s in self.slots if not s.free and not s.prefilling),
         )
         sel_np = self.block_mgr.tables[slot_ids]
-        with self.flight.span("ls.prefill.dispatch", **_span_meta(ticket)):
-            key = self._split_key()
-        # adapter rows for the CHUNK batch rows (loop-thread snapshot,
-        # RACE801); None when the store is disabled keeps the seed trace
-        ad_np = (
+        out = await self._dispatch_prefill(
+            loop, ticket, mode, tokens, suffix_lens, sel_np,
+            jnp.asarray(sel_np), temps, topks, topps,
+            # adapter rows for the CHUNK batch rows (loop-thread snapshot,
+            # RACE801)
             self._ad_rows[slot_ids].copy()
-            if self._ad_rows is not None else None
+            if self._ad_rows is not None else None,
+            starts=starts, nrb=nrb,
         )
-
-        def _run():
-            self._fault("prefill")
-            if self._lockstep is not None:
-                self._lockstep.broadcast(
-                    {
-                        "op": "prefill_continue",
-                        "sampler_mode": list(mode),
-                        "nrb": nrb,
-                        "tokens": tokens,
-                        "starts": starts,
-                        "lengths": suffix_lens,
-                        "sel": sel_np,
-                        "key": np.asarray(key),
-                        "temps": temps,
-                        "topks": topks,
-                        "topps": topps,
-                    }
-                )
-            with self.flight.span(
-                "ls.prefill.dispatch", **_span_meta(ticket)
-            ):
-                ad_kw = (
-                    {}
-                    if ad_np is None
-                    else {"ad_layers": self._ad_layers,
-                          "ad_ids": jnp.asarray(ad_np)}
-                )
-                out = fn(
-                    self.params, self.cache_k, self.cache_v,
-                    jnp.asarray(tokens), jnp.asarray(starts),
-                    jnp.asarray(suffix_lens), jnp.asarray(sel_np), key,
-                    jnp.asarray(temps), jnp.asarray(topks),
-                    jnp.asarray(topps), **ad_kw,
-                )
-            # the donated caches are re-bound HERE, on the dispatch thread
-            # — the same side that reads them in every dispatch closure, so
-            # cache_k/cache_v stay single-thread-role (RACE801)
-            self.cache_k, self.cache_v = out[2], out[3]
-            t_dev = time.monotonic()
-            # the ONE per-dispatch sync, on the dispatch thread and timed
-            # (the sample's device_ms); the token/logprob fetch rides the
-            # same stop so the loop thread never blocks on the device
-            # graftcheck: disable=JAX104 the one per-dispatch sync, moved off-loop and timed
-            jax.block_until_ready(out)
-            device_s = time.monotonic() - t_dev
-            return np.asarray(out[0]), np.asarray(out[1]), device_s
-
-        with self.flight.span("ls.prefill.fetch", seq=ticket["dispatch"]):
-            next_np, logprob_np, device_s = await loop.run_in_executor(
-                self._executor, _run
-            )
+        next_np, logprob_np, device_s = await self._fetch_prefill(
+            loop, ticket, out
+        )
         with self.flight.span("ls.prefill.emit", rows=len(pre)):
             now = time.monotonic()
             done_slots = []
@@ -6732,7 +6784,7 @@ class TpuServingEngine:
                     self._m_tokens(1)
             self._flight_record(
                 "prefill", device_s=device_s, tokens=len(done_slots),
-                **ticket,
+                ahead=0, **ticket,
             )
         if done_slots:
             await self._flush_emits(done_slots, "ls.prefill.emit")
@@ -6745,425 +6797,395 @@ class TpuServingEngine:
         With the prefix cache on, each request first matches its
         prompt against cached block chains; matched requests adopt the
         shared blocks and prefill only the SUFFIX (grouped by suffix-length
-        bucket, dispatched through the continuation path)."""
+        bucket, dispatched through the continuation path).
+
+        A wave's batches are dispatched ONE ahead: batch N+1 is selected,
+        packed and handed to the device (:meth:`_admit_dispatch`) before
+        batch N's first tokens are waited for and emitted
+        (:meth:`_admit_complete`), so the device runs N+1 while the host
+        fetches, emits and flushes N and packs N+2. Selection, keys and
+        dispatches keep the serial order, so every program gets the
+        arguments it always got; the depth is one and nothing is in flight
+        when this returns (or raises: what was dispatched is completed
+        first, so the loop's failure paths find the slots the serial order
+        left them). What N+1's selection no longer sees is what N's
+        completion does: a request that ends at its first token frees its
+        slot one batch later, and (docs/PREFIX.md) batch N registers its
+        prefixes after batch N+1 was matched, so N+1 misses what N is about
+        to publish, as two requests of one batch miss each other."""
+        flying = None  # the batch on the device: (batch, ticket, out, ahead)
+        try:
+            while True:
+                nxt = await self._admit_dispatch(loop, flying is not None)
+                done, flying = flying, nxt
+                if done is not None:
+                    await self._admit_complete(loop, *done)
+                if nxt is None:
+                    return
+        except Exception:
+            if flying is not None:
+                await self._admit_complete(loop, *flying)
+            raise
+
+    async def _admit_dispatch(self, loop, ahead: bool):
+        """Select, claim, pack and dispatch the next prefill batch; None
+        when the queue, the free slots or the pool yield none. ``ahead``
+        says its predecessor is still unfetched (the flight sample's
+        ``ahead``)."""
         use_prefix = self.config.prefix_cache
-        while not self.scheduler.empty():
-            free = [i for i, s in enumerate(self.slots) if s.free]
-            if not free:
-                return
-            batch: list[tuple[int, _Request, int]] = []  # (slot, req, reuse)
-            bucket = None
-            while (
-                not self.scheduler.empty()
-                and len(batch) < min(len(free), self.config.prefill_batch)
-            ):
-                # ``ls.admit`` spans the synchronous stretches of this
-                # pass; its two awaits (adapter resolve, prefix promotion)
-                # run outside any span
-                with self.flight.span("ls.admit"):
-                    # the scheduler names the next admission candidate (FIFO
-                    # head by default; the WDRR-selected class head under QoS)
-                    request = self.scheduler.peek()
-                    if request is None:
-                        break
-                    if request.future.cancelled():
-                        self.scheduler.pop()  # caller gave up while queued
-                        # the caller walked away — answered by cancellation,
-                        # so a restart must not replay it
+        if self.scheduler.empty():
+            return None
+        free = [i for i, s in enumerate(self.slots) if s.free]
+        if not free:
+            return None
+        batch: list[tuple[int, _Request, int]] = []  # (slot, req, reuse)
+        bucket = None
+        while (
+            not self.scheduler.empty()
+            and len(batch) < min(len(free), self.config.prefill_batch)
+        ):
+            # ``ls.admit`` spans the synchronous stretches of this
+            # pass; its two awaits (adapter resolve, prefix promotion)
+            # run outside any span
+            with self.flight.span("ls.admit"):
+                # the scheduler names the next admission candidate (FIFO
+                # head by default; the WDRR-selected class head under QoS)
+                request = self.scheduler.peek()
+                if request is None:
+                    break
+                if request.future.cancelled():
+                    self.scheduler.pop()  # caller gave up while queued
+                    # the caller walked away — answered by cancellation,
+                    # so a restart must not replay it
+                    self._journal_retire(request)
+                    continue
+                if request.deadline is not None:
+                    # deadline gate (docs/RESILIENCE.md): shed BEFORE
+                    # any device work when the remaining budget cannot
+                    # cover the admission estimate — an explicit
+                    # 504-shaped refusal beats a silent late completion
+                    left = remaining_s(request.deadline)
+                    estimate = self._admit_estimate_s()
+                    if left <= estimate:
+                        self.scheduler.pop()
+                        err = self._note_deadline_shed(
+                            request, "admission", left, estimate
+                        )
                         self._journal_retire(request)
+                        if not request.future.done():
+                            request.future.set_exception(err)
                         continue
-                    if request.deadline is not None:
-                        # deadline gate (docs/RESILIENCE.md): shed BEFORE
-                        # any device work when the remaining budget cannot
-                        # cover the admission estimate — an explicit
-                        # 504-shaped refusal beats a silent late completion
-                        left = remaining_s(request.deadline)
-                        estimate = self._admit_estimate_s()
-                        if left <= estimate:
-                            self.scheduler.pop()
-                            err = self._note_deadline_shed(
-                                request, "admission", left, estimate
-                            )
-                            self._journal_retire(request)
-                            if not request.future.done():
-                                request.future.set_exception(err)
-                            continue
-                if self.adapter_store is not None and request.adapter:
-                    # multi-LoRA resolve (docs/ADAPTERS.md): the request
-                    # admits only once its adapter holds a device row.
-                    # "wait" stashed it off-scheduler (like the prefix
-                    # hydration stash), "refused" failed it loudly —
-                    # both popped it, so the pass moves on.
-                    verdict = await self._resolve_adapter(loop, request)
-                    if verdict == "backpressure":
-                        # every T0 row pinned by in-flight requests;
-                        # finishing slots release pins — retry next pass
-                        break
-                    if verdict != "ready":
+            if self.adapter_store is not None and request.adapter:
+                # multi-LoRA resolve (docs/ADAPTERS.md): the request
+                # admits only once its adapter holds a device row.
+                # "wait" stashed it off-scheduler (like the prefix
+                # hydration stash), "refused" failed it loudly —
+                # both popped it, so the pass moves on.
+                verdict = await self._resolve_adapter(loop, request)
+                if verdict == "backpressure":
+                    # every T0 row pinned by in-flight requests;
+                    # finishing slots release pins — retry next pass
+                    break
+                if verdict != "ready":
+                    continue
+            with self.flight.span("ls.admit"):
+                # one chain-digest walk per admission attempt, shared by
+                # the hydration check, the promotion, and match_prefix
+                # below — the admission path hashes the prompt ONCE
+                chain = (
+                    self.block_mgr.chain_digests(request.context_tokens)
+                    if self.prefix_store is not None
+                    and use_prefix
+                    and not request.preemptions
+                    and not request.adapter
+                    else None
+                )
+                if (
+                    chain is not None
+                    and not request.hydrate_attempted
+                    and not self._draining
+                ):
+                    # tiered prefix store: when the prompt's chain
+                    # extends into T2 (object storage), stash the
+                    # request OFF the queue while the background
+                    # hydrator pulls the blobs into T1 — it requeues at
+                    # class front the moment they land (or the timeout
+                    # falls it back to cold compute). Never head-blocks:
+                    # the loop moves on to the next admission candidate.
+                    request.hydrate_attempted = True
+                    missing = self._chain_t2_candidates(chain)
+                    if missing and self.prefix_store.request_hydration(
+                        missing
+                    ):
+                        self.scheduler.pop()
+                        deadline = (
+                            time.monotonic()
+                            + self.prefix_store.spec.hydrate_timeout_s
+                        )
+                        self._prefix_hydrating.append(
+                            (request, deadline, missing)
+                        )
+                        self.flight.event(
+                            "prefix-hydrate", stage="begin",
+                            blocks=len(missing),
+                        )
+                        self._journey(
+                            request, "hydrate-begin", blocks=len(missing)
+                        )
                         continue
-                with self.flight.span("ls.admit"):
-                    # one chain-digest walk per admission attempt, shared by
-                    # the hydration check, the promotion, and match_prefix
-                    # below — the admission path hashes the prompt ONCE
-                    chain = (
-                        self.block_mgr.chain_digests(request.context_tokens)
-                        if self.prefix_store is not None
-                        and use_prefix
-                        and not request.preemptions
-                        and not request.adapter
-                        else None
+                if not self.block_mgr.can_admit(
+                    len(request.prompt_tokens) + request.max_tokens + 1
+                ):
+                    # pool backpressure: the worst case doesn't fit the
+                    # pool right now; finished slots will free reservations.
+                    # (Requests that could NEVER fit are rejected up front in
+                    # generate(), so this always unblocks eventually. The
+                    # QoS loop may also preempt a lower-class victim to
+                    # unblock this head — see _maybe_preempt.)
+                    break
+            # a resumed request's prefill content is its full context
+            # (prompt + generated so far), rebuilding the KV state the
+            # preemption dropped; untouched requests see ctx == prompt
+            ctx = request.context_tokens
+            # adapter requests bypass the shared prefix plane both
+            # ways: their KV is colored by the adapter's attention
+            # projections, so reusing a base/other-adapter chain
+            # would splice foreign KV under this request — and
+            # registering theirs would poison adapter-less traffic
+            # (docs/ADAPTERS.md)
+            shared = (
+                use_prefix and not request.preemptions
+                and not request.adapter
+            )
+            if shared and chain is not None:
+                # promote the T1 run extending this prompt's T0
+                # chain back into pool blocks, so the match
+                # below sees the longer chain (docs/PREFIX.md)
+                await self._promote_prefix(loop, request, chain)
+            with self.flight.span("ls.admit"):
+                if shared:
+                    blocks, reuse = self.block_mgr.match_prefix(
+                        ctx, digests=chain
                     )
                     if (
-                        chain is not None
-                        and not request.hydrate_attempted
-                        and not self._draining
+                        reuse
+                        and len(ctx) - reuse
+                        > self.config.prefix_cache_max_suffix
                     ):
-                        # tiered prefix store: when the prompt's chain
-                        # extends into T2 (object storage), stash the
-                        # request OFF the queue while the background
-                        # hydrator pulls the blobs into T1 — it requeues at
-                        # class front the moment they land (or the timeout
-                        # falls it back to cold compute). Never head-blocks:
-                        # the loop moves on to the next admission candidate.
-                        request.hydrate_attempted = True
-                        missing = self._chain_t2_candidates(chain)
-                        if missing and self.prefix_store.request_hydration(
-                            missing
-                        ):
-                            self.scheduler.pop()
-                            deadline = (
-                                time.monotonic()
-                                + self.prefix_store.spec.hydrate_timeout_s
-                            )
-                            self._prefix_hydrating.append(
-                                (request, deadline, missing)
-                            )
-                            self.flight.event(
-                                "prefix-hydrate", stage="begin",
-                                blocks=len(missing),
-                            )
-                            self._journey(
-                                request, "hydrate-begin", blocks=len(missing)
-                            )
-                            continue
-                    if not self.block_mgr.can_admit(
-                        len(request.prompt_tokens) + request.max_tokens + 1
-                    ):
-                        # pool backpressure: the worst case doesn't fit the
-                        # pool right now; finished slots will free reservations.
-                        # (Requests that could NEVER fit are rejected up front in
-                        # generate(), so this always unblocks eventually. The
-                        # QoS loop may also preempt a lower-class victim to
-                        # unblock this head — see _maybe_preempt.)
-                        break
-                # a resumed request's prefill content is its full context
-                # (prompt + generated so far), rebuilding the KV state the
-                # preemption dropped; untouched requests see ctx == prompt
-                ctx = request.context_tokens
-                # adapter requests bypass the shared prefix plane both
-                # ways: their KV is colored by the adapter's attention
-                # projections, so reusing a base/other-adapter chain
-                # would splice foreign KV under this request — and
-                # registering theirs would poison adapter-less traffic
-                # (docs/ADAPTERS.md)
-                shared = (
-                    use_prefix and not request.preemptions
-                    and not request.adapter
-                )
-                if shared and chain is not None:
-                    # promote the T1 run extending this prompt's T0
-                    # chain back into pool blocks, so the match
-                    # below sees the longer chain (docs/PREFIX.md)
-                    await self._promote_prefix(loop, request, chain)
-                with self.flight.span("ls.admit"):
-                    if shared:
-                        blocks, reuse = self.block_mgr.match_prefix(
-                            ctx, digests=chain
-                        )
-                        if (
-                            reuse
-                            and len(ctx) - reuse
-                            > self.config.prefix_cache_max_suffix
-                        ):
-                            # long suffix, small saving: the flash/ring full
-                            # prefill beats the XLA continuation path
-                            blocks, reuse = [], 0
-                    else:
+                        # long suffix, small saving: the flash/ring full
+                        # prefill beats the XLA continuation path
                         blocks, reuse = [], 0
-                    to_prefill = len(ctx) - reuse
-                    if (
-                        self.config.prefill_chunk > 0
-                        and to_prefill > self.config.prefill_chunk
-                    ):
-                        # chunked prefill: claim the slot + reservation now, but
-                        # feed the prompt through _advance_prefills one bounded
-                        # chunk per loop pass instead of one monolithic prefill
-                        slot_id = free.pop(len(batch))
-                        self.scheduler.pop()
-                        self.block_mgr.admit(
-                            slot_id,
-                            len(request.prompt_tokens) + request.max_tokens + 1,
-                        )
-                        if blocks:
-                            self.block_mgr.adopt_prefix(slot_id, blocks)
-                        slot = self.slots[slot_id]
-                        # slot claimed BEFORE the physical grow: an allocator
-                        # failure below is then recoverable (a popped request
-                        # in no slot would be invisible to every failure
-                        # path). The chunked claim must undo ITSELF on a
-                        # grow failure: a prefilling slot whose table never
-                        # grew would scatter its chunks into the scratch
-                        # block (silent corruption), and the shrink sweep
-                        # deliberately leaves prefilling slots alone —
-                        # requeue (or shed past the retry cap) HERE, then
-                        # re-raise so the loop's shrink pass still adapts.
-                        slot.request = request
-                        slot.prefilling = True
-                        slot.prefill_done = reuse
-                        if self._ad_rows is not None:
-                            self._ad_rows[slot_id] = request.adapter_row
-                        try:
-                            self._fault("pool-grow")
-                            self.block_mgr.ensure_capacity(slot_id, len(ctx))
-                        except Exception as e:
-                            # monolithic members selected earlier this pass
-                            # are popped + reserved but NOT yet slotted —
-                            # invisible to every failure path (the shrink
-                            # sweep and _fail_inflight both walk slots):
-                            # undo them first, reservations released and
-                            # requeued front in order
-                            for sid, req, _r in reversed(batch):
-                                self.block_mgr.release(sid)
-                                self.scheduler.requeue_front(req)
-                            batch.clear()
-                            if not self._resource_exhausted(e):
-                                raise
-                            if request.preemptions >= _SHRINK_RETRY_CAP:
-                                self._shed_stranded(slot_id, e)
-                                self._shrink_inline_shed += 1
-                            else:
-                                self._preempt_slot(
-                                    slot_id, reason="pool-shrink"
-                                )
-                                self._shrink_inline_preempted += 1
-                            raise
-                        request.admit_time = time.monotonic()
-                        self._note_resume(request)
-                        self._journey(request, "admit", chunked=True)
-                        if reuse:
-                            self.prefix_hits += 1
-                            self.prefix_tokens += reuse
-                            self._m_prefix_hits(1)
-                            self._m_prefix_tokens(reuse)
-                        continue
-                    b = _bucket(to_prefill, hi=self.model_config.max_seq_len)
-                    if bucket is None:
-                        bucket = b
-                    elif b != bucket:
-                        break
-                    slot_id = free[len(batch)]
+                else:
+                    blocks, reuse = [], 0
+                to_prefill = len(ctx) - reuse
+                if (
+                    self.config.prefill_chunk > 0
+                    and to_prefill > self.config.prefill_chunk
+                ):
+                    # chunked prefill: claim the slot + reservation now, but
+                    # feed the prompt through _advance_prefills one bounded
+                    # chunk per loop pass instead of one monolithic prefill
+                    slot_id = free.pop(len(batch))
                     self.scheduler.pop()
-                    # reserve at pop time so the NEXT peek's can_admit sees
-                    # this batch member's reservation
                     self.block_mgr.admit(
-                        slot_id, len(request.prompt_tokens) + request.max_tokens + 1
+                        slot_id,
+                        len(request.prompt_tokens) + request.max_tokens + 1,
                     )
                     if blocks:
                         self.block_mgr.adopt_prefix(slot_id, blocks)
-                    batch.append((slot_id, request, reuse))
-            if not batch:
-                return
-            with self.flight.span(
-                "ls.admit", queued=self.scheduler.qsize(),
-                admitted=len(batch),
-            ):
-                admit_now = time.monotonic()
-                for slot_id, request, _reuse in batch:
-                    self.slots[slot_id].request = request
+                    slot = self.slots[slot_id]
+                    # slot claimed BEFORE the physical grow: an allocator
+                    # failure below is then recoverable (a popped request
+                    # in no slot would be invisible to every failure
+                    # path). The chunked claim must undo ITSELF on a
+                    # grow failure: a prefilling slot whose table never
+                    # grew would scatter its chunks into the scratch
+                    # block (silent corruption), and the shrink sweep
+                    # deliberately leaves prefilling slots alone —
+                    # requeue (or shed past the retry cap) HERE, then
+                    # re-raise so the loop's shrink pass still adapts.
+                    slot.request = request
+                    slot.prefilling = True
+                    slot.prefill_done = reuse
                     if self._ad_rows is not None:
                         self._ad_rows[slot_id] = request.adapter_row
-                    request.admit_time = admit_now
+                    try:
+                        self._fault("pool-grow")
+                        self.block_mgr.ensure_capacity(slot_id, len(ctx))
+                    except Exception as e:
+                        # monolithic members selected earlier this pass
+                        # are popped + reserved but NOT yet slotted —
+                        # invisible to every failure path (the shrink
+                        # sweep and _fail_inflight both walk slots):
+                        # undo them first, reservations released and
+                        # requeued front in order
+                        for sid, req, _r in reversed(batch):
+                            self.block_mgr.release(sid)
+                            self.scheduler.requeue_front(req)
+                        batch.clear()
+                        if not self._resource_exhausted(e):
+                            raise
+                        if request.preemptions >= _SHRINK_RETRY_CAP:
+                            self._shed_stranded(slot_id, e)
+                            self._shrink_inline_shed += 1
+                        else:
+                            self._preempt_slot(
+                                slot_id, reason="pool-shrink"
+                            )
+                            self._shrink_inline_preempted += 1
+                        raise
+                    request.admit_time = time.monotonic()
                     self._note_resume(request)
-                    self._journey(request, "admit")
-                # physical grows AFTER every batch member owns its slot: an
-                # allocator failure here is then recoverable by the shrink
-                # pass's preempt-and-requeue sweep (a popped request in no
-                # slot would be invisible to every failure path)
-                self._fault("pool-grow")
-                for slot_id, request, _reuse in batch:
-                    self.block_mgr.ensure_capacity(
-                        slot_id, len(request.context_tokens)
-                    )
-            with self.flight.span(
-                "ls.prefill.pack", rows=len(batch), bucket=bucket
-            ):
-                Bp = _pow2(len(batch))
-                use_continue = any(r > 0 for _, _, r in batch)
-                padded = np.zeros((Bp, bucket), dtype=np.int32)
-                lengths = np.zeros(Bp, dtype=np.int32)
-                starts = np.zeros(Bp, dtype=np.int32)
-                slot_ids = np.zeros(Bp, dtype=np.int32)
-                temps = np.zeros(Bp, dtype=np.float32)
-                topks = np.zeros(Bp, dtype=np.int32)
-                topps = np.ones(Bp, dtype=np.float32)
-                for i in range(Bp):
-                    slot_id, request, reuse = batch[min(i, len(batch) - 1)]
-                    suffix = request.context_tokens[reuse:]
-                    padded[i, : len(suffix)] = suffix
-                    lengths[i] = len(suffix)
-                    starts[i] = reuse
-                    slot_ids[i] = slot_id
-                    temps[i] = request.temperature
-                    topks[i] = request.top_k
-                    topps[i] = request.top_p
-                prefill_mode = self._sampler_mode(temps, topks, topps)
-                # per-batch-row adapter rows (loop-thread snapshot, RACE801)
-                ad_np = (
-                    self._ad_rows[slot_ids].copy()
-                    if self._ad_rows is not None else None
+                    self._journey(request, "admit", chunked=True)
+                    if reuse:
+                        self.prefix_hits += 1
+                        self.prefix_tokens += reuse
+                        self._m_prefix_hits(1)
+                        self._m_prefix_tokens(reuse)
+                    continue
+                b = _bucket(to_prefill, hi=self.model_config.max_seq_len)
+                if bucket is None:
+                    bucket = b
+                elif b != bucket:
+                    break
+                slot_id = free[len(batch)]
+                self.scheduler.pop()
+                # reserve at pop time so the NEXT peek's can_admit sees
+                # this batch member's reservation
+                self.block_mgr.admit(
+                    slot_id, len(request.prompt_tokens) + request.max_tokens + 1
                 )
-
-                # per-batch-row block tables (duplicate padded rows write
-                # identical values to identical blocks — harmless)
-                sel_np = self.block_mgr.tables[slot_ids]
-                sel = jnp.asarray(sel_np)
-                if self.is_hybrid:
-                    # the recurrent state's rows are the slots' own
-                    sel = (sel, jnp.asarray(slot_ids))
-                if use_continue:
-                    nrb = self._read_blocks_for(int(starts.max()))
-                    prefill_fn = self._prefill_continue_fn(prefill_mode, nrb)
-                    self._note_compile(
-                        "prefill-continue", (prefill_mode, nrb, Bp, bucket)
-                    )
-                    program = self._program_prefill_continue(
-                        nrb, Bp, bucket, prefill_mode
-                    )
-                else:
-                    prefill_fn = self._prefill_fn(prefill_mode)
-                    # same Python variant, fresh XLA program per (bucket, rows)
-                    self._note_compile("prefill", (prefill_mode, bucket, Bp))
-                    program = self._program_prefill(bucket, Bp, prefill_mode)
-            ticket = self._ticket(
-                program, 0,
-                sum(1 for s in self.slots if not s.free and not s.prefilling)
-                - len(batch),
+                if blocks:
+                    self.block_mgr.adopt_prefix(slot_id, blocks)
+                batch.append((slot_id, request, reuse))
+        if not batch:
+            return None
+        with self.flight.span(
+            "ls.admit", queued=self.scheduler.qsize(),
+            admitted=len(batch),
+        ):
+            admit_now = time.monotonic()
+            for slot_id, request, _reuse in batch:
+                self.slots[slot_id].request = request
+                if self._ad_rows is not None:
+                    self._ad_rows[slot_id] = request.adapter_row
+                request.admit_time = admit_now
+                self._note_resume(request)
+                self._journey(request, "admit")
+            # physical grows AFTER every batch member owns its slot: an
+            # allocator failure here is then recoverable by the shrink
+            # pass's preempt-and-requeue sweep (a popped request in no
+            # slot would be invisible to every failure path)
+            self._fault("pool-grow")
+            for slot_id, request, _reuse in batch:
+                self.block_mgr.ensure_capacity(
+                    slot_id, len(request.context_tokens)
+                )
+        with self.flight.span(
+            "ls.prefill.pack", rows=len(batch), bucket=bucket
+        ):
+            Bp = _pow2(len(batch))
+            use_continue = any(r > 0 for _, _, r in batch)
+            padded = np.zeros((Bp, bucket), dtype=np.int32)
+            lengths = np.zeros(Bp, dtype=np.int32)
+            starts = np.zeros(Bp, dtype=np.int32)
+            slot_ids = np.zeros(Bp, dtype=np.int32)
+            temps = np.zeros(Bp, dtype=np.float32)
+            topks = np.zeros(Bp, dtype=np.int32)
+            topps = np.ones(Bp, dtype=np.float32)
+            for i in range(Bp):
+                slot_id, request, reuse = batch[min(i, len(batch) - 1)]
+                suffix = request.context_tokens[reuse:]
+                padded[i, : len(suffix)] = suffix
+                lengths[i] = len(suffix)
+                starts[i] = reuse
+                slot_ids[i] = slot_id
+                temps[i] = request.temperature
+                topks[i] = request.top_k
+                topps[i] = request.top_p
+            prefill_mode = self._sampler_mode(temps, topks, topps)
+            # per-batch-row adapter rows (loop-thread snapshot, RACE801)
+            ad_np = (
+                self._ad_rows[slot_ids].copy()
+                if self._ad_rows is not None else None
             )
-            with self.flight.span(
-                "ls.prefill.dispatch", **_span_meta(ticket)
-            ):
-                key = self._split_key()
 
-            def _run():
-                self._fault("prefill")
-                if self._lockstep is not None:
-                    desc = {
-                        "sampler_mode": list(prefill_mode),
-                        "tokens": padded,
-                        "lengths": lengths,
-                        "sel": np.asarray(sel_np),
-                        "key": np.asarray(key),
-                        "temps": temps,
-                        "topks": topks,
-                        "topps": topps,
-                    }
-                    if use_continue:
-                        desc.update(
-                            {"op": "prefill_continue", "starts": starts,
-                             "nrb": nrb}
-                        )
-                    else:
-                        desc["op"] = "prefill"
-                    self._lockstep.broadcast(desc)
-                with self.flight.span(
-                    "ls.prefill.dispatch", **_span_meta(ticket)
-                ):
-                    if use_continue:
-                        args = (
-                            self.params, self.cache_k, self.cache_v,
-                            jnp.asarray(padded), jnp.asarray(starts),
-                            jnp.asarray(lengths), sel, key,
-                            jnp.asarray(temps), jnp.asarray(topks),
-                            jnp.asarray(topps),
-                        )
-                    else:
-                        args = (self.params, self.cache_k, self.cache_v) + (
-                            () if self.state is None else (self.state,)
-                        ) + (
-                            jnp.asarray(padded), jnp.asarray(lengths),
-                            sel, key,
-                            jnp.asarray(temps), jnp.asarray(topks),
-                            jnp.asarray(topps),
-                        )
-                    ad_kw = (
-                        {}
-                        if ad_np is None
-                        else {"ad_layers": self._ad_layers,
-                              "ad_ids": jnp.asarray(ad_np)}
-                    )
-                    variant = f"_cont_nrb{nrb}" if use_continue else ""
-                    self.profiler.dump_hlo(
-                        f"prefill_p{bucket}_b{Bp}{variant}", prefill_fn, *args
-                    )
-                    out = prefill_fn(*args, **ad_kw)
-                # donated caches re-bound on the dispatch thread — see
-                # _advance_prefills._run (RACE801: single thread role)
-                self.cache_k, self.cache_v = out[2], out[3]
-                # the hybrid family's recurrent state is donated with them
-                self.state = out[4] if len(out) > 4 else None
-                t_dev = time.monotonic()
-                # same single sync the loop-thread np.asarray used to pay,
-                # moved onto the dispatch thread so it can be timed; the
-                # token/logprob fetch rides the same stop
-                # graftcheck: disable=JAX104 the one per-dispatch sync, moved off-loop and timed
-                jax.block_until_ready(out)
-                device_s = time.monotonic() - t_dev
-                return np.asarray(out[0]), np.asarray(out[1]), device_s
+            # per-batch-row block tables (duplicate padded rows write
+            # identical values to identical blocks — harmless)
+            sel_np = self.block_mgr.tables[slot_ids]
+            sel = jnp.asarray(sel_np)
+            if self.is_hybrid:
+                # the recurrent state's rows are the slots' own
+                sel = (sel, jnp.asarray(slot_ids))
+            if use_continue:
+                nrb = self._read_blocks_for(int(starts.max()))
+                self._note_compile(
+                    "prefill-continue", (prefill_mode, nrb, Bp, bucket)
+                )
+                program = self._program_prefill_continue(
+                    nrb, Bp, bucket, prefill_mode
+                )
+            else:
+                # same Python variant, fresh XLA program per (bucket, rows)
+                self._note_compile("prefill", (prefill_mode, bucket, Bp))
+                program = self._program_prefill(bucket, Bp, prefill_mode)
+        ticket = self._ticket(
+            program, 0,
+            sum(1 for s in self.slots if not s.free and not s.prefilling)
+            - len(batch),
+        )
+        out = await self._dispatch_prefill(
+            loop, ticket, prefill_mode, padded, lengths, sel_np, sel,
+            temps, topks, topps, ad_np,
+            starts=starts if use_continue else None,
+            nrb=nrb if use_continue else None,
+        )
+        return batch, ticket, out, int(ahead)
 
-            with self.flight.span(
-                "ls.prefill.fetch", seq=ticket["dispatch"]
-            ):
-                next_np, logprob_np, device_s = await loop.run_in_executor(
-                    self._executor, _run
-                )
-            with self.flight.span("ls.prefill.emit", rows=len(batch)):
-                if use_prefix:
-                    for slot_id, request, reuse in batch:
-                        if request.preemptions or request.adapter:
-                            # resumed contexts stay out of the prefix cache
-                            # (generated content is not a shareable prompt);
-                            # adapter contexts too — their KV is colored by
-                            # the adapter's projections (docs/ADAPTERS.md)
-                            continue
-                        self.block_mgr.register_prefix(
-                            slot_id, request.prompt_tokens
-                        )
-                        if reuse:
-                            self.prefix_hits += 1
-                            self.prefix_tokens += reuse
-                            self._m_prefix_hits(1)
-                            self._m_prefix_tokens(reuse)
-                now = time.monotonic()
-                admitted_slots = []
-                for i, (slot_id, request, _reuse) in enumerate(batch):
-                    self._lengths[slot_id] = len(request.context_tokens)
-                    self._current[slot_id] = int(next_np[i])
-                    self._temps[slot_id] = request.temperature
-                    self._topks[slot_id] = request.top_k
-                    self._topps[slot_id] = request.top_p
-                    self._pres[slot_id] = request.presence_penalty
-                    self._freq[slot_id] = request.frequency_penalty
-                    if request.first_token_time is None:
-                        request.first_token_time = now
-                        self._journey(request, "first-token")
-                    self._emit_token(slot_id, int(next_np[i]), float(logprob_np[i]))
-                    admitted_slots.append(slot_id)
-                self._m_tokens(len(batch))
-                self._flight_record(
-                    "prefill", device_s=device_s, tokens=len(batch),
-                    **ticket,
-                )
-            await self._flush_emits(admitted_slots, "ls.prefill.emit")
+    async def _admit_complete(self, loop, batch, ticket, out, ahead) -> None:
+        """Wait for a dispatched batch's first tokens, publish its
+        prefixes, set its slots' host state and emit."""
+        next_np, logprob_np, device_s = await self._fetch_prefill(
+            loop, ticket, out
+        )
+        with self.flight.span("ls.prefill.emit", rows=len(batch)):
+            if self.config.prefix_cache:
+                for slot_id, request, reuse in batch:
+                    if request.preemptions or request.adapter:
+                        # resumed contexts stay out of the prefix cache
+                        # (generated content is not a shareable prompt);
+                        # adapter contexts too — their KV is colored by
+                        # the adapter's projections (docs/ADAPTERS.md)
+                        continue
+                    self.block_mgr.register_prefix(
+                        slot_id, request.prompt_tokens
+                    )
+                    if reuse:
+                        self.prefix_hits += 1
+                        self.prefix_tokens += reuse
+                        self._m_prefix_hits(1)
+                        self._m_prefix_tokens(reuse)
+            now = time.monotonic()
+            admitted_slots = []
+            for i, (slot_id, request, _reuse) in enumerate(batch):
+                self._lengths[slot_id] = len(request.context_tokens)
+                self._current[slot_id] = int(next_np[i])
+                self._temps[slot_id] = request.temperature
+                self._topks[slot_id] = request.top_k
+                self._topps[slot_id] = request.top_p
+                self._pres[slot_id] = request.presence_penalty
+                self._freq[slot_id] = request.frequency_penalty
+                if request.first_token_time is None:
+                    request.first_token_time = now
+                    self._journey(request, "first-token")
+                self._emit_token(slot_id, int(next_np[i]), float(logprob_np[i]))
+                admitted_slots.append(slot_id)
+            self._m_tokens(len(batch))
+            self._flight_record(
+                "prefill", device_s=device_s, tokens=len(batch),
+                ahead=ahead, **ticket,
+            )
+        await self._flush_emits(admitted_slots, "ls.prefill.emit")
 
     def _process_chunk(
         self,

@@ -179,6 +179,7 @@ class FlightRecorder:
         self.events_by_type: dict[str, int] = {}
         self.spec_accepted = 0
         self.spec_rejected = 0
+        self.prefill_ahead = 0
 
     # -- recording (engine hot path: appends + counter bumps only) -------
 
@@ -190,7 +191,8 @@ class FlightRecorder:
     @staticmethod
     def span(name: str, **meta: Any):
         """A host span on the profiler's clock: a context manager around
-        synchronous code (or the one ``await`` a ``*.fetch`` span names).
+        synchronous code (or the one ``await`` a ``*.fetch`` span, or the
+        loop's wait for a prefill's dispatch half, names).
         ``name`` is one of :data:`SPANS`; ``meta`` become the event's
         stats. Outside a profiler session this checks a flag and records
         nothing."""
@@ -222,6 +224,7 @@ class FlightRecorder:
         routed_pairs: int | None = None,
         expert_load_max: int | None = None,
         state_bytes: int | None = None,
+        ahead: int | None = None,
     ) -> dict[str, Any]:
         """Record one dispatched burst. ``wall`` is the time since the
         previous boundary. ``overlapped_s`` is host work the pipelined
@@ -247,7 +250,10 @@ class FlightRecorder:
         pairs the chunk's active rows sent to the experts held here, the most
         any one expert of any one layer got of them, and the bytes of
         recurrent state of the slots it advanced (read and written once a
-        step)."""
+        step). ``ahead`` (a prefill batch only) is 1 when the batch was
+        dispatched while its predecessor's first tokens were unfetched, so
+        its ``device_s`` is what was left of the program when the host came
+        to wait for it, not the program's run time."""
         now = time.monotonic()
         wall_ms = (now - self._last_mark) * 1000.0
         self._last_mark = now
@@ -293,6 +299,9 @@ class FlightRecorder:
             entry["routed_pairs"] = routed_pairs
             entry["expert_load_max"] = expert_load_max
             entry["state_bytes"] = state_bytes
+        if ahead is not None:
+            entry["ahead"] = ahead
+            self.prefill_ahead += ahead
         self._samples.append(entry)
         self.recorded += 1
         self.wall_ms += wall_ms
@@ -395,6 +404,13 @@ class FlightRecorder:
         return events[-n:] if n else events
 
     @property
+    def prefill_ahead_share(self) -> float | None:
+        """Prefill batches dispatched one ahead over all prefill batches
+        (cumulative; None before the first)."""
+        batches = self.steps_by_phase.get("prefill", 0)
+        return round(self.prefill_ahead / batches, 4) if batches else None
+
+    @property
     def dropped(self) -> int:
         """Samples evicted from the ring (0 until ``recorded`` exceeds
         ``LS_TPU_FLIGHT_BUFFER``)."""
@@ -454,6 +470,7 @@ class FlightRecorder:
                 "events_by_type": dict(self.events_by_type),
                 "spec_accepted": self.spec_accepted,
                 "spec_rejected": self.spec_rejected,
+                "prefill_ahead_share": self.prefill_ahead_share,
             },
             "window": {
                 "samples": len(window),
