@@ -1,0 +1,31 @@
+"""The gated experts' matmuls of a ``granitemoehybrid`` model against their
+roofline: the least time for one decode step's (the held and the shared
+experts' weights of every layer once, three matrices' worth each, or the
+operations of the routed pairs and the shared expert, whichever is longer;
+``lib/roofline_granite.py`` ``experts_floor``) over the device time a step
+spends under the scopes ``moe_experts`` and ``moe_shared`` (the two matmuls
+of an expert and the ``silu(a) * b`` between them). Routed pairs and rows a
+step come from the flight samples' ``routed_pairs`` and
+``active_at_dispatch``."""
+
+META = {
+    "unit": "%", "better": "higher", "layer": "kernels",
+    "moves": "tpot_p50_ms", "source": "device_trace",
+}
+SCOPES = ("moe_experts", "moe_shared")
+
+
+def read(obs):
+    from lib import roofline_granite
+
+    shape = roofline_granite.shape_of(obs)
+    load = roofline_granite.per_step(obs)
+    if shape is None or load is None or not obs.get("peaks"):
+        return None
+    step_ms = roofline_granite.scope_ms_step(obs, SCOPES)
+    if not step_ms:
+        return None
+    floor = roofline_granite.experts_floor(
+        shape, routed_pairs=load["routed_pairs"], batch=load["slots"],
+        peaks=obs["peaks"])
+    return 100.0 * floor["floor_s"] / (step_ms / 1e3)

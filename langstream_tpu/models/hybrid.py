@@ -1,23 +1,37 @@
 """Hybrid decoder: Mamba-2 mixers, grouped-query attention and routed
-experts in one stack (the ``nemotron_h`` family), over the paged pool.
+experts in one stack (the ``nemotron_h`` and ``granitemoehybrid`` families),
+over the paged pool.
 
-Every layer is ONE mixer with its own pre-norm and residual,
-``x <- x + Mixer_kind(RMSNorm(x))``, its kind from the published pattern
-(``M`` Mamba-2, ``*`` attention, ``E`` mixture of experts). The pattern is a
-run of blocks ``M [*] E`` (23 of them at the published depth, 6 with an
-attention layer), and that block is what the programs scan: the Mamba-2 and
-expert weights are stacked by block, the attention weights by attention
-layer and reached through the block's index when it has one.
+Every sub-layer is ONE mixer with its own pre-norm and residual,
+``x <- x + m * Mixer_kind(RMSNorm(x))``, its kind from the pattern (``M``
+Mamba-2, ``*`` attention, ``E`` mixture of experts; ``m`` the family's
+``residual_multiplier``, 1 where it has none). The pattern is a run of
+blocks with at least one mixer and then the experts, ``ME``, ``M*E`` or
+``*E``, and that block is what the programs scan. ``nemotron_h`` publishes
+the pattern itself (blocks ``M [*] E``: attention is an extra, 23 blocks and
+6 attention layers at the published depth); a ``granitemoehybrid`` layer is a
+mixer and then the experts, so its ``layer_types`` read ``ME`` for "mamba"
+and ``*E`` for "attention" (attention takes the Mamba-2 mixer's place). The
+expert weights are stacked by block, the Mamba-2 and the attention weights
+by their own layers; where every block has its Mamba-2 mixer the scan slices
+its weights with the block, otherwise a block reaches them, as it does the
+attention's, through its index and only when it has one.
+
+What else differs between the two families are facts of the checkpoint and
+fields of :class:`HybridConfig`, branched on in Python while a program is
+traced: the router's rule, the expert's activation, the three multipliers,
+the attention's scale and whether the head is the embedding.
 
 Two kinds of per-request state live side by side:
 
-- the six attention layers' keys and values, in the paged pool
+- the attention layers' keys and values, in the paged pool
   (:mod:`langstream_tpu.models.paged`), read through the kernel the engine
   selected, exactly as the dense family's;
-- a fixed-size recurrent state per slot for the 23 Mamba-2 layers: the
+- a fixed-size recurrent state per slot for the Mamba-2 layers: the
   float32 state ``(heads, head_dim, state)`` and the last ``kernel - 1``
   inputs of the convolution. It is not paged: prefill writes a slot's rows
-  whole, a decode step advances them in place.
+  whole, a decode step advances them in place. A block without a Mamba-2
+  mixer holds no rows of it.
 
 The expert layer serves one chip's share of an expert-parallel deployment
 (``experts_held`` of ``experts`` from ``expert_first``): the router keeps
@@ -44,7 +58,12 @@ from langstream_tpu.models.llama_paged import (
     _cache_partial_xla,
     pack_tokens_logprobs,
 )
-from langstream_tpu.models.moe import relu2_experts, sigmoid_topk_routing
+from langstream_tpu.models.moe import (
+    EXPERT_ACTS,
+    dropless_experts,
+    sigmoid_topk_routing,
+    softmax_topk_routing,
+)
 from langstream_tpu.models.paged import write_rows
 from langstream_tpu.ops.paged_attention import (
     NEG_INF,
@@ -53,6 +72,7 @@ from langstream_tpu.ops.paged_attention import (
 )
 
 NEMOTRON3_NANO_PATTERN = "MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*EMEMEMEME"
+_BLOCK = r"(?:M\*?|\*)E"     # at least one mixer, then the experts
 
 
 @dataclasses.dataclass(frozen=True)
@@ -82,17 +102,31 @@ class HybridConfig:
     experts: int = 128
     experts_per_token: int = 6
     shared_intermediate: int = 3712
-    routed_scale: float = 2.5
+    routed_scale: float = 2.5         # the sigmoid router's alone
     router_dtype: Any = jnp.float32   # published; lower only as a control
     # this chip's share of the expert-parallel deployment
     experts_held: int = 16
     expert_first: int = 0
+    # facts of the checkpoint that differ between the families (the defaults
+    # are nemotron_h's; at 1.0 and None nothing is traced for them)
+    router: str = "sigmoid"           # or "softmax_topk" (moe.py)
+    expert_act: str = "relu2"         # or "silu_gated" (moe.py EXPERT_ACTS)
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0  # on every residual branch
+    logits_scaling: float = 1.0       # the logits are DIVIDED by it
+    attention_scale: float | None = None   # None: 1/sqrt(head_dim)
+    tied_head: bool = False           # the head is the embedding
 
     def __post_init__(self):
-        if not re.fullmatch(r"(M\*?E)+", self.pattern):
+        if not re.fullmatch(f"(?:{_BLOCK})+", self.pattern):
             raise ValueError(
-                f"pattern {self.pattern!r} is not a run of blocks M[*]E"
+                f"pattern {self.pattern!r} is not a run of blocks ME, M*E "
+                f"or *E (at least one mixer, then the experts)"
             )
+        if self.router not in ("sigmoid", "softmax_topk"):
+            raise ValueError(f"unknown router rule {self.router!r}")
+        if self.expert_act not in EXPERT_ACTS:
+            raise ValueError(f"unknown expert activation {self.expert_act!r}")
         if len(self.pattern) != self.layers:
             raise ValueError(
                 f"layers={self.layers} but the pattern has "
@@ -109,6 +143,36 @@ class HybridConfig:
         return cls(max_seq_len=max_seq_len)
 
     @classmethod
+    def granite4_h_small_ep2(cls, max_seq_len: int = 2048) -> "HybridConfig":
+        """ibm-granite/granite-4.0-h-small as one chip of a pair that shares
+        each layer, one pipeline stage of four: layers 0-9 of 40 (one whole
+        period ``M M M M M A M M M M``, each followed by its experts), 36 of
+        72 experts and 50,176 of 100,352 vocabulary rows held here, mixers
+        and the shared expert whole."""
+        return cls(
+            vocab_size=50176, hidden=4096, layers=20, heads=32, kv_heads=8,
+            head_dim=128, intermediate=768, pattern="ME" * 5 + "*E" + "ME" * 4,
+            ssm_heads=128, ssm_head_dim=64, ssm_groups=1, ssm_state=128,
+            experts=72, experts_per_token=10, shared_intermediate=1536,
+            experts_held=36, max_seq_len=max_seq_len, **_GRANITE_FACTS,
+        )
+
+    @classmethod
+    def granite_tiny(cls, max_seq_len: int = 128,
+                     expert_first: int = 0) -> "HybridConfig":
+        """Test size of the ``granitemoehybrid`` layer: two periods of
+        ``M M A M``, half of the experts held."""
+        return cls(
+            vocab_size=384, hidden=64, layers=16, heads=4, kv_heads=2,
+            head_dim=16, intermediate=32, pattern="MEME*EME" * 2,
+            ssm_heads=16, ssm_head_dim=8, ssm_groups=1, ssm_state=16,
+            chunk_size=16, experts=8, experts_per_token=3,
+            shared_intermediate=48, experts_held=4,
+            expert_first=expert_first, max_seq_len=max_seq_len,
+            **_GRANITE_FACTS,
+        )
+
+    @classmethod
     def tiny(cls, max_seq_len: int = 128, expert_first: int = 0) -> "HybridConfig":
         """Test size: the same family, at least two of each kind."""
         return cls(
@@ -122,12 +186,26 @@ class HybridConfig:
 
     @property
     def blocks(self) -> tuple[bool, ...]:
-        """One entry a block ``M [*] E``: whether it has the attention."""
-        return tuple("*" in b for b in re.findall(r"M\*?E", self.pattern))
+        """One entry a block: whether it has the attention."""
+        return tuple("*" in b for b in re.findall(_BLOCK, self.pattern))
+
+    @property
+    def mamba_blocks(self) -> tuple[bool, ...]:
+        """One entry a block: whether it has the Mamba-2 mixer."""
+        return tuple("M" in b for b in re.findall(_BLOCK, self.pattern))
 
     @property
     def attn_layers(self) -> int:
         return self.pattern.count("*")
+
+    @property
+    def mamba_layers(self) -> int:
+        return self.pattern.count("M")
+
+    @property
+    def attn_scale(self) -> float:
+        return (1.0 / math.sqrt(self.head_dim) if self.attention_scale is None
+                else self.attention_scale)
 
     @property
     def d_inner(self) -> int:
@@ -139,14 +217,26 @@ class HybridConfig:
 
     @property
     def state_bytes_per_slot(self) -> int:
-        """Recurrent state and convolution tail of one slot, all layers."""
-        n = len(self.blocks)
+        """Recurrent state and convolution tail of one slot, all Mamba-2
+        layers."""
+        n = self.mamba_layers
         ssm = (self.ssm_heads * self.ssm_head_dim * self.ssm_state
                * jnp.dtype(self.state_dtype).itemsize)
         conv = ((self.conv_kernel - 1) * self.conv_dim
                 * jnp.dtype(self.dtype).itemsize)
         return n * (ssm + conv)
 
+
+#: what a ``granitemoehybrid`` checkpoint fixes beside its sizes
+#: (granite-4.0-h-small's config.json: attention_multiplier,
+#: embedding_multiplier, residual_multiplier, logits_scaling,
+#: tie_word_embeddings; its router takes the top k logits and then their
+#: softmax, its experts are gated)
+_GRANITE_FACTS = dict(
+    router="softmax_topk", expert_act="silu_gated", embedding_multiplier=12.0,
+    residual_multiplier=0.22, logits_scaling=16.0,
+    attention_scale=0.0078125, tied_head=True,
+)
 
 # ---------------------------------------------------------------------------
 # parameters and state
@@ -157,14 +247,24 @@ def init_hybrid_params(config: HybridConfig, key: jax.Array | None = None) -> di
     """Random parameters from a key, one jitted draw a leaf so that the
     float32 draw of a big leaf never sits beside the whole tree. An
     expert's weights depend on its GLOBAL id, so the shares of one
-    deployment are slices of the same 128 experts. ``A_log``, ``dt_bias``,
+    deployment are slices of the same experts. ``A_log``, ``dt_bias``,
     ``D`` and the router's correction bias are drawn well away from
-    trivial values: a term left out changes the logits."""
+    trivial values: a term left out changes the logits. So are the scales
+    where a family's multipliers would flatten what random weights give:
+    the queries' and keys' weights are drawn wider by what an attention
+    scale under ``1/sqrt(head_dim)`` takes away (the scores keep a spread
+    of about 1), and a tied embedding narrower, ``1 / (sqrt(hidden) x
+    embedding_multiplier)``, so that a token's own row does not decide its
+    logits (it adds about one spread of the rest to its own) and greedy
+    decoding does not repeat itself."""
     c = config
     key = key if key is not None else jax.random.PRNGKey(0)
     n = len(c.blocks)
-    nA = c.attn_layers
+    nM, nA = c.mamba_layers, c.attn_layers
     H, I, Is = c.hidden, c.intermediate, c.shared_intermediate
+    gated = 2 if c.expert_act == "silu_gated" else 1   # [a | b] in one
+    qk_fan_in = H if c.attention_scale is None else (
+        H * c.attention_scale * math.sqrt(c.head_dim))
     names = iter(range(10 ** 6))
 
     def normal(shape, fan_in, dtype=None):
@@ -194,52 +294,60 @@ def init_hybrid_params(config: HybridConfig, key: jax.Array | None = None) -> di
             lambda i: jax.vmap(lambda e: one(i, e))(held)
         ))(jnp.arange(n))
 
-    dt = jnp.exp(uniform((n, c.ssm_heads), math.log(0.001), math.log(0.1)))
-    return {
-        "embed": normal((c.vocab_size, H), 1.0),
+    dt = jnp.exp(uniform((nM, c.ssm_heads), math.log(0.001), math.log(0.1)))
+    params = {
+        "embed": normal((c.vocab_size, H), 1.0 if not c.tied_head else
+                        H * c.embedding_multiplier ** 2),
         "final_norm": jnp.ones((H,), c.dtype),
-        "lm_head": normal((H, c.vocab_size), H),
-        "mamba": {
-            "norm": jnp.ones((n, H), c.dtype),
-            # [z | xBC | dt] = W_in u, the published fused projection as
-            # its three column blocks: the fused width (10304 at the
-            # published sizes) is no multiple of the 128-lane tile, and
-            # the runtime then keeps the array transposed and the program
-            # copies all of it back before every chunk
-            "w_z": normal((n, H, c.d_inner), H),
-            "w_xbc": normal((n, H, c.conv_dim), H),
-            "w_dt": normal((n, H, c.ssm_heads), H),
-            "conv_w": normal((n, c.conv_dim, c.conv_kernel), c.conv_kernel),
-            "conv_b": normal((n, c.conv_dim), 25.0),
-            # softplus(dt_bias) is log-uniform in [0.001, 0.1]; A in [1, 16]
-            "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
-            "A_log": jnp.log(uniform((n, c.ssm_heads), 1.0, 16.0)),
-            "D": uniform((n, c.ssm_heads), 0.5, 1.5),
-            "gate_norm": jnp.ones((n, c.d_inner), c.dtype),
-            "w_out": normal((n, c.d_inner, H), c.d_inner),
-        },
-        "attn": {
-            "norm": jnp.ones((nA, H), c.dtype),
-            "wq": normal((nA, H, c.heads * c.head_dim), H),
-            "wk": normal((nA, H, c.kv_heads * c.head_dim), H),
-            "wv": normal((nA, H, c.kv_heads * c.head_dim), H),
-            "wo": normal((nA, c.heads * c.head_dim, H), c.heads * c.head_dim),
-        },
-        "moe": {
-            "norm": jnp.ones((n, H), c.dtype),
-            "router": normal((n, H, c.experts), H, jnp.float32),
-            # small beside the spread of the scores, as a trained bias is:
-            # the scores decide the winners and the bias the near-ties
-            "bias": uniform((n, c.experts), -0.02, 0.02),
-            # both (held, I, H): the expert width (1856) is no multiple of
-            # the lane tile either, so the up-projection is kept output-
-            # major and contracts its last axis
-            "w_up": experts((I, H), H),
-            "w_down": experts((I, H), I),
-            "ws_up": normal((n, H, Is), H),
-            "ws_down": normal((n, Is, H), Is),
-        },
     }
+    if not c.tied_head:
+        params["lm_head"] = normal((H, c.vocab_size), H)
+    params["mamba"] = {     # one row a Mamba-2 layer
+        "norm": jnp.ones((nM, H), c.dtype),
+        # [z | xBC | dt] = W_in u, the published fused projection as
+        # its three column blocks: the fused width (10304 at nemotron_h's
+        # published sizes) is no multiple of the 128-lane tile, and
+        # the runtime then keeps the array transposed and the program
+        # copies all of it back before every chunk
+        "w_z": normal((nM, H, c.d_inner), H),
+        "w_xbc": normal((nM, H, c.conv_dim), H),
+        "w_dt": normal((nM, H, c.ssm_heads), H),
+        "conv_w": normal((nM, c.conv_dim, c.conv_kernel), c.conv_kernel),
+        "conv_b": normal((nM, c.conv_dim), 25.0),
+        # softplus(dt_bias) is log-uniform in [0.001, 0.1]; A in [1, 16]
+        "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+        "A_log": jnp.log(uniform((nM, c.ssm_heads), 1.0, 16.0)),
+        "D": uniform((nM, c.ssm_heads), 0.5, 1.5),
+        "gate_norm": jnp.ones((nM, c.d_inner), c.dtype),
+        "w_out": normal((nM, c.d_inner, H), c.d_inner),
+    }
+    params["attn"] = {      # one row an attention layer
+        "norm": jnp.ones((nA, H), c.dtype),
+        "wq": normal((nA, H, c.heads * c.head_dim), qk_fan_in),
+        "wk": normal((nA, H, c.kv_heads * c.head_dim), qk_fan_in),
+        "wv": normal((nA, H, c.kv_heads * c.head_dim), H),
+        "wo": normal((nA, c.heads * c.head_dim, H), c.heads * c.head_dim),
+    }
+    sigmoid = c.router == "sigmoid"
+    moe = params["moe"] = {     # one row a block; drawn in this order
+        "norm": jnp.ones((n, H), c.dtype),
+        # float32 as nemotron_h publishes it; the softmax router's weights
+        # are the model's type and its logits float32
+        "router": normal((n, H, c.experts), H, jnp.float32 if sigmoid else None),
+    }
+    if sigmoid:
+        # small beside the spread of the scores, as a trained bias is:
+        # the scores decide the winners and the bias the near-ties
+        moe["bias"] = uniform((n, c.experts), -0.02, 0.02)
+    # both (held, I, H): the expert width (1856 at nemotron_h's sizes) is no
+    # multiple of the lane tile either, so the up-projection is kept output-
+    # major and contracts its last axis; a gated expert's is its two halves
+    # [a | b], (held, 2 I, H)
+    moe["w_up"] = experts((gated * I, H), H)
+    moe["w_down"] = experts((I, H), I)
+    moe["ws_up"] = normal((n, H, gated * Is), H)
+    moe["ws_down"] = normal((n, Is, H), Is)
+    return params
 
 
 def init_hybrid_pool(config: HybridConfig, layout) -> tuple[jax.Array, jax.Array]:
@@ -252,10 +360,10 @@ def init_hybrid_pool(config: HybridConfig, layout) -> tuple[jax.Array, jax.Array
 
 
 def init_hybrid_state(config: HybridConfig, slots: int) -> dict:
-    """``{"ssm": (blocks, slots, heads, head_dim, state), "conv": (blocks,
-    slots, kernel - 1, conv_dim)}``, zeros."""
+    """``{"ssm": (Mamba-2 layers, slots, heads, head_dim, state), "conv":
+    (Mamba-2 layers, slots, kernel - 1, conv_dim)}``, zeros."""
     c = config
-    n = len(c.blocks)
+    n = c.mamba_layers
     return {
         "ssm": jnp.zeros(
             (n, slots, c.ssm_heads, c.ssm_head_dim, c.ssm_state), c.state_dtype
@@ -438,22 +546,52 @@ def moe_mixer(c: HybridConfig, lp: dict, h: jax.Array, valid: jax.Array,
     """Routed experts held here plus the shared expert over rows ``h (T,
     H)``; ``lp`` is one expert layer's weights, or with ``layer`` its
     ``w_up`` and ``w_down`` are the stacks of every layer's
-    (models/moe.py ``relu2_experts_grouped``). Returns ``(out (T, H), load (experts_held,) int32, chosen experts
-    (T, k))``; ``valid`` rows are the ones that count (padding and idle
-    slots route nowhere)."""
+    (models/moe.py ``dropless_experts_grouped``). Returns ``(out (T, H),
+    load (experts_held,) int32, chosen experts (T, k))``; ``valid`` rows are
+    the ones that count (padding and idle slots route nowhere). A gated
+    expert's ``silu(a) * b`` lies between its matmuls, under their scope."""
+    act = EXPERT_ACTS[c.expert_act]
     with jax.named_scope("moe_router"):
-        experts, weights = sigmoid_topk_routing(
-            h, lp["router"], lp["bias"], c.experts_per_token, c.routed_scale,
-            c.router_dtype,
-        )
-    routed, load = relu2_experts(
+        if c.router == "sigmoid":
+            experts, weights = sigmoid_topk_routing(
+                h, lp["router"], lp["bias"], c.experts_per_token,
+                c.routed_scale, c.router_dtype,
+            )
+        else:
+            experts, weights = softmax_topk_routing(
+                h, lp["router"], c.experts_per_token, c.router_dtype)
+    routed, load = dropless_experts(
         h, experts, weights, lp["w_up"], lp["w_down"], c.expert_first, valid,
-        layer=layer,
+        layer=layer, act=act,
     )
     with jax.named_scope("moe_shared"):
-        shared = jnp.square(jax.nn.relu(h @ lp["ws_up"])) @ lp["ws_down"]
+        shared = act(h @ lp["ws_up"]) @ lp["ws_down"]
     with jax.named_scope("moe_combine"):
         return (routed + shared.astype(jnp.float32)).astype(h.dtype), load, experts
+
+
+def _residual(c: HybridConfig, x: jax.Array, out: jax.Array) -> jax.Array:
+    """``x + m * out``; at ``m`` = 1 no multiply is traced."""
+    if c.residual_multiplier == 1.0:
+        return x + out
+    return x + out * c.residual_multiplier
+
+
+def _embed(c: HybridConfig, params: dict, tokens: jax.Array) -> jax.Array:
+    x = params["embed"][tokens]
+    return x if c.embedding_multiplier == 1.0 else x * c.embedding_multiplier
+
+
+def _logits(c: HybridConfig, params: dict, x: jax.Array) -> jax.Array:
+    """Float32 logits of normed rows ``x (B, H)``: over the head, or over
+    the embedding where the head is tied to it, divided by the family's
+    ``logits_scaling``."""
+    if c.tied_head:
+        logits = jnp.einsum("bh,vh->bv", x, params["embed"])
+    else:
+        logits = x @ params["lm_head"]
+    logits = logits.astype(jnp.float32)
+    return logits if c.logits_scaling == 1.0 else logits / c.logits_scaling
 
 
 # ---------------------------------------------------------------------------
@@ -464,21 +602,44 @@ def moe_mixer(c: HybridConfig, lp: dict, h: jax.Array, valid: jax.Array,
 def _block_xs(c: HybridConfig, params: dict, experts_in_xs: bool = True):
     """What the scan over blocks slices a block at a time. A prefill keeps
     the routed experts' stacks out (``experts_in_xs=False``) and reads them
-    by the block's index (:func:`moe_mixer` ``layer``)."""
+    by the block's index (:func:`moe_mixer` ``layer``). Where every block
+    has its Mamba-2 mixer the first entry is the mixers' weights, sliced
+    with the block; otherwise ``(has the mixer, its Mamba-2 layer)`` and the
+    block reaches the weights by that index (:func:`_mamba_in_block`); a
+    block without the mixer points past the last layer, where a write of
+    state rows is dropped."""
     has = jnp.asarray(c.blocks)
     # a block without attention points at the spare row past the last layer
     idx = jnp.where(has, jnp.cumsum(has) - 1, c.attn_layers).astype(jnp.int32)
     moe = params["moe"] if experts_in_xs else {
         k: v for k, v in params["moe"].items() if k not in ("w_up", "w_down")}
-    return (params["mamba"], moe, has, idx,
-            jnp.arange(len(c.blocks), dtype=jnp.int32))
+    mamba = params["mamba"]
+    if not all(c.mamba_blocks):
+        has_m = jnp.asarray(c.mamba_blocks)
+        mamba = (has_m, jnp.where(
+            has_m, jnp.cumsum(has_m) - 1, c.mamba_layers).astype(jnp.int32))
+    return (mamba, moe, has, idx, jnp.arange(len(c.blocks), dtype=jnp.int32))
 
 
-def _attn_weights(params: dict, a: jax.Array) -> dict:
+def _layer_at(stack: dict, i: jax.Array) -> dict:
     return jax.tree.map(
-        lambda t: jax.lax.dynamic_index_in_dim(t, a, keepdims=False),
-        params["attn"],
-    )
+        lambda t: jax.lax.dynamic_index_in_dim(t, i, keepdims=False), stack)
+
+
+def _mamba_in_block(c: HybridConfig, params: dict, mp, i, mixer: Callable,
+                    absent: Callable, *operands):
+    """The block's Mamba-2 sub-layer: ``(its Mamba-2 layer, mixer(weights,
+    that layer, *operands))``. ``mp`` and ``i`` are the block's slice of
+    :func:`_block_xs`: the layer's weights and the block's index where every
+    block has the mixer (nothing is traced around it), else ``(has the
+    mixer, its layer)``, and a block without one gives ``absent(*operands)``
+    in the mixer's place."""
+    if all(c.mamba_blocks):
+        return i, mixer(mp, i, *operands)
+    has_m, m = mp
+    return m, jax.lax.cond(
+        has_m, lambda *a: mixer(_layer_at(params["mamba"], m), m, *a), absent,
+        *operands)
 
 
 def hybrid_prefill_paged(
@@ -512,10 +673,10 @@ def hybrid_prefill_paged(
     flash = (_flash_mode(Pn) if use_flash is None
              else ("compiled" if use_flash else None))
     with jax.named_scope("embed"):
-        x = params["embed"][tokens]
+        x = _embed(c, params, tokens)
 
     def attention(x, a):
-        ap = _attn_weights(params, a)
+        ap = _layer_at(params["attn"], a)
         with jax.named_scope("attn_qkv"):
             h = _rms_norm(x, ap["norm"], c.norm_eps)
             q = jnp.einsum("bph,hd->bpd", h, ap["wq"]).reshape(
@@ -530,12 +691,14 @@ def hybrid_prefill_paged(
                 from langstream_tpu.ops.flash_attention import flash_attention
 
                 out = flash_attention(
-                    q, k, v, causal=True, interpret=(flash == "interpret"))
+                    q, k, v, causal=True, scale=c.attention_scale,
+                    interpret=(flash == "interpret"))
         else:
             with jax.named_scope("kv_read"):
                 qg = q.reshape(B, Pn, c.kv_heads, G, c.head_dim)
                 s = jnp.einsum("bqkgd,bskd->bkgqs", qg, k).astype(jnp.float32)
-                s = s / math.sqrt(c.head_dim)
+                s = (s / math.sqrt(c.head_dim) if c.attention_scale is None
+                     else s * c.attention_scale)
                 mask = (jnp.arange(Pn)[:, None] >= jnp.arange(Pn)[None, :])[
                     None] & real[:, None, :]
                 s = jnp.where(mask[:, None, None], s, NEG_INF)
@@ -543,32 +706,45 @@ def hybrid_prefill_paged(
                     "bkgqs,bskd->bqkgd", jax.nn.softmax(s, -1).astype(x.dtype), v)
         with jax.named_scope("attn_out"):
             out = out.reshape(B, Pn, c.heads * c.head_dim)
-            x = x + jnp.einsum("bpd,dh->bph", out, ap["wo"])
+            x = _residual(c, x, jnp.einsum("bpd,dh->bph", out, ap["wo"]))
         return x, k.reshape(B, Pn, KhD), v.reshape(B, Pn, KhD)
 
     def no_attention(x, a):
         zero = jnp.zeros((B, Pn, KhD), x.dtype)
         return x, zero, zero
 
+    def mamba(mp, m, x):
+        return mamba_prefill(
+            c, mp, _rms_norm(x, mp["norm"], c.norm_eps), lengths)
+
+    def no_mamba(x):
+        # nothing for the residual, and rows whose write is dropped
+        return (jnp.zeros_like(x),
+                jnp.zeros((B,) + state["ssm"].shape[2:], state["ssm"].dtype),
+                jnp.zeros((B,) + state["conv"].shape[2:], state["conv"].dtype))
+
     def block(carry, xs):
         x, ks, vs, ssm_all, conv_all = carry
         mp, ep, has, a, i = xs
-        out, ssm, tail = mamba_prefill(
-            c, mp, _rms_norm(x, mp["norm"], c.norm_eps), lengths)
+        m, (out, ssm, tail) = _mamba_in_block(
+            c, params, mp, i, mamba, no_mamba, x)
         with jax.named_scope("ssm_state_write"):
             # this layer's rows of the batch's slots, in the carry: the
-            # whole state is never stacked beside itself
-            ssm_all = ssm_all.at[i, slot_ids].set(ssm)
-            conv_all = conv_all.at[i, slot_ids].set(tail)
-        x = x + out
+            # whole state is never stacked beside itself, and stays outside
+            # the conditional a block without the mixer needs (a whole
+            # state through its branches is copied whole; such a block's
+            # layer lies past the last, and its rows are dropped)
+            ssm_all = ssm_all.at[m, slot_ids].set(ssm, mode="drop")
+            conv_all = conv_all.at[m, slot_ids].set(tail, mode="drop")
+        x = _residual(c, x, out)
         x, k, v = jax.lax.cond(has, attention, no_attention, x, a)
         ks = jax.lax.dynamic_update_index_in_dim(ks, k, a, 0)
         vs = jax.lax.dynamic_update_index_in_dim(vs, v, a, 0)
         h = _rms_norm(x, ep["norm"], c.norm_eps).reshape(B * Pn, c.hidden)
         ep = dict(ep, w_up=params["moe"]["w_up"], w_down=params["moe"]["w_down"])
         out, _, chosen = moe_mixer(c, ep, h, real.reshape(-1), layer=i)
-        return (x + out.reshape(B, Pn, c.hidden), ks, vs, ssm_all, conv_all), \
-            chosen.reshape(B, Pn, -1)
+        x = _residual(c, x, out.reshape(B, Pn, c.hidden))
+        return (x, ks, vs, ssm_all, conv_all), chosen.reshape(B, Pn, -1)
 
     spare = jnp.zeros((nA + 1, B, Pn, KhD), c.dtype)
     (x, ks, vs, ssm_all, conv_all), routed = jax.lax.scan(
@@ -578,7 +754,7 @@ def hybrid_prefill_paged(
         x = _rms_norm(x, params["final_norm"], c.norm_eps)
         last = jnp.take_along_axis(
             x, (lengths - 1)[:, None, None].clip(0), axis=1).squeeze(1)
-        logits = (last @ params["lm_head"]).astype(jnp.float32)
+        logits = _logits(c, params, last)
     starts = jnp.zeros((B,), jnp.int32)
     with jax.named_scope("kv_write"):
         pool_k = write_rows(pool_k, ks[:nA], block_tables, starts, real)
@@ -628,7 +804,7 @@ def hybrid_decode_chunk_paged(
     nA, nB = c.attn_layers, len(c.blocks)
     KhD = c.kv_heads * c.head_dim
     G = c.heads // c.kv_heads
-    scale = 1.0 / math.sqrt(c.head_dim)
+    scale = c.attn_scale
     adv = active.astype(jnp.int32)
     pen = sample_extras is not None
     counts0 = sample_extras[2] if pen else None
@@ -639,7 +815,7 @@ def hybrid_decode_chunk_paged(
             at = lambda t: jax.lax.dynamic_index_in_dim(t, a, keepdims=False)  # noqa: E731
             return _cache_partial_xla(
                 c, q, at(pool_k), at(pool_v), block_tables, base_lengths,
-                num_read_blocks,
+                num_read_blocks, scale=c.attention_scale,
             )
         return paged_attention_partial(
             q, pool_k, pool_v, a, block_tables, base_lengths,
@@ -654,11 +830,11 @@ def hybrid_decode_chunk_paged(
         with jax.named_scope("sample"):
             key, sub = jax.random.split(key)
         with jax.named_scope("embed"):
-            x = params["embed"][tokens]
+            x = _embed(c, params, tokens)
         buf_mask = jnp.arange(num_steps)[None, :] <= step_idx      # (1, K)
 
         def attention(x, a, kbuf, vbuf):
-            ap = _attn_weights(params, a)
+            ap = _layer_at(params["attn"], a)
             with jax.named_scope("attn_qkv"):
                 h = _rms_norm(x, ap["norm"], c.norm_eps)
                 q = (h @ ap["wq"]).reshape(B, c.heads, c.head_dim)
@@ -689,19 +865,24 @@ def hybrid_decode_chunk_paged(
                      jnp.sum(p_b, axis=-1).reshape(B, c.heads)),
                 ]).astype(x.dtype).reshape(B, c.heads * c.head_dim)
             with jax.named_scope("attn_out"):
-                return x + out @ ap["wo"], k, v
+                return _residual(c, x, out @ ap["wo"]), k, v
 
         def no_attention(x, a, kbuf, vbuf):
             zero = jnp.zeros((B, c.kv_heads, c.head_dim), x.dtype)
             return x, zero, zero
 
+        def mamba(mp, m, x, ssm, conv):
+            out, ssm, conv = mamba_step(
+                c, mp, _rms_norm(x, mp["norm"], c.norm_eps), ssm, conv, m,
+                active)
+            return _residual(c, x, out), ssm, conv
+
         def block(carry, xs):
             x, kbuf, vbuf, ssm, conv = carry
             mp, ep, has, a, i = xs
-            out, ssm, conv = mamba_step(
-                c, mp, _rms_norm(x, mp["norm"], c.norm_eps), ssm, conv, i,
-                active)
-            x = x + out
+            _, (x, ssm, conv) = _mamba_in_block(
+                c, params, mp, i, mamba, lambda *through: through, x, ssm,
+                conv)
             x, k, v = jax.lax.cond(
                 has, attention, no_attention, x, a, kbuf, vbuf)
             with jax.named_scope("attn_qkv"):
@@ -711,13 +892,14 @@ def hybrid_decode_chunk_paged(
                     vbuf, v[None, :, None], (a, 0, step_idx, 0, 0))
             out, load_i, chosen = moe_mixer(
                 c, ep, _rms_norm(x, ep["norm"], c.norm_eps), active)
-            return (x + out, kbuf, vbuf, ssm, conv), (load_i, chosen)
+            return (_residual(c, x, out), kbuf, vbuf, ssm, conv), \
+                (load_i, chosen)
 
         (x, kbuf, vbuf, ssm, conv), (load_step, chosen) = jax.lax.scan(
             block, (x, kbuf, vbuf, ssm, conv), block_xs)
         with jax.named_scope("lm_head"):
             x = _rms_norm(x, params["final_norm"], c.norm_eps)
-            logits = (x @ params["lm_head"]).astype(jnp.float32)
+            logits = _logits(c, params, x)
         with jax.named_scope("sample"):
             nxt, lp_ = (sample_fn(logits, sub, counts) if pen
                         else sample_fn(logits, sub))

@@ -623,6 +623,7 @@ def _cache_partial_xla(
     block_tables: jax.Array,  # (B, max_blocks)
     lengths: jax.Array,       # (B,)
     num_read_blocks: int,
+    scale: float | None = None,   # None: 1/sqrt(head_dim)
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Reference paged read: gather the window densely, compute partial
     softmax stats. Works on every backend and under pjit meshes (gathers
@@ -636,7 +637,8 @@ def _cache_partial_xla(
     W = (kw["s"] if isinstance(kw, dict) else kw).shape[1]
     G = c.heads // c.kv_heads
     qg = q.reshape(B, c.kv_heads, G, c.head_dim)
-    s = cache_scores(qg, kw) / math.sqrt(c.head_dim)
+    s = cache_scores(qg, kw)
+    s = s / math.sqrt(c.head_dim) if scale is None else s * scale
     mask = (jnp.arange(W)[None, :] < lengths[:, None])[:, None, None, :]
     s = jnp.where(mask, s, NEG_INF)
     m = jnp.max(s, axis=-1)                                   # (B, Kh, G)

@@ -388,6 +388,24 @@ DENSE_ROWS_MAX = 512
 GROUP_BLOCK_ROWS = 256
 
 
+def _rounded_to(dtype):
+    """Identity for float32; below it (a control of the reference check,
+    never served) a rounding to ``dtype`` that stays float32. Not a pair of
+    converts: XLA keeps the excess precision of those."""
+    if jnp.dtype(dtype) == jnp.float32:
+        return lambda x: x
+    info = jnp.finfo(dtype)
+    return lambda x: jax.lax.reduce_precision(x, info.nexp, info.nmant)
+
+
+def _router_logits(h: jax.Array, router: jax.Array, rounded) -> jax.Array:
+    f32 = jnp.float32
+    return rounded(jnp.dot(
+        rounded(h.astype(f32)), rounded(router.astype(f32)),
+        precision=jax.lax.Precision.HIGHEST,
+    ))
+
+
 def sigmoid_topk_routing(
     h: jax.Array,          # (T, H)
     router: jax.Array,     # (H, E) float32
@@ -401,32 +419,55 @@ def sigmoid_topk_routing(
     chosen scores (without the bias) normalised to sum 1 and scaled.
     ``dtype`` below float32 (a control of the reference check, never
     served) rounds the operands, the logits and the scores to it."""
-    f32 = jnp.float32
-    if jnp.dtype(dtype) == f32:
-        rounded = lambda x: x                                 # noqa: E731
-    else:
-        # not a pair of converts: XLA keeps the excess precision of those
-        info = jnp.finfo(dtype)
-        rounded = lambda x: jax.lax.reduce_precision(         # noqa: E731
-            x, info.nexp, info.nmant)
-    scores = rounded(jax.nn.sigmoid(rounded(jnp.dot(
-        rounded(h.astype(f32)), rounded(router.astype(f32)),
-        precision=jax.lax.Precision.HIGHEST,
-    ))))
+    rounded = _rounded_to(dtype)
+    scores = rounded(jax.nn.sigmoid(_router_logits(h, router, rounded)))
     _, experts = jax.lax.top_k(scores + bias.astype(jnp.float32), k)
     chosen = jnp.take_along_axis(scores, experts, axis=-1)
     weights = chosen / (chosen.sum(axis=-1, keepdims=True) + 1e-20) * scale
     return experts.astype(jnp.int32), weights
 
 
-def relu2_experts_dense(
+def softmax_topk_routing(
+    h: jax.Array,          # (T, H)
+    router: jax.Array,     # (H, E), the model's type
+    k: int,
+    dtype=jnp.float32,
+) -> tuple[jax.Array, jax.Array]:
+    """``(experts (T, k) int32, weights (T, k) float32)``: float32 logits
+    over all experts, the top ``k`` LOGITS chosen, and the softmax taken over
+    those ``k`` alone (so they sum to 1 wherever the winners live): no bias,
+    no scale. ``dtype`` as in :func:`sigmoid_topk_routing`."""
+    rounded = _rounded_to(dtype)
+    top, experts = jax.lax.top_k(_router_logits(h, router, rounded), k)
+    return experts.astype(jnp.int32), rounded(jax.nn.softmax(top, axis=-1))
+
+
+def relu2(up: jax.Array) -> jax.Array:
+    """``relu(x W_up)^2``: the up-projection is ``(.., I)``."""
+    return jnp.square(jax.nn.relu(up))
+
+
+def silu_gated(up: jax.Array) -> jax.Array:
+    """``silu(a) * b`` with ``[a | b] = x W_in``: the input projection is
+    ``(.., 2 I)``, both halves one matmul."""
+    a, b = jnp.split(up, 2, axis=-1)
+    return jax.nn.silu(a) * b
+
+
+#: an expert's activation by its published name: what stands between the
+#: input projection ``w_up (held, I or 2 I, H)`` and ``w_down (held, I, H)``
+EXPERT_ACTS = {"relu2": relu2, "silu_gated": silu_gated}
+
+
+def dropless_experts_dense(
     x: jax.Array,          # (T, H)
     experts: jax.Array,    # (T, k) global expert ids
     weights: jax.Array,    # (T, k) float32
-    w_up: jax.Array,       # (held, I, H): output-major, contracts H
+    w_up: jax.Array,       # (held, I or 2 I, H): output-major, contracts H
     w_down: jax.Array,     # (held, I, H)
     first: int,
     valid: jax.Array | None = None,   # (T,) rows that count
+    act=relu2,
 ) -> tuple[jax.Array, jax.Array]:
     """Every held expert over every row, combined with the routing weight
     (zero for a pair that was not chosen): exact, and free of any
@@ -441,25 +482,24 @@ def relu2_experts_dense(
         load = hit.sum(axis=(0, 1)).astype(jnp.int32)
     with jax.named_scope("moe_experts"):
         up = jnp.einsum("th,eih->eti", x, w_up)
-        act = jnp.square(jax.nn.relu(up))
-        down = jnp.einsum("eti,eih->eth", act, w_down)
+        down = jnp.einsum("eti,eih->eth", act(up), w_down)
     with jax.named_scope("moe_combine"):
         out = jnp.einsum("eth,te->th", down.astype(jnp.float32), combine)
     return out, load
 
 
-def relu2_experts_grouped(
+def dropless_experts_grouped(
     x: jax.Array, experts: jax.Array, weights: jax.Array,
     w_up: jax.Array, w_down: jax.Array, first: int,
     valid: jax.Array | None = None, block_rows: int = GROUP_BLOCK_ROWS,
-    layer: jax.Array | None = None,
+    layer: jax.Array | None = None, act=relu2,
 ) -> tuple[jax.Array, jax.Array]:
     """The chosen pairs sorted by held expert, each expert's run padded to
     whole blocks of ``block_rows``, and one block a step: gather its rows,
-    the expert's two matmuls, scale by the routing weight, scatter-add. The
-    loop runs as many steps as there are blocks, so the work follows the
-    pairs routed here and not the worst case. Same results as
-    :func:`relu2_experts_dense`.
+    the expert's matmuls with ``act`` between them, scale by the routing
+    weight, scatter-add. The loop runs as many steps as there are blocks, so
+    the work follows the pairs routed here and not the worst case. Same
+    results as :func:`dropless_experts_dense`.
 
     With ``layer``, ``w_up`` and ``w_down`` are the stacks ``(layers, held,
     I, H)`` of a model that scans its layers, and a step reads
@@ -494,7 +534,7 @@ def relu2_experts_grouped(
             xb = x[row]
         with jax.named_scope("moe_experts"):
             up = jnp.einsum("th,ih->ti", xb, at(w_up, e))
-            down = jnp.dot(jnp.square(jax.nn.relu(up)), at(w_down, e))
+            down = jnp.dot(act(up), at(w_down, e))
         with jax.named_scope("moe_combine"):
             scale = jnp.where(live, pair_weight[pair], 0.0)
             return out.at[jnp.where(live, row, T)].add(
@@ -507,15 +547,17 @@ def relu2_experts_grouped(
     return out, counts
 
 
-def relu2_experts(x, experts, weights, w_up, w_down, first, valid=None,
-                  layer=None):
-    """Dropless routed experts (non-gated relu-squared): the dense pass for
-    a decode batch, the grouped pass for a prefill's rows. ``w_up`` and
-    ``w_down`` are one layer's ``(held, I, H)``, or with ``layer`` the
-    stacks ``(layers, held, I, H)``."""
+def dropless_experts(x, experts, weights, w_up, w_down, first, valid=None,
+                     layer=None, act=relu2):
+    """Dropless routed experts: the dense pass for a decode batch, the
+    grouped pass for a prefill's rows. ``w_up`` and ``w_down`` are one
+    layer's ``(held, I or 2 I, H)`` and ``(held, I, H)``, or with ``layer``
+    the stacks of every layer's."""
     if x.shape[0] > DENSE_ROWS_MAX:
-        return relu2_experts_grouped(
-            x, experts, weights, w_up, w_down, first, valid, layer=layer)
+        return dropless_experts_grouped(
+            x, experts, weights, w_up, w_down, first, valid, layer=layer,
+            act=act)
     if layer is not None:
         w_up, w_down = w_up[layer], w_down[layer]
-    return relu2_experts_dense(x, experts, weights, w_up, w_down, first, valid)
+    return dropless_experts_dense(
+        x, experts, weights, w_up, w_down, first, valid, act=act)

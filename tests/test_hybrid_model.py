@@ -191,10 +191,10 @@ def test_both_passes_equal_a_loop_over_the_chosen_held_experts(routed, path, fir
     x, experts, weights, w_up, w_down = routed
     stack = lambda w: jnp.stack([jnp.zeros_like(w), jnp.asarray(w)])  # noqa: E731
     fn = {
-        "dense": moe.relu2_experts_dense,
-        "grouped": lambda *a: moe.relu2_experts_grouped(*a, block_rows=64),
+        "dense": moe.dropless_experts_dense,
+        "grouped": lambda *a: moe.dropless_experts_grouped(*a, block_rows=64),
         # the experts' weights as layer 1 of a stack of two
-        "grouped-stacked": lambda x, e, w, up, down, f: moe.relu2_experts_grouped(
+        "grouped-stacked": lambda x, e, w, up, down, f: moe.dropless_experts_grouped(
             x, e, w, stack(up), stack(down), f, block_rows=64, layer=1),
     }[path]
     out, load = jax.jit(fn, static_argnums=5)(
@@ -211,9 +211,9 @@ def test_a_row_does_not_depend_on_its_batch_neighbours(routed):
     x, experts, weights, w_up, w_down = routed
     args = lambda rows: map(jnp.asarray, (  # noqa: E731
         x[rows], experts[rows], weights[rows], w_up, w_down))
-    full, _ = moe.relu2_experts_grouped(*args(slice(None)), 4, block_rows=64)
-    few, _ = moe.relu2_experts_grouped(*args(slice(100, 420)), 4, block_rows=64)
-    one, _ = moe.relu2_experts_dense(*args(slice(123, 124)), 4)
+    full, _ = moe.dropless_experts_grouped(*args(slice(None)), 4, block_rows=64)
+    few, _ = moe.dropless_experts_grouped(*args(slice(100, 420)), 4, block_rows=64)
+    one, _ = moe.dropless_experts_dense(*args(slice(123, 124)), 4)
     np.testing.assert_allclose(few, full[100:420], rtol=1e-5, atol=1e-6)
     np.testing.assert_allclose(one[0], full[123], rtol=1e-5, atol=1e-6)
 
@@ -221,7 +221,7 @@ def test_a_row_does_not_depend_on_its_batch_neighbours(routed):
 def test_rows_that_do_not_count_route_nowhere(routed):
     x, experts, weights, w_up, w_down = routed
     valid = np.arange(600) % 3 != 0
-    for fn in (moe.relu2_experts_dense, moe.relu2_experts_grouped):
+    for fn in (moe.dropless_experts_dense, moe.dropless_experts_grouped):
         out, load = fn(*map(jnp.asarray, (x, experts, weights, w_up, w_down)),
                        0, jnp.asarray(valid))
         assert not np.asarray(out)[~valid].any()

@@ -1,13 +1,16 @@
-"""The hybrid model at its published widths on the chip, without the
+"""A hybrid model (``nemotron-3-nano-30b-a3b-ep8`` or, with ``--config``,
+``granite-4.0-h-small-ep2``) at its published widths on the chip, without the
 benchmark's harness around it: builds the engine from a configuration's
-``serving`` block, runs the reference check as the file states it and again
+``serving`` block, runs the reference check as the file states it (the
+reference the file names) and again
 with the recurrent state, then the router, kept in bfloat16 (the readings
 the file's ``state_rms_share`` and ``first_routing_differing_share`` have to lie
 under), then a wave of long prompts and a full batch of decodes with the
 device's memory after each.
 
-    chiprun -- python3 tools/hybrid_probe.py [--config <file>] [--seeds n ...]
-        [--controls n] [--faults] [--crossover] [--checks-only] [--trace 1]
+    chiprun -- python3 tools/hybrid_probe.py [--config <name or file>]
+        [--seeds n ...] [--controls n] [--faults] [--crossover]
+        [--checks-only] [--trace 1]
 
 Refuses to run off a TPU. Prints one JSON line last."""
 
@@ -16,6 +19,7 @@ from __future__ import annotations
 import argparse
 import asyncio
 import dataclasses
+import importlib
 import json
 import os
 import sys
@@ -46,16 +50,22 @@ def crossover(engine) -> list[dict]:
 
     c, lp = engine.model_config, engine.params["moe"]
     w_up, w_down = lp["w_up"][0], lp["w_down"][0]
+    act = moe.EXPERT_ACTS[c.expert_act]
     rows_out = []
-    for rows in (64, 128, 256, 384, 512, 768, 1024, 2048):
+    for rows in (64, 96, 128, 256, 384, 512, 768, 1024, 2048):
         h = jax.random.normal(jax.random.PRNGKey(rows), (rows, c.hidden), c.dtype)
-        experts, weights = moe.sigmoid_topk_routing(
-            h, lp["router"][0], lp["bias"][0], c.experts_per_token, c.routed_scale)
+        if c.router == "sigmoid":
+            experts, weights = moe.sigmoid_topk_routing(
+                h, lp["router"][0], lp["bias"][0], c.experts_per_token,
+                c.routed_scale)
+        else:
+            experts, weights = moe.softmax_topk_routing(
+                h, lp["router"][0], c.experts_per_token)
         row = {"rows": rows}
-        for name, fn in (("dense", moe.relu2_experts_dense),
-                         ("grouped", moe.relu2_experts_grouped)):
+        for name, fn in (("dense", moe.dropless_experts_dense),
+                         ("grouped", moe.dropless_experts_grouped)):
             call = jax.jit(lambda h, e, w, fn=fn: fn(
-                h, e, w, w_up, w_down, c.expert_first)[0])
+                h, e, w, w_up, w_down, c.expert_first, act=act)[0])
             call(h, experts, weights).block_until_ready()
             t = time.monotonic()
             for _ in range(10):
@@ -73,10 +83,9 @@ async def run(args) -> dict:
     import numpy as np
 
     from langstream_tpu.serving.engine import ServingConfig, TpuServingEngine
-    from reference import hybrid_ssm_moe as reference
-
     with open(args.config) as f:
         config = json.load(f)
+    reference = importlib.import_module(f"reference.{config['reference']}")
     out: dict = {"device": jax.devices()[0].device_kind}
     t = time.monotonic()
     engine = TpuServingEngine(ServingConfig.from_dict(config["serving"]))
@@ -117,7 +126,7 @@ async def run(args) -> dict:
         for fault in reference.FAULTS:
             report = await asyncio.to_thread(
                 reference.judge, engine, got, tolerance, (fault,))
-            row = {k: report[k] for k in (
+            row = {k: report.get(k) for k in (
                 "passed", "worst_rms_share", "worst_correlation",
                 "first_state_rms_share", "worst_routing_shortfall",
                 "first_routing_shortfall", "first_routing_differing_share",
@@ -188,8 +197,9 @@ async def run(args) -> dict:
 
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
-    ap.add_argument("--config", default=os.path.join(
-        ROOT, "bench", "configs", "nemotron-3-nano-30b-a3b-ep8.json"))
+    ap.add_argument("--config", default="nemotron-3-nano-30b-a3b-ep8",
+                    help="a hybrid configuration: a file, or the name of one "
+                         "of bench/configs")
     ap.add_argument("--seeds", type=int, nargs="+", default=[2 ** 31 + 17])
     ap.add_argument("--trace", type=int, default=0)
     ap.add_argument("--faults", action="store_true",
@@ -204,6 +214,9 @@ def main() -> int:
     ap.add_argument("--checks-only", action="store_true",
                     help="stop after the reference checks")
     args = ap.parse_args()
+    if not os.path.exists(args.config):
+        args.config = os.path.join(
+            ROOT, "bench", "configs", f"{args.config}.json")
     from langstream_tpu.compile_cache import configure_compile_cache
 
     configure_compile_cache()
