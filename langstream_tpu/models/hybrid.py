@@ -70,6 +70,7 @@ from langstream_tpu.ops.paged_attention import (
     merge_partial_attention,
     paged_attention_partial,
 )
+from langstream_tpu.ops.ssm_state import ssm_state_step
 
 NEMOTRON3_NANO_PATTERN = "MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*EMEMEMEME"
 _BLOCK = r"(?:M\*?|\*)E"     # at least one mixer, then the experts
@@ -495,15 +496,18 @@ def mamba_prefill(c: HybridConfig, lp: dict, u: jax.Array, lengths: jax.Array):
 
 
 def mamba_step(c: HybridConfig, lp: dict, u: jax.Array, ssm: jax.Array,
-               conv: jax.Array, i: jax.Array, active: jax.Array):
+               conv: jax.Array, i: jax.Array, active: jax.Array,
+               kernel: str = "xla"):
     """One token a slot through Mamba-2 layer ``i``: ``u (B, H)`` normed,
     ``ssm (layers, B, heads, head_dim, state)`` and ``conv (layers, B,
     kernel - 1, conv_dim)`` the stacked state of every layer, of which this
     one's rows are read and replaced in place (under the scopes, so that a
     trace charges the state's traffic to ``ssm_scan`` and the tail's to
-    ``ssm_conv``). A slot that is not active keeps its rows."""
+    ``ssm_conv``). A slot that is not active keeps its rows. ``kernel`` is
+    the decode program's one selection (``"xla"``, ``"pallas"``,
+    ``"pallas-interpret"``): how the state's pass is lowered
+    (:func:`langstream_tpu.ops.ssm_state.ssm_state_step`)."""
     B = u.shape[0]
-    j = c.ssm_heads // c.ssm_groups
     with jax.named_scope("ssm_in"):
         z, xbc, dt = _project_in(lp, u)
     with jax.named_scope("ssm_conv"):
@@ -518,19 +522,14 @@ def mamba_step(c: HybridConfig, lp: dict, u: jax.Array, ssm: jax.Array,
             conv, jnp.where(active[:, None, None], window[:, 1:], tail), i, 0)
     with jax.named_scope("ssm_scan"):
         f32 = jnp.float32
-        state = jax.lax.dynamic_index_in_dim(ssm, i, keepdims=False)
         dt = jax.nn.softplus(dt.astype(f32) + lp["dt_bias"])     # (B, heads)
         decay = jnp.exp(dt * -jnp.exp(lp["A_log"]))
         xf = x.astype(f32)
-        Bh = jnp.repeat(Bm.astype(f32), j, axis=1)               # (B, heads, n)
-        Ch = jnp.repeat(Cm.astype(f32), j, axis=1)
-        new = (state.astype(f32) * decay[..., None, None]
-               + (dt[..., None] * xf)[..., None] * Bh[:, :, None, :])
-        y = jnp.einsum("bhpn,bhn->bhp", new, Ch) + lp["D"][:, None] * xf
-        ssm = jax.lax.dynamic_update_index_in_dim(
-            ssm,
-            jnp.where(active[:, None, None, None], new.astype(ssm.dtype), state),
-            i, 0)
+        # new = state * decay + (dt x) B^T; y = new C; the rows replaced
+        y, ssm = ssm_state_step(
+            ssm, i, decay, dt[..., None] * xf, Bm.astype(f32), Cm.astype(f32),
+            active, kernel=kernel)
+        y = y + lp["D"][:, None] * xf
     with jax.named_scope("ssm_out"):
         out = _gated_out(c, lp, y.reshape(B, c.d_inner), z)
     return out, ssm, conv
@@ -874,7 +873,7 @@ def hybrid_decode_chunk_paged(
         def mamba(mp, m, x, ssm, conv):
             out, ssm, conv = mamba_step(
                 c, mp, _rms_norm(x, mp["norm"], c.norm_eps), ssm, conv, m,
-                active)
+                active, kernel)
             return _residual(c, x, out), ssm, conv
 
         def block(carry, xs):

@@ -4,6 +4,9 @@
   (prefill/forward): O(S) memory instead of the O(S²) score matrix.
 - :mod:`langstream_tpu.ops.paged_attention` — paged decode reads (bf16 and
   int8 pools) and the multi-query history read of continuation/verify.
+- :mod:`langstream_tpu.ops.ssm_state` — one decode step of a Mamba-2
+  layer's recurrent state, on the stacked state in place: one pass over it
+  where the XLA expression makes three.
 - :mod:`langstream_tpu.ops.selfcheck` — builds every kernel above at a
   served model's shapes and compares it with the XLA read it replaces.
 

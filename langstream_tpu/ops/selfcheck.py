@@ -15,6 +15,8 @@ f32 scales), outputs are O(1) softmax averages of unit-normal values, and
 the kernels accumulate in f32 over blocks where XLA reduces over the whole
 window — 3e-2 absolute covers the bf16 probability rounding on both sides
 (the interpreted CPU tests see ~3e-2 on O(1–4) outputs, tests/test_paged.py).
+The Mamba-2 state's pass (:func:`check_state_kernel`) is float32 on both
+sides: its row compares at 1e-4 of the expression's largest value.
 """
 
 from __future__ import annotations
@@ -27,6 +29,7 @@ import jax.numpy as jnp
 import numpy as np
 
 TOLERANCE = 3e-2
+STATE_TOLERANCE = 1e-4
 
 
 def _int8_pool(key, shape_rows: tuple, kv_heads: int, head_dim: int) -> dict:
@@ -57,10 +60,11 @@ def _tables_and_lengths(batch: int, read_blocks: int, block_size: int, nb: int):
 
 
 def _row(kernel: str, shape: dict, interpret: bool,
-         run: Callable[[], tuple[Any, Any]]) -> dict[str, Any]:
+         run: Callable[[], tuple[Any, Any]],
+         tol: float = TOLERANCE) -> dict[str, Any]:
     row: dict[str, Any] = {
         "kernel": kernel, "shape": shape, "interpret": interpret,
-        "tol": TOLERANCE,
+        "tol": tol,
     }
     try:
         got, ref = run()
@@ -72,10 +76,48 @@ def _row(kernel: str, shape: dict, interpret: bool,
         return row
     err = float(np.max(np.abs(got - ref)))
     row.update(
-        ok=bool(np.isfinite(got).all() and err <= TOLERANCE),
-        max_abs_err=round(err, 5),
+        ok=bool(np.isfinite(got).all() and err <= tol),
+        max_abs_err=float(f"{err:.3g}"),
     )
     return row
+
+
+def check_state_kernel(model_config, *, slots: int,
+                       interpret: bool = False) -> dict[str, Any]:
+    """One row: the Mamba-2 state's decode step (``ops/ssm_state.py``) at a
+    hybrid model's heads, groups and tile, on a stack of two layers of
+    ``slots`` slots of which the second layer is advanced and one slot is
+    idle, against the XLA expression; the output ``y`` and the stack are
+    each compared as shares of the expression's largest value."""
+    from langstream_tpu.ops.ssm_state import ssm_state_step
+
+    c = model_config
+    heads, P, N, G = c.ssm_heads, c.ssm_head_dim, c.ssm_state, c.ssm_groups
+    ks = jax.random.split(jax.random.PRNGKey(22), 5)
+    ssm = jax.random.normal(ks[0], (2, slots, heads, P, N)).astype(c.state_dtype)
+    operands = (
+        jax.random.uniform(ks[1], (slots, heads), jnp.float32, 0.5, 1.0),
+        jax.random.normal(ks[2], (slots, heads, P), jnp.float32),
+        jax.random.normal(ks[3], (slots, G, N), jnp.float32),
+        jax.random.normal(ks[4], (slots, G, N), jnp.float32),
+        jnp.arange(slots) != 1,
+    )
+
+    def run():
+        got, ref = (
+            jax.jit(lambda s, *a, kernel=kernel: ssm_state_step(
+                s, 1, *a, kernel=kernel))(ssm, *operands)
+            for kernel in ("pallas-interpret" if interpret else "pallas", "xla"))
+        scales = [jnp.max(jnp.abs(r.astype(jnp.float32))) for r in ref]
+        flat = lambda out: jnp.concatenate([  # noqa: E731
+            (r.astype(jnp.float32) / s).ravel() for r, s in zip(out, scales)])
+        return flat(got), flat(ref)
+
+    return _row(
+        "_ssm_state_kernel",
+        {"layers": 2, "slots": slots, "heads": heads, "head_dim": P,
+         "state": N, "groups": G},
+        interpret, run, tol=STATE_TOLERANCE)
 
 
 def check_kernels(

@@ -1805,6 +1805,10 @@ class TpuServingEngine:
                 f"paged_kernel=xla (or auto) for sharded int8 pools"
             )
         self.paged_read_kernel = kernel
+        # the decode program's other kernel, the Mamba-2 state's pass
+        # (ops/ssm_state.py), follows the same selection; the dense
+        # family has no such state
+        self.ssm_state_kernel = kernel if self.is_hybrid else None
         # continuation prefill / speculative verify read history
         # through the multi-query kernel, which has no int8 twin:
         # int8 pools take the XLA history sweep there, by selection
@@ -3300,6 +3304,9 @@ class TpuServingEngine:
             "steps": dict(self.flight.steps_by_phase),
             # the share of prefill batches dispatched one ahead (_admit)
             "prefill_ahead_share": self.flight.prefill_ahead_share,
+            # how a decode step's pass over the Mamba-2 state is lowered
+            # (what mamba_step was handed; None without such state)
+            "ssm_state_kernel": self.ssm_state_kernel,
             # watchdog verdict + warmup/readiness posture (serving/health.py)
             "health": self.health(),
             # drain-before-terminate posture + last drain's counts

@@ -292,6 +292,15 @@ def test_prefill_then_paged_decode_is_the_reference_s_forward(
     assert not np.asarray(state["ssm"])[:, 1].any()     # the idle slot
 
 
+def test_a_decode_chunk_through_the_kernels_is_the_xla_chunk(c, params):
+    """The mixer under ``lax.cond`` (a block here may lack it): the stacked
+    state goes through the conditional into the kernel and out, in place."""
+    from test_hybrid_model import assert_a_chunk_is_the_same_through_the_kernels
+
+    assert not all(c.mamba_blocks)
+    assert_a_chunk_is_the_same_through_the_kernels(c, params)
+
+
 @pytest.mark.parametrize("fault", [
     "softmax_then_top_k", "residual_multiplier_one", "attention_scale_rsqrt",
     "no_logits_scaling", "no_shared_expert", "no_embedding_multiplier"])

@@ -83,6 +83,40 @@ def test_concurrent_slots_of_unequal_length_stream_what_each_streams_alone(
     assert owners["recurrent-state"] == 4 * per_slot
 
 
+@pytest.mark.parametrize("selected, handed", [
+    ("auto", "xla"), ("pallas-interpret", "pallas-interpret")])
+def test_the_state_s_pass_follows_the_engine_s_one_kernel_selection(
+        run_async, alone, selected, handed):
+    """``stats()`` reports what ``mamba_step`` was handed, and the streams
+    through the interpreted kernels are the XLA ones."""
+    async def main():
+        engine = TpuServingEngine(config(paged_kernel=selected))
+        try:
+            outs = await asyncio.gather(
+                *(engine.generate(PROMPTS[i], greedy()) for i in (1, 3, 0)))
+            return ([o["tokens"] for o in outs], engine.stats(),
+                    engine.paged_read_kernel)
+        finally:
+            await engine.close()
+
+    streams, stats, read = run_async(main())
+    assert stats["ssm_state_kernel"] == read == handed
+    assert streams == [alone[1], alone[3], alone[0]]
+
+
+def test_a_family_without_the_state_reports_no_kernel_for_it(run_async):
+    async def main():
+        engine = TpuServingEngine(ServingConfig(
+            model="tiny", model_dtype="float32", slots=2, max_seq_len=64,
+            prefix_cache=False))
+        try:
+            return engine.stats()["ssm_state_kernel"], engine.paged_read_kernel
+        finally:
+            await engine.close()
+
+    assert run_async(main()) == (None, "xla")
+
+
 def test_a_reused_slot_leaks_no_state(run_async, alone):
     """One slot: every request runs in the rows the last one left."""
     async def main():
@@ -249,3 +283,4 @@ def test_the_lowered_programs_carry_the_hybrid_scopes(run_async):
     for scope in scopes:
         assert re.search(rf'[/"]{scope}/', text), scope
     assert "kv_read/paged_read" in text
+    assert "ssm_scan/ssm_state_step" in text

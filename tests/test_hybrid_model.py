@@ -104,14 +104,15 @@ def test_prefill_then_steps_is_one_long_prefill(c, params):
     np.testing.assert_allclose(tail[0], tail_w, rtol=1e-5, atol=1e-6)
 
 
-def test_an_idle_slot_keeps_its_state(c, params):
+@pytest.mark.parametrize("kernel", ["xla", "pallas-interpret"])
+def test_an_idle_slot_keeps_its_state(c, params, kernel):
     lp = layer(params["mamba"], 0)
     rng = np.random.default_rng(6)
     state = jnp.asarray(rng.normal(size=(3, 2, 8, 8, 16)), jnp.float32)
     tail = jnp.asarray(rng.normal(size=(3, 2, 3, c.conv_dim)), jnp.float32)
     u = jnp.asarray(rng.normal(size=(2, c.hidden)), jnp.float32)
     _, new_state, new_tail = mamba_step(
-        c, lp, u, state, tail, 1, jnp.asarray([True, False]))
+        c, lp, u, state, tail, 1, jnp.asarray([True, False]), kernel)
     assert not np.allclose(new_state[1, 0], state[1, 0])
     np.testing.assert_array_equal(new_state[1, 1], state[1, 1])
     np.testing.assert_array_equal(new_tail[1, 1], tail[1, 1])
@@ -133,6 +134,51 @@ def prefill(c, params, rows, bucket, slots=4):
         jnp.asarray([len(r) for r in rows], jnp.int32), pool_k, pool_v,
         init_hybrid_state(c, slots), jnp.asarray(tables),
         jnp.arange(len(rows), dtype=jnp.int32))
+
+
+def decode_chunk_by(c, params, kernel, steps=6):
+    """Three prompts prefilled, then one chunk of ``steps`` greedy decode
+    steps with the fourth slot idle, the decode program's kernels (the paged
+    read and the state's pass) lowered as ``kernel`` says. Returns ``(tokens
+    (steps, slots), log-probabilities, the state before, the state after)``."""
+    from langstream_tpu.models.hybrid import hybrid_decode_chunk_paged
+
+    rng = np.random.default_rng(11)
+    rows = [rng.integers(0, c.vocab_size, size=n) for n in (21, 32, 9)]
+    logits, pool_k, pool_v, state, _ = prefill(c, params, rows, 32)
+    tables = np.zeros((4, 16), np.int32)
+    tables[:3] = 1 + np.arange(48).reshape(3, 16)
+    first = np.zeros(4, np.int32)
+    first[:3] = np.asarray(logits).argmax(-1)
+    lengths = np.asarray([21, 32, 9, 0], np.int32)
+    out = jax.jit(lambda pk, pv, st: hybrid_decode_chunk_paged(
+        c, params, jnp.asarray(first), jnp.asarray(lengths),
+        jnp.asarray(lengths > 0), pk, pv, st, jnp.asarray(tables),
+        lambda lg, key: (jnp.argmax(lg, -1).astype(jnp.int32),
+                         jnp.max(jax.nn.log_softmax(lg), -1)),
+        jax.random.PRNGKey(0), steps, num_read_blocks=3, kernel=kernel))(
+        pool_k, pool_v, state)
+    return np.asarray(out[0]), np.asarray(out[1]), state, out[6]
+
+
+def assert_a_chunk_is_the_same_through_the_kernels(c, params):
+    """``kernel="pallas-interpret"`` against ``kernel="xla"`` over a chunk:
+    the scan's carry takes the kernel's aliased output step after step."""
+    tokens, lps, before, after = decode_chunk_by(c, params, "xla")
+    tokens_k, lps_k, _, after_k = decode_chunk_by(c, params, "pallas-interpret")
+    np.testing.assert_array_equal(tokens_k, tokens)
+    np.testing.assert_allclose(lps_k, lps, rtol=2e-4, atol=2e-5)
+    for name in ("ssm", "conv"):
+        np.testing.assert_allclose(after_k[name], after[name], rtol=2e-4, atol=2e-5)
+        # every live slot's rows of every Mamba-2 layer moved; the idle
+        # slot's did not
+        assert not np.allclose(after_k[name][:, :3], before[name][:, :3])
+        np.testing.assert_array_equal(after_k[name][:, 3], before[name][:, 3])
+
+
+def test_a_decode_chunk_through_the_kernels_is_the_xla_chunk(c, params):
+    assert all(c.mamba_blocks)              # the mixer in every block
+    assert_a_chunk_is_the_same_through_the_kernels(c, params)
 
 
 def test_a_padded_bucket_and_batch_neighbours_change_nothing(c, params):

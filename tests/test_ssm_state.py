@@ -1,0 +1,212 @@
+"""The Mamba-2 state's decode step as a Pallas kernel (ops/ssm_state.py),
+through the interpreter, against the XLA expression it replaces
+(``kernel="xla"``): the same float32 arithmetic on the stacked state in
+place. A slot that is not active keeps its rows bit for bit, the other
+layers' rows are not written, and the tile follows the state's shape."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from langstream_tpu.models.hybrid import HybridConfig
+from langstream_tpu.ops import ssm_state
+from langstream_tpu.ops.ssm_state import ssm_state_step, tile_heads
+
+TINY, GRANITE_TINY = HybridConfig.tiny(), HybridConfig.granite_tiny()
+
+#: layers, slots, heads, head_dim, state, groups
+SHAPES = {
+    "hybrid-tiny": (TINY.mamba_layers, 3, TINY.ssm_heads, TINY.ssm_head_dim,
+                    TINY.ssm_state, TINY.ssm_groups),
+    "granite-tiny": (GRANITE_TINY.mamba_layers, 3, GRANITE_TINY.ssm_heads,
+                     GRANITE_TINY.ssm_head_dim, GRANITE_TINY.ssm_state,
+                     GRANITE_TINY.ssm_groups),
+    # the served tiles: (64, 128) a head, eight heads a group or all in one
+    "heads64-groups8": (3, 2, 64, 64, 128, 8),
+    "heads128-groups1": (3, 4, 128, 64, 128, 1),
+}
+
+
+def operands(shape, dtype=jnp.float32, idle=(1,)):
+    L, B, heads, P, N, G = shape
+    ks = jax.random.split(jax.random.PRNGKey(L * heads + B), 5)
+    ssm = jax.random.normal(ks[0], (L, B, heads, P, N), jnp.float32).astype(dtype)
+    decay = jax.random.uniform(ks[1], (B, heads), jnp.float32, 0.3, 1.0)
+    dtx = jax.random.normal(ks[2], (B, heads, P), jnp.float32)
+    Bm = jax.random.normal(ks[3], (B, G, N), jnp.float32)
+    Cm = jax.random.normal(ks[4], (B, G, N), jnp.float32)
+    active = jnp.asarray([b not in idle for b in range(B)])
+    return ssm, decay, dtx, Bm, Cm, active
+
+
+def step(kernel, ssm, layer, *rest):
+    return jax.jit(lambda s, i, *a: ssm_state_step(s, i, *a, kernel=kernel))(
+        ssm, jnp.asarray(layer, jnp.int32), *rest)
+
+
+@pytest.mark.parametrize("where", ["first", "middle", "last"])
+@pytest.mark.parametrize("name", list(SHAPES))
+def test_the_kernel_is_the_xla_expression_in_place(name, where):
+    shape = SHAPES[name]
+    L = shape[0]
+    layer = {"first": 0, "middle": L // 2, "last": L - 1}[where]
+    ssm, *rest = operands(shape)
+    want_y, want = step("xla", ssm, layer, *rest)
+    y, got = step("pallas-interpret", ssm, layer, *rest)
+    assert y.shape == want_y.shape and y.dtype == jnp.float32
+    assert got.shape == ssm.shape and got.dtype == ssm.dtype
+    np.testing.assert_allclose(y, want_y, rtol=2e-4, atol=2e-4)
+    np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-5)
+    got, ssm = np.asarray(got), np.asarray(ssm)
+    # the idle slot's rows of this layer, and every other layer's rows
+    assert np.array_equal(got[layer, 1], ssm[layer, 1])
+    assert np.array_equal(np.delete(got, layer, 0), np.delete(ssm, layer, 0))
+    assert not np.array_equal(got[layer, 0], ssm[layer, 0])
+
+
+@pytest.mark.parametrize("name", ["hybrid-tiny", "heads64-groups8"])
+def test_a_bfloat16_state_is_rounded_once_as_the_expression_rounds_it(name):
+    """``state_dtype`` may be bfloat16 (the reference check's control): the
+    arithmetic stays float32 and the rows are rounded as they are stored."""
+    ssm, *rest = operands(SHAPES[name], jnp.bfloat16)
+    want_y, want = step("xla", ssm, 1, *rest)
+    y, got = step("pallas-interpret", ssm, 1, *rest)
+    assert got.dtype == jnp.bfloat16
+    np.testing.assert_allclose(y, want_y, rtol=2e-4, atol=2e-4)
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32), rtol=1e-2, atol=1e-2)
+    assert np.array_equal(np.asarray(got[1, 1]), np.asarray(ssm[1, 1]))
+
+
+def test_every_slot_idle_leaves_the_stack_as_it_was():
+    shape = SHAPES["hybrid-tiny"]
+    ssm, *rest, _ = operands(shape)
+    idle = jnp.zeros((shape[1],), bool)
+    want_y, _ = step("xla", ssm, 0, *rest, idle)
+    y, got = step("pallas-interpret", ssm, 0, *rest, idle)
+    assert np.array_equal(np.asarray(got), np.asarray(ssm))
+    # the output is still the updated state's, as in the expression
+    np.testing.assert_allclose(y, want_y, rtol=2e-4, atol=2e-4)
+
+
+@pytest.mark.parametrize("heads, head_dim, state, itemsize, want", [
+    (64, 64, 128, 4, 64),      # nemotron_h: a slot's 2 MiB in one tile
+    (128, 64, 128, 4, 64),     # granitemoehybrid: two tiles a slot
+    (128, 64, 128, 2, 128),    # a bfloat16 state: half the bytes a head
+    (8, 8, 16, 4, 8), (16, 8, 16, 4, 16),
+    (24, 64, 256, 4, 24), (96, 64, 128, 4, 48),
+])
+def test_the_tile_follows_the_state_s_shape(heads, head_dim, state, itemsize, want):
+    got = tile_heads(heads, head_dim, state, itemsize)
+    assert got == want and heads % got == 0
+    assert got * head_dim * state * itemsize <= ssm_state.TILE_BYTES
+
+
+def test_a_slot_s_head_tiles_fill_one_panel_of_y(monkeypatch):
+    """Several tiles a slot (Granite's two at the served width): each writes
+    its own heads' columns of the slot's ``y`` and no other."""
+    shape = SHAPES["heads128-groups1"]
+    ssm, *rest = operands(shape)
+    whole_y, whole = step("pallas-interpret", ssm, 2, *rest)
+    monkeypatch.setattr(ssm_state, "TILE_BYTES", 16 * 64 * 128 * 4)
+    assert tile_heads(128, 64, 128, 4) == 16
+    y, got = step("pallas-interpret", ssm, 2, *rest)
+    assert np.array_equal(np.asarray(y), np.asarray(whole_y))
+    assert np.array_equal(np.asarray(got), np.asarray(whole))
+
+
+def test_an_unknown_selection_is_not_taken_for_the_kernel():
+    ssm, *rest = operands(SHAPES["hybrid-tiny"])
+    with pytest.raises(ValueError, match="ssm_state_step"):
+        ssm_state_step(ssm, 0, *rest, kernel="mosaic")
+
+
+
+@pytest.mark.parametrize("preset", ["tiny", "granite_tiny"])
+def test_the_selfcheck_s_row_holds_the_kernel_to_float32(preset):
+    """``ops/selfcheck.py`` compares the kernel with the expression at 1e-4
+    of the expression's largest value, not at the bfloat16 reads' 3e-2."""
+    from langstream_tpu.ops import selfcheck
+
+    c = getattr(HybridConfig, preset)()
+    row = selfcheck.check_state_kernel(c, slots=3, interpret=True)
+    assert row["kernel"] == "_ssm_state_kernel"
+    assert row["ok"] and row["interpret"], row
+    assert row["tol"] == selfcheck.STATE_TOLERANCE == 1e-4
+    assert row["max_abs_err"] < 1e-5
+    assert (row["shape"]["heads"], row["shape"]["groups"]) == (
+        c.ssm_heads, c.ssm_groups)
+
+
+@pytest.mark.parametrize("which", ["y", "state"])
+def test_a_row_that_misses_its_tolerance_is_not_ok(monkeypatch, which):
+    from langstream_tpu.ops import selfcheck
+
+    def off_by_a_thousandth(ssm, layer, decay, dtx, Bm, Cm, active, *, kernel):
+        y, new = ssm_state.ssm_state_step_xla(
+            ssm, layer, decay, dtx, Bm, Cm, active)
+        if kernel == "xla":
+            return y, new
+        return (y * 1.001, new) if which == "y" else (y, new * 1.001)
+
+    monkeypatch.setattr(ssm_state, "ssm_state_step", off_by_a_thousandth)
+    row = selfcheck.check_state_kernel(
+        HybridConfig.tiny(), slots=2, interpret=True)
+    assert not row["ok"] and 5e-4 < row["max_abs_err"] < 2e-3
+
+
+# -- the kernel through the TPU's own compiler, at the served shapes ---------
+# (no chip: a described v5e; the interpreter accepts layouts Mosaic refuses)
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler here: nothing to hold it to
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def no_compile_cache():
+    """A program compiled for a described chip is written to the persistent
+    cache and cannot be read back without one: keep it out."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    compilation_cache.reset_cache()
+
+
+@pytest.mark.parametrize("preset, slots", [
+    ("nemotron3_nano_ep8", 64), ("granite4_h_small_ep2", 96)])
+def test_mosaic_builds_the_kernel_at_the_served_shapes_in_place(
+        one_chip, no_compile_cache, preset, slots):
+    c = getattr(HybridConfig, preset)()
+    L, heads, P, N, G = (c.mamba_layers, c.ssm_heads, c.ssm_head_dim,
+                         c.ssm_state, c.ssm_groups)
+    on = lambda shape, dtype=jnp.float32: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dtype, sharding=one_chip)
+    compiled = jax.jit(
+        lambda *a: ssm_state_step(*a, kernel="pallas"), donate_argnums=(0,),
+    ).lower(
+        on((L, slots, heads, P, N), c.state_dtype), on((), jnp.int32),
+        on((slots, heads)), on((slots, heads, P)), on((slots, G, N)),
+        on((slots, G, N)), on((slots,), jnp.bool_),
+    ).compile()
+    stack = L * slots * heads * P * N * 4
+    memory = compiled.memory_analysis()
+    # the stack is the output's own buffer, and nothing of its size beside it
+    assert memory.alias_size_in_bytes >= stack
+    assert memory.temp_size_in_bytes < stack // 100
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "ssm_state_step" in text

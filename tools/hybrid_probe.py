@@ -10,8 +10,10 @@ device's memory after each.
 
     chiprun -- python3 tools/hybrid_probe.py [--config <name or file>]
         [--seeds n ...] [--controls n] [--faults] [--crossover]
-        [--checks-only] [--trace 1]
+        [--checks-only] [--no-warmup] [--trace 1]
 
+``--trace 1`` ends with the device time of a decode step by scope
+(``by_scope_ms_step``; steps counted as the benchmark's readers count them).
 Refuses to run off a TPU. Prints one JSON line last."""
 
 from __future__ import annotations
@@ -85,15 +87,22 @@ async def run(args) -> dict:
     from langstream_tpu.serving.engine import ServingConfig, TpuServingEngine
     with open(args.config) as f:
         config = json.load(f)
+    if args.no_warmup:
+        config["serving"]["warmup-on-start"] = False
     reference = importlib.import_module(f"reference.{config['reference']}")
     out: dict = {"device": jax.devices()[0].device_kind}
     t = time.monotonic()
     engine = TpuServingEngine(ServingConfig.from_dict(config["serving"]))
     out["build_s"] = round(time.monotonic() - t, 1)
     out["kernel"] = engine.paged_read_kernel
+    out["ssm_state_kernel"] = engine.stats()["ssm_state_kernel"]
     out["memory_built"] = memory("engine build")
     tolerance = config["reference_tolerance"]
     mc = engine.model_config
+    from langstream_tpu.ops.selfcheck import check_state_kernel
+
+    out["kernel_check"] = check_state_kernel(mc, slots=8)
+    print(f"[probe] kernel: {json.dumps(out['kernel_check'])[:600]}", flush=True)
     controls = (
         ("as served", None),
         # the readings the file's state_rms_share and first_routing_differing_share
@@ -168,7 +177,12 @@ async def run(args) -> dict:
         from lib import hybridtrace, xplane
 
         trace_dir = os.path.join(ROOT, "chiprun_out", "hybrid_probe_trace")
-        task = asyncio.ensure_future(wave(slots, 200, 129))
+        # long enough at either configuration that the trace, 4 s in, falls
+        # on decode chunks (96 slots' prefill and 128 steps were over by
+        # then), and once untraced first: the longer rows' decode windows
+        # compile on their first use, and a trace over a compile is empty
+        await wave(slots, 200, 257)
+        task = asyncio.ensure_future(wave(slots, 200, 257))
         await asyncio.sleep(4.0)
         await asyncio.to_thread(jax.profiler.start_trace, trace_dir)
         await asyncio.sleep(2.5)
@@ -178,7 +192,26 @@ async def run(args) -> dict:
         reduced = hybridtrace.reduce(path)
         plain = xplane.reduce(xplane.load(path), 2.5)
         runs = xplane.program(plain, "decode_chunk")
+        # the program scans the model's blocks: inside a run the most
+        # frequent op ran steps x blocks times (a run cut by an end of the
+        # trace counts the steps that ran inside it)
+        blocks = len(mc.blocks)
+        steps = sum(round(n / blocks) for n in runs["op_counts"] if n >= blocks)
+        per_step = lambda table: {  # noqa: E731
+            k: round(1e3 * v / steps, 3)
+            for k, v in sorted(table.items(), key=lambda kv: -kv[1])[:16]
+        } if steps else {}
+        # a conditional's own event spans its children's, which are counted
+        # under their scopes (the reduction does not know it for a container)
+        conds = sum(v for k, v in reduced["scopes"]["unscoped"].items()
+                    if k.startswith("cond."))
         out["trace"] = {
+            "steps": steps,
+            "step_ms": round(
+                1e3 * (reduced["scopes"]["total_s"] - conds) / steps, 3)
+            if steps else None,
+            "by_scope_ms_step": per_step(reduced["scopes"]["by_scope"]),
+            "unscoped_ms_step": per_step(reduced["scopes"]["unscoped"]),
             "by_scope_s": reduced["scopes"]["by_scope"],
             "unscoped_s": dict(sorted(reduced["scopes"]["unscoped"].items(),
                                       key=lambda kv: -kv[1])[:12]),
@@ -213,6 +246,9 @@ def main() -> int:
                     help="also time the dense and the grouped expert pass by rows")
     ap.add_argument("--checks-only", action="store_true",
                     help="stop after the reference checks")
+    ap.add_argument("--no-warmup", action="store_true",
+                    help="build the engine without its warm-up of every "
+                         "shape: each program compiles when first met")
     args = ap.parse_args()
     if not os.path.exists(args.config):
         args.config = os.path.join(
