@@ -61,6 +61,7 @@ from langstream_tpu.models.llama_paged import (
 from langstream_tpu.models.moe import (
     EXPERT_ACTS,
     dropless_experts,
+    group_limited_softmax_routing,
     sigmoid_topk_routing,
     softmax_topk_routing,
 )
@@ -540,7 +541,7 @@ def mamba_step(c: HybridConfig, lp: dict, u: jax.Array, ssm: jax.Array,
 # ---------------------------------------------------------------------------
 
 
-def moe_mixer(c: HybridConfig, lp: dict, h: jax.Array, valid: jax.Array,
+def moe_mixer(c, lp: dict, h: jax.Array, valid: jax.Array,
               layer: jax.Array | None = None):
     """Routed experts held here plus the shared expert over rows ``h (T,
     H)``; ``lp`` is one expert layer's weights, or with ``layer`` its
@@ -548,7 +549,10 @@ def moe_mixer(c: HybridConfig, lp: dict, h: jax.Array, valid: jax.Array,
     (models/moe.py ``dropless_experts_grouped``). Returns ``(out (T, H),
     load (experts_held,) int32, chosen experts (T, k))``; ``valid`` rows are
     the ones that count (padding and idle slots route nowhere). A gated
-    expert's ``silu(a) * b`` lies between its matmuls, under their scope."""
+    expert's ``silu(a) * b`` lies between its matmuls, under their scope.
+    ``c`` is this family's config or the latent family's (models/latent.py):
+    what is read of it are the router's rule and numbers, the activation and
+    the share held."""
     act = EXPERT_ACTS[c.expert_act]
     with jax.named_scope("moe_router"):
         if c.router == "sigmoid":
@@ -556,6 +560,10 @@ def moe_mixer(c: HybridConfig, lp: dict, h: jax.Array, valid: jax.Array,
                 h, lp["router"], lp["bias"], c.experts_per_token,
                 c.routed_scale, c.router_dtype,
             )
+        elif c.router == "group_limited":
+            experts, weights = group_limited_softmax_routing(
+                h, lp["router"], c.experts_per_token, c.n_group,
+                c.topk_group, c.routed_scale, c.router_dtype)
         else:
             experts, weights = softmax_topk_routing(
                 h, lp["router"], c.experts_per_token, c.router_dtype)

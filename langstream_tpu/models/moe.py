@@ -442,6 +442,35 @@ def softmax_topk_routing(
     return experts.astype(jnp.int32), rounded(jax.nn.softmax(top, axis=-1))
 
 
+def group_limited_softmax_routing(
+    h: jax.Array,          # (T, H)
+    router: jax.Array,     # (H, E), the model's type
+    k: int,
+    n_group: int,
+    topk_group: int,
+    scale: float,
+    dtype=jnp.float32,
+) -> tuple[jax.Array, jax.Array]:
+    """``(experts (T, k) int32, weights (T, k) float32)``: float32 logits,
+    the softmax over ALL experts, the experts in ``n_group`` runs of
+    consecutive ids, a group scored by its best expert, the best
+    ``topk_group`` groups kept and the rest zeroed, the top ``k`` of what is
+    kept, and their own softmax scores times ``scale`` as the weights: not
+    renormalised, so they do not sum to 1 (device-limited routing: with a
+    group a device, a token's experts lie on ``topk_group`` devices at
+    most). Ties go to the lower id, among groups and among experts.
+    ``dtype`` as in :func:`sigmoid_topk_routing`."""
+    rounded = _rounded_to(dtype)
+    scores = rounded(jax.nn.softmax(_router_logits(h, router, rounded), axis=-1))
+    T, E = scores.shape
+    best = scores.reshape(T, n_group, E // n_group).max(axis=-1)
+    _, groups = jax.lax.top_k(best, topk_group)                 # (T, topk_group)
+    kept = (groups[..., None] == jnp.arange(n_group)).any(axis=1)   # (T, n_group)
+    masked = jnp.where(jnp.repeat(kept, E // n_group, axis=1), scores, 0.0)
+    weights, experts = jax.lax.top_k(masked, k)
+    return experts.astype(jnp.int32), weights * scale
+
+
 def relu2(up: jax.Array) -> jax.Array:
     """``relu(x W_up)^2``: the up-projection is ``(.., I)``."""
     return jnp.square(jax.nn.relu(up))

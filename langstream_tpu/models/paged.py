@@ -79,6 +79,17 @@ def init_paged_kv_cache(
     return jnp.zeros(shape, dtype=c.dtype), jnp.zeros(shape, dtype=c.dtype)
 
 
+def init_latent_pool(config, layout: PagedLayout) -> tuple[jax.Array, None]:
+    """The pool of a latent-attention model (models/latent.py): ONE array
+    ``(L, num_blocks, block_size, row_width)`` whose row is a position's
+    compressed latent and rotary key, with no head axis, key and value in
+    the same bytes; nothing stands in the value pool's place. Blocks, tables
+    and :func:`write_rows` know nothing of a row's width."""
+    c = config
+    shape = (c.layers, layout.num_blocks, layout.block_size, c.row_width)
+    return jnp.zeros(shape, dtype=c.dtype), None
+
+
 def init_paged_kv_cache_int8(
     config, layout: PagedLayout
 ) -> tuple[dict, dict]:

@@ -34,6 +34,9 @@ while this one is computed. A slot of length 0 runs zero iterations and
 starts no copy. What a call costs follows the live blocks, not
 ``num_read_blocks``, which only caps the rows a slot may attend.
 
+A pool whose row is one latent, key and value in the same bytes and no head
+axis (:func:`latent_read`), is read by the same walk (:func:`_live_tiles`).
+
 The multi-query twin (:func:`paged_attention_multiquery_partial`,
 continuation prefill and speculative verify) still sweeps a static
 ``(B, T-blocks, num_read_blocks)`` grid over one layer's pool slice.
@@ -58,32 +61,32 @@ NEG_INF = float(jnp.finfo(jnp.float32).min)
 TILE_VMEM_BYTES = 4 * 1024 * 1024
 
 
-def _paged_read_kernel(
+def _live_tiles(
     layer_ref,    # SMEM (1,) int32
     tables_ref,   # SMEM (B, max_blocks) int32
     lengths_ref,  # SMEM (B,) int32
-    q_ref,        # (1, H, D)
-    k_hbm,        # (L, nb, bs, KhD), in HBM
-    v_hbm,
-    acc_out,      # (1, H, D) f32
-    m_out,        # (1, H, 128) f32
-    l_out,        # (1, H, 128) f32
-    k_tile,       # VMEM (2, T*bs, KhD): two buffers of one tile
-    v_tile,
-    sems,         # DMA (2, 2): [k|v, buffer]
+    pools,        # the pools in HBM, each (L, nb, bs, row width)
+    tiles,        # one VMEM (2, T*bs, row width) a pool: two buffers of a tile
+    sems,         # DMA (pools, 2): [pool, buffer]
     buf_ref,      # SMEM (1,) int32: the buffer the next tile to compute is in
     *,
-    scale: float,
     block_size: int,
     tile_blocks: int,
     num_read_blocks: int,
-    kv_heads: int,
-    head_dim: int,
 ):
+    """The walk over a slot's live blocks that the single-query reads share
+    (:func:`_paged_read_kernel` over a K and a V pool, :func:`_latent_read_
+    kernel` over one pool of latent rows): grid step ``b`` is slot ``b``;
+    a tile is ``tile_blocks`` blocks, each live block of it one copy from
+    ``pool[layer, table[b, j]]`` into one of a tile's two buffers, and the
+    next tile's copies (the next live slot's first tile, at a slot's end)
+    fly while this one is computed. Starts the first live slot's first
+    tile at step 0 and returns ``(length, sweep)``: the slot's rows and
+    ``sweep(compute, carry)``, which runs ``carry = compute(buf, start,
+    carry)`` a tile in order, ``buf`` the buffer the tile's rows from
+    ``start`` lie in."""
     b = pl.program_id(0)
     B = pl.num_programs(0)
-    _, H, D = q_ref.shape
-    G = H // kv_heads
     bs, T = block_size, tile_blocks
     rows_t = T * bs
     layer = layer_ref[0]
@@ -92,16 +95,14 @@ def _paged_read_kernel(
         return jnp.minimum(lengths_ref[slot], num_read_blocks * bs)
 
     def for_live_blocks(slot, t, buf, act):
-        """``act`` on the K and the V copy of each live block of tile ``t``
+        """``act`` on every pool's copy of each live block of tile ``t``
         of ``slot``; none for the table columns past the slot's length."""
         n = pl.cdiv(rows_of(slot), bs)
         for j in range(T):
             @pl.when(t * T + j < n)
             def _():
                 blk = tables_ref[slot, t * T + j]
-                for i, (pool, tile) in enumerate(
-                    ((k_hbm, k_tile), (v_hbm, v_tile))
-                ):
+                for i, (pool, tile) in enumerate(zip(pools, tiles)):
                     act(pltpu.make_async_copy(
                         pool.at[layer, blk],
                         tile.at[buf, pl.ds(j * bs, bs)],
@@ -129,6 +130,85 @@ def _paged_read_kernel(
     length = rows_of(b)
     num_tiles = pl.cdiv(pl.cdiv(length, bs), T)
     after = next_live(b)
+
+    def sweep(compute, carry):
+        def tile_step(t, carry):
+            buf = buf_ref[0]
+            # the next tile's copies fly while this one is computed: the
+            # slot's own next tile, or at its end the next live slot's first
+            last = t + 1 >= num_tiles
+            ahead_slot = jnp.where(last, after, b)
+            ahead_tile = jnp.where(last, 0, t + 1)
+
+            @pl.when(ahead_slot < B)
+            def _():
+                for_live_blocks(
+                    ahead_slot, ahead_tile, 1 - buf, lambda c: c.start()
+                )
+
+            for_live_blocks(b, t, buf, lambda c: c.wait())
+            carry = compute(buf, t * rows_t, carry)
+            buf_ref[0] = 1 - buf
+            return carry
+
+        return jax.lax.fori_loop(0, num_tiles, tile_step, carry)
+
+    return length, sweep
+
+
+def _live_masks(start, rows_t: int, length):
+    """``(live (1, rows), live_rows (rows, 1))`` of a tile from ``start``."""
+    live = start + jax.lax.broadcasted_iota(
+        jnp.int32, (1, rows_t), 1
+    ) < length
+    live_rows = start + jax.lax.broadcasted_iota(
+        jnp.int32, (rows_t, 1), 0
+    ) < length
+    return live, live_rows
+
+
+def _online_softmax(s, live, m_prev, l_prev):
+    """One tile of the running softmax over scores ``s (H, rows)``:
+    ``(m_new, l_new, alpha, p)`` with ``p`` float32, 0 where not live."""
+    s = jnp.where(live, s, NEG_INF)
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    shift = jnp.where(m_new <= NEG_INF, 0.0, m_new)
+    p = jnp.where(live, jnp.exp(s - shift), 0.0)
+    alpha = jnp.exp(jnp.where(m_prev <= NEG_INF, NEG_INF, m_prev - shift))
+    l_new = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
+    return m_new, l_new, alpha, p
+
+
+def _paged_read_kernel(
+    layer_ref,    # SMEM (1,) int32
+    tables_ref,   # SMEM (B, max_blocks) int32
+    lengths_ref,  # SMEM (B,) int32
+    q_ref,        # (1, H, D)
+    k_hbm,        # (L, nb, bs, KhD), in HBM
+    v_hbm,
+    acc_out,      # (1, H, D) f32
+    m_out,        # (1, H, 128) f32
+    l_out,        # (1, H, 128) f32
+    k_tile,       # VMEM (2, T*bs, KhD): two buffers of one tile
+    v_tile,
+    sems,         # DMA (2, 2): [k|v, buffer]
+    buf_ref,      # SMEM (1,) int32: the buffer the next tile to compute is in
+    *,
+    scale: float,
+    block_size: int,
+    tile_blocks: int,
+    num_read_blocks: int,
+    kv_heads: int,
+    head_dim: int,
+):
+    _, H, D = q_ref.shape
+    G = H // kv_heads
+    rows_t = tile_blocks * block_size
+    length, sweep = _live_tiles(
+        layer_ref, tables_ref, lengths_ref, (k_hbm, v_hbm), (k_tile, v_tile),
+        sems, buf_ref, block_size=block_size, tile_blocks=tile_blocks,
+        num_read_blocks=num_read_blocks,
+    )
     q = q_ref[0]                                       # (H, D)
 
     def head(tile, buf, kh):
@@ -138,29 +218,9 @@ def _paged_read_kernel(
         # r5's chip attribution pinned the q8 lane's 62-vs-42 ms/step on.
         return tile[buf, :, kh * head_dim:(kh + 1) * head_dim]
 
-    def tile_step(t, carry):
+    def compute(buf, start, carry):
         m_prev, l_prev, acc = carry                    # (H,1) (H,1) (H,D)
-        buf = buf_ref[0]
-        # the next tile's copies fly while this one is computed: the slot's
-        # own next tile, or at its end the next live slot's first
-        last = t + 1 >= num_tiles
-        ahead_slot = jnp.where(last, after, b)
-        ahead_tile = jnp.where(last, 0, t + 1)
-
-        @pl.when(ahead_slot < B)
-        def _():
-            for_live_blocks(
-                ahead_slot, ahead_tile, 1 - buf, lambda c: c.start()
-            )
-
-        for_live_blocks(b, t, buf, lambda c: c.wait())
-        start = t * rows_t
-        live = start + jax.lax.broadcasted_iota(
-            jnp.int32, (1, rows_t), 1
-        ) < length
-        live_rows = start + jax.lax.broadcasted_iota(
-            jnp.int32, (rows_t, 1), 0
-        ) < length
+        live, live_rows = _live_masks(start, rows_t, length)
         s = jnp.concatenate([
             jax.lax.dot_general(
                 q[kh * G:(kh + 1) * G], head(k_tile, buf, kh),
@@ -168,12 +228,7 @@ def _paged_read_kernel(
             )                                          # (G, rows)
             for kh in range(kv_heads)
         ], axis=0) * scale
-        s = jnp.where(live, s, NEG_INF)
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        shift = jnp.where(m_new <= NEG_INF, 0.0, m_new)
-        p = jnp.where(live, jnp.exp(s - shift), 0.0)
-        alpha = jnp.exp(jnp.where(m_prev <= NEG_INF, NEG_INF, m_prev - shift))
-        l_new = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
+        m_new, l_new, alpha, p = _online_softmax(s, live, m_prev, l_prev)
         p = p.astype(v_tile.dtype)
         pv = []
         for kh in range(kv_heads):
@@ -187,17 +242,79 @@ def _paged_read_kernel(
                 (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
             ))                                         # (G, D)
         acc = acc * alpha + jnp.concatenate(pv, axis=0)
-        buf_ref[0] = 1 - buf
         return m_new, l_new, acc
 
-    m, l, acc = jax.lax.fori_loop(
-        0, num_tiles, tile_step,
-        (
-            jnp.full((H, 1), NEG_INF, jnp.float32),
-            jnp.zeros((H, 1), jnp.float32),
-            jnp.zeros((H, D), jnp.float32),
-        ),
+    m, l, acc = sweep(compute, (
+        jnp.full((H, 1), NEG_INF, jnp.float32),
+        jnp.zeros((H, 1), jnp.float32),
+        jnp.zeros((H, D), jnp.float32),
+    ))
+    acc_out[0] = acc
+    m_out[0] = jnp.broadcast_to(m, m_out.shape[1:])
+    l_out[0] = jnp.broadcast_to(l, l_out.shape[1:])
+
+
+def _latent_read_kernel(
+    layer_ref,    # SMEM (1,) int32
+    tables_ref,   # SMEM (B, max_blocks) int32
+    lengths_ref,  # SMEM (B,) int32
+    q_ref,        # (1, H, W): [q absorbed into the latent | rotary part]
+    pool_hbm,     # (L, nb, bs, W) latent rows [c_kv | k_pe], in HBM
+    acc_out,      # (1, H, Dv) f32
+    m_out,        # (1, H, 128) f32
+    l_out,        # (1, H, 128) f32
+    tile,         # VMEM (2, T*bs, W): two buffers of one tile
+    sems,         # DMA (1, 2)
+    buf_ref,      # SMEM (1,) int32
+    *,
+    scale: float,
+    block_size: int,
+    tile_blocks: int,
+    num_read_blocks: int,
+    value_dim: int,
+):
+    """Every head against the ONE row a position: the key is the whole row,
+    the value its first ``value_dim`` lanes, the same bytes of the same
+    tile. ``value_dim`` is a multiple of the lane tile, so both parts are
+    lane-aligned slices and each tile is two score dots and one value dot
+    of all the heads at once."""
+    _, H, W = q_ref.shape
+    Dv = value_dim
+    rows_t = tile_blocks * block_size
+    length, sweep = _live_tiles(
+        layer_ref, tables_ref, lengths_ref, (pool_hbm,), (tile,), sems,
+        buf_ref, block_size=block_size, tile_blocks=tile_blocks,
+        num_read_blocks=num_read_blocks,
     )
+    q = q_ref[0]                                       # (H, W)
+    q_lat, q_pe = q[:, :Dv], q[:, Dv:]
+
+    def compute(buf, start, carry):
+        m_prev, l_prev, acc = carry                    # (H,1) (H,1) (H,Dv)
+        live, live_rows = _live_masks(start, rows_t, length)
+        # what is not live is zeroed, as in the K/V read: 0 × NaN is NaN
+        c = tile[buf, :, :Dv]
+        c = jnp.where(live_rows, c, jnp.zeros_like(c))             # (rows, Dv)
+        pe = tile[buf, :, Dv:]                                     # (rows, W-Dv)
+        dims = (((1,), (1,)), ((), ()))
+        s = (
+            jax.lax.dot_general(q_lat, c, dims,
+                                preferred_element_type=jnp.float32)
+            + jax.lax.dot_general(q_pe, pe, dims,
+                                  preferred_element_type=jnp.float32)
+        ) * scale                                                  # (H, rows)
+        m_new, l_new, alpha, p = _online_softmax(s, live, m_prev, l_prev)
+        acc = acc * alpha + jax.lax.dot_general(
+            p.astype(c.dtype), c, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        return m_new, l_new, acc
+
+    m, l, acc = sweep(compute, (
+        jnp.full((H, 1), NEG_INF, jnp.float32),
+        jnp.zeros((H, 1), jnp.float32),
+        jnp.zeros((H, Dv), jnp.float32),
+    ))
     acc_out[0] = acc
     m_out[0] = jnp.broadcast_to(m, m_out.shape[1:])
     l_out[0] = jnp.broadcast_to(l, l_out.shape[1:])
@@ -399,6 +516,99 @@ def paged_attention_partial(
         q, k_pool, v_pool,
     )
     return acc, m[:, :, 0], l[:, :, 0]
+
+
+#: blocks of one tile of the latent read (1,024 rows at bs 64: both buffers
+#: 2.6 MiB of VMEM at a 640-lane bf16 row, the scores of 128 heads 512 KiB).
+#: On the v5e, 96 slots of 484k rows in all, ms a call: 4 blocks 1.85,
+#: 8 blocks 1.55, 16 blocks 1.45, against a floor of 0.68
+#: (tools/latent_probe.py --kernels --tiles 4 8 16)
+LATENT_TILE_BLOCKS = 16
+
+
+def latent_read(
+    q: jax.Array,             # (B, H, W): [absorbed query | rotary part]
+    pool: jax.Array,          # (L, nb, bs, W): latent rows [c_kv | k_pe]
+    layer,                    # () int32: which layer of the stacked pool
+    block_tables: jax.Array,  # (B, max_blocks) int32
+    lengths: jax.Array,       # (B,) int32: cache rows to attend per slot
+    *,
+    num_read_blocks: int,     # static cap on the table columns a slot reads
+    value_dim: int,           # leading lanes of a row that are its value
+    scale: float,
+    interpret: bool = False,
+) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """Partial (unnormalised) latent attention over the cache segment: every
+    head's query against the one ``W``-wide row a position, whose first
+    ``value_dim`` lanes are also the value (multi-head latent attention with
+    the up-projections absorbed into the query and the output). Returns
+    ``(acc (B, H, value_dim) f32, m (B, H) f32, l (B, H) f32)`` for
+    :func:`merge_partial_attention`. The pool is read in place, live blocks
+    only, as :func:`paged_attention_partial` reads its two."""
+    B, H, W = q.shape
+    bs = pool.shape[2]
+    tile_blocks = max(1, min(LATENT_TILE_BLOCKS, num_read_blocks))
+    kernel = functools.partial(
+        _latent_read_kernel,
+        scale=scale, block_size=bs, tile_blocks=tile_blocks,
+        num_read_blocks=num_read_blocks, value_dim=value_dim,
+    )
+    per_slot = lambda shape: pl.BlockSpec(  # noqa: E731
+        shape, lambda b, layer, tables, lengths: (b, 0, 0)
+    )
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(B,),
+        in_specs=[per_slot((1, H, W)), pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=[
+            per_slot((1, H, value_dim)), per_slot((1, H, 128)),
+            per_slot((1, H, 128)),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((2, tile_blocks * bs, W), pool.dtype),
+            pltpu.SemaphoreType.DMA((1, 2)),
+            pltpu.SMEM((1,), jnp.int32),
+        ],
+    )
+    acc, m, l = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=[
+            jax.ShapeDtypeStruct((B, H, value_dim), jnp.float32),
+            jax.ShapeDtypeStruct((B, H, 128), jnp.float32),
+            jax.ShapeDtypeStruct((B, H, 128), jnp.float32),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+        ),
+        interpret=interpret,
+        name="latent_read",
+    )(jnp.asarray(layer, jnp.int32).reshape(1), block_tables, lengths, q, pool)
+    return acc, m[:, :, 0], l[:, :, 0]
+
+
+def latent_read_xla(
+    q: jax.Array, pool: jax.Array, layer, block_tables: jax.Array,
+    lengths: jax.Array, *, num_read_blocks: int, value_dim: int, scale: float,
+) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """:func:`latent_read` as an XLA expression: the window gathered densely
+    from the layer's slice of the pool (a copy the kernel does not pay), the
+    same partial softmax. Every backend; what the kernel is checked
+    against."""
+    B, H, W = q.shape
+    bs = pool.shape[2]
+    rows = jax.lax.dynamic_index_in_dim(pool, layer, keepdims=False)[
+        block_tables[:, :num_read_blocks]
+    ].reshape(B, num_read_blocks * bs, W)
+    live = (jnp.arange(num_read_blocks * bs)[None, :] < lengths[:, None])
+    s = jnp.einsum("bhw,btw->bht", q, rows).astype(jnp.float32) * scale
+    s = jnp.where(live[:, None, :], s, NEG_INF)
+    m = jnp.max(s, axis=-1)
+    shift = jnp.where(m <= NEG_INF, 0.0, m)
+    p = jnp.where(live[:, None, :], jnp.exp(s - shift[..., None]), 0.0)
+    values = jnp.where(live[..., None], rows[..., :value_dim], 0)
+    acc = jnp.einsum("bht,btd->bhd", p.astype(q.dtype), values)
+    return acc.astype(jnp.float32), m, jnp.sum(p, axis=-1)
 
 
 def _paged_attention_partial_q8(

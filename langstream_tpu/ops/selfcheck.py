@@ -264,3 +264,78 @@ def check_kernels(
         {"B": 1, "S": flash_seq, "H": H, "Kh": Kh, "D": D}, interpret, flash,
     ))
     return rows
+
+
+def check_latent_kernels(model_config, *, block_size: int, read_blocks: int,
+                         batch: int, flash_seq: int = 512,
+                         interpret: bool = False) -> list[dict[str, Any]]:
+    """Two rows for a latent-attention model (models/latent.py): the latent
+    read (``ops/paged_attention.py`` ``latent_read``) at the model's heads
+    and row width on a stack of two layers, the second one read, ragged
+    lengths and an idle slot, against its XLA expression; and the flash
+    kernel with the model's key width and its narrower value width, told
+    the true length of a right-padded row, against the einsum on the real
+    rows."""
+    from langstream_tpu.ops.flash_attention import flash_attention
+    from langstream_tpu.ops.paged_attention import (
+        NEG_INF,
+        latent_read,
+        latent_read_xla,
+        merge_partial_attention,
+    )
+
+    c = model_config
+    H, W, Dv = c.heads, c.row_width, c.kv_rank
+    nb = batch * read_blocks + 1
+    keys = jax.random.split(jax.random.PRNGKey(23), 5)
+    # scores of about unit spread, as the K/V rows' of check_kernels are
+    # (1/sqrt(D) there): the tolerance is for probabilities that bfloat16
+    # rounds on both sides, not for a softmax sharpened by the row's width
+    q = (jax.random.normal(keys[0], (batch, H, W), jnp.float32)
+         / (c.attn_scale * math.sqrt(W))).astype(jnp.bfloat16)
+    pool = jax.random.normal(keys[1], (2, nb, block_size, W), jnp.bfloat16)
+    tables, lengths = _tables_and_lengths(batch, read_blocks, block_size, nb)
+    if batch > 2:
+        lengths = lengths.at[2].set(0)          # an idle slot
+    kw = dict(num_read_blocks=read_blocks, value_dim=Dv, scale=c.attn_scale)
+
+    def read():
+        got = jax.jit(lambda q, p, t, n: merge_partial_attention([
+            latent_read(q, p, 1, t, n, interpret=interpret, **kw)]))(
+            q, pool, tables, lengths)
+        ref = jax.jit(lambda q, p, t, n: merge_partial_attention([
+            latent_read_xla(q, p, 1, t, n, **kw)]))(q, pool, tables, lengths)
+        return got, ref
+
+    rows = [_row(
+        "_latent_read_kernel",
+        {"B": batch, "H": H, "W": W, "Dv": Dv, "block": block_size,
+         "read_blocks": read_blocks}, interpret, read)]
+
+    D, dv, heads = c.head_dim, c.v_dim, min(H, 8)
+    real = flash_seq - flash_seq // 3
+
+    def flash():
+        qf = jax.random.normal(keys[2], (1, flash_seq, heads, D), jnp.bfloat16)
+        kf = jax.random.normal(keys[3], (1, flash_seq, heads, D), jnp.bfloat16)
+        vf = jax.random.normal(keys[4], (1, flash_seq, heads, dv), jnp.bfloat16)
+        got = jax.jit(lambda q, k, v: flash_attention(
+            q, k, v, causal=True, scale=c.attn_scale, interpret=interpret,
+            lengths=jnp.asarray([real], jnp.int32)))(qf, kf, vf)
+
+        def xla_attention(q, k, v):
+            s = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32)
+            causal = (jnp.arange(flash_seq)[:, None]
+                      >= jnp.arange(flash_seq)[None, :])
+            s = jnp.where(causal[None, None], s * c.attn_scale, NEG_INF)
+            return jnp.einsum(
+                "bhqk,bkhd->bqhd", jax.nn.softmax(s, -1).astype(q.dtype), v)
+
+        ref = jax.jit(xla_attention)(qf, kf, vf)
+        return got[:, :real], ref[:, :real]
+
+    rows.append(_row(
+        "_flash_ragged_kernel",
+        {"B": 1, "S": flash_seq, "real": real, "H": heads, "D": D, "Dv": dv},
+        interpret, flash))
+    return rows

@@ -143,16 +143,34 @@ _MODEL_CONFIGS = {
 # (models/moe.py `moe_serving_ffn`). Lazy: moe.py imports only when used.
 _MOE_MODELS = ("moe-tiny", "moe-8x7b", "mixtral-8x7b")
 
-# Hybrid (Mamba-2 + attention + routed experts, models/hybrid.py; the
-# nemotron_h and granitemoehybrid layers): a family
-# of its own programs, with a per-slot recurrent state beside the paged pool
-# (name -> the HybridConfig classmethod that builds it)
-_HYBRID_MODELS = {
-    "hybrid-tiny": "tiny",
-    "nemotron-3-nano-30b-a3b-ep8": "nemotron3_nano_ep8",
-    "granite-tiny": "granite_tiny",
-    "granite-4.0-h-small-ep2": "granite4_h_small_ep2",
+# Families with programs of their own beside the paged pool, one table:
+# name -> (family, the classmethod of the family's config class that builds
+# it). "hybrid" (models/hybrid.py HybridConfig: Mamba-2 + attention + routed
+# experts, the nemotron_h and granitemoehybrid layers) keeps a per-slot
+# recurrent state beside a K/V pool; "latent" (models/latent.py
+# LatentConfig: multi-head latent attention + group-limited routed experts,
+# the deepseek_v2 layer) keeps ONE pool whose row is a compressed latent.
+_FAMILY_MODELS = {
+    "hybrid-tiny": ("hybrid", "tiny"),
+    "nemotron-3-nano-30b-a3b-ep8": ("hybrid", "nemotron3_nano_ep8"),
+    "granite-tiny": ("hybrid", "granite_tiny"),
+    "granite-4.0-h-small-ep2": ("hybrid", "granite4_h_small_ep2"),
+    "deepseek-tiny": ("latent", "tiny"),
+    "deepseek-v2-ep8": ("latent", "deepseek_v2_ep8"),
 }
+
+
+def _family_config_class(family: str):
+    """Lazy: a family's module imports only when one of its models is
+    served."""
+    if family == "hybrid":
+        from langstream_tpu.models.hybrid import HybridConfig
+
+        return HybridConfig
+    from langstream_tpu.models.latent import LatentConfig
+
+    return LatentConfig
+
 
 #: adaptive pool-shrink (docs/RESILIENCE.md): preempt-and-retry rounds a
 #: stranded (never-prefilled) request gets before its failure stops
@@ -174,10 +192,9 @@ _RESOURCE_EXHAUSTED_RE = re.compile(
 
 
 def _resolve_model_config(name: str, max_seq_len: int):
-    if name in _HYBRID_MODELS:
-        from langstream_tpu.models.hybrid import HybridConfig
-
-        return getattr(HybridConfig, _HYBRID_MODELS[name])(
+    if name in _FAMILY_MODELS:
+        family, method = _FAMILY_MODELS[name]
+        return getattr(_family_config_class(family), method)(
             max_seq_len=max_seq_len
         )
     if name in _MOE_MODELS:
@@ -192,7 +209,7 @@ def _resolve_model_config(name: str, max_seq_len: int):
     if name not in _MODEL_CONFIGS:
         raise ValueError(
             f"unknown model {name!r}; known: "
-            f"{sorted(_MODEL_CONFIGS) + sorted(_MOE_MODELS) + sorted(_HYBRID_MODELS)}"
+            f"{sorted(_MODEL_CONFIGS) + sorted(_MOE_MODELS) + sorted(_FAMILY_MODELS)}"
         )
     return _MODEL_CONFIGS[name](max_seq_len=max_seq_len)
 
@@ -887,7 +904,10 @@ class TpuServingEngine:
                 self.model_config, dtype=dtypes[config.model_dtype]
             )
         self.is_moe = config.model in _MOE_MODELS
-        self.is_hybrid = config.model in _HYBRID_MODELS
+        #: which programs serve the model: "dense" (models/llama_paged.py,
+        #: the MoE FFN plugged into it), or a _FAMILY_MODELS family
+        self.family = _FAMILY_MODELS.get(config.model, ("dense", None))[0]
+        self.is_hybrid = self.family == "hybrid"
         self.tokenizer: Tokenizer = load_tokenizer(config.tokenizer)
         if self.tokenizer.vocab_size > self.model_config.vocab_size:
             raise ValueError(
@@ -1357,7 +1377,7 @@ class TpuServingEngine:
         )
         self._state_bytes = tree_device_bytes(self.state)
         act_bytes = np.dtype(mc.dtype).itemsize
-        if self.is_moe or self.is_hybrid:
+        if self.is_moe or self.family != "dense":
             # routed experts: the host can't know which experts fire, so
             # the FLOPs term estimates params from the measured bytes —
             # divided by the ACTUAL weight width (int8 → 1, else the
@@ -1616,15 +1636,22 @@ class TpuServingEngine:
         # full-precision tree PLUS the int8 copy (>= 24 GB at the 8B shape
         # — certain OOM on a 16 GB chip, round-4 bench root cause)
         quantized_at_init = False
-        if self.is_hybrid:
+        if self.family != "dense":
             self._refuse_what_assumes_history_is_kv()
-            from langstream_tpu.models.hybrid import init_hybrid_params
+            if self.is_hybrid:
+                from langstream_tpu.models.hybrid import (
+                    init_hybrid_params as init_params,
+                )
+            else:
+                from langstream_tpu.models.latent import (
+                    init_latent_params as init_params,
+                )
 
             log.warning(
                 "model %r: using random-init weights (offline/dev mode)",
                 self.config.model,
             )
-            self.params = init_hybrid_params(mc)
+            self.params = init_params(mc)
         elif self.is_moe:
             from langstream_tpu.models.moe import init_moe_params, moe_serving_ffn
 
@@ -1759,6 +1786,11 @@ class TpuServingEngine:
 
             init_cache = partial(init_hybrid_pool, mc, self.paged_layout)
             init_state = partial(init_hybrid_state, mc, self.config.slots)
+        elif self.family == "latent":
+            from langstream_tpu.models.paged import init_latent_pool
+
+            # one array of latent rows; nothing in the value pool's place
+            init_cache = partial(init_latent_pool, mc, self.paged_layout)
         elif self.config.kv_quantize == "int8":
             from langstream_tpu.models.paged import init_paged_kv_cache_int8
 
@@ -2002,6 +2034,28 @@ class TpuServingEngine:
 
                 return _decode_chunk
 
+            if self.family == "latent":
+                # the dense family's signature (no state rides behind the
+                # caches); cache_v is None, as init_latent_pool left it
+                @partial(jax.jit, donate_argnums=(1,))
+                def _decode_chunk(params, pool, cache_v, tokens, lengths,
+                                  active, tables, key, temps, topks, topps,
+                                  pres=None, freq=None, counts=None):
+                    from langstream_tpu.models.latent import (
+                        latent_decode_chunk_paged,
+                    )
+
+                    return latent_decode_chunk_paged(
+                        mc_static, params, tokens, lengths, active, pool,
+                        tables, _sample_fn_for(temps, topks, topps, pres, freq),
+                        key, K, num_read_blocks=window,
+                        kernel=self.paged_read_kernel,
+                        sample_extras=_extras(pres, freq, counts),
+                        return_packed=True,
+                    ) + (cache_v,)
+
+                return _decode_chunk
+
             @partial(jax.jit, donate_argnums=(1, 2))
             def _decode_chunk(params, cache_k, cache_v, tokens, lengths,
                               active, tables, key, temps, topks, topps,
@@ -2075,6 +2129,28 @@ class TpuServingEngine:
                             use_top_k=use_top_k, all_greedy=all_greedy,
                         )
                     return next_tokens, logprobs, ck, cv, st
+
+                return _prefill
+
+            if self.family == "latent":
+                @partial(jax.jit, donate_argnums=(1,))
+                def _prefill(params, pool, cache_v, tokens, lengths, tables,
+                             key, temps, topks, topps):
+                    from langstream_tpu.models.latent import (
+                        latent_prefill_paged,
+                    )
+
+                    logits, pool, _routed = latent_prefill_paged(
+                        mc_static, params, tokens, lengths, pool, tables,
+                        use_flash=prefill_flash,
+                    )
+                    with jax.named_scope("sample"):
+                        next_tokens, logprobs = sample_tokens(
+                            logits, key, temps, topks,
+                            use_top_p=use_top_p, top_ps=topps,
+                            use_top_k=use_top_k, all_greedy=all_greedy,
+                        )
+                    return next_tokens, logprobs, pool, cache_v
 
                 return _prefill
 
@@ -2193,57 +2269,77 @@ class TpuServingEngine:
         self._spec_step_fns: dict[tuple[int, tuple], Any] = {}
 
     def _refuse_what_assumes_history_is_kv(self) -> None:
-        """A hybrid model's history is K/V blocks AND a recurrent state
-        that has no snapshot: every feature that adopts, rolls back, moves
-        or replays a request's history by its blocks alone would serve
-        wrong tokens. Each is refused here by the name of its option;
-        nothing falls back."""
+        """Every feature that adopts, rolls back, moves or replays a
+        request's history as blocks of K and V rows alone would serve wrong
+        tokens (or none) where the history is something else: a hybrid
+        model's is K/V blocks AND a recurrent state that has no snapshot, a
+        latent model's one array of compressed rows that the K/V-shaped
+        paths (continuation prefill, the handoff's two pools, the int8
+        rows' per-head scales) cannot read. Each is refused here by the name
+        of its option, with the family's own reason; nothing falls back."""
         cfg = self.config
-        refused = {  # option: (set, why it cannot be served)
-            "prefix-cache": (
-                cfg.prefix_cache,
-                "adopted blocks carry no recurrent state; set "
-                "prefix-cache: false"),
-            "prefix-store": (
-                cfg.prefix_store is not None and cfg.prefix_store.enabled,
-                "its tiers hold K/V blocks only"),
-            "prefill-chunk": (
-                cfg.prefill_chunk > 0,
-                "continuation prefill resumes from K/V alone; set "
-                "prefill-chunk: 0"),
-            "speculative-drafts": (
-                cfg.speculative_drafts > 0,
-                "a rejected draft cannot be rolled out of the recurrent "
-                "state; set speculative-drafts: 0"),
-            "pool-role": (
-                cfg.pool_role != "combined",
-                "the K/V handoff carries no recurrent state; use "
-                "pool-role: combined"),
-            "adapter-store": (
-                cfg.adapter_store is not None and cfg.adapter_store.enabled,
-                "the hybrid programs apply no adapters"),
-            "quantize": (
-                cfg.quantize not in (None, "none"),
-                "the hybrid programs read bf16 weights only"),
-            "kv-quantize": (
-                cfg.kv_quantize not in (None, "none"),
-                "the hybrid programs read a bf16 pool only"),
-            "mesh": (
-                bool(cfg.mesh),
-                "this family serves one chip's share of its deployment; "
-                "no mesh"),
-            "journal-dir": (
-                bool(cfg.journal_dir),
-                "journal replay re-admits by K/V-era rules untested beside "
-                "recurrent state"),
-            "checkpoint": (
-                bool(cfg.checkpoint), "no checkpoint loader for this family"),
+        family = self.family
+        why = {  # the reasons both families share
+            "prefix-store": "its tiers hold K/V blocks only",
+            "adapter-store": f"the {family} programs apply no adapters",
+            "quantize": f"the {family} programs read bf16 weights only",
+            "mesh": "this family serves one chip's share of its "
+                    "deployment; no mesh",
+            "checkpoint": "no checkpoint loader for this family",
         }
-        for option, (on, why) in refused.items():
-            if on:
+        what, own = {
+            "hybrid": ("keeps a recurrent state beside its K/V blocks", {
+                "prefix-cache": "adopted blocks carry no recurrent state; "
+                                "set prefix-cache: false",
+                "prefill-chunk": "continuation prefill resumes from K/V "
+                                 "alone; set prefill-chunk: 0",
+                "speculative-drafts": "a rejected draft cannot be rolled out "
+                                      "of the recurrent state; set "
+                                      "speculative-drafts: 0",
+                "pool-role": "the K/V handoff carries no recurrent state; "
+                             "use pool-role: combined",
+                "kv-quantize": "the hybrid programs read a bf16 pool only",
+                "journal-dir": "journal replay re-admits by K/V-era rules "
+                               "untested beside recurrent state",
+            }),
+            "latent": ("keeps one pool of latent rows, not K and V", {
+                "prefix-cache": "no continuation prefill over a latent "
+                                "history yet, so an adopted prefix cannot "
+                                "be extended; set prefix-cache: false",
+                "prefill-chunk": "no continuation prefill over a latent "
+                                 "history yet; set prefill-chunk: 0",
+                "speculative-drafts": "the verify step reads K/V history "
+                                      "through the multi-query kernel; set "
+                                      "speculative-drafts: 0",
+                "pool-role": "the handoff's payload carries a K and a V "
+                             "array; use pool-role: combined",
+                "kv-quantize": "int8 rows carry one scale a K/V head; a "
+                               "latent row has no head axis",
+                "journal-dir": "journal replay re-admits by K/V-era rules "
+                               "untested over a latent pool",
+            }),
+        }[family]
+        why.update(own)
+        on = {  # in the order they are refused
+            "prefix-cache": cfg.prefix_cache,
+            "prefix-store": (
+                cfg.prefix_store is not None and cfg.prefix_store.enabled),
+            "prefill-chunk": cfg.prefill_chunk > 0,
+            "speculative-drafts": cfg.speculative_drafts > 0,
+            "pool-role": cfg.pool_role != "combined",
+            "adapter-store": (
+                cfg.adapter_store is not None and cfg.adapter_store.enabled),
+            "quantize": cfg.quantize not in (None, "none"),
+            "kv-quantize": cfg.kv_quantize not in (None, "none"),
+            "mesh": bool(cfg.mesh),
+            "journal-dir": bool(cfg.journal_dir),
+            "checkpoint": bool(cfg.checkpoint),
+        }
+        for option, is_on in on.items():
+            if is_on:
                 raise ValueError(
-                    f"model {cfg.model!r} keeps a recurrent state beside "
-                    f"its K/V blocks and cannot serve with {option}: {why}"
+                    f"model {cfg.model!r} {what} and cannot serve with "
+                    f"{option}: {why[option]}"
                 )
 
     def _refuse_cache_that_cannot_fit(self, init_cache) -> None:
@@ -2482,19 +2578,24 @@ class TpuServingEngine:
         active_at_dispatch: int | None = None,
         live_blocks: int | None = None,
         table_blocks: int | None = None,
+        live_rows: int | None = None,
         routed_pairs: int | None = None,
         expert_load_max: int | None = None,
         state_bytes: int | None = None,
         ahead: int | None = None,
+        prompt_tokens: int | None = None,
     ) -> None:
         """One flight sample per dispatched burst, plus its Prometheus
         mirrors. ``program``, ``dispatch``, ``steps``,
-        ``active_at_dispatch``, ``live_blocks`` and ``table_blocks`` are
-        the dispatch's :meth:`_ticket`, taken when it was made;
+        ``active_at_dispatch``, ``live_blocks``, ``table_blocks`` and
+        ``live_rows`` are the dispatch's :meth:`_ticket`, taken when it was
+        made;
         ``routed_pairs``, ``expert_load_max`` and ``state_bytes`` (a hybrid
         model's decode chunk) joined it when the chunk's packed fetch
         landed (:meth:`_await_chunk`). ``ahead`` (a prefill batch) is 1 when
-        it was dispatched with its predecessor unfetched (:meth:`_admit`).
+        it was dispatched with its predecessor unfetched (:meth:`_admit`),
+        ``prompt_tokens`` the true tokens its rows prefilled (without the
+        padding to the bucket and without an adopted prefix).
         ``overlapped_s`` is host work the pipelined loop ran
         under an in-flight dispatch's device shadow (see flight.py).
         ``program`` keys the sample by the compiled variant that ran and
@@ -2530,10 +2631,12 @@ class TpuServingEngine:
             active_at_dispatch=active_at_dispatch,
             live_blocks=live_blocks,
             table_blocks=table_blocks,
+            live_rows=live_rows,
             routed_pairs=routed_pairs,
             expert_load_max=expert_load_max,
             state_bytes=state_bytes,
             ahead=ahead,
+            prompt_tokens=prompt_tokens,
         )
         # watchdog heartbeat: a recorded dispatch IS step progress
         self.watchdog.beat(sample["queue_depth"])
@@ -2572,33 +2675,39 @@ class TpuServingEngine:
     def _ticket(
         self, program: str, steps: int, active: int,
         live_blocks: int | None = None, table_blocks: int | None = None,
+        live_rows: int | None = None,
     ) -> dict:
         """What a dispatch knows when it is made and its flight sample,
         recorded when the result is processed, no longer does: the program
         variant, the dispatch's ordinal (the ``seq`` of its host spans),
         the decode steps it fuses (0 for a prefill), the slots running and,
         for a decode chunk, the pool blocks its read has to fetch
-        against the table columns of its window (:meth:`_read_blocks`).
+        against the table columns of its window, and the rows in those
+        blocks (:meth:`_read_blocks`).
         Loop thread only; rides to :meth:`_flight_record` as keywords."""
         self._dispatch_seq += 1
         return {
             "program": program, "dispatch": self._dispatch_seq,
             "steps": steps, "active_at_dispatch": active,
             "live_blocks": live_blocks, "table_blocks": table_blocks,
+            "live_rows": live_rows,
         }
 
     def _read_blocks(self, active: list[int], ahead: int, window: int):
-        """``(live_blocks, table_blocks)`` of a decode chunk's paged read:
-        the blocks that hold rows, summed over every slot of the batch from
-        the host's lengths (``ahead`` rows further for the running slots,
-        whose in-flight chunk the host has not processed), and the table
-        columns a sweep of the whole window would visit. Their ratio is
-        the share of such a sweep that is live."""
+        """``(live_blocks, table_blocks, live_rows)`` of a decode chunk's
+        paged read: the blocks that hold rows, summed over every slot of the
+        batch from the host's lengths (``ahead`` rows further for the
+        running slots, whose in-flight chunk the host has not processed),
+        the table columns a sweep of the whole window would visit, and the
+        rows themselves (what a step of the chunk reads of each layer's
+        pool). The first two's ratio is the share of such a sweep that is
+        live."""
         bs = self.paged_layout.block_size
         rows = self._lengths.astype(np.int64)
         rows[active] += ahead
         rows = np.minimum(rows, window * bs)
-        return int((-(-rows // bs)).sum()), self.config.slots * window
+        return (int((-(-rows // bs)).sum()), self.config.slots * window,
+                int(rows.sum()))
 
     def _flight_stall(self, reason: str) -> None:
         """Record an idle/blocked engine-loop gap as stall time."""
@@ -3010,6 +3119,12 @@ class TpuServingEngine:
         1024 rows (excess <128 rows/slot where most serving lengths live),
         powers of two beyond (a long-context engine would otherwise compile
         a fresh ~30s decode variant every 128 generated tokens)."""
+        if self.family == "latent":
+            # one decode program a chunk size: the latent read fetches a
+            # slot's live blocks and nothing else, whatever the window, and
+            # a slot of this family is long (a window bucket every power of
+            # two would be four more programs of its five-layer step)
+            return self.paged_layout.max_blocks_per_slot
         if max_len <= 1024:
             window = max(128, -(-max_len // 128) * 128)
         else:
@@ -4451,6 +4566,9 @@ class TpuServingEngine:
                 self._m_active(len(active))
                 self._m_queued(self.scheduler.qsize())
                 if not active:
+                    # nobody waits for a token (the admitted all ended at
+                    # their first): the round is over without a burst
+                    self._prefill_round_s, self._admit_cut = 0.0, False
                     if self.scheduler.empty() and not self._has_prefilling():
                         self._wake.clear()
                         # a stashed hydration resolves on the hydrator
@@ -4494,9 +4612,14 @@ class TpuServingEngine:
                         or (self._freq[active] != 0).any()
                     )
                 ):
+                    self._prefill_round_s, self._admit_cut = 0.0, False
                     await self._speculative_burst(loop, active)
                 else:
-                    await self._decode_burst(loop, active)
+                    # a round ends here: what admission was cut short for
+                    # is one chunk, fetched before the next prefill
+                    lone, self._admit_cut = self._admit_cut, False
+                    self._prefill_round_s = 0.0
+                    await self._decode_burst(loop, active, lone_chunk=lone)
             except Exception as e:  # device/runtime error: fail in-flight work,
                 # free the slots, keep serving (callers see the exception)
                 if (
@@ -6124,7 +6247,9 @@ class TpuServingEngine:
             ),
         )
 
-    async def _decode_burst(self, loop, active: list[int]) -> None:
+    async def _decode_burst(
+        self, loop, active: list[int], lone_chunk: bool = False
+    ) -> None:
         """Depth-2 pipelined chunk decoding (docs/PIPELINE.md): chunk k+1
         is dispatched from chunk k's *device-resident* outputs before k's
         tokens reach the host (the sampler feedback never round-trips),
@@ -6151,7 +6276,14 @@ class TpuServingEngine:
         same sequential loop serves penalty bursts and the
         ``pipeline=False`` / ``LS_TPU_PIPELINE=0`` escape hatch — it is
         the reference the pipelined loop's greedy byte-identity is tested
-        against."""
+        against.
+
+        ``lone_chunk``: admission stopped at its round's prefill budget
+        with slots free and work waiting (:meth:`_admit`), so the burst
+        would end after its first chunk anyway. It then dispatches no
+        second chunk behind it: the prefills held back run after ONE
+        chunk, not two, and the budget bounds the time between a running
+        request's tokens."""
         # host spans (flight.SPANS): the loop-thread work before each
         # dispatch is ``ls.decode.prepare``
         with self.flight.span("ls.decode.prepare", active=len(active)):
@@ -6474,7 +6606,7 @@ class TpuServingEngine:
                             active_mask
                         )
                 running = [self.slots[i].request for i in active]
-                if all(
+                if lone_chunk or all(
                     r is None or r.max_tokens - len(r.generated) <= K
                     for r in running
                 ):
@@ -6813,6 +6945,19 @@ class TpuServingEngine:
         if done_slots:
             await self._flush_emits(done_slots, "ls.prefill.emit")
 
+    # The most prefill, in seconds of the device, that admission dispatches
+    # between two decode chunks. A running request waits for every prefill
+    # of a round, so without a bound a wave of long prompts (0.15-0.75 s
+    # each at 4k-16k tokens) holds every stream for as many seconds as
+    # slots were freed, and an empty engine with a backlog decodes nothing
+    # until every slot is filled. Seconds and not a count or tokens: a wave
+    # of short batches (12 of 16 ms) or of a few long ones (4 of 460 ms)
+    # stays whole under every model. The batch in flight and the one
+    # dispatched behind it run past the budget.
+    _PREFILL_ROUND_S = 2.0
+    _prefill_round_s = 0.0  # device seconds of prefill since the last burst
+    _admit_cut = False  # the round's admission stopped at the budget
+
     async def _admit(self, loop) -> None:
         """Admit queued requests in batched prefill calls (grouped by
         prompt-length bucket, count padded to a power of two by repeating
@@ -6836,11 +6981,25 @@ class TpuServingEngine:
         completion does: a request that ends at its first token frees its
         slot one batch later, and (docs/PREFIX.md) batch N registers its
         prefixes after batch N+1 was matched, so N+1 misses what N is about
-        to publish, as two requests of one batch miss each other."""
+        to publish, as two requests of one batch miss each other.
+
+        A round's prefills are bounded: once the batches completed since
+        the last decode burst have taken ``_PREFILL_ROUND_S`` of the
+        device, no further batch is dispatched, and the slots still free
+        are filled after the next chunk (``_admit_cut`` tells the burst to
+        make it one)."""
         flying = None  # the batch on the device: (batch, ticket, out, ahead)
         try:
             while True:
-                nxt = await self._admit_dispatch(loop, flying is not None)
+                if self._prefill_round_s >= self._PREFILL_ROUND_S:
+                    # what ends a burst at its first chunk
+                    # (_burst_should_yield): work waiting and a slot free
+                    self._admit_cut = not self.scheduler.empty() and any(
+                        s.free for s in self.slots
+                    )
+                    nxt = None
+                else:
+                    nxt = await self._admit_dispatch(loop, flying is not None)
                 done, flying = flying, nxt
                 if done is not None:
                     await self._admit_complete(loop, *done)
@@ -7205,9 +7364,14 @@ class TpuServingEngine:
                 self._emit_token(slot_id, int(next_np[i]), float(logprob_np[i]))
                 admitted_slots.append(slot_id)
             self._m_tokens(len(batch))
+            self._prefill_round_s += device_s
             self._flight_record(
                 "prefill", device_s=device_s, tokens=len(batch),
                 ahead=ahead, **ticket,
+                prompt_tokens=sum(
+                    len(request.context_tokens) - reuse
+                    for _slot, request, reuse in batch
+                ),
             )
         await self._flush_emits(admitted_slots, "ls.prefill.emit")
 

@@ -221,10 +221,12 @@ class FlightRecorder:
         active_at_dispatch: int | None = None,
         live_blocks: int | None = None,
         table_blocks: int | None = None,
+        live_rows: int | None = None,
         routed_pairs: int | None = None,
         expert_load_max: int | None = None,
         state_bytes: int | None = None,
         ahead: int | None = None,
+        prompt_tokens: int | None = None,
     ) -> dict[str, Any]:
         """Record one dispatched burst. ``wall`` is the time since the
         previous boundary. ``overlapped_s`` is host work the pipelined
@@ -244,7 +246,8 @@ class FlightRecorder:
         at this later boundary where ``occupancy`` is read; omitted
         together when the caller has no dispatch to name. ``live_blocks``
         and ``table_blocks`` (a paged decode chunk only) are the pool
-        blocks its read had to fetch and the table columns of its window.
+        blocks its read had to fetch and the table columns of its window;
+        ``live_rows`` the rows in those blocks, summed over the slots.
         ``routed_pairs``, ``expert_load_max`` and ``state_bytes`` (a hybrid
         model's decode chunk only, models/hybrid.py) are the (token, expert)
         pairs the chunk's active rows sent to the experts held here, the most
@@ -253,7 +256,8 @@ class FlightRecorder:
         step). ``ahead`` (a prefill batch only) is 1 when the batch was
         dispatched while its predecessor's first tokens were unfetched, so
         its ``device_s`` is what was left of the program when the host came
-        to wait for it, not the program's run time."""
+        to wait for it, not the program's run time. ``prompt_tokens`` (a
+        prefill batch only) are the true tokens its rows prefilled."""
         now = time.monotonic()
         wall_ms = (now - self._last_mark) * 1000.0
         self._last_mark = now
@@ -295,6 +299,8 @@ class FlightRecorder:
         if live_blocks is not None:
             entry["live_blocks"] = live_blocks
             entry["table_blocks"] = table_blocks
+            if live_rows is not None:
+                entry["live_rows"] = live_rows
         if routed_pairs is not None:
             entry["routed_pairs"] = routed_pairs
             entry["expert_load_max"] = expert_load_max
@@ -302,6 +308,8 @@ class FlightRecorder:
         if ahead is not None:
             entry["ahead"] = ahead
             self.prefill_ahead += ahead
+        if prompt_tokens is not None:
+            entry["prompt_tokens"] = prompt_tokens
         self._samples.append(entry)
         self.recorded += 1
         self.wall_ms += wall_ms

@@ -363,12 +363,13 @@ def test_the_engine_knows_the_new_names_and_refuses_the_same_options():
     from langstream_tpu.serving.engine import (
         ServingConfig,
         TpuServingEngine,
-        _HYBRID_MODELS,
+        _FAMILY_MODELS,
         _resolve_model_config,
     )
 
-    assert _HYBRID_MODELS["granite-tiny"] == "granite_tiny"
-    assert _HYBRID_MODELS["granite-4.0-h-small-ep2"] == "granite4_h_small_ep2"
+    assert _FAMILY_MODELS["granite-tiny"] == ("hybrid", "granite_tiny")
+    assert _FAMILY_MODELS["granite-4.0-h-small-ep2"] == \
+        ("hybrid", "granite4_h_small_ep2")
     real = _resolve_model_config("granite-4.0-h-small-ep2", 2048)
     assert real == HybridConfig.granite4_h_small_ep2() and real.max_seq_len == 2048
     base = dict(model="granite-tiny", model_dtype="float32", slots=2,

@@ -210,3 +210,80 @@ def test_mosaic_builds_the_kernel_at_the_served_shapes_in_place(
     assert memory.temp_size_in_bytes < stack // 100
     text = compiled.as_text()
     assert "tpu_custom_call" in text and "ssm_state_step" in text
+
+
+# the latent family's two kernels (models/latent.py) are held to the same
+# compiler HERE, in the one file whose fixture describes the chip: a second
+# file with such a fixture could go to another worker, which cannot load the
+# TPU's library beside this one and would skip every test in silence
+
+
+def test_mosaic_builds_the_latent_read_at_the_served_shapes(
+        one_chip, no_compile_cache):
+    """deepseek-v2-ep8: 96 slots, 128 heads against one 640-lane row a
+    position (512 + 64 and the padding to the lane tile: a 576-wide row is
+    refused, ``Slice shape ... must be aligned to tiling (128)``), 256 table
+    columns, the layer-stacked pool in place."""
+    from langstream_tpu.models.latent import LatentConfig
+    from langstream_tpu.ops.paged_attention import latent_read
+
+    c = LatentConfig.deepseek_v2_ep8()
+    slots, blocks = 96, 14401
+    on = lambda shape, dtype: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dtype, sharding=one_chip)
+    compiled = jax.jit(lambda q, pool, layer, tables, lengths: latent_read(
+        q, pool, layer, tables, lengths, num_read_blocks=256,
+        value_dim=c.kv_rank, scale=c.attn_scale,
+    )).lower(
+        on((slots, c.heads, c.row_width), c.dtype),
+        on((c.layers, blocks, 64, c.row_width), c.dtype), on((), jnp.int32),
+        on((slots, 256), jnp.int32), on((slots,), jnp.int32),
+    ).compile()
+    pool = c.layers * blocks * 64 * c.row_width * 2
+    # the pool is read where it lies: no slice or gather of it beside it
+    assert compiled.memory_analysis().temp_size_in_bytes < pool // 100
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "latent_read" in text
+
+
+@pytest.mark.parametrize("told", [True, False], ids=["lengths", "no-lengths"])
+def test_mosaic_builds_flash_with_keys_of_192_and_values_of_128(
+        one_chip, no_compile_cache, told):
+    from langstream_tpu.ops.flash_attention import flash_attention
+
+    on = lambda shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dtype, sharding=one_chip)
+    if told:
+        fn = lambda q, k, v, n: flash_attention(  # noqa: E731
+            q, k, v, causal=True, scale=0.11472, lengths=n,
+            block_q=1024, block_k=1024)      # as models/latent.py serves it
+        args = (on((1,), jnp.int32),)
+    else:
+        fn = lambda q, k, v: flash_attention(  # noqa: E731
+            q, k, v, causal=True, scale=0.11472)
+        args = ()
+    compiled = jax.jit(fn).lower(
+        on((1, 8192, 16, 192)), on((1, 8192, 16, 192)), on((1, 8192, 16, 128)),
+        *args).compile()
+    assert "flash_prefill" in compiled.as_text()
+
+
+def test_the_selfcheck_s_latent_rows_hold_both_kernels(monkeypatch):
+    from langstream_tpu.models.latent import LatentConfig
+    from langstream_tpu.ops import paged_attention, selfcheck
+
+    rows = selfcheck.check_latent_kernels(
+        LatentConfig.tiny(), block_size=8, read_blocks=6, batch=4,
+        flash_seq=48, interpret=True)
+    assert [r["kernel"] for r in rows] == [
+        "_latent_read_kernel", "_flash_ragged_kernel"]
+    assert all(r["ok"] and r["interpret"] for r in rows), rows
+    # a read that forgets its scale is not ok
+    real = paged_attention.latent_read
+    monkeypatch.setattr(
+        paged_attention, "latent_read",
+        lambda *a, scale, **kw: real(*a, scale=scale * 1.5, **kw))
+    bad = selfcheck.check_latent_kernels(
+        LatentConfig.tiny(), block_size=8, read_blocks=6, batch=4,
+        flash_seq=48, interpret=True)
+    assert not bad[0]["ok"] and bad[1]["ok"]
