@@ -47,6 +47,9 @@ _DISPATCH_FUNCS = {
     "_speculative_burst",
     "_advance_prefills",
     "_admit",
+    "_admit_select",
+    "_admit_candidates",
+    "_admit_return",
     "_admit_dispatch",
     "_admit_complete",
     "_dispatch_prefill",

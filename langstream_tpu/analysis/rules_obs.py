@@ -127,6 +127,9 @@ _HOT_LOOP_FUNCS = {
     "_speculative_burst",
     "_advance_prefills",
     "_admit",
+    "_admit_select",
+    "_admit_candidates",
+    "_admit_return",
     "_admit_dispatch",
     "_admit_complete",
     "_dispatch_prefill",

@@ -125,7 +125,7 @@ from langstream_tpu.serving.qos import (
     priority_rank,
 )
 from langstream_tpu.serving.sampler import sample_tokens
-from langstream_tpu.serving.scheduler import make_scheduler
+from langstream_tpu.serving.scheduler import make_scheduler, plan_wave
 
 log = logging.getLogger(__name__)
 
@@ -3417,8 +3417,10 @@ class TpuServingEngine:
             # running engine decompose where its dispatches go without a
             # bench run
             "steps": dict(self.flight.steps_by_phase),
-            # the share of prefill batches dispatched one ahead (_admit)
+            # the share of prefill batches dispatched one ahead, and the
+            # requests a batch carried in the mean (_admit)
             "prefill_ahead_share": self.flight.prefill_ahead_share,
+            "prefill_rows_mean": self.flight.prefill_rows_mean,
             # how a decode step's pass over the Mamba-2 state is lowered
             # (what mamba_step was handed; None without such state)
             "ssm_state_kernel": self.ssm_state_kernel,
@@ -6959,47 +6961,61 @@ class TpuServingEngine:
     _admit_cut = False  # the round's admission stopped at the budget
 
     async def _admit(self, loop) -> None:
-        """Admit queued requests in batched prefill calls (grouped by
-        prompt-length bucket, count padded to a power of two by repeating
-        the last row — a duplicate write of identical K/V is a no-op).
+        """Admit queued requests in batched prefill calls: a wave at a time
+        (:meth:`_admit_select`: every request the free slots, the pool and
+        the queue allow, in the scheduler's order), cut into batches by
+        prompt-length bucket (``scheduler.plan_wave``: a bucket's requests
+        together, in powers of two, so no row of a program is padding).
 
         With the prefix cache on, each request first matches its
         prompt against cached block chains; matched requests adopt the
         shared blocks and prefill only the SUFFIX (grouped by suffix-length
         bucket, dispatched through the continuation path).
 
-        A wave's batches are dispatched ONE ahead: batch N+1 is selected,
-        packed and handed to the device (:meth:`_admit_dispatch`) before
-        batch N's first tokens are waited for and emitted
-        (:meth:`_admit_complete`), so the device runs N+1 while the host
-        fetches, emits and flushes N and packs N+2. Selection, keys and
-        dispatches keep the serial order, so every program gets the
-        arguments it always got; the depth is one and nothing is in flight
-        when this returns (or raises: what was dispatched is completed
-        first, so the loop's failure paths find the slots the serial order
-        left them). What N+1's selection no longer sees is what N's
-        completion does: a request that ends at its first token frees its
-        slot one batch later, and (docs/PREFIX.md) batch N registers its
-        prefixes after batch N+1 was matched, so N+1 misses what N is about
-        to publish, as two requests of one batch miss each other.
+        A wave's batches are dispatched ONE ahead: batch N+1 is packed and
+        handed to the device (:meth:`_admit_dispatch`) before batch N's
+        first tokens are waited for and emitted (:meth:`_admit_complete`),
+        so the device runs N+1 while the host fetches, emits and flushes N
+        and packs N+2. Keys are split in dispatch order; the depth is one
+        and nothing is in flight when this returns (or raises: what was
+        dispatched is completed first, and what was planned and not
+        dispatched is back in the queue, so the loop's failure paths find
+        every request in a slot or in the scheduler). A wave is matched
+        against the prefix cache before its first batch is dispatched
+        (docs/PREFIX.md): its requests miss what the wave itself is about
+        to publish, as two requests of one batch miss each other; and a
+        request that ends at its first token frees its slot for the next
+        wave, selected when this one's plan is spent.
 
         A round's prefills are bounded: once the batches completed since
         the last decode burst have taken ``_PREFILL_ROUND_S`` of the
-        device, no further batch is dispatched, and the slots still free
-        are filled after the next chunk (``_admit_cut`` tells the burst to
-        make it one)."""
+        device, no further batch is dispatched: what the plan still holds
+        returns to the queue's front in arrival order, reservations
+        released, and the slots still free are filled after the next chunk
+        (``_admit_cut`` tells the burst to make it one)."""
         flying = None  # the batch on the device: (batch, ticket, out, ahead)
+        plan: deque = deque()  # the wave's batches not yet dispatched
         try:
             while True:
+                nxt = None
                 if self._prefill_round_s >= self._PREFILL_ROUND_S:
+                    if plan:
+                        self.flight.event(
+                            "admit-cut", returned=sum(map(len, plan))
+                        )
+                    self._admit_return(plan)
                     # what ends a burst at its first chunk
                     # (_burst_should_yield): work waiting and a slot free
                     self._admit_cut = not self.scheduler.empty() and any(
                         s.free for s in self.slots
                     )
-                    nxt = None
                 else:
-                    nxt = await self._admit_dispatch(loop, flying is not None)
+                    if not plan:
+                        plan.extend(await self._admit_select(loop))
+                    if plan:
+                        nxt = await self._admit_dispatch(
+                            loop, plan.popleft(), flying is not None
+                        )
                 done, flying = flying, nxt
                 if done is not None:
                     await self._admit_complete(loop, *done)
@@ -7009,24 +7025,72 @@ class TpuServingEngine:
             if flying is not None:
                 await self._admit_complete(loop, *flying)
             raise
+        finally:
+            self._admit_return(plan)
 
-    async def _admit_dispatch(self, loop, ahead: bool):
-        """Select, claim, pack and dispatch the next prefill batch; None
-        when the queue, the free slots or the pool yield none. ``ahead``
-        says its predecessor is still unfetched (the flight sample's
-        ``ahead``)."""
+    def _admit_return(self, plan) -> None:
+        """Give what a wave claimed and did not dispatch back to the
+        scheduler's front, in arrival order (slots were handed out in
+        that order), with the reservations released. Empties ``plan``, an
+        iterable of batches of (slot, request, reuse)."""
+        if not plan:
+            return
+        entries = sorted(
+            (entry for batch in plan for entry in batch),
+            key=lambda entry: entry[0],
+        )
+        plan.clear()
+        for slot_id, _request, _reuse in entries:
+            self.block_mgr.release(slot_id)
+        self.scheduler.give_back([request for _s, request, _r in entries])
+
+    async def _admit_select(self, loop) -> list:
+        """Select and claim a wave, and plan its batches: the requests the
+        scheduler yields until the free slots, the pool's reservations or
+        the queue run out, each popped with its slot and its reservation
+        (and its matched prefix adopted), then cut into prefill batches of
+        (slot, request, reuse) by ``plan_wave``. Empty when the queue, the
+        free slots or the pool yield none."""
+        wave: list[tuple[int, _Request, int]] = []  # (slot, req, reuse)
+        try:
+            await self._admit_candidates(loop, wave)
+        except BaseException:
+            # popped and reserved but in no slot: invisible to every
+            # failure path (the shrink sweep and _fail_inflight walk slots)
+            self._admit_return([wave])
+            raise
+        # what decides a candidate's program: its bucket, and whether it
+        # continues an adopted prefix (plain rows stay on the flash path)
+        programs = [
+            (self._prefill_bucket(request, reuse), reuse > 0)
+            for _slot, request, reuse in wave
+        ]
+        classes = (
+            [request.priority for _slot, request, _reuse in wave]
+            if self.scheduler.depths() is not None else None
+        )
+        return [
+            [wave[i] for i in batch]
+            for batch in plan_wave(
+                programs, len(wave), self.config.prefill_batch, classes
+            )
+        ]
+
+    def _prefill_bucket(self, request, reuse: int) -> int:
+        return _bucket(
+            len(request.context_tokens) - reuse,
+            hi=self.model_config.max_seq_len,
+        )
+
+    async def _admit_candidates(self, loop, wave: list) -> None:
+        """The selection pass of :meth:`_admit_select`: everything per
+        candidate, in the scheduler's order, until the wave ends at the
+        first candidate the pool cannot admit (nobody is admitted past a
+        request that waits for blocks). Appends to ``wave``, so that what
+        was claimed is known to the caller when this raises."""
         use_prefix = self.config.prefix_cache
-        if self.scheduler.empty():
-            return None
         free = [i for i, s in enumerate(self.slots) if s.free]
-        if not free:
-            return None
-        batch: list[tuple[int, _Request, int]] = []  # (slot, req, reuse)
-        bucket = None
-        while (
-            not self.scheduler.empty()
-            and len(batch) < min(len(free), self.config.prefill_batch)
-        ):
+        while not self.scheduler.empty() and len(wave) < len(free):
             # ``ls.admit`` spans the synchronous stretches of this
             # pass; its two awaits (adapter resolve, prefix promotion)
             # run outside any span
@@ -7168,7 +7232,7 @@ class TpuServingEngine:
                     # chunked prefill: claim the slot + reservation now, but
                     # feed the prompt through _advance_prefills one bounded
                     # chunk per loop pass instead of one monolithic prefill
-                    slot_id = free.pop(len(batch))
+                    slot_id = free.pop(len(wave))
                     self.scheduler.pop()
                     self.block_mgr.admit(
                         slot_id,
@@ -7196,16 +7260,9 @@ class TpuServingEngine:
                         self._fault("pool-grow")
                         self.block_mgr.ensure_capacity(slot_id, len(ctx))
                     except Exception as e:
-                        # monolithic members selected earlier this pass
-                        # are popped + reserved but NOT yet slotted —
-                        # invisible to every failure path (the shrink
-                        # sweep and _fail_inflight both walk slots):
-                        # undo them first, reservations released and
-                        # requeued front in order
-                        for sid, req, _r in reversed(batch):
-                            self.block_mgr.release(sid)
-                            self.scheduler.requeue_front(req)
-                        batch.clear()
+                        # (the wave's monolithic members, popped and not
+                        # yet slotted, are returned by _admit_select, to
+                        # the front of this one)
                         if not self._resource_exhausted(e):
                             raise
                         if request.preemptions >= _SHRINK_RETRY_CAP:
@@ -7226,23 +7283,22 @@ class TpuServingEngine:
                         self._m_prefix_hits(1)
                         self._m_prefix_tokens(reuse)
                     continue
-                b = _bucket(to_prefill, hi=self.model_config.max_seq_len)
-                if bucket is None:
-                    bucket = b
-                elif b != bucket:
-                    break
-                slot_id = free[len(batch)]
+                slot_id = free[len(wave)]
                 self.scheduler.pop()
                 # reserve at pop time so the NEXT peek's can_admit sees
-                # this batch member's reservation
+                # this wave member's reservation
                 self.block_mgr.admit(
                     slot_id, len(request.prompt_tokens) + request.max_tokens + 1
                 )
                 if blocks:
                     self.block_mgr.adopt_prefix(slot_id, blocks)
-                batch.append((slot_id, request, reuse))
-        if not batch:
-            return None
+                wave.append((slot_id, request, reuse))
+
+    async def _admit_dispatch(self, loop, batch, ahead: bool):
+        """Slot, pack and dispatch one planned prefill batch. ``ahead``
+        says its predecessor is still unfetched (the flight sample's
+        ``ahead``)."""
+        bucket = self._prefill_bucket(batch[0][1], batch[0][2])
         with self.flight.span(
             "ls.admit", queued=self.scheduler.qsize(),
             admitted=len(batch),

@@ -180,6 +180,7 @@ class FlightRecorder:
         self.spec_accepted = 0
         self.spec_rejected = 0
         self.prefill_ahead = 0
+        self.prefill_rows = 0
 
     # -- recording (engine hot path: appends + counter bumps only) -------
 
@@ -308,6 +309,8 @@ class FlightRecorder:
         if ahead is not None:
             entry["ahead"] = ahead
             self.prefill_ahead += ahead
+        if phase == "prefill":
+            self.prefill_rows += tokens
         if prompt_tokens is not None:
             entry["prompt_tokens"] = prompt_tokens
         self._samples.append(entry)
@@ -419,6 +422,13 @@ class FlightRecorder:
         return round(self.prefill_ahead / batches, 4) if batches else None
 
     @property
+    def prefill_rows_mean(self) -> float | None:
+        """Requests a prefill batch carried, mean over all prefill batches
+        (the samples' ``tokens``; cumulative; None before the first)."""
+        batches = self.steps_by_phase.get("prefill", 0)
+        return round(self.prefill_rows / batches, 4) if batches else None
+
+    @property
     def dropped(self) -> int:
         """Samples evicted from the ring (0 until ``recorded`` exceeds
         ``LS_TPU_FLIGHT_BUFFER``)."""
@@ -479,6 +489,7 @@ class FlightRecorder:
                 "spec_accepted": self.spec_accepted,
                 "spec_rejected": self.spec_rejected,
                 "prefill_ahead_share": self.prefill_ahead_share,
+                "prefill_rows_mean": self.prefill_rows_mean,
             },
             "window": {
                 "samples": len(window),

@@ -32,7 +32,7 @@ from __future__ import annotations
 
 import time
 from collections import deque
-from typing import Any, Iterable
+from typing import Any, Hashable, Iterable, Sequence
 
 from langstream_tpu.serving.qos import (
     PRIORITY_CLASSES,
@@ -48,6 +48,51 @@ def _pct(sorted_values: list, q: float):
     if not sorted_values:
         return None
     return sorted_values[min(len(sorted_values) - 1, int(q * len(sorted_values)))]
+
+
+def plan_wave(
+    buckets: Sequence[Hashable],
+    free: int,
+    prefill_batch: int,
+    classes: Sequence[Hashable] | None = None,
+) -> list[list[int]]:
+    """Cut a wave's candidates into prefill batches, as lists of indices.
+
+    ``buckets`` holds, in the order the scheduler yielded them, what decides
+    a candidate's prefill program (its prompt's length bucket); the first
+    ``free`` of them are the wave. Candidates of one bucket go together in
+    arrival order, a bucket's group is cut into powers of two, largest
+    first, of at most ``prefill_batch`` rows (5 -> 4 + 1, 7 -> 4 + 2 + 1),
+    so a program's rows are all requests and none is padding, and the
+    batches go out in the order of their oldest member: the scheduler's
+    head is in the first batch. With ``prefill_batch`` 1, or one bucket,
+    that is the arrival order.
+
+    ``classes`` (the QoS scheduler's class of each candidate) keeps the
+    grouping inside a run of one class as the scheduler yielded it, so no
+    request is put ahead of one of a higher class."""
+    n = min(len(buckets), free)
+    cap = 1 << (max(prefill_batch, 1).bit_length() - 1)
+    plan: list[list[int]] = []
+    start = 0
+    while start < n:
+        end = start + 1
+        while (
+            end < n and (classes is None or classes[end] == classes[start])
+        ):
+            end += 1
+        groups: dict[Hashable, list[int]] = {}
+        for i in range(start, end):
+            groups.setdefault(buckets[i], []).append(i)
+        run = []
+        for group in groups.values():
+            while group:
+                rows = min(cap, 1 << (len(group).bit_length() - 1))
+                run.append(group[:rows])
+                group = group[rows:]
+        plan.extend(sorted(run, key=lambda batch: batch[0]))
+        start = end
+    return plan
 
 
 class Scheduler:
@@ -74,6 +119,13 @@ class Scheduler:
         raise NotImplementedError
 
     def requeue_front(self, request) -> None:
+        raise NotImplementedError
+
+    def give_back(self, requests: Sequence) -> None:
+        """Undo the ``pop`` of ``requests`` (given in the order they were
+        popped): admission took them for a wave and did not dispatch them.
+        They return to the front in that order and are not counted as
+        admitted until they are popped again."""
         raise NotImplementedError
 
     def drain(self) -> list:
@@ -127,6 +179,10 @@ class FifoScheduler(Scheduler):
 
     def requeue_front(self, request) -> None:
         self._queue.appendleft(request)
+
+    def give_back(self, requests) -> None:
+        self._queue.extendleft(reversed(requests))
+        self.admitted -= len(requests)
 
     def drain(self) -> list:
         out = list(self._queue)
@@ -216,6 +272,18 @@ class QosScheduler(Scheduler):
         # policy applies to NEW work, never to work already admitted
         cls = normalize_priority(getattr(request, "priority", "default"))
         self._queues[cls].appendleft(request)
+
+    def give_back(self, requests) -> None:
+        # each returns to its class's front with the credit its pop spent,
+        # so the class's next dequeues are these again; a wait sampled at
+        # the pop stays in the window and is sampled again at the next
+        for request in reversed(requests):
+            self.requeue_front(request)
+            cls = request.priority
+            self._deficit[cls] += 1.0
+            self.counters[cls]["admitted"] -= 1
+            if getattr(request, "preemptions", 0):
+                self.counters[cls]["resumed"] -= 1
 
     # -- WDRR dequeue ----------------------------------------------------
 

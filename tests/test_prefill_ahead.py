@@ -1,22 +1,26 @@
 """A wave's prefill batches are dispatched one ahead (engine ``_admit``):
 batch N+1 is packed and queued on the device before batch N's first tokens
 are fetched. What has to hold, on the CPU at the tiny sizes: the served
-tokens and logprobs are the serial order's, the flight samples say which
-batches were ahead, nothing is in flight when ``_admit`` returns, and a
-fault with a batch in flight leaves what the serial order left."""
+tokens and logprobs are the serial order's (the wave's plan, a batch at a
+time), the flight samples say which batches were ahead, nothing is in
+flight when ``_admit`` returns, and a fault with a batch in flight leaves
+what the serial order left."""
 
 from __future__ import annotations
 
 import asyncio
+from collections import deque
 
 import pytest
 
 from langstream_tpu.serving.faults import FaultPlan
 
 #: eight prompts whose byte lengths alternate over the buckets 32, 64 and
-#: 128, so the bucket rule ends every batch after one request: a wave of
-#: eight batches
+#: 128, so no two neighbours share a program. The wave's plan
+#: (``scheduler.plan_wave``) groups them by bucket and cuts at powers of
+#: two: requests (0, 3), (1, 5), (2, 4), 6, 7: five batches
 _LENGTHS = (10, 40, 90, 20, 70, 35, 12, 100)
+_ROWS = [2, 2, 2, 1, 1]
 PROMPTS = [
     "".join(chr(97 + (i * 7 + j) % 26) for j in range(n))
     for i, n in enumerate(_LENGTHS)
@@ -42,12 +46,23 @@ def _config(**kw):
 
 
 def _serial(engine) -> None:
-    """The order before the batches were dispatched ahead: each batch is
-    fetched and emitted before the next is selected."""
+    """The plan's order with nothing dispatched ahead: each batch of a
+    wave is fetched and emitted before the next is dispatched."""
 
     async def admit(loop):
-        while (handle := await engine._admit_dispatch(loop, False)) is not None:
-            await engine._admit_complete(loop, *handle)
+        plan = deque()
+        try:
+            while True:
+                if not plan:
+                    plan.extend(await engine._admit_select(loop))
+                if not plan:
+                    break
+                handle = await engine._admit_dispatch(
+                    loop, plan.popleft(), False
+                )
+                await engine._admit_complete(loop, *handle)
+        finally:
+            engine._admit_return(plan)  # what a failure left undispatched
 
     engine._admit = admit
 
@@ -131,7 +146,7 @@ async def _wave(config, temperature, serial=False, prompts=PROMPTS):
 def test_a_wave_ahead_serves_the_serial_orders_tokens(
     run_async, name, temperature
 ):
-    """Eight batches of unlike buckets: tokens and logprobs equal the serial
+    """Five batches over three buckets: tokens and logprobs equal the serial
     order's, request for request (the keys are split in dispatch order, and
     a batch's program gets the arguments it always got)."""
     config = _config(**CONFIGS[name])
@@ -142,7 +157,7 @@ def test_a_wave_ahead_serves_the_serial_orders_tokens(
     assert [s["program"] for s in ahead["prefill"]] == [
         s["program"] for s in serial["prefill"]
     ]
-    assert len(ahead["prefill"]) == len(PROMPTS)
+    assert [s["tokens"] for s in ahead["prefill"]] == _ROWS
     assert all(len(tokens) == 6 for tokens, _ in ahead["outs"])
 
 
@@ -153,14 +168,14 @@ def test_the_flight_samples_say_which_batches_were_ahead(run_async, name):
     return of ``_admit`` each dispatched batch has been fetched."""
     ahead = run_async(_wave(_config(**CONFIGS[name]), 0))
     flags = [s["ahead"] for s in ahead["prefill"]]
-    assert flags == [0] + [1] * (len(PROMPTS) - 1)
-    assert ahead["share"] == ahead["stats_share"] == round(7 / 8, 4)
+    assert flags == [0] + [1] * (len(_ROWS) - 1)
+    assert ahead["share"] == ahead["stats_share"] == round(4 / 5, 4)
     seen = ahead["seen"]
-    assert seen["dispatched"] == seen["fetched"] == len(PROMPTS)
+    assert seen["dispatched"] == seen["fetched"] == len(_ROWS)
     assert seen["open_at_return"] and set(seen["open_at_return"]) == {0}
     # the serial order reads 0 throughout
     serial = run_async(_wave(_config(**CONFIGS[name]), 0, serial=True))
-    assert [s["ahead"] for s in serial["prefill"]] == [0] * len(PROMPTS)
+    assert [s["ahead"] for s in serial["prefill"]] == [0] * len(_ROWS)
     assert serial["share"] == 0.0
 
 
@@ -201,9 +216,10 @@ def test_an_allocator_refusal_with_a_batch_in_flight_requeues_the_second(
     run_async, serial
 ):
     """The second batch's dispatch is refused memory while the first is
-    unfetched: the first batch's request keeps its slot and decodes on, the
-    second's is swept back to the queue by the shrink pass and prefilled
-    again, and every request ends with the tokens of an undisturbed run."""
+    unfetched: the first batch's requests keep their slots and decode on,
+    the second's are swept back to the queue by the shrink pass and
+    prefilled again, what the plan still held returns to the queue, and
+    every request ends with the tokens of an undisturbed run."""
     base = run_async(_wave(_config(), 0))
     faults = (FaultPlan(site="prefill", shape="oom", after=1, count=1),)
     got = run_async(_wave(_config(faults=faults), 0, serial=serial))
@@ -211,8 +227,10 @@ def test_an_allocator_refusal_with_a_batch_in_flight_requeues_the_second(
     assert got["survival"]["shrinks"] >= 1
     assert all(got["free"]) and got["reserved"] == 0
     seen = got["seen"]
-    # eight requests, one of them dispatched twice, the refused one uncounted
-    assert seen["dispatched"] == seen["fetched"] == len(PROMPTS)
+    # the first batch, then (the refused one uncounted, its two requests
+    # swept back, the rest of the plan returned) the six left, planned
+    # again: (1, 5), (2, 4), 6, 7
+    assert seen["dispatched"] == seen["fetched"] == 5
     assert set(seen["open_at_return"]) == {0}
 
 
