@@ -36,6 +36,7 @@ from langstream_tpu.agents.services import (
     resolve_service_provider,
 )
 from langstream_tpu.core.expressions import evaluate_accessor, render_template
+from langstream_tpu.core.tracing import host_span
 
 log = logging.getLogger(__name__)
 
@@ -155,25 +156,28 @@ class _StreamWriter:
     async def _flush(self, last: bool) -> None:
         if not self.buffer and not last:
             return
-        text = "".join(self.buffer)
-        self.buffer = []
-        if self.completion_field == "value":
-            value: Any = text
-        else:
-            mutable = MutableRecord(value={})
-            mutable.set_field(self.completion_field, text)
-            value = mutable.value
-        record = make_record(
-            value=value,
-            key=self.source_record.key,
-            headers=dict(self.source_record.headers)
-            | {
-                "stream-id": self.stream_id,
-                "stream-index": str(self.index),
-                "stream-last-message": str(last).lower(),
-            },
-        )
-        self.index += 1
+        # runs on the engine's loop between two of its dispatches: building
+        # the record is this hop's synchronous stretch, the write the topic's
+        with host_span("ls.hop.agent"):
+            text = "".join(self.buffer)
+            self.buffer = []
+            if self.completion_field == "value":
+                value: Any = text
+            else:
+                mutable = MutableRecord(value={})
+                mutable.set_field(self.completion_field, text)
+                value = mutable.value
+            record = make_record(
+                value=value,
+                key=self.source_record.key,
+                headers=dict(self.source_record.headers)
+                | {
+                    "stream-id": self.stream_id,
+                    "stream-index": str(self.index),
+                    "stream-last-message": str(last).lower(),
+                },
+            )
+            self.index += 1
         await self.producer.write(record)
 
 
@@ -188,61 +192,66 @@ class ChatCompletionsAgent(_AIAgentBase):
             self._stream_producer = context.get_topic_producer(stream_topic)
 
     async def process_record(self, record: Record) -> list[Record]:
-        mutable = MutableRecord.from_record(record)
-        messages = [
-            {
-                "role": m.get("role", "user"),
-                "content": render_template(m.get("content", ""), mutable),
-            }
-            for m in self.configuration.get("messages", [])
-        ]
-        writer = None
-        consumer = None
-        if self._stream_producer is not None:
-            writer = _StreamWriter(
-                self._stream_producer,
-                record,
-                self.configuration.get("stream-response-completion-field", "value"),
-                int(self.configuration.get("min-chunks-per-message", 20)),
-            )
-            consumer = writer.on_chunk
+        # ``ls.hop.agent``: the two synchronous stretches of this hop on
+        # the engine's loop, never the wait for the engine's answer
+        with host_span("ls.hop.agent"):
+            mutable = MutableRecord.from_record(record)
+            messages = [
+                {
+                    "role": m.get("role", "user"),
+                    "content": render_template(m.get("content", ""), mutable),
+                }
+                for m in self.configuration.get("messages", [])
+            ]
+            consumer = None
+            if self._stream_producer is not None:
+                consumer = _StreamWriter(
+                    self._stream_producer,
+                    record,
+                    self.configuration.get(
+                        "stream-response-completion-field", "value"
+                    ),
+                    int(self.configuration.get("min-chunks-per-message", 20)),
+                ).on_chunk
+            options = self._options(record)
         try:
             result = await self.provider.get_completions_service(
                 self.configuration
-            ).chat_completions(messages, self._options(record), consumer)
+            ).chat_completions(messages, options, consumer)
         except asyncio.CancelledError:
             if self._stream_cancelled(record):
                 return []  # client disconnect: terminal, commit quietly
             raise
 
-        completion_field = self.configuration.get("completion-field")
-        if completion_field:
-            if completion_field == "value":
-                mutable.value = result.text
-            else:
-                mutable.set_field(completion_field, result.text)
-        log_field = self.configuration.get("log-field")
-        if log_field:
-            mutable.set_field(log_field, json.dumps(messages))
-        for header_name, attr in (
-            ("prompt-tokens", "num_prompt_tokens"),
-            ("completion-tokens", "num_completion_tokens"),
-        ):
-            mutable.properties[f"langstream-{header_name}"] = str(
-                getattr(result, attr)
-            )
-        if result.ttft_s > 0:
-            # engine-measured decomposition: client TTFT minus this is the
-            # gateway/broker transport share
+        with host_span("ls.hop.agent"):
+            completion_field = self.configuration.get("completion-field")
+            if completion_field:
+                if completion_field == "value":
+                    mutable.value = result.text
+                else:
+                    mutable.set_field(completion_field, result.text)
+            log_field = self.configuration.get("log-field")
+            if log_field:
+                mutable.set_field(log_field, json.dumps(messages))
             for header_name, attr in (
-                ("ttft-ms", "ttft_s"),
-                ("queue-wait-ms", "queue_wait_s"),
-                ("prefill-ms", "prefill_s"),
+                ("prompt-tokens", "num_prompt_tokens"),
+                ("completion-tokens", "num_completion_tokens"),
             ):
                 mutable.properties[f"langstream-{header_name}"] = str(
-                    round(getattr(result, attr) * 1000, 3)
+                    getattr(result, attr)
                 )
-        return [mutable.to_record()]
+            if result.ttft_s > 0:
+                # engine-measured decomposition: client TTFT minus this is
+                # the gateway/broker transport share
+                for header_name, attr in (
+                    ("ttft-ms", "ttft_s"),
+                    ("queue-wait-ms", "queue_wait_s"),
+                    ("prefill-ms", "prefill_s"),
+                ):
+                    mutable.properties[f"langstream-{header_name}"] = str(
+                        round(getattr(result, attr) * 1000, 3)
+                    )
+            return [mutable.to_record()]
 
 
 class TextCompletionsAgent(_AIAgentBase):
@@ -256,37 +265,41 @@ class TextCompletionsAgent(_AIAgentBase):
             self._stream_producer = context.get_topic_producer(stream_topic)
 
     async def process_record(self, record: Record) -> list[Record]:
-        mutable = MutableRecord.from_record(record)
-        prompt_cfg = self.configuration.get("prompt", [])
-        if isinstance(prompt_cfg, str):
-            prompt_cfg = [prompt_cfg]
-        prompt = "\n".join(render_template(p, mutable) for p in prompt_cfg)
-        consumer = None
-        if self._stream_producer is not None:
-            writer = _StreamWriter(
-                self._stream_producer,
-                record,
-                self.configuration.get("stream-response-completion-field", "value"),
-                int(self.configuration.get("min-chunks-per-message", 20)),
-            )
-            consumer = writer.on_chunk
+        with host_span("ls.hop.agent"):  # as in ChatCompletionsAgent
+            mutable = MutableRecord.from_record(record)
+            prompt_cfg = self.configuration.get("prompt", [])
+            if isinstance(prompt_cfg, str):
+                prompt_cfg = [prompt_cfg]
+            prompt = "\n".join(render_template(p, mutable) for p in prompt_cfg)
+            consumer = None
+            if self._stream_producer is not None:
+                consumer = _StreamWriter(
+                    self._stream_producer,
+                    record,
+                    self.configuration.get(
+                        "stream-response-completion-field", "value"
+                    ),
+                    int(self.configuration.get("min-chunks-per-message", 20)),
+                ).on_chunk
+            options = self._options(record)
         try:
             result = await self.provider.get_completions_service(
                 self.configuration
-            ).text_completions(prompt, self._options(record), consumer)
+            ).text_completions(prompt, options, consumer)
         except asyncio.CancelledError:
             if self._stream_cancelled(record):
                 return []  # client disconnect: terminal, commit quietly
             raise
-        completion_field = self.configuration.get("completion-field", "value")
-        if completion_field == "value":
-            mutable.value = result.text
-        else:
-            mutable.set_field(completion_field, result.text)
-        log_field = self.configuration.get("log-field")
-        if log_field:
-            mutable.set_field(log_field, prompt)
-        return [mutable.to_record()]
+        with host_span("ls.hop.agent"):
+            completion_field = self.configuration.get("completion-field", "value")
+            if completion_field == "value":
+                mutable.value = result.text
+            else:
+                mutable.set_field(completion_field, result.text)
+            log_field = self.configuration.get("log-field")
+            if log_field:
+                mutable.set_field(log_field, prompt)
+            return [mutable.to_record()]
 
 
 class ComputeAIEmbeddingsAgent(AgentProcessor):

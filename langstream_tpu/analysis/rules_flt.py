@@ -54,6 +54,7 @@ _DISPATCH_FUNCS = {
     "_admit_complete",
     "_dispatch_prefill",
     "_fetch_prefill",
+    "_await_chunk",
     "_apply_imports",
     "_export_ready_slots",
     "_export_slot",

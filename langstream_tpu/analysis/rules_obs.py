@@ -134,6 +134,14 @@ _HOT_LOOP_FUNCS = {
     "_admit_complete",
     "_dispatch_prefill",
     "_fetch_prefill",
+    # the dispatch's clock (flight.py DispatchClock, resumed): the chunk's
+    # blocking fetch on the dispatch thread, the loop's wait for it, the
+    # ticket they write into, the delivery under ``ls.hop.deliver``
+    "_fetch_chunk",
+    "_await_chunk",
+    "_drain_pending",
+    "_ticket",
+    "_deliver_chunk",
     "_process_chunk",
     "_emit_token",
     "_flush_emits",

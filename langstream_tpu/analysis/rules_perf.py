@@ -57,6 +57,8 @@ _DISPATCH_FUNCS = {
     "_admit_dispatch",
     "_admit_complete",
     "_dispatch_prefill",
+    "_await_chunk",
+    "_deliver_chunk",
     "_process_chunk",
     "_emit_token",
     "_flush_emits",

@@ -10,7 +10,8 @@ number (see ``docs/OBSERVABILITY.md``).
 
 Design constraints (this module is on the record hot path):
 
-- **zero dependencies** — stdlib only, importable from every layer;
+- **zero dependencies** — stdlib only, importable from every layer
+  (:func:`host_span` uses JAX's profiler only where JAX is already loaded);
 - **always-on-cheap** — a span is one small object and one deque append;
   ids come from ``os.urandom``; durations from ``time.monotonic()``
   (wall clock is for display anchoring only, never measurement);
@@ -38,10 +39,12 @@ Context propagates two ways:
 
 from __future__ import annotations
 
+import contextlib
 import contextvars
 import json
 import logging
 import os
+import sys
 import threading
 import time
 from collections import deque
@@ -274,6 +277,39 @@ def record_span(
         SPANS.add(span._to_dict(duration_s))
     except Exception:
         log.debug("record_span failed", exc_info=True)
+
+
+# ---------------------------------------------------------------------------
+# host spans on the profiler's clock
+# ---------------------------------------------------------------------------
+
+#: what :func:`host_span` hands out where no profiler session can be open
+_NO_SPAN = contextlib.nullcontext()
+_annotation = None  # jax.profiler.TraceAnnotation, once JAX is loaded
+_session = None  # its ``is_enabled``: whether a profiler session is open
+
+
+def host_span(name: str, **meta: Any):
+    """A host span on the PROFILER's clock (not this module's ring): a
+    ``jax.profiler.TraceAnnotation`` around synchronous code, so that under
+    a profiler session (``/profile/start``, the benchmark's ``--trace 1``)
+    the stretch lands in the ``/host:CPU`` plane of the same ``.xplane.pb``
+    as the device's operations. ``name`` is one of
+    ``serving/flight.py`` ``SPANS`` (the whole vocabulary); ``meta`` become
+    the event's stats. Outside a session this checks the profiler's flag and
+    hands out one shared no-op context manager; so it does in a process that
+    never loaded JAX (a gateway pod with no engine): the helper never
+    imports JAX itself."""
+    global _annotation, _session
+    if _session is None:
+        if "jax" not in sys.modules:
+            return _NO_SPAN
+        from jax.profiler import TraceAnnotation as _annotation
+
+        _session = _annotation.is_enabled
+    if not _session():
+        return _NO_SPAN
+    return _annotation(name, **meta)
 
 
 # ---------------------------------------------------------------------------

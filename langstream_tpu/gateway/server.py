@@ -35,7 +35,12 @@ from langstream_tpu.api.topics import (
     OFFSET_HEADER,
     TopicConnectionsRuntimeRegistry,
 )
-from langstream_tpu.core.tracing import TRACE_HEADER, TraceContext, start_span
+from langstream_tpu.core.tracing import (
+    TRACE_HEADER,
+    TraceContext,
+    host_span,
+    start_span,
+)
 from langstream_tpu.gateway.auth import (
     AuthenticationException,
     get_auth_provider,
@@ -1055,23 +1060,27 @@ class GatewayServer:
                 if msg.type != WSMsgType.TEXT:
                     continue
                 try:
-                    payload = json.loads(msg.data)
-                    headers, span = self._traced_headers(
-                        {**(payload.get("headers") or {}), **inject},
-                        "gateway.chat",
-                    )
-                    self._stamp_replica(
-                        headers, tenant, app_id, params, principal,
-                        value=payload.get("value"),
-                    )
-                    self._stamp_deadline(
-                        headers, limiter, params, qos_priority
-                    )
-                    retry = (
-                        limiter.admit_request(qos_tenant)
-                        if limiter is not None
-                        else None
-                    )
+                    # from a client's frame to its produce, on the loop
+                    # this gateway shares with the engine in a one-pod
+                    # deployment (the write itself is ``ls.hop.topic``)
+                    with host_span("ls.hop.gw.recv"):
+                        payload = json.loads(msg.data)
+                        headers, span = self._traced_headers(
+                            {**(payload.get("headers") or {}), **inject},
+                            "gateway.chat",
+                        )
+                        self._stamp_replica(
+                            headers, tenant, app_id, params, principal,
+                            value=payload.get("value"),
+                        )
+                        self._stamp_deadline(
+                            headers, limiter, params, qos_priority
+                        )
+                        retry = (
+                            limiter.admit_request(qos_tenant)
+                            if limiter is not None
+                            else None
+                        )
                     if retry is not None:
                         span.end(error="throttled")
                         self._count_throttle(qos_tenant)
@@ -1130,17 +1139,22 @@ class GatewayServer:
         try:
             while not ws.closed:
                 records = await reader.read(timeout=0.5)
-                for record in records:
-                    headers = record.header_map()
-                    if all(headers.get(k) == v for k, v in inject.items()):
-                        await ws.send_json(self._record_json(record))
-                        if (
-                            active
-                            and str(headers.get(STREAM_LAST_HEADER)).lower()
-                            == "true"
-                        ):
-                            # completed stream: drop its cancel handle
-                            active.discard(headers.get(STREAM_ID_HEADER))
+                if not records:
+                    continue
+                # from a record to the end of its frame's send (held across
+                # ``send_json``, which yields only under back-pressure)
+                with host_span("ls.hop.gw.send", records=len(records)):
+                    for record in records:
+                        headers = record.header_map()
+                        if all(headers.get(k) == v for k, v in inject.items()):
+                            await ws.send_json(self._record_json(record))
+                            if (
+                                active
+                                and str(headers.get(STREAM_LAST_HEADER)).lower()
+                                == "true"
+                            ):
+                                # completed stream: drop its cancel handle
+                                active.discard(headers.get(STREAM_ID_HEADER))
         except (asyncio.CancelledError, ConnectionResetError):
             pass
         except Exception:

@@ -34,6 +34,7 @@ from langstream_tpu.api.topics import (
     TopicProducer,
     TopicReader,
 )
+from langstream_tpu.core.tracing import host_span
 
 
 class _Partition:
@@ -127,16 +128,19 @@ class MemoryBroker:
     async def publish(self, topic_name: str, record: Record) -> TopicOffset:
         topic = self.topic(topic_name)
         async with topic.cond:
-            partition = topic.route(record)
-            stamped = SimpleRecord(
-                value=record.value,
-                key=record.key,
-                headers=record.headers,
-                origin=topic_name,
-                timestamp=record.timestamp,
-            )
-            offset = partition.append(stamped)
-            topic.cond.notify_all()
+            # a write's synchronous stretch on the loop it shares with the
+            # engine (``ls.hop.topic``; the readers it wakes open their own)
+            with host_span("ls.hop.topic"):
+                partition = topic.route(record)
+                stamped = SimpleRecord(
+                    value=record.value,
+                    key=record.key,
+                    headers=record.headers,
+                    origin=topic_name,
+                    timestamp=record.timestamp,
+                )
+                offset = partition.append(stamped)
+                topic.cond.notify_all()
         return TopicOffset(topic_name, partition.index, offset)
 
 
@@ -215,7 +219,8 @@ class MemoryTopicConsumer(TopicConsumer):
                 await asyncio.wait_for(topic.cond.wait(), timeout=self.poll_timeout)
             except asyncio.TimeoutError:
                 return []
-            return self._poll_locked(topic)
+            with host_span("ls.hop.topic"):  # from wake to return
+                return self._poll_locked(topic)
 
     def _poll_locked(self, topic: MemoryTopic) -> list[Record]:
         batch: list[Record] = []
@@ -297,7 +302,8 @@ class MemoryTopicReader(TopicReader):
                 await asyncio.wait_for(topic.cond.wait(), timeout=timeout)
             except asyncio.TimeoutError:
                 return []
-            return self._poll_locked(topic)
+            with host_span("ls.hop.topic"):  # from wake to return
+                return self._poll_locked(topic)
 
     def _poll_locked(self, topic: MemoryTopic) -> list[Record]:
         batch: list[Record] = []

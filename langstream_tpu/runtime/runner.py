@@ -44,7 +44,12 @@ from langstream_tpu.api.topics import (
     TopicProducer,
 )
 from langstream_tpu.core.asyncutil import spawn_retained
-from langstream_tpu.core.tracing import TRACE_HEADER, TraceContext, start_span
+from langstream_tpu.core.tracing import (
+    TRACE_HEADER,
+    TraceContext,
+    host_span,
+    start_span,
+)
 from langstream_tpu.gateway.router import (
     BOUNCE_HEADER,
     MAX_BOUNCES,
@@ -421,12 +426,15 @@ class AgentRunner:
                 if not records:
                     await asyncio.sleep(0)
                     continue
-                self.records_in += len(records)
-                self._m_records_in(len(records))
-                self._inflight += len(records)
-                self._m_pending(self._inflight)
-                records = [self._begin_record_trace(r) for r in records]
-                self.processor.process(records, self.record_sink)
+                # the per-record bookkeeping before the agent's call, on
+                # the loop this runner shares with its agent's engine
+                with host_span("ls.hop.runner", records=len(records)):
+                    self.records_in += len(records)
+                    self._m_records_in(len(records))
+                    self._inflight += len(records)
+                    self._m_pending(self._inflight)
+                    records = [self._begin_record_trace(r) for r in records]
+                    self.processor.process(records, self.record_sink)
                 await asyncio.sleep(0)
         except Exception as e:  # loop-level failure is fatal for the replica
             self._fatal = e
@@ -558,10 +566,11 @@ class AgentRunner:
         if result.error is not None:
             await self._handle_error(result.source_record, result.error)
             return
-        self.errors_handler.clear(result.source_record)
-        self._inflight = max(0, self._inflight - 1)
-        self._m_pending(self._inflight)
-        self.tracker.track(result.source_record, len(result.results))
+        with host_span("ls.hop.runner"):  # and after the agent's answer
+            self.errors_handler.clear(result.source_record)
+            self._inflight = max(0, self._inflight - 1)
+            self._m_pending(self._inflight)
+            self.tracker.track(result.source_record, len(result.results))
         if not result.results:
             await self.tracker.commit_if_tracked_empty(result.source_record)
             self._finish_record_trace(result.source_record, results=0)

@@ -85,7 +85,7 @@ from langstream_tpu.serving.faults import (
     InjectedFault,
     plans_from_env,
 )
-from langstream_tpu.serving.flight import FlightRecorder
+from langstream_tpu.serving.flight import FlightRecorder, resumed
 from langstream_tpu.serving.incident import (
     IncidentRecorder,
     adapter_eviction_storm,
@@ -2576,20 +2576,23 @@ class TpuServingEngine:
         dispatch: int | None = None,
         steps: int = 0,
         active_at_dispatch: int | None = None,
-        live_blocks: int | None = None,
-        table_blocks: int | None = None,
         live_rows: int | None = None,
         routed_pairs: int | None = None,
         expert_load_max: int | None = None,
         state_bytes: int | None = None,
         ahead: int | None = None,
         prompt_tokens: int | None = None,
+        clock: dict | None = None,
     ) -> None:
         """One flight sample per dispatched burst, plus its Prometheus
         mirrors. ``program``, ``dispatch``, ``steps``,
-        ``active_at_dispatch``, ``live_blocks``, ``table_blocks`` and
-        ``live_rows`` are the dispatch's :meth:`_ticket`, taken when it was
-        made;
+        ``active_at_dispatch`` and ``live_rows`` are the dispatch's
+        :meth:`_ticket`, taken when it was made; ``clock`` is
+        the ticket's times, stamped on the dispatch thread as the program
+        was handed to the device and seen complete and by the loop after
+        each of the dispatch's awaits (flight.py ``DispatchClock``,
+        ``resumed``: the sample's ``gap_ms``, ``program_ms``,
+        ``resume_lag_ms``);
         ``routed_pairs``, ``expert_load_max`` and ``state_bytes`` (a hybrid
         model's decode chunk) joined it when the chunk's packed fetch
         landed (:meth:`_await_chunk`). ``ahead`` (a prefill batch) is 1 when
@@ -2621,7 +2624,6 @@ class TpuServingEngine:
             queue_depth=self.scheduler.qsize(),
             stall=stall,
             kv_used=kv_used,
-            prefix_hits=self.prefix_hits,
             spec_accepted=spec_accepted,
             spec_rejected=spec_rejected,
             queue_by_class=depths,
@@ -2629,14 +2631,13 @@ class TpuServingEngine:
             dispatch=dispatch,
             steps=steps,
             active_at_dispatch=active_at_dispatch,
-            live_blocks=live_blocks,
-            table_blocks=table_blocks,
             live_rows=live_rows,
             routed_pairs=routed_pairs,
             expert_load_max=expert_load_max,
             state_bytes=state_bytes,
             ahead=ahead,
             prompt_tokens=prompt_tokens,
+            clock=clock,
         )
         # watchdog heartbeat: a recorded dispatch IS step progress
         self.watchdog.beat(sample["queue_depth"])
@@ -2674,40 +2675,34 @@ class TpuServingEngine:
 
     def _ticket(
         self, program: str, steps: int, active: int,
-        live_blocks: int | None = None, table_blocks: int | None = None,
         live_rows: int | None = None,
     ) -> dict:
         """What a dispatch knows when it is made and its flight sample,
         recorded when the result is processed, no longer does: the program
         variant, the dispatch's ordinal (the ``seq`` of its host spans),
         the decode steps it fuses (0 for a prefill), the slots running and,
-        for a decode chunk, the pool blocks its read has to fetch
-        against the table columns of its window, and the rows in those
-        blocks (:meth:`_read_blocks`).
-        Loop thread only; rides to :meth:`_flight_record` as keywords."""
+        for a decode chunk, the rows its read has to fetch
+        (:meth:`_read_rows`). ``clock`` starts empty:
+        the dispatch thread stamps it (``flight.clock``) and the loop adds
+        its resume lag after each await (``flight.resumed``).
+        Made on the loop thread; rides to :meth:`_flight_record` as
+        keywords."""
         self._dispatch_seq += 1
         return {
             "program": program, "dispatch": self._dispatch_seq,
             "steps": steps, "active_at_dispatch": active,
-            "live_blocks": live_blocks, "table_blocks": table_blocks,
-            "live_rows": live_rows,
+            "live_rows": live_rows, "clock": {},
         }
 
-    def _read_blocks(self, active: list[int], ahead: int, window: int):
-        """``(live_blocks, table_blocks, live_rows)`` of a decode chunk's
-        paged read: the blocks that hold rows, summed over every slot of the
+    def _read_rows(self, active: list[int], ahead: int, window: int) -> int:
+        """``live_rows`` of a decode chunk's paged read: the rows a step of
+        the chunk reads of each layer's pool, summed over every slot of the
         batch from the host's lengths (``ahead`` rows further for the
-        running slots, whose in-flight chunk the host has not processed),
-        the table columns a sweep of the whole window would visit, and the
-        rows themselves (what a step of the chunk reads of each layer's
-        pool). The first two's ratio is the share of such a sweep that is
-        live."""
-        bs = self.paged_layout.block_size
+        running slots, whose in-flight chunk the host has not processed)."""
         rows = self._lengths.astype(np.int64)
         rows[active] += ahead
-        rows = np.minimum(rows, window * bs)
-        return (int((-(-rows // bs)).sum()), self.config.slots * window,
-                int(rows.sum()))
+        return int(np.minimum(
+            rows, window * self.paged_layout.block_size).sum())
 
     def _flight_stall(self, reason: str) -> None:
         """Record an idle/blocked engine-loop gap as stall time."""
@@ -5881,15 +5876,17 @@ class TpuServingEngine:
                 jnp.asarray(current_np), jnp.asarray(lengths_np),
                 amask, tables_dev, key, temps, topks, topps, **ad_kw,
             )
+            self.flight.clock.enqueued(ticket["clock"], packed)
             self.cache_k, self.cache_v = ck, cv
             self._decode_dispatches += 1
             self._start_fetch(packed)
-            return self._fetch_chunk(packed, K)[:3]
+            return self._fetch_chunk(packed, K, ticket)[:3]
 
         t_wall = time.monotonic()
         chunk_t, chunk_lp, fetch_s = await loop.run_in_executor(
             self._executor, _run
         )
+        resumed(ticket["clock"])
         gen_before = self.total_generated
         finished = self._process_chunk(chunk_t, chunk_lp, live)
         self._spec_note_plain(
@@ -5983,6 +5980,7 @@ class TpuServingEngine:
                 self._ad_rows.copy() if self._ad_rows is not None else None
             )
             key = self._split_key()
+            times: dict = {}  # the step's gap_ms / program_ms
 
             def _run():
                 if self._lockstep is not None:
@@ -6033,6 +6031,7 @@ class TpuServingEngine:
                     key, jnp.asarray(temps_np), jnp.asarray(topks_np),
                     jnp.asarray(topps_np), **ad_kw,
                 )
+                self.flight.clock.enqueued(times, out[0])
                 self._ctx_dev = out[1]
                 self.cache_k, self.cache_v = out[2], out[3]
                 self._spec_dispatches += 1
@@ -6041,6 +6040,7 @@ class TpuServingEngine:
                 # the device finishes — that wait is the step's device time
                 t_dev = time.monotonic()
                 fetched = self._fetch_spec(out[0], D1)
+                self.flight.clock.ready(times)
                 return fetched + (time.monotonic() - t_dev,)
 
             t_wall = time.monotonic()
@@ -6105,6 +6105,7 @@ class TpuServingEngine:
                 spec_accepted=accepted_step,
                 spec_rejected=rejected_step,
                 program=program,
+                clock=times,
             )
             if self._spec_check_uplift():
                 await self._flush_emits(live)
@@ -6156,7 +6157,7 @@ class TpuServingEngine:
         return any(s.free for s in self.slots)
 
     def _fetch_chunk(
-        self, packed, k_steps: int
+        self, packed, k_steps: int, ticket: dict
     ) -> tuple[np.ndarray, np.ndarray, float, np.ndarray]:
         """The designated fetch stage (graftcheck PERF701 polices syncs
         anywhere else on the dispatch path): ONE device→host transfer per
@@ -6166,30 +6167,44 @@ class TpuServingEngine:
         device — the chunk's un-overlapped device wait, which the flight
         recorder subtracts from wall time to expose the host share. The
         fourth is whatever counters the program packed behind them (a
-        hybrid model's expert loads; empty otherwise)."""
+        hybrid model's expert loads; empty otherwise). ``ticket`` is the
+        chunk's: the blocking call is its ``ls.decode.wait`` span, and its
+        ``clock`` gets the completion (``ready``) and this thread's last
+        stamp before returning (``returned_t``, what the loop's resume lag
+        is counted from)."""
         B = self.config.slots
         n = k_steps * B
-        self._fault("fetch")
-        t_dev = time.monotonic()
-        flat = np.asarray(packed)
-        fetch_s = time.monotonic() - t_dev
-        self._decode_fetches += 1
-        return (
-            flat[:n].reshape(k_steps, B),
-            flat[n:2 * n].view(np.float32).reshape(k_steps, B),
-            fetch_s,
-            flat[2 * n:],
-        )
+        times = ticket["clock"]
+        # everything this thread does for the fetch lies under its span, so
+        # that what is left under the loop's held ``ls.decode.fetch`` is
+        # the coroutine's wait for its turn alone
+        with self.flight.span("ls.decode.wait", seq=ticket["dispatch"]):
+            self._fault("fetch")
+            t_dev = time.monotonic()
+            flat = np.asarray(packed)
+            fetch_s = time.monotonic() - t_dev
+            self.flight.clock.ready(times)
+            self._decode_fetches += 1
+            fetched = (
+                flat[:n].reshape(k_steps, B),
+                flat[n:2 * n].view(np.float32).reshape(k_steps, B),
+                fetch_s,
+                flat[2 * n:],
+            )
+            times["returned_t"] = time.monotonic()
+        return fetched
 
     async def _await_chunk(self, loop, packed, k_steps: int, ticket: dict):
         """The loop thread's wait for a dispatched chunk's tokens, from
         handing :meth:`_fetch_chunk` to the dispatch thread until this
-        coroutine runs again: the ``ls.decode.fetch`` span, the one span
-        held across an ``await``."""
+        coroutine runs again: the ``ls.decode.fetch`` span, held across
+        the ``await`` (flight.py ``HELD_SPANS``)."""
         with self.flight.span("ls.decode.fetch", seq=ticket["dispatch"]):
             chunk_t, chunk_lp, fetch_s, loads = await loop.run_in_executor(
-                self._executor, partial(self._fetch_chunk, packed, k_steps)
+                self._executor,
+                partial(self._fetch_chunk, packed, k_steps, ticket),
             )
+            resumed(ticket["clock"])
         if loads.size:
             # a hybrid chunk's expert loads, by (layer, held expert)
             ticket.update(
@@ -6396,47 +6411,50 @@ class TpuServingEngine:
             # Everything the closure needs (the resolved jit variant, the
             # penalty snapshot, the block tables) was prepared on the loop
             # thread by _submit — the dispatch thread reads no mutable
-            # engine fields outside the lockstep protocol branch (RACE801)
-            if self._lockstep is not None:
-                # runs on the single dispatch thread → broadcast order is
-                # dispatch order. Speculative chunks ("decode_cont") carry
-                # only control (plus the active mask, so a mid-burst
-                # finished-slot freeze reaches followers): followers chain
-                # their own device-resident tokens/lengths outputs, so
-                # nothing syncs to host here.
-                desc: dict[str, Any] = {
-                    "op": "decode" if first else "decode_cont",
-                    "sampler_mode": list(sampler_mode),
-                    "window": window,
-                    "k": K,
-                    "key": np.asarray(key),
-                    "active": active_mask,
-                    "tables": tables,  # host snapshot from _grow_blocks
-                }
-                if pen:
-                    # penalty bursts are sequential, so every chunk ships
-                    # fresh host state (counts are (slots, vocab) — heavy,
-                    # but penalties are a per-request opt-in)
-                    desc.update(
-                        pen=True,
-                        pres=pres_np,
-                        freq=freq_np,
-                        counts=counts_np,
-                    )
-                if first:
-                    desc.update(
-                        tokens=np.asarray(self._current),
-                        lengths=np.asarray(self._lengths),
-                        temps=np.asarray(self._temps),
-                        topks=np.asarray(self._topks),
-                        topps=np.asarray(self._topps),
-                    )
-                self._lockstep.broadcast(desc)
-            self.profiler.on_decode_chunk()
-            # the uploads and the call into the jitted chunk
+            # engine fields outside the lockstep protocol branch (RACE801).
+            # The thread's span covers everything it does for the dispatch
+            # (the followers' broadcast, the uploads, the call into the
+            # jitted chunk): what is left under a held span of the loop is
+            # the coroutine's wait for its turn
             with self.flight.span(
                 "ls.decode.dispatch", **_span_meta(ticket), steps=K
             ):
+                if self._lockstep is not None:
+                    # runs on the single dispatch thread → broadcast order is
+                    # dispatch order. Speculative chunks ("decode_cont") carry
+                    # only control (plus the active mask, so a mid-burst
+                    # finished-slot freeze reaches followers): followers chain
+                    # their own device-resident tokens/lengths outputs, so
+                    # nothing syncs to host here.
+                    desc: dict[str, Any] = {
+                        "op": "decode" if first else "decode_cont",
+                        "sampler_mode": list(sampler_mode),
+                        "window": window,
+                        "k": K,
+                        "key": np.asarray(key),
+                        "active": active_mask,
+                        "tables": tables,  # host snapshot from _grow_blocks
+                    }
+                    if pen:
+                        # penalty bursts are sequential, so every chunk ships
+                        # fresh host state (counts are (slots, vocab) — heavy,
+                        # but penalties are a per-request opt-in)
+                        desc.update(
+                            pen=True,
+                            pres=pres_np,
+                            freq=freq_np,
+                            counts=counts_np,
+                        )
+                    if first:
+                        desc.update(
+                            tokens=np.asarray(self._current),
+                            lengths=np.asarray(self._lengths),
+                            temps=np.asarray(self._temps),
+                            topks=np.asarray(self._topks),
+                            topps=np.asarray(self._topps),
+                        )
+                    self._lockstep.broadcast(desc)
+                self.profiler.on_decode_chunk()
                 tables_dev = self._tables_device(tables)
                 caches = (self.params, self.cache_k, self.cache_v) + (
                     () if self.state is None else (self.state,)
@@ -6463,6 +6481,7 @@ class TpuServingEngine:
                     f"decode_chunk_w{window}_s{sampler_mode}", decode_fn, *args
                 )
                 packed, t, l, ck, cv, *st = decode_fn(*args, **ad_kw)
+                self.flight.clock.enqueued(ticket["clock"], packed)
                 self.cache_k, self.cache_v = ck, cv
                 if st:
                     self.state = st[0]
@@ -6472,6 +6491,7 @@ class TpuServingEngine:
                 # been riding under this dispatch's own device shadow
                 self._decode_dispatches += 1
                 self._start_fetch(packed)
+                ticket["clock"]["returned_t"] = time.monotonic()
                 return packed, t, l
 
         # tickets (program id, dispatch ordinal, steps, slots running) of
@@ -6493,7 +6513,7 @@ class TpuServingEngine:
             ticket = self._ticket(
                 self._program_decode(window, K, sampler_mode, pen),
                 K, len(active),
-                *self._read_blocks(active, pending * K, window),
+                self._read_rows(active, pending * K, window),
             )
             prog_q.append(ticket)
             counts_np = _build_counts() if pen else None
@@ -6519,6 +6539,7 @@ class TpuServingEngine:
                 key1, self._read_blocks_for(base_max), 0, first=True,
             )
         out = await first_out
+        resumed(prog_q[0]["clock"])
         chunk_index = 0
         if light or pen or not self._pipeline_on:
             # the SEQUENTIAL reference loop (also the light-load / penalty
@@ -6555,6 +6576,7 @@ class TpuServingEngine:
                         self._read_blocks_for(base_max), 0,
                     )
                 out = await next_out
+                resumed(prog_q[0]["clock"])
 
         async def _drain(out, expected, overlapped_s: float = 0.0) -> None:
             """Fetch + apply one dispatched chunk (the burst's tail or an
@@ -6688,9 +6710,15 @@ class TpuServingEngine:
         finally:
             self._defer_release = False
             if self._deferred_releases:
-                for slot_id in self._deferred_releases:
-                    self.block_mgr.release(slot_id)
-                self._deferred_releases.clear()
+                # a finished slot's blocks go back a table column at a time
+                # (190 of them behind a 12k-token prompt): between the
+                # burst's last emit and the next admission
+                with self.flight.span(
+                    "ls.release", slots=len(self._deferred_releases)
+                ):
+                    for slot_id in self._deferred_releases:
+                        self.block_mgr.release(slot_id)
+                    self._deferred_releases.clear()
 
     async def _drain_pending(self, loop) -> None:
         """Apply the decode chunk the previous pipelined burst left in
@@ -6751,26 +6779,32 @@ class TpuServingEngine:
             )
             key = self._split_key()
 
+        times = ticket["clock"]
+
         def _run():
-            self._fault("prefill")
-            if self._lockstep is not None:
-                desc = {
-                    "op": "prefill",
-                    "sampler_mode": list(mode),
-                    "tokens": tokens,
-                    "lengths": lengths,
-                    "sel": np.asarray(sel_np),
-                    "key": np.asarray(key),
-                    "temps": temps,
-                    "topks": topks,
-                    "topps": topps,
-                }
-                if starts is not None:
-                    desc.update(op="prefill_continue", starts=starts, nrb=nrb)
-                self._lockstep.broadcast(desc)
+            # the thread's span covers everything it does for the dispatch
+            # (the followers' broadcast, the uploads, the call, the
+            # re-binding): what is left under the loop's held
+            # ``ls.prefill.handoff`` is the coroutine's wait for its turn
             with self.flight.span(
                 "ls.prefill.dispatch", **_span_meta(ticket)
             ):
+                self._fault("prefill")
+                if self._lockstep is not None:
+                    desc = {
+                        "op": "prefill",
+                        "sampler_mode": list(mode),
+                        "tokens": tokens,
+                        "lengths": lengths,
+                        "sel": np.asarray(sel_np),
+                        "key": np.asarray(key),
+                        "temps": temps,
+                        "topks": topks,
+                        "topps": topps,
+                    }
+                    if starts is not None:
+                        desc.update(op="prefill_continue", starts=starts, nrb=nrb)
+                    self._lockstep.broadcast(desc)
                 # the hybrid family's state rides behind the caches, a
                 # continuation's starts behind the tokens (never both)
                 args = (self.params, self.cache_k, self.cache_v) + (
@@ -6795,18 +6829,23 @@ class TpuServingEngine:
                     fn, *args,
                 )
                 out = fn(*args, **ad_kw)
-            # the donated caches are re-bound HERE, on the dispatch thread
-            # — the same side that reads them in every dispatch closure, so
-            # cache_k/cache_v stay single-thread-role (RACE801)
-            self.cache_k, self.cache_v = out[2], out[3]
-            # the hybrid family's recurrent state is donated with them
-            self.state = out[4] if len(out) > 4 else None
+                self.flight.clock.enqueued(times, out[0])
+                # the donated caches are re-bound HERE, on the dispatch thread
+                # — the same side that reads them in every dispatch closure, so
+                # cache_k/cache_v stay single-thread-role (RACE801)
+                self.cache_k, self.cache_v = out[2], out[3]
+                # the hybrid family's recurrent state is donated with them
+                self.state = out[4] if len(out) > 4 else None
+                times["returned_t"] = time.monotonic()
             return out[0], out[1]
 
-        # held across the await, as the ``*.fetch`` spans are: until this
-        # coroutine runs again, a device with nothing queued waits for it
-        with self.flight.span("ls.prefill.dispatch", **_span_meta(ticket)):
-            return await loop.run_in_executor(self._executor, _run)
+        # held across the await, as the ``*.fetch`` spans are (flight.py
+        # HELD_SPANS): until this coroutine runs again, a device with
+        # nothing queued waits for it
+        with self.flight.span("ls.prefill.handoff", seq=ticket["dispatch"]):
+            out = await loop.run_in_executor(self._executor, _run)
+            resumed(times)
+        return out
 
     async def _fetch_prefill(self, loop, ticket: dict, out):
         """The second half: wait on the dispatch thread for a dispatched
@@ -6816,18 +6855,37 @@ class TpuServingEngine:
         time for a lone batch, what was left of it for a batch that had a
         successor packed and dispatched meanwhile."""
 
+        times = ticket["clock"]
+
         def _run():
-            t_dev = time.monotonic()
-            # the ONE per-dispatch sync, on the dispatch thread and timed
-            # (the sample's device_ms); the token/logprob fetch rides the
-            # same stop so the loop thread never blocks on the device
-            # graftcheck: disable=JAX104 the one per-dispatch sync, moved off-loop and timed
-            jax.block_until_ready(out)
-            device_s = time.monotonic() - t_dev
-            return np.asarray(out[0]), np.asarray(out[1]), device_s
+            # the thread's span covers the pending chunk's wait, the
+            # batch's own and the two host copies: what is left under the
+            # loop's held ``ls.prefill.fetch`` is the coroutine's wait for
+            # its turn alone
+            with self.flight.span("ls.prefill.wait", seq=ticket["dispatch"]):
+                t_dev = time.monotonic()
+                # a decode chunk left pending ahead of this batch is the
+                # device's first: its completion is seen here, inside the
+                # same timed wait (no further sync: a program enqueued
+                # before this one ends no later), so that its time is not
+                # taken for this program's
+                self.flight.clock.settle(times, jax.block_until_ready)
+                # the ONE per-dispatch sync, on the dispatch thread and
+                # timed (the sample's device_ms); the token/logprob fetch
+                # rides the same stop so the loop thread never blocks on
+                # the device
+                # graftcheck: disable=JAX104 the one per-dispatch sync, moved off-loop and timed
+                jax.block_until_ready(out)
+                device_s = time.monotonic() - t_dev
+                self.flight.clock.ready(times)
+                first = np.asarray(out[0]), np.asarray(out[1])
+                times["returned_t"] = time.monotonic()
+            return first + (device_s,)
 
         with self.flight.span("ls.prefill.fetch", seq=ticket["dispatch"]):
-            return await loop.run_in_executor(self._executor, _run)
+            fetched = await loop.run_in_executor(self._executor, _run)
+            resumed(times)
+        return fetched
 
     async def _advance_prefills(self, loop) -> None:
         """One bounded chunk of progress for every mid-prefill slot, batched
@@ -7738,7 +7796,10 @@ class TpuServingEngine:
                     )
             result = request.on_chunk(new_ids, delta, is_final)
         if asyncio.iscoroutine(result):
-            await result
+            # the consumer's own coroutine: the one hop span held across an
+            # await (what runs inside it opens ``ls.hop.agent``, ``.topic``)
+            with self.flight.span("ls.hop.deliver"):
+                await result
 
     async def _flush_emits(
         self, active: list[int], span: str = "ls.decode.emit"
@@ -7746,24 +7807,32 @@ class TpuServingEngine:
         """Deliver what the burst committed and settle finished requests.
         ``span`` names the host spans around the synchronous parts (a
         prefill's first tokens pass ``ls.prefill.emit``); the consumers'
-        own coroutines run outside any span."""
+        own coroutines run under ``ls.hop.deliver`` (one span a flush for
+        the per-token subscribers, one a request for the per-chunk ones)."""
         emits, self._pending_emits = self._pending_emits, []
         # per-request chunk grouping, first-appearance order: on_token
         # subscribers keep exact per-token delivery; on_chunk subscribers
         # get ONE delivery per request per flush with everything that
         # committed in this burst
         chunks: "OrderedDict[int, list]" = OrderedDict()
-        for request, token, logprob, done in emits:
-            if request.on_token is not None:
-                result = request.on_token(token, logprob, done)
-                if asyncio.iscoroutine(result):
-                    await result
-            if request.on_chunk is not None:
-                entry = chunks.get(id(request))
-                if entry is None:
-                    chunks[id(request)] = [request, done]
-                elif done:
-                    entry[1] = True
+        per_token = []
+        with self.flight.span(span, tokens=len(emits)):
+            for emit in emits:
+                request, done = emit[0], emit[3]
+                if request.on_token is not None:
+                    per_token.append(emit)
+                if request.on_chunk is not None:
+                    entry = chunks.get(id(request))
+                    if entry is None:
+                        chunks[id(request)] = [request, done]
+                    elif done:
+                        entry[1] = True
+        if per_token:
+            with self.flight.span("ls.hop.deliver", frames=len(per_token)):
+                for request, token, logprob, done in per_token:
+                    result = request.on_token(token, logprob, done)
+                    if asyncio.iscoroutine(result):
+                        await result
         if chunks:
             # one clock per flush: chunk emission is the granularity the
             # client observes, so inter-EMIT gaps are what TBT digests

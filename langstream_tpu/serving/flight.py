@@ -71,15 +71,25 @@ Hot-path discipline (graftcheck rule OBS503 gates this): the record path
 is append-only on GIL-atomic deques — **no locks, no I/O, nothing that can
 block the engine loop**. Rollups snapshot with ``list(deque)``.
 
-Host **spans** (:meth:`FlightRecorder.span`) mark the same dispatch
-boundaries on the profiler's clock: a ``jax.profiler.TraceAnnotation`` and
-nothing else. With no profiler session a span records nothing and leaves the
-recorder untouched; under one (``/profile/start``, the benchmark's
-``--trace 1``) it lands in the ``/host:CPU`` plane of the same
-``.xplane.pb`` as the device operations, where ``bench/lib/hosttrace.py``
-lays it against the device's idle gaps. The vocabulary is :data:`SPANS`
-(docs/OBSERVABILITY.md, "Device profiling"); a dispatch's ``seq`` is the
-``dispatch`` field of its flight sample.
+Host **spans** (:meth:`FlightRecorder.span`, which is
+``core/tracing.py`` ``host_span``: the one helper, shared with the loop's
+other tenants) mark the same dispatch boundaries on the profiler's clock: a
+``jax.profiler.TraceAnnotation`` and nothing else. With no profiler session
+a span records nothing and leaves the recorder untouched; under one
+(``/profile/start``, the benchmark's ``--trace 1``) it lands in the
+``/host:CPU`` plane of the same ``.xplane.pb`` as the device operations,
+where ``bench/lib/hosttrace.py`` lays it against the device's idle gaps.
+The vocabulary is :data:`SPANS` (docs/OBSERVABILITY.md, "Device
+profiling"); a dispatch's ``seq`` is the ``dispatch`` field of its flight
+sample.
+
+The **dispatch thread's clock** (:class:`DispatchClock`) is the same
+account with the tracing off: the one thread that hands every program to
+the device and sees every completion stamps both, and each dispatch's
+sample carries ``gap_ms`` (the device stood with nothing queued before this
+program), ``program_ms`` (the program's own time) and ``resume_lag_ms``
+(how long the engine's coroutine waited in the loop's ready queue after the
+dispatch thread had returned).
 
 Sizing: ``LS_TPU_FLIGHT_BUFFER`` samples (default 4096, min 64). Cumulative
 totals (wall/device/host/stall, per-phase step counts, stall seconds by
@@ -99,7 +109,9 @@ from __future__ import annotations
 import os
 import time
 from collections import deque
-from typing import Any
+from typing import Any, Callable
+
+from langstream_tpu.core.tracing import host_span
 
 #: admission-stall reasons a sample may carry (the attribution vocabulary)
 STALL_REASONS = (
@@ -112,21 +124,40 @@ STALL_REASONS = (
 #: dispatch phases (a "stall" sample is the fifth, non-dispatch kind)
 PHASES = ("prefill", "decode", "verify")
 
-#: host spans the engine loop opens (the whole vocabulary; every name a
-#: reader of the profile may meet)
+#: host spans on the profiler's clock (the whole vocabulary; every name a
+#: reader of the profile may meet): the engine loop's, the dispatch
+#: thread's two blocking waits (``*.wait``), and ``ls.hop.*`` around the
+#: synchronous stretches of everything else that runs on the engine's loop
+#: between two of its dispatches
 SPANS = (
     "ls.admit",
     "ls.prefill.pack",
     "ls.prefill.dispatch",
+    "ls.prefill.handoff",
     "ls.prefill.fetch",
+    "ls.prefill.wait",
     "ls.prefill.emit",
     "ls.decode.prepare",
     "ls.decode.dispatch",
     "ls.decode.fetch",
+    "ls.decode.wait",
     "ls.decode.process",
     "ls.decode.emit",
+    "ls.release",
     "ls.idle",
+    "ls.hop.deliver",
+    "ls.hop.agent",
+    "ls.hop.topic",
+    "ls.hop.gw.recv",
+    "ls.hop.gw.send",
+    "ls.hop.runner",
 )
+
+#: the spans held across an ``await`` of the dispatch thread: once that
+#: thread's own span (``*.dispatch``, ``*.wait``) has ended, what is left
+#: under one of these is the engine's coroutine waiting for its turn on the
+#: loop, unless a tenant's ``ls.hop.*`` span started meanwhile
+HELD_SPANS = ("ls.prefill.handoff", "ls.prefill.fetch", "ls.decode.fetch")
 
 
 def _buffer_size() -> int:
@@ -143,12 +174,105 @@ def _pct(sorted_values: list, q: float):
     return sorted_values[min(len(sorted_values) - 1, int(q * len(sorted_values)))]
 
 
+class DispatchClock:
+    """The dispatch thread's account of the device, kept with the tracing
+    off. That thread is single and the device runs programs in the order it
+    hands them over, so two ``time.monotonic()`` stamps a program tile the
+    device's time: ``enqueued`` when the jitted call has returned, ``ready``
+    when the blocking call on its result has. Each program's ``times`` (the
+    ``clock`` of its engine ticket) then holds
+
+    - ``gap_ms``: ``enqueued_t`` less the ``ready_t`` of the program
+      enqueued before it, whatever its phase, where that is positive: the
+      device stood with nothing queued;
+    - ``program_ms``: ``ready_t`` less the later of ``enqueued_t`` and that
+      predecessor's ``ready_t``: the program's own time. A completion seen
+      late lengthens this one and shortens the next by as much, so the sums
+      of the two fields tile from :attr:`first_enqueued_t` to
+      :attr:`last_ready_t`.
+
+    A program whose completion nobody has waited for yet when a LATER one's
+    is (a decode chunk left pending under an admission round's prefills) is
+    waited for first (:meth:`settle`), so its time is not its successor's.
+    A completion is stamped only where this thread waits for it: a batch
+    dispatched one ahead is fetched after its successor's dispatch, so when
+    it ends first the device's wait from there stays in its ``program_ms``
+    and ``gap_ms`` is a LOWER bound of the device's idle time. Dispatch
+    thread only: clock reads, a deque and dictionary stores."""
+
+    def __init__(self, clock: Callable[[], float] = time.monotonic):
+        self._clock = clock
+        #: (times, handle) of the programs enqueued and not yet seen
+        #: complete, in the device's order (at most three: a decode chunk
+        #: and two prefill batches)
+        self._open: deque[tuple[dict, Any]] = deque()
+        self.first_enqueued_t: float | None = None
+        self.last_ready_t: float | None = None
+
+    def enqueued(self, times: dict, handle: Any = None) -> None:
+        """The jitted call returned: the program is the device's. ``handle``
+        is something of its result to wait on (:meth:`settle`)."""
+        now = self._clock()
+        times["enqueued_t"] = now
+        if self.first_enqueued_t is None:
+            self.first_enqueued_t = now
+        self._open.append((times, handle))
+
+    def settle(self, times: dict, wait: Callable[[Any], Any]) -> None:
+        """Before the blocking call on ``times``' own program: wait, in the
+        device's order, for each program enqueued before it whose completion
+        nobody has seen (it cannot end later than this one, so the thread
+        blocks no longer than it would have)."""
+        if "enqueued_t" not in times or "ready_t" in times:
+            return  # not the device's through this clock: nothing to order
+        while self._open and self._open[0][0] is not times:
+            earlier, handle = self._open[0]
+            try:
+                if handle is not None:
+                    wait(handle)
+            finally:  # a failed program is over too: raise it once, here
+                self.ready(earlier)
+
+    def ready(self, times: dict) -> None:
+        """The blocking call on ``times``' program returned. Programs
+        enqueued before it have completed too; one not seen until now ends
+        here, and its successors get what is left."""
+        if "enqueued_t" not in times or "ready_t" in times:
+            return
+        now = self._clock()
+        while self._open:
+            earlier, _handle = self._open.popleft()
+            before = self.last_ready_t
+            start = earlier["enqueued_t"]
+            earlier["gap_ms"] = 0.0
+            if before is not None:
+                earlier["gap_ms"] = max(0.0, start - before) * 1e3
+                start = max(start, before)
+            earlier["program_ms"] = (now - start) * 1e3
+            earlier["ready_t"] = now
+            self.last_ready_t = now
+            if earlier is times:
+                return
+
+
+def resumed(times: dict) -> None:
+    """First statement of the engine's coroutine after an ``await`` of the
+    dispatch thread, whose last stamp before returning is
+    ``times["returned_t"]``: what lies between is the wake-up and the loop's
+    ready queue. Summed over a dispatch's awaits into
+    ``times["resume_lag_ms"]``."""
+    times["resume_lag_ms"] = times.get("resume_lag_ms", 0.0) + max(
+        0.0, time.monotonic() - times["returned_t"]
+    ) * 1e3
+
+
 class FlightRecorder:
     """Bounded per-engine telemetry ring. Single writer (the engine loop;
     events may also arrive from the dispatch thread), many readers."""
 
     def __init__(self, slots: int = 0, maxlen: int | None = None):
         self.slots = slots
+        self.clock = DispatchClock()
         self.capacity = maxlen if maxlen is not None else _buffer_size()
         self._samples: deque[dict[str, Any]] = deque(maxlen=self.capacity)
         self._events: deque[dict[str, Any]] = deque(maxlen=512)
@@ -181,6 +305,11 @@ class FlightRecorder:
         self.spec_rejected = 0
         self.prefill_ahead = 0
         self.prefill_rows = 0
+        # cumulative twins of the samples' gap_ms / program_ms /
+        # resume_lag_ms (the dispatch thread's clock, DispatchClock)
+        self.gap_ms = 0.0
+        self.program_ms_by_phase: dict[str, float] = {}
+        self.resume_lag_ms = 0.0
 
     # -- recording (engine hot path: appends + counter bumps only) -------
 
@@ -189,17 +318,12 @@ class FlightRecorder:
         after a long construction gap, so the gap isn't billed as host)."""
         self._last_mark = time.monotonic()
 
-    @staticmethod
-    def span(name: str, **meta: Any):
-        """A host span on the profiler's clock: a context manager around
-        synchronous code (or the one ``await`` a ``*.fetch`` span, or the
-        loop's wait for a prefill's dispatch half, names).
-        ``name`` is one of :data:`SPANS`; ``meta`` become the event's
-        stats. Outside a profiler session this checks a flag and records
-        nothing."""
-        from jax.profiler import TraceAnnotation
-
-        return TraceAnnotation(name, **meta)
+    #: a host span on the profiler's clock: a context manager around
+    #: synchronous code (or the one ``await`` one of :data:`HELD_SPANS`
+    #: names). ``name`` is one of :data:`SPANS`; ``meta`` become the event's
+    #: stats. Outside a profiler session this checks a flag and records
+    #: nothing. The one helper every layer on the loop uses.
+    span = staticmethod(host_span)
 
     def sample(
         self,
@@ -212,7 +336,6 @@ class FlightRecorder:
         queue_depth: int = 0,
         stall: str | None = None,
         kv_used: float | None = None,
-        prefix_hits: int = 0,
         spec_accepted: int = 0,
         spec_rejected: int = 0,
         queue_by_class: dict[str, int] | None = None,
@@ -220,14 +343,13 @@ class FlightRecorder:
         dispatch: int | None = None,
         steps: int = 0,
         active_at_dispatch: int | None = None,
-        live_blocks: int | None = None,
-        table_blocks: int | None = None,
         live_rows: int | None = None,
         routed_pairs: int | None = None,
         expert_load_max: int | None = None,
         state_bytes: int | None = None,
         ahead: int | None = None,
         prompt_tokens: int | None = None,
+        clock: dict | None = None,
     ) -> dict[str, Any]:
         """Record one dispatched burst. ``wall`` is the time since the
         previous boundary. ``overlapped_s`` is host work the pipelined
@@ -245,10 +367,9 @@ class FlightRecorder:
         (decode steps it fused; 0 for a prefill) and ``active_at_dispatch``
         (slots running when it was dispatched) were taken at dispatch, not
         at this later boundary where ``occupancy`` is read; omitted
-        together when the caller has no dispatch to name. ``live_blocks``
-        and ``table_blocks`` (a paged decode chunk only) are the pool
-        blocks its read had to fetch and the table columns of its window;
-        ``live_rows`` the rows in those blocks, summed over the slots.
+        together when the caller has no dispatch to name. ``live_rows``
+        (a paged decode chunk only) are the rows its read had to fetch of
+        each layer's pool, summed over the slots.
         ``routed_pairs``, ``expert_load_max`` and ``state_bytes`` (a hybrid
         model's decode chunk only, models/hybrid.py) are the (token, expert)
         pairs the chunk's active rows sent to the experts held here, the most
@@ -258,7 +379,10 @@ class FlightRecorder:
         dispatched while its predecessor's first tokens were unfetched, so
         its ``device_s`` is what was left of the program when the host came
         to wait for it, not the program's run time. ``prompt_tokens`` (a
-        prefill batch only) are the true tokens its rows prefilled."""
+        prefill batch only) are the true tokens its rows prefilled.
+        ``clock`` is the dispatch's times as :class:`DispatchClock` and
+        :func:`resumed` left them: the sample's ``gap_ms``, ``program_ms``
+        and ``resume_lag_ms``, each omitted where it was not taken."""
         now = time.monotonic()
         wall_ms = (now - self._last_mark) * 1000.0
         self._last_mark = now
@@ -284,7 +408,6 @@ class FlightRecorder:
             "queue_depth": queue_depth,
             "stall": stall,
             "kv_used": round(kv_used, 4) if kv_used is not None else None,
-            "prefix_hits": prefix_hits,
         }
         if spec_accepted or spec_rejected:
             entry["spec_accepted"] = spec_accepted
@@ -297,11 +420,8 @@ class FlightRecorder:
             entry["dispatch"] = dispatch
             entry["steps"] = steps
             entry["active_at_dispatch"] = active_at_dispatch
-        if live_blocks is not None:
-            entry["live_blocks"] = live_blocks
-            entry["table_blocks"] = table_blocks
-            if live_rows is not None:
-                entry["live_rows"] = live_rows
+        if live_rows is not None:
+            entry["live_rows"] = live_rows
         if routed_pairs is not None:
             entry["routed_pairs"] = routed_pairs
             entry["expert_load_max"] = expert_load_max
@@ -313,6 +433,18 @@ class FlightRecorder:
             self.prefill_rows += tokens
         if prompt_tokens is not None:
             entry["prompt_tokens"] = prompt_tokens
+        if clock:
+            if "program_ms" in clock:
+                entry["gap_ms"] = round(clock["gap_ms"], 3)
+                entry["program_ms"] = round(clock["program_ms"], 3)
+                self.gap_ms += clock["gap_ms"]
+                self.program_ms_by_phase[phase] = (
+                    self.program_ms_by_phase.get(phase, 0.0)
+                    + clock["program_ms"]
+                )
+            if "resume_lag_ms" in clock:
+                entry["resume_lag_ms"] = round(clock["resume_lag_ms"], 3)
+                self.resume_lag_ms += clock["resume_lag_ms"]
         self._samples.append(entry)
         self.recorded += 1
         self.wall_ms += wall_ms
@@ -361,7 +493,6 @@ class FlightRecorder:
             "queue_depth": queue_depth,
             "stall": reason,
             "kv_used": round(kv_used, 4) if kv_used is not None else None,
-            "prefix_hits": 0,
         }
         if queue_by_class is not None:
             entry["queue_by_class"] = dict(queue_by_class)
@@ -490,6 +621,12 @@ class FlightRecorder:
                 "spec_rejected": self.spec_rejected,
                 "prefill_ahead_share": self.prefill_ahead_share,
                 "prefill_rows_mean": self.prefill_rows_mean,
+                "gap_ms": round(self.gap_ms, 3),
+                "program_ms_by_phase": {
+                    k: round(v, 3)
+                    for k, v in self.program_ms_by_phase.items()
+                },
+                "resume_lag_ms": round(self.resume_lag_ms, 3),
             },
             "window": {
                 "samples": len(window),

@@ -159,16 +159,15 @@ def test_a_span_without_a_profiler_session_leaves_the_recorder_untouched():
     (dict(dispatch=10, active_at_dispatch=3),           # a prefill: no steps
      {"dispatch": 10, "steps": 0, "active_at_dispatch": 3}),
     (dict(dispatch=11, steps=32, active_at_dispatch=97,  # a paged decode
-          live_blocks=730, table_blocks=4096),
+          live_rows=44_100),
      {"dispatch": 11, "steps": 32, "active_at_dispatch": 97,
-      "live_blocks": 730, "table_blocks": 4096}),
+      "live_rows": 44_100}),
     (dict(), {}),                       # no dispatch named: schema unchanged
 ])
 def test_sample_carries_what_the_dispatch_knew(fields, expected):
     recorder = FlightRecorder(slots=64)
     entry = recorder.sample("decode", device_s=0.001, occupancy=58, **fields)
-    carried = ("dispatch", "steps", "active_at_dispatch", "live_blocks",
-               "table_blocks")
+    carried = ("dispatch", "steps", "active_at_dispatch", "live_rows")
     assert {k: entry[k] for k in carried if k in entry} == expected
     assert entry["occupancy"] == 58     # stays what it was for its readers
 
@@ -198,6 +197,240 @@ def test_bench_rollup_carries_the_record_keys():
 # --------------------------------------------------------------------------
 # engine integration (CPU backend): the acceptance decomposition
 # --------------------------------------------------------------------------
+# the dispatch thread's clock: gap_ms, program_ms, resume_lag_ms (PR 36)
+# --------------------------------------------------------------------------
+
+
+class _Ticks:
+    """A clock that reads what it is told, for ``flight.time`` or a
+    ``DispatchClock``."""
+
+    def __init__(self, ticks):
+        self.ticks = list(ticks)
+
+    def monotonic(self):
+        return self.ticks.pop(0)
+
+    __call__ = monotonic
+
+    @staticmethod
+    def time():
+        return 1_700_000_000.0
+
+
+def _tiles(times):
+    """Sum of gap_ms + program_ms of the programs seen complete."""
+    return sum(t["gap_ms"] + t["program_ms"] for t in times if "ready_t" in t)
+
+
+@pytest.mark.parametrize("script, expected", [
+    # (op, program) in the dispatch thread's order, the clock a second a call.
+    # In order, fetched at once: a gap wherever the next was enqueued late.
+    ([("enq", 0), ("rdy", 0), ("enq", 1), ("rdy", 1)],
+     [(0.0, 1000.0), (1000.0, 1000.0)]),
+    # one ahead: 1 was enqueued while 0 ran, so no gap, and 1 starts at 0's end
+    ([("enq", 0), ("enq", 1), ("rdy", 0), ("rdy", 1)],
+     [(0.0, 2000.0), (0.0, 1000.0)]),
+    # a completion seen LATE: 0 (a pending decode chunk) is waited for only
+    # after its successor 1 was: 0 ends where 1's wait ended, 1 gets what is
+    # left (nothing), nothing is counted twice, and 2 starts from there
+    ([("enq", 0), ("enq", 1), ("rdy", 1), ("rdy", 0), ("enq", 2), ("rdy", 2)],
+     [(0.0, 2000.0), (0.0, 0.0), (1000.0, 1000.0)]),
+    # ... unless it is settled first: 1's wait sees 0 complete on the way
+    ([("enq", 0), ("enq", 1), ("settle", 1), ("rdy", 1), ("rdy", 0)],
+     [(0.0, 2000.0), (0.0, 1000.0)]),
+    # one ahead, and whether or not the device ran dry before 2's dispatch:
+    # 1's completion is stamped where this thread waits for it, at its fetch
+    # after 2's dispatch, so a wait of the device from 1's end stays in 1's
+    # program_ms (gap_ms is a lower bound of the device's idle time)
+    ([("enq", 0), ("enq", 1), ("rdy", 0), ("enq", 2), ("rdy", 1), ("rdy", 2)],
+     [(0.0, 2000.0), (0.0, 2000.0), (0.0, 1000.0)]),
+], ids=["in-order", "one-ahead", "seen-late", "settled", "fetched-after-next"])
+def test_the_dispatch_clock_tiles_the_device_s_time(script, expected):
+    from langstream_tpu.serving.flight import DispatchClock
+
+    clock = DispatchClock(clock=_Ticks(range(100, 200)))
+    times = [{} for _ in expected]
+    waited = []
+    for op, i in script:
+        if op == "enq":
+            clock.enqueued(times[i], handle=i)
+        elif op == "settle":
+            clock.settle(times[i], waited.append)
+        else:
+            clock.ready(times[i])
+    got = [(t["gap_ms"], t["program_ms"]) for t in times]
+    assert got == [pytest.approx(e) for e in expected]
+    # no double count, no hole: the fields tile first enqueue to last ready
+    assert _tiles(times) == pytest.approx(
+        (clock.last_ready_t - clock.first_enqueued_t) * 1e3)
+    assert waited == ([0] if ("settle", 1) in script else [])
+    assert not clock._open
+
+
+@pytest.mark.parametrize("times", [{}, {"enqueued_t": 1.0, "ready_t": 2.0}],
+                         ids=["never-enqueued", "already-seen"])
+def test_the_dispatch_clock_ignores_what_it_did_not_enqueue(times):
+    from langstream_tpu.serving.flight import DispatchClock
+
+    clock = DispatchClock(clock=_Ticks(range(10)))
+    other: dict = {}
+    clock.enqueued(other)
+    before = dict(times)
+    clock.settle(times, lambda handle: 1 / 0)
+    clock.ready(times)
+    assert times == before and len(clock._open) == 1
+
+
+#: (phase, device_s, overlapped_s, tokens, ahead) of a recorded sequence, the
+#: clock's reading at each record, and what the PARENT's recorder (commit
+#: 5171d91, before the clock fields) wrote for it: wall, device, host,
+#: overlapped of each sample, and the rollup's totals
+_RECORDED = [
+    ("prefill", 0.0312, 0.0, 3, 0), ("prefill", 0.0047, 0.0, 2, 1),
+    ("decode", 0.4181, 0.0, 96, None), ("decode", 0.0009, 0.2875, 128, None),
+    ("stall", None, None, 0, None), ("prefill", 3.4125, 0.0, 1, 0),
+    ("decode", 999.0, 0.5, 64, None),
+]
+_RECORDED_TICKS = [100.0, 100.0413, 100.0602, 100.5127, 101.0391, 101.0519,
+                   104.4711, 104.9999]
+_PARENT_WROTE = [[41.3, 31.2, 10.1, 0.0], [18.9, 4.7, 14.2, 0.0],
+                 [452.5, 418.1, 34.4, 0.0], [526.4, 288.4, 238.0, 287.5],
+                 [12.8, 0.0, 0.0, 0.0], [3419.2, 3412.5, 6.7, 0.0],
+                 [528.8, 528.8, 0.0, 0.0]]
+_PARENT_TOTALS = {"wall_ms": 4999.9, "device_ms": 4683.7, "host_ms": 303.4,
+                  "host_overlapped_ms": 287.5, "stall_ms": 12.8,
+                  "prefill_ahead_share": 0.3333}
+
+
+@pytest.mark.parametrize("clock", [
+    None, {}, {"gap_ms": 7.25, "program_ms": 911.5, "resume_lag_ms": 0.4},
+    {"resume_lag_ms": 3.0},
+], ids=["no-clock", "empty", "all-three", "lag-only"])
+def test_the_clock_fields_leave_the_wall_decomposition_as_the_parent_wrote_it(
+        monkeypatch, clock):
+    """``device_ms``, ``host_ms``, ``ahead`` and the totals the round budget
+    and every older reader use are byte-equal to the parent's on a recorded
+    sequence, whatever the dispatch's clock carried."""
+    from langstream_tpu.serving import flight
+
+    monkeypatch.setattr(flight, "time", _Ticks(_RECORDED_TICKS))
+    recorder = flight.FlightRecorder(slots=8, maxlen=64)
+    wrote = []
+    for phase, device_s, overlapped_s, tokens, ahead in _RECORDED:
+        if phase == "stall":
+            entry = recorder.stall("queue-empty")
+        else:
+            extra = {"ahead": ahead} if ahead is not None else {}
+            entry = recorder.sample(
+                phase, device_s=device_s, overlapped_s=overlapped_s,
+                tokens=tokens, clock=dict(clock) if clock is not None else None,
+                **extra)
+            for key in ("gap_ms", "program_ms", "resume_lag_ms"):
+                assert entry.get(key) == (clock or {}).get(key)
+        wrote.append([entry["wall_ms"], entry["device_ms"], entry["host_ms"],
+                      entry["host_overlapped_ms"]])
+    assert json.dumps(wrote) == json.dumps(_PARENT_WROTE)
+    totals = recorder.summary()["totals"]
+    assert json.dumps({k: totals[k] for k in _PARENT_TOTALS}) == json.dumps(
+        _PARENT_TOTALS)
+    # the cumulative twins: six dispatch samples carried the clock
+    n = 6 if clock else 0
+    assert totals["gap_ms"] == pytest.approx(n * (clock or {}).get("gap_ms", 0))
+    assert sum(totals["program_ms_by_phase"].values()) == pytest.approx(
+        n * (clock or {}).get("program_ms", 0))
+    assert totals["resume_lag_ms"] == pytest.approx(
+        n * (clock or {}).get("resume_lag_ms", 0))
+
+
+def _tiny_engine():
+    from langstream_tpu.serving.engine import ServingConfig, TpuServingEngine
+
+    return TpuServingEngine(ServingConfig(
+        model="tiny", model_dtype="float32", slots=4, max_seq_len=128,
+        decode_chunk=4, kv_block_size=16, prefix_cache=False,
+    ))
+
+
+def test_an_engine_s_samples_tile_the_dispatch_thread_s_account(run_async):
+    """Over a run of the tiny engine (prefill batches one ahead, pipelined
+    chunks, a chunk left pending under the next round's prefills): every
+    dispatch's sample carries the three fields, and gap_ms + program_ms of
+    consecutive samples tile from the first program's enqueue to the last
+    one's completion."""
+    async def main():
+        engine = _tiny_engine()
+        try:
+            await engine.generate("warm the shapes", {"max-tokens": 6})
+            await asyncio.gather(*(
+                engine.generate(f"tile the device's time {i} " * (1 + i % 3),
+                                {"max-tokens": 4 + 3 * i})
+                for i in range(7)))
+        finally:
+            await engine.close()
+        return engine.flight
+
+    flight = run_async(main())
+    samples = [s for s in flight.recent(0) if s["phase"] != "stall"]
+    assert len(samples) >= 8
+    for s in samples:
+        assert s["gap_ms"] >= 0 and s["program_ms"] >= 0, s
+        assert s["resume_lag_ms"] >= 0, s
+    clock = flight.clock
+    assert not clock._open                 # every program was seen complete
+    span_ms = (clock.last_ready_t - clock.first_enqueued_t) * 1e3
+    tiled = sum(s["gap_ms"] + s["program_ms"] for s in samples)
+    assert tiled == pytest.approx(span_ms, abs=0.001 * len(samples))  # rounding
+    totals = flight.summary()["totals"]
+    assert totals["gap_ms"] + sum(
+        totals["program_ms_by_phase"].values()) == pytest.approx(span_ms, abs=0.01)
+    assert set(totals["program_ms_by_phase"]) == {"prefill", "decode"}
+    assert totals["resume_lag_ms"] == pytest.approx(
+        sum(s["resume_lag_ms"] for s in samples), abs=0.001 * len(samples))
+    # a program's own time is never more than the wall its sample tiles plus
+    # what ran before the sample's slice began; its wait (device_ms) is
+    assert all(s["device_ms"] <= s["wall_ms"] for s in samples)
+
+
+@pytest.mark.parametrize("blocked_s", [0.0, 0.05], ids=["idle-loop", "a-tenant"])
+def test_resume_lag_is_the_loop_s_ready_queue(run_async, blocked_s):
+    """With nothing else on the loop the coroutine runs again within a
+    wake-up of the dispatch thread's return; with a tenant that holds the
+    loop synchronously for ``blocked_s`` at a time, some dispatch waits at
+    least that long for its turn, and the sample says so."""
+    async def tenant(stop):
+        while not stop.is_set():
+            time.sleep(blocked_s)          # synchronous: the loop stands
+            await asyncio.sleep(0.001)
+
+    async def main():
+        engine = _tiny_engine()
+        stop = asyncio.Event()
+        try:
+            await engine.generate("warm the shapes", {"max-tokens": 6})
+            mark = engine.flight.recorded
+            beside = (asyncio.ensure_future(tenant(stop)) if blocked_s
+                      else None)
+            await asyncio.gather(*(
+                engine.generate(f"who holds the loop {i}", {"max-tokens": 8})
+                for i in range(3)))
+            stop.set()
+            if beside is not None:
+                await beside
+        finally:
+            await engine.close()
+        return engine.flight.recent(engine.flight.recorded - mark)
+
+    lags = [s["resume_lag_ms"] for s in run_async(main())
+            if s["phase"] != "stall"]
+    assert lags
+    if blocked_s:
+        # a fetch of some milliseconds returns while the tenant sleeps: the
+        # coroutine is ready and waits out what is left of the sleep
+        assert max(lags) >= 0.5 * blocked_s * 1e3, lags
+        assert sum(lags) >= blocked_s * 1e3, lags
+    else:
+        assert sorted(lags)[len(lags) // 2] < 5.0, lags   # "0": a wake-up
 
 
 def test_paged_engine_under_load_decomposes_wall_time(run_async):
@@ -294,13 +527,14 @@ def test_engine_samples_name_their_dispatch(run_async):
         # the steps are the program's own chunk size
         assert f":k{s['steps']}:" in s["program"] and s["steps"] > 0
         assert 1 <= s["active_at_dispatch"] <= 4
-        # the paged read's work: blocks that hold rows (a prompt of ~20
-        # bytes and up to 9 answers: one 64-row block a slot, two at most)
-        # against the table columns of the whole batch's window
-        assert s["active_at_dispatch"] <= s["live_blocks"] <= 8
-        assert s["table_blocks"] >= 4 and s["table_blocks"] % 4 == 0
-        assert s["live_blocks"] <= s["table_blocks"]
-    assert all("live_blocks" not in s for s in prefill)
+        # the paged read's work: the rows it has to fetch (a prompt of ~20
+        # bytes and up to 9 answers a slot; the blocks that hold them and
+        # the window's table columns, once written beside them, had no
+        # reader and went: PR 36)
+        assert s["active_at_dispatch"] <= s["live_rows"] \
+            <= 64 * s["active_at_dispatch"]
+        assert "table_blocks" not in s and "live_blocks" not in s
+    assert all("live_rows" not in s for s in prefill)
     # a chunk in which requests finish is recorded after their slots were
     # freed: what was running at dispatch is the larger number
     assert any(s["active_at_dispatch"] > s["occupancy"] for s in decode)

@@ -10,13 +10,15 @@ import contextlib
 import glob
 import os
 import re
+import sys
+import threading
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from langstream_tpu.serving.flight import SPANS
+from langstream_tpu.serving.flight import HELD_SPANS, SPANS
 
 LAYER_SCOPES = ("embed", "attn_qkv", "kv_read", "attn_out", "ffn", "lm_head",
                 "sample")
@@ -35,7 +37,9 @@ def tiny_engine(**kw):
 # -- (2) a real capture, on the CPU backend ------------------------------
 
 
-def host_spans(trace_dir):
+def host_spans(trace_dir, threads=False):
+    """``(name, stats)`` of every ``ls.*`` event of the capture's host
+    plane; with ``threads``, ``(name, stats, thread)`` (the plane's line)."""
     from jax.profiler import ProfileData
 
     (path,) = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
@@ -44,10 +48,11 @@ def host_spans(trace_dir):
     for plane in ProfileData.from_file(path).planes:
         if plane.name != "/host:CPU":
             continue
-        for line in plane.lines:
+        for thread, line in enumerate(plane.lines):
             for e in line.events:
                 if e.name.startswith("ls."):
-                    spans.append((e.name, dict(e.stats)))
+                    spans.append((e.name, dict(e.stats))
+                                 + ((thread,) if threads else ()))
     return spans
 
 
@@ -92,6 +97,253 @@ def test_a_capture_holds_the_engine_loop_s_spans(run_async, tmp_path):
             sample = dispatched[meta["seq"]]
             assert meta["program"] == sample["program"]
             assert meta["steps"] == sample["steps"] > 0
+
+
+# -- (2b) the loop's other tenants, and the wait's two halves ------------
+
+CHAT_APP = {
+    "configuration.yaml": """
+configuration:
+  resources:
+    - type: "tpu-serving-configuration"
+      name: "tpu"
+      configuration:
+        model: "tiny"
+        model-dtype: "float32"
+        slots: 4
+        max-seq-len: 128
+        kv-block-size: 16
+        decode-chunk: 4
+        prefix-cache: false
+        streaming: true
+""",
+    "pipeline.yaml": """
+topics:
+  - name: "questions"
+    creation-mode: create-if-not-exists
+  - name: "answers"
+    creation-mode: create-if-not-exists
+pipeline:
+  - name: "chat"
+    type: "ai-chat-completions"
+    input: "questions"
+    output: "answers"
+    configuration:
+      model: "tiny"
+      max-tokens: 10
+      completion-field: "value.answer"
+      stream-to-topic: "answers"
+      stream-response-completion-field: "value"
+      min-chunks-per-message: 1
+      messages:
+        - role: user
+          content: "{{ value }}"
+""",
+    "gateways.yaml": """
+gateways:
+  - id: "chat"
+    type: chat
+    chat-options:
+      questions-topic: "questions"
+      answers-topic: "answers"
+      headers:
+        - key: "langstream-client-session-id"
+          value-from-parameters: sessionId
+""",
+}
+CHAT_INSTANCE = """
+instance:
+  streamingCluster:
+    type: "memory"
+  computeCluster:
+    type: "local"
+"""
+
+
+@pytest.fixture(scope="module")
+def chat_capture(tmp_path_factory):
+    """One chat turn through the whole one-pod deployment (gateway, topic,
+    runner, agent, engine: one event loop) under a profiler session: the
+    capture's ``(name, stats, thread)`` spans."""
+    import socket
+
+    import aiohttp
+
+    from langstream_tpu.controlplane.server import (
+        ControlPlaneServer,
+        LocalComputeRuntime,
+    )
+    from langstream_tpu.controlplane.stores import InMemoryApplicationStore
+    from langstream_tpu.gateway.server import GatewayRegistry, GatewayServer
+    from langstream_tpu.serving.engine import TpuServingEngine
+
+    trace_dir = str(tmp_path_factory.mktemp("chat-capture"))
+
+    def free_port():
+        with socket.socket() as sock:
+            sock.bind(("127.0.0.1", 0))
+            return sock.getsockname()[1]
+
+    async def ask(session, url, text):
+        async with session.ws_connect(url) as chat:
+            await chat.send_json({"value": text})
+            while True:
+                msg = await asyncio.wait_for(chat.receive_json(), 300)
+                headers = (msg.get("record") or {}).get("headers") or {}
+                if "langstream-completion-tokens" in headers:
+                    return
+                assert "record" in msg or msg.get("status") in (None, "OK"), msg
+
+    async def main():
+        registry = GatewayRegistry()
+        compute = LocalComputeRuntime(gateway_registry=registry)
+        control = ControlPlaneServer(
+            store=InMemoryApplicationStore(), compute=compute, port=free_port())
+        gateway = GatewayServer(registry=registry, port=free_port())
+        await control.start()
+        await gateway.start()
+        session = aiohttp.ClientSession()
+        try:
+            api = f"http://127.0.0.1:{control.port}"
+            async with session.put(f"{api}/api/tenants/prof") as resp:
+                assert resp.status in (200, 201)
+            async with session.post(
+                f"{api}/api/applications/prof/chat",
+                json={"files": CHAT_APP, "instance": CHAT_INSTANCE},
+            ) as resp:
+                assert resp.status in (200, 201), await resp.text()
+            url = (f"ws://127.0.0.1:{gateway.port}/v1/chat/prof/chat/chat"
+                   "?param:sessionId=s1")
+            await ask(session, url, "warm the shapes")   # builds the engine
+            with jax.profiler.trace(trace_dir):
+                await ask(session, url, "what runs on this loop?")
+        finally:
+            await session.close()
+            await gateway.stop()
+            await control.stop()
+            await compute.close()
+            with TpuServingEngine._instances_lock:
+                engines = list(TpuServingEngine._instances.values())
+            for engine in engines:
+                await engine.close()
+            TpuServingEngine.reset_instances()
+
+    asyncio.run(main())
+    return host_spans(trace_dir, threads=True)
+
+
+def test_every_span_of_a_chat_turn_is_in_the_vocabulary(chat_capture):
+    names = {name for name, _, _ in chat_capture}
+    assert names <= set(SPANS), names - set(SPANS)
+    assert set(HELD_SPANS) <= set(SPANS)
+
+
+@pytest.mark.parametrize("name", [n for n in SPANS if n.startswith("ls.hop.")])
+def test_a_chat_turn_opens_every_tenant_s_span(chat_capture, name):
+    assert any(n == name for n, _, _ in chat_capture), name
+
+
+@pytest.mark.parametrize("name", HELD_SPANS + ("ls.prefill.wait",
+                                               "ls.decode.wait"))
+def test_the_held_spans_and_the_waits_carry_the_dispatch_s_seq(chat_capture,
+                                                               name):
+    seqs = [stats.get("seq") for n, stats, _ in chat_capture if n == name]
+    assert seqs and all(isinstance(seq, int) and seq > 0 for seq in seqs), seqs
+
+
+def test_the_tenants_run_on_the_loop_s_thread_and_the_waits_do_not(
+        chat_capture):
+    threads = {}
+    for name, _, thread in chat_capture:
+        threads.setdefault(name, set()).add(thread)
+    (loop,) = threads["ls.decode.prepare"]        # the engine's coroutine
+    for name in ("ls.hop.gw.send", "ls.hop.gw.recv", "ls.hop.topic",
+                 "ls.hop.agent", "ls.hop.deliver", "ls.hop.runner",
+                 "ls.prefill.handoff", "ls.prefill.fetch", "ls.decode.fetch"):
+        assert threads[name] == {loop}, (name, threads[name], loop)
+    (dispatch,) = threads["ls.decode.dispatch"]   # the dispatch thread
+    assert dispatch != loop
+    for name in ("ls.prefill.wait", "ls.decode.wait"):
+        assert threads[name] == {dispatch}, (name, threads[name])
+
+
+# the dispatch thread's jobs that the loop awaits under a held span (and
+# the decode dispatch beside them), by the qualified name of their code
+THREAD_JOBS = {
+    "TpuServingEngine._dispatch_prefill.<locals>._run": "ls.prefill.dispatch",
+    "TpuServingEngine._fetch_prefill.<locals>._run": "ls.prefill.wait",
+    "TpuServingEngine._fetch_chunk": "ls.decode.wait",
+    "TpuServingEngine._decode_burst.<locals>._dispatch": "ls.decode.dispatch",
+}
+
+
+class _ThreadSpan:
+    """``flight.span`` for the test below: counts the spans open on the
+    thread that opens them."""
+
+    open_on = {}
+
+    def __init__(self, name, **meta):
+        self.name = name
+
+    def __enter__(self):
+        self.thread = threading.get_ident()
+        self.open_on.setdefault(self.thread, []).append(self.name)
+
+    def __exit__(self, *exc):
+        self.open_on[self.thread].pop()
+
+
+@pytest.mark.parametrize("job", sorted(THREAD_JOBS))
+def test_a_dispatch_thread_job_does_nothing_outside_its_span(run_async, job):
+    """What an idle instant under a held name means (the coroutine waits
+    for its turn, nobody named) holds only if the dispatch thread's work
+    has a name of its own from the job's first call to its last: every
+    call a job makes lies inside the span its thread opens."""
+    stray, seen = [], set()
+
+    def watch(frame, event, arg):
+        # the job's own frame: what it calls with no span open on this
+        # thread, the span's own construction and entry apart
+        if event not in ("call", "c_call"):
+            return
+        caller = frame.f_back if event == "call" else frame
+        if caller is None or caller.f_code.co_qualname != job:
+            return
+        opened = _ThreadSpan.open_on.get(threading.get_ident())
+        if opened:
+            seen.add(opened[-1])
+            return
+        callee = (frame.f_code.co_qualname if event == "call"
+                  else getattr(arg, "__qualname__", repr(arg)))
+        # a span's construction (its meta) and entry are not work under it
+        if not callee.startswith(("_ThreadSpan", "_span_meta")):
+            stray.append(callee)
+
+    async def main():
+        engine = tiny_engine()
+        engine.flight.span = _ThreadSpan
+
+        def on_the_dispatch_thread(fn, *args):
+            return asyncio.get_running_loop().run_in_executor(
+                engine._executor, fn, *args)
+
+        try:
+            await engine.generate("warm the shapes", {"max-tokens": 6})
+            await on_the_dispatch_thread(sys.setprofile, watch)
+            try:
+                await asyncio.gather(*(
+                    engine.generate(f"span prompt {i}", {"max-tokens": 6})
+                    for i in range(3)
+                ))
+            finally:
+                await on_the_dispatch_thread(sys.setprofile, None)
+        finally:
+            await engine.close()
+
+    run_async(main())
+    assert THREAD_JOBS[job] in seen, (job, seen)   # the job ran, under its span
+    assert not stray, (job, stray)
 
 
 # -- (3) the scope names in the lowered programs -------------------------

@@ -38,6 +38,119 @@ def _fresh_spans():
 
 
 # --------------------------------------------------------------------------
+# host spans on the profiler's clock: one helper, no JAX of its own
+# --------------------------------------------------------------------------
+
+
+def test_host_span_in_a_process_without_jax_is_one_shared_no_op():
+    """A pod that never loaded JAX (a gateway with no engine) opens no-op
+    spans, and the helper does not load JAX for them."""
+    import os
+    import subprocess
+    import sys
+
+    code = (
+        "import sys\n"
+        "from langstream_tpu.core import tracing\n"
+        "a = tracing.host_span('ls.hop.gw.send', records=3)\n"
+        "b = tracing.host_span('ls.hop.topic')\n"
+        "with a:\n"
+        "    with b:\n"
+        "        pass\n"
+        "assert a is b is tracing._NO_SPAN, (a, b)\n"
+        "assert 'jax' not in sys.modules and 'numpy' not in sys.modules\n"
+        "print('no-op')\n"
+    )
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    done = subprocess.run(
+        [sys.executable, "-c", code], cwd=root, capture_output=True, text=True,
+        timeout=120, env={**os.environ, "PYTHONPATH": root},
+    )
+    assert done.returncode == 0, done.stderr[-2000:]
+    assert done.stdout.strip() == "no-op"
+
+
+def test_host_span_is_the_profiler_s_annotation_under_a_session(tmp_path):
+    import jax.profiler
+
+    from langstream_tpu.serving.flight import FlightRecorder
+
+    # no session: the profiler's flag is read and nothing is built
+    assert tracing.host_span("ls.hop.agent", records=2) is tracing._NO_SPAN
+    with jax.profiler.trace(str(tmp_path)):
+        span = tracing.host_span("ls.hop.agent", records=2)
+        assert isinstance(span, jax.profiler.TraceAnnotation)
+        with span:
+            pass
+    assert tracing.host_span("ls.hop.agent") is tracing._NO_SPAN
+    # the engine's spans and the tenants' are the same helper
+    assert FlightRecorder.span is tracing.host_span
+    assert FlightRecorder(slots=1).span is tracing.host_span
+
+
+@pytest.mark.parametrize("module", [
+    "langstream_tpu.gateway.server", "langstream_tpu.runtime.runner",
+    "langstream_tpu.runtime.memory_broker", "langstream_tpu.agents.ai",
+    "langstream_tpu.serving.flight",
+])
+def test_every_layer_on_the_loop_opens_its_spans_through_the_one_helper(module):
+    """No second wrapper: a module that opens ``ls.*`` spans binds
+    ``core.tracing.host_span`` itself and never the profiler's class."""
+    import importlib
+    import inspect
+
+    mod = importlib.import_module(module)
+    assert mod.host_span is tracing.host_span
+    assert "import TraceAnnotation" not in inspect.getsource(mod)
+
+
+def test_a_gateway_s_push_loop_without_jax_opens_no_op_spans(
+        run_async, monkeypatch):
+    """The gateway's frames go through the helper: with JAX hidden from it
+    (``sys.modules`` is what it consults) the push loop's span is the shared
+    no-op, and the frame is sent all the same."""
+    import sys
+
+    from langstream_tpu.api.record import make_record
+    from langstream_tpu.gateway import server
+
+    monkeypatch.delitem(sys.modules, "jax")
+    monkeypatch.setattr(tracing, "_session", None)
+    opened = []
+
+    def spy(name, **meta):
+        span = tracing.host_span(name, **meta)
+        opened.append((name, meta, span))
+        return span
+
+    monkeypatch.setattr(server, "host_span", spy)
+
+    class Socket:
+        closed = False
+        sent: list = []
+
+        async def send_json(self, frame):
+            self.sent.append(frame)
+            self.closed = True          # one frame, then the loop ends
+
+    class Reader:
+        async def read(self, timeout=None):
+            return [
+                make_record(value="mine", headers={"session": "s1"}),
+                make_record(value="another's", headers={"session": "s2"}),
+            ]
+
+    gateway = server.GatewayServer.__new__(server.GatewayServer)
+    socket_ = Socket()
+    run_async(gateway._chat_push_loop(socket_, Reader(), {"session": "s1"}))
+    assert [f["record"]["value"] for f in socket_.sent] == ["mine"]
+    assert [(name, meta) for name, meta, _ in opened] == [
+        ("ls.hop.gw.send", {"records": 2})]
+    assert opened[0][2] is tracing._NO_SPAN
+    assert "jax" not in sys.modules
+
+
+# --------------------------------------------------------------------------
 # context + span units
 # --------------------------------------------------------------------------
 
