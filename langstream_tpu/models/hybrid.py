@@ -819,9 +819,9 @@ def hybrid_decode_chunk_paged(
 
     def cache_partial(q, a):
         if kernel == "xla":
-            at = lambda t: jax.lax.dynamic_index_in_dim(t, a, keepdims=False)  # noqa: E731
+            # the stacked pool and the layer's index, as the kernel takes them
             return _cache_partial_xla(
-                c, q, at(pool_k), at(pool_v), block_tables, base_lengths,
+                c, q, pool_k, pool_v, a, block_tables, base_lengths,
                 num_read_blocks, scale=c.attention_scale,
             )
         return paged_attention_partial(

@@ -2,7 +2,9 @@
 
 The row arithmetic (:func:`quantize_rows`, :func:`cache_scores`,
 :func:`cache_values`) is shared by the paged int8 pool the engine serves
-(:mod:`langstream_tpu.models.paged`, ``ops/paged_attention.py``). The
+(:mod:`langstream_tpu.models.paged`, ``ops/paged_attention.py``); the
+decode read's two products over a gathered window, bf16 or int8, are
+:func:`window_scores` and :func:`window_values`. The
 dense-cache helpers (:func:`init_kv_cache_int8`, :func:`cache_write_rows`,
 :func:`cache_slice_window`, ...) serve the dense reference in
 :mod:`langstream_tpu.models.llama`, which only tests call since PR 29.
@@ -137,3 +139,68 @@ def cache_values(probs: jax.Array, cv_l: Any) -> jax.Array:
     return jnp.einsum(
         "bkgs,bskd->bkgd", scaled, cv_l["q"].astype(probs.dtype)
     )
+
+
+def _own_head(kv_heads: int) -> jax.Array:
+    """(1, Kh, 1, Kh, 1) bool: a kv head against itself, placed for the
+    (B, Kh, *, Kh, *) views of the two window products."""
+    return jnp.eye(kv_heads, dtype=bool)[None, :, None, :, None]
+
+
+def window_scores(q: jax.Array, kw: Any, kv_heads: int) -> jax.Array:
+    """Scores of one query a slot against a gathered window, in the layout
+    the gather left it (``models/paged.py`` ``gather_kv``).
+
+    ``q``: (B, H, D); ``kw``: (B, W, Kh*D) bf16 or the int8 pair ``{"q":
+    (B, W, Kh*D), "s": (B, W, Kh)}``. Returns f32 (B, Kh, G, W), unscaled by
+    1/sqrt(D) (the caller applies it).
+
+    The window is contracted over ``Kh*D`` AS IT LIES, against a
+    block-diagonal query (B, Kh*D, H): head h's D numbers in its own kv
+    head's rows, zeros in the others' (they add nothing to a sum). Kh times
+    the multiply-adds of a product a head, and no copy: splitting ``Kh*D``
+    into a batch dimension of heads makes the compiler carry heads outward,
+    two passes over a window of slots x rows x Kh*D bytes, where the
+    product itself is bound by reading it once (ROADMAP S1). The int8 ->
+    model-dtype convert rides in the product's operand; the scale is
+    constant along D and multiplies the scores."""
+    B, H, D = q.shape
+    G = H // kv_heads
+    quant = is_quant_cache(kw)
+    data = kw["q"] if quant else kw
+    W = data.shape[1]
+    # (B, j, D, k, G): q[b, k, g, d] where j == k
+    qt = q.reshape(B, kv_heads, G, D).transpose(0, 3, 1, 2)
+    qbd = jnp.where(_own_head(kv_heads), qt[:, None], 0).reshape(
+        B, kv_heads * D, H
+    )
+    s = jnp.einsum(
+        "bwc,bch->bhw", data.astype(q.dtype), qbd,
+        preferred_element_type=jnp.float32,
+    ).reshape(B, kv_heads, G, W)
+    if quant:
+        s = s * kw["s"].transpose(0, 2, 1)[:, :, None, :]
+    return s
+
+
+def window_values(probs: jax.Array, vw: Any, kv_heads: int, dtype) -> jax.Array:
+    """Value mix of a gathered window, in the layout the gather left it.
+
+    ``probs``: f32 (B, Kh, G, W); ``vw``: (B, W, Kh*D) bf16 or the int8
+    pair; ``dtype`` the model's (the product's operands). Returns f32
+    (B, Kh, G, D).
+
+    Every head is mixed with the whole ``Kh*D`` row, (B, H, W) against
+    (B, W, Kh*D), and its own kv head's block of the result is kept: the
+    twin of :func:`window_scores`. The int8 scale varies along the
+    contracted W and folds into the probabilities."""
+    B, Kh, G, W = probs.shape
+    quant = is_quant_cache(vw)
+    data = vw["q"] if quant else vw
+    if quant:
+        probs = probs * vw["s"].transpose(0, 2, 1)[:, :, None, :]
+    mixed = jnp.einsum(
+        "bhw,bwc->bhc", probs.astype(dtype).reshape(B, Kh * G, W),
+        data.astype(dtype), preferred_element_type=jnp.float32,
+    ).reshape(B, Kh, G, Kh, -1)
+    return jnp.where(_own_head(Kh), mixed, 0.0).sum(axis=3)

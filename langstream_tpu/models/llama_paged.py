@@ -598,60 +598,72 @@ def llama_verify_chunk_paged(
     return emitted, adv, next_tokens, new_lengths, pool_k, pool_v, logprobs
 
 
-def _gather_layer_window(c, pool_l, block_tables, num_read_blocks):
-    """Densify one layer's window: (B, W, Kh, D) bf16, or the int8
-    {"q": (B,W,Kh,D), "s": (B,W,Kh)} pair ready for the kvquant helpers."""
-    add_l = lambda a: a[None]
-    drop_l = lambda a: a[0]
-    if isinstance(pool_l, dict):
-        w = gather_kv(jax.tree.map(add_l, pool_l), block_tables, num_read_blocks)
-        B, W = w["s"].shape[1:3]
-        return {
-            "q": w["q"][0].reshape(B, W, c.kv_heads, c.head_dim),
-            "s": w["s"][0],
-        }
-    w = drop_l(gather_kv(add_l(pool_l), block_tables, num_read_blocks))
-    B, W = w.shape[:2]
-    return w.reshape(B, W, c.kv_heads, c.head_dim)
+# The XLA read takes a window in passes of so many bytes of ONE pool's
+# gathered rows (slots x rows x Kh*D): small enough that the TPU compiler
+# keeps a pass's K rows, then its V rows, in VMEM (128 MiB on a v5e) from
+# the gather that writes them to the product that reads them, so that a
+# window's bytes cross HBM once, on their way out of the pool. At Mistral-7B's
+# posture (64 slots, int8 rows of 1,024 B) a pass is 512 rows.
+_XLA_READ_PASS_BYTES = 32 * 2**20
 
 
 def _cache_partial_xla(
     c: LlamaConfig,
     q: jax.Array,             # (B, H, D)
-    ck_l,                     # (nb, bs, KhD) array or int8 {"q","s"} pool
-    cv_l,
+    pool_k,                   # (L, nb, bs, KhD) array or int8 {"q","s"} pool
+    pool_v,
+    layer,                    # the layer's index in the stacked pools
     block_tables: jax.Array,  # (B, max_blocks)
     lengths: jax.Array,       # (B,)
     num_read_blocks: int,
     scale: float | None = None,   # None: 1/sqrt(head_dim)
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
-    """Reference paged read: gather the window densely, compute partial
-    softmax stats. Works on every backend and under pjit meshes (gathers
-    shard like any XLA op); pays one densified copy. int8 pools read
-    through the fused kvquant helpers (scales onto scores/probs)."""
-    from langstream_tpu.models.kvquant import cache_scores, cache_values
+    """The XLA paged read: partial softmax statistics of one query a slot
+    over the layer's window. Works on every backend, for bf16 and int8
+    pools and under pjit meshes (gathers shard like any XLA op): what the
+    CPU, every int8 pool and the tests' reference read through.
+
+    A window's bytes move once (ROADMAP S1): :func:`gather_kv` takes the
+    slots' blocks out of the STACKED pool at ``(layer, block)`` (no slice
+    of the layer, no fill), the two products contract the rows in the
+    layout the gather wrote (``kvquant.window_scores`` / ``window_values``:
+    int8 converts in the product's operand, scales onto scores and
+    probabilities), and the window goes in passes of
+    ``_XLA_READ_PASS_BYTES`` whose rows never reach HBM again, their
+    partials merged as the chunk buffer's is. It still sweeps the whole
+    window bucket: reading a slot's live blocks alone is the Pallas
+    driver's (``ops/paged_attention.py``)."""
+    from langstream_tpu.models.kvquant import window_scores, window_values
+    from langstream_tpu.ops.paged_attention import merge_partials
 
     B, H, D = q.shape
-    kw = _gather_layer_window(c, ck_l, block_tables, num_read_blocks)
-    vw = _gather_layer_window(c, cv_l, block_tables, num_read_blocks)
-    W = (kw["s"] if isinstance(kw, dict) else kw).shape[1]
-    G = c.heads // c.kv_heads
-    qg = q.reshape(B, c.kv_heads, G, c.head_dim)
-    s = cache_scores(qg, kw)
-    s = s / math.sqrt(c.head_dim) if scale is None else s * scale
-    mask = (jnp.arange(W)[None, :] < lengths[:, None])[:, None, None, :]
-    s = jnp.where(mask, s, NEG_INF)
-    m = jnp.max(s, axis=-1)                                   # (B, Kh, G)
-    shift = jnp.where(m <= NEG_INF, 0.0, m)
-    p = jnp.exp(s - shift[..., None])
-    p = jnp.where(mask, p, 0.0)
-    l = jnp.sum(p, axis=-1)
-    acc = cache_values(p.astype(q.dtype), vw).astype(jnp.float32)
-    return (
-        acc.reshape(B, H, D),
-        m.reshape(B, H),
-        l.reshape(B, H),
-    )
+    data = pool_k["q"] if isinstance(pool_k, dict) else pool_k
+    bs, row_bytes = data.shape[2], data.shape[3] * data.dtype.itemsize
+    cols = max(1, _XLA_READ_PASS_BYTES // (B * bs * row_bytes))
+
+    def one_pass(first: int, n: int):
+        """Table columns ``first .. first + n``: rows from ``first * bs``."""
+        tables = block_tables[:, first:first + n]
+        kw = gather_kv(pool_k, tables, n, layer=layer)
+        vw = gather_kv(pool_v, tables, n, layer=layer)
+        s = window_scores(q, kw, c.kv_heads)                  # (B, Kh, G, W)
+        s = s / math.sqrt(c.head_dim) if scale is None else s * scale
+        rows = first * bs + jnp.arange(n * bs)
+        mask = (rows[None, :] < lengths[:, None])[:, None, None, :]
+        s = jnp.where(mask, s, NEG_INF)
+        m = jnp.max(s, axis=-1)                               # (B, Kh, G)
+        shift = jnp.where(m <= NEG_INF, 0.0, m)
+        p = jnp.exp(s - shift[..., None])
+        p = jnp.where(mask, p, 0.0)
+        l = jnp.sum(p, axis=-1)
+        acc = window_values(p, vw, c.kv_heads, q.dtype)
+        return acc.reshape(B, H, D), m.reshape(B, H), l.reshape(B, H)
+
+    out = None
+    for first in range(0, num_read_blocks, cols):
+        part = one_pass(first, min(cols, num_read_blocks - first))
+        out = part if out is None else merge_partials(out, part)
+    return out
 
 
 def llama_decode_chunk_paged(
@@ -720,13 +732,13 @@ def llama_decode_chunk_paged(
         )
 
     def cache_partial(q, kv_l):
+        # either read takes the stacked pool where it lies (read-only for
+        # the whole chunk) and the layer's index: no slice of it exists
         if kernel == "xla":
-            ck_l, cv_l = kv_l
             return _cache_partial_xla(
-                c, q, ck_l, cv_l, block_tables, base_lengths, num_read_blocks
+                c, q, pool_k, pool_v, kv_l, block_tables, base_lengths,
+                num_read_blocks,
             )
-        # the Pallas read takes the stacked pool where it lies (read-only
-        # for the whole chunk) and the layer's index: no slice of it exists
         if mesh is not None and len(mesh.devices.flatten()) > 1:
             # pallas_call has no SPMD rule: shared mesh wrapper — slots on
             # dp, heads on tp, per-axis degradation
@@ -824,9 +836,8 @@ def llama_decode_chunk_paged(
         layer_xs = (
             params["layers"],
             None if adapters is None else adapters["layers"],
-            # the XLA read has the scan slice the layer's pool; the Pallas
-            # read is handed the layer's index and closes over the pool
-            (pool_k, pool_v) if kernel == "xla" else jnp.arange(c.layers),
+            # the read is handed the layer's index and closes over the pool
+            jnp.arange(c.layers),
             kbuf, vbuf,
         )
         x, (kbuf, vbuf) = jax.lax.scan(layer, x, layer_xs)

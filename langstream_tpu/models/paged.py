@@ -15,9 +15,10 @@ functions here are jit-pure; the host side (free lists, reservations) lives
 in :class:`BlockManager`.
 
 Read paths:
-- :func:`gather_kv` — XLA reference: gathers a slot's blocks into a dense
-  window. Correct everywhere (CPU tests, sharded meshes); costs an extra
-  HBM round-trip for the gathered copy.
+- :func:`gather_kv` — XLA read: gathers a slot's blocks from the stacked
+  pool into a dense window. Correct everywhere (CPU tests, sharded meshes,
+  int8 pools); the window is written once and read once by the product
+  that contracts it.
 - :mod:`langstream_tpu.ops.paged_attention` — Pallas kernel that walks the
   block table directly via scalar prefetch; no gathered copy. Single-chip
   TPU fast path.
@@ -183,19 +184,33 @@ def gather_kv(
     cache,                    # (L, nb, bs, KhD) array or int8 {"q","s"} pool
     block_tables: jax.Array,  # (B, max_blocks)
     num_read_blocks: int,     # static: table columns to read (window bucket)
+    layer=None,               # None: every layer; else one layer's index
 ):
-    """XLA reference read: densify the first ``num_read_blocks`` blocks of
-    every slot → ``(L, B, num_read_blocks*bs, KhD)`` (int8 pools gather
-    data and scales alike — trailing dims pass through)."""
+    """XLA read: the first ``num_read_blocks`` blocks of every slot as a
+    dense window, ``(L, B, num_read_blocks*bs, KhD)``, or with ``layer``
+    (an int or a traced scalar, the decode scan's) ``(B, num_read_blocks*bs,
+    KhD)`` of that layer alone (int8 pools gather data and scales alike:
+    trailing dims pass through).
+
+    One gather on the STACKED pool, indexed ``(layer, block)``: no slice of
+    a layer exists beside the pool, and the window leaves with the pool's
+    own minor dimension, so a product that contracts ``Kh*D`` as it lies
+    (``kvquant.window_scores`` / ``window_values``) reads what the gather
+    wrote and nothing relays it (ROADMAP S1). The indices are promised in
+    bounds: a table holds block ids of the pool, or 0, the scratch block
+    (:class:`BlockManager`), so a fill guards nothing and its select would
+    pass over the whole window; rows past a slot's length are masked on
+    the scores by the caller."""
     tables = block_tables[:, :num_read_blocks]               # (B, nrb)
-    B = tables.shape[0]
+    index = (slice(None) if layer is None else layer, tables)
 
     def gather(pool):
-        bs = pool.shape[2]
-        tail = pool.shape[3:]
-        gathered = jnp.take(pool, tables, axis=1)  # (L, B, nrb, bs, tail)
-        return gathered.reshape(
-            (pool.shape[0], B, num_read_blocks * bs) + tail
+        # ([L,] B, nrb, bs, tail) -> ([L,] B, nrb*bs, tail)
+        got = pool.at[index].get(mode="promise_in_bounds")
+        lead = got.ndim - pool.ndim + 1
+        return got.reshape(
+            got.shape[:lead] + (num_read_blocks * pool.shape[2],)
+            + pool.shape[3:]
         )
 
     if isinstance(cache, dict):

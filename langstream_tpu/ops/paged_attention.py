@@ -935,19 +935,25 @@ def shard_mapped_paged_read(
     )
 
 
+def merge_partials(a, b):
+    """Two ``(acc, m, l)`` partials of one softmax as one: the associative
+    online-softmax merge (a side that attended nothing has ``m <= NEG_INF``
+    and weighs 0)."""
+    (acc, m, l), (acc2, m2, l2) = a, b
+    m_new = jnp.maximum(m, m2)
+    shift = jnp.where(m_new <= NEG_INF, 0.0, m_new)
+    a1 = jnp.exp(jnp.where(m <= NEG_INF, NEG_INF, m - shift))
+    a2 = jnp.exp(jnp.where(m2 <= NEG_INF, NEG_INF, m2 - shift))
+    return acc * a1[..., None] + acc2 * a2[..., None], m_new, l * a1 + l2 * a2
+
+
 def merge_partial_attention(
     parts: list[tuple[jax.Array, jax.Array, jax.Array]],
 ) -> jax.Array:
     """Combine per-segment ``(acc, m, l)`` partials into normalised attention
-    output: the associative online-softmax merge."""
+    output (:func:`merge_partials`, then the division by the sum)."""
     acc, m, l = parts[0]
-    for acc2, m2, l2 in parts[1:]:
-        m_new = jnp.maximum(m, m2)
-        shift = jnp.where(m_new <= NEG_INF, 0.0, m_new)
-        a1 = jnp.exp(jnp.where(m <= NEG_INF, NEG_INF, m - shift))
-        a2 = jnp.exp(jnp.where(m2 <= NEG_INF, NEG_INF, m2 - shift))
-        acc = acc * a1[..., None] + acc2 * a2[..., None]
-        l = l * a1 + l2 * a2
-        m = m_new
+    for part in parts[1:]:
+        acc, m, l = merge_partials((acc, m, l), part)
     inv = jnp.where(l > 0.0, 1.0 / jnp.maximum(l, 1e-30), 0.0)
     return acc * inv[..., None]

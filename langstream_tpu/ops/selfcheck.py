@@ -132,10 +132,8 @@ def check_kernels(
     """One row per (kernel, window bucket). ``model_config`` supplies
     heads / kv_heads / head_dim; pools are random, made from a fixed seed."""
     from langstream_tpu.models.llama import _flash_mode
-    from langstream_tpu.models.llama_paged import (
-        _cache_partial_xla,
-        _gather_layer_window,
-    )
+    from langstream_tpu.models.llama_paged import _cache_partial_xla
+    from langstream_tpu.models.paged import gather_kv
     from langstream_tpu.ops.flash_attention import flash_attention
     from langstream_tpu.ops.paged_attention import (
         NEG_INF,
@@ -165,9 +163,10 @@ def check_kernels(
         }
 
         def single(pk, pv, nrb=nrb, tables=tables, lengths=lengths):
-            # the kernel reads the layer-stacked pool in place: layer 1 of
+            # both reads take the layer-stacked pool in place: layer 1 of
             # two holds the pool, layer 0 zeros
             stack = lambda a: jnp.stack([jnp.zeros_like(a), a])  # noqa: E731
+            pk, pv = jax.tree.map(stack, pk), jax.tree.map(stack, pv)
             got = jax.jit(
                 lambda q, k, v, t, n: merge_partial_attention([
                     paged_attention_partial(
@@ -175,11 +174,10 @@ def check_kernels(
                         head_dim=D, interpret=interpret,
                     )
                 ])
-            )(q1, jax.tree.map(stack, pk), jax.tree.map(stack, pv),
-              tables, lengths)
+            )(q1, pk, pv, tables, lengths)
             ref = jax.jit(
                 lambda q, k, v, t, n: merge_partial_attention([
-                    _cache_partial_xla(c, q, k, v, t, n, nrb)
+                    _cache_partial_xla(c, q, k, v, 1, t, n, nrb)
                 ])
             )(q1, pk, pv, tables, lengths)
             return got, ref
@@ -206,8 +204,10 @@ def check_kernels(
             def xla_history(q, k, v, t, n):
                 # the read the kernel replaces: densify the window, every
                 # suffix query attends the history rows < start
-                kw = _gather_layer_window(c, k, t, nrb).astype(jnp.float32)
-                vw = _gather_layer_window(c, v, t, nrb).astype(jnp.float32)
+                window = lambda pool: gather_kv(  # noqa: E731
+                    pool[None], t, nrb, layer=0
+                ).astype(jnp.float32).reshape(batch, -1, Kh, D)
+                kw, vw = window(k), window(v)
                 W = kw.shape[1]
                 qg = q.astype(jnp.float32).reshape(
                     batch, t_block, Kh, H // Kh, D

@@ -1812,13 +1812,13 @@ class TpuServingEngine:
             # bf16 pools read through the Pallas kernel on TPU (under
             # a mesh per-shard via shard_map: slots on dp, heads on
             # tp): it fetches only the live blocks, from the stacked
-            # pool in place. int8 pools read through the fused XLA
-            # gather: their Pallas twin _paged_kernel_q8 is still on
-            # the static (slots, table columns) grid over a slice of
-            # the layer's pool, compiles on the v5e and matches the
-            # gather at 8B shapes (chip_smoke.py), and has no timing;
-            # moving it onto the bf16 kernel's driver is ROADMAP S3's
-            # next step; paged_kernel=pallas selects it meanwhile.
+            # pool in place. int8 pools read through the XLA gather
+            # (llama_paged._cache_partial_xla: one gather a pool on the
+            # stacked pool, the window written once and read once by
+            # the product that contracts it as it lies, ROADMAP S1).
+            # Their Pallas twin _paged_kernel_q8 (static grid over a
+            # layer's slice, checked by chip_smoke.py, never timed) goes
+            # onto the bf16 kernel's driver later; pallas selects it now.
             kernel = (
                 "pallas"
                 if jax.default_backend() == "tpu" and not quant_pool

@@ -482,7 +482,9 @@ def test_paged_kernel_partial_matches_xla_reference():
     tables = jnp.array([[1, 2, 3, 0], [4, 5, 0, 0], [6, 7, 8, 9]], jnp.int32)
     lengths = jnp.array([20, 9, 24], jnp.int32)
 
-    ref = _cache_partial_xla(c, q, pool_k, pool_v, tables, lengths, nrb)
+    ref = _cache_partial_xla(
+        c, q, pool_k[None], pool_v[None], 0, tables, lengths, nrb
+    )
     got = paged_attention_partial(
         q, pool_k[None], pool_v[None], 0, tables, lengths,
         num_read_blocks=nrb, kv_heads=Kh, head_dim=D, interpret=True,
@@ -520,11 +522,11 @@ def test_paged_kernel_partial_q8_matches_xla_reference():
     tables = jnp.array([[1, 2, 3, 0], [4, 5, 0, 0], [6, 7, 8, 9]], jnp.int32)
     lengths = jnp.array([20, 9, 24], jnp.int32)
 
-    ref = _cache_partial_xla(c, q, pool_k, pool_v, tables, lengths, nrb)
-    add_l = lambda a: a[None]  # noqa: E731 — the kernel takes stacked pools
+    add_l = lambda a: a[None]  # noqa: E731 — both reads take stacked pools
+    pool_k, pool_v = jax.tree.map(add_l, pool_k), jax.tree.map(add_l, pool_v)
+    ref = _cache_partial_xla(c, q, pool_k, pool_v, 0, tables, lengths, nrb)
     got = paged_attention_partial(
-        q, jax.tree.map(add_l, pool_k), jax.tree.map(add_l, pool_v), 0,
-        tables, lengths,
+        q, pool_k, pool_v, 0, tables, lengths,
         num_read_blocks=nrb, kv_heads=Kh, head_dim=D, interpret=True,
     )
     out_ref = merge_partial_attention([ref])
@@ -570,11 +572,11 @@ def test_paged_kernel_q8_batch_leading_layout_pin():
     # ragged: sub-block, block-exact, and full-sweep rows all in one batch
     lengths = jnp.array([3, 8, 11, 16, 5, 13], jnp.int32)
 
-    ref = _cache_partial_xla(c, q, pool_k, pool_v, tables, lengths, nrb)
-    add_l = lambda a: a[None]  # noqa: E731 — the kernel takes stacked pools
+    add_l = lambda a: a[None]  # noqa: E731 — both reads take stacked pools
+    pool_k, pool_v = jax.tree.map(add_l, pool_k), jax.tree.map(add_l, pool_v)
+    ref = _cache_partial_xla(c, q, pool_k, pool_v, 0, tables, lengths, nrb)
     got = paged_attention_partial(
-        q, jax.tree.map(add_l, pool_k), jax.tree.map(add_l, pool_v), 0,
-        tables, lengths,
+        q, pool_k, pool_v, 0, tables, lengths,
         num_read_blocks=nrb, kv_heads=Kh, head_dim=D, interpret=True,
     )
     out_ref = merge_partial_attention([ref])
@@ -629,7 +631,7 @@ def _read_both(c, q, pool_k, pool_v, tables, lengths, layer):
         interpret=True,
     )
     ref = _cache_partial_xla(
-        c, q, jnp.asarray(pool_k[layer]), jnp.asarray(pool_v[layer]),
+        c, q, jnp.asarray(pool_k), jnp.asarray(pool_v), layer,
         tables, lengths, _READ_NRB,
     )
     # a slot that attends nothing has no rows to sum: l is 0 on both sides
@@ -735,6 +737,96 @@ def test_paged_read_never_touches_a_dead_block_or_row(blocks, monkeypatch):
     )
 
 
+_XLA_READ_TOL = {"float32": 1e-5, "bfloat16": 2e-2, "int8": 2e-2}
+
+
+@pytest.mark.parametrize("scale", [None, 0.2], ids=["rsqrt-d", "scale-0.2"])
+@pytest.mark.parametrize("G", [1, 2, 4])
+@pytest.mark.parametrize("cols", [None, 2], ids=["one-pass", "passes-2-2-1"])
+@pytest.mark.parametrize("pool", ["float32", "bfloat16", "int8"])
+def test_xla_read_is_a_plain_softmax_over_the_slot_s_rows(
+        monkeypatch, pool, cols, G, scale):
+    """``_cache_partial_xla`` (the gather on the stacked pool at (layer,
+    block), the two products over the rows as they lie, the window in one
+    pass or in passes of two table columns with a last one of one) against
+    a plain float32 softmax over the same rows: accumulator, max and sum, at
+    a layer other than 0 of a stack whose layers differ, through shuffled
+    tables. Slots: a free one, one at the window's last row, one that ends
+    inside a block (and before the last pass), one at a block's end, one of
+    a single row; a table's columns past the slot's blocks hold 0, the
+    scratch block, whose rows are loud."""
+    from langstream_tpu.models import llama_paged
+    from langstream_tpu.models.kvquant import dequantize_rows, quantize_rows
+    from langstream_tpu.models.llama_paged import _cache_partial_xla
+    from langstream_tpu.ops.paged_attention import NEG_INF
+
+    lengths = np.array((0, _READ_WINDOW, 17, 16, 1), np.int32)
+    dtype = jnp.float32 if pool == "float32" else jnp.bfloat16
+    c, q, pool_k, pool_v, tables, _ = _read_case(G, lengths, seed=7, dtype=dtype)
+    B, Kh, D, layer = len(lengths), c.kv_heads, c.head_dim, 2
+    live_cols = -(-lengths // _READ_BS)
+    tables = np.where(
+        np.arange(_READ_NRB)[None, :] < live_cols[:, None], tables, 0
+    )
+    pool_k[:, 0], pool_v[:, 0] = 1e4, -1e4           # scratch: loud, finite
+    rows = lambda a: jnp.asarray(a).reshape(  # noqa: E731
+        _READ_LAYERS, _READ_NB, _READ_BS, Kh, D
+    )
+    if pool == "int8":
+        fold = lambda t: {  # noqa: E731
+            "q": t["q"].reshape(_READ_LAYERS, _READ_NB, _READ_BS, Kh * D),
+            "s": t["s"],
+        }
+        qk, qv = quantize_rows(rows(pool_k)), quantize_rows(rows(pool_v))
+        dense_k = dequantize_rows(qk, jnp.float32)
+        dense_v = dequantize_rows(qv, jnp.float32)
+        pool_k, pool_v = fold(qk), fold(qv)
+    else:
+        dense_k = rows(pool_k).astype(jnp.float32)
+        dense_v = rows(pool_v).astype(jnp.float32)
+        pool_k, pool_v = jnp.asarray(pool_k), jnp.asarray(pool_v)
+
+    if cols:
+        item = 1 if pool == "int8" else jnp.dtype(dtype).itemsize
+        monkeypatch.setattr(
+            llama_paged, "_XLA_READ_PASS_BYTES",
+            cols * B * _READ_BS * Kh * D * item,
+        )
+    # the layer rides in as the decode scan's traced index does
+    acc, m, l = jax.jit(
+        lambda layer: _cache_partial_xla(
+            c, q, pool_k, pool_v, layer, jnp.asarray(tables),
+            jnp.asarray(lengths), _READ_NRB, scale=scale,
+        )
+    )(jnp.int32(layer))
+
+    window = lambda dense: np.asarray(dense)[layer][tables].reshape(  # noqa: E731
+        B, _READ_WINDOW, Kh, D
+    )
+    kw, vw = window(dense_k), window(dense_v)
+    qg = np.asarray(q, np.float32).reshape(B, Kh, G, D)
+    s = np.einsum("bkgd,bwkd->bkgw", qg, kw)
+    s = s * (D ** -0.5 if scale is None else scale)
+    live = (np.arange(_READ_WINDOW)[None, :] < lengths[:, None])[:, None, None]
+    want_m = np.where(live, s, NEG_INF).max(axis=-1)
+    p = np.exp(np.where(live, s - want_m[..., None], -np.inf))
+    want_l = p.sum(axis=-1)
+    want_acc = np.einsum("bkgw,bwkd->bkgd", p, vw)
+
+    tol = _XLA_READ_TOL[pool]
+    H = Kh * G
+    np.testing.assert_allclose(
+        np.asarray(m), want_m.reshape(B, H), rtol=tol, atol=tol)
+    np.testing.assert_allclose(
+        np.asarray(l), want_l.reshape(B, H), rtol=tol, atol=tol)
+    np.testing.assert_allclose(
+        np.asarray(acc), want_acc.reshape(B, H, D), rtol=tol,
+        atol=tol * max(1.0, float(np.abs(want_acc).max())))
+    # the free slot attends nothing
+    assert (np.asarray(l)[0] == 0).all() and (np.asarray(acc)[0] == 0).all()
+    assert (np.asarray(m)[0] <= NEG_INF).all()
+
+
 def _tiny_chunk_layer_body(kernel):
     """The jaxpr of the layer scan's body in the tiny decode chunk (no
     lowering: ``kernel="pallas"`` traces on any backend), and the scan's
@@ -788,36 +880,83 @@ def test_pallas_read_takes_the_stacked_pool_in_place():
     ]
 
 
-# the XLA read's part of the layer body, primitive by primitive, as the
-# parent commit (82ff52d) traced it: the guard on the CPU for "the program
-# of an engine on paged_kernel=xla did not change" (Mistral's cell)
-_XLA_KV_READ = (
-    "broadcast_in_dim slice jit reshape slice squeeze reshape "
-    "broadcast_in_dim slice jit reshape slice squeeze reshape reshape "
-    "dot_general convert_element_type div iota broadcast_in_dim "
-    "broadcast_in_dim lt broadcast_in_dim jit reduce_max le jit "
-    "broadcast_in_dim sub exp jit reduce_sum convert_element_type "
-    "dot_general convert_element_type reshape reshape reshape reshape "
-    "dot_general convert_element_type div broadcast_in_dim jit reduce_max "
-    "le jit broadcast_in_dim sub exp broadcast_in_dim jit reduce_sum "
-    "convert_element_type dot_general convert_element_type reshape reshape "
-    "reshape max le jit le sub jit exp le sub jit exp broadcast_in_dim mul "
-    "broadcast_in_dim mul add mul mul add gt max div jit broadcast_in_dim "
-    "mul convert_element_type reshape"
-).split()
-
-
-def test_xla_read_traces_to_the_program_it_traced_to():
+def test_xla_read_takes_the_stacked_pool_in_place():
+    """The XLA read's mechanism, on the jaxpr (ROADMAP S1): the layer scan
+    hands it the layer's index, as it hands the Pallas read, and the body
+    closes over the stack; each pool is gathered once, at (layer, block),
+    with its indices promised in bounds; no value of a layer's or of the
+    stack's size is made in the body."""
     c, body = _tiny_chunk_layer_body("xla")
-    kv_read = [
-        e.primitive.name for e in body.eqns
-        if "kv_read" in str(e.source_info.name_stack)
+    stack = (c.layers, 9, 8, c.kv_heads * c.head_dim)
+    assert [v.aval.shape for v in body.invars].count(stack) == 2
+    assert [v.aval.shape for v in body.invars].count(stack[1:]) == 0
+    pools = [v for v in body.invars if v.aval.shape == stack]
+    gathers = [
+        e for e in body.eqns
+        if e.primitive.name == "gather"
+        and "kv_read" in str(e.source_info.name_stack)
     ]
-    assert kv_read == _XLA_KV_READ
-    # the scan still slices the layer's pool for it: the body's inputs
-    # hold one layer's (nb, bs, Kh·D), not the stack
-    pool_l = (9, 8, c.kv_heads * c.head_dim)
-    assert [v.aval.shape for v in body.invars].count(pool_l) == 2
+    assert len(gathers) == 2
+    assert sorted(id(e.invars[0]) for e in gathers) == sorted(map(id, pools))
+    for e in gathers:
+        assert e.params["mode"] == jax.lax.GatherScatterMode.PROMISE_IN_BOUNDS
+        assert e.params["slice_sizes"] == (1, 1) + stack[2:]
+    assert not [
+        e.primitive.name for e in body.eqns for v in e.outvars
+        if getattr(v.aval, "shape", ())[-3:] == stack[1:]
+    ]
+
+
+def _lowered_int8_chunk():
+    """StableHLO text of the tiny decode chunk on an int8 pool (text only:
+    nothing is compiled or run), with the pool's and a layer's types."""
+    from langstream_tpu.models.llama import LlamaConfig, init_llama_params
+    from langstream_tpu.models.llama_paged import llama_decode_chunk_paged
+
+    c = LlamaConfig.tiny()
+    params = init_llama_params(c, jax.random.PRNGKey(0))
+    B, nb, bs, KhD = 2, 9, 8, c.kv_heads * c.head_dim
+    pool = lambda: {  # noqa: E731
+        "q": jnp.zeros((c.layers, nb, bs, KhD), jnp.int8),
+        "s": jnp.zeros((c.layers, nb, bs, c.kv_heads), jnp.float32),
+    }
+
+    def chunk(params, tokens, lengths, active, pool_k, pool_v, tables, key):
+        return llama_decode_chunk_paged(
+            c, params, tokens, lengths, active, pool_k, pool_v, tables,
+            greedy_sample, key, 2, num_read_blocks=3, kernel="xla",
+        )
+
+    text = jax.jit(chunk).lower(
+        params, jnp.zeros((B,), jnp.int32), jnp.ones((B,), jnp.int32),
+        jnp.ones((B,), bool), pool(), pool(), jnp.zeros((B, 4), jnp.int32),
+        jax.random.PRNGKey(0),
+    ).as_text()
+    return text, f"{c.layers}x{nb}x{bs}x{KhD}xi8", f"{nb}x{bs}x{KhD}xi8"
+
+
+def test_int8_chunk_lowers_without_a_fill_or_a_layer_slice():
+    """The lowered text of the int8 decode chunk (CPU, text only): the
+    pool's data is gathered from the stack, twice a layer body (K and V);
+    no ``select`` is fed by such a gather (``jnp.take``'s default
+    ``mode="fill"`` put one over the whole window), and no ``dynamic_slice``
+    makes a whole ``(nb, bs, Kh*D)`` layer beside the pool (the scan over
+    the pools did, for K and for V, every layer of every step)."""
+    import re
+
+    text, stack, layer = _lowered_int8_chunk()
+    gathered = re.findall(
+        rf'(%\S+) = "stablehlo\.gather"\(%\S+, %\S+\).*\(tensor<{stack}>, ', text
+    )
+    assert len(gathered) == 2
+    selects = [ln for ln in text.splitlines() if "stablehlo.select" in ln]
+    assert selects                      # the masks on the scores are there
+    for name in gathered:
+        assert not [ln for ln in selects if re.search(rf"{name}\b", ln)]
+    assert not [
+        ln for ln in text.splitlines()
+        if "dynamic_slice" in ln and f"x{layer}>" in ln
+    ]
 
 
 # ---------------------------------------------------------------------------

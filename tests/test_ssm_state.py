@@ -287,3 +287,52 @@ def test_the_selfcheck_s_latent_rows_hold_both_kernels(monkeypatch):
         LatentConfig.tiny(), block_size=8, read_blocks=6, batch=4,
         flash_seq=48, interpret=True)
     assert not bad[0]["ok"] and bad[1]["ok"]
+
+
+def test_the_tpu_compiler_moves_the_xla_read_s_window_once(
+        one_chip, no_compile_cache):
+    """The XLA gather read (``llama_paged._cache_partial_xla``) at
+    mistral-7b-v0.3's posture: 64 slots, 32/8 heads of 128, the int8 pool of
+    1,228 blocks of 64 rows stacked over the layers, the whole-slot window,
+    called from a scan over the layers as the decode chunk calls it. In the
+    compiled text the only int8 values of a pass's size are the eight
+    gathers (four passes of 512 rows, K and V), each assigned to VMEM
+    (``S(1)``): the product reads them there. No int8 value is copied (the
+    products contract the rows as they lie), and nothing of the window's or
+    a layer's size exists: no fill's select, no slice of the layer (ROADMAP
+    S1)."""
+    import re
+
+    from langstream_tpu.models.llama import LlamaConfig
+    from langstream_tpu.models.llama_paged import _cache_partial_xla
+
+    B, H, Kh, D, L, nb, bs, cols = 64, 32, 8, 128, 4, 1228, 64, 32
+    c = LlamaConfig(heads=H, kv_heads=Kh, head_dim=D, layers=L)
+    on = lambda shape, dtype: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dtype, sharding=one_chip)
+    pool = lambda: {"q": on((L, nb, bs, Kh * D), jnp.int8),  # noqa: E731
+                    "s": on((L, nb, bs, Kh), jnp.float32)}
+
+    def layers(q, pool_k, pool_v, tables, lengths):
+        def one(total, layer):
+            acc, m, l = _cache_partial_xla(
+                c, q, pool_k, pool_v, layer, tables, lengths, cols)
+            return total + acc.sum() + l.sum() + m.max(), None
+
+        return jax.lax.scan(one, 0.0, jnp.arange(L))[0]
+
+    text = jax.jit(layers).lower(
+        on((B, H, D), jnp.bfloat16), pool(), pool(),
+        on((B, cols), jnp.int32), on((B,), jnp.int32),
+    ).compile().as_text()
+    gathers = [
+        ln for ln in text.splitlines()
+        if re.match(rf"\s+%\S+ = s8\[{8 * B},{bs},{Kh * D}\]\{{[^}}]*\}} fusion\(", ln)
+        and "kind=kCustom" in ln
+    ]
+    assert len(gathers) == 8 and all("S(1)}" in ln for ln in gathers)
+    # what the form before left between the pool and the products
+    assert not re.search(r"= s8\[[0-9,]*\]\{[^}]*\} copy\(", text)
+    for gone in (f"s8[{B * cols},{bs},{Kh * D}]", f"s8[{nb},{bs},{Kh * D}]",
+                 f"s8[1,{B},{cols},{bs},{Kh * D}]"):
+        assert f"= {gone}" not in text and f"({gone}" not in text
