@@ -1,13 +1,13 @@
-"""Hybrid decoder: Mamba-2 mixers, grouped-query attention and routed
-experts in one stack (the ``nemotron_h`` and ``granitemoehybrid`` families),
-over the paged pool.
+"""Hybrid decoder: Mamba-2 or gated delta-rule mixers, grouped-query attention
+and routed experts in one stack (the ``nemotron_h``, ``granitemoehybrid`` and
+``solar_open2`` families), over the paged pool.
 
 Every sub-layer is ONE mixer with its own pre-norm and residual,
 ``x <- x + m * Mixer_kind(RMSNorm(x))``, its kind from the pattern (``M``
-Mamba-2, ``*`` attention, ``E`` mixture of experts; ``m`` the family's
-``residual_multiplier``, 1 where it has none). The pattern is a run of
-blocks with at least one mixer and then the experts, ``ME``, ``M*E`` or
-``*E``, and that block is what the programs scan. ``nemotron_h`` publishes
+Mamba-2, ``K`` gated delta rule, ``*`` attention, ``E`` mixture of experts;
+``m`` the family's ``residual_multiplier``, 1 where it has none). The pattern
+is a run of blocks with at least one mixer and then the experts, ``ME``,
+``M*E``, ``KE`` or ``*E``, and that block is what the programs scan. ``nemotron_h`` publishes
 the pattern itself (blocks ``M [*] E``: attention is an extra, 23 blocks and
 6 attention layers at the published depth); a ``granitemoehybrid`` layer is a
 mixer and then the experts, so its ``layer_types`` read ``ME`` for "mamba"
@@ -15,7 +15,10 @@ and ``*E`` for "attention" (attention takes the Mamba-2 mixer's place). The
 expert weights are stacked by block, the Mamba-2 and the attention weights
 by their own layers; where every block has its Mamba-2 mixer the scan slices
 its weights with the block, otherwise a block reaches them, as it does the
-attention's, through its index and only when it has one.
+attention's, through its index and only when it has one. A ``solar_open2``
+layer is a delta-rule mixer or a gated attention and then the experts
+(``KE`` or ``*E``, three to one); its delta-rule weights and state are
+stacked by their own layers and reached by index, as Mamba-2's are there.
 
 What else differs between the two families are facts of the checkpoint and
 fields of :class:`HybridConfig`, branched on in Python while a program is
@@ -31,7 +34,11 @@ Two kinds of per-request state live side by side:
   float32 state ``(heads, head_dim, state)`` and the last ``kernel - 1``
   inputs of the convolution. It is not paged: prefill writes a slot's rows
   whole, a decode step advances them in place. A block without a Mamba-2
-  mixer holds no rows of it.
+  mixer holds no rows of it. The delta-rule layers keep a second stacked
+  state under keys of their own, ``(heads, value dim, key dim)`` float32 a
+  layer a slot (the transpose of the paper's ``S``, so that what a decode
+  step spreads along lanes are the key-indexed vectors it is given as rows)
+  and the tail of their three convolutions as one.
 
 The expert layer serves one chip's share of an expert-parallel deployment
 (``experts_held`` of ``experts`` from ``expert_first``): the router keeps
@@ -52,6 +59,7 @@ from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
+import jax.scipy.linalg
 
 from langstream_tpu.models.llama import _flash_mode, _rms_norm
 from langstream_tpu.models.llama_paged import (
@@ -71,10 +79,14 @@ from langstream_tpu.ops.paged_attention import (
     merge_partial_attention,
     paged_attention_partial,
 )
+from langstream_tpu.ops.delta_state import delta_state_step
 from langstream_tpu.ops.ssm_state import ssm_state_step
 
 NEMOTRON3_NANO_PATTERN = "MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*EMEMEMEME"
-_BLOCK = r"(?:M\*?|\*)E"     # at least one mixer, then the experts
+_BLOCK = r"(?:[MK]\*?|\*)E"  # at least one mixer, then the experts
+#: the stacked states a pattern may have (:func:`init_hybrid_state`), in the
+#: order the programs carry them
+_STATE_KINDS = ("ssm", "conv", "delta", "dconv")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -118,12 +130,18 @@ class HybridConfig:
     logits_scaling: float = 1.0       # the logits are DIVIDED by it
     attention_scale: float | None = None   # None: 1/sqrt(head_dim)
     tied_head: bool = False           # the head is the embedding
+    attn_gate: bool = False           # y = W_o [o * sigmoid(W_gate x)]
+    # the gated delta-rule mixer (pattern ``K``; none unless the pattern has one)
+    delta_heads: int = 0
+    delta_head_dim: int = 128         # keys and values alike
+    delta_gate_rank: int = 128        # the decay's and the output gate's
+    delta_chunk: int = 64             # tokens of one chunk of the prefill
 
     def __post_init__(self):
         if not re.fullmatch(f"(?:{_BLOCK})+", self.pattern):
             raise ValueError(
-                f"pattern {self.pattern!r} is not a run of blocks ME, M*E "
-                f"or *E (at least one mixer, then the experts)"
+                f"pattern {self.pattern!r} is not a run of blocks ME, M*E, "
+                f"KE or *E (at least one mixer, then the experts)"
             )
         if self.router not in ("sigmoid", "softmax_topk"):
             raise ValueError(f"unknown router rule {self.router!r}")
@@ -175,6 +193,37 @@ class HybridConfig:
         )
 
     @classmethod
+    def solar_open2_ep8(cls, max_seq_len: int = 2048) -> "HybridConfig":
+        """upstage/Solar-Open2-250B as one chip of the eight that share each
+        layer, one pipeline stage of twelve: layers 0-3 of 48 (one whole
+        period: gated attention, then three gated delta-rule layers, each
+        followed by its experts), 40 of 320 experts and 24,576 of 196,608
+        rows of the embedding and of the untied head held here, mixers, the
+        shared expert and the router whole."""
+        return cls(
+            vocab_size=24576, hidden=4096, layers=8, heads=64, kv_heads=8,
+            head_dim=128, intermediate=1280, pattern="*E" + "KE" * 3,
+            delta_heads=64, delta_head_dim=128, delta_gate_rank=128,
+            experts=320, experts_per_token=8, shared_intermediate=1280,
+            experts_held=40, max_seq_len=max_seq_len, **_SOLAR_FACTS,
+        )
+
+    @classmethod
+    def solar_tiny(cls, max_seq_len: int = 128,
+                   expert_first: int = 0) -> "HybridConfig":
+        """Test size of the ``solar_open2`` layer: one period, half of the
+        experts held."""
+        return cls(
+            vocab_size=384, hidden=64, layers=8, heads=4, kv_heads=2,
+            head_dim=16, intermediate=32, pattern="*E" + "KE" * 3,
+            delta_heads=4, delta_head_dim=16, delta_gate_rank=8,
+            delta_chunk=16, experts=8, experts_per_token=3,
+            shared_intermediate=32, experts_held=4,
+            expert_first=expert_first, max_seq_len=max_seq_len,
+            **_SOLAR_FACTS,
+        )
+
+    @classmethod
     def tiny(cls, max_seq_len: int = 128, expert_first: int = 0) -> "HybridConfig":
         """Test size: the same family, at least two of each kind."""
         return cls(
@@ -195,6 +244,19 @@ class HybridConfig:
     def mamba_blocks(self) -> tuple[bool, ...]:
         """One entry a block: whether it has the Mamba-2 mixer."""
         return tuple("M" in b for b in re.findall(_BLOCK, self.pattern))
+
+    @property
+    def delta_blocks(self) -> tuple[bool, ...]:
+        """One entry a block: whether it has the delta-rule mixer."""
+        return tuple("K" in b for b in re.findall(_BLOCK, self.pattern))
+
+    @property
+    def delta_layers(self) -> int:
+        return self.pattern.count("K")
+
+    @property
+    def delta_inner(self) -> int:
+        return self.delta_heads * self.delta_head_dim
 
     @property
     def attn_layers(self) -> int:
@@ -220,13 +282,17 @@ class HybridConfig:
     @property
     def state_bytes_per_slot(self) -> int:
         """Recurrent state and convolution tail of one slot, all Mamba-2
-        layers."""
+        and delta-rule layers."""
         n = self.mamba_layers
         ssm = (self.ssm_heads * self.ssm_head_dim * self.ssm_state
                * jnp.dtype(self.state_dtype).itemsize)
         conv = ((self.conv_kernel - 1) * self.conv_dim
                 * jnp.dtype(self.dtype).itemsize)
-        return n * (ssm + conv)
+        delta = (self.delta_heads * self.delta_head_dim ** 2
+                 * jnp.dtype(self.state_dtype).itemsize)
+        dconv = ((self.conv_kernel - 1) * 3 * self.delta_inner
+                 * jnp.dtype(self.dtype).itemsize)
+        return n * (ssm + conv) + self.delta_layers * (delta + dconv)
 
 
 #: what a ``granitemoehybrid`` checkpoint fixes beside its sizes
@@ -238,6 +304,15 @@ _GRANITE_FACTS = dict(
     router="softmax_topk", expert_act="silu_gated", embedding_multiplier=12.0,
     residual_multiplier=0.22, logits_scaling=16.0,
     attention_scale=0.0078125, tied_head=True,
+)
+
+#: what a ``solar_open2`` checkpoint fixes beside its sizes (Solar-Open2-250B's
+#: config.json: use_gqa_gate, norm_topk_prob, routed_scaling_factor 1,
+#: tie_word_embeddings false; sigmoid scores renormalised over the chosen,
+#: gated experts)
+_SOLAR_FACTS = dict(
+    router="sigmoid", expert_act="silu_gated", routed_scale=1.0,
+    attn_gate=True,
 )
 
 # ---------------------------------------------------------------------------
@@ -304,25 +379,26 @@ def init_hybrid_params(config: HybridConfig, key: jax.Array | None = None) -> di
     }
     if not c.tied_head:
         params["lm_head"] = normal((H, c.vocab_size), H)
-    params["mamba"] = {     # one row a Mamba-2 layer
-        "norm": jnp.ones((nM, H), c.dtype),
-        # [z | xBC | dt] = W_in u, the published fused projection as
-        # its three column blocks: the fused width (10304 at nemotron_h's
-        # published sizes) is no multiple of the 128-lane tile, and
-        # the runtime then keeps the array transposed and the program
-        # copies all of it back before every chunk
-        "w_z": normal((nM, H, c.d_inner), H),
-        "w_xbc": normal((nM, H, c.conv_dim), H),
-        "w_dt": normal((nM, H, c.ssm_heads), H),
-        "conv_w": normal((nM, c.conv_dim, c.conv_kernel), c.conv_kernel),
-        "conv_b": normal((nM, c.conv_dim), 25.0),
-        # softplus(dt_bias) is log-uniform in [0.001, 0.1]; A in [1, 16]
-        "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
-        "A_log": jnp.log(uniform((nM, c.ssm_heads), 1.0, 16.0)),
-        "D": uniform((nM, c.ssm_heads), 0.5, 1.5),
-        "gate_norm": jnp.ones((nM, c.d_inner), c.dtype),
-        "w_out": normal((nM, c.d_inner, H), c.d_inner),
-    }
+    if nM:
+        params["mamba"] = {   # one row a Mamba-2 layer
+            "norm": jnp.ones((nM, H), c.dtype),
+            # [z | xBC | dt] = W_in u, the published fused projection as
+            # its three column blocks: the fused width (10304 at nemotron_h's
+            # published sizes) is no multiple of the 128-lane tile, and
+            # the runtime then keeps the array transposed and the program
+            # copies all of it back before every chunk
+            "w_z": normal((nM, H, c.d_inner), H),
+            "w_xbc": normal((nM, H, c.conv_dim), H),
+            "w_dt": normal((nM, H, c.ssm_heads), H),
+            "conv_w": normal((nM, c.conv_dim, c.conv_kernel), c.conv_kernel),
+            "conv_b": normal((nM, c.conv_dim), 25.0),
+            # softplus(dt_bias) is log-uniform in [0.001, 0.1]; A in [1, 16]
+            "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+            "A_log": jnp.log(uniform((nM, c.ssm_heads), 1.0, 16.0)),
+            "D": uniform((nM, c.ssm_heads), 0.5, 1.5),
+            "gate_norm": jnp.ones((nM, c.d_inner), c.dtype),
+            "w_out": normal((nM, c.d_inner, H), c.d_inner),
+        }
     params["attn"] = {      # one row an attention layer
         "norm": jnp.ones((nA, H), c.dtype),
         "wq": normal((nA, H, c.heads * c.head_dim), qk_fan_in),
@@ -349,6 +425,33 @@ def init_hybrid_params(config: HybridConfig, key: jax.Array | None = None) -> di
     moe["w_down"] = experts((I, H), I)
     moe["ws_up"] = normal((n, H, gated * Is), H)
     moe["ws_down"] = normal((n, Is, H), Is)
+    # drawn last, and only where the pattern has them: the draws above keep
+    # the keys they had before these kinds of layer existed
+    if c.attn_gate:
+        params["attn"]["wg"] = normal((nA, H, c.heads * c.head_dim), H)
+    nK, Kd, r = c.delta_layers, c.delta_inner, c.delta_gate_rank
+    if nK:
+        dt = jnp.exp(uniform((nK, Kd), math.log(0.001), math.log(0.1)))
+        params["delta"] = {   # one row a delta-rule layer
+            "norm": jnp.ones((nK, H), c.dtype),
+            # [q | k | v] = W x: the three projections side by side, as the
+            # three depthwise convolutions are one over their channels
+            "w_qkv": normal((nK, H, 3 * Kd), H),
+            "conv_w": normal((nK, 3 * Kd, c.conv_kernel), c.conv_kernel),
+            # the decay's gate, low rank, one value a key channel:
+            # g = -exp(A_log) softplus(W_up W_down x + dt_bias);
+            # softplus(dt_bias) is log-uniform in [0.001, 0.1], A in [1, 16]
+            "w_f_down": normal((nK, H, r), H),
+            "w_f_up": normal((nK, r, Kd), r),
+            "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+            "A_log": jnp.log(uniform((nK, c.delta_heads), 1.0, 16.0)),
+            "w_beta": normal((nK, H, c.delta_heads), H),
+            # the output's gate, low rank too
+            "w_g_down": normal((nK, H, r), H),
+            "w_g_up": normal((nK, r, Kd), r),
+            "out_norm": uniform((nK, c.delta_head_dim), 0.5, 1.5).astype(c.dtype),
+            "w_out": normal((nK, Kd, H), Kd),
+        }
     return params
 
 
@@ -363,15 +466,26 @@ def init_hybrid_pool(config: HybridConfig, layout) -> tuple[jax.Array, jax.Array
 
 def init_hybrid_state(config: HybridConfig, slots: int) -> dict:
     """``{"ssm": (Mamba-2 layers, slots, heads, head_dim, state), "conv":
-    (Mamba-2 layers, slots, kernel - 1, conv_dim)}``, zeros."""
+    (Mamba-2 layers, slots, kernel - 1, conv_dim)}`` where the pattern has
+    Mamba-2 layers and ``{"delta": (delta-rule layers, slots, heads, value
+    dim, key dim), "dconv": (delta-rule layers, slots, kernel - 1, 3 x
+    heads x head_dim)}`` where it has delta-rule layers, zeros."""
     c = config
-    n = c.mamba_layers
-    return {
-        "ssm": jnp.zeros(
+    n, nK = c.mamba_layers, c.delta_layers
+    state = {}
+    if n or not nK:     # (a pattern of attention alone keeps its empty rows)
+        state["ssm"] = jnp.zeros(
             (n, slots, c.ssm_heads, c.ssm_head_dim, c.ssm_state), c.state_dtype
-        ),
-        "conv": jnp.zeros((n, slots, c.conv_kernel - 1, c.conv_dim), c.dtype),
-    }
+        )
+        state["conv"] = jnp.zeros(
+            (n, slots, c.conv_kernel - 1, c.conv_dim), c.dtype)
+    if nK:
+        state["delta"] = jnp.zeros(
+            (nK, slots, c.delta_heads, c.delta_head_dim, c.delta_head_dim),
+            c.state_dtype)
+        state["dconv"] = jnp.zeros(
+            (nK, slots, c.conv_kernel - 1, 3 * c.delta_inner), c.dtype)
+    return state
 
 
 # ---------------------------------------------------------------------------
@@ -537,6 +651,205 @@ def mamba_step(c: HybridConfig, lp: dict, u: jax.Array, ssm: jax.Array,
 
 
 # ---------------------------------------------------------------------------
+# gated delta rule (a decay a key channel)
+# ---------------------------------------------------------------------------
+
+#: rows of a sub-block of a chunk: inside one the relative decays are formed
+#: pair by pair, between two as a product of two factors that are both <= 1
+DELTA_SUB = 16
+
+
+def _delta_gates(c: HybridConfig, lp: dict, u: jax.Array):
+    """``(g (..., heads, dk) float32 <= 0, beta (..., heads) float32 in (0,
+    2))`` of normed rows ``u``: the log of the decay a key channel and the
+    delta rule's step (doubled: ``kda_allow_neg_eigval``)."""
+    f32 = jnp.float32
+    lead = u.shape[:-1]
+    f = ((u @ lp["w_f_down"]) @ lp["w_f_up"]).astype(f32) + lp["dt_bias"]
+    g = -jnp.exp(lp["A_log"])[:, None] * jax.nn.softplus(f).reshape(
+        lead + (c.delta_heads, c.delta_head_dim))
+    beta = 2.0 * jax.nn.sigmoid((u @ lp["w_beta"]).astype(f32))
+    return g, beta
+
+
+def _delta_qkv(c: HybridConfig, conv: jax.Array):
+    """The convolutions' output ``(..., 3 x inner)`` float32 to ``q`` (unit
+    length a head, scaled by ``dk ** -0.5``), ``k`` (unit length) and ``v``,
+    each ``(..., heads, dk)`` float32."""
+    lead = conv.shape[:-1]
+    x = jax.nn.silu(conv).astype(c.dtype).astype(jnp.float32).reshape(
+        lead + (3, c.delta_heads, c.delta_head_dim))
+    q, k, v = x[..., 0, :, :], x[..., 1, :, :], x[..., 2, :, :]
+    unit = lambda t: t * jax.lax.rsqrt(  # noqa: E731
+        jnp.sum(t * t, axis=-1, keepdims=True) + 1e-6)
+    return unit(q) * c.delta_head_dim ** -0.5, unit(k), v
+
+
+def _delta_out(c: HybridConfig, lp: dict, o: jax.Array, u: jax.Array):
+    """``W_o [RMSNorm_head(o) * w * sigmoid(W_up W_down u)]``; ``o (...,
+    heads, dv)`` float32."""
+    lead = o.shape[:-2]
+    o = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True) + c.norm_eps)
+    o = (o.astype(c.dtype) * lp["out_norm"]).reshape(lead + (c.delta_inner,))
+    gate = jax.nn.sigmoid(
+        ((u @ lp["w_g_down"]) @ lp["w_g_up"]).astype(jnp.float32))
+    return (o * gate.astype(c.dtype)) @ lp["w_out"]
+
+
+def delta_chunked(
+    q: jax.Array,      # (B, P, heads, dk) float32
+    k: jax.Array,      # (B, P, heads, dk) float32
+    v: jax.Array,      # (B, P, heads, dv) float32
+    g: jax.Array,      # (B, P, heads, dk) float32 <= 0; 0 where a row is padding
+    beta: jax.Array,   # (B, P, heads) float32; 0 where a row is padding
+    chunk: int,
+) -> tuple[jax.Array, jax.Array]:
+    """The gated delta rule ``S_t = (I - b_t k_t k_t^T) Diag(exp g_t) S_{t-1}
+    + b_t k_t v_t^T``, ``o_t = S_t^T q_t`` over a whole prompt in chunks.
+    Returns ``(o (B, P, heads, dv) float32, S_P^T (B, heads, dv, dk)
+    float32)``. A position with ``g = 0`` and ``beta = 0`` leaves the state
+    as it is, so a right-padded row ends with the state after its last token.
+
+    With ``G_i`` the chunk's running sum of ``g`` and ``S_0`` the state it
+    starts from, every token's write is ``k_i w_i^T`` with ``W = U - W_k
+    S_0``, where ``[U | W_k] = (I + A)^-1 [b v | b k exp(G)]`` and ``A_ij =
+    b_i sum_d k_i k_j exp(G_i - G_j)`` for ``j < i`` (the UT transform: one
+    unit lower-triangular system a chunk a head); then ``O = (q exp(G)) S_0
+    + A' W`` with ``A'_ij = sum_d q_i k_j exp(G_i - G_j)`` for ``j <= i``,
+    and the state goes on as ``Diag(exp G_L) S_0 + (k exp(G_L - G))^T W``.
+
+    A decay a CHANNEL means ``exp(G_i - G_j)`` does not leave the sum over
+    channels as a quotient of two scalars, and the usual ``(k_i exp(G_i))
+    (k_j exp(-G_j))`` overflows under strong decay (``-G`` of 88 and the
+    float32 is infinite). Every exponent formed here is <= 0: between two
+    sub-blocks of :data:`DELTA_SUB` rows the decay is split at the later
+    one's first row ``r`` (``j < r <= i``: ``G_i - G_r`` and ``G_r - G_j``
+    both), inside one it is formed pair by pair."""
+    f32 = jnp.float32
+    B, Pn, H, D = k.shape
+    L = min(chunk, Pn)
+    nc = Pn // L
+    C = min(DELTA_SUB, L)
+    ns = L // C
+
+    def chunks(t):          # (B, P, H, ...) -> (nc, B, H, L, ...)
+        t = t.reshape((B, nc, L) + t.shape[2:])
+        return jnp.moveaxis(jnp.moveaxis(t, 3, 2), 1, 0)
+
+    row = jnp.arange(L)
+    lower = row[:, None] >= row[None, :]
+    in_sub = jnp.arange(C)[:, None] >= jnp.arange(C)[None, :]
+
+    def one(S, inp):        # S (B, H, dv, dk)
+        q, k, v, g, b = inp                                  # (B, H, L, .)
+        G = jnp.cumsum(g, axis=2)
+        Gs = G.reshape(B, H, ns, C, D)
+        first = Gs[:, :, :, :1]                              # (B,H,ns,1,D)
+        into = jnp.exp(Gs - first).reshape(B, H, L, D)       # exp(G_i - G_r)
+        kd, qd = k * into, q * into
+        kk, qk = [], []
+        for I in range(ns):
+            rows = slice(I * C, (I + 1) * C)
+            # earlier sub-blocks: k_j exp(G_r - G_j), r this one's first row
+            kc = k[:, :, : I * C] * jnp.exp(first[:, :, I] - G[:, :, : I * C])
+            # this sub-block: pair by pair, i >= j only
+            Gi = Gs[:, :, I]
+            pair = Gi[:, :, :, None] - Gi[:, :, None]        # (B,H,C,C,D)
+            pair = jnp.where(in_sub[..., None], pair, 0.0)
+            w = jnp.where(in_sub[..., None], jnp.exp(pair), 0.0)
+            w = w * k[:, :, rows][:, :, None]                # x k_j
+            pad = jnp.zeros((B, H, C, L - (I + 1) * C), f32)
+            for own, mine, rest in ((k, kd, kk), (q, qd, qk)):
+                rest.append(jnp.concatenate([
+                    jnp.einsum("bhid,bhjd->bhij", mine[:, :, rows], kc),
+                    jnp.sum(own[:, :, rows][:, :, :, None] * w, axis=-1),
+                    pad], axis=-1))
+        kk = jnp.concatenate(kk, axis=2)                     # (B, H, L, L)
+        qk = jnp.where(lower, jnp.concatenate(qk, axis=2), 0.0)
+        A = jnp.where(row[:, None] > row[None, :], kk, 0.0) * b[..., None]
+        rhs = jnp.concatenate(
+            [v * b[..., None], k * jnp.exp(G) * b[..., None]], axis=-1)
+        sol = jax.scipy.linalg.solve_triangular(
+            A + jnp.eye(L, dtype=f32), rhs, lower=True, unit_diagonal=True)
+        U, Wk = sol[..., : v.shape[-1]], sol[..., v.shape[-1]:]
+        W = U - jnp.einsum("bhld,bhvd->bhlv", Wk, S)
+        o = (jnp.einsum("bhld,bhvd->bhlv", q * jnp.exp(G), S)
+             + jnp.einsum("bhij,bhjv->bhiv", qk, W))
+        last = G[:, :, -1:]                                  # (B, H, 1, D)
+        S = S * jnp.exp(last) + jnp.einsum(
+            "bhlv,bhld->bhvd", W, k * jnp.exp(last - G))
+        return S, o
+
+    S, o = jax.lax.scan(
+        one, jnp.zeros((B, H, v.shape[-1], D), f32),
+        tuple(chunks(t) for t in (q, k, v, g, beta)))
+    # (nc, B, H, L, dv) -> (B, P, H, dv)
+    o = jnp.moveaxis(o, 0, 1).swapaxes(2, 3).reshape(B, Pn, H, v.shape[-1])
+    return o, S
+
+
+def delta_prefill(c: HybridConfig, lp: dict, u: jax.Array, lengths: jax.Array):
+    """The gated delta-rule mixer over right-padded prompts ``u (B, P, H)``
+    (already normed). Returns ``(out (B, P, H), state (B, heads, dv, dk),
+    conv tail (B, kernel - 1, 3 x inner))``, state and tail as they stand
+    after each row's last real token."""
+    B, Pn, _ = u.shape
+    kk = c.conv_kernel
+    with jax.named_scope("delta_in"):
+        qkv = u @ lp["w_qkv"]
+        g, beta = _delta_gates(c, lp, u)
+    with jax.named_scope("delta_conv"):
+        padded = jnp.pad(qkv, ((0, 0), (kk - 1, 0), (0, 0)))
+        conv = sum(
+            padded[:, i : i + Pn].astype(jnp.float32)
+            * lp["conv_w"][:, i].astype(jnp.float32)
+            for i in range(kk)
+        )
+        q, k, v = _delta_qkv(c, conv)
+        tail = jnp.take_along_axis(
+            padded, (lengths[:, None] + jnp.arange(kk - 1)[None, :])[..., None],
+            axis=1,
+        )
+    with jax.named_scope("delta_chunk"):
+        real = jnp.arange(Pn)[None, :] < lengths[:, None]
+        g = jnp.where(real[..., None, None], g, 0.0)
+        beta = jnp.where(real[..., None], beta, 0.0)
+        o, state = delta_chunked(q, k, v, g, beta, c.delta_chunk)
+    with jax.named_scope("delta_out"):
+        out = _delta_out(c, lp, o, u)
+    return out, state.astype(c.state_dtype), tail
+
+
+def delta_step(c: HybridConfig, lp: dict, u: jax.Array, delta: jax.Array,
+               dconv: jax.Array, i: jax.Array, active: jax.Array,
+               kernel: str = "xla"):
+    """One token a slot through delta-rule layer ``i``: ``u (B, H)`` normed,
+    ``delta (layers, B, heads, dv, dk)`` and ``dconv (layers, B, kernel - 1,
+    3 x inner)`` the stacked state of every layer, of which this one's rows
+    are read and replaced in place (:func:`mamba_step`'s contract, and its
+    ``kernel``: :func:`langstream_tpu.ops.delta_state.delta_state_step`)."""
+    with jax.named_scope("delta_in"):
+        qkv = u @ lp["w_qkv"]
+        g, beta = _delta_gates(c, lp, u)
+    with jax.named_scope("delta_conv"):
+        tail = jax.lax.dynamic_index_in_dim(dconv, i, keepdims=False)
+        window = jnp.concatenate([tail, qkv[:, None]], axis=1)   # (B, k, C)
+        conv = jnp.einsum(
+            "bkc,ck->bc", window.astype(jnp.float32),
+            lp["conv_w"].astype(jnp.float32),
+        )
+        q, k, v = _delta_qkv(c, conv)
+        dconv = jax.lax.dynamic_update_index_in_dim(
+            dconv, jnp.where(active[:, None, None], window[:, 1:], tail), i, 0)
+    with jax.named_scope("delta_state"):
+        o, delta = delta_state_step(
+            delta, i, jnp.exp(g), k, q, v, beta, active, kernel=kernel)
+    with jax.named_scope("delta_out"):
+        out = _delta_out(c, lp, o, u)
+    return out, delta, dconv
+
+
+# ---------------------------------------------------------------------------
 # experts
 # ---------------------------------------------------------------------------
 
@@ -614,18 +927,24 @@ def _block_xs(c: HybridConfig, params: dict, experts_in_xs: bool = True):
     with the block; otherwise ``(has the mixer, its Mamba-2 layer)`` and the
     block reaches the weights by that index (:func:`_mamba_in_block`); a
     block without the mixer points past the last layer, where a write of
-    state rows is dropped."""
+    state rows is dropped. A pattern with delta-rule layers has a sixth
+    entry, ``(has the delta-rule mixer, its delta-rule layer)`` likewise."""
     has = jnp.asarray(c.blocks)
     # a block without attention points at the spare row past the last layer
     idx = jnp.where(has, jnp.cumsum(has) - 1, c.attn_layers).astype(jnp.int32)
     moe = params["moe"] if experts_in_xs else {
         k: v for k, v in params["moe"].items() if k not in ("w_up", "w_down")}
-    mamba = params["mamba"]
-    if not all(c.mamba_blocks):
+    mamba = params.get("mamba")
+    if c.mamba_layers and not all(c.mamba_blocks):
         has_m = jnp.asarray(c.mamba_blocks)
         mamba = (has_m, jnp.where(
             has_m, jnp.cumsum(has_m) - 1, c.mamba_layers).astype(jnp.int32))
-    return (mamba, moe, has, idx, jnp.arange(len(c.blocks), dtype=jnp.int32))
+    xs = (mamba, moe, has, idx, jnp.arange(len(c.blocks), dtype=jnp.int32))
+    if c.delta_layers:
+        has_k = jnp.asarray(c.delta_blocks)
+        xs += ((has_k, jnp.where(
+            has_k, jnp.cumsum(has_k) - 1, c.delta_layers).astype(jnp.int32)),)
+    return xs
 
 
 def _layer_at(stack: dict, i: jax.Array) -> dict:
@@ -711,8 +1030,13 @@ def hybrid_prefill_paged(
                 s = jnp.where(mask[:, None, None], s, NEG_INF)
                 out = jnp.einsum(
                     "bkgqs,bskd->bqkgd", jax.nn.softmax(s, -1).astype(x.dtype), v)
+        out = out.reshape(B, Pn, c.heads * c.head_dim)
+        if c.attn_gate:
+            with jax.named_scope("attn_gate"):
+                out = out * jax.nn.sigmoid(
+                    jnp.einsum("bph,hd->bpd", h, ap["wg"]).astype(jnp.float32)
+                ).astype(out.dtype)
         with jax.named_scope("attn_out"):
-            out = out.reshape(B, Pn, c.heads * c.head_dim)
             x = _residual(c, x, jnp.einsum("bpd,dh->bph", out, ap["wo"]))
         return x, k.reshape(B, Pn, KhD), v.reshape(B, Pn, KhD)
 
@@ -730,20 +1054,41 @@ def hybrid_prefill_paged(
                 jnp.zeros((B,) + state["ssm"].shape[2:], state["ssm"].dtype),
                 jnp.zeros((B,) + state["conv"].shape[2:], state["conv"].dtype))
 
+    def delta(x, kd):
+        lp = _layer_at(params["delta"], kd)
+        return delta_prefill(c, lp, _rms_norm(x, lp["norm"], c.norm_eps), lengths)
+
+    def no_delta(x, kd):
+        return (jnp.zeros_like(x),
+                jnp.zeros((B,) + state["delta"].shape[2:], state["delta"].dtype),
+                jnp.zeros((B,) + state["dconv"].shape[2:], state["dconv"].dtype))
+
+    kinds = tuple(kind for kind in _STATE_KINDS if kind in state)
+
     def block(carry, xs):
-        x, ks, vs, ssm_all, conv_all = carry
-        mp, ep, has, a, i = xs
-        m, (out, ssm, tail) = _mamba_in_block(
-            c, params, mp, i, mamba, no_mamba, x)
-        with jax.named_scope("ssm_state_write"):
-            # this layer's rows of the batch's slots, in the carry: the
-            # whole state is never stacked beside itself, and stays outside
-            # the conditional a block without the mixer needs (a whole
-            # state through its branches is copied whole; such a block's
-            # layer lies past the last, and its rows are dropped)
-            ssm_all = ssm_all.at[m, slot_ids].set(ssm, mode="drop")
-            conv_all = conv_all.at[m, slot_ids].set(tail, mode="drop")
-        x = _residual(c, x, out)
+        x, ks, vs = carry[:3]
+        rec = dict(zip(kinds, carry[3:]))
+        mp, ep, has, a, i = xs[:5]
+        if c.mamba_layers:
+            m, (out, ssm, tail) = _mamba_in_block(
+                c, params, mp, i, mamba, no_mamba, x)
+            with jax.named_scope("ssm_state_write"):
+                # this layer's rows of the batch's slots, in the carry: the
+                # whole state is never stacked beside itself, and stays
+                # outside the conditional a block without the mixer needs (a
+                # whole state through its branches is copied whole; such a
+                # block's layer lies past the last, and its rows are dropped)
+                rec["ssm"] = rec["ssm"].at[m, slot_ids].set(ssm, mode="drop")
+                rec["conv"] = rec["conv"].at[m, slot_ids].set(tail, mode="drop")
+            x = _residual(c, x, out)
+        if c.delta_layers:
+            has_k, kd = xs[5]
+            out, S, tail = jax.lax.cond(has_k, delta, no_delta, x, kd)
+            with jax.named_scope("delta_state_write"):
+                # outside the conditional, as above
+                rec["delta"] = rec["delta"].at[kd, slot_ids].set(S, mode="drop")
+                rec["dconv"] = rec["dconv"].at[kd, slot_ids].set(tail, mode="drop")
+            x = _residual(c, x, out)
         x, k, v = jax.lax.cond(has, attention, no_attention, x, a)
         ks = jax.lax.dynamic_update_index_in_dim(ks, k, a, 0)
         vs = jax.lax.dynamic_update_index_in_dim(vs, v, a, 0)
@@ -751,11 +1096,12 @@ def hybrid_prefill_paged(
         ep = dict(ep, w_up=params["moe"]["w_up"], w_down=params["moe"]["w_down"])
         out, _, chosen = moe_mixer(c, ep, h, real.reshape(-1), layer=i)
         x = _residual(c, x, out.reshape(B, Pn, c.hidden))
-        return (x, ks, vs, ssm_all, conv_all), chosen.reshape(B, Pn, -1)
+        return (x, ks, vs) + tuple(rec[kind] for kind in kinds), \
+            chosen.reshape(B, Pn, -1)
 
     spare = jnp.zeros((nA + 1, B, Pn, KhD), c.dtype)
-    (x, ks, vs, ssm_all, conv_all), routed = jax.lax.scan(
-        block, (x, spare, spare, state["ssm"], state["conv"]),
+    (x, ks, vs, *rec), routed = jax.lax.scan(
+        block, (x, spare, spare) + tuple(state[kind] for kind in kinds),
         _block_xs(c, params, experts_in_xs=False))
     with jax.named_scope("lm_head"):
         x = _rms_norm(x, params["final_norm"], c.norm_eps)
@@ -766,7 +1112,7 @@ def hybrid_prefill_paged(
     with jax.named_scope("kv_write"):
         pool_k = write_rows(pool_k, ks[:nA], block_tables, starts, real)
         pool_v = write_rows(pool_v, vs[:nA], block_tables, starts, real)
-    return logits, pool_k, pool_v, {"ssm": ssm_all, "conv": conv_all}, routed
+    return logits, pool_k, pool_v, dict(zip(kinds, rec)), routed
 
 
 # ---------------------------------------------------------------------------
@@ -816,6 +1162,7 @@ def hybrid_decode_chunk_paged(
     pen = sample_extras is not None
     counts0 = sample_extras[2] if pen else None
     block_xs = _block_xs(c, params)
+    kinds = tuple(kind for kind in _STATE_KINDS if kind in state)
 
     def cache_partial(q, a):
         if kernel == "xla":
@@ -832,8 +1179,9 @@ def hybrid_decode_chunk_paged(
         )
 
     def step(carry, step_idx):
-        tokens, kbuf, vbuf, key, ssm, conv, load = carry[:7]
-        counts = carry[7] if pen else None
+        tokens, kbuf, vbuf, key = carry[:4]
+        rec, load = carry[4 : 4 + len(kinds)], carry[4 + len(kinds)]
+        counts = carry[-1] if pen else None
         with jax.named_scope("sample"):
             key, sub = jax.random.split(key)
         with jax.named_scope("embed"):
@@ -871,6 +1219,10 @@ def hybrid_decode_chunk_paged(
                      m_b.reshape(B, c.heads),
                      jnp.sum(p_b, axis=-1).reshape(B, c.heads)),
                 ]).astype(x.dtype).reshape(B, c.heads * c.head_dim)
+            if c.attn_gate:
+                with jax.named_scope("attn_gate"):
+                    out = out * jax.nn.sigmoid(
+                        (h @ ap["wg"]).astype(jnp.float32)).astype(out.dtype)
             with jax.named_scope("attn_out"):
                 return _residual(c, x, out @ ap["wo"]), k, v
 
@@ -884,12 +1236,26 @@ def hybrid_decode_chunk_paged(
                 active, kernel)
             return _residual(c, x, out), ssm, conv
 
+        def delta(x, kd, S, dconv):
+            lp = _layer_at(params["delta"], kd)
+            out, S, dconv = delta_step(
+                c, lp, _rms_norm(x, lp["norm"], c.norm_eps), S, dconv, kd,
+                active, kernel)
+            return _residual(c, x, out), S, dconv
+
         def block(carry, xs):
-            x, kbuf, vbuf, ssm, conv = carry
-            mp, ep, has, a, i = xs
-            _, (x, ssm, conv) = _mamba_in_block(
-                c, params, mp, i, mamba, lambda *through: through, x, ssm,
-                conv)
+            x, kbuf, vbuf = carry[:3]
+            rec = dict(zip(kinds, carry[3:]))
+            mp, ep, has, a, i = xs[:5]
+            if c.mamba_layers:
+                _, (x, rec["ssm"], rec["conv"]) = _mamba_in_block(
+                    c, params, mp, i, mamba, lambda *through: through, x,
+                    rec["ssm"], rec["conv"])
+            if c.delta_layers:
+                has_k, kd = xs[5]
+                x, rec["delta"], rec["dconv"] = jax.lax.cond(
+                    has_k, delta, lambda x, kd, *through: (x,) + through,
+                    x, kd, rec["delta"], rec["dconv"])
             x, k, v = jax.lax.cond(
                 has, attention, no_attention, x, a, kbuf, vbuf)
             with jax.named_scope("attn_qkv"):
@@ -899,11 +1265,11 @@ def hybrid_decode_chunk_paged(
                     vbuf, v[None, :, None], (a, 0, step_idx, 0, 0))
             out, load_i, chosen = moe_mixer(
                 c, ep, _rms_norm(x, ep["norm"], c.norm_eps), active)
-            return (_residual(c, x, out), kbuf, vbuf, ssm, conv), \
-                (load_i, chosen)
+            return (_residual(c, x, out), kbuf, vbuf) + tuple(
+                rec[kind] for kind in kinds), (load_i, chosen)
 
-        (x, kbuf, vbuf, ssm, conv), (load_step, chosen) = jax.lax.scan(
-            block, (x, kbuf, vbuf, ssm, conv), block_xs)
+        (x, kbuf, vbuf, *rec), (load_step, chosen) = jax.lax.scan(
+            block, (x, kbuf, vbuf) + tuple(rec), block_xs)
         with jax.named_scope("lm_head"):
             x = _rms_norm(x, params["final_norm"], c.norm_eps)
             logits = _logits(c, params, x)
@@ -911,19 +1277,21 @@ def hybrid_decode_chunk_paged(
             nxt, lp_ = (sample_fn(logits, sub, counts) if pen
                         else sample_fn(logits, sub))
             nxt = jnp.where(active, nxt, tokens)
-        out_carry = (nxt, kbuf, vbuf, key, ssm, conv, load + load_step)
+        out_carry = (nxt, kbuf, vbuf, key, *rec, load + load_step)
         if pen:
             out_carry += (counts.at[jnp.arange(B), nxt].add(adv),)
         return out_carry, (nxt, lp_, chosen)
 
     kbuf0 = jnp.zeros((nA + 1, B, num_steps, c.kv_heads, c.head_dim), c.dtype)
-    carry0 = (tokens0, kbuf0, kbuf0, key, state["ssm"], state["conv"],
-              jnp.zeros((nB, c.experts_held), jnp.int32))
+    carry0 = (tokens0, kbuf0, kbuf0, key) + tuple(
+        state[kind] for kind in kinds) + (
+        jnp.zeros((nB, c.experts_held), jnp.int32),)
     if pen:
         carry0 += (counts0,)
     out_carry, (chunk_tokens, chunk_lps, routed) = jax.lax.scan(
         step, carry0, jnp.arange(num_steps))
-    final_tokens, kbuf, vbuf, _, ssm, conv, load = out_carry[:7]
+    final_tokens, kbuf, vbuf = out_carry[:3]
+    rec, load = out_carry[4 : 4 + len(kinds)], out_carry[4 + len(kinds)]
     valid = jnp.broadcast_to(active[:, None], (B, num_steps))
     with jax.named_scope("kv_write"):
         pool_k = write_rows(
@@ -933,7 +1301,7 @@ def hybrid_decode_chunk_paged(
             pool_v, vbuf[:nA].reshape(nA, B, num_steps, KhD), block_tables,
             base_lengths, valid)
     final_lengths = base_lengths + num_steps * adv
-    state = {"ssm": ssm, "conv": conv}
+    state = dict(zip(kinds, rec))
     if return_packed:
         packed = jnp.concatenate(
             [pack_tokens_logprobs(chunk_tokens, chunk_lps), load.reshape(-1)])

@@ -15,8 +15,9 @@ f32 scales), outputs are O(1) softmax averages of unit-normal values, and
 the kernels accumulate in f32 over blocks where XLA reduces over the whole
 window — 3e-2 absolute covers the bf16 probability rounding on both sides
 (the interpreted CPU tests see ~3e-2 on O(1–4) outputs, tests/test_paged.py).
-The Mamba-2 state's pass (:func:`check_state_kernel`) is float32 on both
-sides: its row compares at 1e-4 of the expression's largest value.
+The recurrent state's passes (:func:`check_state_kernel` for Mamba-2's,
+:func:`check_delta_state_kernel` for the delta rule's) are float32 on both
+sides: their rows compare at 1e-4 of the expression's largest value.
 """
 
 from __future__ import annotations
@@ -102,22 +103,56 @@ def check_state_kernel(model_config, *, slots: int,
         jax.random.normal(ks[4], (slots, G, N), jnp.float32),
         jnp.arange(slots) != 1,
     )
+    return _state_row(
+        "_ssm_state_kernel",
+        {"layers": 2, "slots": slots, "heads": heads, "head_dim": P,
+         "state": N, "groups": G},
+        ssm_state_step, ssm, operands, interpret)
 
+
+def _state_row(name: str, shape: dict, step: Callable, state, operands,
+               interpret: bool) -> dict[str, Any]:
+    """A state kernel's row: ``step(state, 1, *operands, kernel=...)`` through
+    the kernel against its XLA form, the output and the stack each compared
+    as shares of the expression's largest value."""
     def run():
         got, ref = (
-            jax.jit(lambda s, *a, kernel=kernel: ssm_state_step(
-                s, 1, *a, kernel=kernel))(ssm, *operands)
+            jax.jit(lambda s, *a, kernel=kernel: step(
+                s, 1, *a, kernel=kernel))(state, *operands)
             for kernel in ("pallas-interpret" if interpret else "pallas", "xla"))
         scales = [jnp.max(jnp.abs(r.astype(jnp.float32))) for r in ref]
         flat = lambda out: jnp.concatenate([  # noqa: E731
             (r.astype(jnp.float32) / s).ravel() for r, s in zip(out, scales)])
         return flat(got), flat(ref)
 
-    return _row(
-        "_ssm_state_kernel",
-        {"layers": 2, "slots": slots, "heads": heads, "head_dim": P,
-         "state": N, "groups": G},
-        interpret, run, tol=STATE_TOLERANCE)
+    return _row(name, shape, interpret, run, tol=STATE_TOLERANCE)
+
+
+def check_delta_state_kernel(model_config, *, slots: int,
+                             interpret: bool = False) -> dict[str, Any]:
+    """One row: the delta-rule state's decode step (``ops/delta_state.py``)
+    at a hybrid model's delta-rule heads and tile, on a stack of two layers
+    of ``slots`` slots of which the second layer is advanced and one slot is
+    idle, against the XLA expression, as :func:`check_state_kernel`."""
+    from langstream_tpu.ops.delta_state import delta_state_step
+
+    c = model_config
+    heads, D = c.delta_heads, c.delta_head_dim
+    ks = jax.random.split(jax.random.PRNGKey(23), 6)
+    unit = lambda t: t / jnp.linalg.norm(t, axis=-1, keepdims=True)  # noqa: E731
+    state = jax.random.normal(ks[0], (2, slots, heads, D, D)).astype(c.state_dtype)
+    operands = (
+        jax.random.uniform(ks[1], (slots, heads, D), jnp.float32, 0.2, 1.0),
+        unit(jax.random.normal(ks[2], (slots, heads, D), jnp.float32)),
+        unit(jax.random.normal(ks[3], (slots, heads, D), jnp.float32)),
+        jax.random.normal(ks[4], (slots, heads, D), jnp.float32),
+        jax.random.uniform(ks[5], (slots, heads), jnp.float32, 0.0, 2.0),
+        jnp.arange(slots) != 1,
+    )
+    return _state_row(
+        "_delta_state_kernel",
+        {"layers": 2, "slots": slots, "heads": heads, "head_dim": D},
+        delta_state_step, state, operands, interpret)
 
 
 def check_kernels(

@@ -145,9 +145,9 @@ _MOE_MODELS = ("moe-tiny", "moe-8x7b", "mixtral-8x7b")
 
 # Families with programs of their own beside the paged pool, one table:
 # name -> (family, the classmethod of the family's config class that builds
-# it). "hybrid" (models/hybrid.py HybridConfig: Mamba-2 + attention + routed
-# experts, the nemotron_h and granitemoehybrid layers) keeps a per-slot
-# recurrent state beside a K/V pool; "latent" (models/latent.py
+# it). "hybrid" (models/hybrid.py HybridConfig: Mamba-2 or gated delta-rule
+# mixers + attention + routed experts, the nemotron_h, granitemoehybrid and
+# solar_open2 layers) keeps a per-slot recurrent state beside a K/V pool; "latent" (models/latent.py
 # LatentConfig: multi-head latent attention + group-limited routed experts,
 # the deepseek_v2 layer) keeps ONE pool whose row is a compressed latent.
 _FAMILY_MODELS = {
@@ -155,6 +155,8 @@ _FAMILY_MODELS = {
     "nemotron-3-nano-30b-a3b-ep8": ("hybrid", "nemotron3_nano_ep8"),
     "granite-tiny": ("hybrid", "granite_tiny"),
     "granite-4.0-h-small-ep2": ("hybrid", "granite4_h_small_ep2"),
+    "solar-tiny": ("hybrid", "solar_tiny"),
+    "solar-open2-250b-ep8": ("hybrid", "solar_open2_ep8"),
     "deepseek-tiny": ("latent", "tiny"),
     "deepseek-v2-ep8": ("latent", "deepseek_v2_ep8"),
 }
@@ -1837,9 +1839,9 @@ class TpuServingEngine:
                 f"paged_kernel=xla (or auto) for sharded int8 pools"
             )
         self.paged_read_kernel = kernel
-        # the decode program's other kernel, the Mamba-2 state's pass
-        # (ops/ssm_state.py), follows the same selection; the dense
-        # family has no such state
+        # the decode program's other kernel, the recurrent state's pass
+        # (Mamba-2's ops/ssm_state.py, the delta rule's ops/delta_state.py),
+        # follows the same selection; the dense family has no such state
         self.ssm_state_kernel = kernel if self.is_hybrid else None
         # continuation prefill / speculative verify read history
         # through the multi-query kernel, which has no int8 twin:

@@ -212,6 +212,32 @@ def test_mosaic_builds_the_kernel_at_the_served_shapes_in_place(
     assert "tpu_custom_call" in text and "ssm_state_step" in text
 
 
+def test_mosaic_builds_the_delta_rule_s_kernel_at_the_served_shape_in_place(
+        one_chip, no_compile_cache):
+    """solar-open2-250b-ep8: 3 layers x 192 slots x 64 heads of (128, 128)
+    float32, 2.4 GB, the stack the output's own buffer (ops/delta_state.py;
+    held HERE for the reason given below)."""
+    from langstream_tpu.ops.delta_state import delta_state_step
+
+    c = HybridConfig.solar_open2_ep8()
+    L, slots, heads, D = c.delta_layers, 192, c.delta_heads, c.delta_head_dim
+    on = lambda shape, dtype=jnp.float32: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dtype, sharding=one_chip)
+    compiled = jax.jit(
+        lambda *a: delta_state_step(*a, kernel="pallas"), donate_argnums=(0,),
+    ).lower(
+        on((L, slots, heads, D, D), c.state_dtype), on((), jnp.int32),
+        on((slots, heads, D)), on((slots, heads, D)), on((slots, heads, D)),
+        on((slots, heads, D)), on((slots, heads)), on((slots,), jnp.bool_),
+    ).compile()
+    stack = L * slots * heads * D * D * 4
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= stack
+    assert memory.temp_size_in_bytes < stack // 100
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "delta_state_step" in text
+
+
 # the latent family's two kernels (models/latent.py) are held to the same
 # compiler HERE, in the one file whose fixture describes the chip: a second
 # file with such a fixture could go to another worker, which cannot load the

@@ -1,5 +1,5 @@
 """A hybrid model (``nemotron-3-nano-30b-a3b-ep8`` or, with ``--config``,
-``granite-4.0-h-small-ep2``) at its published widths on the chip, without the
+``granite-4.0-h-small-ep2`` or ``solar-open2-250b-ep8``) at its published widths on the chip, without the
 benchmark's harness around it: builds the engine from a configuration's
 ``serving`` block, runs the reference check as the file states it (the
 reference the file names) and again
@@ -99,9 +99,13 @@ async def run(args) -> dict:
     out["memory_built"] = memory("engine build")
     tolerance = config["reference_tolerance"]
     mc = engine.model_config
-    from langstream_tpu.ops.selfcheck import check_state_kernel
+    from langstream_tpu.ops import selfcheck
 
-    out["kernel_check"] = check_state_kernel(mc, slots=8)
+    # the family's state kernel at this model's shapes: Mamba-2's or, for a
+    # pattern of delta-rule layers, the delta rule's
+    out["kernel_check"] = (
+        selfcheck.check_delta_state_kernel if mc.delta_layers
+        else selfcheck.check_state_kernel)(mc, slots=8)
     print(f"[probe] kernel: {json.dumps(out['kernel_check'])[:600]}", flush=True)
     controls = (
         ("as served", None),
@@ -131,13 +135,16 @@ async def run(args) -> dict:
         # the served program once, against the reference with each term of
         # the published equations left out (or put in) in turn: each has to
         # come out as not passed
-        got = await asyncio.to_thread(reference.served, engine, args.seeds[0])
+        got = await asyncio.to_thread(
+            reference.served, engine, args.seeds[0],
+            **getattr(reference, "FAULT_CHECK", {}))
         for fault in reference.FAULTS:
             report = await asyncio.to_thread(
                 reference.judge, engine, got, tolerance, (fault,))
             row = {k: report.get(k) for k in (
                 "passed", "worst_rms_share", "worst_correlation",
-                "first_state_rms_share", "worst_routing_shortfall",
+                "first_state_rms_share", "prefill_state_rms_share",
+                "worst_routing_shortfall",
                 "first_routing_shortfall", "first_routing_differing_share",
                 "routing_decisions_differing")}
             print(f"[probe] fault {fault}: {json.dumps(row)}", flush=True)
@@ -176,6 +183,10 @@ async def run(args) -> dict:
     if args.trace:
         from lib import hybridtrace, xplane
 
+        if mc.delta_layers:     # the delta-rule programs name more seams
+            from lib import roofline_delta
+
+            hybridtrace.SCOPES = hybridtrace.SCOPES + roofline_delta.SCOPES
         trace_dir = os.path.join(ROOT, "chiprun_out", "hybrid_probe_trace")
         # long enough at either configuration that the trace, 4 s in, falls
         # on decode chunks (96 slots' prefill and 128 steps were over by
