@@ -79,6 +79,7 @@ from langstream_tpu.ops.paged_attention import (
     merge_partial_attention,
     paged_attention_partial,
 )
+from langstream_tpu.ops.delta_chunk import delta_chunk_rule
 from langstream_tpu.ops.delta_state import delta_state_step
 from langstream_tpu.ops.ssm_state import ssm_state_step
 
@@ -672,14 +673,23 @@ def _delta_gates(c: HybridConfig, lp: dict, u: jax.Array):
     return g, beta
 
 
-def _delta_qkv(c: HybridConfig, conv: jax.Array):
+def _delta_qkv(c: HybridConfig, conv: jax.Array, flat: bool = False):
     """The convolutions' output ``(..., 3 x inner)`` float32 to ``q`` (unit
     length a head, scaled by ``dk ** -0.5``), ``k`` (unit length) and ``v``,
-    each ``(..., heads, dk)`` float32."""
+    each ``(..., heads, dk)`` float32. ``flat`` cuts ``q | k | v`` out of the
+    lanes BEFORE the head axis is split off: the same values, and what the
+    TPU compiler lays out as it lies in front of the chunk kernel's operands
+    (cut after the split, it moves the convolutions' whole output to a
+    rows-minor layout and each of the three back: 12 ms a prefill of 8 x
+    1,024)."""
     lead = conv.shape[:-1]
-    x = jax.nn.silu(conv).astype(c.dtype).astype(jnp.float32).reshape(
-        lead + (3, c.delta_heads, c.delta_head_dim))
-    q, k, v = x[..., 0, :, :], x[..., 1, :, :], x[..., 2, :, :]
+    x = jax.nn.silu(conv).astype(c.dtype).astype(jnp.float32)
+    if flat:
+        q, k, v = (t.reshape(lead + (c.delta_heads, c.delta_head_dim))
+                   for t in jnp.split(x, 3, axis=-1))
+    else:
+        x = x.reshape(lead + (3, c.delta_heads, c.delta_head_dim))
+        q, k, v = x[..., 0, :, :], x[..., 1, :, :], x[..., 2, :, :]
     unit = lambda t: t * jax.lax.rsqrt(  # noqa: E731
         jnp.sum(t * t, axis=-1, keepdims=True) + 1e-6)
     return unit(q) * c.delta_head_dim ** -0.5, unit(k), v
@@ -788,11 +798,14 @@ def delta_chunked(
     return o, S
 
 
-def delta_prefill(c: HybridConfig, lp: dict, u: jax.Array, lengths: jax.Array):
+def delta_prefill(c: HybridConfig, lp: dict, u: jax.Array, lengths: jax.Array,
+                  kernel: str = "xla"):
     """The gated delta-rule mixer over right-padded prompts ``u (B, P, H)``
     (already normed). Returns ``(out (B, P, H), state (B, heads, dv, dk),
     conv tail (B, kernel - 1, 3 x inner))``, state and tail as they stand
-    after each row's last real token."""
+    after each row's last real token. ``kernel`` is :func:`delta_step`'s: the
+    chunked rule as :func:`delta_chunked` (``"xla"``) or as
+    :func:`langstream_tpu.ops.delta_chunk.delta_chunk_rule`."""
     B, Pn, _ = u.shape
     kk = c.conv_kernel
     with jax.named_scope("delta_in"):
@@ -805,7 +818,7 @@ def delta_prefill(c: HybridConfig, lp: dict, u: jax.Array, lengths: jax.Array):
             * lp["conv_w"][:, i].astype(jnp.float32)
             for i in range(kk)
         )
-        q, k, v = _delta_qkv(c, conv)
+        q, k, v = _delta_qkv(c, conv, flat=kernel != "xla")
         tail = jnp.take_along_axis(
             padded, (lengths[:, None] + jnp.arange(kk - 1)[None, :])[..., None],
             axis=1,
@@ -814,7 +827,14 @@ def delta_prefill(c: HybridConfig, lp: dict, u: jax.Array, lengths: jax.Array):
         real = jnp.arange(Pn)[None, :] < lengths[:, None]
         g = jnp.where(real[..., None, None], g, 0.0)
         beta = jnp.where(real[..., None], beta, 0.0)
-        o, state = delta_chunked(q, k, v, g, beta, c.delta_chunk)
+        if kernel == "xla":
+            o, state = delta_chunked(q, k, v, g, beta, c.delta_chunk)
+        elif kernel in ("pallas", "pallas-interpret"):
+            o, state = delta_chunk_rule(
+                q, k, v, g, beta, c.delta_chunk, lengths,
+                interpret=(kernel == "pallas-interpret"))
+        else:
+            raise ValueError(f"delta_prefill: unknown kernel {kernel!r}")
     with jax.named_scope("delta_out"):
         out = _delta_out(c, lp, o, u)
     return out, state.astype(c.state_dtype), tail
@@ -979,13 +999,22 @@ def hybrid_prefill_paged(
     block_tables: jax.Array,  # (B, max_blocks): rows of THIS batch
     slot_ids: jax.Array,      # (B,) the slots whose state rows are written
     use_flash: bool | None = None,
+    kernel: str | None = None,
 ):
     """Prompt forward: the attention layers' K/V rows land in the pool, and
     each row's recurrent state and convolution tail, as they stand after its
     last real token, overwrite its slot's rows of ``state``. Returns
     ``(last-token logits (B, V), pool_k, pool_v, state, routed)``; ``routed
     (blocks, B, P, k)`` are the experts the router chose, for the reference
-    check (a caller that drops it pays nothing for it).
+    check (a caller that drops it pays nothing for it). ``kernel`` is the
+    engine's one selection for the recurrent state's kernels
+    (``ssm_state_kernel``); in a prefill only a delta-rule block reads it
+    (:func:`delta_prefill`). A caller that hands none gets, as with
+    ``use_flash``, what the engine resolves on this backend for the pool this
+    family has (``"pallas"`` on a TPU, ``"xla"`` elsewhere): the reference
+    check's model function (``bench/reference/solar_open2.py``
+    ``check_engine``) hands none, and holds the engine's PROGRAM to the
+    model's FUNCTION as its decode side does, under one selection.
 
     The commit is :func:`langstream_tpu.models.paged.write_rows`, the one
     every family uses: the attention layer is folded into the row index, so
@@ -998,6 +1027,8 @@ def hybrid_prefill_paged(
     real = jnp.arange(Pn)[None, :] < lengths[:, None]             # (B, P)
     flash = (_flash_mode(Pn) if use_flash is None
              else ("compiled" if use_flash else None))
+    if kernel is None:
+        kernel = "pallas" if jax.default_backend() == "tpu" else "xla"
     with jax.named_scope("embed"):
         x = _embed(c, params, tokens)
 
@@ -1056,7 +1087,8 @@ def hybrid_prefill_paged(
 
     def delta(x, kd):
         lp = _layer_at(params["delta"], kd)
-        return delta_prefill(c, lp, _rms_norm(x, lp["norm"], c.norm_eps), lengths)
+        return delta_prefill(
+            c, lp, _rms_norm(x, lp["norm"], c.norm_eps), lengths, kernel)
 
     def no_delta(x, kd):
         return (jnp.zeros_like(x),

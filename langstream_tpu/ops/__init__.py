@@ -7,6 +7,10 @@
 - :mod:`langstream_tpu.ops.ssm_state` — one decode step of a Mamba-2
   layer's recurrent state, on the stacked state in place: one pass over it
   where the XLA expression makes three.
+- :mod:`langstream_tpu.ops.delta_state`, :mod:`langstream_tpu.ops.delta_chunk`
+  — the gated delta rule's state: a decode step's pass over it in place, and
+  a prefill's chunked rule as one kernel a layer, the state carried through
+  a prompt's chunks in VMEM.
 - :mod:`langstream_tpu.ops.selfcheck` — builds every kernel above at a
   served model's shapes and compares it with the XLA read it replaces.
 

@@ -17,7 +17,12 @@ window — 3e-2 absolute covers the bf16 probability rounding on both sides
 (the interpreted CPU tests see ~3e-2 on O(1–4) outputs, tests/test_paged.py).
 The recurrent state's passes (:func:`check_state_kernel` for Mamba-2's,
 :func:`check_delta_state_kernel` for the delta rule's) are float32 on both
-sides: their rows compare at 1e-4 of the expression's largest value.
+sides: their rows compare at 1e-4 of the expression's largest value. The
+chunked delta rule of a prefill (:func:`check_delta_chunk_kernel`) rounds the
+operands of its large products to bfloat16, as the XLA form's are rounded on
+a TPU and are NOT on the CPU: its row compares at 2e-2 of the expression's
+largest value (the interpreted kernel reads 4e-3 against float32 products; a
+decay factor left out reads 0.3).
 """
 
 from __future__ import annotations
@@ -31,6 +36,7 @@ import numpy as np
 
 TOLERANCE = 3e-2
 STATE_TOLERANCE = 1e-4
+CHUNK_TOLERANCE = 2e-2
 
 
 def _int8_pool(key, shape_rows: tuple, kv_heads: int, head_dim: int) -> dict:
@@ -153,6 +159,49 @@ def check_delta_state_kernel(model_config, *, slots: int,
         "_delta_state_kernel",
         {"layers": 2, "slots": slots, "heads": heads, "head_dim": D},
         delta_state_step, state, operands, interpret)
+
+
+def check_delta_chunk_kernel(model_config, *, rows: int = 2,
+                             interpret: bool = False) -> dict[str, Any]:
+    """One row: the chunked delta rule of a prefill (``ops/delta_chunk.py``)
+    at a hybrid model's delta-rule heads and chunk, ``rows`` right-padded
+    prompts of two chunks (the first whole; the others end inside their first
+    chunk, so their second is skipped), against the XLA expression
+    (``models/hybrid.py`` ``delta_chunked``): the real rows' output and the
+    state, each as shares of the expression's largest value."""
+    from langstream_tpu.models.hybrid import delta_chunked
+    from langstream_tpu.ops.delta_chunk import delta_chunk_rule
+
+    c = model_config
+    heads, D, chunk = c.delta_heads, c.delta_head_dim, c.delta_chunk
+    Pn = 2 * chunk
+    ks = jax.random.split(jax.random.PRNGKey(24), 5)
+    unit = lambda t: t / jnp.linalg.norm(t, axis=-1, keepdims=True)  # noqa: E731
+    lengths = jnp.full((rows,), chunk - 3, jnp.int32).at[0].set(Pn)
+    real = jnp.arange(Pn)[None, :] < lengths[:, None]
+    q = unit(jax.random.normal(ks[0], (rows, Pn, heads, D))) * D ** -0.5
+    k = unit(jax.random.normal(ks[1], (rows, Pn, heads, D)))
+    v = jax.random.normal(ks[2], (rows, Pn, heads, D))
+    g = jnp.where(real[..., None, None], -jax.random.uniform(
+        ks[3], (rows, Pn, heads, D), jnp.float32, 0.0, 0.5), 0.0)
+    beta = jnp.where(real[..., None], jax.random.uniform(
+        ks[4], (rows, Pn, heads), jnp.float32, 0.0, 2.0), 0.0)
+
+    def run():
+        got = jax.jit(lambda *a: delta_chunk_rule(
+            *a, chunk, lengths, interpret=interpret))(q, k, v, g, beta)
+        ref = jax.jit(lambda *a: delta_chunked(*a, chunk))(q, k, v, g, beta)
+        scales = [jnp.max(jnp.abs(r)) for r in ref]
+        flat = lambda out: jnp.concatenate([  # noqa: E731
+            (jnp.where(real[..., None, None], out[0], 0.0) / scales[0]).ravel(),
+            (out[1] / scales[1]).ravel()])
+        return flat(got), flat(ref)
+
+    return _row(
+        "_delta_chunk_kernel",
+        {"rows": rows, "tokens": Pn, "chunk": chunk, "heads": heads,
+         "head_dim": D, "lengths": [Pn] + [chunk - 3] * (rows - 1)},
+        interpret, run, tol=CHUNK_TOLERANCE)
 
 
 def check_kernels(

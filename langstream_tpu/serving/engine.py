@@ -1839,9 +1839,9 @@ class TpuServingEngine:
                 f"paged_kernel=xla (or auto) for sharded int8 pools"
             )
         self.paged_read_kernel = kernel
-        # the decode program's other kernel, the recurrent state's pass
-        # (Mamba-2's ops/ssm_state.py, the delta rule's ops/delta_state.py),
-        # follows the same selection; the dense family has no such state
+        # the hybrid family's other kernels: the recurrent state's pass
+        # (Mamba-2's ops/ssm_state.py, the delta rule's ops/delta_state.py
+        # and a prefill's chunked rule, ops/delta_chunk.py) follows it
         self.ssm_state_kernel = kernel if self.is_hybrid else None
         # continuation prefill / speculative verify read history
         # through the multi-query kernel, which has no int8 twin:
@@ -2123,7 +2123,7 @@ class TpuServingEngine:
                     logits, ck, cv, st, _routed = hybrid_prefill_paged(
                         mc_static, params, tokens, lengths, cache_k, cache_v,
                         state, tables, slot_ids, use_flash=prefill_flash,
-                    )
+                        kernel=self.ssm_state_kernel)
                     with jax.named_scope("sample"):
                         next_tokens, logprobs = sample_tokens(
                             logits, key, temps, topks,

@@ -107,6 +107,10 @@ async def run(args) -> dict:
         selfcheck.check_delta_state_kernel if mc.delta_layers
         else selfcheck.check_state_kernel)(mc, slots=8)
     print(f"[probe] kernel: {json.dumps(out['kernel_check'])[:600]}", flush=True)
+    if mc.delta_layers:     # and a prefill's chunked rule (ops/delta_chunk.py)
+        out["chunk_kernel_check"] = selfcheck.check_delta_chunk_kernel(mc)
+        print(f"[probe] kernel: {json.dumps(out['chunk_kernel_check'])[:600]}",
+              flush=True)
     controls = (
         ("as served", None),
         # the readings the file's state_rms_share and first_routing_differing_share
