@@ -1,0 +1,32 @@
+"""The paged read of a model with a pool a layer kind (``ops/paged_attention.py``
+``paged_attention_partial``: a full layer's walk from row 0, a window layer's
+from the first row its query sees) against its roofline, over both kinds:
+the least time for one step's reads (the live rows once, ``min(length,
+sliding_window)`` of a slot on a window layer and ``length`` on a full one, 2
+x kv heads x head_dim x 2 B a row, or the heads' operations over them:
+``lib/roofline_swa.py`` ``read_floor``) over the kernel's device time a step,
+which is the seconds of the op ``paged_read.N`` in the decode programs over
+the steps in the trace. The rows are the flight samples' ``live_rows`` and
+``window_rows`` (``serving/engine.py`` ``_read_rows``, ``_pool_rows``).
+
+A posture that reads the pools through XLA, a program of another family and
+a run that was not traced give nothing."""
+
+META = {"unit": "%", "better": "higher", "layer": "kernels",
+    "moves": "tpot_p50_ms", "source": "device_trace"}
+
+
+def read(obs):
+    from lib import roofline_swa
+
+    shape, load = roofline_swa.shape_of(obs), roofline_swa.per_step(obs)
+    kernel = roofline_swa.read_kernel(obs)
+    if shape is None or load is None or kernel is None or not obs.get("peaks"):
+        return None
+    _, steps = roofline_swa.traced_steps(obs)
+    if not steps:
+        return None
+    floor = roofline_swa.read_floor(
+        shape, full_rows=load["full_rows"], window_rows=load["window_rows"],
+        peaks=obs["peaks"])
+    return 100.0 * floor["floor_s"] / (kernel["total_s"] / steps)
