@@ -617,6 +617,7 @@ def _cache_partial_xla(
     lengths: jax.Array,       # (B,)
     num_read_blocks: int,
     scale: float | None = None,   # None: 1/sqrt(head_dim)
+    firsts: jax.Array | None = None,  # (B,) the first row a slot's query sees
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """The XLA paged read: partial softmax statistics of one query a slot
     over the layer's window. Works on every backend, for bf16 and int8
@@ -632,7 +633,10 @@ def _cache_partial_xla(
     ``_XLA_READ_PASS_BYTES`` whose rows never reach HBM again, their
     partials merged as the chunk buffer's is. It still sweeps the whole
     window bucket: reading a slot's live blocks alone is the Pallas
-    driver's (``ops/paged_attention.py``)."""
+    driver's (``ops/paged_attention.py``). With ``firsts`` (a layer that
+    attends a window of its last rows) a slot's query sees the rows
+    ``[firsts[b], lengths[b])`` and the ``num_read_blocks`` columns swept are
+    the slot's own, from the block that holds its first row."""
     from langstream_tpu.models.kvquant import window_scores, window_values
     from langstream_tpu.ops.paged_attention import merge_partials
 
@@ -643,13 +647,25 @@ def _cache_partial_xla(
 
     def one_pass(first: int, n: int):
         """Table columns ``first .. first + n``: rows from ``first * bs``."""
-        tables = block_tables[:, first:first + n]
+        if firsts is None:
+            tables = block_tables[:, first:first + n]
+        else:
+            col0 = firsts // bs
+            cols = col0[:, None] + first + jnp.arange(n)[None, :]
+            tables = jnp.take_along_axis(
+                block_tables, jnp.minimum(cols, block_tables.shape[1] - 1),
+                axis=1)
         kw = gather_kv(pool_k, tables, n, layer=layer)
         vw = gather_kv(pool_v, tables, n, layer=layer)
         s = window_scores(q, kw, c.kv_heads)                  # (B, Kh, G, W)
         s = s / math.sqrt(c.head_dim) if scale is None else s * scale
-        rows = first * bs + jnp.arange(n * bs)
-        mask = (rows[None, :] < lengths[:, None])[:, None, None, :]
+        rows = (first * bs + jnp.arange(n * bs))[None, :]
+        if firsts is not None:
+            rows = (col0 * bs)[:, None] + rows
+        mask = rows < lengths[:, None]
+        if firsts is not None:
+            mask = mask & (rows >= firsts[:, None])
+        mask = mask[:, None, None, :]
         s = jnp.where(mask, s, NEG_INF)
         m = jnp.max(s, axis=-1)                               # (B, Kh, G)
         shift = jnp.where(m <= NEG_INF, 0.0, m)

@@ -70,14 +70,7 @@ def init_paged_kv_cache(
     config, layout: PagedLayout
 ) -> tuple[jax.Array, jax.Array]:
     """Pool arrays ``(L, num_blocks, block_size, Kh*D)`` for K and V."""
-    c = config
-    shape = (
-        c.layers,
-        layout.num_blocks,
-        layout.block_size,
-        c.kv_heads * c.head_dim,
-    )
-    return jnp.zeros(shape, dtype=c.dtype), jnp.zeros(shape, dtype=c.dtype)
+    return init_kv_pool(config, layout, config.layers)
 
 
 def init_latent_pool(config, layout: PagedLayout) -> tuple[jax.Array, None]:
@@ -89,6 +82,19 @@ def init_latent_pool(config, layout: PagedLayout) -> tuple[jax.Array, None]:
     c = config
     shape = (c.layers, layout.num_blocks, layout.block_size, c.row_width)
     return jnp.zeros(shape, dtype=c.dtype), None
+
+
+def init_kv_pool(config, layout: PagedLayout, layers: int):
+    """``(K, V)`` pools ``(layers, layout.num_blocks, block_size, Kh*D)`` of
+    ONE kind of attention layer of a model that has two (models/swa.py: the
+    layers that attend every row, and the layers that attend the last
+    ``window`` rows, whose blocks a slot holds as a ring in a pool and a
+    layout of their own; :class:`BlockManager`). Each pool has its own block
+    0 as scratch."""
+    c = config
+    shape = (layers, layout.num_blocks, layout.block_size,
+             c.kv_heads * c.head_dim)
+    return jnp.zeros(shape, c.dtype), jnp.zeros(shape, c.dtype)
 
 
 def init_paged_kv_cache_int8(
@@ -231,6 +237,26 @@ class BlockManager:
     Block 0 is reserved as the scatter scratch target for masked writes and
     is never allocated.
 
+    **Two kinds of layer** (``window_layout`` and ``window_ring``; a model
+    whose layers attend either every row or the last W, models/swa.py): a
+    second pool with its own free list and scratch block, of which a slot
+    holds at most ``window_ring`` blocks (``W / block_size + 1``) whatever
+    its length, as a RING: logical block ``n`` of the slot lives in its ring
+    block ``n % window_ring``, taken from the free list the first time the
+    slot grows into it and overwritten in place from then on (the rows it
+    held lie behind every later query's window). ``tables`` is then ``[the
+    full kind's columns | the window kind's columns]``, both
+    ``max_blocks_per_slot`` wide and both indexed by LOGICAL block, so a
+    program finds a row's block the same way in either; a ring block fills
+    every column it will ever serve when it is taken, so the table of a slot
+    that has grown past the ring never changes again. Admission reserves a
+    request's worst case in both kinds and refuses what does not fit in
+    either; release (and a preemption, which is a release) returns both.
+    Why a ring and not blocks returned to the free list as the window moves
+    on: the window pool is sized for every slot's ring, so a returned block
+    buys no admission, and a table that changes costs an edit and an upload
+    every second chunk a slot.
+
     **Automatic prefix caching** (vLLM-style): full blocks of committed
     prompts are content-addressed by a chained digest of their tokens.
     A new request whose prompt starts with a cached chain adopts those
@@ -241,8 +267,18 @@ class BlockManager:
     """
 
     def __init__(self, layout: PagedLayout, slots: int,
-                 state_bytes_per_slot: int = 0):
+                 state_bytes_per_slot: int = 0,
+                 window_layout: PagedLayout | None = None,
+                 window_ring: int = 0):
         self.layout = layout
+        self.window_layout = window_layout
+        self.window_ring = window_ring if window_layout is not None else 0
+        if window_layout is not None and not (
+                0 < window_ring < window_layout.num_blocks):
+            raise ValueError(
+                f"a slot's ring of {window_ring} blocks does not fit a "
+                f"window pool of {window_layout.num_blocks} (block 0 is "
+                f"scratch)")
         # a hybrid model keeps a fixed-size recurrent state per slot beside
         # its pool rows (models/hybrid.py): allocated once for every slot,
         # never paged; a slot's rows are live from its admission to its
@@ -268,9 +304,22 @@ class BlockManager:
         self._slot_shared: list[list[int]] = [[] for _ in range(slots)]
         self._slot_blocks: list[list[int]] = [[] for _ in range(slots)]
         self._slot_reservation = [0] * slots
+        width = layout.max_blocks_per_slot
         self.tables = np.zeros(
-            (slots, layout.max_blocks_per_slot), dtype=np.int32
+            (slots, width * (2 if window_layout is not None else 1)),
+            dtype=np.int32,
         )
+        # the window kind: its free list, a slot's ring blocks in ring
+        # order, its reservations, and the table's second half (a view)
+        self._wfree = (
+            list(range(window_layout.num_blocks - 1, 0, -1))
+            if window_layout is not None else [])
+        self._wreserved = 0
+        self._slot_ring: list[list[int]] = [[] for _ in range(slots)]
+        self._slot_wreservation = [0] * slots
+        self.window_tables = self.tables[:, width:]
+        #: ring blocks returned to the free list at releases, ever
+        self.window_blocks_released = 0
         # prefix cache: chain digest -> block id (insertion order = LRU),
         # block refcounts (slot adoptions + cache membership), reverse map,
         # and the chain topology (parent digest + child count) so eviction
@@ -526,6 +575,11 @@ class BlockManager:
     def blocks_needed(self, total_tokens: int) -> int:
         return -(-total_tokens // self.layout.block_size)
 
+    def window_blocks_needed(self, total_tokens: int) -> int:
+        """Window-kind blocks a slot of ``total_tokens`` rows holds: its
+        blocks up to the ring, and never more (0 without the kind)."""
+        return min(self.blocks_needed(total_tokens), self.window_ring)
+
     def fits_ever(self, total_tokens: int) -> bool:
         """Whether a request of this worst-case size could EVER be admitted
         (even into an empty pool) — callers must reject oversized requests
@@ -539,6 +593,8 @@ class BlockManager:
         return (
             self._reserved + need <= self.usable_blocks
             and need <= self.layout.max_blocks_per_slot
+            and self._wreserved + self.window_blocks_needed(total_tokens)
+            <= self.window_usable_blocks
         )
 
     # -- adaptive budget (pool-shrink, docs/RESILIENCE.md) --------------
@@ -552,6 +608,24 @@ class BlockManager:
     def usable_blocks(self) -> int:
         """The LIVE admission budget: configured minus withheld."""
         return self.configured_blocks - self._budget_reduction
+
+    @property
+    def window_usable_blocks(self) -> int:
+        """The window kind's live admission budget: its configured blocks
+        less the share of them that :meth:`reduce_budget` withholds of the
+        full kind's (one reduction, both kinds), never under one ring."""
+        if self.window_layout is None:
+            return 0
+        configured = self.window_layout.num_blocks - 1
+        withheld = -(-self._budget_reduction * configured
+                     // max(1, self.configured_blocks))
+        return max(self.window_ring, configured - withheld)
+
+    @property
+    def window_slot_blocks_max(self) -> int:
+        """The most window-kind blocks any slot holds now (never more than
+        the ring); a walk of the slots' rings, cheap enough for a dispatch."""
+        return max((len(ring) for ring in self._slot_ring), default=0)
 
     @property
     def budget_reduction(self) -> int:
@@ -594,6 +668,8 @@ class BlockManager:
             raise RuntimeError("paged KV pool exhausted (admission bug)")
         self._slot_reservation[slot] = need
         self._reserved += need
+        self._slot_wreservation[slot] = self.window_blocks_needed(total_tokens)
+        self._wreserved += self._slot_wreservation[slot]
         self._state_live[slot] = True
 
     # -- growth --------------------------------------------------------
@@ -619,6 +695,17 @@ class BlockManager:
             self._slot_blocks[slot].append(b)
             self.tables[slot, idx] = b
             grown += 1
+        ring = self._slot_ring[slot]
+        while len(ring) < min(need, self.window_ring):
+            if not self._wfree:
+                raise RuntimeError(
+                    "paged KV window pool exhausted despite reservation "
+                    "accounting")
+            b = self._wfree.pop()
+            # every logical block this ring block will ever serve
+            self.window_tables[slot, len(ring)::self.window_ring] = b
+            ring.append(b)
+            grown += 1
         return grown
 
     def release(self, slot: int) -> None:
@@ -628,7 +715,12 @@ class BlockManager:
         self._slot_reservation[slot] = 0
         self._slot_shared[slot] = []
         self._slot_blocks[slot] = []
-        self.tables[slot, :] = 0
+        self._wfree.extend(reversed(self._slot_ring[slot]))
+        self.window_blocks_released += len(self._slot_ring[slot])
+        self._slot_ring[slot] = []
+        self._wreserved -= self._slot_wreservation[slot]
+        self._slot_wreservation[slot] = 0
+        self.tables[slot, :] = 0        # both kinds' columns
         self._state_live[slot] = False
 
     # -- stats ---------------------------------------------------------
@@ -644,7 +736,11 @@ class BlockManager:
         budget: a shrunk pool reports the pressure admissions actually
         face, not the configured capacity they temporarily lost."""
         usable = self.usable_blocks
-        return self._reserved / usable if usable > 0 else 1.0
+        ratio = self._reserved / usable if usable > 0 else 1.0
+        if self.window_layout is not None:
+            # the kind that refuses first is the pressure admissions face
+            ratio = max(ratio, self._wreserved / self.window_usable_blocks)
+        return ratio
 
     def prefix_block_count(self) -> int:
         """Blocks currently pinned by the content-addressed prefix cache
@@ -653,6 +749,31 @@ class BlockManager:
         return len(self._prefix)
 
     def stats(self) -> dict:
+        stats = self._full_kind_stats()
+        if self.window_layout is None:
+            return stats
+        # both kinds in the totals a poll of the pool reads (the share of
+        # the pools' blocks that back a slot's rows), the window kind's own
+        # beside them
+        held = sum(len(ring) for ring in self._slot_ring)
+        stats.update(
+            num_blocks=stats["num_blocks"] + self.window_layout.num_blocks,
+            free_blocks=stats["free_blocks"] + len(self._wfree),
+            reserved_blocks=stats["reserved_blocks"] + self._wreserved,
+            live_blocks=stats["live_blocks"] + held,
+            full_num_blocks=stats["num_blocks"],
+            full_live_blocks=stats["live_blocks"],
+            window_num_blocks=self.window_layout.num_blocks,
+            window_live_blocks=held,
+            window_reserved_blocks=self._wreserved,
+            window_budget_blocks=self.window_usable_blocks,
+            window_ring_blocks=self.window_ring,
+            window_slot_blocks_max=self.window_slot_blocks_max,
+            window_blocks_released=self.window_blocks_released,
+        )
+        return stats
+
+    def _full_kind_stats(self) -> dict:
         return {
             "num_blocks": self.layout.num_blocks,
             "free_blocks": len(self._free),

@@ -1,0 +1,613 @@
+"""Decoder whose attention layers are of two kinds in one stack, window and
+full (the ``afmoe`` family: Trinity-Large-Preview), over a paged pool a kind.
+
+A layer is ``x <- x + N_post(Attn(N_in(x)))``, ``x <- x + N_post(FFN(N_pre(
+x)))`` (sandwich norms: one before and one AFTER each sub-layer); the first
+``dense_layers`` layers' FFN is one gated MLP, every later layer's the routed
+experts beside a shared one (``moe_mixer`` of :mod:`langstream_tpu.models.
+hybrid`, sigmoid scores + a selection bias, the chosen scores renormalised
+and scaled). The embedding is scaled by ``sqrt(hidden)``; the head is untied.
+
+**Attention, every layer**: grouped queries with an RMSNorm of each query
+and key head (one gain of ``head_dim`` each, shared by the heads) and an
+elementwise output gate, ``W_o [o * sigmoid(W_g h)]``. **What the layer's
+kind decides** (``layer_kinds``: ``W`` window, ``F`` full):
+
+- a ``W`` layer rotates queries and keys (half-split rotary over the whole
+  head) and query ``i`` sees the keys ``j`` with ``0 <= i - j < window``;
+- an ``F`` layer applies no rotation and sees every key before it.
+
+**Two pools** (:func:`langstream_tpu.models.paged.init_kv_pool`, one a
+kind): the full layers' ``(F layers, blocks, bs, Kh*D)``, in which a slot
+holds every row it has written, and the window layers' ``(W layers, window
+blocks, bs, Kh*D)``, in which a slot holds a RING of ``window / bs + 1``
+blocks whatever its length (:class:`langstream_tpu.models.paged.
+BlockManager`): logical block ``n`` lives in ring block ``n % ring``, so a
+row written ``ring * bs`` positions after another overwrites it, and by then
+it lies behind every later query's window. Keys are stored rotated, so a
+window layer's read needs no order among its blocks, only which rows are
+live: the rows ``[length + step - (window - 1), length)`` of the logical
+table, which the read is told as a first row (:func:`langstream_tpu.ops.
+paged_attention.paged_attention_partial` ``firsts``; its XLA twin
+:func:`langstream_tpu.models.llama_paged._cache_partial_xla`). Both walk at
+most ``ring`` blocks a slot. A prefill writes a window layer's last
+``window`` rows only. The programs are handed ONE table, ``[the full kind's
+columns | the window kind's columns]``, each half ``max_blocks`` wide and
+indexed by logical block.
+
+The layers are few (one pipeline stage's) and of unlike kinds, so the
+programs walk them in Python; every layer's weights are leaves of their own.
+The expert layer serves one chip's share of an expert-parallel deployment,
+as the hybrid and latent families' do.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import math
+from typing import Any, Callable
+
+import jax
+import jax.numpy as jnp
+
+from langstream_tpu.models.hybrid import moe_mixer
+from langstream_tpu.models.llama import (
+    _apply_rope,
+    _flash_mode,
+    _rms_norm,
+    _rope,
+)
+from langstream_tpu.models.llama_paged import (
+    _cache_partial_xla,
+    pack_tokens_logprobs,
+)
+from langstream_tpu.models.moe import silu_gated
+from langstream_tpu.models.paged import write_rows
+from langstream_tpu.ops.paged_attention import (
+    NEG_INF,
+    merge_partial_attention,
+    paged_attention_partial,
+)
+
+#: query and key rows of one block of the prefill's flash kernel (keys and
+#: values of 128; the latent family's measurement at 192 / 128 chose the
+#: same: models/latent.py FLASH_BLOCK)
+FLASH_BLOCK = 1024
+#: rows of one pass of the dense layers' gated MLP in a prefill: its
+#: ``[gate | up]`` of a 16,384-row prompt at width 12,288 is 0.8 GB in
+#: bfloat16 whole
+DENSE_FFN_ROWS = 4096
+
+
+@dataclasses.dataclass(frozen=True)
+class SwaConfig:
+    # the fields the dense family's config has, under the same names
+    vocab_size: int = 25024
+    hidden: int = 3072
+    layers: int = 5
+    heads: int = 48
+    kv_heads: int = 8
+    head_dim: int = 128
+    intermediate: int = 12288        # the dense layers' gated MLP
+    rope_theta: float = 10000.0      # the window layers' alone
+    norm_eps: float = 1e-5
+    max_seq_len: int = 16384
+    dtype: Any = jnp.bfloat16
+    # the two kinds of attention layer
+    window: int = 4096               # a W layer's query i sees i - j < window
+    layer_kinds: str = "WWFWW"       # of the layers held: W window, F full
+    # FFN
+    dense_layers: int = 1            # leading layers with the gated MLP
+    moe_intermediate: int = 3072     # one routed expert's width
+    shared_intermediate: int = 3072  # num_shared_experts x moe_intermediate
+    experts: int = 256
+    experts_per_token: int = 4
+    routed_scale: float = 2.448      # route_scale, on the renormalised scores
+    router_dtype: Any = jnp.float32  # the logits'; lower only as a control
+    # this chip's share of the expert-parallel deployment
+    experts_held: int = 32
+    expert_first: int = 0
+    # what moe_mixer reads of a family
+    router: str = "sigmoid"
+    expert_act: str = "silu_gated"
+    #: recurrent state beside the pools: none
+    state_bytes_per_slot: int = 0
+
+    def __post_init__(self):
+        if len(self.layer_kinds) != self.layers or set(self.layer_kinds) - set("WF"):
+            raise ValueError(
+                f"layer_kinds {self.layer_kinds!r} names {self.layers} "
+                f"layers as W (window) or F (full)")
+        if not ("W" in self.layer_kinds and "F" in self.layer_kinds):
+            raise ValueError("at least one window and one full layer")
+        if not 0 < self.dense_layers < self.layers:
+            raise ValueError("at least one dense and one expert layer")
+        if not 0 <= self.expert_first <= self.experts - self.experts_held:
+            raise ValueError("the held experts lie outside the router's")
+
+    @classmethod
+    def trinity_large_preview_ep8(cls, max_seq_len: int = 16384) -> "SwaConfig":
+        """arcee-ai/Trinity-Large-Preview as one chip of the eight that share
+        each layer, rank 0 of the pipeline stage that holds layers 5-9 of 60:
+        the last dense layer (5, window) and one whole period of the expert
+        layers (6 window, 7 full, 8 and 9 window), experts 0-31 of 256 and
+        rows 0-25,023 of the 200,192 of the embedding and of the untied head
+        held here; attention, the dense MLP, the shared expert and the
+        router whole."""
+        return cls(max_seq_len=max_seq_len)
+
+    @classmethod
+    def tiny(cls, max_seq_len: int = 128, expert_first: int = 0,
+             experts_held: int = 4) -> "SwaConfig":
+        """Test size of the same grammar: one dense layer and a period of
+        four, a window of 32 rows, half of 8 experts held."""
+        return cls(
+            vocab_size=384, hidden=64, layers=5, heads=4, kv_heads=2,
+            head_dim=16, intermediate=96, window=32, layer_kinds="WWFWW",
+            dense_layers=1, moe_intermediate=32, shared_intermediate=32,
+            experts=8, experts_per_token=2, experts_held=experts_held,
+            expert_first=expert_first, max_seq_len=max_seq_len,
+        )
+
+    @property
+    def sparse_layers(self) -> int:
+        return self.layers - self.dense_layers
+
+    @property
+    def window_layers(self) -> int:
+        return self.layer_kinds.count("W")
+
+    @property
+    def full_layers(self) -> int:
+        return self.layer_kinds.count("F")
+
+    @property
+    def kind_index(self) -> tuple[int, ...]:
+        """A layer's index among the layers of its own kind: its row of its
+        kind's pool."""
+        seen = {"W": 0, "F": 0}
+        out = []
+        for kind in self.layer_kinds:
+            out.append(seen[kind])
+            seen[kind] += 1
+        return tuple(out)
+
+    def ring_blocks(self, block_size: int) -> int:
+        """Blocks of a slot's ring in the window kind's pool: the window's
+        rows and the block the newest rows are being written into."""
+        return -(-self.window // block_size) + 1
+
+
+# ---------------------------------------------------------------------------
+# parameters
+# ---------------------------------------------------------------------------
+
+
+def init_swa_params(config: SwaConfig, key: jax.Array | None = None) -> dict:
+    """Random parameters from a key, one jitted draw a leaf. An expert's
+    weights depend on its GLOBAL id and its layer, so the shares of one
+    deployment are slices of the same experts. The embedding is drawn at
+    ``1 / sqrt(hidden)`` so that the scaled embedding has a spread of 1, as
+    every sub-layer's normed output has: with a unit embedding the residual
+    would be the token's own row fifty times over whatever the layers add,
+    and no comparison of logits would see them. The gains of the query, key
+    and post norms and the router's selection bias are drawn away from
+    trivial values: a term left out changes the logits."""
+    c = config
+    key = key if key is not None else jax.random.PRNGKey(0)
+    H, D, names = c.hidden, c.head_dim, iter(range(10 ** 6))
+
+    def normal(shape, fan_in, dtype=None):
+        k = jax.random.fold_in(key, next(names))
+        scale = 1.0 / math.sqrt(fan_in)
+        return jax.jit(
+            lambda k: (jax.random.normal(k, shape, jnp.float32) * scale
+                       ).astype(dtype or c.dtype)
+        )(k)
+
+    def gain(shape):
+        k = jax.random.fold_in(key, next(names))
+        return jax.random.uniform(k, shape, jnp.float32, 0.5, 1.5).astype(c.dtype)
+
+    def experts(layer, shape, fan_in):
+        """(held,) + shape, expert e from (layer, global e)."""
+        k = jax.random.fold_in(jax.random.fold_in(key, next(names)), layer)
+        scale = 1.0 / math.sqrt(fan_in)
+        held = c.expert_first + jnp.arange(c.experts_held)
+        return jax.jit(jax.vmap(lambda e: (
+            jax.random.normal(jax.random.fold_in(k, e), shape, jnp.float32)
+            * scale).astype(c.dtype)))(held)
+
+    def attention():
+        return {
+            "norm": jnp.ones((H,), c.dtype),
+            "wq": normal((H, c.heads * D), H),
+            "wk": normal((H, c.kv_heads * D), H),
+            "wv": normal((H, c.kv_heads * D), H),
+            "wg": normal((H, c.heads * D), H),
+            "wo": normal((c.heads * D, H), c.heads * D),
+            "q_norm": gain((D,)),
+            "k_norm": gain((D,)),
+            "post_norm": gain((H,)),
+        }
+
+    I, Ie, Is = c.intermediate, c.moe_intermediate, c.shared_intermediate
+    layers = []
+    for layer in range(c.layers):
+        lp = {"attn": attention()}
+        if layer < c.dense_layers:
+            lp["ffn"] = {
+                "norm": jnp.ones((H,), c.dtype),
+                "w_up": normal((H, 2 * I), H),          # [gate | up]
+                "w_down": normal((I, H), I),
+                "post_norm": gain((H,)),
+            }
+        else:
+            kb = jax.random.fold_in(key, next(names))
+            lp["moe"] = {
+                "norm": jnp.ones((H,), c.dtype),
+                # the model's type; the logits are float32 (moe.py)
+                "router": normal((H, c.experts), H),
+                # small beside the spread of the scores, as a trained bias
+                # is: the scores decide the winners and the bias the ties
+                "bias": jax.random.uniform(
+                    kb, (c.experts,), jnp.float32, -0.02, 0.02),
+                # (held, 2 I, H) and (held, I, H), as the other families'
+                # gated experts (models/moe.py dropless_experts)
+                "w_up": experts(layer, (2 * Ie, H), H),
+                "w_down": experts(layer, (Ie, H), Ie),
+                "ws_up": normal((H, 2 * Is), H),
+                "ws_down": normal((Is, H), Is),
+                "post_norm": gain((H,)),
+            }
+        layers.append(lp)
+    return {
+        "embed": normal((c.vocab_size, H), H),
+        "final_norm": jnp.ones((H,), c.dtype),
+        "lm_head": normal((H, c.vocab_size), H),
+        "layers": layers,
+    }
+
+
+# ---------------------------------------------------------------------------
+# what both programs share
+# ---------------------------------------------------------------------------
+
+
+def _embed(c: SwaConfig, params: dict, tokens: jax.Array) -> jax.Array:
+    x = params["embed"][tokens]
+    return (x.astype(jnp.float32) * math.sqrt(c.hidden)).astype(x.dtype)
+
+
+def _logits(params: dict, x: jax.Array) -> jax.Array:
+    return (x @ params["lm_head"]).astype(jnp.float32)
+
+
+def _projections(c: SwaConfig, ap: dict, x: jax.Array, kind: str,
+                 positions: jax.Array):
+    """``(q (..., heads, D), k, v (..., kv_heads, D), gate (..., heads * D)
+    float32 logits)`` of a layer's normed input: each query and key head
+    normed, and rotated at ``positions`` where the layer attends a window."""
+    lead = x.shape[:-1]
+    with jax.named_scope("attn_qkv"):
+        h = _rms_norm(x, ap["norm"], c.norm_eps)
+        q = (h @ ap["wq"]).reshape(lead + (c.heads, c.head_dim))
+        k = (h @ ap["wk"]).reshape(lead + (c.kv_heads, c.head_dim))
+        v = (h @ ap["wv"]).reshape(lead + (c.kv_heads, c.head_dim))
+        gate = h @ ap["wg"]
+    with jax.named_scope("qk_norm"):
+        q = _rms_norm(q, ap["q_norm"], c.norm_eps)
+        k = _rms_norm(k, ap["k_norm"], c.norm_eps)
+    if kind == "W":
+        with jax.named_scope("rope"):
+            cos, sin = _rope(positions, c.head_dim, c.rope_theta)
+            q, k = _apply_rope(q, cos, sin), _apply_rope(k, cos, sin)
+    return q, k, v, gate
+
+
+def _attention_out(c: SwaConfig, ap: dict, x: jax.Array, out: jax.Array,
+                   gate: jax.Array) -> jax.Array:
+    """``x + N_post(W_o [out * sigmoid(gate)])``; ``out (..., heads * D)``."""
+    with jax.named_scope("attn_gate"):
+        out = out * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(out.dtype)
+    with jax.named_scope("attn_out"):
+        a = out @ ap["wo"]
+    with jax.named_scope("post_norm"):
+        return x + _rms_norm(a, ap["post_norm"], c.norm_eps)
+
+
+def _dense_ffn(c: SwaConfig, fp: dict, x: jax.Array) -> jax.Array:
+    """``x + N_post(W_down[silu(W_gate h) * W_up h])`` over rows ``x (T, H)``,
+    ``DENSE_FFN_ROWS`` at a time."""
+    with jax.named_scope("ffn"):
+        h = _rms_norm(x, fp["norm"], c.norm_eps)
+        one = lambda rows: silu_gated(rows @ fp["w_up"]) @ fp["w_down"]  # noqa: E731
+        T = h.shape[0]
+        if T > DENSE_FFN_ROWS and T % DENSE_FFN_ROWS == 0:
+            f = jax.lax.map(
+                one, h.reshape(T // DENSE_FFN_ROWS, DENSE_FFN_ROWS, -1)
+            ).reshape(T, -1)
+        else:
+            f = one(h)
+    with jax.named_scope("post_norm"):
+        return x + _rms_norm(f, fp["post_norm"], c.norm_eps)
+
+
+def _experts(c: SwaConfig, ep: dict, x: jax.Array, valid: jax.Array):
+    """``(x + N_post(experts(N_pre(x))), load, chosen)`` over rows ``x (T,
+    H)``."""
+    out, load, chosen = moe_mixer(
+        c, ep, _rms_norm(x, ep["norm"], c.norm_eps), valid)
+    with jax.named_scope("post_norm"):
+        return x + _rms_norm(out, ep["post_norm"], c.norm_eps), load, chosen
+
+
+def split_tables(block_tables: jax.Array):
+    """``(the full kind's columns, the window kind's)`` of the one table the
+    programs are handed (:class:`langstream_tpu.models.paged.BlockManager`)."""
+    width = block_tables.shape[1] // 2
+    return block_tables[:, :width], block_tables[:, width:]
+
+
+# ---------------------------------------------------------------------------
+# prefill
+# ---------------------------------------------------------------------------
+
+
+def swa_prefill_paged(
+    config: SwaConfig,
+    params: dict,
+    tokens: jax.Array,        # (B, P) int32, right-padded
+    lengths: jax.Array,       # (B,) true lengths
+    pool_k: jax.Array,        # (full layers, nb, bs, Kh*D)
+    pool_v: jax.Array,
+    wpool: dict,              # {"k", "v"}: (window layers, window nb, bs, Kh*D)
+    block_tables: jax.Array,  # (B, 2 x max_blocks): [full | window], THIS batch
+    use_flash: bool | None = None,
+):
+    """Prompt forward: every layer's K and V rows land in its kind's pool
+    through :func:`langstream_tpu.models.paged.write_rows`, a full layer's
+    all of them, a window layer's last ``window`` alone (the rows a later
+    query can still see; an earlier one's ring block would be overwritten by
+    a later one's within this very scatter). Returns ``(last-token logits
+    (B, V), pool_k, pool_v, wpool, routed)``; ``routed (expert layers, B, P,
+    k)`` are the experts the router chose, for the reference check (a caller
+    that drops it pays nothing for it)."""
+    c = config
+    B, Pn = tokens.shape
+    KhD = c.kv_heads * c.head_dim
+    G = c.heads // c.kv_heads
+    positions = jnp.arange(Pn)
+    real = positions[None, :] < lengths[:, None]                   # (B, P)
+    flash = (_flash_mode(Pn) if use_flash is None
+             else ("compiled" if use_flash else None))
+
+    def attend(q, k, v, kind):
+        window = c.window if kind == "W" else None
+        if flash is not None:
+            # the lengths spare the kernel the padding's blocks, the window
+            # the key blocks wholly behind it
+            from langstream_tpu.ops.flash_attention import flash_attention
+
+            return flash_attention(
+                q, k, v, causal=True, block_q=FLASH_BLOCK,
+                block_k=FLASH_BLOCK, interpret=(flash == "interpret"),
+                lengths=lengths, window=window)
+        qg = q.reshape(B, Pn, c.kv_heads, G, c.head_dim)
+        s = jnp.einsum("bqkgd,bskd->bkgqs", qg, k).astype(jnp.float32)
+        s = s / math.sqrt(c.head_dim)
+        behind = positions[:, None] - positions[None, :]           # i - j
+        mask = behind >= 0
+        if window is not None:
+            mask = mask & (behind < window)
+        mask = mask[None] & real[:, None, :]
+        s = jnp.where(mask[:, None, None], s, NEG_INF)
+        return jnp.einsum(
+            "bkgqs,bskd->bqkgd", jax.nn.softmax(s, -1).astype(v.dtype), v)
+
+    with jax.named_scope("embed"):
+        x = _embed(c, params, tokens)
+    rows = {"W": ([], []), "F": ([], [])}
+    routed = []
+    for layer, (lp, kind) in enumerate(zip(params["layers"], c.layer_kinds)):
+        ap = lp["attn"]
+        q, k, v, gate = _projections(c, ap, x, kind, positions)
+        with jax.named_scope("swa_flash" if kind == "W" else "full_flash"):
+            out = attend(q, k, v, kind)
+        x = _attention_out(
+            c, ap, x, out.reshape(B, Pn, c.heads * c.head_dim), gate)
+        rows[kind][0].append(k.reshape(B, Pn, KhD))
+        rows[kind][1].append(v.reshape(B, Pn, KhD))
+        if layer < c.dense_layers:
+            x = _dense_ffn(c, lp["ffn"], x.reshape(B * Pn, c.hidden)
+                           ).reshape(B, Pn, c.hidden)
+        else:
+            x, _, chosen = _experts(
+                c, lp["moe"], x.reshape(B * Pn, c.hidden), real.reshape(-1))
+            x = x.reshape(B, Pn, c.hidden)
+            routed.append(chosen.reshape(B, Pn, -1))
+    with jax.named_scope("lm_head"):
+        x = _rms_norm(x, params["final_norm"], c.norm_eps)
+        last = jnp.take_along_axis(
+            x, (lengths - 1)[:, None, None].clip(0), axis=1).squeeze(1)
+        logits = _logits(params, last)
+    full_tables, window_tables = split_tables(block_tables)
+    starts = jnp.zeros((B,), jnp.int32)
+    with jax.named_scope("kv_write"):
+        pool_k = write_rows(
+            pool_k, jnp.stack(rows["F"][0]), full_tables, starts, real)
+        pool_v = write_rows(
+            pool_v, jnp.stack(rows["F"][1]), full_tables, starts, real)
+    with jax.named_scope("swa_write"):
+        seen = real & (positions[None, :] >= (lengths - c.window)[:, None])
+        wpool = {
+            "k": write_rows(wpool["k"], jnp.stack(rows["W"][0]),
+                            window_tables, starts, seen),
+            "v": write_rows(wpool["v"], jnp.stack(rows["W"][1]),
+                            window_tables, starts, seen),
+        }
+    return logits, pool_k, pool_v, wpool, jnp.stack(routed)
+
+
+# ---------------------------------------------------------------------------
+# decode
+# ---------------------------------------------------------------------------
+
+
+def swa_decode_chunk_paged(
+    config: SwaConfig,
+    params: dict,
+    tokens0: jax.Array,       # (B,)
+    base_lengths: jax.Array,  # (B,)
+    active: jax.Array,        # (B,) bool
+    pool_k: jax.Array,        # read-only during the chunk
+    pool_v: jax.Array,
+    wpool: dict,
+    block_tables: jax.Array,  # (B, 2 x max_blocks): [full | window]
+    sample_fn: Callable,
+    key: jax.Array,
+    num_steps: int,
+    num_read_blocks: int,
+    kernel: str = "xla",      # "xla" | "pallas" | "pallas-interpret"
+    sample_extras=None,       # (presences, frequencies, counts0)
+    return_packed: bool = False,
+):
+    """K fused decode steps. Both pools are read-only and every layer's new
+    K and V rows gather in a chunk buffer (one scatter a pool at the end,
+    :func:`langstream_tpu.models.paged.write_rows`; a window layer's rows
+    overwrite, through the ring, rows that lay behind the window before the
+    chunk began). A window layer's read is told the first row its query
+    sees, ``length + step - (window - 1)``, and walks the blocks from there.
+
+    Returns ``(chunk_tokens, chunk_logprobs, final_tokens, final_lengths,
+    pool_k, pool_v, wpool, load, routed)`` where ``load (expert layers,
+    experts_held)`` counts the chosen pairs each held expert got over the
+    chunk's active rows and ``routed (steps, expert layers, B, k)`` are the
+    experts the router chose (for the reference check);
+    ``return_packed=True`` folds tokens, logprobs and ``load`` into one
+    int32 array in their place and leaves ``routed`` out."""
+    c = config
+    B = tokens0.shape[0]
+    KhD = c.kv_heads * c.head_dim
+    G = c.heads // c.kv_heads
+    scale = 1.0 / math.sqrt(c.head_dim)
+    adv = active.astype(jnp.int32)
+    pen = sample_extras is not None
+    counts0 = sample_extras[2] if pen else None
+    full_tables, window_tables = split_tables(block_tables)
+    ring = c.ring_blocks(pool_k.shape[2])
+    if num_steps > c.window:
+        raise ValueError("a chunk's own rows have to lie inside the window")
+
+    def cache_partial(q, kind, a, firsts):
+        """The pool's part of layer ``a`` of its kind, through the read the
+        engine selected; a window layer's from its first row."""
+        pk, pv, tables = ((wpool["k"], wpool["v"], window_tables)
+                          if kind == "W" else (pool_k, pool_v, full_tables))
+        if kernel == "xla":
+            return _cache_partial_xla(
+                c, q, pk, pv, a, tables, base_lengths,
+                min(ring, num_read_blocks) if kind == "W" else num_read_blocks,
+                firsts=firsts)
+        return paged_attention_partial(
+            q, pk, pv, a, tables, base_lengths,
+            num_read_blocks=num_read_blocks, kv_heads=c.kv_heads,
+            head_dim=c.head_dim, scale=scale,
+            interpret=(kernel == "pallas-interpret"), firsts=firsts)
+
+    def step(carry, step_idx):
+        tokens, kbufs, vbufs, key, load = carry[:5]
+        counts = carry[5] if pen else None
+        with jax.named_scope("sample"):
+            key, sub = jax.random.split(key)
+        with jax.named_scope("embed"):
+            x = _embed(c, params, tokens)
+        buf_mask = jnp.arange(num_steps)[None, :] <= step_idx      # (1, K)
+        positions = base_lengths + step_idx
+        firsts = jnp.maximum(positions - (c.window - 1), 0)
+        kbufs, vbufs, loads, chose = list(kbufs), list(vbufs), [], []
+        for layer, (lp, kind) in enumerate(zip(params["layers"], c.layer_kinds)):
+            ap = lp["attn"]
+            q, k, v, gate = _projections(c, ap, x, kind, positions)
+            with jax.named_scope("attn_qkv"):
+                # this layer's rows of the chunk so far, with this step's
+                kb = kbufs[layer] = jax.lax.dynamic_update_slice_in_dim(
+                    kbufs[layer], k[:, None], step_idx, axis=1)    # (B,K,Kh,D)
+                vb = vbufs[layer] = jax.lax.dynamic_update_slice_in_dim(
+                    vbufs[layer], v[:, None], step_idx, axis=1)
+            with jax.named_scope("swa_read" if kind == "W" else "full_read"):
+                part_c = cache_partial(
+                    q, kind, c.kind_index[layer],
+                    firsts if kind == "W" else None)
+            with jax.named_scope("attn_buf"):
+                # the chunk's own rows: all inside any window (K <= window)
+                qg = q.reshape(B, c.kv_heads, G, c.head_dim)
+                s = jnp.einsum("bkgd,btkd->bkgt", qg, kb).astype(jnp.float32)
+                s = jnp.where(buf_mask[:, None, None, :], s * scale, NEG_INF)
+                m_b = jnp.max(s, axis=-1)
+                p_b = jnp.where(
+                    buf_mask[:, None, None, :], jnp.exp(s - m_b[..., None]), 0.0)
+                acc_b = jnp.einsum(
+                    "bkgt,btkd->bkgd", p_b.astype(vb.dtype), vb
+                ).astype(jnp.float32)
+                out = merge_partial_attention([
+                    part_c,
+                    (acc_b.reshape(B, c.heads, c.head_dim),
+                     m_b.reshape(B, c.heads),
+                     jnp.sum(p_b, axis=-1).reshape(B, c.heads)),
+                ]).astype(x.dtype).reshape(B, c.heads * c.head_dim)
+            x = _attention_out(c, ap, x, out, gate)
+            if layer < c.dense_layers:
+                x = _dense_ffn(c, lp["ffn"], x)
+            else:
+                x, load_i, chosen = _experts(c, lp["moe"], x, active)
+                loads.append(load_i)
+                chose.append(chosen)
+        with jax.named_scope("lm_head"):
+            logits = _logits(
+                params, _rms_norm(x, params["final_norm"], c.norm_eps))
+        with jax.named_scope("sample"):
+            nxt, lp_ = (sample_fn(logits, sub, counts) if pen
+                        else sample_fn(logits, sub))
+            nxt = jnp.where(active, nxt, tokens)
+        out_carry = (nxt, tuple(kbufs), tuple(vbufs), key,
+                     load + jnp.stack(loads))
+        if pen:
+            out_carry += (counts.at[jnp.arange(B), nxt].add(adv),)
+        return out_carry, (nxt, lp_, jnp.stack(chose))
+
+    buf0 = tuple(jnp.zeros((B, num_steps, c.kv_heads, c.head_dim), c.dtype)
+                 for _ in range(c.layers))
+    carry0 = (tokens0, buf0, buf0, key,
+              jnp.zeros((c.sparse_layers, c.experts_held), jnp.int32))
+    if pen:
+        carry0 += (counts0,)
+    out_carry, (chunk_tokens, chunk_lps, routed) = jax.lax.scan(
+        step, carry0, jnp.arange(num_steps))
+    final_tokens, kbufs, vbufs, _, load = out_carry[:5]
+    valid = jnp.broadcast_to(active[:, None], (B, num_steps))
+
+    def of_kind(bufs, kind):
+        return jnp.stack([
+            buf.reshape(B, num_steps, KhD)
+            for buf, k in zip(bufs, c.layer_kinds) if k == kind])
+
+    with jax.named_scope("kv_write"):
+        pool_k = write_rows(
+            pool_k, of_kind(kbufs, "F"), full_tables, base_lengths, valid)
+        pool_v = write_rows(
+            pool_v, of_kind(vbufs, "F"), full_tables, base_lengths, valid)
+    with jax.named_scope("swa_write"):
+        wpool = {
+            "k": write_rows(wpool["k"], of_kind(kbufs, "W"), window_tables,
+                            base_lengths, valid),
+            "v": write_rows(wpool["v"], of_kind(vbufs, "W"), window_tables,
+                            base_lengths, valid),
+        }
+    final_lengths = base_lengths + num_steps * adv
+    if return_packed:
+        packed = jnp.concatenate(
+            [pack_tokens_logprobs(chunk_tokens, chunk_lps), load.reshape(-1)])
+        return packed, final_tokens, final_lengths, pool_k, pool_v, wpool
+    return (chunk_tokens, chunk_lps, final_tokens, final_lengths, pool_k,
+            pool_v, wpool, load, routed)

@@ -200,11 +200,15 @@ def _flash_ragged_kernel(
     scale: float,
     block_q: int,
     block_k: int,
+    window: int | None = None,
 ):
     """Causal self-attention of right-padded rows whose true lengths are
     known: the blocks past a row's length, of queries and of keys, are not
     computed (their queries' output is zeros), and only a block the diagonal
-    crosses pays for the mask."""
+    crosses pays for the mask. With ``window`` a query ``i`` sees the keys
+    ``j`` with ``0 <= i - j < window``: a key block wholly behind the window
+    of the query block's first row is skipped like one in the future, and a
+    block the window's edge crosses is masked like one the diagonal crosses."""
     b, qi, ki = pl.program_id(0), pl.program_id(2), pl.program_id(3)
     num_k = pl.num_programs(3)
     length = lengths_ref[b]
@@ -216,6 +220,13 @@ def _flash_ragged_kernel(
     live = jnp.logical_and(q_start < length, k_start < length)
     below = k_start + block_k - 1 <= q_start   # every column under every row
     reached = k_start <= q_start + block_q - 1
+    if window is not None:
+        # some key of the block within the first (furthest-seeing) row's
+        # window; every key within the last row's
+        reached = jnp.logical_and(
+            reached, k_start + block_k - 1 > q_start - window)
+        below = jnp.logical_and(
+            below, q_start + block_q - 1 - k_start < window)
 
     def scores():
         return jax.lax.dot_general(
@@ -233,8 +244,11 @@ def _flash_ragged_kernel(
             jnp.int32, (block_q, block_k), 0)
         cols = k_start + jax.lax.broadcasted_iota(
             jnp.int32, (block_q, block_k), 1)
-        _online_update(scores(), rows >= cols, v_ref[0, 0], m_ref, l_ref,
-                       acc_ref)
+        s = scores()
+        mask = rows >= cols
+        if window is not None:
+            mask = mask & (rows - cols < window)
+        _online_update(s, mask, v_ref[0, 0], m_ref, l_ref, acc_ref)
 
     pl.when(ki == num_k - 1)(lambda: _write_out(o_ref, l_ref, acc_ref))
 
@@ -249,12 +263,14 @@ def _flash_bhsd_ragged(
     block_q: int,
     block_k: int,
     interpret: bool,
+    window: int | None = None,
 ) -> jax.Array:
     B, H, S, D = q.shape
     Kh, Dv = k.shape[1], v.shape[3]
     group = H // Kh
     kernel = functools.partial(
-        _flash_ragged_kernel, scale=scale, block_q=block_q, block_k=block_k)
+        _flash_ragged_kernel, scale=scale, block_q=block_q, block_k=block_k,
+        **({} if window is None else {"window": window}))
 
     def last_q(b, lengths):
         return jnp.maximum(pl.cdiv(lengths[b], block_q) - 1, 0)
@@ -267,6 +283,10 @@ def _flash_bhsd_ragged(
     def kv_index(b, h, qi, ki, lengths):
         q_row = jnp.minimum(qi, last_q(b, lengths)) * block_q + block_q - 1
         last = jnp.minimum(q_row, jnp.maximum(lengths[b] - 1, 0)) // block_k
+        if window is not None:
+            # nor is a block behind the window of the block's first row
+            first = jnp.maximum(q_row - block_q + 2 - window, 0) // block_k
+            return (b, h // group, jnp.clip(ki, first, last), 0)
         return (b, h // group, jnp.minimum(ki, last), 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -311,6 +331,7 @@ def flash_attention(
     interpret: bool = False,
     mesh=None,  # jax.sharding.Mesh: run the kernel per-shard via shard_map
     lengths: jax.Array | None = None,  # (B,) true rows of right-padded rows
+    window: int | None = None,  # query i sees keys j with 0 <= i - j < window
 ) -> jax.Array:
     """Flash attention over ``(batch, seq, heads, head_dim)`` tensors.
 
@@ -329,7 +350,9 @@ def flash_attention(
     nor computes the blocks past it, and masks only the blocks the diagonal
     crosses; a padded query's output is zeros where it lies in a block of
     padding alone, and as unread as ever otherwise. Without it the call is
-    what it was.
+    what it was. ``window`` (with ``lengths``) narrows the causal mask to the
+    last ``window`` keys of each query and skips the key blocks wholly behind
+    it, which are neither fetched nor computed.
     """
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
@@ -369,6 +392,8 @@ def flash_attention(
         raise ValueError(
             "flash attention takes lengths for causal self-attention on one "
             "device only")
+    if window is not None and lengths is None:
+        raise ValueError("flash attention takes a window with lengths only")
     B, Sq, H, D = q.shape
     Sk = k.shape[1]
     if causal and Sq != Sk:
@@ -391,7 +416,8 @@ def flash_attention(
     if lengths is not None:
         out = _flash_bhsd_ragged(
             qt, kt, vt, lengths, scale=scale, block_q=block_q,
-            block_k=block_k, interpret=interpret)
+            block_k=block_k, interpret=interpret,
+            **({} if window is None else {"window": window}))
     else:
         out = _flash_bhsd(
             qt, kt, vt,

@@ -73,6 +73,7 @@ def _live_tiles(
     block_size: int,
     tile_blocks: int,
     num_read_blocks: int,
+    firsts_ref=None,  # SMEM (B,) int32: the first row a slot's query sees
 ):
     """The walk over a slot's live blocks that the single-query reads share
     (:func:`_paged_read_kernel` over a K and a V pool, :func:`_latent_read_
@@ -84,7 +85,10 @@ def _live_tiles(
     tile at step 0 and returns ``(length, sweep)``: the slot's rows and
     ``sweep(compute, carry)``, which runs ``carry = compute(buf, start,
     carry)`` a tile in order, ``buf`` the buffer the tile's rows from
-    ``start`` lie in."""
+    ``start`` lie in. With ``firsts_ref`` (a layer that attends a window) the
+    walk starts at the block that holds a slot's first row, not at block 0:
+    the table columns before it are never visited, and the caller masks the
+    rows of that block that lie before the first."""
     b = pl.program_id(0)
     B = pl.num_programs(0)
     bs, T = block_size, tile_blocks
@@ -94,14 +98,22 @@ def _live_tiles(
     def rows_of(slot):
         return jnp.minimum(lengths_ref[slot], num_read_blocks * bs)
 
+    def first_block(slot):
+        return firsts_ref[slot] // bs
+
     def for_live_blocks(slot, t, buf, act):
         """``act`` on every pool's copy of each live block of tile ``t``
         of ``slot``; none for the table columns past the slot's length."""
         n = pl.cdiv(rows_of(slot), bs)
+
+        def column(j):
+            return (t * T + j if firsts_ref is None
+                    else first_block(slot) + t * T + j)
+
         for j in range(T):
-            @pl.when(t * T + j < n)
+            @pl.when(column(j) < n)
             def _():
-                blk = tables_ref[slot, t * T + j]
+                blk = tables_ref[slot, column(j)]
                 for i, (pool, tile) in enumerate(zip(pools, tiles)):
                     act(pltpu.make_async_copy(
                         pool.at[layer, blk],
@@ -128,7 +140,12 @@ def _live_tiles(
             for_live_blocks(first, 0, 0, lambda c: c.start())
 
     length = rows_of(b)
-    num_tiles = pl.cdiv(pl.cdiv(length, bs), T)
+    if firsts_ref is None:
+        num_tiles = pl.cdiv(pl.cdiv(length, bs), T)
+    else:
+        num_tiles = pl.cdiv(
+            jnp.maximum(pl.cdiv(length, bs) - first_block(b), 0), T)
+        row0 = first_block(b) * bs
     after = next_live(b)
 
     def sweep(compute, carry):
@@ -147,7 +164,9 @@ def _live_tiles(
                 )
 
             for_live_blocks(b, t, buf, lambda c: c.wait())
-            carry = compute(buf, t * rows_t, carry)
+            carry = compute(
+                buf, t * rows_t if firsts_ref is None else row0 + t * rows_t,
+                carry)
             buf_ref[0] = 1 - buf
             return carry
 
@@ -156,14 +175,16 @@ def _live_tiles(
     return length, sweep
 
 
-def _live_masks(start, rows_t: int, length):
-    """``(live (1, rows), live_rows (rows, 1))`` of a tile from ``start``."""
-    live = start + jax.lax.broadcasted_iota(
-        jnp.int32, (1, rows_t), 1
-    ) < length
-    live_rows = start + jax.lax.broadcasted_iota(
-        jnp.int32, (rows_t, 1), 0
-    ) < length
+def _live_masks(start, rows_t: int, length, first=None):
+    """``(live (1, rows), live_rows (rows, 1))`` of a tile from ``start``:
+    the rows under ``length`` and, with ``first``, not before it."""
+    across = start + jax.lax.broadcasted_iota(jnp.int32, (1, rows_t), 1)
+    live = across < length
+    down = start + jax.lax.broadcasted_iota(jnp.int32, (rows_t, 1), 0)
+    live_rows = down < length
+    if first is not None:
+        live = live & (across >= first)
+        live_rows = live_rows & (down >= first)
     return live, live_rows
 
 
@@ -200,6 +221,7 @@ def _paged_read_kernel(
     num_read_blocks: int,
     kv_heads: int,
     head_dim: int,
+    firsts_ref=None,
 ):
     _, H, D = q_ref.shape
     G = H // kv_heads
@@ -208,7 +230,9 @@ def _paged_read_kernel(
         layer_ref, tables_ref, lengths_ref, (k_hbm, v_hbm), (k_tile, v_tile),
         sems, buf_ref, block_size=block_size, tile_blocks=tile_blocks,
         num_read_blocks=num_read_blocks,
+        **({} if firsts_ref is None else {"firsts_ref": firsts_ref}),
     )
+    first = None if firsts_ref is None else firsts_ref[pl.program_id(0)]
     q = q_ref[0]                                       # (H, D)
 
     def head(tile, buf, kh):
@@ -220,7 +244,7 @@ def _paged_read_kernel(
 
     def compute(buf, start, carry):
         m_prev, l_prev, acc = carry                    # (H,1) (H,1) (H,D)
-        live, live_rows = _live_masks(start, rows_t, length)
+        live, live_rows = _live_masks(start, rows_t, length, first)
         s = jnp.concatenate([
             jax.lax.dot_general(
                 q[kh * G:(kh + 1) * G], head(k_tile, buf, kh),
@@ -439,6 +463,7 @@ def paged_attention_partial(
     head_dim: int,
     scale: float | None = None,
     interpret: bool = False,
+    firsts: jax.Array | None = None,  # (B,) int32: the first row a slot sees
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Partial (unnormalised) paged attention over the cache segment.
 
@@ -446,13 +471,18 @@ def paged_attention_partial(
     to merge with other segments via :func:`merge_partial_attention`.
 
     The pool is the layer-stacked one, read in place: the kernel fetches
-    ``pool[layer, table[b, j]]`` for the live ``j`` only.
+    ``pool[layer, table[b, j]]`` for the live ``j`` only. With ``firsts``
+    (a layer that attends a window of its last rows) a slot's query sees the
+    rows ``[firsts[b], lengths[b])``: the walk starts at the block that holds
+    the first and the rows of it before the first are masked.
 
     int8 pools (``{"q": int8, "s": f32}`` dicts) still read through the
     static-grid twin on a slice of the layer: their ``(bs, Kh)`` scale rows
     are 8 lanes wide, and Mosaic refuses a hand-made copy of them ("slice
     shape must be aligned to tiling (128)"); see ROADMAP S3.
     """
+    if isinstance(k_pool, dict) and firsts is not None:
+        raise ValueError("the int8 pool's read takes no first row")
     if isinstance(k_pool, dict):
         at_layer = lambda a: jax.lax.dynamic_index_in_dim(  # noqa: E731
             a, layer, keepdims=False
@@ -476,12 +506,20 @@ def paged_attention_partial(
         scale=scale, block_size=bs, tile_blocks=tile_blocks,
         num_read_blocks=num_read_blocks, kv_heads=kv_heads, head_dim=head_dim,
     )
+    prefetched = (
+        jnp.asarray(layer, jnp.int32).reshape(1), block_tables, lengths)
+    if firsts is not None:
+        # a fourth prefetched scalar array, handed on under its name
+        inner = kernel
+        kernel = lambda layer, tables, lengths, firsts, *refs: inner(  # noqa: E731
+            layer, tables, lengths, *refs, firsts_ref=firsts)
+        prefetched += (firsts.astype(jnp.int32),)
     per_slot = lambda shape: pl.BlockSpec(  # noqa: E731
-        shape, lambda b, layer, tables, lengths: (b, 0, 0)
+        shape, lambda b, *prefetched: (b, 0, 0)
     )
     tile = pltpu.VMEM((2, tile_blocks * bs, KhD), k_pool.dtype)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=len(prefetched),
         grid=(B,),
         in_specs=[
             per_slot((1, H, D)),
@@ -511,10 +549,7 @@ def paged_attention_partial(
         ),
         interpret=interpret,
         name="paged_read",
-    )(
-        jnp.asarray(layer, jnp.int32).reshape(1), block_tables, lengths,
-        q, k_pool, v_pool,
-    )
+    )(*prefetched, q, k_pool, v_pool)
     return acc, m[:, :, 0], l[:, :, 0]
 
 

@@ -149,7 +149,10 @@ _MOE_MODELS = ("moe-tiny", "moe-8x7b", "mixtral-8x7b")
 # mixers + attention + routed experts, the nemotron_h, granitemoehybrid and
 # solar_open2 layers) keeps a per-slot recurrent state beside a K/V pool; "latent" (models/latent.py
 # LatentConfig: multi-head latent attention + group-limited routed experts,
-# the deepseek_v2 layer) keeps ONE pool whose row is a compressed latent.
+# the deepseek_v2 layer) keeps ONE pool whose row is a compressed latent; "swa"
+# (models/swa.py SwaConfig: window and full attention layers in one stack +
+# sigmoid-routed experts, the afmoe layer) keeps a pool a layer kind, the
+# window kind's as a ring of blocks a slot.
 _FAMILY_MODELS = {
     "hybrid-tiny": ("hybrid", "tiny"),
     "nemotron-3-nano-30b-a3b-ep8": ("hybrid", "nemotron3_nano_ep8"),
@@ -159,6 +162,8 @@ _FAMILY_MODELS = {
     "solar-open2-250b-ep8": ("hybrid", "solar_open2_ep8"),
     "deepseek-tiny": ("latent", "tiny"),
     "deepseek-v2-ep8": ("latent", "deepseek_v2_ep8"),
+    "trinity-tiny": ("swa", "tiny"),
+    "trinity-large-preview-ep8": ("swa", "trinity_large_preview_ep8"),
 }
 
 
@@ -169,6 +174,10 @@ def _family_config_class(family: str):
         from langstream_tpu.models.hybrid import HybridConfig
 
         return HybridConfig
+    if family == "swa":
+        from langstream_tpu.models.swa import SwaConfig
+
+        return SwaConfig
     from langstream_tpu.models.latent import LatentConfig
 
     return LatentConfig
@@ -1644,6 +1653,10 @@ class TpuServingEngine:
                 from langstream_tpu.models.hybrid import (
                     init_hybrid_params as init_params,
                 )
+            elif self.family == "swa":
+                from langstream_tpu.models.swa import (
+                    init_swa_params as init_params,
+                )
             else:
                 from langstream_tpu.models.latent import (
                     init_latent_params as init_params,
@@ -1774,13 +1787,37 @@ class TpuServingEngine:
             hbm_fraction_of_dense=self.config.kv_pool_fraction,
             num_blocks=self.config.kv_pool_blocks,
         )
+        window_kind = {}
+        if self.family == "swa":
+            # a second pool for the layers that attend a window: a ring of
+            # window / block_size + 1 blocks a slot, whatever its length,
+            # and room for every slot's (models/paged.py BlockManager)
+            ring = mc.ring_blocks(self.paged_layout.block_size)
+            self.window_layout = PagedLayout(
+                block_size=self.paged_layout.block_size,
+                num_blocks=self.config.slots * ring + 1,
+                max_blocks_per_slot=self.paged_layout.max_blocks_per_slot,
+            )
+            window_kind = dict(
+                window_layout=self.window_layout, window_ring=ring)
         self.block_mgr = BlockManager(
             self.paged_layout, self.config.slots,
             state_bytes_per_slot=(
                 mc.state_bytes_per_slot if self.is_hybrid else 0
             ),
+            **window_kind,
         )
-        if self.is_hybrid:
+        if self.family == "swa":
+            from langstream_tpu.models.paged import init_kv_pool
+
+            # the full layers' pool where every family's K and V pools are,
+            # the window layers' behind them where the hybrid family's
+            # recurrent state is: donated and re-bound with the caches
+            init_cache = partial(
+                init_kv_pool, mc, self.paged_layout, mc.full_layers)
+            init_state = lambda: dict(zip("kv", init_kv_pool(  # noqa: E731
+                mc, self.window_layout, mc.window_layers)))
+        elif self.is_hybrid:
             from langstream_tpu.models.hybrid import (
                 init_hybrid_pool,
                 init_hybrid_state,
@@ -2036,6 +2073,27 @@ class TpuServingEngine:
 
                 return _decode_chunk
 
+            if self.family == "swa":
+                @partial(jax.jit, donate_argnums=(1, 2, 3))
+                def _decode_chunk(params, cache_k, cache_v, wpool, tokens,
+                                  lengths, active, tables, key, temps, topks,
+                                  topps, pres=None, freq=None, counts=None):
+                    from langstream_tpu.models.swa import (
+                        swa_decode_chunk_paged,
+                    )
+
+                    return swa_decode_chunk_paged(
+                        mc_static, params, tokens, lengths, active,
+                        cache_k, cache_v, wpool, tables,
+                        _sample_fn_for(temps, topks, topps, pres, freq),
+                        key, K, num_read_blocks=window,
+                        kernel=self.paged_read_kernel,
+                        sample_extras=_extras(pres, freq, counts),
+                        return_packed=True,
+                    )
+
+                return _decode_chunk
+
             if self.family == "latent":
                 # the dense family's signature (no state rides behind the
                 # caches); cache_v is None, as init_latent_pool left it
@@ -2131,6 +2189,25 @@ class TpuServingEngine:
                             use_top_k=use_top_k, all_greedy=all_greedy,
                         )
                     return next_tokens, logprobs, ck, cv, st
+
+                return _prefill
+
+            if self.family == "swa":
+                @partial(jax.jit, donate_argnums=(1, 2, 3))
+                def _prefill(params, cache_k, cache_v, wpool, tokens,
+                             lengths, tables, key, temps, topks, topps):
+                    from langstream_tpu.models.swa import swa_prefill_paged
+
+                    logits, ck, cv, wp, _routed = swa_prefill_paged(
+                        mc_static, params, tokens, lengths, cache_k, cache_v,
+                        wpool, tables, use_flash=prefill_flash)
+                    with jax.named_scope("sample"):
+                        next_tokens, logprobs = sample_tokens(
+                            logits, key, temps, topks,
+                            use_top_p=use_top_p, top_ps=topps,
+                            use_top_k=use_top_k, all_greedy=all_greedy,
+                        )
+                    return next_tokens, logprobs, ck, cv, wp
 
                 return _prefill
 
@@ -2303,6 +2380,26 @@ class TpuServingEngine:
                 "kv-quantize": "the hybrid programs read a bf16 pool only",
                 "journal-dir": "journal replay re-admits by K/V-era rules "
                                "untested beside recurrent state",
+            }),
+            "swa": ("keeps a second pool for its window layers, a ring of "
+                    "blocks a slot", {
+                "prefix-cache": "a window layer's cached block is "
+                                "overwritten once its slot grows a ring "
+                                "past it and is not reusable past the "
+                                "window; set prefix-cache: false",
+                "prefill-chunk": "no continuation prefill over two kinds "
+                                 "of history yet; set prefill-chunk: 0",
+                "speculative-drafts": "the verify step reads one K/V pool "
+                                      "through the multi-query kernel, "
+                                      "which knows no window; set "
+                                      "speculative-drafts: 0",
+                "pool-role": "the handoff's payload carries one pool's "
+                             "blocks, not a ring's; use pool-role: "
+                             "combined",
+                "kv-quantize": "the window read takes a first row, which "
+                               "the int8 pool's read does not",
+                "journal-dir": "journal replay re-admits by K/V-era rules "
+                               "untested over two kinds of pool",
             }),
             "latent": ("keeps one pool of latent rows, not K and V", {
                 "prefix-cache": "no continuation prefill over a latent "
@@ -2585,6 +2682,7 @@ class TpuServingEngine:
         ahead: int | None = None,
         prompt_tokens: int | None = None,
         clock: dict | None = None,
+        pool_rows: dict | None = None,
     ) -> None:
         """One flight sample per dispatched burst, plus its Prometheus
         mirrors. ``program``, ``dispatch``, ``steps``,
@@ -2640,6 +2738,7 @@ class TpuServingEngine:
             ahead=ahead,
             prompt_tokens=prompt_tokens,
             clock=clock,
+            pool_rows=pool_rows,
         )
         # watchdog heartbeat: a recorded dispatch IS step progress
         self.watchdog.beat(sample["queue_depth"])
@@ -2677,14 +2776,15 @@ class TpuServingEngine:
 
     def _ticket(
         self, program: str, steps: int, active: int,
-        live_rows: int | None = None,
+        live_rows: int | None = None, pool_rows: dict | None = None,
     ) -> dict:
         """What a dispatch knows when it is made and its flight sample,
         recorded when the result is processed, no longer does: the program
         variant, the dispatch's ordinal (the ``seq`` of its host spans),
         the decode steps it fuses (0 for a prefill), the slots running and,
         for a decode chunk, the rows its read has to fetch
-        (:meth:`_read_rows`). ``clock`` starts empty:
+        (:meth:`_read_rows`; a model with a pool a layer kind adds
+        :meth:`_pool_rows`). ``clock`` starts empty:
         the dispatch thread stamps it (``flight.clock``) and the loop adds
         its resume lag after each await (``flight.resumed``).
         Made on the loop thread; rides to :meth:`_flight_record` as
@@ -2694,6 +2794,34 @@ class TpuServingEngine:
             "program": program, "dispatch": self._dispatch_seq,
             "steps": steps, "active_at_dispatch": active,
             "live_rows": live_rows, "clock": {},
+            **({} if pool_rows is None else {"pool_rows": pool_rows}),
+        }
+
+    def _pool_rows(self, active: list[int], ahead: int) -> dict | None:
+        """What a model with a pool a layer kind (models/swa.py) adds to a
+        decode chunk's flight sample: ``window_rows``, the rows a step reads
+        of each WINDOW layer's pool (a slot's last ``window`` at most, where
+        ``live_rows`` counts the full layers' whole history);
+        ``pool_rows_held``, the rows both kinds hold for the running slots
+        over all layers, in whole blocks; ``pool_rows_one_table``, what ONE
+        table for all layers would hold for them (every layer every block);
+        and ``window_slot_blocks_max``, the most window blocks any slot
+        holds (never more than the ring). None for every other family."""
+        if self.family != "swa":
+            return None
+        mc, bs = self.model_config, self.paged_layout.block_size
+        rows = self._lengths.astype(np.int64)
+        rows[active] += ahead
+        rows = rows[active]
+        blocks = -(-rows // bs)
+        ring = self.block_mgr.window_ring
+        held = (mc.full_layers * blocks
+                + mc.window_layers * np.minimum(blocks, ring)).sum() * bs
+        return {
+            "window_rows": int(np.minimum(rows, mc.window).sum()),
+            "pool_rows_held": int(held),
+            "pool_rows_one_table": int(blocks.sum() * bs * mc.layers),
+            "window_slot_blocks_max": self.block_mgr.window_slot_blocks_max,
         }
 
     def _read_rows(self, active: list[int], ahead: int, window: int) -> int:
@@ -3116,11 +3244,13 @@ class TpuServingEngine:
         1024 rows (excess <128 rows/slot where most serving lengths live),
         powers of two beyond (a long-context engine would otherwise compile
         a fresh ~30s decode variant every 128 generated tokens)."""
-        if self.family == "latent":
+        if self.family in ("latent", "swa"):
             # one decode program a chunk size: the latent read fetches a
             # slot's live blocks and nothing else, whatever the window, and
             # a slot of this family is long (a window bucket every power of
-            # two would be four more programs of its five-layer step)
+            # two would be four more programs of its five-layer step); the
+            # swa family's reads walk live blocks too, its window layers'
+            # from their first row
             return self.paged_layout.max_blocks_per_slot
         if max_len <= 1024:
             window = max(128, -(-max_len // 128) * 128)
@@ -6516,6 +6646,7 @@ class TpuServingEngine:
                 self._program_decode(window, K, sampler_mode, pen),
                 K, len(active),
                 self._read_rows(active, pending * K, window),
+                self._pool_rows(active, pending * K),
             )
             prog_q.append(ticket)
             counts_np = _build_counts() if pen else None

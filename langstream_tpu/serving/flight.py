@@ -350,6 +350,7 @@ class FlightRecorder:
         ahead: int | None = None,
         prompt_tokens: int | None = None,
         clock: dict | None = None,
+        pool_rows: dict | None = None,
     ) -> dict[str, Any]:
         """Record one dispatched burst. ``wall`` is the time since the
         previous boundary. ``overlapped_s`` is host work the pipelined
@@ -380,6 +381,10 @@ class FlightRecorder:
         its ``device_s`` is what was left of the program when the host came
         to wait for it, not the program's run time. ``prompt_tokens`` (a
         prefill batch only) are the true tokens its rows prefilled.
+        ``pool_rows`` (a decode chunk of a model with a pool a layer kind,
+        models/swa.py) joins the sample key by key: ``window_rows``,
+        ``pool_rows_held``, ``pool_rows_one_table``,
+        ``window_slot_blocks_max`` (engine.py ``_pool_rows``).
         ``clock`` is the dispatch's times as :class:`DispatchClock` and
         :func:`resumed` left them: the sample's ``gap_ms``, ``program_ms``
         and ``resume_lag_ms``, each omitted where it was not taken."""
@@ -433,6 +438,8 @@ class FlightRecorder:
             self.prefill_rows += tokens
         if prompt_tokens is not None:
             entry["prompt_tokens"] = prompt_tokens
+        if pool_rows is not None:
+            entry.update(pool_rows)
         if clock:
             if "program_ms" in clock:
                 entry["gap_ms"] = round(clock["gap_ms"], 3)

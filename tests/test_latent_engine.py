@@ -207,7 +207,7 @@ def test_every_option_that_assumes_history_is_kv_is_refused_by_name(option):
 def test_one_table_resolves_both_families_names():
     assert _FAMILY_MODELS["deepseek-tiny"] == ("latent", "tiny")
     assert _FAMILY_MODELS["deepseek-v2-ep8"] == ("latent", "deepseek_v2_ep8")
-    assert {f for f, _ in _FAMILY_MODELS.values()} == {"hybrid", "latent"}
+    assert {f for f, _ in _FAMILY_MODELS.values()} == {"hybrid", "latent", "swa"}
     with pytest.raises(ValueError) as e:
         _resolve_model_config("no-such-model", 128)
     assert "deepseek-v2-ep8" in str(e.value) and "hybrid-tiny" in str(e.value)
