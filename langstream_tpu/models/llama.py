@@ -589,7 +589,8 @@ def llama_decode_chunk(
 
     HBM discipline: the big cache is consumed read-only (no per-step
     rematerialisation); each step's new K/V lands in a small chunk buffer
-    ``(L, B, num_steps, Kh, D)`` carried through the step scan; a single
+    ``(L, B, num_steps, Kh, D)`` carried through the step scan AND the layer
+    scan, which writes a step's rows of one layer into it in place; a single
     commit writes the buffer back into the cache at the end. Attention spans
     [cache rows < base_len] ∪ [buffer rows ≤ step]. Per-step HBM traffic is
     params + cache *read* only — the difference between ~1k and ~10k tok/s.
@@ -632,8 +633,11 @@ def llama_decode_chunk(
             cos, sin = _rope(positions, c.head_dim, c.rope_theta)
             buf_mask = (jnp.arange(num_steps)[None, :] <= step_idx)  # (1, K)
 
-        def layer(x, layer_in):
-            lp, ck_l, cv_l, kbuf_l, vbuf_l = layer_in
+        def layer(carry, layer_in):
+            # the chunk buffer rides the carry and takes the step's rows in
+            # place (as xs/ys the stacked ys is a new array every step)
+            x, kbuf, vbuf = carry
+            lp, ck_l, cv_l, kv_l = layer_in
             with jax.named_scope("attn_qkv"):
                 h = _rms_norm(x, lp["attn_norm"], c.norm_eps)
                 q = (h @ _w(lp["wq"])).reshape(B, c.heads, c.head_dim)
@@ -641,12 +645,16 @@ def llama_decode_chunk(
                 v = (h @ _w(lp["wv"])).reshape(B, c.kv_heads, c.head_dim)
                 q = _apply_rope(q, cos, sin)
                 k = _apply_rope(k, cos, sin)
-                kbuf_l = jax.lax.dynamic_update_slice_in_dim(
-                    kbuf_l, k[:, None], step_idx, axis=1
+                kbuf = jax.lax.dynamic_update_slice(
+                    kbuf, k[None, :, None], (kv_l, 0, step_idx, 0, 0)
                 )
-                vbuf_l = jax.lax.dynamic_update_slice_in_dim(
-                    vbuf_l, v[:, None], step_idx, axis=1
+                vbuf = jax.lax.dynamic_update_slice(
+                    vbuf, v[None, :, None], (kv_l, 0, step_idx, 0, 0)
                 )
+                # the layer's slice, read after the write: the step's own
+                # row is in it
+                kbuf_l = jax.lax.dynamic_index_in_dim(kbuf, kv_l, keepdims=False)
+                vbuf_l = jax.lax.dynamic_index_in_dim(vbuf, kv_l, keepdims=False)
             with jax.named_scope("kv_read"):
                 qg = q.reshape(B, c.kv_heads, G, c.head_dim)
                 s_cache = cache_scores(qg, ck_l)
@@ -668,10 +676,11 @@ def llama_decode_chunk(
             with jax.named_scope("ffn"):
                 h2 = _rms_norm(x, lp["mlp_norm"], c.norm_eps)
                 x = x + ffn(h2, lp, active)
-            return x, (kbuf_l, vbuf_l)
+            return (x, kbuf, vbuf), None
 
-        x, (kbuf, vbuf) = jax.lax.scan(
-            layer, x, (params["layers"], cache_k, cache_v, kbuf, vbuf)
+        (x, kbuf, vbuf), _ = jax.lax.scan(
+            layer, (x, kbuf, vbuf),
+            (params["layers"], cache_k, cache_v, jnp.arange(c.layers)),
         )
         with jax.named_scope("lm_head"):
             x = _rms_norm(x, params["final_norm"], c.norm_eps)

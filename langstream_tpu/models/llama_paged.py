@@ -706,7 +706,7 @@ def llama_decode_chunk_paged(
 ) -> tuple[jax.Array, ...]:
     """K fused decode steps against the paged pool; same two-segment
     discipline as the dense ``llama_decode_chunk`` (pool read-only, new K/V
-    in a chunk buffer, one scatter commit at the end).
+    in a chunk buffer in both scans' carry, one scatter commit at the end).
 
     ``return_packed=True`` folds the chunk's host-bound outputs into the
     program itself (:func:`pack_tokens_logprobs`) and returns
@@ -789,8 +789,12 @@ def llama_decode_chunk_paged(
             buf_mask = jnp.arange(num_steps)[None, :] <= step_idx  # (1, K)
         G = c.heads // c.kv_heads
 
-        def layer(x, layer_in):
-            lp, al, kv_l, kbuf_l, vbuf_l = layer_in
+        def layer(carry, layer_in):
+            # the whole chunk buffer rides the carry and takes the step's
+            # rows in place (as xs/ys the stacked ys is a new array: the
+            # compiler copies the buffer whole every step)
+            x, kbuf, vbuf = carry
+            lp, al, kv_l = layer_in
             with jax.named_scope("attn_qkv"):
                 h = _rms_norm(x, lp["attn_norm"], c.norm_eps)
                 q = h @ _w(lp["wq"])
@@ -806,12 +810,16 @@ def llama_decode_chunk_paged(
                 v = v.reshape(B, c.kv_heads, c.head_dim)
                 q = _apply_rope(q, cos, sin)
                 k = _apply_rope(k, cos, sin)
-                kbuf_l = jax.lax.dynamic_update_slice_in_dim(
-                    kbuf_l, k[:, None], step_idx, axis=1
+                kbuf = jax.lax.dynamic_update_slice(
+                    kbuf, k[None, :, None], (kv_l, 0, step_idx, 0, 0)
                 )
-                vbuf_l = jax.lax.dynamic_update_slice_in_dim(
-                    vbuf_l, v[:, None], step_idx, axis=1
+                vbuf = jax.lax.dynamic_update_slice(
+                    vbuf, v[None, :, None], (kv_l, 0, step_idx, 0, 0)
                 )
+                # the layer's slice, read after the write: the step's own
+                # row is in it
+                kbuf_l = jax.lax.dynamic_index_in_dim(kbuf, kv_l, keepdims=False)
+                vbuf_l = jax.lax.dynamic_index_in_dim(vbuf, kv_l, keepdims=False)
             with jax.named_scope("kv_read"):
                 # segment 1: paged pool (partial stats)
                 acc_c, m_c, l_c = cache_partial(q, kv_l)
@@ -847,16 +855,16 @@ def llama_decode_chunk_paged(
             with jax.named_scope("ffn"):
                 h2 = _rms_norm(x, lp["mlp_norm"], c.norm_eps)
                 x = x + ffn(h2, lp, active)
-            return x, (kbuf_l, vbuf_l)
+            return (x, kbuf, vbuf), None
 
         layer_xs = (
             params["layers"],
             None if adapters is None else adapters["layers"],
-            # the read is handed the layer's index and closes over the pool
+            # the read and the buffer's write are handed the layer's index;
+            # the read closes over the pool
             jnp.arange(c.layers),
-            kbuf, vbuf,
         )
-        x, (kbuf, vbuf) = jax.lax.scan(layer, x, layer_xs)
+        (x, kbuf, vbuf), _ = jax.lax.scan(layer, (x, kbuf, vbuf), layer_xs)
         with jax.named_scope("lm_head"):
             x = _rms_norm(x, params["final_norm"], c.norm_eps)
             logits = (x @ _w(params["lm_head"])).astype(jnp.float32)

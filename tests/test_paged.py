@@ -463,6 +463,162 @@ def test_paged_decode_chunk_matches_dense(kernel):
     np.testing.assert_array_equal(got, ref)
 
 
+def _chunk_step_by_step(c, params, tokens, lengths, active, pool_k, pool_v,
+                        tables, sample_fn, key, num_steps, num_read_blocks,
+                        counts=None):
+    """The decode chunk as one would write it down: a Python loop over the
+    steps and, inside it, over the layers; a chunk buffer A LAYER that takes
+    the step's rows with ``.at[].set``; the pool read through the XLA
+    gather; one commit at the end. The layer math is the model's own."""
+    import math
+
+    from langstream_tpu.models.llama import (
+        _apply_rope, _default_ffn, _rms_norm, _rope,
+    )
+    from langstream_tpu.models.llama_paged import _cache_partial_xla
+    from langstream_tpu.models.paged import write_rows
+    from langstream_tpu.models.quant import as_weight, embedding_take
+    from langstream_tpu.ops.paged_attention import (
+        NEG_INF, merge_partial_attention,
+    )
+
+    B, G = tokens.shape[0], c.heads // c.kv_heads
+    adv = active.astype(jnp.int32)
+    kbufs = [jnp.zeros((B, num_steps, c.kv_heads, c.head_dim), c.dtype)
+             for _ in range(c.layers)]
+    vbufs = [jnp.zeros_like(b) for b in kbufs]
+    chunk_tokens, chunk_lps = [], []
+    for step in range(num_steps):
+        key, sub = jax.random.split(key)
+        x = embedding_take(params["embed"], tokens)
+        cos, sin = _rope(lengths + step * adv, c.head_dim, c.rope_theta)
+        seen = (jnp.arange(num_steps) <= step)[None, None, None, :]
+        for layer in range(c.layers):
+            lp = jax.tree.map(lambda a: a[layer], params["layers"])
+            h = _rms_norm(x, lp["attn_norm"], c.norm_eps)
+            q = (h @ as_weight(lp["wq"])).reshape(B, c.heads, c.head_dim)
+            k = (h @ as_weight(lp["wk"])).reshape(B, c.kv_heads, c.head_dim)
+            v = (h @ as_weight(lp["wv"])).reshape(B, c.kv_heads, c.head_dim)
+            q, k = _apply_rope(q, cos, sin), _apply_rope(k, cos, sin)
+            kbufs[layer] = kbufs[layer].at[:, step].set(k)
+            vbufs[layer] = vbufs[layer].at[:, step].set(v)
+            pooled = _cache_partial_xla(
+                c, q, pool_k, pool_v, layer, tables, lengths, num_read_blocks)
+            qg = q.reshape(B, c.kv_heads, G, c.head_dim)
+            s = jnp.einsum("bkgd,btkd->bkgt", qg, kbufs[layer])
+            s = s.astype(jnp.float32) / math.sqrt(c.head_dim)
+            s = jnp.where(seen, s, NEG_INF)
+            m = jnp.max(s, axis=-1)
+            p = jnp.exp(s - jnp.where(m <= NEG_INF, 0.0, m)[..., None])
+            p = jnp.where(seen, p, 0.0)
+            acc = jnp.einsum("bkgt,btkd->bkgd", p.astype(c.dtype),
+                             vbufs[layer]).astype(jnp.float32)
+            out = merge_partial_attention([pooled, (
+                acc.reshape(B, c.heads, c.head_dim), m.reshape(B, c.heads),
+                jnp.sum(p, axis=-1).reshape(B, c.heads),
+            )]).astype(x.dtype).reshape(B, c.heads * c.head_dim)
+            x = x + out @ as_weight(lp["wo"])
+            x = x + _default_ffn(_rms_norm(x, lp["mlp_norm"], c.norm_eps),
+                                 lp, active)
+        x = _rms_norm(x, params["final_norm"], c.norm_eps)
+        logits = (x @ as_weight(params["lm_head"])).astype(jnp.float32)
+        if counts is None:
+            nxt, lp_ = sample_fn(logits, sub)
+        else:
+            nxt, lp_ = sample_fn(logits, sub, counts)
+        tokens = jnp.where(active, nxt, tokens)
+        if counts is not None:
+            counts = counts.at[jnp.arange(B), tokens].add(adv)
+        chunk_tokens.append(tokens)
+        chunk_lps.append(lp_)
+    valid = jnp.broadcast_to(active[:, None], (B, num_steps))
+    rows = lambda bufs: jnp.stack(bufs).reshape(  # noqa: E731
+        c.layers, B, num_steps, c.kv_heads * c.head_dim)
+    return (jnp.stack(chunk_tokens), jnp.stack(chunk_lps), tokens,
+            lengths + num_steps * adv,
+            write_rows(pool_k, rows(kbufs), tables, lengths, valid),
+            write_rows(pool_v, rows(vbufs), tables, lengths, valid))
+
+
+@pytest.mark.parametrize("penalties", [False, True], ids=["plain", "penalties"])
+@pytest.mark.parametrize("pool", ["bf16", "int8"])
+@pytest.mark.parametrize("num_steps", [8, 32])
+def test_the_chunk_buffer_in_the_carry_is_the_step_by_step_chunk(
+        num_steps, pool, penalties):
+    """The chunk keeps ONE buffer of all layers in both scans' carry and
+    writes a step's rows into it in place; tokens, log-probabilities, final
+    tokens and lengths and both pools (an int8 pool's scales too) equal,
+    bit for bit, those of the loop written down step by step with a buffer a
+    layer. Slot 2 is inactive (it keeps its token, its length and its
+    rows), slot 1 starts two rows before a block's edge and slot 3 crosses
+    one block with 8 steps and four with 32."""
+    from langstream_tpu.models.llama_paged import llama_decode_chunk_paged
+
+    c, params = _setup_model(seed=11, max_seq=128)
+    B, bs, cols = 4, 8, 8
+    rng = np.random.default_rng(num_steps + penalties)
+    shape = (c.layers, 1 + B * cols, bs, c.kv_heads * c.head_dim)
+    if pool == "bf16":
+        pools = [jnp.asarray(rng.normal(size=shape), jnp.bfloat16)
+                 for _ in range(2)]
+    else:
+        pools = [{"q": jnp.asarray(rng.integers(-127, 128, shape), jnp.int8),
+                  "s": jnp.asarray(rng.uniform(0.01, 0.05, shape[:3] + (
+                      c.kv_heads,)), jnp.float32)} for _ in range(2)]
+    tables = jnp.asarray(1 + rng.permutation(B * cols).reshape(B, cols),
+                         jnp.int32)
+    lengths = jnp.asarray([5, 30, 17, 3], jnp.int32)
+    active = jnp.asarray([True, True, False, True])
+    tokens = jnp.asarray([5, 9, 11, 200], jnp.int32)
+    counts = (jnp.asarray(rng.integers(0, 2, (B, c.vocab_size)), jnp.int32)
+              if penalties else None)
+
+    def sample(logits, key, counts=None):
+        if counts is not None:
+            logits = logits - 0.6 * (counts > 0) - 0.2 * counts
+        t = jax.random.categorical(key, logits / 0.8).astype(jnp.int32)
+        return t, jnp.take_along_axis(
+            jax.nn.log_softmax(logits), t[:, None], axis=1)[:, 0]
+
+    key = jax.random.PRNGKey(1)
+    # both run a primitive at a time: compiled whole, the CPU's compiler
+    # drops a bfloat16 rounding between two ops it fuses and vectorises a
+    # loop's body by its own lights, so two programs of one arithmetic
+    # differ in the last bit by their form (the scans against the parent's
+    # scans, compiled, are bit-equal: CHANGES.md, PR 42)
+    with jax.disable_jit():
+        got = llama_decode_chunk_paged(
+            c, params, tokens, lengths, active, *pools, tables, sample, key,
+            num_steps, num_read_blocks=cols, kernel="xla",
+            sample_extras=(None, None, counts) if penalties else None)
+        want = _chunk_step_by_step(
+            c, params, tokens, lengths, active, *pools, tables, sample, key,
+            num_steps, cols, counts)
+    assert jax.tree.structure(got) == jax.tree.structure(want)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert g.dtype == w.dtype and g.shape == w.shape
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+    chunk_tokens, _, final_tokens, final_lengths, pool_k, _ = got
+    # the inactive slot stood still; the others advanced a row a step
+    np.testing.assert_array_equal(
+        np.asarray(final_lengths), [5 + num_steps, 30 + num_steps, 17,
+                                    3 + num_steps])
+    assert int(final_tokens[2]) == 11
+    assert (np.asarray(chunk_tokens)[:, 2] == 11).all()
+    # and the commit reached the blocks past the edges, and no other
+    data = lambda p: np.asarray(  # noqa: E731
+        (p["q"] if isinstance(p, dict) else p).astype(jnp.float32))
+    before, after = data(pools[0]), data(pool_k)
+    for slot, first in enumerate([5, 30, None, 3]):
+        rows = [] if first is None else range(first, first + num_steps)
+        touched = {int(tables[slot, r // bs]) for r in rows}
+        if rows:   # from inside a block over one edge, or four
+            assert len(touched) == rows[-1] // bs - rows[0] // bs + 1 > 1
+        for block in map(int, tables[slot]):
+            changed = not np.array_equal(before[:, block], after[:, block])
+            assert changed == (block in touched), (slot, block)
+
+
 def test_paged_kernel_partial_matches_xla_reference():
     """paged_attention_partial (interpret) ≡ the XLA gather reference on
     random inputs with ragged lengths."""
@@ -945,14 +1101,17 @@ def test_int8_chunk_lowers_without_a_fill_or_a_layer_slice():
     import re
 
     text, stack, layer = _lowered_int8_chunk()
-    gathered = re.findall(
-        rf'(%\S+) = "stablehlo\.gather"\(%\S+, %\S+\).*\(tensor<{stack}>, ', text
-    )
-    assert len(gathered) == 2
-    selects = [ln for ln in text.splitlines() if "stablehlo.select" in ln]
-    assert selects                      # the masks on the scores are there
-    for name in gathered:
-        assert not [ln for ln in selects if re.search(rf"{name}\b", ln)]
+    gathers = 0
+    assert "stablehlo.select" in text   # the masks on the scores are there
+    for func in text.split("func.func")[1:]:   # a value's name is its
+        gathered = re.findall(                 # function's own
+            rf'(%\S+) = "stablehlo\.gather"\(%\S+, %\S+\).*\(tensor<{stack}>, ',
+            func)
+        gathers += len(gathered)
+        selects = [ln for ln in func.splitlines() if "stablehlo.select" in ln]
+        for name in gathered:
+            assert not [ln for ln in selects if re.search(rf"{name}\b", ln)]
+    assert gathers == 2
     assert not [
         ln for ln in text.splitlines()
         if "dynamic_slice" in ln and f"x{layer}>" in ln

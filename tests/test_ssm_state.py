@@ -395,3 +395,73 @@ def test_the_tpu_compiler_moves_the_xla_read_s_window_once(
     for gone in (f"s8[{B * cols},{bs},{Kh * D}]", f"s8[{nb},{bs},{Kh * D}]",
                  f"s8[1,{B},{cols},{bs},{Kh * D}]"):
         assert f"= {gone}" not in text and f"({gone}" not in text
+
+
+def test_the_tpu_compiler_writes_the_chunk_buffer_s_rows_in_place(
+        one_chip, no_compile_cache):
+    """The dense family's paged decode chunk (``llama_decode_chunk_paged``)
+    at internlm2-1.8b's posture: 24 layers, 128 slots, chunks of 32 steps,
+    8 kv heads of 128, the bf16 pool of 901 blocks read by the Pallas kernel.
+    The chunk buffer ``bf16[24,128,32,8,128]`` (201 MB for K, as much for V)
+    rides both scans' carry: inside the loops nothing copies it, the only
+    ``dynamic-update-slice`` ops are the two into the buffers themselves (a
+    step's rows of one layer, 256 KB) and none rewrites a layer's slice.
+    Handed to the layer scan as ``xs`` and back as ``ys`` it was copied
+    whole twice a step and each layer's slice ``bf16[128,32,8,128]`` (8 MB)
+    was rewritten to put those rows into it (ROADMAP S4). What stays is the
+    loop's exit:
+    one copy a buffer into the row-major form the commit's reshape takes,
+    once a chunk, as before."""
+    import importlib.util
+    import pathlib
+
+    from langstream_tpu.models.llama import LlamaConfig, init_llama_params
+    from langstream_tpu.models.llama_paged import llama_decode_chunk_paged
+
+    spec = importlib.util.spec_from_file_location(
+        "ops_of_shape",
+        pathlib.Path(__file__).parents[1] / "tools" / "ops_of_shape.py")
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+
+    L, B, K, Kh, D, nb, bs, cols = 24, 128, 32, 8, 128, 901, 64, 32
+    c = LlamaConfig(vocab_size=92544, hidden=2048, layers=L, heads=16,
+                    kv_heads=Kh, head_dim=D, intermediate=8192,
+                    rope_theta=1e6, max_seq_len=2048)
+    on = lambda shape, dtype: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dtype, sharding=one_chip)
+    params = jax.tree.map(lambda a: on(a.shape, a.dtype),
+                          jax.eval_shape(lambda: init_llama_params(c)))
+
+    def greedy(logits, key):
+        return (jnp.argmax(logits, axis=-1).astype(jnp.int32),
+                jnp.max(jax.nn.log_softmax(logits), axis=-1))
+
+    def chunk(params, tokens, lengths, active, pool_k, pool_v, tables, key):
+        return llama_decode_chunk_paged(
+            c, params, tokens, lengths, active, pool_k, pool_v, tables,
+            greedy, key, K, num_read_blocks=cols, kernel="pallas",
+            return_packed=True)
+
+    pool = on((L, nb, bs, Kh * D), jnp.bfloat16)
+    compiled = jax.jit(chunk, donate_argnums=(4, 5)).lower(
+        params, on((B,), jnp.int32), on((B,), jnp.int32), on((B,), jnp.bool_),
+        pool, pool, on((B, cols), jnp.int32), on((2,), jnp.uint32),
+    ).compile()
+    text = compiled.as_text()
+    assert "paged_read" in text
+    buffer, layer = f"bf16[{L},{B},{K},{Kh},{D}]", f"bf16[{B},{K},{Kh},{D}]"
+    ops = tool.moved(tool.hlo_ops_of_shape(text, [buffer, layer]))
+    # the two updates are the carried buffers' own; none rewrites a slice
+    assert [shape for _, op, shape in ops
+            if op == "dynamic-update-slice"] == [buffer] * 2, ops
+    # the only copies are the exit's two, in the entry computation (the
+    # text's last): no loop body copies a buffer or a slice
+    copies = [name for name, op, _ in ops if op == "copy"]
+    entry = text.index("\nENTRY ")
+    assert len(copies) == 2 and all(
+        text.index(f"%{name} = ") > entry for name in copies), ops
+    # the loop's state and the exit's copies: four buffers where the form
+    # before kept eight (1.61 GB)
+    assert compiled.memory_analysis().temp_size_in_bytes < 5 * (
+        L * B * K * Kh * D * 2)
