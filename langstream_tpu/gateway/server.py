@@ -79,6 +79,140 @@ STREAM_ID_HEADER = "langstream-stream-id"
 STREAM_LAST_HEADER = "stream-last-message"
 
 
+class _ChatSocket:
+    """One chat socket's place on its answers topic: the header values it
+    injected (what its records are matched by) and the FIFO that its own
+    task sends from."""
+
+    __slots__ = ("keys", "values", "queue")
+
+    def __init__(self, inject: dict[str, Any]):
+        self.keys = tuple(sorted(inject))
+        self.values = tuple(inject[k] for k in self.keys)
+        # graftcheck: disable=QOS601 holds only the answers to what THIS socket produced (each produce admitted by the limiter, each answer bounded by max-tokens); a bound would drop a frame or make the topic's one reader wait for one slow client
+        self.queue: asyncio.Queue[Record] = asyncio.Queue()
+
+
+class _AnswersReader:
+    """ONE reader on one answers topic for every chat socket open on it.
+
+    A record read is handed to the sockets whose injected headers it
+    carries, by a dictionary lookup, and only those sockets' tasks wake:
+    a publish costs one reader wake, one lookup and one queue put a
+    matching socket, whatever the number of sockets on the topic.
+    Sockets are indexed by the tuple of the values under their key set,
+    one index a key set (two gateways with different ``headers`` on one
+    topic, a limiter on or off); a socket that injects nothing has the
+    empty tuple, which every record carries.
+
+    The reader never awaits a socket: ``put_nowait`` on an unbounded
+    queue, so a client that does not read holds back its own frames
+    alone. Subscribing and leaving are dictionary entries; neither
+    touches the topic."""
+
+    def __init__(self, key: tuple[str, str], runtime, reader):
+        from langstream_tpu.api.metrics import PrometheusMetricsReporter
+
+        self.key = key  # (streaming cluster, topic): the server's index
+        self.topic = topic = key[1]
+        self._runtime = runtime
+        self._reader = reader
+        # key set -> values under it -> the sockets that injected them
+        self._sockets: dict[tuple, dict[tuple, list[_ChatSocket]]] = {}
+        # sockets with a value no dictionary can key (a list from a
+        # principal's claims): compared one by one
+        self._unhashable: list[_ChatSocket] = []
+        reporter = PrometheusMetricsReporter(
+            prefix="langstream_gateway", agent_id=topic
+        )
+        self._count_read = reporter.counter(
+            "chat_records_read_total",
+            "records the gateway read from this chat answers topic",
+        )
+        self.count_sent = reporter.counter(
+            "chat_frames_sent_total",
+            "frames the gateway sent to chat sockets from this answers topic",
+        )
+        #: set once the reader stands at ``latest`` (or has failed to)
+        self.ready = asyncio.Event()
+        #: the reader has failed or was stopped: no socket may join it
+        self.closed = False
+        self.task = asyncio.ensure_future(self._run())
+
+    @property
+    def idle(self) -> bool:
+        return not self._sockets and not self._unhashable
+
+    def subscribe(self, inject: dict[str, Any]) -> _ChatSocket:
+        socket = _ChatSocket(inject)
+        by_values = self._sockets.setdefault(socket.keys, {})
+        try:
+            by_values.setdefault(socket.values, []).append(socket)
+        except TypeError:
+            if not by_values:
+                del self._sockets[socket.keys]
+            self._unhashable.append(socket)
+        return socket
+
+    def leave(self, socket: _ChatSocket) -> None:
+        if socket in self._unhashable:
+            self._unhashable.remove(socket)
+            return
+        by_values = self._sockets[socket.keys]
+        sockets = by_values[socket.values]
+        sockets.remove(socket)
+        if not sockets:
+            del by_values[socket.values]
+            if not by_values:
+                del self._sockets[socket.keys]
+
+    def _hand_out(self, records: list[Record]) -> None:
+        self._count_read(len(records))
+        for record in records:
+            headers = record.header_map()
+            for keys, by_values in self._sockets.items():
+                values = tuple(headers.get(k) for k in keys)
+                try:
+                    sockets = by_values.get(values, ())
+                except TypeError:  # the RECORD carries an unhashable value
+                    sockets = [
+                        s for v, ss in by_values.items() if v == values
+                        for s in ss
+                    ]
+                for socket in sockets:
+                    socket.queue.put_nowait(record)
+            for socket in self._unhashable:
+                if all(
+                    headers.get(k) == v
+                    for k, v in zip(socket.keys, socket.values)
+                ):
+                    socket.queue.put_nowait(record)
+
+    async def _run(self) -> None:
+        try:
+            await self._reader.start()
+            self.ready.set()
+            while True:
+                # the reader's wake-to-return is the topic's
+                # (``ls.hop.topic``); the hand-out is the gateway's
+                records = await self._reader.read(timeout=0.5)
+                if records:
+                    with host_span("ls.hop.gw.send", records=len(records)):
+                        self._hand_out(records)
+        except Exception:
+            log.exception("chat answers reader on %r failed", self.topic)
+        finally:
+            self.closed = True
+            self.ready.set()
+            await self._reader.close()
+            await self._runtime.close()
+
+    async def stop(self) -> None:
+        self.closed = True
+        self.task.cancel()
+        await asyncio.gather(self.task, return_exceptions=True)
+
+
 class GatewayRegistry:
     """Resolves (tenant, application, gateway-id) → (Gateway, streaming
     cluster config). Backed by the application store in the control plane,
@@ -295,6 +429,9 @@ class GatewayServer:
         # per-QoS-tenant throttle counters (lazily created: tenants are
         # client identities, unknown until the first 429)
         self._m_throttled: dict[str, Any] = {}
+        # the one reader of each answers topic chat sockets are open on,
+        # by (streaming cluster, topic); lives while a socket is subscribed
+        self._answers: dict[tuple[str, str], _AnswersReader] = {}
 
     async def start(self) -> None:
         self._runner = web.AppRunner(self.app)
@@ -309,6 +446,9 @@ class GatewayServer:
             await proxy_client.close()
         if self._runner is not None:
             await self._runner.cleanup()
+        for answers in list(self._answers.values()):
+            await answers.stop()
+        self._answers.clear()
 
     # ------------------------------------------------------------------
     # shared plumbing
@@ -1036,10 +1176,6 @@ class GatewayServer:
         runtime = TopicConnectionsRuntimeRegistry.get_runtime(streaming)
         producer = runtime.create_producer("gateway-chat", {"topic": questions_topic})
         await producer.start()
-        reader = runtime.create_reader(
-            {"topic": answers_topic}, initial_position="latest"
-        )
-        await reader.start()
         inject = {
             **self._mapped_headers(gateway.produce_headers, params, principal),
             **self._qos_headers(limiter, params, principal),
@@ -1051,11 +1187,21 @@ class GatewayServer:
         chat_stream = self._stream_requested(options)
         active_streams: set[str] = set()
         # the same headers injected on produce are the consume-side filters
-        # (that's how chat correlates answers to this session)
+        # (that's how chat correlates answers to this session): the topic's
+        # one reader hands this socket the records that carry them. It is
+        # subscribed before its first client frame is read, so no answer of
+        # its own can pass it by
+        answers = self._answers_reader(streaming, answers_topic)
+        socket = answers.subscribe(inject)
         pusher = asyncio.ensure_future(
-            self._chat_push_loop(ws, reader, inject, active_streams)
+            self._chat_send_loop(ws, answers, socket, active_streams)
         )
         try:
+            # only a topic's first socket waits here: for the reader to
+            # stand at ``latest``
+            await answers.ready.wait()
+            if answers.closed:
+                raise RuntimeError(f"no reader on {answers_topic!r}")
             async for msg in ws:
                 if msg.type != WSMsgType.TEXT:
                     continue
@@ -1121,36 +1267,63 @@ class GatewayServer:
                 # every stream still open on this socket (no-op for
                 # completed streams — they left the registry)
                 STREAMS.cancel(sid)
+            # the socket's entry goes, and what is still queued for it
+            answers.leave(socket)
+            if answers.idle:
+                await self._drop_answers_reader(answers)
             await producer.close()
-            await reader.close()
             await runtime.close()
             await self._emit_event(
                 gateway, streaming, "ClientDisconnected", tenant, app_id
             )
         return ws
 
-    async def _chat_push_loop(
+    def _answers_reader(
+        self, streaming: dict[str, Any], topic: str
+    ) -> _AnswersReader:
+        """The one reader of this answers topic: the topic's first chat
+        socket makes it (at ``latest``), the last to leave drops it."""
+        key = (json.dumps(streaming, sort_keys=True, default=str), topic)
+        answers = self._answers.get(key)
+        if answers is None or answers.closed:
+            runtime = TopicConnectionsRuntimeRegistry.get_runtime(streaming)
+            reader = runtime.create_reader(
+                {"topic": topic}, initial_position="latest"
+            )
+            answers = self._answers[key] = _AnswersReader(key, runtime, reader)
+        return answers
+
+    async def _drop_answers_reader(self, answers: _AnswersReader) -> None:
+        if self._answers.get(answers.key) is answers:
+            del self._answers[answers.key]
+        await answers.stop()
+
+    async def _chat_send_loop(
         self,
         ws,
-        reader,
-        inject: dict[str, Any],
+        answers: _AnswersReader,
+        socket: _ChatSocket,
         active: set | None = None,
     ) -> None:
+        """Send one chat socket the records its answers topic's reader
+        queued for it, in the reader's order: the socket's own task, so a
+        client that does not read holds back no one else's frames."""
+        queue = socket.queue
         try:
             while not ws.closed:
-                records = await reader.read(timeout=0.5)
-                if not records:
-                    continue
+                records = [await queue.get()]
+                while not queue.empty():
+                    records.append(queue.get_nowait())
                 # from a record to the end of its frame's send (held across
                 # ``send_json``, which yields only under back-pressure)
                 with host_span("ls.hop.gw.send", records=len(records)):
                     for record in records:
-                        headers = record.header_map()
-                        if all(headers.get(k) == v for k, v in inject.items()):
-                            await ws.send_json(self._record_json(record))
+                        await ws.send_json(self._record_json(record))
+                        answers.count_sent(1)
+                        if active:
+                            headers = record.header_map()
                             if (
-                                active
-                                and str(headers.get(STREAM_LAST_HEADER)).lower()
+                                str(headers.get(STREAM_LAST_HEADER)).lower()
                                 == "true"
                             ):
                                 # completed stream: drop its cancel handle
@@ -1158,7 +1331,7 @@ class GatewayServer:
         except (asyncio.CancelledError, ConnectionResetError):
             pass
         except Exception:
-            log.exception("chat push loop failed")
+            log.exception("chat send loop failed")
 
     # ------------------------------------------------------------------
     # service gateway: agent proxy

@@ -104,11 +104,13 @@ def test_every_layer_on_the_loop_opens_its_spans_through_the_one_helper(module):
     assert "import TraceAnnotation" not in inspect.getsource(mod)
 
 
-def test_a_gateway_s_push_loop_without_jax_opens_no_op_spans(
+def test_a_gateway_s_answers_path_without_jax_opens_no_op_spans(
         run_async, monkeypatch):
     """The gateway's frames go through the helper: with JAX hidden from it
-    (``sys.modules`` is what it consults) the push loop's span is the shared
-    no-op, and the frame is sent all the same."""
+    (``sys.modules`` is what it consults) the spans of the answers topic's
+    one reader's hand-out and of the socket's send are the shared no-op, and
+    the frame is sent all the same."""
+    import asyncio
     import sys
 
     from langstream_tpu.api.record import make_record
@@ -134,19 +136,43 @@ def test_a_gateway_s_push_loop_without_jax_opens_no_op_spans(
             self.closed = True          # one frame, then the loop ends
 
     class Reader:
-        async def read(self, timeout=None):
-            return [
-                make_record(value="mine", headers={"session": "s1"}),
-                make_record(value="another's", headers={"session": "s2"}),
-            ]
+        batches = [[
+            make_record(value="mine", headers={"session": "s1"}),
+            make_record(value="another's", headers={"session": "s2"}),
+        ]]
 
-    gateway = server.GatewayServer.__new__(server.GatewayServer)
-    socket_ = Socket()
-    run_async(gateway._chat_push_loop(socket_, Reader(), {"session": "s1"}))
+        async def start(self):
+            pass
+
+        async def read(self, timeout=None):
+            if self.batches:
+                return self.batches.pop()
+            await asyncio.sleep(3600)
+
+        async def close(self):
+            pass
+
+    class Runtime:
+        async def close(self):
+            pass
+
+    async def main():
+        gateway = server.GatewayServer.__new__(server.GatewayServer)
+        answers = server._AnswersReader(("{}", "answers"), Runtime(), Reader())
+        try:
+            socket_ = Socket()
+            await gateway._chat_send_loop(
+                socket_, answers, answers.subscribe({"session": "s1"}))
+        finally:
+            await answers.stop()
+        return socket_
+
+    socket_ = run_async(main())
     assert [f["record"]["value"] for f in socket_.sent] == ["mine"]
     assert [(name, meta) for name, meta, _ in opened] == [
-        ("ls.hop.gw.send", {"records": 2})]
-    assert opened[0][2] is tracing._NO_SPAN
+        ("ls.hop.gw.send", {"records": 2}),    # the reader's hand-out
+        ("ls.hop.gw.send", {"records": 1})]    # the socket's own send
+    assert all(span is tracing._NO_SPAN for _, _, span in opened)
     assert "jax" not in sys.modules
 
 
