@@ -51,11 +51,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from langstream_tpu.models.family import Family
 from langstream_tpu.models.hybrid import moe_mixer
 from langstream_tpu.models.llama import _flash_mode, _rms_norm
 from langstream_tpu.models.llama_paged import pack_tokens_logprobs
 from langstream_tpu.models.moe import silu_gated
-from langstream_tpu.models.paged import write_rows
+from langstream_tpu.models.paged import init_latent_pool, write_rows
 from langstream_tpu.ops.paged_attention import (
     NEG_INF,
     latent_read,
@@ -649,3 +650,63 @@ def latent_decode_chunk_paged(
         return packed, final_tokens, final_lengths, pool
     return (chunk_tokens, chunk_lps, final_tokens, final_lengths, pool, load,
             routed)
+
+
+# ---------------------------------------------------------------------------
+# the family, as the serving engine asks it (models/family.py)
+# ---------------------------------------------------------------------------
+
+
+def _family_prefill(mc, params, residents, tokens, lengths, tables,
+                    use_flash=None, kernel=None):
+    # ``kernel`` selects a recurrent state's kernels; this family has none
+    pool, cache_v = residents
+    logits, pool, _routed = latent_prefill_paged(
+        mc, params, tokens, lengths, pool, tables, use_flash=use_flash)
+    return logits, (pool, cache_v)
+
+
+def _family_decode_chunk(mc, params, residents, tokens, lengths, active,
+                         tables, sample_fn, key, num_steps, **kernels):
+    pool, cache_v = residents
+    return latent_decode_chunk_paged(
+        mc, params, tokens, lengths, active, pool, tables, sample_fn, key,
+        num_steps, **kernels) + (cache_v,)
+
+
+FAMILY = Family(
+    name="latent",
+    config_class=LatentConfig,
+    presets={"deepseek-tiny": "tiny", "deepseek-v2-ep8": "deepseek_v2_ep8"},
+    what="keeps one pool of latent rows, not K and V",
+    refusals={
+        "prefix-cache": "no continuation prefill over a latent history yet, "
+                        "so an adopted prefix cannot be extended; set "
+                        "prefix-cache: false",
+        "prefill-chunk": "no continuation prefill over a latent history "
+                         "yet; set prefill-chunk: 0",
+        "speculative-drafts": "the verify step reads K/V history through the "
+                              "multi-query kernel; set speculative-drafts: 0",
+        "pool-role": "the handoff's payload carries a K and a V array; use "
+                     "pool-role: combined",
+        "kv-quantize": "int8 rows carry one scale a K/V head; a latent row "
+                       "has no head axis",
+        "journal-dir": "journal replay re-admits by K/V-era rules untested "
+                       "over a latent pool",
+    },
+    init_params=init_latent_params,
+    # one array of latent rows; nothing in the value pool's place
+    init_pools=lambda mc, layout, slots: (
+        lambda: init_latent_pool(mc, layout), None),
+    prefill=_family_prefill,
+    decode_chunk=_family_decode_chunk,
+    # the dense family's signature (no state rides behind the caches);
+    # cache_v is None, as init_latent_pool left it
+    residents=2,
+    donate=(1,),
+    # one decode program a chunk size: the latent read fetches a slot's live
+    # blocks and nothing else, whatever the window, and a slot of this
+    # family is long (a window bucket every power of two would be four more
+    # programs of its five-layer step)
+    one_decode_window=True,
+)

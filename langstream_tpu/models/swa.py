@@ -49,7 +49,9 @@ from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
+from langstream_tpu.models.family import Family
 from langstream_tpu.models.hybrid import moe_mixer
 from langstream_tpu.models.llama import (
     _apply_rope,
@@ -62,7 +64,7 @@ from langstream_tpu.models.llama_paged import (
     pack_tokens_logprobs,
 )
 from langstream_tpu.models.moe import silu_gated
-from langstream_tpu.models.paged import write_rows
+from langstream_tpu.models.paged import PagedLayout, init_kv_pool, write_rows
 from langstream_tpu.ops.paged_attention import (
     NEG_INF,
     merge_partial_attention,
@@ -611,3 +613,112 @@ def swa_decode_chunk_paged(
         return packed, final_tokens, final_lengths, pool_k, pool_v, wpool
     return (chunk_tokens, chunk_lps, final_tokens, final_lengths, pool_k,
             pool_v, wpool, load, routed)
+
+
+# ---------------------------------------------------------------------------
+# the family, as the serving engine asks it (models/family.py)
+# ---------------------------------------------------------------------------
+
+
+def _window_kind(mc, layout, slots):
+    """The second pool, for the layers that attend a window: a ring of
+    window / block_size + 1 blocks a slot, whatever its length, and room for
+    every slot's (models/paged.py BlockManager)."""
+    ring = mc.ring_blocks(layout.block_size)
+    return {
+        "window_layout": PagedLayout(
+            block_size=layout.block_size,
+            num_blocks=slots * ring + 1,
+            max_blocks_per_slot=layout.max_blocks_per_slot),
+        "window_ring": ring,
+    }
+
+
+def _init_pools(mc, layout, slots):
+    # the full layers' pool where every family's K and V pools are, the
+    # window layers' behind them where the hybrid family's recurrent state
+    # is: donated and re-bound with the caches
+    window_layout = _window_kind(mc, layout, slots)["window_layout"]
+    return (
+        lambda: init_kv_pool(mc, layout, mc.full_layers),
+        lambda: dict(zip("kv", init_kv_pool(
+            mc, window_layout, mc.window_layers))))
+
+
+def _family_prefill(mc, params, residents, tokens, lengths, tables,
+                    use_flash=None, kernel=None):
+    # ``kernel`` selects a recurrent state's kernels; this family has none
+    cache_k, cache_v, wpool = residents
+    logits, ck, cv, wp, _routed = swa_prefill_paged(
+        mc, params, tokens, lengths, cache_k, cache_v, wpool, tables,
+        use_flash=use_flash)
+    return logits, (ck, cv, wp)
+
+
+def _family_decode_chunk(mc, params, residents, tokens, lengths, active,
+                         tables, sample_fn, key, num_steps, **kernels):
+    cache_k, cache_v, wpool = residents
+    return swa_decode_chunk_paged(
+        mc, params, tokens, lengths, active, cache_k, cache_v, wpool, tables,
+        sample_fn, key, num_steps, **kernels)
+
+
+def _pool_rows(mc, block_mgr, rows):
+    """What a pool a layer kind adds to a decode chunk's flight sample, from
+    the running slots' ``rows``: ``window_rows``, the rows a step reads of
+    each WINDOW layer's pool (a slot's last ``window`` at most, where
+    ``live_rows`` counts the full layers' whole history);
+    ``pool_rows_held``, the rows both kinds hold for the running slots over
+    all layers, in whole blocks; ``pool_rows_one_table``, what ONE table for
+    all layers would hold for them (every layer every block); and
+    ``window_slot_blocks_max``, the most window blocks any slot holds (never
+    more than the ring)."""
+    bs = block_mgr.layout.block_size
+    blocks = -(-rows // bs)
+    held = (mc.full_layers * blocks
+            + mc.window_layers * np.minimum(blocks, block_mgr.window_ring)
+            ).sum() * bs
+    return {
+        "window_rows": int(np.minimum(rows, mc.window).sum()),
+        "pool_rows_held": int(held),
+        "pool_rows_one_table": int(blocks.sum() * bs * mc.layers),
+        "window_slot_blocks_max": block_mgr.window_slot_blocks_max,
+    }
+
+
+FAMILY = Family(
+    name="swa",
+    config_class=SwaConfig,
+    presets={
+        "trinity-tiny": "tiny",
+        "trinity-large-preview-ep8": "trinity_large_preview_ep8",
+    },
+    what="keeps a second pool for its window layers, a ring of blocks a slot",
+    refusals={
+        "prefix-cache": "a window layer's cached block is overwritten once "
+                        "its slot grows a ring past it and is not reusable "
+                        "past the window; set prefix-cache: false",
+        "prefill-chunk": "no continuation prefill over two kinds of history "
+                         "yet; set prefill-chunk: 0",
+        "speculative-drafts": "the verify step reads one K/V pool through "
+                              "the multi-query kernel, which knows no "
+                              "window; set speculative-drafts: 0",
+        "pool-role": "the handoff's payload carries one pool's blocks, not "
+                     "a ring's; use pool-role: combined",
+        "kv-quantize": "the window read takes a first row, which the int8 "
+                       "pool's read does not",
+        "journal-dir": "journal replay re-admits by K/V-era rules untested "
+                       "over two kinds of pool",
+    },
+    init_params=init_swa_params,
+    init_pools=_init_pools,
+    prefill=_family_prefill,
+    decode_chunk=_family_decode_chunk,
+    residents=3,  # cache_k, cache_v, the window layers' {"k", "v"}
+    donate=(1, 2, 3),
+    block_manager_kwargs=_window_kind,
+    # one decode program a chunk size, as the latent family: its reads walk
+    # live blocks too, its window layers' from their first row
+    one_decode_window=True,
+    pool_rows=_pool_rows,
+)

@@ -363,13 +363,15 @@ def test_the_engine_knows_the_new_names_and_refuses_the_same_options():
     from langstream_tpu.serving.engine import (
         ServingConfig,
         TpuServingEngine,
-        _FAMILY_MODELS,
+        _family_of,
         _resolve_model_config,
     )
 
-    assert _FAMILY_MODELS["granite-tiny"] == ("hybrid", "granite_tiny")
-    assert _FAMILY_MODELS["granite-4.0-h-small-ep2"] == \
-        ("hybrid", "granite4_h_small_ep2")
+    hybrid = _family_of("granite-tiny")
+    assert (hybrid.name, hybrid.presets["granite-tiny"]) == \
+        ("hybrid", "granite_tiny")
+    assert _family_of("granite-4.0-h-small-ep2") is hybrid and \
+        hybrid.presets["granite-4.0-h-small-ep2"] == "granite4_h_small_ep2"
     real = _resolve_model_config("granite-4.0-h-small-ep2", 2048)
     assert real == HybridConfig.granite4_h_small_ep2() and real.max_seq_len == 2048
     base = dict(model="granite-tiny", model_dtype="float32", slots=2,

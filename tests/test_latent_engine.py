@@ -15,10 +15,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from langstream_tpu.models.family import families
 from langstream_tpu.serving.engine import (
     ServingConfig,
     TpuServingEngine,
-    _FAMILY_MODELS,
+    _family_of,
     _resolve_model_config,
 )
 
@@ -205,9 +206,12 @@ def test_every_option_that_assumes_history_is_kv_is_refused_by_name(option):
 
 
 def test_one_table_resolves_both_families_names():
-    assert _FAMILY_MODELS["deepseek-tiny"] == ("latent", "tiny")
-    assert _FAMILY_MODELS["deepseek-v2-ep8"] == ("latent", "deepseek_v2_ep8")
-    assert {f for f, _ in _FAMILY_MODELS.values()} == {"hybrid", "latent", "swa"}
+    latent = _family_of("deepseek-tiny")
+    assert (latent.name, latent.presets["deepseek-tiny"]) == ("latent", "tiny")
+    assert _family_of("deepseek-v2-ep8") is latent and \
+        latent.presets["deepseek-v2-ep8"] == "deepseek_v2_ep8"
+    assert {f.name for f in families()} == {"hybrid", "latent", "swa"}
+    assert _family_of("tiny") is None and _family_of("moe-tiny") is None
     with pytest.raises(ValueError) as e:
         _resolve_model_config("no-such-model", 128)
     assert "deepseek-v2-ep8" in str(e.value) and "hybrid-tiny" in str(e.value)

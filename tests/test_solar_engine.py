@@ -18,9 +18,9 @@ import pytest
 
 from langstream_tpu.models.hybrid import HybridConfig
 from langstream_tpu.serving.engine import (
-    _FAMILY_MODELS,
     ServingConfig,
     TpuServingEngine,
+    _family_of,
     _resolve_model_config,
 )
 
@@ -62,8 +62,10 @@ def alone(run_async_module):
 
 
 def test_the_engine_knows_the_new_names():
-    assert _FAMILY_MODELS["solar-tiny"] == ("hybrid", "solar_tiny")
-    assert _FAMILY_MODELS["solar-open2-250b-ep8"] == ("hybrid", "solar_open2_ep8")
+    hybrid = _family_of("solar-tiny")
+    assert (hybrid.name, hybrid.presets["solar-tiny"]) == ("hybrid", "solar_tiny")
+    assert _family_of("solar-open2-250b-ep8") is hybrid and \
+        hybrid.presets["solar-open2-250b-ep8"] == "solar_open2_ep8"
     real = _resolve_model_config("solar-open2-250b-ep8", 2048)
     assert real == HybridConfig.solar_open2_ep8() and real.max_seq_len == 2048
     with pytest.raises(ValueError) as e:

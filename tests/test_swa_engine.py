@@ -20,9 +20,9 @@ import pytest
 
 from langstream_tpu.models.swa import SwaConfig
 from langstream_tpu.serving.engine import (
-    _FAMILY_MODELS,
     ServingConfig,
     TpuServingEngine,
+    _family_of,
     _resolve_model_config,
 )
 
@@ -66,9 +66,10 @@ def alone(run_async_module):
 
 
 def test_the_engine_knows_the_new_names():
-    assert _FAMILY_MODELS["trinity-tiny"] == ("swa", "tiny")
-    assert _FAMILY_MODELS["trinity-large-preview-ep8"] == (
-        "swa", "trinity_large_preview_ep8")
+    swa = _family_of("trinity-tiny")
+    assert (swa.name, swa.presets["trinity-tiny"]) == ("swa", "tiny")
+    assert _family_of("trinity-large-preview-ep8") is swa and swa.presets[
+        "trinity-large-preview-ep8"] == "trinity_large_preview_ep8"
     real = _resolve_model_config("trinity-large-preview-ep8", 16384)
     assert real == SwaConfig.trinity_large_preview_ep8()
     assert real.max_seq_len == 16384
