@@ -877,7 +877,8 @@ def delta_step(c: HybridConfig, lp: dict, u: jax.Array, delta: jax.Array,
 
 def moe_mixer(c, lp: dict, h: jax.Array, valid: jax.Array,
               layer: jax.Array | None = None):
-    """Routed experts held here plus the shared expert over rows ``h (T,
+    """Routed experts held here plus the shared expert (where the layer
+    has one: ``ws_up`` and ``ws_down`` among its weights) over rows ``h (T,
     H)``; ``lp`` is one expert layer's weights, or with ``layer`` its
     ``w_up`` and ``w_down`` are the stacks of every layer's
     (models/moe.py ``dropless_experts_grouped``). Returns ``(out (T, H),
@@ -905,6 +906,11 @@ def moe_mixer(c, lp: dict, h: jax.Array, valid: jax.Array,
         h, experts, weights, lp["w_up"], lp["w_down"], c.expert_first, valid,
         layer=layer, act=act,
     )
+    if "ws_up" not in lp:
+        # a layer without a shared expert (models/swa.py
+        # ``shared_intermediate`` 0) holds no weights for one: nothing traced
+        with jax.named_scope("moe_combine"):
+            return routed.astype(h.dtype), load, experts
     with jax.named_scope("moe_shared"):
         shared = act(h @ lp["ws_up"]) @ lp["ws_down"]
     with jax.named_scope("moe_combine"):

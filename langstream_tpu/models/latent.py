@@ -54,6 +54,7 @@ import numpy as np
 from langstream_tpu.models.family import Family
 from langstream_tpu.models.hybrid import moe_mixer
 from langstream_tpu.models.llama import _flash_mode, _rms_norm
+from langstream_tpu.models.llama import yarn_inv_freq as _yarn_inv_freq
 from langstream_tpu.models.llama_paged import pack_tokens_logprobs
 from langstream_tpu.models.moe import silu_gated
 from langstream_tpu.models.paged import init_latent_pool, write_rows
@@ -197,24 +198,12 @@ def yarn_mscale(factor: float, mscale: float) -> float:
 
 
 def yarn_inv_freq(c: LatentConfig) -> np.ndarray:
-    """``(rope_dim / 2,)`` float32 inverse frequencies: ``1/theta_i`` where a
-    dimension turns more than ``beta_fast`` times over the original length,
-    ``1/(factor theta_i)`` where it turns less than ``beta_slow`` times, a
-    linear ramp between the two correction dimensions."""
-    d, half = c.rope_dim, c.rope_dim // 2
-    theta = c.rope_theta ** (np.arange(0, d, 2, dtype=np.float64) / d)
-
-    def correction_dim(rotations):
-        return (d * math.log(c.rope_original_max / (rotations * 2 * math.pi))
-                / (2 * math.log(c.rope_theta)))
-
-    low = max(math.floor(correction_dim(c.rope_beta_fast)), 0)
-    high = min(math.ceil(correction_dim(c.rope_beta_slow)), d - 1)
-    ramp = np.clip(
-        (np.arange(half) - low) / ((high if high != low else high + 0.001)
-                                   - low), 0, 1)
-    return ((1 / (c.rope_factor * theta)) * ramp
-            + (1 / theta) * (1 - ramp)).astype(np.float32)
+    """``(rope_dim / 2,)`` float32 inverse frequencies of the ``rope_dim``
+    slice (:func:`langstream_tpu.models.llama.yarn_inv_freq`, the table the
+    window-and-full family's YaRN layers take too)."""
+    return _yarn_inv_freq(c.rope_dim, c.rope_theta, c.rope_factor,
+                          c.rope_original_max, c.rope_beta_fast,
+                          c.rope_beta_slow)
 
 
 def rotate(c: LatentConfig, x: jax.Array, positions: jax.Array) -> jax.Array:

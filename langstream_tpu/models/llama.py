@@ -37,6 +37,7 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from langstream_tpu.models.kvquant import (
@@ -227,6 +228,29 @@ def _rope(positions: jax.Array, head_dim: int, theta: float) -> tuple[jax.Array,
     freqs = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) / half))
     angles = positions[..., None].astype(jnp.float32) * freqs
     return jnp.cos(angles), jnp.sin(angles)
+
+
+def yarn_inv_freq(dim: int, theta: float, factor: float, original_max: int,
+                  beta_fast: float, beta_slow: float) -> np.ndarray:
+    """``(dim / 2,)`` float32 inverse frequencies of YaRN over a rotary
+    width of ``dim``: ``1/theta_i`` where a dimension turns more than
+    ``beta_fast`` times over the original length, ``1/(factor theta_i)``
+    where it turns less than ``beta_slow`` times, a linear ramp between the
+    two correction dimensions (the first rounded down, the second up)."""
+    half = dim // 2
+    thetas = theta ** (np.arange(0, dim, 2, dtype=np.float64) / dim)
+
+    def correction_dim(rotations):
+        return (dim * math.log(original_max / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(correction_dim(beta_fast)), 0)
+    high = min(math.ceil(correction_dim(beta_slow)), dim - 1)
+    ramp = np.clip(
+        (np.arange(half) - low) / ((high if high != low else high + 0.001)
+                                   - low), 0, 1)
+    return ((1 / (factor * thetas)) * ramp
+            + (1 / thetas) * (1 - ramp)).astype(np.float32)
 
 
 def _apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:

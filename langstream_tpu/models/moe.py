@@ -382,7 +382,17 @@ def moe_param_count(config: MoEConfig) -> int:
 #: the v5e at the published share (16 experts of 2 x 2688 x 1856, top 6 of
 #: 128; tools/hybrid_probe.py --crossover, ms a layer, dense / grouped):
 #: 256 rows 0.56 / 1.44, 512 rows 1.09 / 1.24, 768 rows 1.56 / 1.29,
-#: 2,048 rows 4.36 / 1.47
+#: 2,048 rows 4.36 / 1.47. At a WHOLE layer of experts (64 held of 64 of
+#: 2 x 896 x 2304, top 8: every expert is chosen by some row from 64 rows
+#: on, and the dense pass does 64 / 8 = 8 times the routed products;
+#: tools/swa_probe.py --config mellum2-12b-a2.5b-8l --crossover, PR 46) the
+#: crossover lies between the same two row counts: 64 rows 1.13 / 3.73,
+#: 128 rows 1.14 / 3.70, 192 rows 1.17 / 3.76 (0.97 ms stream the layer's
+#: 793 MB), 256 rows 1.29 / 3.79, 384 rows 1.89 / 3.72, 512 rows 2.66 /
+#: 4.57, 768 rows 4.15 / 3.84, 1,024 rows 5.70 / 3.97; the grouped pass's
+#: 64 steps cost 58 us each whatever their rows (a block of 128 rows: 2.77
+#: to 3.11 ms up to 768 rows; of 64 rows: 2.34 to 2.45 up to 384 and 5.08 at
+#: 768), so the rule stands as it is
 DENSE_ROWS_MAX = 512
 #: rows of one step of the grouped pass (one expert's weights a step)
 GROUP_BLOCK_ROWS = 256

@@ -628,6 +628,11 @@ class BlockManager:
         return max((len(ring) for ring in self._slot_ring), default=0)
 
     @property
+    def window_blocks_held(self) -> int:
+        """Window-kind blocks in the slots' rings now."""
+        return sum(len(ring) for ring in self._slot_ring)
+
+    @property
     def budget_reduction(self) -> int:
         return self._budget_reduction
 
@@ -755,7 +760,7 @@ class BlockManager:
         # both kinds in the totals a poll of the pool reads (the share of
         # the pools' blocks that back a slot's rows), the window kind's own
         # beside them
-        held = sum(len(ring) for ring in self._slot_ring)
+        held = self.window_blocks_held
         stats.update(
             num_blocks=stats["num_blocks"] + self.window_layout.num_blocks,
             free_blocks=stats["free_blocks"] + len(self._wfree),

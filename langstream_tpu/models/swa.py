@@ -1,21 +1,32 @@
 """Decoder whose attention layers are of two kinds in one stack, window and
-full (the ``afmoe`` family: Trinity-Large-Preview), over a paged pool a kind.
+full, over a paged pool a kind. Two members (:class:`SwaConfig` states what
+each has; what a member lacks traces nothing and holds no weights):
 
-A layer is ``x <- x + N_post(Attn(N_in(x)))``, ``x <- x + N_post(FFN(N_pre(
-x)))`` (sandwich norms: one before and one AFTER each sub-layer); the first
-``dense_layers`` layers' FFN is one gated MLP, every later layer's the routed
-experts beside a shared one (``moe_mixer`` of :mod:`langstream_tpu.models.
-hybrid`, sigmoid scores + a selection bias, the chosen scores renormalised
-and scaled). The embedding is scaled by ``sqrt(hidden)``; the head is untied.
+- ``afmoe`` (Trinity-Large-Preview): ``x <- x + N_post(Attn(N_in(x)))``,
+  ``x <- x + N_post(FFN(N_pre(x)))`` (sandwich norms: one before and one
+  AFTER each sub-layer); an elementwise output gate, ``W_o [o * sigmoid(W_g
+  h)]``; the first ``dense_layers`` layers' FFN one gated MLP, every later
+  layer's the routed experts beside a shared one (sigmoid scores + a
+  selection bias, the chosen scores renormalised and scaled); the embedding
+  scaled by ``sqrt(hidden)``; window layers rotated, full layers not;
+- ``mellum`` (Mellum2-12B-A2.5B): ``x <- x + Attn(N_in(x))``, ``x <- x +
+  FFN(N_pre(x))``; no gate, no norm after a sub-layer, no dense layer, no
+  shared expert, the embedding as it is; the experts' weights the softmax of
+  the chosen logits; window layers rotated plainly and full layers by YaRN
+  (a frequency table and an attention factor on cos and sin of their own).
+
+The routed experts are ``moe_mixer`` of :mod:`langstream_tpu.models.hybrid`;
+the head is untied.
 
 **Attention, every layer**: grouped queries with an RMSNorm of each query
-and key head (one gain of ``head_dim`` each, shared by the heads) and an
-elementwise output gate, ``W_o [o * sigmoid(W_g h)]``. **What the layer's
-kind decides** (``layer_kinds``: ``W`` window, ``F`` full):
+and key head (``qk_norm``: one gain of ``head_dim`` each, shared by the
+heads). **What the layer's kind decides** (``layer_kinds``: ``W`` window,
+``F`` full):
 
-- a ``W`` layer rotates queries and keys (half-split rotary over the whole
-  head) and query ``i`` sees the keys ``j`` with ``0 <= i - j < window``;
-- an ``F`` layer applies no rotation and sees every key before it.
+- its rotation (:class:`Rope`, one a kind, or none: half-split rotary over
+  the whole head);
+- a ``W`` layer's query ``i`` sees the keys ``j`` with ``0 <= i - j <
+  window``, an ``F`` layer's every key before it.
 
 **Two pools** (:func:`langstream_tpu.models.paged.init_kv_pool`, one a
 kind): the full layers' ``(F layers, blocks, bs, Kh*D)``, in which a slot
@@ -58,6 +69,7 @@ from langstream_tpu.models.llama import (
     _flash_mode,
     _rms_norm,
     _rope,
+    yarn_inv_freq,
 )
 from langstream_tpu.models.llama_paged import (
     _cache_partial_xla,
@@ -82,6 +94,30 @@ DENSE_FFN_ROWS = 4096
 
 
 @dataclasses.dataclass(frozen=True)
+class Rope:
+    """One layer kind's rotation: plain rotary at ``theta``, or YaRN where
+    ``factor`` is over 1 (its frequency table from ``original_max`` and the
+    two betas, cos and sin multiplied by ``attention_factor``, so a layer's
+    scores carry its square)."""
+
+    theta: float = 10000.0
+    factor: float = 1.0
+    original_max: int = 0
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 1.0
+
+    def cos_sin(self, positions: jax.Array, head_dim: int):
+        if self.factor <= 1.0:
+            return _rope(positions, head_dim, self.theta)
+        angles = positions[..., None].astype(jnp.float32) * yarn_inv_freq(
+            head_dim, self.theta, self.factor, self.original_max,
+            self.beta_fast, self.beta_slow)
+        return (jnp.cos(angles) * self.attention_factor,
+                jnp.sin(angles) * self.attention_factor)
+
+
+@dataclasses.dataclass(frozen=True)
 class SwaConfig:
     # the fields the dense family's config has, under the same names
     vocab_size: int = 25024
@@ -91,17 +127,23 @@ class SwaConfig:
     kv_heads: int = 8
     head_dim: int = 128
     intermediate: int = 12288        # the dense layers' gated MLP
-    rope_theta: float = 10000.0      # the window layers' alone
     norm_eps: float = 1e-5
     max_seq_len: int = 16384
     dtype: Any = jnp.bfloat16
     # the two kinds of attention layer
     window: int = 4096               # a W layer's query i sees i - j < window
     layer_kinds: str = "WWFWW"       # of the layers held: W window, F full
+    window_rope: Rope | None = Rope()   # a kind's rotation, None for none
+    full_rope: Rope | None = None
+    # what a member's attention and residual have
+    qk_norm: bool = True             # each query and key head RMS-normed
+    output_gate: bool = True         # W_o [o * sigmoid(W_g h)]
+    post_norms: bool = True          # a norm AFTER each sub-layer
+    embed_scaled: bool = True        # the embedding times sqrt(hidden)
     # FFN
     dense_layers: int = 1            # leading layers with the gated MLP
     moe_intermediate: int = 3072     # one routed expert's width
-    shared_intermediate: int = 3072  # num_shared_experts x moe_intermediate
+    shared_intermediate: int = 3072  # the shared expert's width, 0 for none
     experts: int = 256
     experts_per_token: int = 4
     routed_scale: float = 2.448      # route_scale, on the renormalised scores
@@ -110,7 +152,7 @@ class SwaConfig:
     experts_held: int = 32
     expert_first: int = 0
     # what moe_mixer reads of a family
-    router: str = "sigmoid"
+    router: str = "sigmoid"          # or "softmax": models/moe.py
     expert_act: str = "silu_gated"
     #: recurrent state beside the pools: none
     state_bytes_per_slot: int = 0
@@ -122,8 +164,8 @@ class SwaConfig:
                 f"layers as W (window) or F (full)")
         if not ("W" in self.layer_kinds and "F" in self.layer_kinds):
             raise ValueError("at least one window and one full layer")
-        if not 0 < self.dense_layers < self.layers:
-            raise ValueError("at least one dense and one expert layer")
+        if not 0 <= self.dense_layers < self.layers:
+            raise ValueError("at least one expert layer")
         if not 0 <= self.expert_first <= self.experts - self.experts_held:
             raise ValueError("the held experts lie outside the router's")
 
@@ -150,6 +192,56 @@ class SwaConfig:
             experts=8, experts_per_token=2, experts_held=experts_held,
             expert_first=expert_first, max_seq_len=max_seq_len,
         )
+
+    @classmethod
+    def mellum2_12b_a2_5b_8l(cls, max_seq_len: int = 8768) -> "SwaConfig":
+        """JetBrains/Mellum2-12B-A2.5B-Instruct as stage 0 of four pipeline
+        stages: layers 0-7 of 28 (two periods ``WWWF``) whole, all 64
+        experts of each and the whole vocabulary (the last norm and the
+        head ride here so that the stage gives logits). Both rotations at
+        ``theta`` 500,000, the full layers' YaRN as published
+        (``rope_parameters``); the published ``intermediate_size`` 7,168 is
+        carried and unused: every layer's FFN is sparse."""
+        return cls(
+            vocab_size=98304, hidden=2304, layers=8, heads=32, kv_heads=4,
+            head_dim=128, intermediate=7168, norm_eps=1e-6, window=1024,
+            layer_kinds="WWWFWWWF", window_rope=Rope(theta=500000.0),
+            full_rope=Rope(theta=500000.0, factor=16.0, original_max=8192,
+                           beta_fast=32.0, beta_slow=1.0,
+                           attention_factor=1.2772588722239782),
+            output_gate=False, post_norms=False, embed_scaled=False,
+            dense_layers=0, moe_intermediate=896, shared_intermediate=0,
+            experts=64, experts_per_token=8, experts_held=64,
+            router="softmax", max_seq_len=max_seq_len,
+        )
+
+    @classmethod
+    def mellum_tiny(cls, max_seq_len: int = 256) -> "SwaConfig":
+        """Test size of that member: two periods ``WWWF``, a window of 32
+        rows, 8 experts top-2 all held, and YaRN over an original length of
+        64 rows at ``theta`` 10,000 so that the ramp's three regions all
+        occur among the head's 8 frequencies (dimension 0 kept, 1 and 2 on
+        the ramp, 3-7 divided by the factor)."""
+        return cls(
+            vocab_size=384, hidden=64, layers=8, heads=4, kv_heads=2,
+            head_dim=16, intermediate=96, norm_eps=1e-6, window=32,
+            layer_kinds="WWWFWWWF", window_rope=Rope(theta=10000.0),
+            full_rope=Rope(theta=10000.0, factor=8.0, original_max=64,
+                           beta_fast=8.0, beta_slow=1.0,
+                           attention_factor=1.2079441541679836),
+            output_gate=False, post_norms=False, embed_scaled=False,
+            dense_layers=0, moe_intermediate=32, shared_intermediate=0,
+            experts=8, experts_per_token=2, experts_held=8,
+            router="softmax", max_seq_len=max_seq_len,
+        )
+
+    @property
+    def rope_theta(self) -> float:
+        """The window layers' ``theta``, under the dense family's name."""
+        return self.window_rope.theta if self.window_rope else 0.0
+
+    def rope_of(self, kind: str) -> Rope | None:
+        return self.window_rope if kind == "W" else self.full_rope
 
     @property
     def sparse_layers(self) -> int:
@@ -188,13 +280,15 @@ class SwaConfig:
 def init_swa_params(config: SwaConfig, key: jax.Array | None = None) -> dict:
     """Random parameters from a key, one jitted draw a leaf. An expert's
     weights depend on its GLOBAL id and its layer, so the shares of one
-    deployment are slices of the same experts. The embedding is drawn at
-    ``1 / sqrt(hidden)`` so that the scaled embedding has a spread of 1, as
-    every sub-layer's normed output has: with a unit embedding the residual
-    would be the token's own row fifty times over whatever the layers add,
-    and no comparison of logits would see them. The gains of the query, key
-    and post norms and the router's selection bias are drawn away from
-    trivial values: a term left out changes the logits."""
+    deployment are slices of the same experts. The embedding is drawn so
+    that what enters the first layer has a spread of 1, as every sub-layer's
+    normed output has (at ``1 / sqrt(hidden)`` where the program scales it:
+    with a unit embedding the residual would be the token's own row fifty
+    times over whatever the layers add, and no comparison of logits would
+    see them). The gains of the query, key and post norms and the router's
+    selection bias are drawn away from trivial values: a term left out
+    changes the logits. A leaf the member lacks (the gate, a post norm, the
+    selection bias, the shared expert) is not drawn."""
     c = config
     key = key if key is not None else jax.random.PRNGKey(0)
     H, D, names = c.hidden, c.head_dim, iter(range(10 ** 6))
@@ -221,50 +315,55 @@ def init_swa_params(config: SwaConfig, key: jax.Array | None = None) -> dict:
             * scale).astype(c.dtype)))(held)
 
     def attention():
-        return {
+        ap = {
             "norm": jnp.ones((H,), c.dtype),
             "wq": normal((H, c.heads * D), H),
             "wk": normal((H, c.kv_heads * D), H),
             "wv": normal((H, c.kv_heads * D), H),
-            "wg": normal((H, c.heads * D), H),
-            "wo": normal((c.heads * D, H), c.heads * D),
-            "q_norm": gain((D,)),
-            "k_norm": gain((D,)),
-            "post_norm": gain((H,)),
         }
+        if c.output_gate:
+            ap["wg"] = normal((H, c.heads * D), H)
+        ap["wo"] = normal((c.heads * D, H), c.heads * D)
+        if c.qk_norm:
+            ap["q_norm"], ap["k_norm"] = gain((D,)), gain((D,))
+        if c.post_norms:
+            ap["post_norm"] = gain((H,))
+        return ap
 
     I, Ie, Is = c.intermediate, c.moe_intermediate, c.shared_intermediate
     layers = []
     for layer in range(c.layers):
         lp = {"attn": attention()}
         if layer < c.dense_layers:
-            lp["ffn"] = {
+            sub = lp["ffn"] = {
                 "norm": jnp.ones((H,), c.dtype),
                 "w_up": normal((H, 2 * I), H),          # [gate | up]
                 "w_down": normal((I, H), I),
-                "post_norm": gain((H,)),
             }
         else:
             kb = jax.random.fold_in(key, next(names))
-            lp["moe"] = {
+            sub = lp["moe"] = {
                 "norm": jnp.ones((H,), c.dtype),
                 # the model's type; the logits are float32 (moe.py)
                 "router": normal((H, c.experts), H),
+            }
+            if c.router == "sigmoid":
                 # small beside the spread of the scores, as a trained bias
                 # is: the scores decide the winners and the bias the ties
-                "bias": jax.random.uniform(
-                    kb, (c.experts,), jnp.float32, -0.02, 0.02),
-                # (held, 2 I, H) and (held, I, H), as the other families'
-                # gated experts (models/moe.py dropless_experts)
-                "w_up": experts(layer, (2 * Ie, H), H),
-                "w_down": experts(layer, (Ie, H), Ie),
-                "ws_up": normal((H, 2 * Is), H),
-                "ws_down": normal((Is, H), Is),
-                "post_norm": gain((H,)),
-            }
+                sub["bias"] = jax.random.uniform(
+                    kb, (c.experts,), jnp.float32, -0.02, 0.02)
+            # (held, 2 I, H) and (held, I, H), as the other families'
+            # gated experts (models/moe.py dropless_experts)
+            sub["w_up"] = experts(layer, (2 * Ie, H), H)
+            sub["w_down"] = experts(layer, (Ie, H), Ie)
+            if Is:
+                sub["ws_up"] = normal((H, 2 * Is), H)
+                sub["ws_down"] = normal((Is, H), Is)
+        if c.post_norms:
+            sub["post_norm"] = gain((H,))
         layers.append(lp)
     return {
-        "embed": normal((c.vocab_size, H), H),
+        "embed": normal((c.vocab_size, H), H if c.embed_scaled else 1),
         "final_norm": jnp.ones((H,), c.dtype),
         "lm_head": normal((H, c.vocab_size), H),
         "layers": layers,
@@ -278,6 +377,8 @@ def init_swa_params(config: SwaConfig, key: jax.Array | None = None) -> dict:
 
 def _embed(c: SwaConfig, params: dict, tokens: jax.Array) -> jax.Array:
     x = params["embed"][tokens]
+    if not c.embed_scaled:
+        return x
     return (x.astype(jnp.float32) * math.sqrt(c.hidden)).astype(x.dtype)
 
 
@@ -288,34 +389,49 @@ def _logits(params: dict, x: jax.Array) -> jax.Array:
 def _projections(c: SwaConfig, ap: dict, x: jax.Array, kind: str,
                  positions: jax.Array):
     """``(q (..., heads, D), k, v (..., kv_heads, D), gate (..., heads * D)
-    float32 logits)`` of a layer's normed input: each query and key head
-    normed, and rotated at ``positions`` where the layer attends a window."""
+    logits, or None without an output gate)`` of a layer's normed input:
+    each query and key head normed (``qk_norm``), and rotated at
+    ``positions`` by its kind's rotation, where it has one (scope ``rope`` on
+    a window layer, ``rope_full`` on a full one)."""
     lead = x.shape[:-1]
     with jax.named_scope("attn_qkv"):
         h = _rms_norm(x, ap["norm"], c.norm_eps)
         q = (h @ ap["wq"]).reshape(lead + (c.heads, c.head_dim))
         k = (h @ ap["wk"]).reshape(lead + (c.kv_heads, c.head_dim))
         v = (h @ ap["wv"]).reshape(lead + (c.kv_heads, c.head_dim))
-        gate = h @ ap["wg"]
-    with jax.named_scope("qk_norm"):
-        q = _rms_norm(q, ap["q_norm"], c.norm_eps)
-        k = _rms_norm(k, ap["k_norm"], c.norm_eps)
-    if kind == "W":
-        with jax.named_scope("rope"):
-            cos, sin = _rope(positions, c.head_dim, c.rope_theta)
+        gate = h @ ap["wg"] if c.output_gate else None
+    if c.qk_norm:
+        with jax.named_scope("qk_norm"):
+            q = _rms_norm(q, ap["q_norm"], c.norm_eps)
+            k = _rms_norm(k, ap["k_norm"], c.norm_eps)
+    rope = c.rope_of(kind)
+    if rope is not None:
+        with jax.named_scope("rope" if kind == "W" else "rope_full"):
+            cos, sin = rope.cos_sin(positions, c.head_dim)
             q, k = _apply_rope(q, cos, sin), _apply_rope(k, cos, sin)
     return q, k, v, gate
 
 
+def _residual(c: SwaConfig, sub: dict, x: jax.Array, out: jax.Array):
+    """``x + N_post(out)``, or ``x + out`` for a member without post norms;
+    ``sub`` is the sub-layer's weights."""
+    if not c.post_norms:
+        return x + out
+    with jax.named_scope("post_norm"):
+        return x + _rms_norm(out, sub["post_norm"], c.norm_eps)
+
+
 def _attention_out(c: SwaConfig, ap: dict, x: jax.Array, out: jax.Array,
-                   gate: jax.Array) -> jax.Array:
-    """``x + N_post(W_o [out * sigmoid(gate)])``; ``out (..., heads * D)``."""
-    with jax.named_scope("attn_gate"):
-        out = out * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(out.dtype)
+                   gate: jax.Array | None) -> jax.Array:
+    """``x + N_post(W_o [out * sigmoid(gate)])``, gate and norm where the
+    member has them; ``out (..., heads * D)``."""
+    if gate is not None:
+        with jax.named_scope("attn_gate"):
+            out = out * jax.nn.sigmoid(
+                gate.astype(jnp.float32)).astype(out.dtype)
     with jax.named_scope("attn_out"):
         a = out @ ap["wo"]
-    with jax.named_scope("post_norm"):
-        return x + _rms_norm(a, ap["post_norm"], c.norm_eps)
+    return _residual(c, ap, x, a)
 
 
 def _dense_ffn(c: SwaConfig, fp: dict, x: jax.Array) -> jax.Array:
@@ -331,8 +447,7 @@ def _dense_ffn(c: SwaConfig, fp: dict, x: jax.Array) -> jax.Array:
             ).reshape(T, -1)
         else:
             f = one(h)
-    with jax.named_scope("post_norm"):
-        return x + _rms_norm(f, fp["post_norm"], c.norm_eps)
+    return _residual(c, fp, x, f)
 
 
 def _experts(c: SwaConfig, ep: dict, x: jax.Array, valid: jax.Array):
@@ -340,8 +455,7 @@ def _experts(c: SwaConfig, ep: dict, x: jax.Array, valid: jax.Array):
     H)``."""
     out, load, chosen = moe_mixer(
         c, ep, _rms_norm(x, ep["norm"], c.norm_eps), valid)
-    with jax.named_scope("post_norm"):
-        return x + _rms_norm(out, ep["post_norm"], c.norm_eps), load, chosen
+    return _residual(c, ep, x, out), load, chosen
 
 
 def split_tables(block_tables: jax.Array):
@@ -670,9 +784,12 @@ def _pool_rows(mc, block_mgr, rows):
     ``live_rows`` counts the full layers' whole history);
     ``pool_rows_held``, the rows both kinds hold for the running slots over
     all layers, in whole blocks; ``pool_rows_one_table``, what ONE table for
-    all layers would hold for them (every layer every block); and
+    all layers would hold for them (every layer every block);
     ``window_slot_blocks_max``, the most window blocks any slot holds (never
-    more than the ring)."""
+    more than the ring); ``short_slots``, the running slots whose rows are
+    fewer than the window (their rings are not full and their window layers
+    read what a full layer reads); and ``window_blocks_held``, the window
+    kind's blocks in slots' rings now (of ``slots x ring``)."""
     bs = block_mgr.layout.block_size
     blocks = -(-rows // bs)
     held = (mc.full_layers * blocks
@@ -683,6 +800,8 @@ def _pool_rows(mc, block_mgr, rows):
         "pool_rows_held": int(held),
         "pool_rows_one_table": int(blocks.sum() * bs * mc.layers),
         "window_slot_blocks_max": block_mgr.window_slot_blocks_max,
+        "short_slots": int((rows < mc.window).sum()),
+        "window_blocks_held": block_mgr.window_blocks_held,
     }
 
 
@@ -692,6 +811,8 @@ FAMILY = Family(
     presets={
         "trinity-tiny": "tiny",
         "trinity-large-preview-ep8": "trinity_large_preview_ep8",
+        "mellum-tiny": "mellum_tiny",
+        "mellum2-12b-a2.5b-8l": "mellum2_12b_a2_5b_8l",
     },
     what="keeps a second pool for its window layers, a ring of blocks a slot",
     refusals={

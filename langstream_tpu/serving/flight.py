@@ -384,7 +384,8 @@ class FlightRecorder:
         ``pool_rows`` (a decode chunk of a model with a pool a layer kind,
         models/swa.py) joins the sample key by key: ``window_rows``,
         ``pool_rows_held``, ``pool_rows_one_table``,
-        ``window_slot_blocks_max`` (engine.py ``_pool_rows``).
+        ``window_slot_blocks_max``, ``short_slots``,
+        ``window_blocks_held`` (engine.py ``_pool_rows``).
         ``clock`` is the dispatch's times as :class:`DispatchClock` and
         :func:`resumed` left them: the sample's ``gap_ms``, ``program_ms``
         and ``resume_lag_ms``, each omitted where it was not taken."""

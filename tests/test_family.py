@@ -207,6 +207,9 @@ TABLE_BEFORE = {
     "deepseek-v2-ep8": ("latent", "deepseek_v2_ep8"),
     "trinity-tiny": ("swa", "tiny"),
     "trinity-large-preview-ep8": ("swa", "trinity_large_preview_ep8"),
+    # PR 46: the first names to land in a family that was there
+    "mellum-tiny": ("swa", "mellum_tiny"),
+    "mellum2-12b-a2.5b-8l": ("swa", "mellum2_12b_a2_5b_8l"),
 }
 CONFIG_CLASS = {"hybrid": hybrid.HybridConfig, "latent": latent.LatentConfig,
                 "swa": swa.SwaConfig}

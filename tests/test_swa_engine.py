@@ -244,15 +244,11 @@ def test_every_option_that_assumes_one_table_of_kv_is_refused_by_name(option):
         assert "not reusable past the window" in str(e.value)
 
 
-def test_the_lowered_programs_carry_the_two_kinds_scopes(run_async):
-    decode_scopes = ("embed", "attn_qkv", "qk_norm", "rope", "swa_read",
-                     "full_read", "attn_buf", "attn_gate", "attn_out",
-                     "post_norm", "ffn", "moe_router", "moe_dispatch",
-                     "moe_experts", "moe_shared", "moe_combine", "lm_head",
-                     "sample", "kv_write", "swa_write")
-
+def lowered_programs(engine_config):
+    """``(decode text, decode program's name, prefill text)`` of an engine's
+    own greedy programs, with the scopes in the text."""
     async def main():
-        engine = TpuServingEngine(config(paged_kernel="pallas-interpret"))
+        engine = TpuServingEngine(engine_config)
         try:
             slots = engine.config.slots
             mode = engine._sampler_mode(np.zeros(1, np.float32),
@@ -275,7 +271,18 @@ def test_the_lowered_programs_carry_the_two_kinds_scopes(run_async):
         finally:
             await engine.close()
 
-    text, name, prefill = run_async(main())
+    return main()
+
+
+def test_the_lowered_programs_carry_the_two_kinds_scopes(run_async):
+    decode_scopes = ("embed", "attn_qkv", "qk_norm", "rope", "swa_read",
+                     "full_read", "attn_buf", "attn_gate", "attn_out",
+                     "post_norm", "ffn", "moe_router", "moe_dispatch",
+                     "moe_experts", "moe_shared", "moe_combine", "lm_head",
+                     "sample", "kv_write", "swa_write")
+
+    text, name, prefill = run_async(lowered_programs(
+        config(paged_kernel="pallas-interpret")))
     assert "decode_chunk" in name          # what the trace readers look for
     for scope in decode_scopes:
         assert re.search(rf'[/"]{scope}/', text), scope
@@ -283,3 +290,112 @@ def test_the_lowered_programs_carry_the_two_kinds_scopes(run_async):
     for scope in ("swa_flash", "full_flash", "qk_norm", "rope", "attn_gate",
                   "post_norm", "ffn", "moe_experts", "swa_write", "kv_write"):
         assert re.search(rf'[/"]{scope}/', prefill), scope
+
+
+# ---------------------------------------------------------------------------
+# the family's second member (mellum-tiny: no gate, no post norm, no dense
+# layer, no shared expert, all 8 experts held, YaRN on the full layers)
+# ---------------------------------------------------------------------------
+
+# under the window (32) to its end, past the ring's wrap (40 rows) while it
+# decodes, far past both from the start, and two that wait for a freed slot
+MELLUM_PROMPTS = [list(range(7, 7 + n)) for n in (5, 28, 120, 9, 60)]
+
+
+def mellum_config(**kw):
+    return config(**{"model": "mellum-tiny", "slots": 3, **kw})
+
+
+@pytest.fixture(scope="module")
+def mellum_alone(run_async_module):
+    async def main():
+        engine = TpuServingEngine(mellum_config())
+        try:
+            return [(await engine.generate(p, greedy(20)))["tokens"]
+                    for p in MELLUM_PROMPTS]
+        finally:
+            await engine.close()
+
+    return run_async_module(main())
+
+
+def test_the_engine_knows_the_second_member_s_names():
+    swa = _family_of("mellum-tiny")
+    assert swa is _family_of("trinity-tiny") is _family_of(
+        "mellum2-12b-a2.5b-8l")
+    assert swa.presets["mellum2-12b-a2.5b-8l"] == "mellum2_12b_a2_5b_8l"
+    real = _resolve_model_config("mellum2-12b-a2.5b-8l", 8768)
+    assert real == SwaConfig.mellum2_12b_a2_5b_8l() and real.max_seq_len == 8768
+
+
+@pytest.mark.parametrize("kernel", ["xla", "pallas-interpret"])
+def test_short_and_long_slots_and_a_freed_ring_in_one_batch(
+        run_async, mellum_alone, kernel):
+    """Three slots, five requests: a slot under the window beside one past
+    its ring's wrap, and the fourth and fifth admitted into rings the first
+    ones freed while the long one still runs."""
+    async def main():
+        engine = TpuServingEngine(mellum_config(paged_kernel=kernel))
+        try:
+            outs = await asyncio.gather(
+                *(engine.generate(p, greedy(20)) for p in MELLUM_PROMPTS))
+            return ([o["tokens"] for o in outs], engine.family,
+                    engine.block_mgr.stats(), engine.flight.recent(128))
+        finally:
+            await engine.close()
+
+    streams, family, kv, samples = run_async(main())
+    assert streams == mellum_alone and family == "swa"
+    assert all(len(set(s)) > 2 for s in streams)
+    assert kv["live_blocks"] == kv["reserved_blocks"] == 0
+    assert kv["window_ring_blocks"] == 5 and kv["window_num_blocks"] == 3 * 5 + 1
+    decode = [s for s in samples if s["phase"] == "decode"]
+    # slots shorter than the window ran beside longer ones
+    assert any(0 < s["short_slots"] < s["active_at_dispatch"] for s in decode)
+    for s in decode:
+        assert 0 <= s["short_slots"] <= s["active_at_dispatch"]
+        assert 0 < s["window_blocks_held"] <= 3 * 5
+        assert s["window_slot_blocks_max"] <= 5
+        # 2 winners of 8 experts, all held, 8 layers
+        assert 0 < s["routed_pairs"] <= s["steps"] * s["active_at_dispatch"] * 2 * 8
+    # the long slot's ring is full, a short slot's is not
+    assert max(s["window_blocks_held"] for s in decode) > 5
+    assert all("short_slots" not in s for s in samples if s["phase"] != "decode")
+
+
+def test_the_first_member_s_samples_carry_the_two_gauges_too(run_async):
+    async def main():
+        engine = TpuServingEngine(config())
+        try:
+            await asyncio.gather(
+                *(engine.generate(p, greedy(12)) for p in PROMPTS[:3]))
+            return engine.flight.recent(32)
+        finally:
+            await engine.close()
+
+    decode = [s for s in run_async(main()) if s["phase"] == "decode"]
+    assert decode
+    for s in decode:
+        assert 0 <= s["short_slots"] <= s["active_at_dispatch"]
+        assert 0 < s["window_blocks_held"] <= 4 * 5
+
+
+def test_the_second_member_s_programs_name_its_own_scopes(run_async):
+    text, _, prefill = run_async(lowered_programs(
+        mellum_config(paged_kernel="pallas-interpret")))
+    for scope in ("embed", "attn_qkv", "qk_norm", "rope", "rope_full",
+                  "swa_read", "full_read", "attn_buf", "attn_out",
+                  "moe_router", "moe_dispatch", "moe_experts", "moe_combine",
+                  "lm_head", "sample", "kv_write", "swa_write"):
+        assert re.search(rf'[/"]{scope}/', text), scope
+    for scope in ("swa_flash", "full_flash", "rope", "rope_full", "moe_experts"):
+        assert re.search(rf'[/"]{scope}/', prefill), scope
+    for scope in ("attn_gate", "post_norm", "moe_shared", "ffn"):
+        assert not re.search(rf'[/"]{scope}/', text), scope
+        assert not re.search(rf'[/"]{scope}/', prefill), scope
+
+
+def test_the_second_member_refuses_the_same_options_by_name():
+    with pytest.raises(ValueError) as e:
+        TpuServingEngine(mellum_config(prefix_cache=True))
+    assert "prefix-cache" in str(e.value) and "mellum-tiny" in str(e.value)

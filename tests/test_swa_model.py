@@ -10,6 +10,7 @@ uncut reference's layer); and the block manager with two kinds."""
 
 import dataclasses
 import os
+import re
 import sys
 
 import jax
@@ -397,3 +398,195 @@ def test_one_kind_is_what_it_was():
     m.ensure_capacity(0, 100)
     assert "window_live_blocks" not in m.stats()
     assert m.stats()["live_blocks"] == 13 and m.window_blocks_needed(100) == 0
+
+
+# ---------------------------------------------------------------------------
+# the family's second member: no gate, no post norm, no dense layer, no
+# shared expert, a rotation a layer kind (bench/reference/mellum.py)
+# ---------------------------------------------------------------------------
+
+from langstream_tpu.models.llama import yarn_inv_freq  # noqa: E402
+from langstream_tpu.models.swa import Rope  # noqa: E402
+from reference import mellum  # noqa: E402
+
+#: the tiny member's rotations, as its bench fixture states them
+TINY_ROPE = {
+    "sliding_attention": {"rope_type": "default", "rope_theta": 10000.0},
+    "full_attention": {
+        "rope_type": "yarn", "rope_theta": 10000.0, "factor": 8.0,
+        "original_max_position_embeddings": 64, "beta_fast": 8.0,
+        "beta_slow": 1.0, "attention_factor": 1.2079441541679836},
+}
+
+
+@pytest.fixture(scope="module")
+def mellum_model():
+    c = dataclasses.replace(
+        SwaConfig.mellum_tiny(max_seq_len=MAX_LEN), dtype=jnp.float32)
+    return c, init_swa_params(c, jax.random.PRNGKey(5))
+
+
+def test_the_mellum_presets_are_the_published_grammar():
+    c, real = SwaConfig.mellum_tiny(), SwaConfig.mellum2_12b_a2_5b_8l()
+    assert c.layer_kinds == real.layer_kinds == "WWWFWWWF"
+    assert (real.dense_layers, real.sparse_layers, real.layers) == (0, 8, 8)
+    assert real.kind_index == (0, 1, 2, 0, 3, 4, 5, 1)
+    assert real.ring_blocks(64) == 17 and c.ring_blocks(BS) == 5
+    assert (real.hidden, real.heads, real.kv_heads, real.head_dim,
+            real.moe_intermediate, real.experts, real.experts_held,
+            real.experts_per_token, real.window, real.vocab_size,
+            real.norm_eps, real.router, real.shared_intermediate) == (
+        2304, 32, 4, 128, 896, 64, 64, 8, 1024, 98304, 1e-6, "softmax", 0)
+    assert real.rope_theta == real.full_rope.theta == 500000.0
+    assert not (real.output_gate or real.post_norms or real.embed_scaled)
+    assert real.qk_norm and c.qk_norm
+    assert (c.experts, c.experts_held, c.experts_per_token) == (8, 8, 2)
+    # the other member says what it has too, and is what it was
+    trinity = SwaConfig.trinity_large_preview_ep8()
+    assert trinity.window_rope == Rope(theta=10000.0) and \
+        trinity.full_rope is None and trinity.rope_theta == 10000.0
+    assert trinity.output_gate and trinity.post_norms and trinity.embed_scaled
+
+
+def test_yarn_s_table_is_the_closed_form_at_the_published_numbers():
+    real = SwaConfig.mellum2_12b_a2_5b_8l().full_rope
+    table = yarn_inv_freq(128, real.theta, real.factor, real.original_max,
+                          real.beta_fast, real.beta_slow)
+    i = np.arange(64)
+    f = 500000.0 ** (-i / 64)
+    r = np.clip((i - 18) / 17, 0, 1)            # low 18, high 35
+    np.testing.assert_allclose(table, f * (1 - r) + f / 16 * r, rtol=1e-6)
+    np.testing.assert_allclose(table[:19], f[:19], rtol=1e-6)        # kept
+    np.testing.assert_allclose(table[35:], f[35:] / 16, rtol=1e-6)   # divided
+    assert np.all(table[19:35] < f[19:35]) and np.all(
+        table[19:35] > f[19:35] / 16)                                # the ramp
+    assert abs(real.attention_factor - (0.1 * np.log(16) + 1)) < 1e-12
+    assert round(real.attention_factor, 5) == 1.27726
+    # the reference's own table, from the published section alone
+    np.testing.assert_allclose(
+        mellum.inv_freq(64, mellum.ROPE["full_attention"]), table, rtol=1e-6)
+    np.testing.assert_allclose(
+        mellum.inv_freq(64, mellum.ROPE["sliding_attention"]), f, rtol=1e-12)
+    # cos and sin carry the factor; a plain rotation none
+    cos, sin = real.cos_sin(jnp.arange(3), 128)
+    np.testing.assert_allclose(np.asarray(cos[0]), 1.2772588722239782, rtol=1e-6)
+    np.testing.assert_allclose(
+        np.asarray(cos ** 2 + sin ** 2), 1.2772588722239782 ** 2, rtol=1e-5)
+
+
+def test_the_tiny_ramp_has_its_three_regions():
+    rope = SwaConfig.mellum_tiny().full_rope
+    table = yarn_inv_freq(16, rope.theta, rope.factor, rope.original_max,
+                          rope.beta_fast, rope.beta_slow)
+    f = 10000.0 ** (-np.arange(8) / 8)
+    ratio = table / f
+    assert ratio[0] == pytest.approx(1.0)                 # kept
+    assert 1 / 8 < ratio[2] < ratio[1] < 1.0              # on the ramp
+    np.testing.assert_allclose(ratio[3:], 1 / 8, rtol=1e-6)   # divided
+    np.testing.assert_allclose(
+        mellum.inv_freq(8, TINY_ROPE["full_attention"]), table, rtol=1e-6)
+    assert rope.attention_factor == pytest.approx(0.1 * np.log(8) + 1)
+
+
+def test_a_member_holds_no_leaf_and_traces_no_op_it_lacks(mellum_model):
+    c, params = mellum_model
+    assert sorted(params["layers"][0]) == ["attn", "moe"]
+    for lp in params["layers"]:
+        assert sorted(lp["attn"]) == ["k_norm", "norm", "q_norm", "wk", "wo",
+                                      "wq", "wv"]
+        assert sorted(lp["moe"]) == ["norm", "router", "w_down", "w_up"]
+    bare = init_swa_params(dataclasses.replace(c, qk_norm=False))
+    assert "q_norm" not in bare["layers"][0]["attn"]
+    # the embedding enters the first layer as it is, at a spread of 1
+    assert 0.9 < float(jnp.std(params["embed"])) < 1.1
+    manager, pk, pv, wpool = pools(c)
+    tables = jnp.asarray(manager.tables)
+    decode = DECODE.lower(
+        c, params, jnp.zeros(SLOTS, jnp.int32), jnp.ones(SLOTS, jnp.int32),
+        jnp.ones(SLOTS, bool), pk, pv, wpool, tables, jax.random.PRNGKey(0),
+        4, "xla").as_text(debug_info=True)
+    prefill = PREFILL.lower(
+        c, params, jnp.zeros((1, 64), jnp.int32), jnp.full((1,), 50),
+        pk, pv, wpool, tables[:1]).as_text(debug_info=True)
+    for text in (decode, prefill):
+        for scope in ("attn_gate", "post_norm", "moe_shared", "ffn"):
+            assert not re.search(rf'[/"]{scope}/', text), scope
+        for scope in ("rope", "rope_full", "qk_norm", "moe_router",
+                      "moe_experts", "moe_combine"):
+            assert re.search(rf'[/"]{scope}/', text), scope
+
+
+# prompts shorter than the window (32) that stay there, one that passes it
+# and the ring's wrap (40 rows) while it decodes, one three times it
+@pytest.mark.parametrize("kernel, prompts, steps, chunk", [
+    ("xla", (4, 28, 96), 24, 8),
+    ("pallas-interpret", (4, 28, 96), 16, 8),
+    ("xla", (45, 7, 64), 48, 12),
+])
+def test_the_second_member_s_prefill_then_decode_match_its_reference(
+        mellum_model, kernel, prompts, steps, chunk):
+    c, params = mellum_model
+    manager, pk, pv, wpool = pools(c)
+    rng = np.random.default_rng(11)
+    tokens = [rng.integers(0, c.vocab_size, size=n, dtype=np.int32)
+              for n in prompts]
+    first = np.zeros((SLOTS,), np.int32)
+    first_logits = {}
+    for slot, row in enumerate(tokens):
+        manager.admit(slot, row.size + steps + 1)
+        manager.ensure_capacity(slot, row.size + steps + 1)
+        bucket = 32
+        while bucket < row.size:
+            bucket *= 2
+        padded = np.zeros((1, bucket), np.int32)
+        padded[0, : row.size] = row
+        logits, pk, pv, wpool, _ = PREFILL(
+            c, params, jnp.asarray(padded), jnp.asarray([row.size]), pk, pv,
+            wpool, jnp.asarray(manager.tables[slot][None]))
+        first_logits[slot] = np.asarray(logits)[0]
+        first[slot] = int(np.argmax(first_logits[slot]))
+    lengths = np.array(list(prompts) + [0] * (SLOTS - len(prompts)), np.int32)
+    t0, n, active = jnp.asarray(first), jnp.asarray(lengths), jnp.asarray(lengths > 0)
+    made, logits_made = [], []
+    for _ in range(steps // chunk):
+        out = DECODE(
+            c, params, t0, n, active, pk, pv, wpool,
+            jnp.asarray(manager.tables), jax.random.PRNGKey(0), chunk, kernel)
+        t0, n, pk, pv, wpool = out[2:7]
+        made.append(np.asarray(out[0]))
+        logits_made.append(np.asarray(out[1]))
+    made, logits_made = np.concatenate(made), np.concatenate(logits_made)
+    for slot, row in enumerate(tokens):
+        sequence = np.concatenate([row, first[slot:slot + 1], made[:-1, slot]])
+        positions = list(range(row.size - 1, row.size + steps))
+        want, _, rows = mellum.forward(c, params, sequence, positions,
+                                       rope=TINY_ROPE)
+        got = np.concatenate([first_logits[slot][None], logits_made[:, slot]])
+        assert np.abs(got - want).max() < 2e-3 * want.std(), (slot, kernel)
+        ring_rows = c.ring_blocks(BS) * BS
+        end = row.size + steps
+        held = np.arange(max(0, row.size - c.window, end - ring_rows), end)
+        blocks = manager.window_tables[slot, held // BS]
+        k_got = np.asarray(wpool["k"])[0, blocks, held % BS]
+        v_got = np.asarray(wpool["v"])[0, blocks, held % BS]
+        np.testing.assert_allclose(
+            np.concatenate([k_got, v_got], -1), rows[held], atol=2e-4)
+    # with the published numbers in the tiny preset's place the reference
+    # is another model: the check holds the program to the file's numbers
+    other, _, _ = mellum.forward(c, params, sequence, positions)
+    assert np.abs(got - other).max() > 0.05 * other.std()
+
+
+@pytest.mark.parametrize("fault", mellum.FAULTS)
+def test_each_fault_changes_the_second_member_s_logits(mellum_model, fault):
+    """The reference with one term changed gives other logits than itself
+    (what the bench test then holds to the file's limits)."""
+    c, params = mellum_model
+    tokens = np.random.default_rng(2).integers(0, c.vocab_size, size=90)
+    positions = [30, 60, 89]
+    want, _, _ = mellum.forward(c, params, tokens, positions, rope=TINY_ROPE)
+    got, _, _ = mellum.forward(c, params, tokens, positions, (fault,),
+                               rope=TINY_ROPE)
+    rms = np.sqrt(np.mean((got - want) ** 2, -1)) / want.std(-1)
+    floor = 1e-4 if fault == "bfloat16_router" else 5e-3
+    assert rms.max() > floor, (fault, rms)

@@ -1,21 +1,22 @@
-"""A model of the window-and-full family (``trinity-large-preview-ep8``) at
-its published widths on the chip, without the benchmark's harness around it
-(``tools/hybrid_probe.py``'s twin for ``models/swa.py``): builds the engine
-from a configuration's ``serving`` block, runs the reference check as the
-file states it (``bench/reference/afmoe.py``) over ``--seeds``, with
-``--faults`` judges the first seed's served output against each faulty
-reference (each has to come out as not passed), then one prefill of every
-bucket the cell's prompts reach and a full batch of decodes, with the
-allocator's peak after each: the numbers the configuration's ``memory``
-quotes.
+"""A model of the window-and-full family (``--config``: ``trinity-large-
+preview-ep8``, ``mellum2-12b-a2.5b-8l``) at its published widths on the chip,
+without the benchmark's harness around it (``tools/hybrid_probe.py``'s twin
+for ``models/swa.py``): builds the engine from the configuration's
+``serving`` block, runs the reference check the file names
+(``bench/reference/afmoe.py``, ``mellum.py``) over ``--seeds``, with
+``--faults`` judges the last seed's served output against each faulty
+reference (each has to come out as not passed), with ``--crossover`` times
+one expert layer's dense and grouped pass by rows, then one prefill of every
+bucket a slot can hold and two full batches of decodes, with the allocator's
+peak after each: the numbers the configuration's ``memory`` quotes.
 
     chiprun -- python3 tools/swa_probe.py [--config <name or file>]
-        [--seeds n ...] [--faults] [--checks-only] [--no-checks]
-        [--no-warmup] [--trace 1]
+        [--seeds n ...] [--faults] [--crossover] [--checks-only]
+        [--no-checks] [--no-warmup] [--trace 1]
 
 ``--trace 1`` ends with the device time of a decode step and of a prefill by
-scope. ``--rehearse-cpu`` walks the path at the tiny preset here. Refuses to
-run off a TPU otherwise. Prints one JSON line last."""
+scope. ``--rehearse-cpu`` walks the path at the configuration's tiny preset
+here. Refuses to run off a TPU otherwise. Prints one JSON line last."""
 
 from __future__ import annotations
 
@@ -31,6 +32,8 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, os.path.join(ROOT, "bench"))
 sys.path.insert(1, ROOT)
 
+#: the member's test-size twin by the reference its file names (the other
+#: member's is its bench test's fixture: :func:`tiny_of`)
 TINY = {
     "reference": "afmoe",
     "serving": {"model": "trinity-tiny", "slots": 6, "max-seq-len": 512,
@@ -58,6 +61,60 @@ REPORT_KEYS = (
     "held_pairs_a_token_decode", "decode_tokens_distinct")
 
 
+def tiny_of(config: dict) -> dict:
+    if config["reference"] == "afmoe":
+        return TINY
+    with open(os.path.join(ROOT, "tests", "bench", "fixtures", "wf", "configs",
+                           "mellum-tiny.json")) as f:
+        tiny = json.load(f)
+    tiny["serving"]["model-dtype"] = "float32"
+    return tiny
+
+
+def crossover(engine, rows_list=(64, 128, 192, 256, 384, 512, 768, 1024),
+              blocks=(256, 128, 64)) -> list[dict]:
+    """One expert layer's routed pass at the served widths and share, dense
+    against grouped (at each of ``blocks`` rows a step), by rows:
+    milliseconds a call, the mean of 10 after a warm-up
+    (``tools/hybrid_probe.py`` ``crossover`` for this family's weights).
+    ``models/moe.py`` ``DENSE_ROWS_MAX`` is held to this."""
+    import jax
+
+    from langstream_tpu.models import moe
+
+    c = engine.model_config
+    lp = engine.params["layers"][c.dense_layers]["moe"]
+    act = moe.EXPERT_ACTS[c.expert_act]
+    out = []
+    for rows in rows_list:
+        h = jax.random.normal(jax.random.PRNGKey(rows), (rows, c.hidden), c.dtype)
+        if c.router == "sigmoid":
+            experts, weights = moe.sigmoid_topk_routing(
+                h, lp["router"], lp["bias"], c.experts_per_token, c.routed_scale)
+        else:
+            experts, weights = moe.softmax_topk_routing(
+                h, lp["router"], c.experts_per_token)
+        row = {"rows": rows}
+        passes = [("dense", moe.dropless_experts_dense, {})] + [
+            (f"grouped_{b}", moe.dropless_experts_grouped, {"block_rows": b})
+            for b in blocks]
+        for name, fn, kw in passes:
+            # the weights as arguments: closed over, 0.8 GB of them would be
+            # constants of every one of these programs
+            call = jax.jit(lambda h, e, w, up, down, fn=fn, kw=kw: fn(
+                h, e, w, up, down, c.expert_first, act=act, **kw)[0])
+            args = (h, experts, weights, lp["w_up"], lp["w_down"])
+            call(*args).block_until_ready()
+            t = time.monotonic()
+            for _ in range(10):
+                y = call(*args)
+            y.block_until_ready()
+            row[f"{name}_ms"] = round((time.monotonic() - t) * 100, 3)
+        print(f"[probe] routed pass, one layer: {json.dumps(row)}", flush=True)
+        out.append(row)
+    return out
+
+
 def memory(stage: str) -> dict:
     import jax
 
@@ -71,9 +128,10 @@ def memory(stage: str) -> dict:
 def by_scope(path: str, program: str, over: float) -> dict:
     """Device milliseconds of ``program``'s operations by scope over
     ``over`` (steps, or runs), the sixteen largest."""
-    from lib import roofline_swa
+    from lib import roofline_wf
 
-    reduced = roofline_swa.scope_seconds(path, program)
+    # the family's scopes and the full layers' rotation's
+    reduced = roofline_wf.scope_seconds(path, program)
     per = lambda table: {  # noqa: E731
         k: round(1e3 * v / over, 3)
         for k, v in sorted(table.items(), key=lambda kv: -kv[1])[:16]}
@@ -90,11 +148,10 @@ async def run(args) -> dict:
 
     from langstream_tpu.serving.engine import ServingConfig, TpuServingEngine
 
+    with open(args.config) as f:
+        config = json.load(f)
     if args.rehearse_cpu:
-        config = TINY
-    else:
-        with open(args.config) as f:
-            config = json.load(f)
+        config = tiny_of(config)
     if args.no_warmup:
         config["serving"]["warmup-on-start"] = False
     reference = importlib.import_module(f"reference.{config['reference']}")
@@ -107,6 +164,9 @@ async def run(args) -> dict:
     out["pools"] = {k: v for k, v in engine.block_mgr.stats().items()
                     if "num_blocks" in k or "ring" in k}
     tolerance = config["reference_tolerance"]
+    if args.crossover:
+        sizes = ((16, 64), (8,)) if args.rehearse_cpu else ()
+        out["crossover"] = await asyncio.to_thread(crossover, engine, *sizes)
     got = None
     for seed in [] if args.no_checks else args.seeds:
         t = time.monotonic()
@@ -148,9 +208,18 @@ async def run(args) -> dict:
 
     slots = int(config["serving"]["slots"])
     longest = int(config["serving"]["max-seq-len"])
-    waves = ((1, 40, 2), (1, 90, 2), (slots, 150, 33)) if args.rehearse_cpu else (
-        (1, 1000, 2), (1, 8000, 2), (1, 14300, 2), (2, 8000, 2),
-        (slots, 9000, 65), (slots, min(14300, longest - 200), 129))
+    # one prompt at seven eighths of every prefill bucket a slot can hold,
+    # then two full batches: at half and at the whole of a slot's share of
+    # the full kind's pool
+    buckets, b = [], 32 if args.rehearse_cpu else 128
+    while b - b // 8 + 2 < longest:
+        buckets.append(b)
+        b *= 2
+    share = min(longest, engine.paged_layout.num_blocks
+                * engine.paged_layout.block_size // slots) - 140
+    waves = [(1, b - b // 8, 2) for b in buckets] + [
+        (slots, share // 2, 33 if args.rehearse_cpu else 65),
+        (slots, share, 33 if args.rehearse_cpu else 129)]
     for n, prompt, max_tokens in waves:
         before = engine.flight.recorded
         seconds = await wave(n, prompt, max_tokens)
@@ -172,10 +241,10 @@ async def run(args) -> dict:
         trace_dir = os.path.join(ROOT, "chiprun_out", "swa_probe_trace")
         # one prefill of the 8,192 bucket, then decode chunks of the full
         # batch over long slots, inside one trace
-        task = asyncio.ensure_future(wave(slots, 9000, 257))
+        task = asyncio.ensure_future(wave(slots, share - 140, 257))
         await asyncio.sleep(args.trace_after)
         await asyncio.to_thread(jax.profiler.start_trace, trace_dir)
-        one = await wave(1, 8000, 2)
+        one = await wave(1, min(8000, buckets[-1] - 200), 2)
         await asyncio.sleep(2.5)
         await asyncio.to_thread(jax.profiler.stop_trace)
         await task
@@ -211,6 +280,8 @@ def main() -> int:
                          "(its prefills are over by then)")
     ap.add_argument("--faults", action="store_true",
                     help="also judge the program against each faulty reference")
+    ap.add_argument("--crossover", action="store_true",
+                    help="also time the dense and the grouped expert pass by rows")
     ap.add_argument("--checks-only", action="store_true")
     ap.add_argument("--no-checks", action="store_true")
     ap.add_argument("--no-warmup", action="store_true",
