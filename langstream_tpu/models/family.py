@@ -55,6 +55,7 @@ class Family:
     prefill_selects_slots: bool = False  # sel is (tables, slot_ids)
     one_decode_window: bool = False      # max_blocks_per_slot, no buckets
     state_kernels: bool = False  # its state's kernels follow the paged read's
+    expert_kernels: bool = False  # so does its prefill's routed experts' pass
     pool_rows: Callable | None = None    # (mc, block_mgr, rows) -> a gauge
 
     def config(self, name: str, max_seq_len: int) -> Any:

@@ -876,7 +876,7 @@ def delta_step(c: HybridConfig, lp: dict, u: jax.Array, delta: jax.Array,
 
 
 def moe_mixer(c, lp: dict, h: jax.Array, valid: jax.Array,
-              layer: jax.Array | None = None):
+              layer: jax.Array | None = None, kernel: str | None = None):
     """Routed experts held here plus the shared expert (where the layer
     has one: ``ws_up`` and ``ws_down`` among its weights) over rows ``h (T,
     H)``; ``lp`` is one expert layer's weights, or with ``layer`` its
@@ -887,8 +887,14 @@ def moe_mixer(c, lp: dict, h: jax.Array, valid: jax.Array,
     expert's ``silu(a) * b`` lies between its matmuls, under their scope.
     ``c`` is this family's config or the latent family's (models/latent.py):
     what is read of it are the router's rule and numbers, the activation and
-    the share held."""
+    the share held. ``kernel`` is the programs' one selection (``"xla"`` |
+    ``"pallas"`` | ``"pallas-interpret"``), here the form of a prefill's
+    grouped pass (models/moe.py ``dropless_experts_grouped``; a decode
+    batch's dense pass reads none); a caller that hands none gets what the
+    engine resolves on this backend, as :func:`hybrid_prefill_paged`'s."""
     act = EXPERT_ACTS[c.expert_act]
+    if kernel is None:
+        kernel = "pallas" if jax.default_backend() == "tpu" else "xla"
     with jax.named_scope("moe_router"):
         if c.router == "sigmoid":
             experts, weights = sigmoid_topk_routing(
@@ -904,7 +910,7 @@ def moe_mixer(c, lp: dict, h: jax.Array, valid: jax.Array,
                 h, lp["router"], c.experts_per_token, c.router_dtype)
     routed, load = dropless_experts(
         h, experts, weights, lp["w_up"], lp["w_down"], c.expert_first, valid,
-        layer=layer, act=act,
+        layer=layer, act=act, kernel=kernel, of=c.experts,
     )
     if "ws_up" not in lp:
         # a layer without a shared expert (models/swa.py
@@ -1015,8 +1021,10 @@ def hybrid_prefill_paged(
     (blocks, B, P, k)`` are the experts the router chose, for the reference
     check (a caller that drops it pays nothing for it). ``kernel`` is the
     engine's one selection for the recurrent state's kernels
-    (``ssm_state_kernel``); in a prefill only a delta-rule block reads it
-    (:func:`delta_prefill`). A caller that hands none gets, as with
+    (``ssm_state_kernel``) and the routed experts' (``moe_grouped_kernel``);
+    in a prefill a delta-rule block (:func:`delta_prefill`) and the grouped
+    pass of every block's experts (:func:`moe_mixer`) read it. A caller that
+    hands none gets, as with
     ``use_flash``, what the engine resolves on this backend for the pool this
     family has (``"pallas"`` on a TPU, ``"xla"`` elsewhere): the reference
     check's model function (``bench/reference/solar_open2.py``
@@ -1133,7 +1141,8 @@ def hybrid_prefill_paged(
         vs = jax.lax.dynamic_update_index_in_dim(vs, v, a, 0)
         h = _rms_norm(x, ep["norm"], c.norm_eps).reshape(B * Pn, c.hidden)
         ep = dict(ep, w_up=params["moe"]["w_up"], w_down=params["moe"]["w_down"])
-        out, _, chosen = moe_mixer(c, ep, h, real.reshape(-1), layer=i)
+        out, _, chosen = moe_mixer(
+            c, ep, h, real.reshape(-1), layer=i, kernel=kernel)
         x = _residual(c, x, out.reshape(B, Pn, c.hidden))
         return (x, ks, vs) + tuple(rec[kind] for kind in kinds), \
             chosen.reshape(B, Pn, -1)
@@ -1423,4 +1432,5 @@ FAMILY = Family(
     prefill_compiler_options=_prefill_compiler_options,
     prefill_selects_slots=True,
     state_kernels=True,
+    expert_kernels=True,
 )

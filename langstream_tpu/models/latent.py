@@ -361,13 +361,17 @@ def latent_prefill_paged(
     pool: jax.Array,          # (layers, nb, bs, row_width)
     block_tables: jax.Array,  # (B, max_blocks): rows of THIS batch
     use_flash: bool | None = None,
+    kernel: str | None = None,
 ):
     """Prompt forward through the expanded attention; every layer's latent
     rows land in the pool through :func:`langstream_tpu.models.paged.
     write_rows`, the one commit of every family. Returns ``(last-token
     logits (B, V), pool, routed)``; ``routed (expert layers, B, P, k)`` are
     the experts the router chose, for the reference check (a caller that
-    drops it pays nothing for it)."""
+    drops it pays nothing for it). ``kernel`` is the engine's one selection,
+    here the form of the routed experts' grouped pass
+    (``moe_grouped_kernel``); a caller that hands none gets what the engine
+    resolves on this backend (``moe_mixer``)."""
     c = config
     B, Pn = tokens.shape
     positions = jnp.arange(Pn)
@@ -454,7 +458,8 @@ def latent_prefill_paged(
         x, rows = attention(x, ap)
         h = _rms_norm(x, ep["norm"], c.norm_eps).reshape(B * Pn, c.hidden)
         ep = dict(ep, w_up=moe["w_up"], w_down=moe["w_down"])
-        out, _, chosen = moe_mixer(c, ep, h, real.reshape(-1), layer=i)
+        out, _, chosen = moe_mixer(
+            c, ep, h, real.reshape(-1), layer=i, kernel=kernel)
         return x + out.reshape(B, Pn, c.hidden), \
             (rows, chosen.reshape(B, Pn, -1))
 
@@ -648,10 +653,10 @@ def latent_decode_chunk_paged(
 
 def _family_prefill(mc, params, residents, tokens, lengths, tables,
                     use_flash=None, kernel=None):
-    # ``kernel`` selects a recurrent state's kernels; this family has none
     pool, cache_v = residents
     logits, pool, _routed = latent_prefill_paged(
-        mc, params, tokens, lengths, pool, tables, use_flash=use_flash)
+        mc, params, tokens, lengths, pool, tables, use_flash=use_flash,
+        kernel=kernel)
     return logits, (pool, cache_v)
 
 
@@ -698,4 +703,5 @@ FAMILY = Family(
     # family is long (a window bucket every power of two would be four more
     # programs of its five-layer step)
     one_decode_window=True,
+    expert_kernels=True,
 )

@@ -377,24 +377,39 @@ def moe_param_count(config: MoEConfig) -> int:
 
 #: rows up to which the dense pass over the held experts is taken: every
 #: held expert's weights are streamed anyway at a decode batch, and the
-#: pass has no sort, gather or scatter, while the grouped pass costs at
-#: least one 256-row block a held expert that any row chose. Measured on
-#: the v5e at the published share (16 experts of 2 x 2688 x 1856, top 6 of
-#: 128; tools/hybrid_probe.py --crossover, ms a layer, dense / grouped):
-#: 256 rows 0.56 / 1.44, 512 rows 1.09 / 1.24, 768 rows 1.56 / 1.29,
-#: 2,048 rows 4.36 / 1.47. At a WHOLE layer of experts (64 held of 64 of
-#: 2 x 896 x 2304, top 8: every expert is chosen by some row from 64 rows
-#: on, and the dense pass does 64 / 8 = 8 times the routed products;
-#: tools/swa_probe.py --config mellum2-12b-a2.5b-8l --crossover, PR 46) the
-#: crossover lies between the same two row counts: 64 rows 1.13 / 3.73,
-#: 128 rows 1.14 / 3.70, 192 rows 1.17 / 3.76 (0.97 ms stream the layer's
-#: 793 MB), 256 rows 1.29 / 3.79, 384 rows 1.89 / 3.72, 512 rows 2.66 /
-#: 4.57, 768 rows 4.15 / 3.84, 1,024 rows 5.70 / 3.97; the grouped pass's
-#: 64 steps cost 58 us each whatever their rows (a block of 128 rows: 2.77
-#: to 3.11 ms up to 768 rows; of 64 rows: 2.34 to 2.45 up to 384 and 5.08 at
-#: 768), so the rule stands as it is
-DENSE_ROWS_MAX = 512
-#: rows of one step of the grouped pass (one expert's weights a step)
+#: pass has no sort, gather or scatter. Past it the grouped pass, whose form
+#: is the programs' kernel selection (:func:`dropless_experts_grouped`).
+#: Measured on the v5e, one layer, ms a call, dense / grouped as the XLA loop
+#: of 256-row blocks / grouped through the kernel (PR 47; both probes build
+#: the served engine and time its first expert layer under its own router).
+#: At the published share of 16 experts of 2 x 2688 x 1856, top 6 of 128
+#: (tools/hybrid_probe.py --crossover): 64 rows 0.49 / 1.11 / 0.53, 128 rows
+#: 0.49 / 1.18 / 0.56, 256 rows 0.56 / 1.44 / 0.58, 384 rows 0.82 / 1.23 /
+#: 0.60, 512 rows 1.08 / 1.25 / 0.63, 768 rows 1.55 / 1.30 / 0.70, 1,024
+#: rows 2.33 / 1.97 / 0.86, 2,048 rows 4.36 / 1.47 / 1.40, 4,096 rows - /
+#: 1.68 / 2.25, 8,192 rows - / 3.53 / 4.20 (there the loop's float32 result,
+#: 44 and 88 MB, is kept in VMEM by the compiler and its scatter-adds cost a
+#: third of what they cost in HBM; no cell of that share prefills more than
+#: 2,048 rows). At a WHOLE layer of experts, 64 held of 64 of 3 x 896 x 2304,
+#: top 8 (tools/swa_probe.py --config mellum2-12b-a2.5b-8l --crossover): 64
+#: rows 1.13 / 3.71 / 1.19, 128 rows 1.14 / 3.67 / 1.23, 256 rows 1.27 / 3.78
+#: / 1.29 (0.97 ms stream the layer's 793 MB), 384 rows 1.89 / 3.70 / 1.37,
+#: 512 rows 2.66 / 4.55 / 1.44, 768 rows 4.14 / 3.84 / 1.78, 1,024 rows 5.70
+#: / 3.94 / 2.01, 2,048 rows 13.1 / 6.28 / 3.17, 4,096 rows - / 10.4 / 5.20,
+#: 8,192 rows - / 21.0 / 11.5. Both tables put the crossover between 256 and
+#: 384 rows (it lay between 512 and 768 while the loop was the only grouped
+#: form, whose 64 steps cost 58 us each whatever their rows: 0.83 of
+#: dispatch, 1.61 of matmuls and 1.17 of scatter-adds at 1,024 rows, where
+#: the kernel's pass has 0.26, 1.29 and 0.37), and every cell's decode batch
+#: is 32 to 192 rows: bench/lib/roofline_wf.py prices the decode step by
+#: this constant, under this name
+DENSE_ROWS_MAX = 256
+#: the same bound where the grouped pass is the XLA loop (the CPU, a mesh,
+#: and a chip that holds a SHARE of the experts: below): the loop's crossover
+#: with the dense pass, between 512 and 768 rows in both tables above
+LOOP_DENSE_ROWS_MAX = 512
+#: rows of one step of the grouped pass's XLA loop (one expert's weights a
+#: step), and of one scatter-add of the kernel form's combine at a share
 GROUP_BLOCK_ROWS = 256
 
 
@@ -527,37 +542,66 @@ def dropless_experts_dense(
     return out, load
 
 
+def _pairs_by_expert(experts, first, held, valid):
+    """The dispatch both grouped forms share: ``(here (T, k) bool, order
+    (T*k,), counts (held,), sorted_start (held,))``: the pairs whose expert
+    is held and whose row counts, their indices sorted by held expert (the
+    others last), and each held expert's pairs and the first of them in that
+    order."""
+    local = experts - first
+    here = (local >= 0) & (local < held)
+    if valid is not None:
+        here = here & valid[:, None]
+    group = jnp.where(here, local, held).reshape(-1)          # (T*k,)
+    order = jnp.argsort(group)          # stable: pairs by held expert
+    counts = jnp.bincount(group, length=held + 1)[:held].astype(jnp.int32)
+    return here, order, counts, jnp.cumsum(counts) - counts
+
+
 def dropless_experts_grouped(
     x: jax.Array, experts: jax.Array, weights: jax.Array,
     w_up: jax.Array, w_down: jax.Array, first: int,
-    valid: jax.Array | None = None, block_rows: int = GROUP_BLOCK_ROWS,
-    layer: jax.Array | None = None, act=relu2,
+    valid: jax.Array | None = None, block_rows: int | None = None,
+    layer: jax.Array | None = None, act=relu2, kernel: str = "xla",
+    of: int | None = None,
 ) -> tuple[jax.Array, jax.Array]:
-    """The chosen pairs sorted by held expert, each expert's run padded to
-    whole blocks of ``block_rows``, and one block a step: gather its rows,
-    the expert's matmuls with ``act`` between them, scale by the routing
-    weight, scatter-add. The loop runs as many steps as there are blocks, so
-    the work follows the pairs routed here and not the worst case. Same
-    results as :func:`dropless_experts_dense`.
+    """The chosen pairs sorted by held expert and each run of one expert's
+    rows through that expert's matmuls with ``act`` between them, scaled by
+    the routing weight and summed a token: the work follows the pairs routed
+    here and not the worst case. Same results as
+    :func:`dropless_experts_dense`.
+
+    ``kernel`` is the programs' one selection. ``"pallas"`` (``"pallas-
+    interpret"`` on the CPU): the rows are permuted ONCE into the sorted
+    order, one kernel walks the runs (``ops/grouped_experts.py``; ``block_rows``
+    the rows of one of its products, its ``SUB_ROWS`` where None), and the
+    results are permuted back once, a gather of each token's ``k`` where a
+    quarter or more of the ``of`` experts are held, scatter-adds of the rows
+    routed here where fewer are (:func:`_grouped_by_kernel`; ``of`` None: all
+    are held). ``"xla"``: a loop of one block of
+    ``block_rows`` (default :data:`GROUP_BLOCK_ROWS`) a step, each expert's
+    run padded to whole blocks: gather its rows, the matmuls, scatter-add;
+    the CPU's form, the tests' second opinion, and what a mesh of more than
+    one device runs.
 
     With ``layer``, ``w_up`` and ``w_down`` are the stacks ``(layers, held,
     I, H)`` of a model that scans its layers, and a step reads
     ``w_up[layer, e]`` from them: the scan's own slice of the layer would be
     a copy of all its held experts a layer (the loop inside cannot read
     through it), whoever is chosen."""
+    if kernel != "xla":
+        if kernel not in ("pallas", "pallas-interpret"):
+            raise ValueError(f"dropless_experts_grouped: unknown kernel {kernel!r}")
+        return _grouped_by_kernel(
+            x, experts, weights, w_up, w_down, first, valid, block_rows,
+            layer, act, of, interpret=(kernel == "pallas-interpret"))
     T, k = experts.shape
     held = w_up.shape[-3]
     at = (lambda w, e: w[e]) if layer is None else (lambda w, e: w[layer, e])
-    R = block_rows
+    R = block_rows or GROUP_BLOCK_ROWS
     with jax.named_scope("moe_dispatch"):
-        local = experts - first
-        here = (local >= 0) & (local < held)
-        if valid is not None:
-            here = here & valid[:, None]
-        group = jnp.where(here, local, held).reshape(-1)          # (T*k,)
-        order = jnp.argsort(group)          # stable: pairs by held expert
-        counts = jnp.bincount(group, length=held + 1)[:held].astype(jnp.int32)
-        sorted_start = jnp.cumsum(counts) - counts
+        _, order, counts, sorted_start = _pairs_by_expert(
+            experts, first, held, valid)
         blocks_of = -(-counts // R)
         block_end = jnp.cumsum(blocks_of)
         num_blocks = block_end[-1]
@@ -586,16 +630,185 @@ def dropless_experts_grouped(
     return out, counts
 
 
+#: bytes of the sorted rows one call of the kernel is handed (and returns):
+#: a prefill is cut into chunks of tokens whose pairs routed HERE should
+#: fill no more (all ``k`` of a token where every expert is held; ``held /
+#: of`` of them in the mean at an expert-parallel cut, whose 16,384-row
+#: bucket has 98,304 pairs, 1 GB of rows in and out, an eighth of them
+#: routed here). 4,096 rows of Mellum's layer are 151 MB: one chunk streams
+#: the layer's 793 MB of experts once, two of 2,048 rows twice (the kernel
+#: 3.2 ms against 2 x 1.95, tools/routed_pass.py, PR 47)
+GROUP_PIECE_BYTES = 160 * 1024 * 1024
+#: bytes of the results one gather of the combine takes (a part of a chunk's
+#: tokens, ``k`` rows each; the gather is an array of its own, in the
+#: model's type and in float32, and costs the same 46 ns a row whatever its
+#: size)
+GROUP_PART_BYTES = 32 * 1024 * 1024
+
+
+def _parts(n: int, fits) -> int:
+    """The fewest equal parts of ``n`` for which ``fits(n // parts)``."""
+    return next(p for p in range(1, n + 1) if n % p == 0 and fits(n // p))
+
+
+def _loop(turns, body, carry):
+    """``fori_loop`` from 0; a loop of ONE turn by its static bound is no
+    loop."""
+    return body(0, carry) if isinstance(turns, int) and turns == 1 else (
+        jax.lax.fori_loop(0, turns, body, carry))
+
+
+def _grouped_by_kernel(x, experts, weights, w_up, w_down, first, valid,
+                       block_rows, layer, act, of, interpret):
+    """:func:`dropless_experts_grouped` with the runs in one kernel: see
+    there. Three loops around ONE float32 result, each of one turn wherever
+    its bound allows, so that nothing beside the result is larger than
+    :data:`GROUP_PIECE_BYTES`:
+
+    - over equal chunks of the tokens (static), so that a chunk's pairs
+      routed here fill :data:`GROUP_PIECE_BYTES` in the mean: a chunk is a
+      whole pass of its own, dispatch to combine, and streams the experts
+      its rows chose once more;
+    - inside a chunk over pieces of its sorted pairs, as many as the pairs
+      routed HERE fill (dynamic; more than one only where this chip got
+      more than its share): a piece's rows are gathered once and its runs
+      go through the kernel;
+    - inside a piece the combine, in float32. Where a quarter of the experts
+      or more are held (``of`` None: all) over parts of the chunk's tokens
+      (static, :data:`GROUP_PART_BYTES` each), each token taking the ``k``
+      results of it that lie in the piece times their routing weights: one
+      gather a part, 46-70 ns a row of ALL ``T x k`` against a scatter-add's
+      110-480 of the rows routed here (36 of 72 held, 4,096 rows: 3.6 ms
+      against 9.8). Where fewer are held over the piece's LIVE rows,
+      :data:`GROUP_BLOCK_ROWS` at a time (dynamic), each added to its
+      token's row: at an eighth of the experts a gather of every token's
+      ``k`` costs twice the kernel, and a scatter-add of 256 rows costs
+      0.12-0.3 us a row where one of thousands costs 0.17-3.7
+      (tools/routed_pass.py, PR 47)."""
+    from langstream_tpu.ops import grouped_experts as ge
+
+    T, k = experts.shape
+    H = x.shape[1]
+    held, inter = w_down.shape[-3], w_down.shape[-2]
+    name = next(n for n, fn in EXPERT_ACTS.items() if fn is act)
+    if layer is None:
+        w_up, w_down, layer = w_up[None], w_down[None], 0
+    if valid is None:
+        valid = jnp.ones((T,), bool)
+    tiles = ge.plan(H, inter, ge.GATED[name], x.dtype.itemsize)
+    if block_rows is not None:
+        tiles["tile_rows"] = tiles["tile_rows"] // tiles["sub_rows"] * block_rows
+        tiles["sub_rows"] = block_rows
+    R = tiles["tile_rows"]
+    row_bytes = H * x.dtype.itemsize
+    piece_max = max(R, GROUP_PIECE_BYTES // row_bytes // R * R)
+    # every pair where all the experts are held; a third over this chip's
+    # share in the mean where they are not (more than that is a second piece)
+    share = 1.0 if of is None else min(1.0, held / of * 4 / 3)
+    chunks = _parts(T, lambda rows: rows * k * share <= piece_max)
+    Tc = T // chunks
+    whole = -(-Tc * k // R) * R
+    piece = min(-(-int(Tc * k * share) // R) * R, piece_max)
+    # the combine's parts: of the chunk's tokens where every expert is held,
+    # of the piece's live rows where a share is
+    parts = _parts(Tc, lambda rows: rows * k * row_bytes <= max(
+        GROUP_PART_BYTES, k * row_bytes))
+    Tp = Tc // parts
+    Rp = GROUP_BLOCK_ROWS
+    by_token = of is None or 4 * held >= of
+    rows_of = lambda a, at, n: jax.lax.dynamic_slice_in_dim(a, at, n)  # noqa: E731
+
+    def one_chunk(c, carry):
+        out, load = carry
+        with jax.named_scope("moe_dispatch"):
+            xc = rows_of(x, c * Tc, Tc)
+            wc = rows_of(weights, c * Tc, Tc)
+            here, order, counts, sorted_start = _pairs_by_expert(
+                rows_of(experts, c * Tc, Tc), first, held,
+                rows_of(valid, c * Tc, Tc))
+            # where each pair lies in the sorted order, and the token of
+            # each sorted row
+            place = jnp.argsort(order).astype(jnp.int32).reshape(Tc, k)
+            token = order // k
+
+        def one_piece(p, out):
+            p0 = p * piece
+            with jax.named_scope("moe_dispatch"):
+                xs = xc[token[jnp.clip(p0 + jnp.arange(piece), 0, Tc * k - 1)]]
+                starts = jnp.clip(sorted_start - p0, 0, piece)
+                ends = jnp.clip(sorted_start + counts - p0, 0, piece)
+            with jax.named_scope("moe_experts"):
+                ys = ge.grouped_experts(
+                    xs, w_up, w_down, layer, starts, ends - starts, act=name,
+                    interpret=interpret, **tiles)
+            at = place - p0
+            mine = here & (at >= 0) & (at < piece)
+
+            def tokens_take(i, out):
+                with jax.named_scope("moe_combine"):
+                    got = ys[jnp.clip(rows_of(at, i * Tp, Tp), 0, piece - 1)]
+                    # a row of no run holds whatever was there: selected
+                    # away, never multiplied by zero
+                    mixed = jnp.sum(jnp.where(
+                        rows_of(mine, i * Tp, Tp)[..., None],
+                        got.astype(jnp.float32)
+                        * rows_of(wc, i * Tp, Tp)[..., None], 0.0), axis=1)
+                    first_row = c * Tc + i * Tp
+                    return jax.lax.dynamic_update_slice_in_dim(
+                        out, rows_of(out, first_row, Tp) + mixed, first_row, 0)
+
+            def rows_add(i, out):
+                with jax.named_scope("moe_combine"):
+                    at = p0 + i * Rp + jnp.arange(Rp)
+                    pair = order[jnp.clip(at, 0, Tc * k - 1)]
+                    # a row past the pairs routed here goes nowhere
+                    row = jnp.where(at < total, c * Tc + pair // k, T)
+                    return out.at[row].add(
+                        rows_of(ys, i * Rp, Rp).astype(jnp.float32)
+                        * wc.reshape(-1)[pair][:, None], mode="drop")
+
+            if by_token:
+                return _loop(parts, tokens_take, out)
+            live = jnp.clip(total - p0, 0, piece)
+            return _loop(-(-live // Rp), rows_add, out)
+
+        total = sorted_start[-1] + counts[-1]
+        out = _loop(1 if piece == whole else -(-total // piece), one_piece, out)
+        return out, load + counts
+
+    return _loop(chunks, one_chunk, (
+        jnp.zeros((T, H), jnp.float32), jnp.zeros((held,), jnp.int32)))
+
+
+def grouped_form(kernel: str, held: int, of: int | None) -> tuple[str, int]:
+    """``(the grouped pass's form, the rows up to which the dense pass is
+    taken)`` for a pass handed ``kernel`` and ``held`` of ``of`` experts
+    (None: all). The kernel's pass where the selection is a kernel AND every
+    expert is held, from :data:`DENSE_ROWS_MAX` rows on; else the loop from
+    :data:`LOOP_DENSE_ROWS_MAX` on, as before PR 47. A share keeps the loop
+    because ``nemotron_h``'s prefill program of 8 x 512 rows (16 of 128
+    held) never returned in the cell's warm-up with the kernel's pass inside
+    its scan over blocks (one run, killed after 40 minutes; 1 x 1,024 to 4 x
+    1,024 and 8 x 256 had run, and the pass alone runs at every share in
+    tools/routed_pass.py): the cause is not found, and at a share the pass is
+    level with the loop, not ahead (PERF.md section 6, PR 47)."""
+    if kernel != "xla" and (of is None or of == held):
+        return kernel, DENSE_ROWS_MAX
+    return "xla", LOOP_DENSE_ROWS_MAX
+
+
 def dropless_experts(x, experts, weights, w_up, w_down, first, valid=None,
-                     layer=None, act=relu2):
+                     layer=None, act=relu2, kernel="xla", of=None):
     """Dropless routed experts: the dense pass for a decode batch, the
-    grouped pass for a prefill's rows. ``w_up`` and ``w_down`` are one
-    layer's ``(held, I or 2 I, H)`` and ``(held, I, H)``, or with ``layer``
-    the stacks of every layer's."""
-    if x.shape[0] > DENSE_ROWS_MAX:
+    grouped pass for a prefill's rows (``kernel`` and ``of``: which form of
+    it, and from how many rows: :func:`grouped_form`). ``w_up`` and
+    ``w_down`` are one layer's ``(held, I or 2 I, H)`` and ``(held, I, H)``,
+    or with ``layer`` the stacks of every layer's."""
+    kernel, dense_rows_max = grouped_form(kernel, w_up.shape[-3], of)
+    if x.shape[0] > dense_rows_max:
         return dropless_experts_grouped(
             x, experts, weights, w_up, w_down, first, valid, layer=layer,
-            act=act)
+            act=act, kernel=kernel, of=of)
     if layer is not None:
         w_up, w_down = w_up[layer], w_down[layer]
     return dropless_experts_dense(

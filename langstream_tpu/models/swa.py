@@ -450,11 +450,12 @@ def _dense_ffn(c: SwaConfig, fp: dict, x: jax.Array) -> jax.Array:
     return _residual(c, fp, x, f)
 
 
-def _experts(c: SwaConfig, ep: dict, x: jax.Array, valid: jax.Array):
+def _experts(c: SwaConfig, ep: dict, x: jax.Array, valid: jax.Array,
+             kernel: str | None = None):
     """``(x + N_post(experts(N_pre(x))), load, chosen)`` over rows ``x (T,
-    H)``."""
+    H)``; ``kernel`` is :func:`langstream_tpu.models.hybrid.moe_mixer`'s."""
     out, load, chosen = moe_mixer(
-        c, ep, _rms_norm(x, ep["norm"], c.norm_eps), valid)
+        c, ep, _rms_norm(x, ep["norm"], c.norm_eps), valid, kernel=kernel)
     return _residual(c, ep, x, out), load, chosen
 
 
@@ -480,6 +481,7 @@ def swa_prefill_paged(
     wpool: dict,              # {"k", "v"}: (window layers, window nb, bs, Kh*D)
     block_tables: jax.Array,  # (B, 2 x max_blocks): [full | window], THIS batch
     use_flash: bool | None = None,
+    kernel: str | None = None,
 ):
     """Prompt forward: every layer's K and V rows land in its kind's pool
     through :func:`langstream_tpu.models.paged.write_rows`, a full layer's
@@ -488,7 +490,10 @@ def swa_prefill_paged(
     a later one's within this very scatter). Returns ``(last-token logits
     (B, V), pool_k, pool_v, wpool, routed)``; ``routed (expert layers, B, P,
     k)`` are the experts the router chose, for the reference check (a caller
-    that drops it pays nothing for it)."""
+    that drops it pays nothing for it). ``kernel`` is the engine's one
+    selection, here the form of the routed experts' grouped pass
+    (``moe_grouped_kernel``); a caller that hands none gets what the engine
+    resolves on this backend (``moe_mixer``)."""
     c = config
     B, Pn = tokens.shape
     KhD = c.kv_heads * c.head_dim
@@ -539,7 +544,8 @@ def swa_prefill_paged(
                            ).reshape(B, Pn, c.hidden)
         else:
             x, _, chosen = _experts(
-                c, lp["moe"], x.reshape(B * Pn, c.hidden), real.reshape(-1))
+                c, lp["moe"], x.reshape(B * Pn, c.hidden), real.reshape(-1),
+                kernel)
             x = x.reshape(B, Pn, c.hidden)
             routed.append(chosen.reshape(B, Pn, -1))
     with jax.named_scope("lm_head"):
@@ -761,11 +767,10 @@ def _init_pools(mc, layout, slots):
 
 def _family_prefill(mc, params, residents, tokens, lengths, tables,
                     use_flash=None, kernel=None):
-    # ``kernel`` selects a recurrent state's kernels; this family has none
     cache_k, cache_v, wpool = residents
     logits, ck, cv, wp, _routed = swa_prefill_paged(
         mc, params, tokens, lengths, cache_k, cache_v, wpool, tables,
-        use_flash=use_flash)
+        use_flash=use_flash, kernel=kernel)
     return logits, (ck, cv, wp)
 
 
@@ -841,5 +846,6 @@ FAMILY = Family(
     # one decode program a chunk size, as the latent family: its reads walk
     # live blocks too, its window layers' from their first row
     one_decode_window=True,
+    expert_kernels=True,
     pool_rows=_pool_rows,
 )

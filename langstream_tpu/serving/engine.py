@@ -69,6 +69,7 @@ from langstream_tpu.models.encoder import (
     encoder_param_specs,
     init_encoder_params,
 )
+from langstream_tpu.models.moe import grouped_form
 from langstream_tpu.models.tokenizer import Tokenizer, load_tokenizer
 from langstream_tpu.serving.attribution import (
     ModelShape,
@@ -1819,6 +1820,23 @@ class TpuServingEngine:
         self.ssm_state_kernel = (
             kernel if self._fam is not None and self._fam.state_kernels
             else None)
+        # and so does the form of a prefill's routed experts' grouped pass
+        # (models/moe.py dropless_experts_grouped: ops/grouped_experts.py,
+        # or the XLA loop of one block a step). pallas_call has no rule to
+        # partition it, so more than one device is handed the loop; what the
+        # pass does with the selection at this model's share of the experts,
+        # and from how many rows, is models/moe.py grouped_form's to say
+        self._moe_kernel_handed = (
+            None if self._fam is None or not self._fam.expert_kernels
+            else kernel if self.mesh is None or self.mesh.size == 1
+            else "xla")
+        self.moe_grouped_kernel, self._grouped_rows_over = (
+            (None, None) if self._moe_kernel_handed is None else grouped_form(
+                self._moe_kernel_handed, mc.experts_held, mc.experts))
+        # prefill programs dispatched, and those of them whose rows took the
+        # grouped pass
+        self._prefill_dispatches = 0
+        self._prefill_dispatches_grouped = 0
         # continuation prefill / speculative verify read history
         # through the multi-query kernel, which has no int8 twin:
         # int8 pools take the XLA history sweep there, by selection
@@ -2068,7 +2086,9 @@ class TpuServingEngine:
                     logits, residents = fam.prefill(
                         mc_static, params, rest[:n], tokens, lengths, sel,
                         use_flash=prefill_flash,
-                        kernel=self.ssm_state_kernel)
+                        # the one selection, for whichever kernels the
+                        # family's prefill has
+                        kernel=self.ssm_state_kernel or self._moe_kernel_handed)
                     with jax.named_scope("sample"):
                         next_tokens, logprobs = sample_tokens(
                             logits, key, temps, topks,
@@ -3325,6 +3345,16 @@ class TpuServingEngine:
             # how a decode step's pass over the Mamba-2 state is lowered
             # (what mamba_step was handed; None without such state)
             "ssm_state_kernel": self.ssm_state_kernel,
+            # the form a prefill's routed experts' grouped pass takes
+            # (models/moe.py grouped_form of what the programs are handed
+            # and the share of the experts held; None without experts), and
+            # how often it engages: the prefill programs dispatched and
+            # those whose rows exceed that form's bound
+            "moe_grouped_kernel": self.moe_grouped_kernel,
+            "prefill_dispatches": self._prefill_dispatches,
+            "prefill_dispatches_grouped": (
+                None if self.moe_grouped_kernel is None
+                else self._prefill_dispatches_grouped),
             # watchdog verdict + warmup/readiness posture (serving/health.py)
             "health": self.health(),
             # drain-before-terminate posture + last drain's counts
@@ -6685,6 +6715,10 @@ class TpuServingEngine:
                 else self._prefill_continue_fn(mode, nrb)
             )
             key = self._split_key()
+            self._prefill_dispatches += 1
+            self._prefill_dispatches_grouped += (
+                self._grouped_rows_over is not None
+                and tokens.size > self._grouped_rows_over)
 
         times = ticket["clock"]
 

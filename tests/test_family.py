@@ -195,6 +195,50 @@ def test_a_family_defined_here_alone_is_served_as_the_one_it_wraps(
     assert facts["state_kernel"] == ("xla" if fam.state_kernels else None)
 
 
+async def _long_and_short(model, kernel):
+    """A prompt of 70 tokens (a prefill of 128 rows) and one of 20 (of 32)
+    through an engine under ``kernel``."""
+    engine = TpuServingEngine(ServingConfig(
+        model=model, model_dtype="float32", slots=2, max_seq_len=128,
+        kv_layout="paged", kv_block_size=8, prefix_cache=False,
+        prefill_batch=1, decode_chunk=4, decode_chunk_light=4,
+        paged_kernel=kernel,
+    ))
+    try:
+        outs = [await engine.generate(
+            list(range(5, 5 + n)), {"max-tokens": 5, "temperature": 0})
+            for n in (70, 20)]
+        return [o["tokens"] for o in outs], engine.stats()
+    finally:
+        await engine.close()
+
+
+@pytest.mark.parametrize("model, form", [
+    ("mellum-tiny", "pallas-interpret"),    # every expert held: the kernel
+    ("hybrid-tiny", "xla"), ("deepseek-tiny", "xla")])      # a share: the loop
+def test_the_one_selection_reaches_the_routed_pass_of_a_whole_layer(
+        run_async, monkeypatch, model, form):
+    """``paged_kernel`` is the routed experts' selection too: every family's
+    prefill is handed it, ``models/moe.py`` ``grouped_form`` takes the kernel
+    where every expert is held and keeps the loop at a share, and the engine
+    reports the form taken and how many prefill programs had the rows for it
+    (more than 32 here, for the test's time: 256 and 512 as served). The
+    kernel (interpreted here) streams what the loop streams."""
+    from langstream_tpu.models import moe
+
+    monkeypatch.setattr(moe, "DENSE_ROWS_MAX", 32)
+    monkeypatch.setattr(moe, "LOOP_DENSE_ROWS_MAX", 32)
+    kernel, stats = run_async(_long_and_short(model, "pallas-interpret"))
+    assert stats["moe_grouped_kernel"] == form
+    assert (stats["prefill_dispatches"], stats["prefill_dispatches_grouped"]) == (2, 1)
+    assert all(len(t) == 5 for t in kernel)
+    if form != "xla":      # the loop's engine, once
+        loop, stats = run_async(_long_and_short(model, "auto"))
+        assert stats["moe_grouped_kernel"] == "xla"      # auto, on the CPU
+        assert (stats["prefill_dispatches"], stats["prefill_dispatches_grouped"]) == (2, 1)
+        assert kernel == loop
+
+
 # name -> (family, classmethod): the engine's table as PR 43 had it
 TABLE_BEFORE = {
     "hybrid-tiny": ("hybrid", "tiny"),

@@ -274,6 +274,156 @@ def test_rows_that_do_not_count_route_nowhere(routed):
         assert int(load.sum()) == int((experts[valid] < 4).sum())
 
 
+def _kernel_case(seed=5, T=600, k=3, E=16, held=4, H=128, I=128,
+                 dtype=np.float32):
+    """``(x, experts (T, k) distinct, weights, w_up relu2, w_up gated,
+    w_down)`` at lane-aligned widths."""
+    rng = np.random.default_rng(seed)
+    x = rng.normal(size=(T, H)).astype(dtype)
+    experts = np.argsort(rng.normal(size=(T, E)), axis=1)[:, :k].astype(np.int32)
+    weights = rng.uniform(0.1, 1.0, size=(T, k)).astype(np.float32)
+    up = rng.normal(size=(held, 2 * I, H)).astype(dtype) / np.sqrt(H).astype(dtype)
+    down = rng.normal(size=(held, I, H)).astype(dtype) / np.sqrt(I).astype(dtype)
+    return x, experts, weights, up, down
+
+
+#: each case: what differs from 600 rows choosing 3 of 16 experts, 4 held
+#: from the fifth on, gated, 128 rows a product
+KERNEL_CASES = {
+    "relu2": dict(act="relu2"),
+    "gated-256-rows-a-product": dict(block_rows=256),
+    "relu2-256-rows-a-product": dict(act="relu2", block_rows=256),
+    "rows-by-the-rule": dict(block_rows=None),
+    "held-from-the-first": dict(first=0),
+    "a-valid-mask": dict(valid=True),
+    "rows-that-fill-no-block": dict(T=70),
+    "an-expert-with-no-pair": dict(avoid=5),
+    "one-expert-with-every-pair": dict(force=6),
+    "no-pair-held-here": dict(force=1),
+    "the-stacked-form": dict(layer=1),
+    "the-stacked-form-relu2-masked": dict(layer=1, act="relu2", valid=True),
+    "expert-width-in-tiles": dict(I=384, weight_tile_bytes=3 * 128 * 128 * 4),
+    "expert-width-in-tiles-relu2": dict(
+        I=384, act="relu2", weight_tile_bytes=2 * 128 * 128 * 4),
+    "chunks-of-the-tokens": dict(piece_bytes=1024 * 128 * 4),
+    "a-share-held": dict(of=16),
+    "an-eighth-held": dict(of=32),
+    "an-eighth-held-every-pair-here": dict(of=32, force=6, T=900),
+    "a-share-held-relu2-masked": dict(of=16, act="relu2", valid=True, layer=1),
+    "pieces-of-a-chunk": dict(piece_bytes=512 * 128 * 4, of=16, force=6),
+    "parts-of-a-chunk-s-tokens": dict(part_bytes=64 * 3 * 128 * 4),
+    "a-share-held-every-pair-here": dict(of=16, force=6, T=900),
+    "bfloat16": dict(dtype="bfloat16", valid=True),
+}
+
+
+@pytest.mark.parametrize("case", list(KERNEL_CASES))
+def test_the_kernel_pass_equals_the_dense_pass_and_the_loop(monkeypatch, case):
+    """``kernel="pallas-interpret"`` (models/moe.py ``_grouped_by_kernel``,
+    ops/grouped_experts.py) against ``dropless_experts_dense`` and against
+    the ``"xla"`` grouped form: the result, the load, and rows that do not
+    count exactly zero."""
+    from langstream_tpu.ops import grouped_experts as ge
+
+    o = dict(act="silu_gated", block_rows=128, first=4, T=600, I=128,
+             dtype="float32")
+    o.update(KERNEL_CASES[case])
+    if "weight_tile_bytes" in o:
+        monkeypatch.setattr(ge, "WEIGHT_TILE_BYTES", o["weight_tile_bytes"])
+        assert ge.plan(128, o["I"], o["act"] == "silu_gated", 4)["i_tiles"] == 3
+    if "piece_bytes" in o:
+        monkeypatch.setattr(moe, "GROUP_PIECE_BYTES", o["piece_bytes"])
+    if "part_bytes" in o:
+        monkeypatch.setattr(moe, "GROUP_PART_BYTES", o["part_bytes"])
+    x, experts, weights, up, down = _kernel_case(T=o["T"], I=o["I"])
+    if o["act"] == "relu2":
+        up = up[:, :o["I"]]
+    if "avoid" in o:
+        experts = np.where(experts == o["avoid"], 15, experts)
+    if "force" in o:
+        experts = np.full_like(experts, o["force"])
+    valid = jnp.asarray(np.arange(o["T"]) % 3 != 0) if o.get("valid") else None
+    dt = jnp.dtype(o["dtype"])
+    x, up, down = (jnp.asarray(a).astype(dt) for a in (x, up, down))
+    args = (x, jnp.asarray(experts), jnp.asarray(weights))
+    act = moe.EXPERT_ACTS[o["act"]]
+    dense, load = moe.dropless_experts_dense(
+        *args, up, down, o["first"], valid, act=act)
+    layer = o.get("layer")
+    if layer is not None:
+        up, down = (jnp.stack([jnp.zeros_like(w), w]) for w in (up, down))
+    grouped = lambda kernel, block_rows: jax.jit(  # noqa: E731
+        lambda *a: moe.dropless_experts_grouped(
+            *a, o["first"], valid, block_rows=block_rows, layer=layer, act=act,
+            kernel=kernel, of=o.get("of")))(*args, up, down)
+    loop, load_loop = grouped("xla", 64)
+    got, load_got = grouped("pallas-interpret", o["block_rows"])
+    assert got.dtype == jnp.float32 and got.shape == dense.shape
+    tol = dict(rtol=2e-4, atol=2e-5) if dt == jnp.float32 else dict(
+        rtol=0, atol=0.02 * float(jnp.abs(dense).max()))
+    np.testing.assert_allclose(got, dense, **tol)
+    np.testing.assert_allclose(got, loop, **tol)
+    np.testing.assert_array_equal(load_got, load)
+    np.testing.assert_array_equal(load_got, load_loop)
+    if "force" not in o:
+        assert float(jnp.abs(dense).max()) > 0.1
+    if case == "no-pair-held-here":
+        assert not np.asarray(got).any() and int(load_got.sum()) == 0
+    if valid is not None:
+        assert not np.asarray(got)[~np.asarray(valid)].any()
+
+
+@pytest.mark.parametrize("hidden, inter, gated, i_tiles", [
+    # the six expert cells' layers (bench/configs)
+    (2304, 896, True, 1),       # Mellum: 12 MB an expert
+    (2688, 1856, False, 1),     # Nemotron: 20 MB, and 1,856 is no whole lanes
+    (4096, 768, True, 1),       # Granite: 19 MB
+    (4096, 1280, True, 2),      # Solar: 31 MB
+    (5120, 1536, True, 2),      # DeepSeek-V2: 47 MB
+    (3072, 3072, True, 3),      # Trinity: 57 MB
+])
+def test_the_kernel_s_tiles_follow_the_widths(hidden, inter, gated, i_tiles):
+    from langstream_tpu.ops import grouped_experts as ge
+
+    got = ge.plan(hidden, inter, gated, 2)
+    assert got == {"tile_rows": 512, "sub_rows": 128, "i_tiles": i_tiles}
+    weights = (3 if gated else 2) * (inter // i_tiles) * hidden * 2
+    assert weights <= ge.WEIGHT_TILE_BYTES
+    assert i_tiles == 1 or weights * i_tiles > ge.WEIGHT_TILE_BYTES
+
+
+@pytest.mark.parametrize("kernel, held, of, want", [
+    ("pallas", 64, 64, ("pallas", 256)), ("pallas", 64, None, ("pallas", 256)),
+    ("pallas-interpret", 8, 8, ("pallas-interpret", 256)),
+    ("pallas", 16, 128, ("xla", 512)), ("pallas", 36, 72, ("xla", 512)),
+    ("xla", 64, 64, ("xla", 512)), ("xla", 16, 128, ("xla", 512)),
+])
+def test_the_kernel_s_pass_is_served_where_every_expert_is_held(
+        kernel, held, of, want):
+    """A share keeps the loop and its bound, as before the kernel: a
+    ``nemotron_h`` prefill with the kernel's pass never returned on the chip
+    (models/moe.py ``grouped_form``)."""
+    assert moe.grouped_form(kernel, held, of) == want
+
+
+def test_a_share_s_prefill_traces_the_loop_whatever_the_selection(routed):
+    x, experts, weights, w_up, w_down = routed      # 600 rows, 4 of 16 held
+    args = tuple(map(jnp.asarray, (x, experts, weights, w_up, w_down)))
+    by = lambda **kw: str(jax.make_jaxpr(  # noqa: E731
+        lambda *a: moe.dropless_experts(*a, 4, **kw))(*args))
+    assert "grouped_experts" not in by(kernel="pallas-interpret", of=16)
+    assert by(kernel="pallas-interpret", of=16) == by(kernel="xla", of=16)
+    assert "grouped_experts" in by(kernel="pallas-interpret", of=4)
+
+
+def test_an_unknown_selection_is_not_taken_for_the_experts_kernel(routed):
+    x, experts, weights, w_up, w_down = routed
+    with pytest.raises(ValueError, match="unknown kernel"):
+        moe.dropless_experts_grouped(
+            *map(jnp.asarray, (x, experts, weights, w_up, w_down)), 0,
+            kernel="mosaic")
+
+
 def test_the_shares_add_up_to_the_uncut_reference_layer(c):
     """Four chips of two experts each: what every share's routed experts
     give, plus the shared expert counted once, is the reference's layer

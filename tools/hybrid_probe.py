@@ -30,6 +30,7 @@ import time
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, os.path.join(ROOT, "bench"))
 sys.path.insert(1, ROOT)
+sys.path.insert(2, os.path.join(ROOT, "tools"))
 
 
 def memory(stage: str) -> dict:
@@ -43,40 +44,30 @@ def memory(stage: str) -> dict:
 
 
 def crossover(engine) -> list[dict]:
-    """One expert layer's routed pass at the served widths, dense against
-    grouped, by rows: milliseconds a call, the mean of 10 after a warm-up.
-    ``models/moe.py`` ``DENSE_ROWS_MAX`` is set from this."""
-    import jax
+    """One expert layer's routed pass at the served widths and share by
+    rows, the dense pass, the grouped XLA loop and the grouped kernel
+    (``tools/routed_pass.py`` ``crossover`` over this engine's first expert
+    layer, read from the stacks, and its router): milliseconds a call, and
+    at 1,024 and 4,096 rows each grouped form by scope. ``models/moe.py``
+    ``DENSE_ROWS_MAX`` is held to this."""
+    import jax.numpy as jnp
 
+    import routed_pass
     from langstream_tpu.models import moe
 
     c, lp = engine.model_config, engine.params["moe"]
-    w_up, w_down = lp["w_up"][0], lp["w_down"][0]
-    act = moe.EXPERT_ACTS[c.expert_act]
-    rows_out = []
-    for rows in (64, 96, 128, 256, 384, 512, 768, 1024, 2048):
-        h = jax.random.normal(jax.random.PRNGKey(rows), (rows, c.hidden), c.dtype)
+
+    def route(h):
         if c.router == "sigmoid":
-            experts, weights = moe.sigmoid_topk_routing(
+            return moe.sigmoid_topk_routing(
                 h, lp["router"][0], lp["bias"][0], c.experts_per_token,
                 c.routed_scale)
-        else:
-            experts, weights = moe.softmax_topk_routing(
-                h, lp["router"][0], c.experts_per_token)
-        row = {"rows": rows}
-        for name, fn in (("dense", moe.dropless_experts_dense),
-                         ("grouped", moe.dropless_experts_grouped)):
-            call = jax.jit(lambda h, e, w, fn=fn: fn(
-                h, e, w, w_up, w_down, c.expert_first, act=act)[0])
-            call(h, experts, weights).block_until_ready()
-            t = time.monotonic()
-            for _ in range(10):
-                y = call(h, experts, weights)
-            y.block_until_ready()
-            row[f"{name}_ms"] = round((time.monotonic() - t) * 100, 3)
-        print(f"[probe] routed pass, one layer: {json.dumps(row)}", flush=True)
-        rows_out.append(row)
-    return rows_out
+        return moe.softmax_topk_routing(h, lp["router"][0], c.experts_per_token)
+
+    return routed_pass.crossover(
+        c.hidden, c.dtype, route, lp["w_up"], lp["w_down"], jnp.int32(0),
+        c.expert_first, moe.EXPERT_ACTS[c.expert_act], c.experts,
+        trace_rows=(1024, 4096))
 
 
 async def run(args) -> dict:

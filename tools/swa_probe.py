@@ -6,7 +6,8 @@ for ``models/swa.py``): builds the engine from the configuration's
 (``bench/reference/afmoe.py``, ``mellum.py``) over ``--seeds``, with
 ``--faults`` judges the last seed's served output against each faulty
 reference (each has to come out as not passed), with ``--crossover`` times
-one expert layer's dense and grouped pass by rows, then one prefill of every
+one expert layer's dense pass and both forms of its grouped pass by rows
+(``tools/routed_pass.py``), then one prefill of every
 bucket a slot can hold and two full batches of decodes, with the allocator's
 peak after each: the numbers the configuration's ``memory`` quotes.
 
@@ -15,7 +16,8 @@ peak after each: the numbers the configuration's ``memory`` quotes.
         [--no-checks] [--no-warmup] [--trace 1]
 
 ``--trace 1`` ends with the device time of a decode step and of a prefill by
-scope. ``--rehearse-cpu`` walks the path at the configuration's tiny preset
+scope (``moe_ms``: a prefill's ``moe_dispatch`` / ``moe_experts`` /
+``moe_combine``). ``--rehearse-cpu`` walks the path at the configuration's tiny preset
 here. Refuses to run off a TPU otherwise. Prints one JSON line last."""
 
 from __future__ import annotations
@@ -31,6 +33,7 @@ import time
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, os.path.join(ROOT, "bench"))
 sys.path.insert(1, ROOT)
+sys.path.insert(2, os.path.join(ROOT, "tools"))
 
 #: the member's test-size twin by the reference its file names (the other
 #: member's is its bench test's fixture: :func:`tiny_of`)
@@ -71,48 +74,32 @@ def tiny_of(config: dict) -> dict:
     return tiny
 
 
-def crossover(engine, rows_list=(64, 128, 192, 256, 384, 512, 768, 1024),
-              blocks=(256, 128, 64)) -> list[dict]:
-    """One expert layer's routed pass at the served widths and share, dense
-    against grouped (at each of ``blocks`` rows a step), by rows:
-    milliseconds a call, the mean of 10 after a warm-up
-    (``tools/hybrid_probe.py`` ``crossover`` for this family's weights).
-    ``models/moe.py`` ``DENSE_ROWS_MAX`` is held to this."""
-    import jax
+def crossover(engine, rows_list=None, trace_rows=(1024, 4096),
+              interpret=False) -> list[dict]:
+    """One expert layer's routed pass at the served widths and share by
+    rows, the dense pass, the grouped XLA loop and the grouped kernel
+    (``tools/routed_pass.py`` ``crossover`` over this engine's first expert
+    layer and its router): milliseconds a call, and at ``trace_rows`` each
+    grouped form by scope. ``models/moe.py`` ``DENSE_ROWS_MAX`` is held to
+    this."""
+    import jax.numpy as jnp
 
+    import routed_pass
     from langstream_tpu.models import moe
 
     c = engine.model_config
     lp = engine.params["layers"][c.dense_layers]["moe"]
-    act = moe.EXPERT_ACTS[c.expert_act]
-    out = []
-    for rows in rows_list:
-        h = jax.random.normal(jax.random.PRNGKey(rows), (rows, c.hidden), c.dtype)
+
+    def route(h):
         if c.router == "sigmoid":
-            experts, weights = moe.sigmoid_topk_routing(
+            return moe.sigmoid_topk_routing(
                 h, lp["router"], lp["bias"], c.experts_per_token, c.routed_scale)
-        else:
-            experts, weights = moe.softmax_topk_routing(
-                h, lp["router"], c.experts_per_token)
-        row = {"rows": rows}
-        passes = [("dense", moe.dropless_experts_dense, {})] + [
-            (f"grouped_{b}", moe.dropless_experts_grouped, {"block_rows": b})
-            for b in blocks]
-        for name, fn, kw in passes:
-            # the weights as arguments: closed over, 0.8 GB of them would be
-            # constants of every one of these programs
-            call = jax.jit(lambda h, e, w, up, down, fn=fn, kw=kw: fn(
-                h, e, w, up, down, c.expert_first, act=act, **kw)[0])
-            args = (h, experts, weights, lp["w_up"], lp["w_down"])
-            call(*args).block_until_ready()
-            t = time.monotonic()
-            for _ in range(10):
-                y = call(*args)
-            y.block_until_ready()
-            row[f"{name}_ms"] = round((time.monotonic() - t) * 100, 3)
-        print(f"[probe] routed pass, one layer: {json.dumps(row)}", flush=True)
-        out.append(row)
-    return out
+        return moe.softmax_topk_routing(h, lp["router"], c.experts_per_token)
+
+    return routed_pass.crossover(
+        c.hidden, c.dtype, route, lp["w_up"][None], lp["w_down"][None],
+        jnp.int32(0), c.expert_first, moe.EXPERT_ACTS[c.expert_act], c.experts,
+        rows_list or routed_pass.ROWS, trace_rows, interpret=interpret)
 
 
 def memory(stage: str) -> dict:
@@ -136,6 +123,10 @@ def by_scope(path: str, program: str, over: float) -> dict:
         k: round(1e3 * v / over, 3)
         for k, v in sorted(table.items(), key=lambda kv: -kv[1])[:16]}
     return {"by_scope_ms": per(reduced["by_scope"]),
+            # the routed experts' three, whatever their rank: the gather in
+            # (and the sort), the matmuls, the gather back
+            "moe_ms": {k: round(1e3 * reduced["by_scope"].get(k, 0.0) / over, 3)
+                       for k in ("moe_dispatch", "moe_experts", "moe_combine")},
             "unscoped_ms": per(reduced["unscoped"]),
             "total_ms": round(1e3 * (sum(reduced["by_scope"].values())
                                      + sum(reduced["unscoped"].values()))
@@ -165,7 +156,7 @@ async def run(args) -> dict:
                     if "num_blocks" in k or "ring" in k}
     tolerance = config["reference_tolerance"]
     if args.crossover:
-        sizes = ((16, 64), (8,)) if args.rehearse_cpu else ()
+        sizes = ((16, 64), (), True) if args.rehearse_cpu else ()
         out["crossover"] = await asyncio.to_thread(crossover, engine, *sizes)
     got = None
     for seed in [] if args.no_checks else args.seeds:
