@@ -390,7 +390,10 @@ def moe_param_count(config: MoEConfig) -> int:
 #: 1.68 / 2.25, 8,192 rows - / 3.53 / 4.20 (there the loop's float32 result,
 #: 44 and 88 MB, is kept in VMEM by the compiler and its scatter-adds cost a
 #: third of what they cost in HBM; no cell of that share prefills more than
-#: 2,048 rows). At a WHOLE layer of experts, 64 held of 64 of 3 x 896 x 2304,
+#: 2,048 rows; the third column there is a form of the kernel's pass that
+#: sorted the held pairs alone and added their results 256 rows at a time,
+#: never served and taken out again: commit f49ab30). At a WHOLE layer of
+#: experts, 64 held of 64 of 3 x 896 x 2304,
 #: top 8 (tools/swa_probe.py --config mellum2-12b-a2.5b-8l --crossover): 64
 #: rows 1.13 / 3.71 / 1.19, 128 rows 1.14 / 3.67 / 1.23, 256 rows 1.27 / 3.78
 #: / 1.29 (0.97 ms stream the layer's 793 MB), 384 rows 1.89 / 3.70 / 1.37,
@@ -404,12 +407,17 @@ def moe_param_count(config: MoEConfig) -> int:
 #: is 32 to 192 rows: bench/lib/roofline_wf.py prices the decode step by
 #: this constant, under this name
 DENSE_ROWS_MAX = 256
-#: the same bound where the grouped pass is the XLA loop (the CPU, a mesh,
-#: and a chip that holds a SHARE of the experts: below): the loop's crossover
-#: with the dense pass, between 512 and 768 rows in both tables above
+#: the same bound where the grouped pass is the XLA loop (the CPU, and a
+#: chip that holds a SHARE of the experts: :func:`grouped_form`): the loop's
+#: crossover with the dense pass, between 512 and 768 rows in both tables
+#: above. bench/lib/roofline_wf.py prices a decode step by DENSE_ROWS_MAX
+#: alone, which is right while every window-and-full cell holds all its
+#: experts and decodes at most 192 rows: a configuration of that family
+#: served at a share switches here, at 512, and its reader would have to
+#: read the bound taken, ``grouped_form(...)[1]``
 LOOP_DENSE_ROWS_MAX = 512
 #: rows of one step of the grouped pass's XLA loop (one expert's weights a
-#: step), and of one scatter-add of the kernel form's combine at a share
+#: step)
 GROUP_BLOCK_ROWS = 256
 
 
@@ -563,7 +571,6 @@ def dropless_experts_grouped(
     w_up: jax.Array, w_down: jax.Array, first: int,
     valid: jax.Array | None = None, block_rows: int | None = None,
     layer: jax.Array | None = None, act=relu2, kernel: str = "xla",
-    of: int | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     """The chosen pairs sorted by held expert and each run of one expert's
     rows through that expert's matmuls with ``act`` between them, scaled by
@@ -575,14 +582,12 @@ def dropless_experts_grouped(
     interpret"`` on the CPU): the rows are permuted ONCE into the sorted
     order, one kernel walks the runs (``ops/grouped_experts.py``; ``block_rows``
     the rows of one of its products, its ``SUB_ROWS`` where None), and the
-    results are permuted back once, a gather of each token's ``k`` where a
-    quarter or more of the ``of`` experts are held, scatter-adds of the rows
-    routed here where fewer are (:func:`_grouped_by_kernel`; ``of`` None: all
-    are held). ``"xla"``: a loop of one block of
+    results are permuted back once, a gather of each token's ``k``
+    (:func:`_grouped_by_kernel`). ``"xla"``: a loop of one block of
     ``block_rows`` (default :data:`GROUP_BLOCK_ROWS`) a step, each expert's
     run padded to whole blocks: gather its rows, the matmuls, scatter-add;
-    the CPU's form, the tests' second opinion, and what a mesh of more than
-    one device runs.
+    the CPU's form, the tests' second opinion, and what a chip that holds a
+    share of the experts runs (:func:`grouped_form`).
 
     With ``layer``, ``w_up`` and ``w_down`` are the stacks ``(layers, held,
     I, H)`` of a model that scans its layers, and a step reads
@@ -594,7 +599,7 @@ def dropless_experts_grouped(
             raise ValueError(f"dropless_experts_grouped: unknown kernel {kernel!r}")
         return _grouped_by_kernel(
             x, experts, weights, w_up, w_down, first, valid, block_rows,
-            layer, act, of, interpret=(kernel == "pallas-interpret"))
+            layer, act, interpret=(kernel == "pallas-interpret"))
     T, k = experts.shape
     held = w_up.shape[-3]
     at = (lambda w, e: w[e]) if layer is None else (lambda w, e: w[layer, e])
@@ -631,13 +636,10 @@ def dropless_experts_grouped(
 
 
 #: bytes of the sorted rows one call of the kernel is handed (and returns):
-#: a prefill is cut into chunks of tokens whose pairs routed HERE should
-#: fill no more (all ``k`` of a token where every expert is held; ``held /
-#: of`` of them in the mean at an expert-parallel cut, whose 16,384-row
-#: bucket has 98,304 pairs, 1 GB of rows in and out, an eighth of them
-#: routed here). 4,096 rows of Mellum's layer are 151 MB: one chunk streams
-#: the layer's 793 MB of experts once, two of 2,048 rows twice (the kernel
-#: 3.2 ms against 2 x 1.95, tools/routed_pass.py, PR 47)
+#: a prefill of more is cut into equal chunks of its tokens, each a whole
+#: pass of its own. 4,096 rows of Mellum's layer are 151 MB: one chunk
+#: streams the layer's 793 MB of experts once, two of 2,048 rows twice (the
+#: kernel 3.2 ms against 2 x 1.95, tools/routed_pass.py, PR 47)
 GROUP_PIECE_BYTES = 160 * 1024 * 1024
 #: bytes of the results one gather of the combine takes (a part of a chunk's
 #: tokens, ``k`` rows each; the gather is an array of its own, in the
@@ -651,40 +653,33 @@ def _parts(n: int, fits) -> int:
     return next(p for p in range(1, n + 1) if n % p == 0 and fits(n // p))
 
 
-def _loop(turns, body, carry):
-    """``fori_loop`` from 0; a loop of ONE turn by its static bound is no
-    loop."""
-    return body(0, carry) if isinstance(turns, int) and turns == 1 else (
-        jax.lax.fori_loop(0, turns, body, carry))
+def _loop(turns: int, body, carry):
+    """``fori_loop`` from 0 (rolled: one turn's buffers at a time); a loop of
+    ONE turn is no loop."""
+    return body(0, carry) if turns == 1 else jax.lax.fori_loop(
+        0, turns, body, carry)
 
 
 def _grouped_by_kernel(x, experts, weights, w_up, w_down, first, valid,
-                       block_rows, layer, act, of, interpret):
+                       block_rows, layer, act, interpret):
     """:func:`dropless_experts_grouped` with the runs in one kernel: see
-    there. Three loops around ONE float32 result, each of one turn wherever
-    its bound allows, so that nothing beside the result is larger than
-    :data:`GROUP_PIECE_BYTES`:
+    there. Two static loops around ONE float32 result, each of one turn
+    wherever its bound allows, so that nothing beside the result is larger
+    than :data:`GROUP_PIECE_BYTES`:
 
-    - over equal chunks of the tokens (static), so that a chunk's pairs
-      routed here fill :data:`GROUP_PIECE_BYTES` in the mean: a chunk is a
-      whole pass of its own, dispatch to combine, and streams the experts
-      its rows chose once more;
-    - inside a chunk over pieces of its sorted pairs, as many as the pairs
-      routed HERE fill (dynamic; more than one only where this chip got
-      more than its share): a piece's rows are gathered once and its runs
-      go through the kernel;
-    - inside a piece the combine, in float32. Where a quarter of the experts
-      or more are held (``of`` None: all) over parts of the chunk's tokens
-      (static, :data:`GROUP_PART_BYTES` each), each token taking the ``k``
-      results of it that lie in the piece times their routing weights: one
-      gather a part, 46-70 ns a row of ALL ``T x k`` against a scatter-add's
-      110-480 of the rows routed here (36 of 72 held, 4,096 rows: 3.6 ms
-      against 9.8). Where fewer are held over the piece's LIVE rows,
-      :data:`GROUP_BLOCK_ROWS` at a time (dynamic), each added to its
-      token's row: at an eighth of the experts a gather of every token's
-      ``k`` costs twice the kernel, and a scatter-add of 256 rows costs
-      0.12-0.3 us a row where one of thousands costs 0.17-3.7
-      (tools/routed_pass.py, PR 47)."""
+    - over equal chunks of the tokens, a chunk's ``k`` sorted rows a token
+      within :data:`GROUP_PIECE_BYTES`: a chunk is a whole pass of its own
+      (the dispatch, ONE gather of its rows into the sorted order, the kernel
+      over its runs, the combine) and streams the experts its rows chose once
+      more;
+    - inside a chunk the combine, in float32, over parts of its tokens
+      (:data:`GROUP_PART_BYTES` each), each token taking its ``k`` results
+      times their routing weights: one gather a part, 46-70 ns a row against
+      a scatter-add's 110-480 (tools/routed_pass.py, PR 47).
+
+    Every pair has a sorted row, held here or not (the pairs of no held
+    expert lie last, in no run): right at any share, sized for a chip that
+    holds every expert, which is where :func:`grouped_form` serves it."""
     from langstream_tpu.ops import grouped_experts as ge
 
     T, k = experts.shape
@@ -701,27 +696,20 @@ def _grouped_by_kernel(x, experts, weights, w_up, w_down, first, valid,
         tiles["sub_rows"] = block_rows
     R = tiles["tile_rows"]
     row_bytes = H * x.dtype.itemsize
-    piece_max = max(R, GROUP_PIECE_BYTES // row_bytes // R * R)
-    # every pair where all the experts are held; a third over this chip's
-    # share in the mean where they are not (more than that is a second piece)
-    share = 1.0 if of is None else min(1.0, held / of * 4 / 3)
-    chunks = _parts(T, lambda rows: rows * k * share <= piece_max)
+
+    def fits(limit):    # tokens whose k rows each lie within limit (one does)
+        return lambda rows: rows * k * row_bytes <= max(limit, k * row_bytes)
+
+    chunks = _parts(T, fits(GROUP_PIECE_BYTES))
     Tc = T // chunks
-    whole = -(-Tc * k // R) * R
-    piece = min(-(-int(Tc * k * share) // R) * R, piece_max)
-    # the combine's parts: of the chunk's tokens where every expert is held,
-    # of the piece's live rows where a share is
-    parts = _parts(Tc, lambda rows: rows * k * row_bytes <= max(
-        GROUP_PART_BYTES, k * row_bytes))
+    sorted_rows = -(-Tc * k // R) * R      # a chunk's, in whole tiles
+    parts = _parts(Tc, fits(GROUP_PART_BYTES))
     Tp = Tc // parts
-    Rp = GROUP_BLOCK_ROWS
-    by_token = of is None or 4 * held >= of
     rows_of = lambda a, at, n: jax.lax.dynamic_slice_in_dim(a, at, n)  # noqa: E731
 
     def one_chunk(c, carry):
         out, load = carry
         with jax.named_scope("moe_dispatch"):
-            xc = rows_of(x, c * Tc, Tc)
             wc = rows_of(weights, c * Tc, Tc)
             here, order, counts, sorted_start = _pairs_by_expert(
                 rows_of(experts, c * Tc, Tc), first, held,
@@ -729,52 +717,25 @@ def _grouped_by_kernel(x, experts, weights, w_up, w_down, first, valid,
             # where each pair lies in the sorted order, and the token of
             # each sorted row
             place = jnp.argsort(order).astype(jnp.int32).reshape(Tc, k)
-            token = order // k
+            token = (order // k)[jnp.clip(jnp.arange(sorted_rows), 0, Tc * k - 1)]
+            xs = rows_of(x, c * Tc, Tc)[token]
+        with jax.named_scope("moe_experts"):
+            ys = ge.grouped_experts(
+                xs, w_up, w_down, layer, sorted_start, counts, act=name,
+                interpret=interpret, **tiles)
 
-        def one_piece(p, out):
-            p0 = p * piece
-            with jax.named_scope("moe_dispatch"):
-                xs = xc[token[jnp.clip(p0 + jnp.arange(piece), 0, Tc * k - 1)]]
-                starts = jnp.clip(sorted_start - p0, 0, piece)
-                ends = jnp.clip(sorted_start + counts - p0, 0, piece)
-            with jax.named_scope("moe_experts"):
-                ys = ge.grouped_experts(
-                    xs, w_up, w_down, layer, starts, ends - starts, act=name,
-                    interpret=interpret, **tiles)
-            at = place - p0
-            mine = here & (at >= 0) & (at < piece)
+        def tokens_take(i, out):
+            with jax.named_scope("moe_combine"):
+                # a row of no run holds whatever was there: selected away,
+                # never multiplied by zero
+                mixed = jnp.sum(jnp.where(
+                    rows_of(here, i * Tp, Tp)[..., None],
+                    ys[rows_of(place, i * Tp, Tp)].astype(jnp.float32)
+                    * rows_of(wc, i * Tp, Tp)[..., None], 0.0), axis=1)
+                return jax.lax.dynamic_update_slice_in_dim(
+                    out, mixed, c * Tc + i * Tp, 0)
 
-            def tokens_take(i, out):
-                with jax.named_scope("moe_combine"):
-                    got = ys[jnp.clip(rows_of(at, i * Tp, Tp), 0, piece - 1)]
-                    # a row of no run holds whatever was there: selected
-                    # away, never multiplied by zero
-                    mixed = jnp.sum(jnp.where(
-                        rows_of(mine, i * Tp, Tp)[..., None],
-                        got.astype(jnp.float32)
-                        * rows_of(wc, i * Tp, Tp)[..., None], 0.0), axis=1)
-                    first_row = c * Tc + i * Tp
-                    return jax.lax.dynamic_update_slice_in_dim(
-                        out, rows_of(out, first_row, Tp) + mixed, first_row, 0)
-
-            def rows_add(i, out):
-                with jax.named_scope("moe_combine"):
-                    at = p0 + i * Rp + jnp.arange(Rp)
-                    pair = order[jnp.clip(at, 0, Tc * k - 1)]
-                    # a row past the pairs routed here goes nowhere
-                    row = jnp.where(at < total, c * Tc + pair // k, T)
-                    return out.at[row].add(
-                        rows_of(ys, i * Rp, Rp).astype(jnp.float32)
-                        * wc.reshape(-1)[pair][:, None], mode="drop")
-
-            if by_token:
-                return _loop(parts, tokens_take, out)
-            live = jnp.clip(total - p0, 0, piece)
-            return _loop(-(-live // Rp), rows_add, out)
-
-        total = sorted_start[-1] + counts[-1]
-        out = _loop(1 if piece == whole else -(-total // piece), one_piece, out)
-        return out, load + counts
+        return _loop(parts, tokens_take, out), load + counts
 
     return _loop(chunks, one_chunk, (
         jnp.zeros((T, H), jnp.float32), jnp.zeros((held,), jnp.int32)))
@@ -786,12 +747,16 @@ def grouped_form(kernel: str, held: int, of: int | None) -> tuple[str, int]:
     (None: all). The kernel's pass where the selection is a kernel AND every
     expert is held, from :data:`DENSE_ROWS_MAX` rows on; else the loop from
     :data:`LOOP_DENSE_ROWS_MAX` on, as before PR 47. A share keeps the loop
-    because ``nemotron_h``'s prefill program of 8 x 512 rows (16 of 128
-    held) never returned in the cell's warm-up with the kernel's pass inside
-    its scan over blocks (one run, killed after 40 minutes; 1 x 1,024 to 4 x
-    1,024 and 8 x 256 had run, and the pass alone runs at every share in
-    tools/routed_pass.py): the cause is not found, and at a share the pass is
-    level with the loop, not ahead (PERF.md section 6, PR 47)."""
+    because the kernel's pass sorts and gathers ALL ``T x k`` pairs, which
+    at an eighth of the experts costs twice the kernel, and the form that
+    sorted the held pairs alone (level with the loop in the probes, not
+    ahead) added their results with a loop of 256-row scatter-adds whose
+    program hangs the chip: ``nemotron_h``'s prefill of 8 x 512 rows compiles
+    in 13 s and never returns from its RUN with that loop inside the scan
+    over blocks, returns with the gather by token in its place, and returns
+    with the loop where the program is compiled without the compiler's
+    assignment of buffers to VMEM (PERF.md section 6, PR 47; the same
+    assignment as ``models/hybrid.py`` ``_prefill_compiler_options``)."""
     if kernel != "xla" and (of is None or of == held):
         return kernel, DENSE_ROWS_MAX
     return "xla", LOOP_DENSE_ROWS_MAX
@@ -808,7 +773,7 @@ def dropless_experts(x, experts, weights, w_up, w_down, first, valid=None,
     if x.shape[0] > dense_rows_max:
         return dropless_experts_grouped(
             x, experts, weights, w_up, w_down, first, valid, layer=layer,
-            act=act, kernel=kernel, of=of)
+            act=act, kernel=kernel)
     if layer is not None:
         w_up, w_down = w_up[layer], w_down[layer]
     return dropless_experts_dense(

@@ -1822,17 +1822,16 @@ class TpuServingEngine:
             else None)
         # and so does the form of a prefill's routed experts' grouped pass
         # (models/moe.py dropless_experts_grouped: ops/grouped_experts.py,
-        # or the XLA loop of one block a step). pallas_call has no rule to
-        # partition it, so more than one device is handed the loop; what the
-        # pass does with the selection at this model's share of the experts,
-        # and from how many rows, is models/moe.py grouped_form's to say
-        self._moe_kernel_handed = (
-            None if self._fam is None or not self._fam.expert_kernels
-            else kernel if self.mesh is None or self.mesh.size == 1
-            else "xla")
+        # or the XLA loop of one block a step): the family's programs are
+        # handed the ONE selection, and what the pass does with it at this
+        # model's share of the experts, and from how many rows, is
+        # models/moe.py grouped_form's to say. (pallas_call has no rule to
+        # partition the kernel, and no family with experts serves under a
+        # mesh: _refuse_what_assumes_history_is_kv, above)
         self.moe_grouped_kernel, self._grouped_rows_over = (
-            (None, None) if self._moe_kernel_handed is None else grouped_form(
-                self._moe_kernel_handed, mc.experts_held, mc.experts))
+            grouped_form(kernel, mc.experts_held, mc.experts)
+            if self._fam is not None and self._fam.expert_kernels
+            else (None, None))
         # prefill programs dispatched, and those of them whose rows took the
         # grouped pass
         self._prefill_dispatches = 0
@@ -2087,8 +2086,9 @@ class TpuServingEngine:
                         mc_static, params, rest[:n], tokens, lengths, sel,
                         use_flash=prefill_flash,
                         # the one selection, for whichever kernels the
-                        # family's prefill has
-                        kernel=self.ssm_state_kernel or self._moe_kernel_handed)
+                        # family's prefill has (reported as ssm_state_kernel
+                        # and moe_grouped_kernel)
+                        kernel=self.paged_read_kernel)
                     with jax.named_scope("sample"):
                         next_tokens, logprobs = sample_tokens(
                             logits, key, temps, topks,

@@ -288,7 +288,8 @@ def _kernel_case(seed=5, T=600, k=3, E=16, held=4, H=128, I=128,
 
 
 #: each case: what differs from 600 rows choosing 3 of 16 experts, 4 held
-#: from the fifth on, gated, 128 rows a product
+#: from the fifth on, gated, 128 rows a product (a held subset is right
+#: through the kernel's pass, though served through the loop: grouped_form)
 KERNEL_CASES = {
     "relu2": dict(act="relu2"),
     "gated-256-rows-a-product": dict(block_rows=256),
@@ -306,13 +307,16 @@ KERNEL_CASES = {
     "expert-width-in-tiles-relu2": dict(
         I=384, act="relu2", weight_tile_bytes=2 * 128 * 128 * 4),
     "chunks-of-the-tokens": dict(piece_bytes=1024 * 128 * 4),
-    "a-share-held": dict(of=16),
-    "an-eighth-held": dict(of=32),
-    "an-eighth-held-every-pair-here": dict(of=32, force=6, T=900),
-    "a-share-held-relu2-masked": dict(of=16, act="relu2", valid=True, layer=1),
-    "pieces-of-a-chunk": dict(piece_bytes=512 * 128 * 4, of=16, force=6),
+    "chunks-of-the-tokens-relu2-masked-stacked": dict(
+        piece_bytes=1024 * 128 * 4, act="relu2", valid=True, layer=1),
     "parts-of-a-chunk-s-tokens": dict(part_bytes=64 * 3 * 128 * 4),
-    "a-share-held-every-pair-here": dict(of=16, force=6, T=900),
+    "chunks-and-parts": dict(
+        piece_bytes=1024 * 128 * 4, part_bytes=64 * 3 * 128 * 4, valid=True),
+    "chunks-one-expert-with-every-pair": dict(
+        piece_bytes=1024 * 128 * 4, force=6, T=900),
+    "every-expert-held": dict(first=0, held=16),
+    "every-expert-held-relu2-256-rows": dict(
+        first=0, held=16, act="relu2", block_rows=256, valid=True),
     "bfloat16": dict(dtype="bfloat16", valid=True),
 }
 
@@ -335,7 +339,8 @@ def test_the_kernel_pass_equals_the_dense_pass_and_the_loop(monkeypatch, case):
         monkeypatch.setattr(moe, "GROUP_PIECE_BYTES", o["piece_bytes"])
     if "part_bytes" in o:
         monkeypatch.setattr(moe, "GROUP_PART_BYTES", o["part_bytes"])
-    x, experts, weights, up, down = _kernel_case(T=o["T"], I=o["I"])
+    x, experts, weights, up, down = _kernel_case(
+        T=o["T"], I=o["I"], held=o.get("held", 4))
     if o["act"] == "relu2":
         up = up[:, :o["I"]]
     if "avoid" in o:
@@ -355,7 +360,7 @@ def test_the_kernel_pass_equals_the_dense_pass_and_the_loop(monkeypatch, case):
     grouped = lambda kernel, block_rows: jax.jit(  # noqa: E731
         lambda *a: moe.dropless_experts_grouped(
             *a, o["first"], valid, block_rows=block_rows, layer=layer, act=act,
-            kernel=kernel, of=o.get("of")))(*args, up, down)
+            kernel=kernel))(*args, up, down)
     loop, load_loop = grouped("xla", 64)
     got, load_got = grouped("pallas-interpret", o["block_rows"])
     assert got.dtype == jnp.float32 and got.shape == dense.shape

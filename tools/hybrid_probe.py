@@ -49,7 +49,11 @@ def crossover(engine) -> list[dict]:
     (``tools/routed_pass.py`` ``crossover`` over this engine's first expert
     layer, read from the stacks, and its router): milliseconds a call, and
     at 1,024 and 4,096 rows each grouped form by scope. ``models/moe.py``
-    ``DENSE_ROWS_MAX`` is held to this."""
+    ``DENSE_ROWS_MAX`` is held to this. At a share the kernel's pass is NOT
+    what is served (``moe.grouped_form`` keeps the loop there) and gives
+    every pair a sorted row, held here or not: the third column of PR 47's
+    table at 16 of 128 was read with a form that sorted the held pairs alone
+    and added them 256 rows at a time (commit f49ab30, taken out again)."""
     import jax.numpy as jnp
 
     import routed_pass
@@ -66,7 +70,7 @@ def crossover(engine) -> list[dict]:
 
     return routed_pass.crossover(
         c.hidden, c.dtype, route, lp["w_up"], lp["w_down"], jnp.int32(0),
-        c.expert_first, moe.EXPERT_ACTS[c.expert_act], c.experts,
+        c.expert_first, moe.EXPERT_ACTS[c.expert_act],
         trace_rows=(1024, 4096))
 
 

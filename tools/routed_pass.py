@@ -67,13 +67,14 @@ def by_scope(call, args, program: str) -> dict:
 
 
 def crossover(hidden: int, dtype, route, w_up, w_down, layer, first: int,
-              act, of: int, rows_list=ROWS, trace_rows=(), block_rows=(),
+              act, rows_list=ROWS, trace_rows=(), block_rows=(),
               interpret: bool = False) -> list[dict]:
     """``route(h) -> (experts, weights)`` over ``rows`` random rows of
-    ``hidden`` choosing among ``of`` experts, then each pass with the stacks
-    ``w_up`` / ``w_down`` at ``layer``: milliseconds a call, the mean of 10
-    after a warm-up; at ``trace_rows`` also the grouped forms by scope. ``block_rows``: the kernel pass
-    again at each of these rows a product, beside the rule's choice."""
+    ``hidden``, then each pass with the stacks ``w_up`` / ``w_down`` at
+    ``layer`` (the experts held from ``first`` on): milliseconds a call, the
+    mean of 10 after a warm-up; at ``trace_rows`` also the grouped forms by
+    scope. ``block_rows``: the kernel pass again at each of these rows a
+    product, beside the rule's choice."""
     import jax
 
     from langstream_tpu.models import moe
@@ -85,14 +86,13 @@ def crossover(hidden: int, dtype, route, w_up, w_down, layer, first: int,
         "grouped_xla": lambda h, e, w, up, down: moe.dropless_experts_grouped(
             h, e, w, up, down, first, layer=layer, act=act)[0],
         "grouped_kernel": lambda h, e, w, up, down: moe.dropless_experts_grouped(
-            h, e, w, up, down, first, layer=layer, act=act, kernel=kernel,
-            of=of)[0],
+            h, e, w, up, down, first, layer=layer, act=act, kernel=kernel)[0],
     }
     for b in block_rows:
         passes[f"grouped_kernel_{b}"] = (
             lambda h, e, w, up, down, b=b: moe.dropless_experts_grouped(
                 h, e, w, up, down, first, layer=layer, act=act, kernel=kernel,
-                of=of, block_rows=b)[0])
+                block_rows=b)[0])
     out = []
     for rows in rows_list:
         h = jax.random.normal(jax.random.PRNGKey(rows), (rows, hidden), dtype)
@@ -161,7 +161,7 @@ def main() -> int:
               f"{held} of {of} held, top {k}, {act}", flush=True)
         out[name] = crossover(
             hidden, dtype, lambda h: moe.softmax_topk_routing(h, router, k),
-            w_up, w_down, 0, 0, moe.EXPERT_ACTS[act], of, args.rows,
+            w_up, w_down, 0, 0, moe.EXPERT_ACTS[act], args.rows,
             args.trace_rows, args.block_rows, interpret=args.rehearse_cpu)
     os.makedirs(os.path.join(ROOT, "chiprun_out"), exist_ok=True)
     with open(os.path.join(ROOT, "chiprun_out", "routed_pass.json"), "w") as f:

@@ -98,7 +98,7 @@ def crossover(engine, rows_list=None, trace_rows=(1024, 4096),
 
     return routed_pass.crossover(
         c.hidden, c.dtype, route, lp["w_up"][None], lp["w_down"][None],
-        jnp.int32(0), c.expert_first, moe.EXPERT_ACTS[c.expert_act], c.experts,
+        jnp.int32(0), c.expert_first, moe.EXPERT_ACTS[c.expert_act],
         rows_list or routed_pass.ROWS, trace_rows, interpret=interpret)
 
 
