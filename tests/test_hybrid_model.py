@@ -318,6 +318,10 @@ KERNEL_CASES = {
     "every-expert-held-relu2-256-rows": dict(
         first=0, held=16, act="relu2", block_rows=256, valid=True),
     "bfloat16": dict(dtype="bfloat16", valid=True),
+    "bfloat16-relu2-chunks": dict(
+        dtype="bfloat16", act="relu2", piece_bytes=1024 * 128 * 2),
+    "rows-that-fill-no-block-relu2-stacked": dict(T=70, act="relu2", layer=1),
+    "every-expert-held-one-with-no-pair": dict(first=0, held=16, avoid=5),
 }
 
 
