@@ -54,8 +54,9 @@ class Family:
     prefill_compiler_options: Callable = lambda mc, backend: None
     prefill_selects_slots: bool = False  # sel is (tables, slot_ids)
     one_decode_window: bool = False      # max_blocks_per_slot, no buckets
-    state_kernels: bool = False  # its state's kernels follow the paged read's
-    expert_kernels: bool = False  # so does its prefill's routed experts' pass
+    # what of the ONE kernel selection its programs are handed is reported:
+    state_kernels: bool = False   # ssm_state_kernel (its state has kernels)
+    expert_kernels: bool = False  # moe_grouped_kernel (its prefill has experts)
     pool_rows: Callable | None = None    # (mc, block_mgr, rows) -> a gauge
 
     def config(self, name: str, max_seq_len: int) -> Any:
