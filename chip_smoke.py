@@ -193,6 +193,17 @@ def _check_engine(engine, requests: int, rehearsal: bool) -> list[str]:
             f"continuation read kernel {engine.continuation_read_kernel!r}, "
             f"configuration selects {continuation!r}"
         )
+    # the commit follows the same selection where it can move the pool
+    # (ops/pool_commit.py commit_form: a bf16 pool; no mesh)
+    from langstream_tpu.ops.pool_commit import commit_form
+
+    commit = commit_form(
+        selected if engine.mesh is None else "xla", engine.cache_k)
+    if engine.pool_commit_kernel != commit:
+        failures.append(
+            f"pool commit kernel {engine.pool_commit_kernel!r}, "
+            f"configuration selects {commit!r}"
+        )
     served = [t for t in engine.request_timings if t.get("tokens", 0) > 0]
     if len(served) < requests:
         failures.append(
@@ -299,6 +310,7 @@ async def _serve(rehearsal: bool, mesh: dict, observed: dict) -> list[str]:
             "warmup": engine._warmup_state(),
             "paged_read_kernel": engine.paged_read_kernel,
             "continuation_read_kernel": engine.continuation_read_kernel,
+            "pool_commit_kernel": engine.pool_commit_kernel,
             "decode_read_block_buckets": nrbs,
             "prefill_buckets": prefill_buckets,
             "prefix_hits": engine.prefix_hits,

@@ -74,7 +74,7 @@ from langstream_tpu.models.moe import (
     sigmoid_topk_routing,
     softmax_topk_routing,
 )
-from langstream_tpu.models.paged import write_rows
+from langstream_tpu.models.paged import write_rows_pair
 from langstream_tpu.ops.paged_attention import (
     NEG_INF,
     merge_partial_attention,
@@ -875,6 +875,13 @@ def delta_step(c: HybridConfig, lp: dict, u: jax.Array, delta: jax.Array,
 # ---------------------------------------------------------------------------
 
 
+def backend_kernel() -> str:
+    """The kernel selection the engine resolves on this backend for a bf16
+    pool (``"pallas"`` on a TPU, ``"xla"`` elsewhere): what a model function
+    handed none takes, for its experts' pass, its state's and its commit."""
+    return "pallas" if jax.default_backend() == "tpu" else "xla"
+
+
 def moe_mixer(c, lp: dict, h: jax.Array, valid: jax.Array,
               layer: jax.Array | None = None, kernel: str | None = None):
     """Routed experts held here plus the shared expert (where the layer
@@ -894,7 +901,7 @@ def moe_mixer(c, lp: dict, h: jax.Array, valid: jax.Array,
     engine resolves on this backend, as :func:`hybrid_prefill_paged`'s."""
     act = EXPERT_ACTS[c.expert_act]
     if kernel is None:
-        kernel = "pallas" if jax.default_backend() == "tpu" else "xla"
+        kernel = backend_kernel()
     with jax.named_scope("moe_router"):
         if c.router == "sigmoid":
             experts, weights = sigmoid_topk_routing(
@@ -1031,7 +1038,7 @@ def hybrid_prefill_paged(
     ``check_engine``) hands none, and holds the engine's PROGRAM to the
     model's FUNCTION as its decode side does, under one selection.
 
-    The commit is :func:`langstream_tpu.models.paged.write_rows`, the one
+    The commit is :func:`langstream_tpu.models.paged.write_rows_pair`, the one
     every family uses: the attention layer is folded into the row index, so
     the pool is scattered where it lies and never copied."""
     c = config
@@ -1043,7 +1050,7 @@ def hybrid_prefill_paged(
     flash = (_flash_mode(Pn) if use_flash is None
              else ("compiled" if use_flash else None))
     if kernel is None:
-        kernel = "pallas" if jax.default_backend() == "tpu" else "xla"
+        kernel = backend_kernel()
     with jax.named_scope("embed"):
         x = _embed(c, params, tokens)
 
@@ -1156,10 +1163,10 @@ def hybrid_prefill_paged(
         last = jnp.take_along_axis(
             x, (lengths - 1)[:, None, None].clip(0), axis=1).squeeze(1)
         logits = _logits(c, params, last)
-    starts = jnp.zeros((B,), jnp.int32)
     with jax.named_scope("kv_write"):
-        pool_k = write_rows(pool_k, ks[:nA], block_tables, starts, real)
-        pool_v = write_rows(pool_v, vs[:nA], block_tables, starts, real)
+        pool_k, pool_v = write_rows_pair(
+            (pool_k, pool_v), (a[:nA] for a in (ks, vs)), block_tables, None,
+            real, kernel)
     return logits, pool_k, pool_v, dict(zip(kinds, rec)), routed
 
 
@@ -1188,7 +1195,7 @@ def hybrid_decode_chunk_paged(
 ):
     """K fused decode steps. The pool is read-only and the new K/V rows of
     the attention layers gather in a chunk buffer (one scatter at the end:
-    :func:`langstream_tpu.models.paged.write_rows`, the layer in the row
+    :func:`langstream_tpu.models.paged.write_rows_pair`, the layer in the row
     index, no copy of the pool), as in the dense family's chunk; the
     recurrent state rides the scan's carry and each Mamba-2 layer replaces
     its own rows of it in place.
@@ -1342,12 +1349,10 @@ def hybrid_decode_chunk_paged(
     rec, load = out_carry[4 : 4 + len(kinds)], out_carry[4 + len(kinds)]
     valid = jnp.broadcast_to(active[:, None], (B, num_steps))
     with jax.named_scope("kv_write"):
-        pool_k = write_rows(
-            pool_k, kbuf[:nA].reshape(nA, B, num_steps, KhD), block_tables,
-            base_lengths, valid)
-        pool_v = write_rows(
-            pool_v, vbuf[:nA].reshape(nA, B, num_steps, KhD), block_tables,
-            base_lengths, valid)
+        pool_k, pool_v = write_rows_pair(
+            (pool_k, pool_v),
+            (a[:nA].reshape(nA, B, num_steps, KhD) for a in (kbuf, vbuf)),
+            block_tables, base_lengths, valid, kernel)
     final_lengths = base_lengths + num_steps * adv
     state = dict(zip(kinds, rec))
     if return_packed:

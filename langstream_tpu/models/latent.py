@@ -52,7 +52,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from langstream_tpu.models.family import Family
-from langstream_tpu.models.hybrid import moe_mixer
+from langstream_tpu.models.hybrid import backend_kernel, moe_mixer
 from langstream_tpu.models.llama import _flash_mode, _rms_norm
 from langstream_tpu.models.llama import yarn_inv_freq as _yarn_inv_freq
 from langstream_tpu.models.llama_paged import pack_tokens_logprobs
@@ -378,6 +378,8 @@ def latent_prefill_paged(
     real = positions[None, :] < lengths[:, None]                   # (B, P)
     flash = (_flash_mode(Pn) if use_flash is None
              else ("compiled" if use_flash else None))
+    if kernel is None:      # as moe_mixer resolves it; the commit reads it too
+        kernel = backend_kernel()
     groups = 1
     while B * Pn * (c.heads // groups) > EXPAND_ROWS_X_HEADS and \
             c.heads % (groups * 2) == 0:
@@ -479,9 +481,10 @@ def latent_prefill_paged(
             x, (lengths - 1)[:, None, None].clip(0), axis=1).squeeze(1)
         logits = _logits(params, last)
     with jax.named_scope("mla_kv"):
+        # (the rows begin at position 0: None says so to the commit)
         pool = write_rows(
             pool, jnp.concatenate([rows_d, rows_s], axis=0), block_tables,
-            jnp.zeros((B,), jnp.int32), real)
+            None, real, kernel)
     return logits, pool, routed
 
 
@@ -636,7 +639,7 @@ def latent_decode_chunk_paged(
     with jax.named_scope("mla_kv"):
         pool = write_rows(
             pool, rowbuf, block_tables, base_lengths,
-            jnp.broadcast_to(active[:, None], (B, num_steps)))
+            jnp.broadcast_to(active[:, None], (B, num_steps)), kernel)
     final_lengths = base_lengths + num_steps * adv
     if return_packed:
         packed = jnp.concatenate(

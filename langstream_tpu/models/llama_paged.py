@@ -28,7 +28,7 @@ from langstream_tpu.models.llama import (
     _rope,
     lora_delta,
 )
-from langstream_tpu.models.paged import gather_kv, write_rows
+from langstream_tpu.models.paged import gather_kv, write_rows_pair
 from langstream_tpu.models.quant import as_weight as _w, embedding_take
 from langstream_tpu.ops.paged_attention import (
     NEG_INF,
@@ -49,10 +49,12 @@ def llama_prefill_paged(
     mesh=None,
     ffn=None,                 # pluggable FFN sub-block (MoE family hook)
     adapters: dict | None = None,  # batched ragged LoRA (see lora_delta)
+    kernel: str = "xla",      # the engine's one selection: the commit's form
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Prompt forward + paged cache fill: the shared
     :func:`~langstream_tpu.models.llama.prefill_forward` layer math with the
-    K/V landing in pool blocks — one scatter commit per K and V."""
+    K/V landing in pool blocks — one commit of K and V, from position 0
+    (:func:`~langstream_tpu.models.paged.write_rows_pair`)."""
     from langstream_tpu.models.llama import prefill_forward
 
     c = config
@@ -64,10 +66,17 @@ def llama_prefill_paged(
     KhD = c.kv_heads * c.head_dim
     L = ks.shape[0]
     valid = (jnp.arange(Pn)[None, :] < lengths[:, None])
-    starts = jnp.zeros((B,), dtype=jnp.int32)
-    pool_k = write_rows(pool_k, ks.reshape(L, B, Pn, KhD), block_tables, starts, valid)
-    pool_v = write_rows(pool_v, vs.reshape(L, B, Pn, KhD), block_tables, starts, valid)
+    pool_k, pool_v = write_rows_pair(
+        (pool_k, pool_v), (a.reshape(L, B, Pn, KhD) for a in (ks, vs)),
+        block_tables, None, valid, _commit_kernel(kernel, mesh))
     return logits, pool_k, pool_v
+
+
+def _commit_kernel(kernel: str, mesh) -> str:
+    """The selection handed to the commit: the read's, but the XLA scatter
+    under any mesh (``pallas_call`` has no partition rule, and the read's
+    ``shard_map`` wrapper is not the commit's)."""
+    return kernel if mesh is None else "xla"
 
 
 def llama_prefill_continue_paged(
@@ -102,7 +111,7 @@ def llama_prefill_continue_paged(
     segments with the online-softmax combine: the pool window masked to
     columns ``< start``, and causal self-attention among the suffix.
     Suffix K/V is committed at ``start`` offsets (the same
-    :func:`write_rows` the decode chunk uses). Returns the last REAL suffix
+    :func:`write_rows_pair` the decode chunk uses). Returns the last REAL suffix
     token's logits plus the updated pools.
 
     No reference analogue: the reference's completions are SaaS calls
@@ -352,12 +361,9 @@ def llama_prefill_continue_paged(
                 jnp.float32
             )
     L = c.layers
-    pool_k = write_rows(
-        pool_k, ks.reshape(L, B, P2, KhD), block_tables, start_lengths, pos_valid
-    )
-    pool_v = write_rows(
-        pool_v, vs.reshape(L, B, P2, KhD), block_tables, start_lengths, pos_valid
-    )
+    pool_k, pool_v = write_rows_pair(
+        (pool_k, pool_v), (a.reshape(L, B, P2, KhD) for a in (ks, vs)),
+        block_tables, start_lengths, pos_valid, _commit_kernel(kernel, mesh))
     return logits, pool_k, pool_v
 
 
@@ -546,7 +552,7 @@ def llama_verify_chunk_paged(
     # otherwise be silently corrupted — the decode chunk masks its commit
     # with `active` for exactly this reason). Rows are also capped at the
     # context limit: positions ≥ max_seq_len would clamp to the slot's
-    # LAST table column in write_rows and overwrite committed K/V (the
+    # LAST table column in write_rows_pair and overwrite committed K/V (the
     # engine's emit guard stops streams before any such position's token
     # is ever emitted, so capping the write loses nothing).
     room = jnp.maximum(c.max_seq_len - base_lengths, 0)
@@ -891,14 +897,10 @@ def llama_decode_chunk_paged(
 
     L = c.layers
     valid = jnp.broadcast_to(active[:, None], (B, num_steps))
-    pool_k = write_rows(
-        pool_k, kbuf.reshape(L, B, num_steps, KhD), block_tables,
-        base_lengths, valid,
-    )
-    pool_v = write_rows(
-        pool_v, vbuf.reshape(L, B, num_steps, KhD), block_tables,
-        base_lengths, valid,
-    )
+    pool_k, pool_v = write_rows_pair(
+        (pool_k, pool_v),
+        (a.reshape(L, B, num_steps, KhD) for a in (kbuf, vbuf)),
+        block_tables, base_lengths, valid, _commit_kernel(kernel, mesh))
     final_lengths = base_lengths + num_steps * adv
     if return_packed:
         packed = pack_tokens_logprobs(chunk_tokens, chunk_lps)

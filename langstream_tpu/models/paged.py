@@ -131,27 +131,92 @@ def write_rows(
     cache,                  # (L, nb, bs, KhD) array, or int8 {"q","s"} pools
     rows: jax.Array,        # (L, B, T, KhD) — new bf16 K or V rows per slot
     block_tables: jax.Array,  # (B, max_blocks) int32
-    starts: jax.Array,      # (B,) first sequence position of rows[;, b]
+    starts,                 # (B,) first sequence position of rows[;, b]
     valid: jax.Array,       # (B, T) bool — rows beyond a slot's true count
+    kernel: str = "xla",    # the engine's one kernel selection, handed down
 ):
-    """Scatter ``rows`` into the pool at each slot's block-mapped positions.
+    """ONE pool's commit (the K/V handoff's import, the tests):
+    :func:`write_rows_pair` of a pair of one."""
+    return write_rows_pair(
+        (cache,), (rows,), block_tables, starts, valid, kernel)[0]
+
+
+def write_rows_pair(
+    caches: tuple,          # the pools of ONE kind: (K, V), or (latent,)
+    rows,                   # as many (L, B, T, KhD) arrays of new rows,
+                            # drawn a pool at a time (see below)
+    block_tables: jax.Array,  # (B, max_blocks) int32
+    starts,                 # (B,) first sequence position of rows[;, b];
+                            # None: 0, every slot's (a prefill's statement)
+    valid: jax.Array,       # (B, T) bool — rows beyond a slot's true count
+    kernel: str = "xla",    # the engine's one kernel selection, handed down
+) -> tuple:
+    """Put ``rows`` into the pools at each slot's block-mapped positions.
 
     The one commit of every program family (dense prefill, continuation,
     decode chunk and verify, the K/V handoff's import, the hybrid's
-    attention layers). The pool is scattered as ``(L * nb * bs, tail)`` with
-    the layer folded into the row index, ``l * nb * bs + row``: one scatter
-    along the leading axis, in place on a donated pool. Scattered along its
-    second axis (``.at[:, row]``) the compiler moves the layer axis inward
-    and back out, a copy of the whole pool each way for K and for V; the
-    reshapes here move nothing (``bs`` is a multiple of every row tile).
+    attention layers), under the scope ``kv_commit``, in one of two forms
+    (:func:`langstream_tpu.ops.pool_commit.commit_form`: the selection the
+    read kernels follow, and the pool's type):
 
-    Invalid rows are redirected to their layer's scratch row (block 0 never
-    backs live data; see BlockManager) so the scatter stays shape-static. An
-    int8 pool quantises the rows here — write sites stay layout-agnostic.
-    Rows that are ALREADY quantized (an int8 ``{"q","s"}`` pair, e.g. a KV
-    handoff payload from another replica's identical pool) scatter verbatim,
-    so a transfer never pays a dequant/requant round trip.
+    ``"xla"`` (the CPU, every int8 pool, any mesh; the tests' reference):
+    each pool is scattered as ``(L * nb * bs, tail)`` with the layer folded
+    into the row index, ``l * nb * bs + row``: one scatter along the leading
+    axis, in place on a donated pool. Scattered along its second axis
+    (``.at[:, row]``) the compiler moves the layer axis inward and back out,
+    a copy of the whole pool each way for K and for V; the reshapes here
+    move nothing (``bs`` is a multiple of every row tile). Invalid rows are
+    redirected to their layer's scratch row (block 0 never backs live data;
+    see BlockManager) so the scatter stays shape-static, and each row is
+    issued alone: 130 ns a row on the v5e whatever it holds. ``rows`` is
+    drawn a pool at a time, right before the pool's scatter: a site that
+    hands a generator (``(a.reshape(...) for a in (ks, vs))``) keeps what
+    makes a pool's rows next to its scatter in the program's lowered text,
+    where it was before the pools were committed by one call.
+
+    ``"pallas"`` (a bf16 pool on a TPU): ONE call of ``ops/pool_commit.py``
+    moves runs of rows of every pool of the kind, all layers of a run in one
+    copy, in place. **``valid`` has to be ONE interval of rows a slot**
+    (every caller's is: a prefill's ``[0, length)``, the window kind's last
+    ``W`` rows, all or none of a decode chunk's); the rows outside it are
+    skipped, so the pools are what the scatter leaves in every block but the
+    scratch block 0. A prefill states that its rows begin at position 0 by
+    handing ``starts`` None, and only the commit of whole tiles of ``rows``
+    is traced for it (what is traced is paid at every set-up: a program's
+    first warm use is its trace and lowering).
+
+    An int8 pool quantises the rows here — write sites stay
+    layout-agnostic. Rows that are ALREADY quantized (an int8 ``{"q","s"}``
+    pair, e.g. a KV handoff payload from another replica's identical pool)
+    scatter verbatim, so a transfer never pays a dequant/requant round trip.
     """
+    from langstream_tpu.ops.pool_commit import commit_form, pool_commit
+
+    with jax.named_scope("kv_commit"):
+        form = commit_form(kernel, caches[0])
+        if form == "xla":
+            if starts is None:
+                starts = jnp.zeros((valid.shape[0],), jnp.int32)
+            return tuple(
+                _scatter_rows(cache, new, block_tables, starts, valid)
+                for cache, new in zip(caches, rows))
+        if not isinstance(valid, jax.core.Tracer):
+            held = np.asarray(valid)
+            edges = np.diff(held.astype(np.int8), axis=1, prepend=0, append=0)
+            if (np.abs(edges).sum(axis=1) > 2).any():
+                raise ValueError(
+                    "write_rows: the kernel commits ONE interval of rows a "
+                    "slot; this mask has a gap")
+        held = valid.astype(jnp.int32)
+        lo = jax.lax.argmax(held, 1, jnp.int32)
+        return pool_commit(
+            caches, tuple(rows), block_tables, starts, lo,
+            lo + jax.lax.reduce_sum(held, (1,)),
+            interpret=form == "pallas-interpret")
+
+
+def _scatter_rows(cache, rows, block_tables, starts, valid):
+    """:func:`write_rows` as one XLA scatter on the folded pool."""
     quant = isinstance(cache, dict)
     L, nb, bs, KhD = (cache["q"] if quant else cache).shape
     B, T = (rows["q"] if isinstance(rows, dict) else rows).shape[1:3]

@@ -63,7 +63,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from langstream_tpu.models.family import Family
-from langstream_tpu.models.hybrid import moe_mixer
+from langstream_tpu.models.hybrid import backend_kernel, moe_mixer
 from langstream_tpu.models.llama import (
     _apply_rope,
     _flash_mode,
@@ -76,12 +76,17 @@ from langstream_tpu.models.llama_paged import (
     pack_tokens_logprobs,
 )
 from langstream_tpu.models.moe import silu_gated
-from langstream_tpu.models.paged import PagedLayout, init_kv_pool, write_rows
+from langstream_tpu.models.paged import (
+    PagedLayout,
+    init_kv_pool,
+    write_rows_pair,
+)
 from langstream_tpu.ops.paged_attention import (
     NEG_INF,
     merge_partial_attention,
     paged_attention_partial,
 )
+from langstream_tpu.ops.pool_commit import commit_form
 
 #: query and key rows of one block of the prefill's flash kernel (keys and
 #: values of 128; the latent family's measurement at 192 / 128 chose the
@@ -484,7 +489,7 @@ def swa_prefill_paged(
     kernel: str | None = None,
 ):
     """Prompt forward: every layer's K and V rows land in its kind's pool
-    through :func:`langstream_tpu.models.paged.write_rows`, a full layer's
+    through :func:`langstream_tpu.models.paged.write_rows_pair`, a full layer's
     all of them, a window layer's last ``window`` alone (the rows a later
     query can still see; an earlier one's ring block would be overwritten by
     a later one's within this very scatter). Returns ``(last-token logits
@@ -502,6 +507,8 @@ def swa_prefill_paged(
     real = positions[None, :] < lengths[:, None]                   # (B, P)
     flash = (_flash_mode(Pn) if use_flash is None
              else ("compiled" if use_flash else None))
+    if kernel is None:      # as moe_mixer resolves it; the commit reads it too
+        kernel = backend_kernel()
 
     def attend(q, k, v, kind):
         window = c.window if kind == "W" else None
@@ -554,20 +561,19 @@ def swa_prefill_paged(
             x, (lengths - 1)[:, None, None].clip(0), axis=1).squeeze(1)
         logits = _logits(params, last)
     full_tables, window_tables = split_tables(block_tables)
-    starts = jnp.zeros((B,), jnp.int32)
+    # the rows begin at position 0: None says so to the commit's kernel; the
+    # scatter's zeros are made once for both kinds, as its text had them
+    starts = (jnp.zeros((B,), jnp.int32)
+              if commit_form(kernel, pool_k) == "xla" else None)
     with jax.named_scope("kv_write"):
-        pool_k = write_rows(
-            pool_k, jnp.stack(rows["F"][0]), full_tables, starts, real)
-        pool_v = write_rows(
-            pool_v, jnp.stack(rows["F"][1]), full_tables, starts, real)
+        pool_k, pool_v = write_rows_pair(
+            (pool_k, pool_v), (jnp.stack(r) for r in rows["F"]), full_tables,
+            starts, real, kernel)
     with jax.named_scope("swa_write"):
         seen = real & (positions[None, :] >= (lengths - c.window)[:, None])
-        wpool = {
-            "k": write_rows(wpool["k"], jnp.stack(rows["W"][0]),
-                            window_tables, starts, seen),
-            "v": write_rows(wpool["v"], jnp.stack(rows["W"][1]),
-                            window_tables, starts, seen),
-        }
+        wpool = dict(zip("kv", write_rows_pair(
+            (wpool["k"], wpool["v"]), (jnp.stack(r) for r in rows["W"]),
+            window_tables, starts, seen, kernel)))
     return logits, pool_k, pool_v, wpool, jnp.stack(routed)
 
 
@@ -596,7 +602,7 @@ def swa_decode_chunk_paged(
 ):
     """K fused decode steps. Both pools are read-only and every layer's new
     K and V rows gather in a chunk buffer (one scatter a pool at the end,
-    :func:`langstream_tpu.models.paged.write_rows`; a window layer's rows
+    :func:`langstream_tpu.models.paged.write_rows_pair`; a window layer's rows
     overwrite, through the ring, rows that lay behind the window before the
     chunk began). A window layer's read is told the first row its query
     sees, ``length + step - (window - 1)``, and walks the blocks from there.
@@ -715,17 +721,14 @@ def swa_decode_chunk_paged(
             for buf, k in zip(bufs, c.layer_kinds) if k == kind])
 
     with jax.named_scope("kv_write"):
-        pool_k = write_rows(
-            pool_k, of_kind(kbufs, "F"), full_tables, base_lengths, valid)
-        pool_v = write_rows(
-            pool_v, of_kind(vbufs, "F"), full_tables, base_lengths, valid)
+        pool_k, pool_v = write_rows_pair(
+            (pool_k, pool_v), (of_kind(b, "F") for b in (kbufs, vbufs)),
+            full_tables, base_lengths, valid, kernel)
     with jax.named_scope("swa_write"):
-        wpool = {
-            "k": write_rows(wpool["k"], of_kind(kbufs, "W"), window_tables,
-                            base_lengths, valid),
-            "v": write_rows(wpool["v"], of_kind(vbufs, "W"), window_tables,
-                            base_lengths, valid),
-        }
+        wpool = dict(zip("kv", write_rows_pair(
+            (wpool["k"], wpool["v"]),
+            (of_kind(b, "W") for b in (kbufs, vbufs)), window_tables,
+            base_lengths, valid, kernel)))
     final_lengths = base_lengths + num_steps * adv
     if return_packed:
         packed = jnp.concatenate(

@@ -347,6 +347,63 @@ def check_kernels(
         "_flash_kernel",
         {"B": 1, "S": flash_seq, "H": H, "Kh": Kh, "D": D}, interpret, flash,
     ))
+    rows += check_commit_kernel(
+        c, block_size=block_size, batch=batch, prefill_rows=flash_seq,
+        interpret=interpret)
+    return rows
+
+
+def check_commit_kernel(model_config, *, block_size: int, batch: int,
+                        prefill_rows: int = 512,
+                        interpret: bool = False) -> list[dict[str, Any]]:
+    """Two rows: the commit of new K and V rows into bf16 pools
+    (``ops/pool_commit.py`` under ``models/paged.py`` ``write_rows_pair``,
+    both pools in ONE call) at the model's row width on stacks of two
+    layers, against the scatter, bit for bit in every block but the scratch
+    block 0 (tolerance 0), in each form a program takes: **shifted**, a
+    decode chunk's 32 rows a slot from starts on every side of a tile's and
+    a block's edge with one slot idle, and **aligned**, a prefill's ragged
+    lengths from row 0 with ``starts`` stated as None. No CPU run lowers the
+    kernel: this is where Mosaic compiles it."""
+    from langstream_tpu.models.paged import write_rows_pair
+
+    c = model_config
+    tail = c.kv_heads * c.head_dim
+    # (room for a chunk's 32 rows from the latest start, 3 blocks less one)
+    columns = -(-max(prefill_rows, 3 * block_size + 32) // block_size)
+    nb = batch * columns + 1
+    keys = jax.random.split(jax.random.PRNGKey(24), 4)
+    pools = tuple(jax.random.normal(k, (2, nb, block_size, tail), jnp.bfloat16)
+                  for k in keys[:2])
+    tables = jnp.asarray(
+        1 + np.arange(batch * columns).reshape(batch, columns), jnp.int32)
+    slots = np.arange(batch)
+    cases = {
+        # starts 0, 17, 34, ...: aligned, odd and even shifts, a block's edge
+        "shifted": (32, jnp.asarray(
+            (17 * slots) % (3 * block_size), jnp.int32),
+            np.where(slots == 1, 0, 32)),
+        "aligned": (prefill_rows, None,
+                    np.linspace(1, prefill_rows, batch).astype(np.int64)),
+    }
+    rows = []
+    for name, (T, starts, counts) in cases.items():
+        new = tuple(jax.random.normal(k, (2, batch, T, tail), jnp.bfloat16)
+                    for k in keys[2:])
+        valid = jnp.asarray(np.arange(T)[None, :] < counts[:, None])
+
+        def run(new=new, starts=starts, valid=valid):
+            got, ref = (
+                jax.jit(lambda p, r, kernel=kernel: write_rows_pair(
+                    p, r, tables, starts, valid, kernel))(pools, new)
+                for kernel in ("pallas-interpret" if interpret else "pallas",
+                               "xla"))
+            return (jnp.stack(got)[:, :, 1:], jnp.stack(ref)[:, :, 1:])
+
+        rows.append(_row(
+            "_pool_commit_kernel",
+            {"form": name, "pools": 2, "L": 2, "B": batch, "T": T,
+             "tail": tail, "block": block_size}, interpret, run, tol=0.0))
     return rows
 
 

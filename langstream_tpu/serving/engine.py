@@ -71,6 +71,7 @@ from langstream_tpu.models.encoder import (
 )
 from langstream_tpu.models.moe import grouped_form
 from langstream_tpu.models.tokenizer import Tokenizer, load_tokenizer
+from langstream_tpu.ops.pool_commit import commit_form
 from langstream_tpu.serving.attribution import (
     ModelShape,
     ProgramLedger,
@@ -1845,6 +1846,12 @@ class TpuServingEngine:
             lambda: (init_cache(), init_state())
         )
         cache_k, cache_v = init_cache()
+        # and the form of every program's commit of new rows into this pool
+        # (models/paged.py write_rows, ops/pool_commit.py): the selection
+        # where the kernel can move the pool (bf16, no mesh: pallas_call has
+        # no partition rule), the XLA scatter for an int8 pool or a mesh
+        self.pool_commit_kernel = commit_form(
+            kernel if self.mesh is None else "xla", cache_k)
         # recurrent state beside the pool: None for every family but the
         # hybrid one; donated and re-bound with the caches
         self.state = init_state()
@@ -2115,6 +2122,7 @@ class TpuServingEngine:
                     mc_static, params, tokens, lengths, cache_k, cache_v,
                     tables, use_flash=prefill_flash, mesh=mesh_static,
                     ffn=ffn_static, adapters=adapters,
+                    kernel=self.paged_read_kernel,
                 )
                 with jax.named_scope("sample"):
                     next_tokens, logprobs = _fetchable(
@@ -3345,6 +3353,9 @@ class TpuServingEngine:
             # how a decode step's pass over the Mamba-2 state is lowered
             # (what mamba_step was handed; None without such state)
             "ssm_state_kernel": self.ssm_state_kernel,
+            # the form of every program's commit of new rows into the pool
+            # (write_rows: it engages on every commit or on none)
+            "pool_commit_kernel": self.pool_commit_kernel,
             # the form a prefill's routed experts' grouped pass takes
             # (models/moe.py grouped_form of what the programs are handed
             # and the share of the experts held; None without experts), and
@@ -4318,7 +4329,7 @@ class TpuServingEngine:
                 self._fault("scatter")
                 out_k, out_v = kvtransfer.scatter_slot(
                     self.cache_k, self.cache_v, arrays, table_row, rows,
-                    padded,
+                    padded, kernel=self.pool_commit_kernel,
                 )
                 # donated pools re-bound on the dispatch thread — the
                 # same side every dispatch closure reads them (RACE801)
@@ -5325,7 +5336,7 @@ class TpuServingEngine:
             }
             out_k, out_v = kvtransfer.scatter_slot(
                 self.cache_k, self.cache_v, arrays,
-                table_row, rows, padded,
+                table_row, rows, padded, kernel=self.pool_commit_kernel,
             )
             # donated pools re-bound on the dispatch thread (RACE801:
             # single thread role, same contract as every dispatch)

@@ -292,13 +292,16 @@ fetch_rows = _fetch_rows
 # ---------------------------------------------------------------------------
 
 
-@partial(jax.jit, donate_argnums=(0, 1))
-def _scatter_pools(cache_k, cache_v, k_rows, v_rows, tables, starts, valid):
+@partial(jax.jit, donate_argnums=(0, 1), static_argnames=("kernel",))
+def _scatter_pools(cache_k, cache_v, k_rows, v_rows, tables, starts, valid,
+                   kernel="xla"):
     """Write one imported slot's rows into both pools (donated — the
-    caller rebinds, same contract as every engine dispatch)."""
+    caller rebinds, same contract as every engine dispatch); ``valid`` is
+    the interval ``[0, rows)``, ``kernel`` the commit's form
+    (:func:`~langstream_tpu.models.paged.write_rows`)."""
     return (
-        write_rows(cache_k, k_rows, tables, starts, valid),
-        write_rows(cache_v, v_rows, tables, starts, valid),
+        write_rows(cache_k, k_rows, tables, starts, valid, kernel),
+        write_rows(cache_v, v_rows, tables, starts, valid, kernel),
     )
 
 
@@ -338,15 +341,19 @@ def scatter_slot(
     table_row: np.ndarray,
     rows: int,
     padded_rows: int,
+    kernel: str = "xla",
 ):
     """Scatter an imported slot's rows into the (donated) pools via the
-    slot's freshly allocated block table. Returns the new pool handles —
-    async dispatch; the caller's dispatch-thread closure syncs/times."""
+    slot's freshly allocated block table, in the form the engine's commits
+    take (``kernel``: its ``pool_commit_kernel``). Returns the new pool
+    handles — async dispatch; the caller's dispatch-thread closure
+    syncs/times."""
     k_rows = _rows_tree(arrays, "k", rows, padded_rows)
     v_rows = _rows_tree(arrays, "v", rows, padded_rows)
     tables = jnp.asarray(np.asarray(table_row, dtype=np.int32)[None, :])
     starts = jnp.zeros((1,), dtype=jnp.int32)
     valid = jnp.asarray((np.arange(padded_rows) < rows)[None, :])
     return _scatter_pools(
-        cache_k, cache_v, k_rows, v_rows, tables, starts, valid
+        cache_k, cache_v, k_rows, v_rows, tables, starts, valid,
+        kernel=kernel,
     )

@@ -73,4 +73,7 @@ def test_a_pattern_without_the_delta_rule_traces_nothing_of_it(model):
     pallas, jaxpr, reported = lowered_prefill(model, "pallas-interpret")
     assert reported == "pallas-interpret"
     assert "delta_chunk_rule" not in jaxpr and "triangular_solve" not in jaxpr
-    assert pallas == xla                    # the same program under either
+    # the one kernel the selection puts into this prefill is the commit's
+    # (ops/pool_commit.py, PR 49: K and V in one call)
+    assert jaxpr.count("pallas_call") == 1 and "name=pool_commit" in jaxpr
+    assert pallas != xla

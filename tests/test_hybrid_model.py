@@ -465,7 +465,8 @@ def test_the_shares_add_up_to_the_uncut_reference_layer(c):
 # -- the commit is the one every family uses -------------------------------
 
 
-def parents_hybrid_write_rows(pool, rows, block_tables, starts, valid):
+def parents_hybrid_write_rows(pool, rows, block_tables, starts, valid,
+                             kernel="xla"):
     """The hybrid's own commit as the parent (8e35ac5) had it in
     ``models/hybrid.py``: what ``models/paged.py`` ``write_rows`` has to
     trace to for a plain-array pool."""
@@ -522,12 +523,15 @@ def test_the_shared_commit_leaves_the_hybrid_programs_as_they_were(
     the one of ``models/paged.py`` its prefill and decode chunk are, jaxpr
     and optimised HLO instruction for instruction, what they were with the
     parent's own."""
+    from test_paged import a_pool_at_a_time
+
     from langstream_tpu.models import hybrid, paged
 
-    assert hybrid.write_rows is paged.write_rows
+    assert hybrid.write_rows_pair is paged.write_rows_pair
     assert "def write_rows" not in open(hybrid.__file__).read()
     shared = _hybrid_programs(c, params)
-    monkeypatch.setattr(hybrid, "write_rows", parents_hybrid_write_rows)
+    monkeypatch.setattr(hybrid, "write_rows_pair",
+                        a_pool_at_a_time(parents_hybrid_write_rows))
     own = _hybrid_programs(c, params)
     for name in ("prefill", "chunk"):
         assert "scatter" in shared[name][0]

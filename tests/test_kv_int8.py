@@ -237,7 +237,11 @@ def test_the_int8_pool_after_a_program_is_the_parents_bit_for_bit(
     holds rows: data and scales come out as they did with the parent's
     commit (``tests/test_paged.py`` keeps it as the plain reference), and
     so do the tokens and logits beside them."""
-    from test_paged import greedy_sample, reference_write_rows
+    from test_paged import (
+        a_pool_at_a_time,
+        greedy_sample,
+        reference_write_rows,
+    )
 
     from langstream_tpu.models import llama_paged
     from langstream_tpu.models.paged import PagedLayout, init_paged_kv_cache_int8
@@ -270,7 +274,8 @@ def test_the_int8_pool_after_a_program_is_the_parents_bit_for_bit(
             jax.random.PRNGKey(0), 4, num_read_blocks=3)
 
     got = run()
-    monkeypatch.setattr(llama_paged, "write_rows", reference_write_rows)
+    monkeypatch.setattr(llama_paged, "write_rows_pair",
+                        a_pool_at_a_time(reference_write_rows))
     want = run()
     assert jax.tree.structure(got) == jax.tree.structure(want)
     for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):

@@ -117,8 +117,24 @@ def test_write_rows_and_gather_roundtrip():
 # ---------------------------------------------------------------------------
 
 
-def reference_write_rows(cache, rows, block_tables, starts, valid):
-    """The commit as the parent (8e35ac5) wrote it, the plain reference:
+def a_pool_at_a_time(commit):
+    """A one-pool reference ``commit`` in ``write_rows_pair``'s place in a
+    program: the pools of a kind in turn, each one's rows drawn right before
+    its commit (as the scatter form does), a prefill's ``starts`` None as the
+    zeros they state."""
+    def pair(caches, rows, block_tables, starts, valid, kernel="xla"):
+        if starts is None:
+            starts = jnp.zeros((valid.shape[0],), jnp.int32)
+        return tuple(commit(cache, new, block_tables, starts, valid)
+                     for cache, new in zip(caches, rows))
+
+    return pair
+
+
+def reference_write_rows(cache, rows, block_tables, starts, valid,
+                         kernel="xla"):
+    """The commit as the parent (8e35ac5) wrote it, the plain reference
+    (whatever selection the caller hands down):
     the pool seen as ``(L, nb*bs, tail)`` and scattered along its SECOND
     axis. Same rows to the same places; on the chip the compiler moved the
     layer axis inward and back out around it, a copy of the whole pool each
@@ -231,6 +247,157 @@ def test_write_rows_writes_the_pool_the_parents_form_wrote(kind, commit, L):
                 np.asarray(args[1][leaf][:, 0, 0]))
 
 
+# ---------------------------------------------------------------------------
+# the commit's kernel (ops/pool_commit.py), interpreted, against the scatter
+# ---------------------------------------------------------------------------
+
+
+def _interval(B, T, lo, hi):
+    t = np.arange(T)[None, :]
+    return (t >= np.asarray(lo)[:, None]) & (t < np.asarray(hi)[:, None])
+
+
+def _ring(blocks, cols):
+    """A window kind's table: logical block ``n`` in ring block ``n % ring``."""
+    return [[row[c % len(row)] for c in range(cols)] for row in blocks]
+
+
+# dtype, block rows, row lanes, tables, starts, T, valid
+_KERNEL_COMMITS = {
+    # the scatter's four, as they are, on a float32 pool (a tile is 8 rows,
+    # a block of 8 one tile) ...
+    **{f"{name}-f32": ("float32", 8, 32, [[4, 1, 7], [2, 8, 5]], starts, T,
+                       valid)
+       for name, (starts, T, valid) in _COMMITS.items()},
+    # ... and on a bfloat16 pool at twice the rows a block (a tile is 16)
+    **{f"{name}-bf16": ("bfloat16", 16, 32, [[4, 1, 7], [2, 8, 5]],
+                        [2 * s for s in starts], T, valid)
+       for name, (starts, T, valid) in _COMMITS.items()},
+    # the window kind's prefill: the last 128 rows of a prompt of 250, through
+    # a ring of three blocks of 64 (an edge tile at either end, whole tiles
+    # copied between them)
+    "window-through-a-ring": (
+        "bfloat16", 64, 128, _ring([[5, 2, 7]], 4), [0], 256,
+        _interval(1, 256, [122], [250])),
+    # a prefill of whole blocks and a ragged last one, beside a short one
+    "prefill-ragged-last-block": (
+        "bfloat16", 64, 128, [[3, 6, 1, 8], [2, 5, 4, 7]], [0, 0], 256,
+        _interval(2, 256, [0, 0], [201, 17])),
+    # a decode chunk with an idle slot between two live ones
+    "idle-slot": (
+        "bfloat16", 64, 128, [[3, 6], [2, 5], [4, 7]], [9, 40, 77], 32,
+        _interval(3, 32, [0, 0, 0], [32, 0, 32])),
+    # 8 and 32 rows across a block edge (8: less than a tile of the rows)
+    "T8-across-a-block-edge": (
+        "bfloat16", 64, 128, [[3, 6], [2, 5]], [60, 57], 8,
+        np.ones((2, 8), bool)),
+    "T32-across-a-block-edge": (
+        "bfloat16", 64, 128, [[3, 6], [2, 5]], [50, 33], 32,
+        np.ones((2, 32), bool)),
+    # a start on a tile's edge: the rows' tiles ARE the pool's (direct)
+    "T32-on-a-tile-edge": (
+        "bfloat16", 64, 128, [[3, 6], [2, 5]], [16, 64], 32,
+        np.ones((2, 32), bool)),
+    # a run that starts and ends inside one 16-row tile (rows 69-75)
+    "inside-one-tile": (
+        "bfloat16", 64, 128, [[3, 6]], [67], 16, _interval(1, 16, [2], [9])),
+    # a continuation: a start inside a tile and many tiles of rows
+    "continuation": (
+        "bfloat16", 64, 128, [[3, 6, 1, 8], [2, 5, 4, 7]], [13, 64], 96,
+        _interval(2, 96, [0, 0], [96, 41])),
+    # the served rows' widths: nemotron_h 256, Mellum 512, the latent pool's
+    # 640, InternLM2 and Trinity 1,024
+    **{f"tail-{tail}": (
+        "bfloat16", 64, tail, [[3, 6], [2, 5]], [37, 90], 32,
+        np.ones((2, 32), bool)) for tail in (256, 512, 640, 1024)},
+}
+
+
+@pytest.mark.parametrize("form", ["one", "pair", "aligned"])
+@pytest.mark.parametrize("L", [1, 3])
+@pytest.mark.parametrize("commit", sorted(_KERNEL_COMMITS))
+def test_the_kernel_commits_what_the_scatter_commits(commit, L, form):
+    """Under ``"pallas-interpret"`` the commit leaves every block but the
+    scratch block 0 bit for bit as the scatter leaves it (the rows of the
+    interval where the table says, every other row of every table's blocks
+    and every block no table names as it was), and writes nothing of block
+    0's neighbours: ``write_rows`` of ONE pool, ``write_rows_pair`` of K and
+    V in one call (each pool its own rows), and the ALIGNED form of the pair
+    (the case's intervals from position 0, ``starts`` handed as None, as
+    every prefill hands them: whole tiles copied, an edge under one mask)."""
+    from langstream_tpu.models.paged import write_rows, write_rows_pair
+    from langstream_tpu.ops.pool_commit import commit_form
+
+    dtype, bs, tail, tables, starts, T, valid = _KERNEL_COMMITS[commit]
+    rng = np.random.default_rng(len(commit) + L)
+    B, nb = len(tables), 9
+    pools = tuple(jnp.asarray(rng.normal(size=(L, nb, bs, tail)), dtype)
+                  for _ in range(1 if form == "one" else 2))
+    rows = tuple(jnp.asarray(rng.normal(size=(L, B, T, tail)), dtype)
+                 for _ in pools)
+    tables, valid = jnp.asarray(tables, jnp.int32), jnp.asarray(valid)
+    starts = None if form == "aligned" else jnp.asarray(starts, jnp.int32)
+    assert commit_form("pallas-interpret", pools[0]) == "pallas-interpret"
+
+    def commit_under(kernel):
+        if form == "one":
+            return jax.jit(lambda p, r: (write_rows(
+                p, r, tables, starts, valid, kernel),))(pools[0], rows[0])
+        return jax.jit(lambda p, r: write_rows_pair(
+            p, iter(r), tables, starts, valid, kernel))(pools, rows)
+
+    as_bits = lambda a: np.asarray(a).view(  # noqa: E731
+        np.uint16 if dtype == "bfloat16" else np.uint32)
+    named = {b for row in np.asarray(tables) for b in row}
+    for got, want, pool in zip(
+            commit_under("pallas-interpret"), commit_under("xla"), pools):
+        assert got.dtype == pool.dtype and got.shape == pool.shape
+        np.testing.assert_array_equal(as_bits(got)[:, 1:], as_bits(want)[:, 1:])
+        for block in set(range(1, nb)) - named:
+            np.testing.assert_array_equal(
+                as_bits(got)[:, block], as_bits(pool)[:, block])
+        if np.asarray(valid).any():
+            assert not np.array_equal(
+                as_bits(got)[:, 1:], as_bits(pool)[:, 1:])
+
+
+def test_the_kernel_refuses_a_mask_with_a_gap_and_takes_the_pools_it_can():
+    """The kernel commits ONE interval a slot: a mask it can read (not
+    traced) with a gap is an error, not a wrong pool. The form follows the
+    selection and the pool: an int8 pool, a block shorter than a tile and,
+    compiled, a row that is not whole lane tiles keep the scatter; a mesh
+    keeps it in the dense family's programs."""
+    from langstream_tpu.models.llama_paged import _commit_kernel
+    from langstream_tpu.models.paged import write_rows
+    from langstream_tpu.ops.pool_commit import commit_form, tile_rows
+
+    pool = jnp.zeros((1, 3, 16, 128), jnp.bfloat16)
+    rows = jnp.ones((1, 1, 16, 128), jnp.bfloat16)
+    tables, starts = jnp.asarray([[1, 2]], jnp.int32), jnp.zeros(1, jnp.int32)
+    gap = jnp.asarray([[True] * 4 + [False] * 2 + [True] * 10])
+    with pytest.raises(ValueError, match="ONE interval"):
+        write_rows(pool, rows, tables, starts, gap, kernel="pallas-interpret")
+    # the scatter takes any mask
+    write_rows(pool, rows, tables, starts, gap)
+    assert (tile_rows(jnp.bfloat16), tile_rows(jnp.float32),
+            tile_rows(jnp.int8)) == (16, 8, 0)
+    quant = {"q": jnp.zeros((1, 3, 64, 128), jnp.int8),
+             "s": jnp.zeros((1, 3, 64, 2), jnp.float32)}
+    for kernel in ("pallas", "pallas-interpret"):
+        assert commit_form(kernel, pool) == kernel
+        assert commit_form(kernel, quant) == "xla"
+        assert commit_form(
+            kernel, jnp.zeros((1, 3, 8, 128), jnp.bfloat16)) == "xla"
+        assert commit_form("xla", pool) == "xla"
+    narrow = jnp.zeros((1, 3, 16, 96), jnp.bfloat16)
+    assert commit_form("pallas", narrow) == "xla"
+    assert commit_form("pallas-interpret", narrow) == "pallas-interpret"
+    with pytest.raises(ValueError, match="unknown commit kernel"):
+        commit_form("mosaic", pool)
+    assert _commit_kernel("pallas", None) == "pallas"
+    assert _commit_kernel("pallas", object()) == "xla"
+
+
 def _all_eqns(jaxpr):
     """Every equation of a jaxpr and of the jaxprs inside it (jit, scan,
     cond, custom calls), in order."""
@@ -333,7 +500,8 @@ def test_no_compiled_program_holds_a_second_pool(monkeypatch):
         assert opcodes.count("scatter") == 2, (name, ops)
         # a fusion is the scatter's own wrapper; nothing else is there
         assert set(opcodes) <= {"scatter", "fusion"}, (name, ops)
-    monkeypatch.setattr(llama_paged, "write_rows", reference_write_rows)
+    monkeypatch.setattr(llama_paged, "write_rows_pair",
+                        a_pool_at_a_time(reference_write_rows))
     for name, ops in pool_ops().items():
         assert {"transpose", "copy"} & {op for _, op, _ in ops}, (name, ops)
 
@@ -1684,9 +1852,13 @@ def test_continuation_pallas_kernel_matches_xla():
     np.testing.assert_allclose(
         outs["xla"][0], outs["pallas-interpret"][0], rtol=1e-4, atol=1e-4
     )
+    # every block but the scratch block 0: the selection is the commit's
+    # too, and the kernel skips the padded rows the scatter sends there
     np.testing.assert_allclose(
-        outs["xla"][1], outs["pallas-interpret"][1], rtol=1e-4, atol=1e-4
+        outs["xla"][1][:, 1:], outs["pallas-interpret"][1][:, 1:],
+        rtol=1e-4, atol=1e-4,
     )
+    assert np.asarray(outs["xla"][1][:, 1:]).any()
 
 
 def test_continuation_pallas_kernel_sharded_matches_xla():

@@ -212,6 +212,60 @@ def test_mosaic_builds_the_kernel_at_the_served_shapes_in_place(
     assert "tpu_custom_call" in text and "ssm_state_step" in text
 
 
+# layers, pool blocks, row lanes, slots, rows a slot
+_COMMIT_SHAPES = {
+    "chat-chunk": (24, 901, 1024, 128, 32),
+    "chat-chunk-of-8": (24, 901, 1024, 128, 8),
+    "rag-prefill": (24, 901, 1024, 8, 2048),
+    "mellum-window": (6, 3265, 512, 1, 8192),
+    "latent-640-lanes": (5, 4001, 640, 1, 8192),
+    "nemotron-256-lanes": (6, 2001, 256, 8, 512),
+    "trinity-window": (4, 2081, 1024, 1, 16384),
+}
+
+
+@pytest.mark.parametrize("form", ["aligned", "shifted"])
+@pytest.mark.parametrize("shape", sorted(_COMMIT_SHAPES))
+def test_mosaic_builds_the_commit_at_the_served_shapes_in_place(
+        one_chip, no_compile_cache, shape, form):
+    """``models/paged.py`` ``write_rows_pair`` under ``"pallas"``
+    (ops/pool_commit.py; held HERE for the reason given below), K and V in
+    ONE call, in the form a prefill's ``starts`` (None) and a chunk's or a
+    continuation's ask for: Mosaic takes the copies of 16-row tiles and the
+    merge's 32-bit words at every served row width, each pool is its
+    output's own buffer, and what the program keeps beside them is, for a
+    chunk shorter than a tile, the padded rows."""
+    from langstream_tpu.models.paged import write_rows_pair
+
+    L, nb, tail, B, T = _COMMIT_SHAPES[shape]
+    on = lambda shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dtype, sharding=one_chip)
+
+    def commit(pool_k, pool_v, ks, vs, tables, starts, valid):
+        return write_rows_pair(
+            (pool_k, pool_v), (ks, vs), tables,
+            None if form == "aligned" else starts, valid, "pallas")
+
+    pool, rows = on((L, nb, 64, tail)), on((L, B, T, tail))
+    compiled = jax.jit(commit, donate_argnums=(0, 1)).lower(
+        pool, pool, rows, rows, on((B, 32), jnp.int32), on((B,), jnp.int32),
+        on((B, T), jnp.bool_),
+    ).compile()
+    pools = 2 * L * nb * 64 * tail * 2
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= pools
+    padded = 2 * L * B * 16 * tail * 2 if T % 16 else 0
+    assert memory.temp_size_in_bytes < padded + 4 * 2 ** 20
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and text.count("pool_commit") >= 1
+    assert text.count("custom_call_target=\"tpu_custom_call\"") == 1
+    # the device op is ``pool_commit.N``, and its name stack ends in the
+    # commit's scope: what a trace's reader files its time under
+    call, = [line for line in text.splitlines() if "custom-call(" in line]
+    assert "%pool_commit." in call and "kv_commit/pool_commit/" in call
+    assert "scatter" not in text
+
+
 def test_mosaic_builds_the_delta_rule_s_kernel_at_the_served_shape_in_place(
         one_chip, no_compile_cache):
     """solar-open2-250b-ep8: 3 layers x 192 slots x 64 heads of (128, 128)
@@ -444,7 +498,8 @@ def test_the_tpu_compiler_writes_the_chunk_buffer_s_rows_in_place(
     was rewritten to put those rows into it (ROADMAP S4). What stays is the
     loop's exit:
     one copy a buffer into the row-major form the commit's reshape takes,
-    once a chunk, as before."""
+    once a chunk, as before (the commit itself is ``pool_commit``, once, K
+    and V together)."""
     import importlib.util
     import pathlib
 
@@ -484,7 +539,13 @@ def test_the_tpu_compiler_writes_the_chunk_buffer_s_rows_in_place(
     text = compiled.as_text()
     assert "paged_read" in text
     buffer, layer = f"bf16[{L},{B},{K},{Kh},{D}]", f"bf16[{B},{K},{Kh},{D}]"
-    ops = tool.moved(tool.hlo_ops_of_shape(text, [buffer, layer]))
+    # the commit (PR 49) is the kernel, ONE call for both pools, each its
+    # own output: the exit's copy of a buffer may carry the shape the kernel
+    # takes its rows in
+    rows = f"bf16[{L},{B},{K},{Kh * D}]"
+    assert sum("custom-call(" in line and "pool_commit" in line
+               for line in text.splitlines()) == 1 and " scatter(" not in text
+    ops = tool.moved(tool.hlo_ops_of_shape(text, [buffer, layer, rows]))
     # the two updates are the carried buffers' own; none rewrites a slice
     assert [shape for _, op, shape in ops
             if op == "dynamic-update-slice"] == [buffer] * 2, ops
