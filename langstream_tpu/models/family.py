@@ -24,6 +24,7 @@ MODULES = [
     "langstream_tpu.models.hybrid",
     "langstream_tpu.models.latent",
     "langstream_tpu.models.swa",
+    "langstream_tpu.models.eva",
 ]
 
 

@@ -334,8 +334,14 @@ class BlockManager:
     def __init__(self, layout: PagedLayout, slots: int,
                  state_bytes_per_slot: int = 0,
                  window_layout: PagedLayout | None = None,
-                 window_ring: int = 0):
+                 window_ring: int = 0,
+                 summary_window: int = 0,
+                 summary_chunk: int = 0):
         self.layout = layout
+        # the growth rule of a pool of chunk summaries (models/eva.py): see
+        # blocks_needed
+        self.summary_window = summary_window
+        self.summary_chunk = summary_chunk
         self.window_layout = window_layout
         self.window_ring = window_ring if window_layout is not None else 0
         if window_layout is not None and not (
@@ -637,13 +643,27 @@ class BlockManager:
 
     # -- admission -----------------------------------------------------
 
-    def blocks_needed(self, total_tokens: int) -> int:
+    def _position_blocks(self, total_tokens: int) -> int:
         return -(-total_tokens // self.layout.block_size)
+
+    def blocks_needed(self, total_tokens: int) -> int:
+        """Blocks of this layout's pool that a slot of ``total_tokens``
+        positions reads: a block a ``block_size`` positions, or, where the
+        pool holds chunk SUMMARIES (``summary_window`` W and
+        ``summary_chunk`` C, models/eva.py: one row a chunk of C positions,
+        visible a window of W at a time once the window has closed), the
+        blocks of the ``W / C`` rows of every window closed before the last
+        position's."""
+        if not self.summary_window:
+            return self._position_blocks(total_tokens)
+        closed = max(total_tokens - 1, 0) // self.summary_window
+        return self._position_blocks(
+            closed * (self.summary_window // self.summary_chunk))
 
     def window_blocks_needed(self, total_tokens: int) -> int:
         """Window-kind blocks a slot of ``total_tokens`` rows holds: its
         blocks up to the ring, and never more (0 without the kind)."""
-        return min(self.blocks_needed(total_tokens), self.window_ring)
+        return min(self._position_blocks(total_tokens), self.window_ring)
 
     def fits_ever(self, total_tokens: int) -> bool:
         """Whether a request of this worst-case size could EVER be admitted
@@ -651,7 +671,8 @@ class BlockManager:
         up front or they would queue forever."""
         return self.blocks_needed(total_tokens) <= min(
             self.layout.num_blocks - 1, self.layout.max_blocks_per_slot
-        )
+        ) and (not self.summary_window or self._position_blocks(
+            total_tokens) <= self.layout.max_blocks_per_slot)
 
     def can_admit(self, total_tokens: int) -> bool:
         need = self.blocks_needed(total_tokens)
@@ -691,6 +712,12 @@ class BlockManager:
         """The most window-kind blocks any slot holds now (never more than
         the ring); a walk of the slots' rings, cheap enough for a dispatch."""
         return max((len(ring) for ring in self._slot_ring), default=0)
+
+    @property
+    def summary_blocks_held(self) -> int:
+        """Blocks of this layout's pool in slots' hands now (the summary
+        kind's, for a model that keeps one: models/eva.py)."""
+        return sum(map(len, self._slot_blocks))
 
     @property
     def window_blocks_held(self) -> int:
@@ -755,8 +782,10 @@ class BlockManager:
         and capping keeps the reservation invariant (those excess writes are
         redirected to the scratch block by the unallocated table columns).
         """
-        need = self.blocks_needed(tokens)
-        if self._slot_reservation[slot]:
+        # a summary pool grows a window AHEAD: the open window's chunks
+        # are written as they close and read once the window has
+        need = self.blocks_needed(tokens + self.summary_window)
+        if self._slot_reservation[slot] or self.summary_window:
             need = min(need, self._slot_reservation[slot])
         grown = 0
         while len(self._slot_shared[slot]) + len(self._slot_blocks[slot]) < need:
@@ -766,7 +795,9 @@ class BlockManager:
             self.tables[slot, idx] = b
             grown += 1
         ring = self._slot_ring[slot]
-        while len(ring) < min(need, self.window_ring):
+        while len(ring) < min(
+                self.window_blocks_needed(tokens),
+                self._slot_wreservation[slot] or self.window_ring):
             if not self._wfree:
                 raise RuntimeError(
                     "paged KV window pool exhausted despite reservation "

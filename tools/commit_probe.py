@@ -163,8 +163,15 @@ def seconds_to_lower(fn, *avals, **jit):
     t0 = time.perf_counter()
     traced = jax.jit(fn, **jit).trace(*avals)
     t1 = time.perf_counter()
-    traced.lower(lowering_platforms=("tpu",))
-    return t1 - t0, time.perf_counter() - t1
+    lowered = traced.lower(lowering_platforms=("tpu",))
+    t2 = time.perf_counter()
+    LOWERED_TEXT[:] = [lowered.as_text()]
+    return t1 - t0, t2 - t1
+
+
+#: the text the last :func:`seconds_to_lower` lowered to (a test hashes it:
+#: tests/test_eva_programs.py)
+LOWERED_TEXT: list = []
 
 
 def lowered_commits(kind, B, T, kernel, L=24, nb=901, tail=1024, cols=32):
@@ -265,6 +272,48 @@ def lowered_program(name, B, T):
         return seconds_to_lower(
             prefill, params, ints(B, T), ints(B), full, full, window, window,
             ints(B, 2 * -(-c.max_seq_len // BS)), donate_argnums=(3, 4, 5, 6))
+    if name == "deepseek":
+        from langstream_tpu.models.latent import (
+            LatentConfig,
+            init_latent_params,
+            latent_prefill_paged,
+        )
+
+        c = LatentConfig.deepseek_v2_ep8()
+        params = abstract(jax.eval_shape(lambda: init_latent_params(c)))
+        pool = on((c.layers, 2001, BS, c.row_width), jnp.bfloat16)
+
+        def prefill(params, tokens, lengths, pool, tables):
+            return latent_prefill_paged(
+                c, params, tokens, lengths, pool, tables, use_flash=True,
+                kernel="pallas")
+
+        return seconds_to_lower(
+            prefill, params, ints(B, T), ints(B), pool,
+            ints(B, -(-c.max_seq_len // BS)), donate_argnums=(3,))
+    if name == "evabyte":
+        from langstream_tpu.models.eva import (
+            EvaConfig,
+            eva_prefill_paged,
+            init_eva_params,
+        )
+
+        c = EvaConfig.evabyte_6_5b_8l()
+        params = abstract(jax.eval_shape(lambda: init_eva_params(c)))
+        width = c.heads * c.head_dim
+        summary = on((c.layers, 401, BS, width), jnp.bfloat16)
+        ring = on((c.layers, 20 * c.ring_blocks(BS) + 1, BS, width),
+                  jnp.bfloat16)
+
+        def prefill(params, tokens, lengths, pool_k, pool_v, rk, rv, tables):
+            return eva_prefill_paged(
+                c, params, tokens, lengths, pool_k, pool_v,
+                {"k": rk, "v": rv}, tables, use_flash=True, kernel="pallas")
+
+        return seconds_to_lower(
+            prefill, params, ints(B, T), ints(B), summary, summary, ring,
+            ring, ints(B, 2 * -(-c.max_seq_len // BS)),
+            donate_argnums=(3, 4, 5, 6))
     from langstream_tpu.models.hybrid import (
         HybridConfig,
         hybrid_prefill_paged,
@@ -291,7 +340,8 @@ def lowered_program(name, B, T):
 #: the whole programs Gate 1 holds to the parent's: (family, slots, rows)
 LOWERED_PROGRAMS = [
     ("internlm2", 4, 512), ("internlm2", 8, 2048), ("internlm2", 128, 32),
-    ("mellum", 1, 1024), ("nemotron", 8, 512),
+    ("mellum", 1, 1024), ("nemotron", 8, 512), ("deepseek", 1, 4096),
+    ("evabyte", 1, 8192),
 ]
 
 
@@ -322,15 +372,18 @@ def lowering(args) -> int:
         for n, first in enumerate(runs[0][0]):
             row = {k: v if isinstance(v, str) else median(
                 run[0][n][k] for run in runs) for k, v in first.items()}
-            if args.against and first["what"] == "program":
+            same = lambda run: next((  # noqa: E731
+                r for r in run[1] if r["what"] == "program" and (
+                    r["program"], r["shape"]) == (
+                    first["program"], first["shape"])), None)
+            if args.against and first["what"] == "program" and same(runs[0]):
                 # (the commits' lines are a tree's own: one without the
-                # kernel has no such line)
+                # kernel has no such line; nor has one without the family)
                 timed = [k for k, v in first.items() if not isinstance(v, str)]
                 row.update({f"against_{k}": median(
-                    run[1][n - len(runs[0][0])][k] for run in runs)
-                    for k in timed})
+                    same(run)[k] for run in runs) for k in timed})
                 row["over_s"] = median(
-                    run[0][n]["total_s"] - run[1][n - len(runs[0][0])]["total_s"]
+                    run[0][n]["total_s"] - same(run)["total_s"]
                     for run in runs)
             print(json.dumps(row), flush=True)
         return 0
@@ -360,10 +413,13 @@ def lowering(args) -> int:
         return 0
     paid = set()
     for name, B, T in LOWERED_PROGRAMS:
-        if name not in paid:    # a family's imports, at a shape of their own
-            lowered_program(name, 2, 512)
-            paid.add(name)
-        trace, lower = lowered_program(name, B, T)
+        try:
+            if name not in paid:    # a family's imports, at a shape of their own
+                lowered_program(name, 2, 512)
+                paid.add(name)
+            trace, lower = lowered_program(name, B, T)
+        except ImportError:     # a tree from before the family
+            continue
         line(what="program", program=name, shape=f"{B}x{T}",
              commit="pallas" if has_kernel else "xla", trace_s=trace,
              lower_s=lower, total_s=trace + lower)
