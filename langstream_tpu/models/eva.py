@@ -83,6 +83,7 @@ from langstream_tpu.models.paged import (
     init_kv_pool,
     write_rows_pair,
 )
+from langstream_tpu.models.swa import split_tables  # [first kind | ring]
 from langstream_tpu.ops.paged_attention import (
     NEG_INF,
     merge_partial_attention,
@@ -283,13 +284,6 @@ def summarise(c: EvaConfig, phi: jax.Array, mu: jax.Array, k: jax.Array,
     k_sum = (jnp.sum(a * kf, axis=-3) if c.summary_key == "weighted"
              else jnp.mean(kf, axis=-3)) + mu
     return k_sum.astype(k.dtype), v_sum.astype(v.dtype)
-
-
-def split_tables(block_tables: jax.Array):
-    """``(the summary kind's columns, the ring's)`` of the one table the
-    programs are handed (:class:`langstream_tpu.models.paged.BlockManager`)."""
-    width = block_tables.shape[1] // 2
-    return block_tables[:, :width], block_tables[:, width:]
 
 
 # ---------------------------------------------------------------------------

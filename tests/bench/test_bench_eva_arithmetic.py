@@ -292,5 +292,6 @@ def test_the_file_holds_every_number_of_the_catalog_s_config(config):
     assert len(config["assumed"]) >= 6
     for key in ("rms_share", "mean_rms_share", "min_correlation",
                 "heads_rms_share", "ring_rows_rms_share",
-                "summary_rows_rms_share", "why"):
+                "summary_rows_rms_share", "logits_bfloat16_grid_share",
+                "why"):
         assert key in config["reference_tolerance"]

@@ -17,7 +17,7 @@ import types
 import pytest
 
 from langstream_tpu.models import family as family_mod
-from langstream_tpu.models import hybrid, latent, swa
+from langstream_tpu.models import eva, hybrid, latent, swa
 from langstream_tpu.models.family import Family
 from langstream_tpu.models.paged import PagedLayout, init_kv_pool, init_latent_pool
 from langstream_tpu.serving import engine as engine_mod
@@ -254,12 +254,15 @@ TABLE_BEFORE = {
     # PR 46: the first names to land in a family that was there
     "mellum-tiny": ("swa", "mellum_tiny"),
     "mellum2-12b-a2.5b-8l": ("swa", "mellum2_12b_a2_5b_8l"),
+    # PR 50: the first family added as a module and a line of ``MODULES``
+    "evabyte-tiny": ("eva", "tiny"),
+    "evabyte-6.5b-8l": ("eva", "evabyte_6_5b_8l"),
 }
 CONFIG_CLASS = {"hybrid": hybrid.HybridConfig, "latent": latent.LatentConfig,
-                "swa": swa.SwaConfig}
+                "swa": swa.SwaConfig, "eva": eva.EvaConfig}
 
 
-def test_the_three_modules_serve_the_table_s_names_and_no_other():
+def test_the_four_modules_serve_the_table_s_names_and_no_other():
     served = {name: (fam.name, method) for fam in family_mod.families()
               for name, method in fam.presets.items()}
     assert served == TABLE_BEFORE

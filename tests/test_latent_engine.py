@@ -210,7 +210,7 @@ def test_one_table_resolves_both_families_names():
     assert (latent.name, latent.presets["deepseek-tiny"]) == ("latent", "tiny")
     assert _family_of("deepseek-v2-ep8") is latent and \
         latent.presets["deepseek-v2-ep8"] == "deepseek_v2_ep8"
-    assert {f.name for f in families()} == {"hybrid", "latent", "swa"}
+    assert {f.name for f in families()} == {"hybrid", "latent", "swa", "eva"}
     assert _family_of("tiny") is None and _family_of("moe-tiny") is None
     with pytest.raises(ValueError) as e:
         _resolve_model_config("no-such-model", 128)

@@ -33,7 +33,8 @@ sys.path.insert(1, ROOT)
 REPORT_KEYS = (
     "passed", "worst_rms_share", "mean_rms_share", "worst_correlation",
     "heads_rms_share", "ring_rows_rms_share", "summary_rows_rms_share",
-    "engine_first_token_shortfall", "engine_first_logprob_error",
+    "logits_bfloat16_grid_share", "engine_first_token_shortfall",
+    "engine_first_logprob_error",
     "engine_decode_token_shortfall", "engine_decode_logprob_error",
     "engine_decode_steps_compared", "engine_decode_steps_parted",
     "engine_decode_steps_frozen_at_an_edge", "ring_rows_compared",
@@ -93,11 +94,11 @@ async def run(args) -> dict:
         out.setdefault("checks", []).append(row)
         memory(f"reference check, seed {seed}")
     if args.faults and got is not None:
-        for fault in reference.FAULTS:
+        for fault in args.only_faults or reference.FAULTS:
             t = time.monotonic()
             report = await asyncio.to_thread(
                 reference.judge, engine, got, tolerance, (fault,))
-            row = {k: report.get(k) for k in REPORT_KEYS[:7]}
+            row = {k: report.get(k) for k in REPORT_KEYS[:8]}
             print(f"[probe] fault {fault}: {json.dumps(row)} "
                   f"({time.monotonic() - t:.1f} s)", flush=True)
             out.setdefault("faults", {})[fault] = row
@@ -155,6 +156,8 @@ def main() -> int:
     ap.add_argument("--seeds", type=int, nargs="+", default=[2 ** 31 + 41])
     ap.add_argument("--faults", action="store_true",
                     help="also judge the program against each faulty reference")
+    ap.add_argument("--only-faults", nargs="+", default=None,
+                    help="with --faults: these of the reference's FAULTS alone")
     ap.add_argument("--checks-only", action="store_true")
     ap.add_argument("--no-checks", action="store_true")
     ap.add_argument("--no-warmup", action="store_true",
