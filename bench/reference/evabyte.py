@@ -66,8 +66,8 @@ UNOBSERVABLE = ("head_columns_byte_major", "release_token_ids")
 CHECK_PROMPTS = (1500, 2047, 2049, 4010, 28003)
 CHECK_DECODE_STEPS = 160
 QUERY_BLOCK = 512
-HEAD_GROUP = 8
-FFN_ROWS = 4096   # and the least length a sequence is padded to: a power of two
+HEAD_GROUP = 4
+FFN_ROWS = 1024   # rows of one pass of whatever is row by row: a power of two
 
 
 def _log(message: str) -> None:
@@ -155,9 +155,11 @@ def attend_block(q, k, v, k_sum, v_sum, first, lo, window, chunk, faults=()):
 
 @functools.lru_cache(maxsize=None)
 def _layer_functions(c, faults: tuple):
-    """The forward's three jitted pieces for one reading of the layer (one
+    """The forward's three pieces for one reading of the layer (jitted, one
     trace a padded length): a layer's attention, its gated MLP, and the first
-    layer's rows as a pool would hold them."""
+    layer's rows as a pool would hold them. The residual is updated in
+    place and nothing else of its size is made: the check runs beside a
+    resident engine (at the cell's 24 slots 1.4 GiB of the chip are free)."""
     window, chunk = _geometry(c, faults)
     lower = (below_bfloat16 if "weights_below_bfloat16" in faults
              else (lambda t: t))
@@ -167,65 +169,93 @@ def _layer_functions(c, faults: tuple):
                 if "bfloat16_residual" in faults else (lambda t: t))
     D = c.head_dim
 
-    def group(h, ap, h0):
-        """``(q, k, v, k~, v~)`` of ``HEAD_GROUP`` heads from ``h0``."""
-        T = h.shape[0]
-        cols = slice(h0 * D, (h0 + HEAD_GROUP) * D)
-        heads = slice(h0, h0 + HEAD_GROUP)
-        q, k, v = ((h @ lower(f32(ap[w][:, cols]))).reshape(T, -1, D)
-                   for w in ("wq", "wk", "wv"))
+    def pieces(rows):
+        """``rows (T, n)`` as ``(T // FFN_ROWS, FFN_ROWS, n)``."""
+        n = min(FFN_ROWS, rows.shape[0])
+        return rows.reshape(rows.shape[0] // n, n, -1)
+
+    def group(x, ap, h0):
+        """``(q, k, v, k~, v~)`` of ``HEAD_GROUP`` heads from ``h0``, the
+        norm and the projections ``FFN_ROWS`` rows at a time (the check
+        runs beside a resident engine: no second array of the residual's
+        size is made)."""
+        T = x.shape[0]
+        of = lambda a, axis, n=1: jax.lax.dynamic_slice_in_dim(  # noqa: E731
+            a, h0 * n, HEAD_GROUP * n, axis)
+        g = f32(ap["norm"])
+        ws = [lower(f32(of(ap[w], 1, D))) for w in ("wq", "wk", "wv")]
+        q, k, v = (a.reshape(T, -1, D) for a in jax.lax.map(
+            lambda xb: tuple(rms_norm(xb, g, c.norm_eps, faults) @ w
+                             for w in ws), pieces(x)))
         where = jnp.arange(T)
         q, k = rotate(q, where, c.rope_theta), rotate(k, where, c.rope_theta)
         k, v = rows(k), rows(v)
         k_sum, v_sum = summaries(
-            k, v, f32(ap["phi"][heads]), f32(ap["mu"][heads]), chunk, faults)
+            k, v, f32(of(ap["phi"], 0)), f32(of(ap["mu"], 0)), chunk, faults)
         return q, k, v, rows(k_sum), rows(v_sum)
 
-    def attention(x, ap):
+    def in_place(x, one, *others):
+        """``x`` with ``one(piece of x, pieces of others)`` written over it
+        a piece at a time (``x`` is donated)."""
+        n = min(FFN_ROWS, x.shape[0])
+        cut = lambda a, i: jax.lax.dynamic_slice_in_dim(a, i * n, n, 0)  # noqa: E731
+        return jax.lax.fori_loop(
+            0, x.shape[0] // n,
+            lambda i, x: jax.lax.dynamic_update_slice_in_dim(
+                x, one(cut(x, i), *(cut(a, i) for a in others)), i * n, 0), x)
+
+    def attend(x, ap, h0):
+        """``(T, HEAD_GROUP * D)``: what the heads from ``h0`` attend."""
         T = x.shape[0]
         reach = min(max(window, c.window) + QUERY_BLOCK, T)
-        h = rms_norm(x, f32(ap["norm"]), c.norm_eps, faults)
-        new = x
-        for h0 in range(0, c.heads, HEAD_GROUP):
-            q, k, v, k_sum, v_sum = group(h, ap, h0)
+        q, k, v, k_sum, v_sum = group(x, ap, h0)
 
-            def one(first, q=q, k=k, v=v, k_sum=k_sum, v_sum=v_sum):
-                # from the first key the block's first query can see, and
-                # never so far that the slice would run off the end
-                lo = jnp.clip(first - (reach - QUERY_BLOCK) + 1, 0, T - reach)
-                return attend_block(
-                    jax.lax.dynamic_slice_in_dim(q, first, QUERY_BLOCK, 0),
-                    jax.lax.dynamic_slice_in_dim(k, lo, reach, 0),
-                    jax.lax.dynamic_slice_in_dim(v, lo, reach, 0),
-                    k_sum, v_sum, first, lo, window, chunk, faults)
+        def one(first):
+            # from the first key the block's first query can see, and
+            # never so far that the slice would run off the end
+            lo = jnp.clip(first - (reach - QUERY_BLOCK) + 1, 0, T - reach)
+            return attend_block(
+                jax.lax.dynamic_slice_in_dim(q, first, QUERY_BLOCK, 0),
+                jax.lax.dynamic_slice_in_dim(k, lo, reach, 0),
+                jax.lax.dynamic_slice_in_dim(v, lo, reach, 0),
+                k_sum, v_sum, first, lo, window, chunk, faults)
 
-            o = jax.lax.map(one, jnp.arange(0, T, QUERY_BLOCK)).reshape(T, -1)
-            new = new + o @ lower(f32(ap["wo"][h0 * D:(h0 + HEAD_GROUP) * D]))
-        return residual(new)
+        return jax.lax.map(one, jnp.arange(0, T, QUERY_BLOCK)).reshape(T, -1)
 
-    def first_rows(x, ap):
-        h = rms_norm(x, f32(ap["norm"]), c.norm_eps, faults)
-        parts = [group(h, ap, h0)[1:] for h0 in range(0, c.heads, HEAD_GROUP)]
-        k, v, k_sum, v_sum = (
-            jnp.concatenate([p[i] for p in parts], axis=1).reshape(
-                parts[0][i].shape[0], -1) for i in range(4))
-        return (jnp.concatenate([k, v], axis=-1),
-                jnp.concatenate([k_sum, v_sum], axis=-1))
+    def add_out(x, ap, *outs):
+        """``x + [outs] W_o`` over ``x``: a head group's rows of ``W_o`` on
+        its own output."""
+        wo = lower(f32(ap["wo"])).reshape(len(outs), -1, x.shape[1])
+        return in_place(
+            x, lambda xb, *obs: residual(
+                xb + sum(ob @ w for ob, w in zip(obs, wo))), *outs)
+
+    def first_rows(x, ap, h0):
+        """The rows of ``HEAD_GROUP`` heads from ``h0``: ``((T, 2, heads,
+        D), (T // chunk, 2, heads, D))``, K before V."""
+        _, k, v, k_sum, v_sum = group(x, ap, h0)
+        return jnp.stack([k, v], axis=1), jnp.stack([k_sum, v_sum], axis=1)
 
     def ffn(x, fp):
-        T = x.shape[0]
         up, down = lower(f32(fp["w_up"])), lower(f32(fp["w_down"]))
         g = f32(fp["norm"])
 
         def one(xb):
             u = rms_norm(xb, g, c.norm_eps, faults) @ up
             half = u.shape[1] // 2
-            return xb + (jax.nn.silu(u[:, :half]) * u[:, half:]) @ down
+            return residual(
+                xb + (jax.nn.silu(u[:, :half]) * u[:, half:]) @ down)
 
-        n = min(FFN_ROWS, T)
-        return residual(jax.lax.map(one, x.reshape(T // n, n, -1)).reshape(T, -1))
+        return in_place(x, one)
 
-    return jax.jit(attention), jax.jit(ffn), jax.jit(first_rows)
+    def attention(x, ap):
+        # a head group a program: what one leaves behind is its output alone
+        return add_out(x, ap, *(attend(x, ap, h0)
+                                for h0 in range(0, c.heads, HEAD_GROUP)))
+
+    attend, add_out, ffn = jax.jit(attend), jax.jit(
+        add_out, donate_argnums=0), jax.jit(ffn, donate_argnums=0)
+    return attention, ffn, jax.jit(first_rows)
 
 
 def forward(config, params, tokens, positions, faults=()):
@@ -245,8 +275,13 @@ def forward(config, params, tokens, positions, faults=()):
     _, chunk = _geometry(c, faults)
     with jax.default_matmul_precision("highest"):
         x = f32(params["embed"][jnp.asarray(padded)])
-        rows, summary = first_rows(x, params["layers"][0]["attn"])
-        rows, summary = np.asarray(rows)[:T], np.asarray(summary)[: T // chunk]
+        groups = [tuple(map(np.asarray, first_rows(
+            x, params["layers"][0]["attn"], h0)))
+            for h0 in range(0, c.heads, HEAD_GROUP)]
+        rows, summary = (
+            np.concatenate([g[i] for g in groups], axis=2) for i in (0, 1))
+        rows = rows.reshape(rows.shape[0], -1)[:T]
+        summary = summary.reshape(summary.shape[0], -1)[: T // chunk]
         for lp in params["layers"]:
             x = attention(x, lp["attn"])
             x = ffn(x, lp["ffn"])
@@ -281,13 +316,17 @@ def served(engine, seed: int, *, prompts=CHECK_PROMPTS,
       every slot by the engine's own greedy prefill program, whose token and
       log-probability are held to those logits;
     - then ``steps`` decode steps in the engine's chunks, all live slots in
-      one batch: first the engine's own decode program, in which the slots
-      whose window closes inside this chunk are FROZEN lanes (both programs
-      commit as they go, and the ring's rows of the old window are gone once
-      either has crossed the edge: the second to run would read the first's
-      new window in their place), then the model's function over every live
-      slot with each step's logits out; the engine's tokens and
-      log-probabilities are held to them step by step while the tokens agree."""
+      one batch: first the engine's own decode program over EVERY live slot,
+      those whose window closes inside this chunk among them, then the
+      model's function over the same steps with each step's logits out. Both
+      programs commit as they go, and a slot that has crossed an edge has
+      written its new window over the old one's first rows, so the ring
+      blocks a chunk's rows fall in (two a slot where a chunk is no longer
+      than a block) are copied before the engine's program and put back
+      after it: the model's function starts from the rows the engine's
+      did. The engine's tokens and log-probabilities are held to the
+      model's logits step by step while the tokens agree, across an edge
+      as anywhere else."""
     cfg, manager = engine.config, engine.block_mgr
     if not all(slot.free for slot in engine.slots):
         raise RuntimeError("the engine is serving: the check writes its pools")
@@ -391,19 +430,48 @@ def _served(engine, seed, prompts, plan, tables, steps) -> dict:
         return (flat[: k * slots].reshape(k, slots),
                 flat[k * slots : 2 * k * slots].view(np.float32).reshape(k, slots))
 
+    live_slots = np.flatnonzero(live)
+
+    def touched(at, k):
+        """The ring's blocks that each live slot's rows ``at .. at + k - 1``
+        fall in, by the table's columns from the first row's on."""
+        cols = at[live_slots, None] // bs + np.arange((k + bs - 2) // bs + 1)
+        return jnp.asarray(tables[
+            live_slots[:, None], width + np.minimum(cols, width - 1)].ravel())
+
+    # a block at a time, as slices: a gather along the pool's second axis
+    # copies the whole pool first (3.2 GB at the cell's 24 slots)
+    L, HD = c.layers, c.heads * c.head_dim
+
+    @jax.jit
+    def copy_blocks(ring, blocks):
+        """``{"k", "v"}: (n, layers, 1, block, lanes)``."""
+        return {a: jax.lax.map(lambda b: jax.lax.dynamic_slice(
+            ring[a], (0, b, 0, 0), (L, 1, bs, HD)), blocks) for a in "kv"}
+
+    @functools.partial(jax.jit, donate_argnums=0)
+    def put_back(ring, blocks, held):
+        return jax.lax.fori_loop(0, blocks.shape[0], lambda i, ring: {
+            a: jax.lax.dynamic_update_slice(
+                ring[a], held[a][i], (0, blocks[i], 0, 0)) for a in "kv"},
+            ring)
+
     chunk = max(1, min(int(cfg.decode_chunk), steps))
     t0, n = jnp.asarray(first), jnp.asarray(lengths)
     made, chunk_heads = [], []
     decode_shortfall = decode_error = 0.0
-    compared = parted = frozen = crossings = 0
+    compared = parted = across = crossings = 0
     at = lengths.copy()
     for k in [chunk] * (steps // chunk) + [steps % chunk] * bool(steps % chunk):
         # the slots whose rows at + 0 .. at + k - 1 reach into a new window
         crosses = live & ((at + k - 1) // W > np.maximum(at - 1, 0) // W)
         crossings += int(crosses.sum())
+        blocks = touched(at, k)
+        held = copy_blocks(engine.state, blocks)
         theirs, their_logprobs = _on_engine(
             engine, f"decode program of {k} steps", decode_as_the_engine,
-            t0, n, live & ~crosses, k)
+            t0, n, live, k)
+        engine.state = put_back(engine.state, blocks, held)
         out = model_decode(engine.params, t0, n, engine.cache_k,
                            engine.cache_v, engine.state, k)
         t0, n, engine.cache_k, engine.cache_v, engine.state = out[2:7]
@@ -411,14 +479,14 @@ def _served(engine, seed, prompts, plan, tables, steps) -> dict:
         heads = np.asarray(out[7], np.float64)               # (k, slots, P V)
         agreed = np.cumprod(np.concatenate(
             [np.ones((1, slots), bool), theirs == ours])[:-1], axis=0) > 0
-        agreed &= (live & ~crosses)[None]
+        agreed &= live[None]
         shortfall, error = _held_to_logits(
             theirs, their_logprobs, heads[..., :V], agreed)
         decode_shortfall = max(decode_shortfall, shortfall)
         decode_error = max(decode_error, error)
         compared += int(agreed.sum())
         parted += int((agreed & (theirs != ours)).sum())
-        frozen += int(crosses.sum()) * k
+        across += int(agreed[:, crosses].sum())
         made.append(ours[:, followed])
         chunk_heads.append(heads[:, followed, :V].astype(np.float32))
         at = at + k * live
@@ -463,7 +531,7 @@ def _served(engine, seed, prompts, plan, tables, steps) -> dict:
             "engine_decode_logprob_error": decode_error,
             "engine_decode_steps_compared": compared,
             "engine_decode_steps_parted": parted,
-            "engine_decode_steps_frozen_at_an_edge": frozen,
+            "engine_decode_steps_across_an_edge": across,
         },
         "facts": {
             "prompts": [int(p) for p in prompts],

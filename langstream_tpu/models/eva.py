@@ -25,10 +25,10 @@ the residual ``x`` is float32, ``fp32_skip_add``):
    served is prediction head 0's** (the next byte); heads 1.. (the release's
    multi-byte self-speculation) are computed and handed to whoever asks.
 
-What the published config has no key for is a field of :class:`EvaConfig`
-where the program can take the other reading (``summary_key``,
-``chunk_logit_scale``, ``chunk_logit_norm``); ``bench/configs/
-evabyte-6.5b-8l.json`` lists all of them under ``assumed``.
+What the published config has no key for is written here as the reading
+taken, with no field for the other; ``bench/configs/evabyte-6.5b-8l.json``
+lists each under ``assumed`` beside the reference's fault that is the other
+reading.
 
 **Two pools** (:func:`langstream_tpu.models.paged.init_kv_pool`), both of
 rows ``heads * head_dim`` wide over all layers:
@@ -96,6 +96,9 @@ FLASH_BLOCK = 1024
 #: rows of one pass of the gated MLP in a prefill: ``[gate | up]`` of a
 #: 32,768-row prompt at width 11,008 is 1.4 GB in bfloat16 whole
 FFN_ROWS = 4096
+#: rows of one pass of a prefill's summaries: the float32 copies of the
+#: keys and values of a 32,768-row prompt are 0.54 GB each whole
+SUMMARY_ROWS = 4096
 
 
 @dataclasses.dataclass(frozen=True)
@@ -116,20 +119,14 @@ class EvaConfig:
     window: int = 2048               # query i sees the rows [W (i // W), i]
     chunk: int = 16                  # positions a summary row stands for
     pred_heads: int = 8              # the head's predictions: bytes t+1 ..
-    # what the published config has no key for (the other reading of each)
-    summary_key: str = "mean"        # or "weighted": the a_j-weighted sum
-    chunk_logit_scale: float = 1.0   # on phi . k_j (other: 1 / sqrt(d))
-    chunk_logit_norm: bool = False   # - |k_j|^2 / 2 in the chunk's softmax
     #: recurrent state beside the pools: none
     state_bytes_per_slot: int = 0
 
     def __post_init__(self):
         if self.kv_heads != self.heads:
             raise ValueError("one key-value head a query head")
-        if self.window % self.chunk or self.summary_key not in (
-                "mean", "weighted"):
-            raise ValueError("a window is whole chunks; summary_key is "
-                             "'mean' or 'weighted'")
+        if self.window % self.chunk:
+            raise ValueError("a window is whole chunks")
 
     @classmethod
     def evabyte_6_5b_8l(cls, max_seq_len: int = 32768) -> "EvaConfig":
@@ -269,20 +266,17 @@ def _logits(c: EvaConfig, params: dict, x: jax.Array) -> jax.Array:
     return jnp.dot(h, params["lm_head"], preferred_element_type=jnp.float32)
 
 
-def summarise(c: EvaConfig, phi: jax.Array, mu: jax.Array, k: jax.Array,
-              v: jax.Array):
-    """``(k~, v~) (..., heads, D)`` of chunks ``k, v (..., C, heads, D)``;
-    ``phi`` and ``mu`` broadcast against ``(..., heads, D)``. The softmax
-    over a chunk's ``C`` keys and both sums in float32."""
+def summarise(phi: jax.Array, mu: jax.Array, k: jax.Array, v: jax.Array):
+    """``(k~, v~) (..., heads, D)`` of chunks ``k, v (..., C, heads, D)``:
+    ``v~`` the ``softmax_j(phi . k_j)``-weighted values, ``k~`` the mean key
+    plus ``mu``; ``phi`` and ``mu`` broadcast against ``(..., heads, D)``.
+    The softmax over a chunk's ``C`` keys and both sums in float32."""
     kf, vf = k.astype(jnp.float32), v.astype(jnp.float32)
     phi, mu = phi.astype(jnp.float32), mu.astype(jnp.float32)
-    s = jnp.sum(kf * phi[..., None, :, :], axis=-1) * c.chunk_logit_scale
-    if c.chunk_logit_norm:
-        s = s - 0.5 * jnp.sum(kf * kf, axis=-1)
+    s = jnp.sum(kf * phi[..., None, :, :], axis=-1)
     a = jax.nn.softmax(s, axis=-2)[..., None]              # (..., C, heads, 1)
     v_sum = jnp.sum(a * vf, axis=-3)
-    k_sum = (jnp.sum(a * kf, axis=-3) if c.summary_key == "weighted"
-             else jnp.mean(kf, axis=-3)) + mu
+    k_sum = jnp.mean(kf, axis=-3) + mu
     return k_sum.astype(k.dtype), v_sum.astype(v.dtype)
 
 
@@ -328,6 +322,17 @@ def eva_prefill_paged(
     last_rows = jax.vmap(lambda a, f: jax.lax.dynamic_slice_in_dim(
         a, f, Wl, axis=0))
 
+    def summaries(ap, k, v):
+        """``(k~, v~) (B, P / C, heads, D)`` of every chunk of the bucket,
+        ``SUMMARY_ROWS`` rows at a time."""
+        passes = Pn // SUMMARY_ROWS if Pn % SUMMARY_ROWS == 0 else 1
+        got = jax.lax.map(
+            lambda kv: summarise(ap["phi"], ap["mu"], *kv),
+            tuple(jnp.moveaxis(a.reshape(B, passes, -1, C, c.heads, D), 1, 0)
+                  for a in (k, v)))
+        return tuple(jnp.moveaxis(s, 0, 1).reshape(B, Pn // C, c.heads, D)
+                     for s in got)
+
     def attend(q, k, v, k_sum, v_sum):
         if flash is not None:
             from langstream_tpu.ops.eva_flash import eva_flash
@@ -360,10 +365,7 @@ def eva_prefill_paged(
         ap = lp["attn"]
         q, k, v = _projections(c, ap, x, cos, sin)
         with jax.named_scope("eva_summarise_prefill"):
-            k_sum, v_sum = summarise(
-                c, ap["phi"], ap["mu"],
-                k.reshape(B, Pn // C, C, c.heads, D),
-                v.reshape(B, Pn // C, C, c.heads, D))
+            k_sum, v_sum = summaries(ap, k, v)
         with jax.named_scope("eva_flash"):
             out = attend(q, k, v, k_sum, v_sum)
         x = _attention_out(ap, x, out.reshape(B, Pn, HD))
@@ -508,7 +510,7 @@ def eva_decode_chunk_paged(
                 ring_tables, (i // bs)[:, None], axis=1)[:, 0]
             offset = (i % bs) // C * C
             k_sum, v_sum = summarise(
-                c, phi, mu,
+                phi, mu,
                 chunk_of(ring_k, block, offset).reshape(L, B, C, c.heads, D),
                 chunk_of(ring_v, block, offset).reshape(L, B, C, c.heads, D))
             pool_k, pool_v = write_rows_pair(

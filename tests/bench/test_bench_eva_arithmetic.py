@@ -67,26 +67,27 @@ def test_the_pools_are_the_file_s(shape, config):
     ring = (slots * 32 + 1) * block
     summary = serving["kv-pool-blocks"] * block
     assert block == 8388608
-    # what the file's memory line states: ring 5.38 GB, summaries 3.36 GB
-    assert 5.37e9 < ring < 5.39e9 and 3.36e9 < summary < 3.37e9
+    # what the file's memory line states: ring 6.45 GB, summaries 4.03 GB
+    assert slots == 24 and serving["kv-pool-blocks"] == 481
+    assert 6.44e9 < ring < 6.46e9 and 4.03e9 < summary < 4.04e9
     resident = 2 * (shape.step_params + 320 * 4096) + ring + summary
-    assert 0.65 < resident / 16.909e9 < 0.75
+    assert 0.80 < resident / 16.909e9 < 0.82
 
 
 def test_the_floors(shape):
-    # 20 slots at 14k positions: 1,024 exact and 768 summary rows each
+    # 24 slots at 14k positions: 1,024 exact and 768 summary rows each
     read = roofline_eva.read_floor(
-        shape, window_rows=20 * 1024, summary_rows=20 * 768, peaks=PEAKS)
-    assert read["bytes"] == 8 * 20 * 1792 * 16384 and read["bound_by"] == "bytes"
+        shape, window_rows=24 * 1024, summary_rows=24 * 768, peaks=PEAKS)
+    assert read["bytes"] == 8 * 24 * 1792 * 16384 and read["bound_by"] == "bytes"
     step = roofline_eva.decode_floor(
-        shape, window_rows=20 * 1024, summary_rows=20 * 768, batch=20,
+        shape, window_rows=24 * 1024, summary_rows=24 * 768, batch=24,
         peaks=PEAKS)
     assert step["bound_by"] == "bytes"
     assert step["bytes"] == pytest.approx(
         2 * shape.step_params + read["bytes"]
-        + 8 * 20 * 16384 * (1 + 17 / 16))
+        + 8 * 24 * 16384 * (1 + 17 / 16))
     # the two-pool read is three fifths of the step's bytes there
-    assert 0.55 < read["bytes"] / step["bytes"] < 0.62
+    assert 0.58 < read["bytes"] / step["bytes"] < 0.65
     # a prompt of two windows and a half: each window causal, the second and
     # the half against the summaries before them
     exact, summary = roofline_eva.attended_pairs(shape, 2 * 2048 + 1000)
@@ -141,13 +142,13 @@ def obs():
         "evaprefills": [
             {"prompt_tokens": 12000, "seconds": 0.42, "flash_s": 0.04},
             {"prompt_tokens": 5000, "seconds": 0.21, "flash_s": 0.015}],
-        "serving": {"model": MODEL, "slots": 20, "kv-block-size": 64},
+        "serving": {"model": MODEL, "slots": 24, "kv-block-size": 64},
         "peaks": PEAKS,
         "samples": [
-            {"phase": "decode", "steps": 32, "active_at_dispatch": 20,
-             "live_rows": 20 * 14000, "window_rows": 20 * 1000,
-             "summary_rows": 20 * 768, "pool_rows_held": 20 * 8 * 64 * 46,
-             "pool_rows_plain_cache": 20 * 8 * 64 * 219, "chunk_closes": 1,
+            {"phase": "decode", "steps": 32, "active_at_dispatch": 24,
+             "live_rows": 24 * 14000, "window_rows": 24 * 1000,
+             "summary_rows": 24 * 768, "pool_rows_held": 24 * 8 * 64 * 46,
+             "pool_rows_plain_cache": 24 * 8 * 64 * 219, "chunk_closes": 1,
              "window_closes": 0},
             {"phase": "decode", "steps": 16, "active_at_dispatch": 18,
              "live_rows": 18 * 9000, "window_rows": 18 * 800,
@@ -163,9 +164,9 @@ def test_steps_are_the_read_kernel_s_calls_over_two_reads_a_layer(obs):
     seconds, steps = roofline_eva.traced_steps(obs)
     assert steps == 8 and seconds == pytest.approx(120e-3)
     load = roofline_eva.per_step(obs)
-    assert load["slots"] == pytest.approx((20 * 32 + 18 * 16) / 48)
+    assert load["slots"] == pytest.approx((24 * 32 + 18 * 16) / 48)
     assert load["window_rows"] == pytest.approx(
-        (20 * 1000 * 32 + 18 * 800 * 16) / 48)
+        (24 * 1000 * 32 + 18 * 800 * 16) / 48)
 
 
 def test_each_reader_reads_the_reduction(obs, shape):
@@ -189,12 +190,12 @@ def test_each_reader_reads_the_reduction(obs, shape):
     for name in ("eva_read_roofline", "eva_decode_roofline", "eva_flash_mfu",
                  "eva_prefill_mfu"):
         assert 0 < reader(name)(obs) < 100, name
-    held = 20 * 46 * 32 + 18 * 42 * 16
-    plain = 20 * 219 * 32 + 18 * 141 * 16
+    held = 24 * 46 * 32 + 18 * 42 * 16
+    plain = 24 * 219 * 32 + 18 * 141 * 16
     assert reader("eva_pool_rows_saved_share")(obs) == pytest.approx(
         100 * (1 - held / plain))
-    summary = 20 * 768 * 32 + 18 * 512 * 16
-    exact = 20 * 1000 * 32 + 18 * 800 * 16
+    summary = 24 * 768 * 32 + 18 * 512 * 16
+    exact = 24 * 1000 * 32 + 18 * 800 * 16
     assert reader("eva_summary_rows_share")(obs) == pytest.approx(
         100 * summary / (summary + exact))
 
@@ -204,7 +205,7 @@ def test_a_program_without_the_scopes_or_counters_gives_nothing(name):
     """A parent commit cannot serve the configuration at all; a run that was
     not traced, a program that names no scope and carries no gauge, and a
     run of another family all give nothing and do not raise."""
-    bare = {"serving": {"model": MODEL, "slots": 20}, "peaks": PEAKS,
+    bare = {"serving": {"model": MODEL, "slots": 24}, "peaks": PEAKS,
             "trace": None,
             "samples": [{"phase": "decode", "steps": 8,
                          "active_at_dispatch": 4}],
@@ -261,7 +262,7 @@ def test_the_cell_and_its_traffic_are_the_issue_s(config):
                         max_seq_len=32768, output_lengths=config["output_lengths"])
     prompts = sorted(r["prompt_tokens"] for r in plan["requests"][:48])
     outs = sorted(r["output_tokens"] for r in plan["requests"][:48])
-    assert plan["clients"] == 30 and len(plan["requests"]) >= 48
+    assert plan["clients"] == 36 and len(plan["requests"]) >= 48
     assert prompts[0] >= 4096 and prompts[-1] <= 28672
     assert [outs.count(n) for n in (512, 1024, 2048)] == [10, 14, 24]
     # every prompt past two windows; the buckets 8,192 / 16,384 / 32,768

@@ -414,6 +414,47 @@ def test_mosaic_builds_flash_with_keys_of_192_and_values_of_128(
     assert "flash_prefill" in compiled.as_text()
 
 
+def test_the_largest_eva_prefill_fits_beside_the_cell_s_resident_pools(
+        one_chip, no_compile_cache):
+    """``evabyte-6.5b-8l`` as its cell serves it (``bench/configs``: 24
+    slots' rings and 481 summary blocks, 13.75 GB resident with the
+    weights): the 32,768-row bucket's prefill compiles inside the 15.75 GB
+    a program may use. With the summaries' float32 copies made over the
+    whole prompt, or ``eva_flash``'s rows transposed around it, it is 0.15
+    GB over (PR 50's first form served 20 slots for that)."""
+    import json
+    import pathlib
+
+    from langstream_tpu.models import eva
+    from langstream_tpu.models.paged import PagedLayout, init_kv_pool
+
+    with open(pathlib.Path(__file__).parents[1] / "bench" / "configs"
+              / "evabyte-6.5b-8l.json") as f:
+        serving = json.load(f)["serving"]
+    c = eva.EvaConfig.evabyte_6_5b_8l(serving["max-seq-len"])
+    bs, rows = serving["kv-block-size"], serving["max-seq-len"]
+    layout = PagedLayout(block_size=bs, num_blocks=serving["kv-pool-blocks"],
+                         max_blocks_per_slot=rows // bs)
+    ring = eva._two_kinds(c, layout, serving["slots"])["window_layout"]
+    on = lambda tree: jax.tree.map(lambda a: jax.ShapeDtypeStruct(  # noqa: E731
+        a.shape, a.dtype, sharding=one_chip), tree)
+    params = on(jax.eval_shape(lambda: eva.init_eva_params(c)))
+    pool_k, pool_v = on(jax.eval_shape(lambda: init_kv_pool(c, layout, c.layers)))
+    wpool = dict(zip("kv", on(jax.eval_shape(
+        lambda: init_kv_pool(c, ring, c.layers)))))
+    ints = lambda *shape: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, jnp.int32, sharding=one_chip)
+    compiled = jax.jit(
+        lambda p, t, n, pk, pv, wp, tb: eva.eva_prefill_paged(
+            c, p, t, n, pk, pv, wp, tb, use_flash=True, kernel="pallas")[:4],
+        donate_argnums=(3, 4, 5),
+    ).lower(params, ints(1, rows), ints(1), pool_k, pool_v, wpool,
+            ints(1, 2 * rows // bs)).compile()
+    held = compiled.memory_analysis().argument_size_in_bytes
+    assert 13.7e9 < held < 13.8e9
+    assert "eva_flash" in compiled.as_text()
+
+
 def test_the_selfcheck_s_latent_rows_hold_both_kernels(monkeypatch):
     from langstream_tpu.models.latent import LatentConfig
     from langstream_tpu.ops import paged_attention, selfcheck

@@ -3,7 +3,7 @@ the chip, without the benchmark's harness around it (``tools/swa_probe.py``'s
 twin for ``models/eva.py``): builds the engine from the configuration's
 ``serving`` block, says what the device holds against the file's arithmetic,
 runs the reference check (``bench/reference/evabyte.py``) over ``--seeds``,
-with ``--faults`` judges the last seed's served output against each faulty
+with ``--faults`` judges each seed's served output against each faulty
 reference (each has to come out as not passed), then (unless
 ``--checks-only``) one prefill of every bucket the cell's prompts reach and a
 full batch of decodes at long contexts, with the allocator's peak after each
@@ -37,7 +37,7 @@ REPORT_KEYS = (
     "engine_first_logprob_error",
     "engine_decode_token_shortfall", "engine_decode_logprob_error",
     "engine_decode_steps_compared", "engine_decode_steps_parted",
-    "engine_decode_steps_frozen_at_an_edge", "ring_rows_compared",
+    "engine_decode_steps_across_an_edge", "ring_rows_compared",
     "summary_rows_compared", "slots_live", "rows_live", "window_edges_crossed",
     "chunk_closes")
 
@@ -80,7 +80,6 @@ async def run(args) -> dict:
     how = {k: v for k, v in (
         ("prompts", tolerance.get("check_prompts")),
         ("steps", tolerance.get("check_decode_steps"))) if v}
-    got = None
     for seed in [] if args.no_checks else args.seeds:
         t = time.monotonic()
         got = await asyncio.to_thread(
@@ -93,15 +92,14 @@ async def run(args) -> dict:
               flush=True)
         out.setdefault("checks", []).append(row)
         memory(f"reference check, seed {seed}")
-    if args.faults and got is not None:
-        for fault in args.only_faults or reference.FAULTS:
+        for fault in (args.only_faults or reference.FAULTS) * args.faults:
             t = time.monotonic()
             report = await asyncio.to_thread(
                 reference.judge, engine, got, tolerance, (fault,))
             row = {k: report.get(k) for k in REPORT_KEYS[:8]}
-            print(f"[probe] fault {fault}: {json.dumps(row)} "
+            print(f"[probe] seed {seed}, fault {fault}: {json.dumps(row)} "
                   f"({time.monotonic() - t:.1f} s)", flush=True)
-            out.setdefault("faults", {})[fault] = row
+            out.setdefault("faults", {}).setdefault(str(seed), {})[fault] = row
     if args.checks_only:
         await engine.close()
         return out
