@@ -770,6 +770,39 @@ def _bucket(n: int, lo: int = 32, hi: int = 32768) -> int:
     return min(b, hi)  # hi may not be a power of two (user max_seq_len)
 
 
+# The rows past which a prefill program also exists at the midpoint under
+# every power of two (6,144; 12,288; 24,576). A bucket has to earn its place:
+# a program costs 2-3 s to load at every set-up (ROADMAP S5). Past 4,096 rows
+# a prefill is one prompt a program and its time is flat a row, so a padded
+# row costs what a true one does and the midpoints take a sixth of the rows
+# off; up to 4,096 the cells batch their prefills 8 rows wide, and an engine
+# with ``max-seq-len`` 2,048 keeps exactly the programs it has.
+_PREFILL_MIDPOINTS_ABOVE = 4096
+
+
+def _prefill_bucket_rows(n: int, hi: int) -> int:
+    """The rows of the prefill program that takes ``n`` rows: the smallest
+    of the powers of two and, above ``_PREFILL_MIDPOINTS_ABOVE``, the
+    midpoints under them that holds ``n``, capped at ``hi`` as
+    :func:`_bucket` caps (which it equals up to that threshold)."""
+    b = max(32, _pow2(n))
+    mid = b // 4 * 3
+    if n <= mid and mid > _PREFILL_MIDPOINTS_ABOVE:
+        b = mid
+    return min(b, hi)
+
+
+def _prefill_midpoints(hi: int) -> list[int]:
+    """The buckets of :func:`_prefill_bucket_rows` up to ``hi`` that are no
+    power of two: the programs the rule adds to what a caller who warms by
+    power-of-two range reaches."""
+    out, mid = [], _PREFILL_MIDPOINTS_ABOVE // 2 * 3
+    while mid <= hi:
+        out.append(mid)
+        mid *= 2
+    return out
+
+
 def _dev_cache_cap() -> int:
     try:
         return max(1, int(os.environ.get("LS_TPU_DEV_CACHE_CAP", "32")))
@@ -1836,6 +1869,7 @@ class TpuServingEngine:
         # prefill programs dispatched, and those of them whose rows took the
         # grouped pass
         self._prefill_dispatches = 0
+        self._prefill_dispatches_midpoint = 0
         self._prefill_dispatches_grouped = 0
         # continuation prefill / speculative verify read history
         # through the multi-query kernel, which has no int8 twin:
@@ -2503,6 +2537,7 @@ class TpuServingEngine:
         state_bytes: int | None = None,
         ahead: int | None = None,
         prompt_tokens: int | None = None,
+        bucket: int | None = None,
         clock: dict | None = None,
         pool_rows: dict | None = None,
     ) -> None:
@@ -2520,7 +2555,8 @@ class TpuServingEngine:
         landed (:meth:`_await_chunk`). ``ahead`` (a prefill batch) is 1 when
         it was dispatched with its predecessor unfetched (:meth:`_admit`),
         ``prompt_tokens`` the true tokens its rows prefilled (without the
-        padding to the bucket and without an adopted prefix).
+        padding to the bucket and without an adopted prefix), ``bucket`` the
+        rows its program ran for each of them (:meth:`_prefill_bucket`).
         ``overlapped_s`` is host work the pipelined loop ran
         under an in-flight dispatch's device shadow (see flight.py).
         ``program`` keys the sample by the compiled variant that ran and
@@ -2559,6 +2595,7 @@ class TpuServingEngine:
             state_bytes=state_bytes,
             ahead=ahead,
             prompt_tokens=prompt_tokens,
+            bucket=bucket,
             clock=clock,
             pool_rows=pool_rows,
         )
@@ -3285,9 +3322,15 @@ class TpuServingEngine:
         (heavy-regime burst, power-of-two padded prefill rows,
         prefix-cache continuation when enabled). Greedy only — non-greedy
         sampler variants compile on first use; greedy is what the
-        latency-sensitive paths serve. Prompts in other prefill-length
-        buckets still pay one compile on first sight. Warmup tokens count
-        toward engine metrics (they ran on the chips)."""
+        latency-sensitive paths serve. Then one greedy prefill of one row
+        at every bucket of :func:`_prefill_bucket_rows` that is no power of
+        two (:meth:`_warmup_midpoints`; none with ``max-seq-len`` up to 4,096):
+        the programs the engine's rule adds are the engine's to load, since
+        a caller who warms by the lengths it will send, the longest of each
+        power-of-two range, does not reach them. Prompts in other
+        prefill-length buckets, and other row counts at every bucket, still
+        pay one compile on first sight. Warmup tokens count toward engine
+        metrics (they ran on the chips)."""
         text = "engine warmup probe text. " * 4
         k = max(self.config.decode_chunk, self.config.decode_chunk_light) + 1
         opts = {"max-tokens": k, "temperature": 0}
@@ -3303,12 +3346,38 @@ class TpuServingEngine:
                 for _ in range(wave)
             )
         )
+        midpoints = self._warmup_midpoints()
+        ids = self.tokenizer.encode(text)
+        for i, rows in enumerate(midpoints.values()):
+            # in turn: two of them at once are two programs' scratch; each
+            # starts at another token, so that none continues a prefix
+            await self.generate(
+                [ids[(i + j) % len(ids)] for j in range(rows)],
+                {"max-tokens": 1, "temperature": 0}, _warmup_probe=True,
+            )
         result = {
             "decode_variants": len(self._decode_chunk_fns),
             "prefill_variants": len(self._prefill_fns),
         }
-        self.flight.event("warmup", stage="end", **result)
+        self.flight.event(
+            "warmup", stage="end", **result, prefill_midpoints=list(midpoints)
+        )
         return result
+
+    def _warmup_midpoints(self) -> dict[int, int]:
+        """``{bucket: prompt rows}`` of the warm-up's midpoint prefills: the
+        buckets of :func:`_prefill_bucket_rows` that are no power of two, each
+        with the shortest prompt it takes (flash follows the true length),
+        where this engine prefills such a prompt whole and its pool can hold
+        it. None with ``max-seq-len`` up to 4,096."""
+        out = {}
+        for bucket in _prefill_midpoints(self.model_config.max_seq_len):
+            rows = bucket // 3 * 2 + 1  # one past the power of two under it
+            if 0 < self.config.prefill_chunk < rows:
+                continue  # prefilled in chunks: another program
+            if self.block_mgr.fits_ever(rows + 2):
+                out[bucket] = rows
+        return out
 
     def stats(self) -> dict[str, Any]:
         out = {
@@ -3366,6 +3435,11 @@ class TpuServingEngine:
             "prefill_dispatches_grouped": (
                 None if self.moe_grouped_kernel is None
                 else self._prefill_dispatches_grouped),
+            # those whose bucket is a midpoint (_prefill_bucket_rows), and
+            # the rows the prefill batches' programs ran over their prompts'
+            # true tokens
+            "prefill_dispatches_midpoint": self._prefill_dispatches_midpoint,
+            "prefill_padded_rows_share": self.flight.prefill_padded_rows_share,
             # watchdog verdict + warmup/readiness posture (serving/health.py)
             "health": self.health(),
             # drain-before-terminate posture + last drain's counts
@@ -6727,6 +6801,8 @@ class TpuServingEngine:
             )
             key = self._split_key()
             self._prefill_dispatches += 1
+            self._prefill_dispatches_midpoint += tokens.shape[1] in (
+                _prefill_midpoints(self.model_config.max_seq_len))
             self._prefill_dispatches_grouped += (
                 self._grouped_rows_over is not None
                 and tokens.size > self._grouped_rows_over)
@@ -7087,7 +7163,7 @@ class TpuServingEngine:
         ]
 
     def _prefill_bucket(self, request, reuse: int) -> int:
-        return _bucket(
+        return _prefill_bucket_rows(
             len(request.context_tokens) - reuse,
             hi=self.model_config.max_seq_len,
         )
@@ -7383,6 +7459,7 @@ class TpuServingEngine:
             sum(1 for s in self.slots if not s.free and not s.prefilling)
             - len(batch),
         )
+        ticket["bucket"] = bucket
         out = await self._dispatch_prefill(
             loop, ticket, prefill_mode, padded, lengths, sel_np, sel,
             temps, topks, topps, ad_np,

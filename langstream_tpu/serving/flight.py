@@ -305,6 +305,10 @@ class FlightRecorder:
         self.spec_rejected = 0
         self.prefill_ahead = 0
         self.prefill_rows = 0
+        # the rows the prefill batches' programs ran (bucket x requests)
+        # and the true tokens of their prompts
+        self.prefill_bucket_rows = 0
+        self.prefill_prompt_tokens = 0
         # cumulative twins of the samples' gap_ms / program_ms /
         # resume_lag_ms (the dispatch thread's clock, DispatchClock)
         self.gap_ms = 0.0
@@ -349,6 +353,7 @@ class FlightRecorder:
         state_bytes: int | None = None,
         ahead: int | None = None,
         prompt_tokens: int | None = None,
+        bucket: int | None = None,
         clock: dict | None = None,
         pool_rows: dict | None = None,
     ) -> dict[str, Any]:
@@ -380,7 +385,8 @@ class FlightRecorder:
         dispatched while its predecessor's first tokens were unfetched, so
         its ``device_s`` is what was left of the program when the host came
         to wait for it, not the program's run time. ``prompt_tokens`` (a
-        prefill batch only) are the true tokens its rows prefilled.
+        prefill batch only) are the true tokens its rows prefilled,
+        ``bucket`` (beside them) the rows its program ran for each request.
         ``pool_rows`` (a decode chunk of a model with a pool a layer kind,
         models/swa.py) joins the sample key by key: ``window_rows``,
         ``pool_rows_held``, ``pool_rows_one_table``,
@@ -439,6 +445,10 @@ class FlightRecorder:
             self.prefill_rows += tokens
         if prompt_tokens is not None:
             entry["prompt_tokens"] = prompt_tokens
+        if bucket is not None:
+            entry["bucket"] = bucket
+            self.prefill_bucket_rows += bucket * tokens
+            self.prefill_prompt_tokens += prompt_tokens or 0
         if pool_rows is not None:
             entry.update(pool_rows)
         if clock:
@@ -566,6 +576,14 @@ class FlightRecorder:
         (the samples' ``tokens``; cumulative; None before the first)."""
         batches = self.steps_by_phase.get("prefill", 0)
         return round(self.prefill_rows / batches, 4) if batches else None
+
+    @property
+    def prefill_padded_rows_share(self) -> float | None:
+        """Rows the prefill batches' programs ran (the samples' ``bucket``
+        x ``tokens``) over the true tokens of their prompts (cumulative;
+        None before the first)."""
+        true = self.prefill_prompt_tokens
+        return round(self.prefill_bucket_rows / true, 4) if true else None
 
     @property
     def dropped(self) -> int:

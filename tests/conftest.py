@@ -78,12 +78,13 @@ def dense_reference_greedy():
             llama_decode_step,
             llama_prefill,
         )
-        from langstream_tpu.serving.engine import _bucket
+        from langstream_tpu.serving.engine import _prefill_bucket_rows
 
         c, params, ffn = engine.model_config, engine.params, engine._ffn
         cache_k, cache_v = init_kv_cache(c, 1)
         lengths = jnp.asarray([len(prompt_tokens)], jnp.int32)
-        pad = _bucket(len(prompt_tokens), hi=c.max_seq_len) - len(prompt_tokens)
+        pad = _prefill_bucket_rows(
+            len(prompt_tokens), c.max_seq_len) - len(prompt_tokens)
         logits, cache_k, cache_v = llama_prefill(
             c, params, jnp.asarray([prompt_tokens + [0] * pad], jnp.int32),
             lengths, cache_k, cache_v, jnp.asarray([0]), ffn=ffn,

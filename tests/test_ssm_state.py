@@ -414,14 +414,17 @@ def test_mosaic_builds_flash_with_keys_of_192_and_values_of_128(
     assert "flash_prefill" in compiled.as_text()
 
 
+@pytest.mark.parametrize("bucket", [None, 6144, 12288, 24576])
 def test_the_largest_eva_prefill_fits_beside_the_cell_s_resident_pools(
-        one_chip, no_compile_cache):
+        one_chip, no_compile_cache, bucket):
     """``evabyte-6.5b-8l`` as its cell serves it (``bench/configs``: 24
     slots' rings and 481 summary blocks, 13.75 GB resident with the
     weights): the 32,768-row bucket's prefill compiles inside the 15.75 GB
     a program may use. With the summaries' float32 copies made over the
     whole prompt, or ``eva_flash``'s rows transposed around it, it is 0.15
-    GB over (PR 50's first form served 20 slots for that)."""
+    GB over (PR 50's first form served 20 slots for that). So do the three
+    midpoint buckets (``engine.py`` ``_prefill_bucket_rows``): 6,144 rows
+    are no multiple of the passes' 4,096 and go in one pass."""
     import json
     import pathlib
 
@@ -435,6 +438,7 @@ def test_the_largest_eva_prefill_fits_beside_the_cell_s_resident_pools(
     bs, rows = serving["kv-block-size"], serving["max-seq-len"]
     layout = PagedLayout(block_size=bs, num_blocks=serving["kv-pool-blocks"],
                          max_blocks_per_slot=rows // bs)
+    tables, rows = 2 * rows // bs, bucket or rows
     ring = eva._two_kinds(c, layout, serving["slots"])["window_layout"]
     on = lambda tree: jax.tree.map(lambda a: jax.ShapeDtypeStruct(  # noqa: E731
         a.shape, a.dtype, sharding=one_chip), tree)
@@ -449,7 +453,7 @@ def test_the_largest_eva_prefill_fits_beside_the_cell_s_resident_pools(
             c, p, t, n, pk, pv, wp, tb, use_flash=True, kernel="pallas")[:4],
         donate_argnums=(3, 4, 5),
     ).lower(params, ints(1, rows), ints(1), pool_k, pool_v, wpool,
-            ints(1, 2 * rows // bs)).compile()
+            ints(1, tables)).compile()
     held = compiled.memory_analysis().argument_size_in_bytes
     assert 13.7e9 < held < 13.8e9
     assert "eva_flash" in compiled.as_text()
