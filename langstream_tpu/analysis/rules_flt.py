@@ -22,6 +22,12 @@ Sanctioned shapes, by design:
   — catching a *named* failure is a decision, not a swallow; EXC401/402
   already police genuinely-discarded narrow catches tree-wide.
 
+Out of scope by design: the device clock's watcher
+(``serving/flight.py`` ``_watch``) swallows a failed program's exception
+after stamping its completion — the same error is raised once, on the
+dispatch thread, where ``_fetch_chunk`` / ``_fetch_prefill`` wait for
+that program, and reaches the classifier from there.
+
 Scope: ``serving/engine.py`` only, inside the dispatch-path method set
 (the same surface PERF701 guards, plus the loop itself and the
 import/export/prefix seams that touch the device).

@@ -152,8 +152,15 @@ _HOT_LOOP_FUNCS = {
 }
 
 #: the flight-recorder module is hot-path by contract: EVERY function in it
-#: may be called from the engine loop or the dispatch thread
+#: may be called from the engine loop or the dispatch thread ...
 _RECORDER_MODULE = "langstream_tpu/serving/flight.py"
+
+#: ... but for the device clock's watcher (``_watch``: a thread of its own
+#: whose whole work is to block on a queue and on each program's result)
+#: and the two methods that start and end it (``DispatchClock._start`` at
+#: the first enqueue, ``close`` from the engine's ``close()``, after the
+#: loop task and the dispatch thread are gone)
+_RECORDER_OFF_PATH = {"_watch", "_start", "close"}
 
 #: extra blocking calls beyond the async-rule table: stdout can block on a
 #: full pipe, and open() is disk I/O wherever it runs
@@ -205,6 +212,8 @@ def _hot_functions(mod: Module) -> Iterator[ast.AST]:
     whole_module_hot = mod.path.endswith(_RECORDER_MODULE)
     for node in ast.walk(mod.tree):
         if not isinstance(node, (ast.FunctionDef, ast.AsyncFunctionDef)):
+            continue
+        if whole_module_hot and node.name in _RECORDER_OFF_PATH:
             continue
         if whole_module_hot or node.name in _HOT_LOOP_FUNCS:
             yield node

@@ -21,6 +21,10 @@ Exemptions, by design:
   broadcast ships host bytes by protocol; its key/state fetches are the
   cost of multi-host replay, not an accident (and run on the dispatch
   thread);
+- the device clock's watcher (``serving/flight.py`` ``_watch``, handed
+  ``jax.block_until_ready`` by the engine's constructor): it waits on
+  every program's result, on a thread of its own that neither the loop
+  nor the dispatch thread ever waits for;
 - everything outside the dispatch-path methods (host-side numpy on
   already-fetched chunks in ``_process_chunk`` uses numpy *array math*,
   not ``np.asarray`` conversions, so the rule stays quiet there).

@@ -62,7 +62,9 @@ class MetricsReporter:
     def with_prefix(self, prefix: str) -> "MetricsReporter":
         return self
 
-    def counter(self, name: str, help: str = "") -> Callable[[int], None]:
+    def counter(
+        self, name: str, help: str = "", labels: dict[str, str] | None = None
+    ) -> Callable[[int], None]:
         def _inc(n: int = 1) -> None:
             pass
 

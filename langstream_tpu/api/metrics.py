@@ -132,7 +132,7 @@ class _FallbackMetric:
 _fallback: dict[str, _FallbackMetric] = {}
 
 
-def _fallback_counter(full: str, help: str, label: str) -> Callable[[int], None]:
+def _fallback_counter(full: str, help: str, label) -> Callable[[int], None]:
     with _metric_lock:
         metric = _fallback.setdefault(full, _FallbackMetric("counter", help))
         metric.series.setdefault(label, 0.0)
@@ -185,6 +185,16 @@ def _fallback_histogram(
     return _observe
 
 
+def _sel(label) -> str:
+    """One series' label selector: ``label`` is its agent, or for a counter
+    with labels of its own ``(agent, ((name, value), ...))``."""
+    if isinstance(label, tuple):
+        agent, extra = label
+        pairs = ([("agent_id", agent)] if agent else []) + list(extra)
+        return "{" + ",".join(f'{k}="{v}"' for k, v in pairs) + "}"
+    return f'{{agent_id="{label}"}}' if label else ""
+
+
 def _render_fallback() -> bytes:
     lines: list[str] = []
     with _metric_lock:
@@ -194,7 +204,7 @@ def _render_fallback() -> bytes:
             lines.append(f"# HELP {name} {metric.help or name}")
             lines.append(f"# TYPE {name} {metric.kind}")
             for label, state in metric.series.items():
-                sel = f'{{agent_id="{label}"}}' if label else ""
+                sel = _sel(label)
                 if metric.kind in ("counter", "gauge"):
                     lines.append(f"{name}{sel} {state}")
                     continue
@@ -235,14 +245,24 @@ class PrometheusMetricsReporter(MetricsReporter):
     def _full(self, name: str) -> str:
         return f"{self.prefix}_{name}".replace("-", "_").replace(".", "_")
 
-    def counter(self, name: str, help: str = "") -> Callable[[int], None]:
+    def counter(
+        self, name: str, help: str = "", labels: dict[str, str] | None = None
+    ) -> Callable[[int], None]:
+        """``labels`` (every series of one name gives the same keys) split
+        a counter by a dimension of its own beside the agent:
+        ``device_busy_seconds_total{phase=...}``."""
         full = self._full(name)
+        extra = tuple(sorted((labels or {}).items()))
         if not _HAVE_PROM:
-            return _fallback_counter(full, help, self.agent_id)
+            return _fallback_counter(
+                full, help, (self.agent_id, extra) if extra else self.agent_id
+            )
         with _metric_lock:
             if full not in _counters:
-                _counters[full] = Counter(full, help or full, ["agent_id"])
-            c = _counters[full].labels(agent_id=self.agent_id)
+                _counters[full] = Counter(
+                    full, help or full, ["agent_id", *(k for k, _ in extra)]
+                )
+            c = _counters[full].labels(agent_id=self.agent_id, **dict(extra))
         return lambda n=1: c.inc(n)
 
     def gauge(self, name: str, help: str = "") -> Callable[[float], None]:

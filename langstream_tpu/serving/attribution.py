@@ -316,9 +316,14 @@ class ProgramLedger:
         self._costs[program] = cost
 
     def observe(self, program: str, device_s: float) -> None:
-        """Record one dispatch's measured device wait. Unregistered ids
-        are dropped (a registration always precedes the dispatch on the
-        same thread, so this only guards torn test doubles)."""
+        """Record one dispatch's measured device time: the program's own
+        time by the device's clock where it gave one (the flight sample's
+        ``program_ms``, flight.py ``DispatchClock``: a batch dispatched one
+        ahead reads its whole run, not what was left of it when the host
+        came to wait), else the dispatch thread's wait plus the host work
+        in its shadow. Unregistered ids are dropped (a registration always
+        precedes the dispatch on the same thread, so this only guards torn
+        test doubles)."""
         times = self._times.get(program)
         if times is None:
             return

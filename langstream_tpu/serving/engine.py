@@ -87,7 +87,7 @@ from langstream_tpu.serving.faults import (
     InjectedFault,
     plans_from_env,
 )
-from langstream_tpu.serving.flight import FlightRecorder, resumed
+from langstream_tpu.serving.flight import PHASES, FlightRecorder, resumed
 from langstream_tpu.serving.incident import (
     IncidentRecorder,
     adapter_eviction_storm,
@@ -1134,7 +1134,10 @@ class TpuServingEngine:
         # flight recorder: one sample per dispatched burst + stall gaps +
         # discrete events; served by the pod /flight endpoints and the
         # engine_top console (serving/flight.py)
-        self.flight = FlightRecorder(slots=config.slots)
+        # the device's clock waits on each program's result on a thread of
+        # its own (flight.py DispatchClock; ended in close())
+        self.flight = FlightRecorder(
+            slots=config.slots, watch=jax.block_until_ready)
         # engine watchdog: heartbeat stamped at every flight boundary,
         # judged (wait-free) by probes/stats via health() — the layer that
         # turns a wedged device into a failed k8s liveness probe
@@ -1241,6 +1244,25 @@ class TpuServingEngine:
                 "no-free-slot", "no-kv-blocks", "prefill-in-flight",
                 "queue-empty",
             )
+        }
+        # the device's clock on /metrics (flight.py DispatchClock): an
+        # operator reads the device's idle share from two rates
+        device = PrometheusMetricsReporter(
+            prefix="langstream_engine", agent_id=config.model
+        )
+        self._m_device_idle = device.counter(
+            "device_idle_seconds_total",
+            "seconds the device stood with no program queued (the flight "
+            "samples' gap_ms)",
+        )
+        self._m_device_busy = {
+            phase: device.counter(
+                "device_busy_seconds_total",
+                "seconds the device ran programs, by phase (the flight "
+                "samples' program_ms)",
+                labels={"phase": phase},
+            )
+            for phase in PHASES
         }
         self._m_spec_rejected = reporter.counter(
             "speculative_drafts_rejected_total",
@@ -2562,14 +2584,24 @@ class TpuServingEngine:
         ``program`` keys the sample by the compiled variant that ran and
         feeds the attribution ledger's measured side (achieved-vs-
         expected per program, serving/attribution.py) — credited with
-        the blocked wait PLUS the overlapped host share: under the
-        pipelined loop the device keeps executing while the host works
-        in its shadow, so the wait alone would systematically understate
-        device time and flatter the per-program ratio exactly when
-        pipelining is on. Hot-path discipline (graftcheck OBS503): deque
-        appends and counter bumps only — no I/O, no locks."""
+        the program's own time on the device where the clock gave one
+        (``program_ms``), else with the blocked wait PLUS the overlapped
+        host share: under the pipelined loop the device keeps executing
+        while the host works in its shadow, so the wait alone would
+        systematically understate device time and flatter the per-program
+        ratio exactly when pipelining is on. Hot-path discipline
+        (graftcheck OBS503): deque appends and counter bumps only — no
+        I/O, no locks."""
+        timed = bool(clock) and "program_ms" in clock
         if program is not None:
-            self.attribution.observe(program, device_s + overlapped_s)
+            self.attribution.observe(
+                program,
+                clock["program_ms"] / 1e3 if timed
+                else device_s + overlapped_s,
+            )
+        if timed:  # /metrics twins of the summary's gap_ms / program_ms
+            self._m_device_idle(clock["gap_ms"] / 1e3)
+            self._m_device_busy[phase](clock["program_ms"] / 1e3)
         stall = self._admission_stall()
         kv_used = self.block_mgr.used_ratio()
         depths = self.scheduler.depths()
@@ -3507,6 +3539,9 @@ class TpuServingEngine:
         # makes the reference drops below race-free (the dispatch thread
         # no longer exists when they run)
         self._executor.shutdown(wait=True)
+        # the clock's watcher thread waits on results of programs the
+        # dispatch thread enqueued: with that thread gone nothing feeds it
+        self.flight.clock.close()
         # evict from the singleton cache: a closed engine must not be handed
         # out again (its loop would exit immediately, stranding requests)
         with self._instances_lock:
@@ -5897,7 +5932,8 @@ class TpuServingEngine:
                 jnp.asarray(current_np), jnp.asarray(lengths_np),
                 amask, tables_dev, key, temps, topks, topps, **ad_kw,
             )
-            self.flight.clock.enqueued(ticket["clock"], packed)
+            self.flight.clock.enqueued(
+                ticket["clock"], packed, ticket["dispatch"])
             self.cache_k, self.cache_v = ck, cv
             self._decode_dispatches += 1
             self._start_fetch(packed)
@@ -6502,7 +6538,8 @@ class TpuServingEngine:
                     f"decode_chunk_w{window}_s{sampler_mode}", decode_fn, *args
                 )
                 packed, t, l, ck, cv, *st = decode_fn(*args, **ad_kw)
-                self.flight.clock.enqueued(ticket["clock"], packed)
+                self.flight.clock.enqueued(
+                    ticket["clock"], packed, ticket["dispatch"])
                 self.cache_k, self.cache_v = ck, cv
                 if st:
                     self.state = st[0]
@@ -6857,7 +6894,7 @@ class TpuServingEngine:
                     fn, *args,
                 )
                 out = fn(*args, **ad_kw)
-                self.flight.clock.enqueued(times, out[0])
+                self.flight.clock.enqueued(times, out[0], ticket["dispatch"])
                 # the donated caches are re-bound HERE, on the dispatch thread
                 # — the same side that reads them in every dispatch closure, so
                 # cache_k/cache_v stay single-thread-role (RACE801)

@@ -83,13 +83,20 @@ The vocabulary is :data:`SPANS` (docs/OBSERVABILITY.md, "Device
 profiling"); a dispatch's ``seq`` is the ``dispatch`` field of its flight
 sample.
 
-The **dispatch thread's clock** (:class:`DispatchClock`) is the same
-account with the tracing off: the one thread that hands every program to
-the device and sees every completion stamps both, and each dispatch's
-sample carries ``gap_ms`` (the device stood with nothing queued before this
-program), ``program_ms`` (the program's own time) and ``resume_lag_ms``
-(how long the engine's coroutine waited in the loop's ready queue after the
-dispatch thread had returned).
+The **device's clock** (:class:`DispatchClock`) is the same account with
+the tracing off, over the whole life of the engine. The dispatch thread
+stamps each program as its jitted call returns; its completion is stamped
+where it is FIRST seen: by that thread where it waits for the result, or by
+one watcher thread of the engine that waits on every program's result in the
+device's order (its wait is the span ``dev.watch``, the one name outside
+``ls.``: no idle time is attributed to it). Each dispatch's sample carries
+``gap_ms`` (the device stood with nothing queued before this program: its
+idle time), ``program_ms`` (the program's own time on the device),
+``seen_by`` (``"watch"`` or ``"fetch"``: who stamped the completion) and
+``resume_lag_ms`` (how long the engine's coroutine waited in the loop's
+ready queue after the dispatch thread had returned). A stamp needs the GIL,
+so it can be late by what another thread holds it for; the benchmark's
+``device_clock_late_ms_p95`` measures that against a trace.
 
 Sizing: ``LS_TPU_FLIGHT_BUFFER`` samples (default 4096, min 64). Cumulative
 totals (wall/device/host/stall, per-phase step counts, stall seconds by
@@ -107,7 +114,10 @@ post-mortem breakdown. See ``docs/OBSERVABILITY.md``.
 from __future__ import annotations
 
 import os
+import queue
+import threading
 import time
+import weakref
 from collections import deque
 from typing import Any, Callable
 
@@ -128,7 +138,11 @@ PHASES = ("prefill", "decode", "verify")
 #: reader of the profile may meet): the engine loop's, the dispatch
 #: thread's two blocking waits (``*.wait``), and ``ls.hop.*`` around the
 #: synchronous stretches of everything else that runs on the engine's loop
-#: between two of its dispatches
+#: between two of its dispatches. Beside them :data:`WATCH_SPAN`, the one
+#: name that is NOT attributed: ``bench/lib/hosttrace.py`` gives each idle
+#: instant to the ``ls.*`` span that started last, whatever its thread, so a
+#: third thread's wait under that prefix would take idle time from the
+#: spans of the code that caused it
 SPANS = (
     "ls.admit",
     "ls.prefill.pack",
@@ -153,6 +167,10 @@ SPANS = (
     "ls.hop.runner",
 )
 
+#: the watcher thread's wait for one program's result (``seq`` is the
+#: dispatch's): it ends where :class:`DispatchClock` stamps the completion
+WATCH_SPAN = "dev.watch"
+
 #: the spans held across an ``await`` of the dispatch thread: once that
 #: thread's own span (``*.dispatch``, ``*.wait``) has ended, what is left
 #: under one of these is the engine's coroutine waiting for its turn on the
@@ -174,56 +192,124 @@ def _pct(sorted_values: list, q: float):
     return sorted_values[min(len(sorted_values) - 1, int(q * len(sorted_values)))]
 
 
+def _watch(inbox: "queue.SimpleQueue", wait: Callable[[Any], Any],
+           clock: Callable[[], float]) -> None:
+    """The watcher thread of one :class:`DispatchClock`: wait on each
+    program's result in the device's order (the GIL released while it
+    blocks) and stamp the completion unless the dispatch thread has. It
+    holds no reference to the clock, and to a handle only while it waits on
+    it. A failed program is over too: its error is raised once, where the
+    dispatch thread waits for it, and swallowed here."""
+    while True:
+        item = inbox.get()
+        if item is None:
+            return
+        times, handle, seq = item
+        del item
+        with host_span(WATCH_SPAN, **({} if seq is None else {"seq": seq})):
+            try:
+                wait(handle)
+            # graftcheck: disable=EXC402 the program's error is raised once, where the dispatch thread waits for it
+            except Exception:
+                pass
+            del handle
+            times.setdefault("seen", (clock(), "watch"))
+        del times
+
+
 class DispatchClock:
-    """The dispatch thread's account of the device, kept with the tracing
-    off. That thread is single and the device runs programs in the order it
-    hands them over, so two ``time.monotonic()`` stamps a program tile the
-    device's time: ``enqueued`` when the jitted call has returned, ``ready``
-    when the blocking call on its result has. Each program's ``times`` (the
-    ``clock`` of its engine ticket) then holds
+    """The device's clock, kept with the tracing off. One thread hands
+    every program to the device, which runs them in that order, so two
+    ``time.monotonic()`` stamps a program tile the device's time:
+    ``enqueued_t`` when the jitted call has returned (:meth:`enqueued`),
+    ``done_t`` where its completion is first seen. Two observers see
+    completions: the dispatch thread, where it makes the blocking call on a
+    result (:meth:`ready`, :meth:`settle`), and the watcher, one daemon
+    thread that waits on every program's result in the device's order
+    (``watch``, the blocking call it makes: ``jax.block_until_ready``) and so
+    is waiting when a batch dispatched one ahead ends while the dispatch
+    thread dispatches its successor (:meth:`seen`). Whoever stamps first
+    wins: one ``dict.setdefault`` of ``times["seen"]``, ``(t, "watch" |
+    "fetch")``. The watcher stamps in the device's order; the dispatch
+    thread, seeing a program complete, also stamps every program enqueued
+    before it that nobody has. The arithmetic is the dispatch thread's alone
+    (:meth:`ready`), which closes every program before its sample is
+    recorded. Each program's ``times`` (the ``clock`` of
+    its engine ticket) then holds
 
-    - ``gap_ms``: ``enqueued_t`` less the ``ready_t`` of the program
+    - ``gap_ms``: ``enqueued_t`` less the ``done_t`` of the program
       enqueued before it, whatever its phase, where that is positive: the
-      device stood with nothing queued;
-    - ``program_ms``: ``ready_t`` less the later of ``enqueued_t`` and that
-      predecessor's ``ready_t``: the program's own time. A completion seen
-      late lengthens this one and shortens the next by as much, so the sums
-      of the two fields tile from :attr:`first_enqueued_t` to
-      :attr:`last_ready_t`.
+      device stood with nothing queued, its idle time;
+    - ``program_ms``: ``done_t`` less the later of ``enqueued_t`` and that
+      predecessor's ``done_t``: the program's own time on the device;
+    - ``seen_by``: who stamped ``done_t``.
 
-    A program whose completion nobody has waited for yet when a LATER one's
-    is (a decode chunk left pending under an admission round's prefills) is
-    waited for first (:meth:`settle`), so its time is not its successor's.
-    A completion is stamped only where this thread waits for it: a batch
-    dispatched one ahead is fetched after its successor's dispatch, so when
-    it ends first the device's wait from there stays in its ``program_ms``
-    and ``gap_ms`` is a LOWER bound of the device's idle time. Dispatch
-    thread only: clock reads, a deque and dictionary stores."""
+    The sums of the two fields tile from :attr:`first_enqueued_t` to
+    :attr:`last_done_t` under any interleaving of the two observers (a stamp
+    read before a predecessor's and written after it is moved up to it). A
+    program whose completion nobody has seen when a LATER one's is waited
+    for (a decode chunk left pending under an admission round's prefills)
+    is waited for first (:meth:`settle`), so its time is not its
+    successor's. What is left between the device and these figures is the
+    stamp's lateness: an observer needs the GIL to stamp, and waits for it
+    as long as another thread runs Python (the switch interval, 5 ms, at
+    most; ``device_clock_late_ms_p95`` of the benchmark measures it). A
+    completion seen late lengthens its program and shortens the gap after
+    it by as much. Record path: clock reads, a deque, one
+    ``queue.SimpleQueue.put`` and dictionary stores; no lock."""
 
-    def __init__(self, clock: Callable[[], float] = time.monotonic):
+    def __init__(self, clock: Callable[[], float] = time.monotonic,
+                 watch: Callable[[Any], Any] | None = None):
         self._clock = clock
-        #: (times, handle) of the programs enqueued and not yet seen
-        #: complete, in the device's order (at most three: a decode chunk
-        #: and two prefill batches)
+        #: the watcher's blocking call on a handle; None: no watcher (a
+        #: recorder without an engine, a clock that was closed)
+        self._watch = watch
+        self._inbox: queue.SimpleQueue | None = None
+        self._watcher: threading.Thread | None = None
+        #: (times, handle) of the programs enqueued and not yet closed, in
+        #: the device's order (at most three: a decode chunk and two prefill
+        #: batches); the dispatch thread's alone
         self._open: deque[tuple[dict, Any]] = deque()
         self.first_enqueued_t: float | None = None
-        self.last_ready_t: float | None = None
+        self.last_done_t: float | None = None
 
-    def enqueued(self, times: dict, handle: Any = None) -> None:
+    def enqueued(self, times: dict, handle: Any = None,
+                 seq: int | None = None) -> None:
         """The jitted call returned: the program is the device's. ``handle``
-        is something of its result to wait on (:meth:`settle`)."""
+        is something of its result to wait on (:meth:`settle`, the watcher),
+        ``seq`` the dispatch's ordinal for the watcher's span."""
         now = self._clock()
         times["enqueued_t"] = now
         if self.first_enqueued_t is None:
             self.first_enqueued_t = now
         self._open.append((times, handle))
+        if self._watch is not None and handle is not None:
+            if self._watcher is None:
+                self._start()
+            self._inbox.put((times, handle, seq))
+
+    def _start(self) -> None:
+        """The watcher, at the first enqueue. It ends with :meth:`close`,
+        or when nothing refers to this clock any more."""
+        self._inbox = queue.SimpleQueue()
+        weakref.finalize(self, self._inbox.put, None)
+        self._watcher = threading.Thread(
+            target=_watch, args=(self._inbox, self._watch, self._clock),
+            name="tpu-engine-watch", daemon=True)
+        self._watcher.start()
+
+    def seen(self, times: dict, by: str = "watch") -> None:
+        """An observer other than :meth:`ready` saw ``times``' program
+        complete: the stamp stands unless one was there (what the watcher
+        does after its wait; a test's hand on the second observer)."""
+        times.setdefault("seen", (self._clock(), by))
 
     def settle(self, times: dict, wait: Callable[[Any], Any]) -> None:
         """Before the blocking call on ``times``' own program: wait, in the
         device's order, for each program enqueued before it whose completion
-        nobody has seen (it cannot end later than this one, so the thread
-        blocks no longer than it would have)."""
-        if "enqueued_t" not in times or "ready_t" in times:
+        this thread has not seen (it cannot end later than this one, so the
+        thread blocks no longer than it would have)."""
+        if "enqueued_t" not in times or "done_t" in times:
             return  # not the device's through this clock: nothing to order
         while self._open and self._open[0][0] is not times:
             earlier, handle = self._open[0]
@@ -235,24 +321,37 @@ class DispatchClock:
 
     def ready(self, times: dict) -> None:
         """The blocking call on ``times``' program returned. Programs
-        enqueued before it have completed too; one not seen until now ends
-        here, and its successors get what is left."""
-        if "enqueued_t" not in times or "ready_t" in times:
+        enqueued before it have completed too: each one open is closed
+        here, at the watcher's stamp where it has one, else now."""
+        if "enqueued_t" not in times or "done_t" in times:
             return
         now = self._clock()
         while self._open:
             earlier, _handle = self._open.popleft()
-            before = self.last_ready_t
+            done, by = earlier.setdefault("seen", (now, "fetch"))
+            before = self.last_done_t
             start = earlier["enqueued_t"]
             earlier["gap_ms"] = 0.0
             if before is not None:
                 earlier["gap_ms"] = max(0.0, start - before) * 1e3
                 start = max(start, before)
-            earlier["program_ms"] = (now - start) * 1e3
-            earlier["ready_t"] = now
-            self.last_ready_t = now
+            done = max(done, start)
+            earlier["program_ms"] = (done - start) * 1e3
+            earlier["done_t"] = done
+            earlier["seen_by"] = by
+            self.last_done_t = done
             if earlier is times:
                 return
+
+    def close(self, timeout: float = 2.0) -> None:
+        """End the watcher and wait for it (a wedged device's wait is left
+        to the daemon flag after ``timeout``). Later enqueues are stamped by
+        the dispatch thread alone."""
+        self._watch = None
+        watcher, self._watcher = self._watcher, None
+        if watcher is not None:
+            self._inbox.put(None)
+            watcher.join(timeout)
 
 
 def resumed(times: dict) -> None:
@@ -270,9 +369,14 @@ class FlightRecorder:
     """Bounded per-engine telemetry ring. Single writer (the engine loop;
     events may also arrive from the dispatch thread), many readers."""
 
-    def __init__(self, slots: int = 0, maxlen: int | None = None):
+    def __init__(self, slots: int = 0, maxlen: int | None = None,
+                 watch: Callable[[Any], Any] | None = None):
         self.slots = slots
-        self.clock = DispatchClock()
+        #: ``watch``: the blocking call on a program's result, for the
+        #: clock's watcher thread (the engine gives
+        #: ``jax.block_until_ready``); without it the dispatch thread
+        #: stamps alone
+        self.clock = DispatchClock(watch=watch)
         self.capacity = maxlen if maxlen is not None else _buffer_size()
         self._samples: deque[dict[str, Any]] = deque(maxlen=self.capacity)
         self._events: deque[dict[str, Any]] = deque(maxlen=512)
@@ -309,10 +413,12 @@ class FlightRecorder:
         # and the true tokens of their prompts
         self.prefill_bucket_rows = 0
         self.prefill_prompt_tokens = 0
-        # cumulative twins of the samples' gap_ms / program_ms /
-        # resume_lag_ms (the dispatch thread's clock, DispatchClock)
+        # cumulative twins of the samples' gap_ms / program_ms / seen_by /
+        # resume_lag_ms (the device's clock, DispatchClock): the device's
+        # idle time, its busy time by phase, who stamped the completions
         self.gap_ms = 0.0
         self.program_ms_by_phase: dict[str, float] = {}
+        self.completions_seen_by: dict[str, int] = {}
         self.resume_lag_ms = 0.0
 
     # -- recording (engine hot path: appends + counter bumps only) -------
@@ -393,8 +499,9 @@ class FlightRecorder:
         ``window_slot_blocks_max``, ``short_slots``,
         ``window_blocks_held`` (engine.py ``_pool_rows``).
         ``clock`` is the dispatch's times as :class:`DispatchClock` and
-        :func:`resumed` left them: the sample's ``gap_ms``, ``program_ms``
-        and ``resume_lag_ms``, each omitted where it was not taken."""
+        :func:`resumed` left them: the sample's ``gap_ms``, ``program_ms``,
+        ``seen_by`` and ``resume_lag_ms``, each omitted where it was not
+        taken."""
         now = time.monotonic()
         wall_ms = (now - self._last_mark) * 1000.0
         self._last_mark = now
@@ -455,6 +562,10 @@ class FlightRecorder:
             if "program_ms" in clock:
                 entry["gap_ms"] = round(clock["gap_ms"], 3)
                 entry["program_ms"] = round(clock["program_ms"], 3)
+                if "seen_by" in clock:
+                    by = entry["seen_by"] = clock["seen_by"]
+                    self.completions_seen_by[by] = (
+                        self.completions_seen_by.get(by, 0) + 1)
                 self.gap_ms += clock["gap_ms"]
                 self.program_ms_by_phase[phase] = (
                     self.program_ms_by_phase.get(phase, 0.0)
@@ -652,6 +763,7 @@ class FlightRecorder:
                     k: round(v, 3)
                     for k, v in self.program_ms_by_phase.items()
                 },
+                "completions_seen_by": dict(self.completions_seen_by),
                 "resume_lag_ms": round(self.resume_lag_ms, 3),
             },
             "window": {

@@ -11,6 +11,7 @@ and the ``engine_top --analyze`` post-mortem on a canned dump."""
 import asyncio
 import importlib.util
 import json
+import re
 import socket
 import time
 from pathlib import Path
@@ -197,7 +198,7 @@ def test_bench_rollup_carries_the_record_keys():
 # --------------------------------------------------------------------------
 # engine integration (CPU backend): the acceptance decomposition
 # --------------------------------------------------------------------------
-# the dispatch thread's clock: gap_ms, program_ms, resume_lag_ms (PR 36)
+# the device's clock: gap_ms, program_ms, seen_by, resume_lag_ms (PR 36, 52)
 # --------------------------------------------------------------------------
 
 
@@ -220,55 +221,129 @@ class _Ticks:
 
 def _tiles(times):
     """Sum of gap_ms + program_ms of the programs seen complete."""
-    return sum(t["gap_ms"] + t["program_ms"] for t in times if "ready_t" in t)
+    return sum(t["gap_ms"] + t["program_ms"] for t in times if "done_t" in t)
 
 
-@pytest.mark.parametrize("script, expected", [
-    # (op, program) in the dispatch thread's order, the clock a second a call.
-    # In order, fetched at once: a gap wherever the next was enqueued late.
+def _play(clock, script, times, waited=None):
+    """A script of (op, program[, t]) against a clock WITHOUT its thread:
+    ``enq`` / ``settle`` / ``rdy`` are the dispatch thread's, ``watch`` is
+    what the watcher does after its wait, ``stamp`` writes a watcher's stamp
+    read at ``t`` (one read before a stamp that is already there)."""
+    for op, i, *t in script:
+        if op == "enq":
+            clock.enqueued(times[i], handle=i)
+        elif op == "settle":
+            clock.settle(times[i], waited.append)
+        elif op == "watch":
+            clock.seen(times[i])
+        elif op == "stamp":
+            times[i].setdefault("seen", (t[0], "watch"))
+        else:
+            clock.ready(times[i])
+
+
+@pytest.mark.parametrize("script, expected, seen_by", [
+    # (op, program) in the order the stamps are taken, the clock a second a
+    # call. In order, fetched at once: a gap wherever the next was enqueued
+    # late.
     ([("enq", 0), ("rdy", 0), ("enq", 1), ("rdy", 1)],
-     [(0.0, 1000.0), (1000.0, 1000.0)]),
+     [(0.0, 1000.0), (1000.0, 1000.0)], "ff"),
     # one ahead: 1 was enqueued while 0 ran, so no gap, and 1 starts at 0's end
     ([("enq", 0), ("enq", 1), ("rdy", 0), ("rdy", 1)],
-     [(0.0, 2000.0), (0.0, 1000.0)]),
-    # a completion seen LATE: 0 (a pending decode chunk) is waited for only
-    # after its successor 1 was: 0 ends where 1's wait ended, 1 gets what is
-    # left (nothing), nothing is counted twice, and 2 starts from there
+     [(0.0, 2000.0), (0.0, 1000.0)], "ff"),
+    # a completion NOBODY saw until later: 0 (a pending decode chunk) is
+    # waited for only after its successor 1 was: 0 ends where 1's wait ended,
+    # 1 gets what is left (nothing), nothing is counted twice, and 2 starts
+    # from there
     ([("enq", 0), ("enq", 1), ("rdy", 1), ("rdy", 0), ("enq", 2), ("rdy", 2)],
-     [(0.0, 2000.0), (0.0, 0.0), (1000.0, 1000.0)]),
+     [(0.0, 2000.0), (0.0, 0.0), (1000.0, 1000.0)], "fff"),
     # ... unless it is settled first: 1's wait sees 0 complete on the way
     ([("enq", 0), ("enq", 1), ("settle", 1), ("rdy", 1), ("rdy", 0)],
-     [(0.0, 2000.0), (0.0, 1000.0)]),
-    # one ahead, and whether or not the device ran dry before 2's dispatch:
-    # 1's completion is stamped where this thread waits for it, at its fetch
-    # after 2's dispatch, so a wait of the device from 1's end stays in 1's
-    # program_ms (gap_ms is a lower bound of the device's idle time)
-    ([("enq", 0), ("enq", 1), ("rdy", 0), ("enq", 2), ("rdy", 1), ("rdy", 2)],
-     [(0.0, 2000.0), (0.0, 2000.0), (0.0, 1000.0)]),
-], ids=["in-order", "one-ahead", "seen-late", "settled", "fetched-after-next"])
-def test_the_dispatch_clock_tiles_the_device_s_time(script, expected):
+     [(0.0, 2000.0), (0.0, 1000.0)], "ff"),
+    # one ahead, and the device ran dry before 2's dispatch: the WATCHER was
+    # waiting when 1 ended, so 1 ends there whenever its fetch comes, and
+    # the device's wait from 1's end to 2's dispatch is 2's gap
+    ([("enq", 0), ("enq", 1), ("rdy", 0), ("watch", 1), ("enq", 2),
+      ("rdy", 1), ("rdy", 2)],
+     [(0.0, 2000.0), (0.0, 1000.0), (1000.0, 2000.0)], "fwf"),
+    # the dispatch thread saw it first: the watcher's later stamp changes
+    # nothing
+    ([("enq", 0), ("rdy", 0), ("watch", 0), ("enq", 1), ("rdy", 1)],
+     [(0.0, 1000.0), (2000.0, 1000.0)], "ff"),
+    # the pending chunk again, now watched: it ends at the watcher's stamp
+    # and its successor gets its own time without a settle
+    ([("enq", 0), ("enq", 1), ("watch", 0), ("rdy", 1), ("rdy", 0)],
+     [(0.0, 2000.0), (0.0, 1000.0)], "wf"),
+    # a stamp read before a later one and written after it (the watcher lost
+    # the GIL between its clock read and its store... of the predecessor):
+    # 1's earlier stamp is moved up to 0's, so no program's time is negative
+    ([("enq", 0), ("enq", 1), ("stamp", 0, 105.0), ("rdy", 1)],
+     [(0.0, 5000.0), (0.0, 0.0)], "wf"),
+], ids=["in-order", "one-ahead", "seen-late", "settled",
+        "watched-before-the-next-dispatch", "fetch-first", "watched-pending",
+        "stamp-written-late"])
+def test_the_dispatch_clock_tiles_the_device_s_time(script, expected, seen_by):
     from langstream_tpu.serving.flight import DispatchClock
 
     clock = DispatchClock(clock=_Ticks(range(100, 200)))
     times = [{} for _ in expected]
     waited = []
-    for op, i in script:
-        if op == "enq":
-            clock.enqueued(times[i], handle=i)
-        elif op == "settle":
-            clock.settle(times[i], waited.append)
-        else:
-            clock.ready(times[i])
+    _play(clock, script, times, waited)
     got = [(t["gap_ms"], t["program_ms"]) for t in times]
     assert got == [pytest.approx(e) for e in expected]
-    # no double count, no hole: the fields tile first enqueue to last ready
+    assert "".join(t["seen_by"][0] for t in times) == seen_by
+    # no double count, no hole: the fields tile first enqueue to last done
     assert _tiles(times) == pytest.approx(
-        (clock.last_ready_t - clock.first_enqueued_t) * 1e3)
+        (clock.last_done_t - clock.first_enqueued_t) * 1e3)
     assert waited == ([0] if ("settle", 1) in script else [])
-    assert not clock._open
+    assert not clock._open and clock._watcher is None   # no thread was asked for
 
 
-@pytest.mark.parametrize("times", [{}, {"enqueued_t": 1.0, "ready_t": 2.0}],
+#: the dispatch thread's stamps of three programs, the second dispatched one
+#: ahead and fetched after the third's dispatch; the watcher's three stamps
+#: fall anywhere after each program's enqueue, in the device's order
+_DISPATCH_THREAD = [("enq", 0), ("enq", 1), ("rdy", 0), ("enq", 2), ("rdy", 1),
+                    ("rdy", 2)]
+
+
+def _interleavings():
+    import itertools
+
+    n = len(_DISPATCH_THREAD) + 3
+    for at in itertools.combinations(range(n), 3):
+        script, thread, watched = [], iter(_DISPATCH_THREAD), iter(range(3))
+        for k in range(n):
+            script.append(("watch", next(watched)) if k in at else next(thread))
+        if all(script.index(("watch", i)) > script.index(("enq", i))
+               for i in range(3)):
+            yield script
+
+
+@pytest.mark.parametrize(
+    "script", list(_interleavings()),
+    ids=lambda s: "".join("w" if op == "watch" else "d" for op, _ in s))
+def test_the_two_observers_tile_the_device_s_time_however_they_interleave(script):
+    from langstream_tpu.serving.flight import DispatchClock
+
+    clock = DispatchClock(clock=_Ticks(range(100, 200)))
+    times = [{}, {}, {}]
+    _play(clock, script, times)
+    assert _tiles(times) == pytest.approx(
+        (clock.last_done_t - clock.first_enqueued_t) * 1e3)
+    done = [t["done_t"] for t in times]
+    assert done == sorted(done)
+    for i, t in enumerate(times):
+        assert t["gap_ms"] >= 0 and t["program_ms"] >= 0
+        # the first stamp stands: the watcher's, unless a fetch of this
+        # program or of a later one came before it
+        first = min(script.index(("watch", i)),
+                    *(script.index(("rdy", j)) for j in range(i, 3)))
+        assert t["seen_by"] == ("watch" if script[first][0] == "watch"
+                                else "fetch")
+        assert t["done_t"] == 100 + first or t["done_t"] == done[i - 1]
+
+
+@pytest.mark.parametrize("times", [{}, {"enqueued_t": 1.0, "done_t": 2.0}],
                          ids=["never-enqueued", "already-seen"])
 def test_the_dispatch_clock_ignores_what_it_did_not_enqueue(times):
     from langstream_tpu.serving.flight import DispatchClock
@@ -280,6 +355,156 @@ def test_the_dispatch_clock_ignores_what_it_did_not_enqueue(times):
     clock.settle(times, lambda handle: 1 / 0)
     clock.ready(times)
     assert times == before and len(clock._open) == 1
+
+
+class _Hand:
+    """A clock a test moves by hand, and the fake handles' blocking call."""
+
+    def __init__(self):
+        self.now = 0.0
+        self.failed = []
+
+    def __call__(self):
+        return self.now
+
+    def wait(self, handle):
+        import threading
+
+        assert handle.wait(10.0)
+        if getattr(handle, "fails", False):
+            self.failed.append(threading.current_thread().name)
+            raise RuntimeError("the program failed")
+
+    @staticmethod
+    def stamped(times, timeout=10.0):
+        """Wait for the watcher's stamp of ``times`` (its thread is real)."""
+        deadline = time.monotonic() + timeout
+        while "seen" not in times and time.monotonic() < deadline:
+            time.sleep(0.001)
+        return times.get("seen")
+
+
+def _watchers():
+    import threading
+
+    return [t for t in threading.enumerate() if t.name == "tpu-engine-watch"]
+
+
+def _watched_clock():
+    import threading
+
+    from langstream_tpu.serving.flight import DispatchClock
+
+    hand = _Hand()
+    return hand, DispatchClock(clock=hand, watch=hand.wait), threading.Event
+
+
+def test_a_batch_that_ends_under_its_successor_s_dispatch_is_the_watcher_s():
+    """0 runs 0-2 s; 1 (one ahead) runs 2-3 s and ends while the dispatch
+    thread packs 2, enqueued at 5 s; 1's fetch comes at 6 s. The watcher was
+    waiting: 1 ends at 3 s and the device's 2 s without work are 2's gap."""
+    hand, clock, handle = _watched_clock()
+    before = len(_watchers())
+    try:
+        times, hs = [{}, {}, {}], [handle(), handle(), handle()]
+        clock.enqueued(times[0], hs[0], seq=7)
+        assert len(_watchers()) == before + 1   # started at the first enqueue
+        hand.now = 1.0
+        clock.enqueued(times[1], hs[1], seq=8)
+        hand.now = 2.0
+        hs[0].set()
+        assert hand.stamped(times[0]) == (2.0, "watch")
+        clock.ready(times[0])
+        hand.now = 3.0
+        hs[1].set()
+        assert hand.stamped(times[1]) == (3.0, "watch")
+        hand.now = 5.0
+        clock.enqueued(times[2], hs[2], seq=9)
+        hand.now = 6.0
+        clock.ready(times[1])
+        hand.now = 8.0
+        clock.ready(times[2])            # the fetch saw 2 first
+        hs[2].set()
+        assert [(t["gap_ms"], t["program_ms"], t["seen_by"]) for t in times] == [
+            (0.0, 2000.0, "watch"), (0.0, 1000.0, "watch"),
+            (2000.0, 3000.0, "fetch")]
+        assert _tiles(times) == pytest.approx(8000.0)
+        assert len(_watchers()) == before + 1   # one thread, not one a program
+    finally:
+        clock.close()
+    assert len(_watchers()) == before
+
+
+def test_the_watcher_s_later_stamp_changes_nothing():
+    hand, clock, handle = _watched_clock()
+    try:
+        first, second = {}, {}
+        h1, h2 = handle(), handle()
+        clock.enqueued(first, h1)
+        hand.now = 1.0
+        clock.ready(first)               # the dispatch thread saw it first
+        wrote = dict(first)
+        hand.now = 4.0
+        h1.set()
+        clock.enqueued(second, h2)       # the watcher reaches it after h1
+        h2.set()
+        assert hand.stamped(second) == (4.0, "watch")
+        assert first == wrote and first["seen_by"] == "fetch"
+        assert first["seen"] == (1.0, "fetch")
+    finally:
+        clock.close()
+
+
+def test_a_failed_program_is_stamped_not_raised_and_the_watcher_lives():
+    hand, clock, handle = _watched_clock()
+    try:
+        bad, good = handle(), handle()
+        bad.fails = True
+        failed, after = {}, {}
+        clock.enqueued(failed, bad)
+        hand.now = 1.5
+        bad.set()
+        assert hand.stamped(failed) == (1.5, "watch")
+        assert hand.failed == ["tpu-engine-watch"]
+        with pytest.raises(RuntimeError):    # raised once, where the fetch waits
+            hand.wait(bad)
+        clock.ready(failed)
+        assert failed["seen_by"] == "watch" and failed["program_ms"] == 1500.0
+        hand.now = 2.0
+        clock.enqueued(after, good)
+        hand.now = 2.5
+        good.set()
+        assert hand.stamped(after) == (2.5, "watch")
+        assert clock._watcher.is_alive()
+    finally:
+        clock.close()
+
+
+def test_close_ends_the_watcher_and_a_dropped_clock_s_ends_too():
+    import gc
+
+    hand, clock, handle = _watched_clock()
+    before = len(_watchers())
+    h = handle()
+    h.set()
+    times = {}
+    clock.enqueued(times, h)
+    assert hand.stamped(times)
+    watcher = clock._watcher
+    clock.close()
+    assert not watcher.is_alive() and len(_watchers()) == before
+    later = {}
+    clock.enqueued(later, h)             # closed: the dispatch thread alone
+    clock.ready(later)
+    assert later["seen_by"] == "fetch" and len(_watchers()) == before
+    # an engine nobody closed: its clock's thread ends with the clock
+    hand, dropped, handle = _watched_clock()
+    dropped.enqueued({}, h)
+    watcher = dropped._watcher
+    del dropped
+    gc.collect()
+    watcher.join(5.0)
+    assert not watcher.is_alive()
 
 
 #: (phase, device_s, overlapped_s, tokens, ahead) of a recorded sequence, the
@@ -355,9 +580,11 @@ def _tiny_engine():
 def test_an_engine_s_samples_tile_the_dispatch_thread_s_account(run_async):
     """Over a run of the tiny engine (prefill batches one ahead, pipelined
     chunks, a chunk left pending under the next round's prefills): every
-    dispatch's sample carries the three fields, and gap_ms + program_ms of
+    dispatch's sample carries the four fields, and gap_ms + program_ms of
     consecutive samples tile from the first program's enqueue to the last
-    one's completion."""
+    one's completion. The engine's one watcher thread ends with close()."""
+    before = len(_watchers())
+
     async def main():
         engine = _tiny_engine()
         try:
@@ -366,25 +593,44 @@ def test_an_engine_s_samples_tile_the_dispatch_thread_s_account(run_async):
                 engine.generate(f"tile the device's time {i} " * (1 + i % 3),
                                 {"max-tokens": 4 + 3 * i})
                 for i in range(7)))
+            assert len(_watchers()) == before + 1
         finally:
             await engine.close()
-        return engine.flight
+        return engine.flight, engine.attribution.report()
 
-    flight = run_async(main())
+    flight, programs = run_async(main())
+    assert len(_watchers()) == before      # joined in close()
     samples = [s for s in flight.recent(0) if s["phase"] != "stall"]
     assert len(samples) >= 8
     for s in samples:
         assert s["gap_ms"] >= 0 and s["program_ms"] >= 0, s
         assert s["resume_lag_ms"] >= 0, s
+        assert s["seen_by"] in ("watch", "fetch"), s
     clock = flight.clock
     assert not clock._open                 # every program was seen complete
-    span_ms = (clock.last_ready_t - clock.first_enqueued_t) * 1e3
+    span_ms = (clock.last_done_t - clock.first_enqueued_t) * 1e3
     tiled = sum(s["gap_ms"] + s["program_ms"] for s in samples)
     assert tiled == pytest.approx(span_ms, abs=0.001 * len(samples))  # rounding
     totals = flight.summary()["totals"]
     assert totals["gap_ms"] + sum(
         totals["program_ms_by_phase"].values()) == pytest.approx(span_ms, abs=0.01)
     assert set(totals["program_ms_by_phase"]) == {"prefill", "decode"}
+    assert sum(totals["completions_seen_by"].values()) == len(samples)
+    # the attribution ledger's measured side is the programs' own time
+    assert sum(p["device_s_total"] for p in programs) == pytest.approx(
+        sum(s["program_ms"] for s in samples if "program" in s) / 1e3, abs=1e-3)
+    # and /metrics mirrors the totals, the busy time by phase
+    from langstream_tpu.api.metrics import render_metrics
+
+    scrape = render_metrics().decode()
+    idle = re.search(r'langstream_engine_device_idle_seconds_total'
+                     r'\{agent_id="tiny"\} (\S+)', scrape)
+    busy = dict(re.findall(r'langstream_engine_device_busy_seconds_total'
+                           r'\{agent_id="tiny",phase="(\w+)"\} (\S+)', scrape))
+    assert float(idle.group(1)) >= totals["gap_ms"] / 1e3 - 1e-6
+    assert {"prefill", "decode"} <= set(busy)
+    assert all(float(busy[k]) >= v / 1e3 - 1e-6
+               for k, v in totals["program_ms_by_phase"].items())
     assert totals["resume_lag_ms"] == pytest.approx(
         sum(s["resume_lag_ms"] for s in samples), abs=0.001 * len(samples))
     # a program's own time is never more than the wall its sample tiles plus
@@ -1078,3 +1324,21 @@ def test_engine_top_render_smoke():
     assert "kv pool" in frame
     # empty report renders a hint, not a crash
     assert "no live engines" in engine_top.render([])
+
+
+@pytest.mark.parametrize("totals, line", [
+    ({"gap_ms": 50.0, "program_ms_by_phase": {"decode": 700.0, "prefill": 250.0},
+      "completions_seen_by": {"watch": 30, "fetch": 12}},
+     "device   idle 5.0%  decode 70.0%  prefill 25.0%  "
+     "(stamped by watch 30 / fetch 12)"),
+    # a payload from before the watcher: the fields were bounds, not shown
+    ({"gap_ms": 50.0, "program_ms_by_phase": {"decode": 700.0}}, None),
+    ({}, None),
+], ids=["the-device-s", "bounds", "nothing"])
+def test_engine_top_shows_the_device_s_idle_share_beside_the_host_s(totals, line):
+    engine_top = _load_engine_top()
+    assert engine_top._render_device_clock(totals) == line
+    entry = _canned_entry()
+    entry["summary"]["totals"].update(totals)
+    frame = engine_top.render([entry])
+    assert (line in frame) if line else ("device   idle" not in frame)

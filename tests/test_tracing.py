@@ -512,6 +512,35 @@ def test_fallback_registry_renders_exposition(monkeypatch):
     assert 'fb_lat_seconds_count{agent_id="a1"} 2' in body
 
 
+@pytest.mark.parametrize("have_prom", [True, False], ids=["client", "fallback"])
+def test_a_counter_s_own_labels_ride_beside_the_agent_s(monkeypatch, have_prom):
+    """``counter(labels=)``: one name, a series a label value
+    (``langstream_engine_device_busy_seconds_total{phase}``), on either
+    registry; a counter without labels renders as it did."""
+    import langstream_tpu.api.metrics as metrics_mod
+
+    if not have_prom:
+        monkeypatch.setattr(metrics_mod, "_HAVE_PROM", False)
+        monkeypatch.setattr(metrics_mod, "_fallback", {})
+    elif not metrics_mod._HAVE_PROM:
+        pytest.skip("prometheus_client is not installed")
+    reporter = metrics_mod.PrometheusMetricsReporter(
+        prefix=f"lbl{int(have_prom)}", agent_id="m1"
+    )
+    busy = {phase: reporter.counter("busy_seconds_total", "busy",
+                                    labels={"phase": phase})
+            for phase in ("decode", "prefill")}
+    busy["decode"](1.5)
+    busy["prefill"](0.25)
+    busy["decode"](0.5)
+    reporter.counter("idle_seconds_total", "idle")(0.125)
+    body = metrics_mod.render_metrics().decode()
+    name = f"lbl{int(have_prom)}"
+    assert f'{name}_busy_seconds_total{{agent_id="m1",phase="decode"}} 2.0' in body
+    assert f'{name}_busy_seconds_total{{agent_id="m1",phase="prefill"}} 0.25' in body
+    assert f'{name}_idle_seconds_total{{agent_id="m1"}} 0.125' in body
+
+
 # --------------------------------------------------------------------------
 # pod endpoints: /traces, /traces/<id>, /metrics content type
 # --------------------------------------------------------------------------

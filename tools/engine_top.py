@@ -116,6 +116,28 @@ def _shares(totals: dict) -> tuple[float, float, float, float]:
     return wall, 100 * device / denom, 100 * host / denom, 100 * stall / denom
 
 
+def _render_device_clock(totals: dict) -> str | None:
+    """The device's own clock (serving/flight.py DispatchClock): its idle
+    share and its busy time by phase over the engine's life, with no
+    profile, and who stamped the completions. None on a payload from
+    before the clock had its watcher (its two fields were bounds)."""
+    seen_by = totals.get("completions_seen_by")
+    busy = totals.get("program_ms_by_phase") or {}
+    idle = totals.get("gap_ms") or 0.0
+    total = idle + sum(busy.values())
+    if not seen_by or not total:
+        return None
+    phases = "  ".join(
+        f"{phase} {100 * ms / total:.1f}%"
+        for phase, ms in sorted(busy.items(), key=lambda kv: -kv[1])
+    )
+    return (
+        f"device   idle {100 * idle / total:.1f}%  "
+        f"{phases}  (stamped by watch {seen_by.get('watch', 0)} / "
+        f"fetch {seen_by.get('fetch', 0)})"
+    )
+
+
 # ---------------------------------------------------------------------------
 # live rendering
 # ---------------------------------------------------------------------------
@@ -180,6 +202,9 @@ def render(report: list[dict]) -> str:
                 f"overlap "
                 + (f"{100 * ratio:.1f}%" if ratio is not None else "-")
             )
+        device_line = _render_device_clock(totals)
+        if device_line:
+            lines.append(device_line)
         lines.extend(_render_health(entry.get("health")))
         lines.extend(_render_slo(entry.get("slo")))
         wall, device_pct, host_pct, stall_pct = _shares(totals)
